@@ -66,7 +66,7 @@ _FUNC_NODES = (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)
 
 def _transform_name(node: ast.AST) -> Optional[str]:
     """'jit'/'pmap'/'shard_map' when ``node`` names a JAX transform
-    (``jit``, ``jax.jit``, ``jax.experimental.shard_map.shard_map``)."""
+    (``jit``, ``jax.jit``, ``jax.shard_map``)."""
     name = au.dotted_name(node)
     if name is None:
         return None

@@ -10,8 +10,8 @@ baseline; 1 on an SLO failure or baseline regression; 2 on a config
 error — so CI can use this directly as a regression gate.
 
 The harness measures the serving path, not the accelerator: training is
-tiny linear models, so JAX is pinned to CPU by default
-(``--platform keep`` preserves the environment's choice).
+tiny linear models, so the run uses the CPU unless ``--platform`` says
+otherwise (``--platform keep`` leaves the choice to the environment).
 """
 
 import argparse
@@ -31,16 +31,22 @@ def main(argv=None) -> int:
     ap.add_argument("--artifacts", default=None,
                     help="artifact dir (default: artifacts/loadgen_<name>)")
     ap.add_argument("--platform", default="cpu",
-                    help="JAX_PLATFORMS for the run; 'keep' leaves the "
-                         "environment alone (default: cpu)")
+                    help="JAX platform for the run; 'keep' leaves the "
+                         "environment's choice alone (default: cpu)")
     ap.add_argument("--tick", type=float, default=0.1,
                     help="driver tick interval in seconds")
     args = ap.parse_args(argv)
 
-    if args.platform != "keep":
-        os.environ["JAX_PLATFORMS"] = args.platform
+    import jax
 
-    # import after the platform pin: these pull in jax
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
+    if args.platform != "keep":
+        # jax.config, not the environment: importing this package has
+        # already imported jax, which read JAX_PLATFORMS then
+        jax.config.update("jax_platforms", args.platform)
+
     from baton_tpu.loadgen.engine import run_scenario
     from baton_tpu.loadgen.scenario import ScenarioError, load_scenario
     from baton_tpu.loadgen.slo import evaluate_slo, write_report

@@ -65,16 +65,15 @@ flat ``{metric_name: float}`` namespace:
     ``peak_hbm_gb_max``, ``recompile_storm_rounds``. A compute value
     that is null *with a recorded reason* in every round (CPU smoke has
     no MFU) becomes a ``skips`` entry instead of a metric — the
-    baseline gate reports it ``skipped`` rather than regressed, exactly
-    the bench carve-out; a null with NO reason is simply absent and
-    regresses.
+    baseline gate reports it ``skipped`` rather than regressed; a null
+    with NO reason is simply absent and regresses.
 
 A *counter* address that the run never touched resolves to 0 — a
 counter is born at its first ``inc``, so absence IS zero
 (``counter:…``, ``fleet:counter:…``, and the ``loadgen:…`` namespace).
 Every other address — timers, gauges, derived ``rounds.*`` — stays
 missing when unproduced, and missing is a failure: "we stopped
-measuring it" is precisely the regression class that hid the BENCH_r04
+measuring it" is precisely the regression class that once hid a
 ``fused_rounds_per_sec`` drop.
 
 Two gates run over that namespace, both recorded in ``slo_report.json``:
@@ -551,99 +550,6 @@ def derive_compute_metrics(
     return metrics, skips
 
 
-def derive_bench_metrics(parsed: dict) -> "tuple[Dict[str, float], Dict[str, str]]":
-    """Flatten one ``bench.py`` output record into the flat SLO
-    namespace under a ``bench:`` prefix, so :func:`check_baseline` can
-    gate flagship performance the same way it gates scenario telemetry.
-
-    Returns ``(metrics, skips)``. A numeric field becomes
-    ``bench:<name>``; each ``flagship_mfu_recorded`` record becomes
-    ``bench:flagship:<model>:mfu`` / ``:rounds_per_sec``. A null
-    ``fused_rounds_per_sec`` / ``mfu`` with a recorded excuse
-    (``fused_skip_reason`` or ``degraded_reason``) lands in ``skips``
-    instead — visible and auditable; a null with NO recorded reason is
-    simply absent, which the baseline gate treats as a regression (the
-    BENCH_r03→r04 silent-drop class)."""
-    metrics: Dict[str, float] = {}
-    skips: Dict[str, str] = {}
-    for field in ("value", "rounds_per_sec", "dispatch_rounds_per_sec",
-                  "fused_rounds_per_sec", "mfu",
-                  "samples_per_sec_per_chip", "compile_s"):
-        v = parsed.get(field)
-        name = f"bench:{'rounds_per_sec' if field == 'value' else field}"
-        if isinstance(v, (int, float)) and not isinstance(v, bool):
-            metrics[name] = float(v)
-        elif v is None and field in parsed:
-            reason = parsed.get("fused_skip_reason") or parsed.get(
-                "degraded_reason"
-            )
-            if reason:
-                skips[name] = str(reason)
-    # donation on/off HBM-plan comparison and the wave1024 recorded
-    # number: null with a recorded ``*_reason`` skips; null without one
-    # regresses. Records from before bench.py emitted these fields are
-    # recognizable by the missing ``donation_enabled`` marker and skip
-    # with an explicit pre-schema note instead of failing the gate on
-    # history the new code never measured.
-    pre_schema = "donation_enabled" not in parsed
-    donation = parsed.get("donation_hbm")
-    if isinstance(donation, dict):
-        delta = donation.get("delta_gb")
-        if isinstance(delta, (int, float)) and not isinstance(delta, bool):
-            metrics["bench:donation_hbm_delta_gb"] = float(delta)
-        for variant in ("donate_on", "donate_off"):
-            plan = (donation.get(variant) or {}).get("plan_gb")
-            if isinstance(plan, (int, float)) and not isinstance(plan, bool):
-                metrics[f"bench:donation_{variant}_plan_gb"] = float(plan)
-    elif parsed.get("donation_hbm_reason"):
-        skips["bench:donation_hbm_delta_gb"] = str(
-            parsed["donation_hbm_reason"])
-    elif pre_schema:
-        skips["bench:donation_hbm_delta_gb"] = (
-            "record predates the donation-plan bench stage")
-    wave1024 = parsed.get("wave1024_recorded")
-    if isinstance(wave1024, dict):
-        rps = wave1024.get("rounds_per_sec")
-        if isinstance(rps, (int, float)) and not isinstance(rps, bool):
-            metrics["bench:wave1024_rounds_per_sec"] = float(rps)
-    elif parsed.get("wave1024_reason"):
-        skips["bench:wave1024_rounds_per_sec"] = str(
-            parsed["wave1024_reason"])
-    elif pre_schema:
-        skips["bench:wave1024_rounds_per_sec"] = (
-            "record predates the wave1024_reason bench field")
-    flagship = parsed.get("flagship_mfu_recorded") or {}
-    for rec in flagship.get("records") or []:
-        model = rec.get("model")
-        if not model:
-            continue
-        for field in ("mfu", "rounds_per_sec", "tokens_per_sec_per_chip",
-                      "peak_hbm_gb"):
-            v = rec.get(field)
-            if isinstance(v, (int, float)) and not isinstance(v, bool):
-                metrics[f"bench:flagship:{model}:{field}"] = float(v)
-    return metrics, skips
-
-
-def check_bench_baseline(
-    baseline: dict, parsed: dict
-) -> "tuple[List[dict], Dict[str, str]]":
-    """Baseline-delta gate over one bench record. Same comparison rules
-    as :func:`check_baseline`, with one bench-specific carve-out: a
-    metric that is missing *with a recorded skip reason* reports
-    ``skipped`` instead of regressing — an unmeasured flagship number
-    must name why (accelerator probe failed, budget exhausted), or it
-    fails CI."""
-    metrics, skips = derive_bench_metrics(parsed)
-    results = check_baseline(baseline, metrics)
-    for entry in results:
-        reason = skips.get(entry["metric"])
-        if entry["regression"] and entry["observed"] is None and reason:
-            entry["regression"] = False
-            entry["note"] = f"skipped: {reason}"
-    return results, skips
-
-
 def evaluate_slo(
     slo: SLOSpec,
     records: List[dict],
@@ -692,9 +598,8 @@ def evaluate_slo(
         for entry in results:
             reason = compute_skips.get(entry["metric"])
             if entry["regression"] and entry["observed"] is None and reason:
-                # same carve-out as check_bench_baseline: unmeasured
-                # WITH a recorded reason is a visible skip, not a
-                # silent regression
+                # unmeasured WITH a recorded reason is a visible skip,
+                # not a silent regression
                 entry["regression"] = False
                 entry["note"] = f"skipped: {reason}"
         baseline_block = {

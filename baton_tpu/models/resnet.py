@@ -58,9 +58,10 @@ def _conv_im2col(x, w, stride=1):
     Why this exists: the flagship workload vmaps the model over a client
     axis with PER-CLIENT weights. ``vmap`` of ``conv_general_dilated``
     with a batched rhs lowers to a C-group grouped convolution, whose
-    small per-group contractions leave the MXU mostly idle (measured
-    ~8% MFU on v5e, TPU_EVIDENCE_r3.md). This formulation keeps every
-    FLOP in a plain matmul: patch extraction is kh*kw strided slices
+    small per-group contractions leave the MXU mostly idle (recorded
+    at ~8% MFU on v5e; not measured on the current stack). This
+    formulation keeps every FLOP in a plain matmul: patch extraction
+    is kh*kw strided slices
     (pure data movement, weight-independent — vmap leaves it untouched),
     and the contraction [B*OH*OW, kh*kw*Cin] x [kh*kw*Cin, Cout] becomes
     an MXU-tiled *batched* matmul under client-vmap. The kh*kw-fold

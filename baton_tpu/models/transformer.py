@@ -133,67 +133,12 @@ def dot_product_attention(q, k, v, bias=None, causal=False):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-# dense materializes a [B, Hq, Lq, Lk] fp32 score tensor; beyond this
-# budget (or past the length where the Pallas kernel measures faster —
-# 4.1x at L=4096 on v5e, see bench.py's attention micro-bench) the
-# flash kernel takes over. The crossover and block shapes are module
-# state so a measured sweep can recalibrate them per process
-# (configure_attention_dispatch below).
+# Dense attention materializes a [B, Hq, Lq, Lk] fp32 score tensor; at
+# or past _FLASH_MIN_LEN keys, or past the score-tensor budget, the
+# Pallas flash kernel takes over. Both are constants: the threshold has
+# not been swept on the current stack (ROADMAP S5 / D6).
 _FLASH_MIN_LEN = 4096
-# None = inherit the kernel's own block defaults (and their internal
-# sequence clamping/alignment); a (block_q, block_k) tuple only after a
-# measured sweep configured one
-_FLASH_BLOCKS = None
 _DENSE_SCORES_BUDGET_BYTES = 512 * 1024 ** 2
-
-
-def configure_attention_dispatch(min_len=None, blocks=None,
-                                 sweep_path=None):
-    """Apply a MEASURED flash-vs-dense crossover to the dispatcher.
-
-    Explicit ``min_len`` / ``blocks`` win. Otherwise ``sweep_path``
-    names an artifact written by ``benchmarks/attention_sweep.py`` on
-    hardware (``attention_sweep_tpu.json``): the threshold becomes the
-    smallest measured L whose best flash block config beats the dense
-    einsum, and the dispatcher adopts that config's (block_q, block_k).
-    Only ``platform == "tpu"`` artifacts are trusted — a CPU/interpret
-    sweep must never steer the TPU dispatch. Returns the
-    ``(min_len, (block_q, block_k))`` now in effect; no-ops (returning
-    current state) when the artifact is missing/foreign or shows no
-    crossover.
-    """
-    global _FLASH_MIN_LEN, _FLASH_BLOCKS
-    if sweep_path is not None and min_len is None and blocks is None:
-        import json
-
-        try:
-            with open(sweep_path) as f:
-                sweep = json.load(f)
-            records = (sorted(sweep.get("results", []),
-                              key=lambda r: r.get("L", 1 << 30))
-                       if sweep.get("platform") == "tpu" else [])
-        except (OSError, ValueError, AttributeError):
-            records = []
-        for rec in records:
-            # per-record tolerance: one malformed row (a null timing, a
-            # foreign shape) must not discard the valid rows after it
-            try:
-                dense = rec.get("dense_ms")
-                flash = rec.get("flash") or {}
-                if not (isinstance(dense, (int, float)) and flash):
-                    continue
-                spec, ms = min(flash.items(), key=lambda kv: kv[1])
-                if ms < dense:
-                    min_len = rec["L"]
-                    blocks = tuple(int(x) for x in spec.split("x"))
-                    break
-            except (ValueError, KeyError, TypeError, AttributeError):
-                continue
-    if min_len is not None:
-        _FLASH_MIN_LEN = int(min_len)
-    if blocks is not None:
-        _FLASH_BLOCKS = (int(blocks[0]), int(blocks[1]))
-    return _FLASH_MIN_LEN, _FLASH_BLOCKS
 
 
 def default_attention(q, k, v, bias=None, causal=False):
@@ -202,10 +147,10 @@ def default_attention(q, k, v, bias=None, causal=False):
     On TPU, long sequences route to the Pallas flash-attention kernel
     (ops/flash_attention.py): O(L·block) memory instead of the dense
     [B, H, L, L] score tensor, fused online softmax, same numerics
-    (fp32 softmax, GQA), measured 4x faster than the dense einsum at
-    L=4096 on v5e. Short sequences stay on the dense path — XLA's fused
-    attention wins there (measured crossover ~2-4k), and so does every
-    non-TPU backend (CPU tests would hit the interpreted Pallas kernel).
+    (fp32 softmax, GQA). Short sequences stay on the dense path, and so
+    does every non-TPU backend (CPU tests would hit the interpreted
+    Pallas kernel). Which side is faster at which length is not
+    measured on the current stack.
 
     The dispatch happens at trace time (shapes and
     ``jax.default_backend()`` are ordinary Python), so the jitted
@@ -223,10 +168,7 @@ def default_attention(q, k, v, bias=None, causal=False):
         ):
             from baton_tpu.ops.flash_attention import flash_attention
 
-            kw = ({} if _FLASH_BLOCKS is None else
-                  {"block_q": _FLASH_BLOCKS[0],
-                   "block_k": _FLASH_BLOCKS[1]})
-            return flash_attention(q, k, v, bias=bias, causal=causal, **kw)
+            return flash_attention(q, k, v, bias=bias, causal=causal)
     return dot_product_attention(q, k, v, bias=bias, causal=causal)
 
 

@@ -24,8 +24,9 @@ replacing the dense ``dot_product_attention`` einsum path
   (transformer.py:31-32): additive per-key bias [B, 1, 1, L], static
   causal masking from global positions.
 
-On CPU (tests, the 8-device virtual mesh) the kernel runs in Pallas
-interpret mode — same code path, bit-compatible math.
+Interpret mode exists for the CPU tests (same code path, same math)
+and is refused on a TPU backend — :func:`_resolve_interpret` is the one
+place that decides.
 """
 
 from __future__ import annotations
@@ -37,25 +38,33 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces; absent on CPU-only builds of pallas
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
 def _spec(block, index_map):
-    if _VMEM is None:
-        return pl.BlockSpec(block, index_map)
-    return pl.BlockSpec(block, index_map, memory_space=_VMEM)
+    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """The one place that decides between the Mosaic-compiled kernel and
+    the Pallas interpreter. ``None`` means the backend's only sound
+    choice: compiled on a TPU, interpreted on the CPU (the test
+    backend). Interpreting on a TPU would report a kernel that never
+    ran, so it is an error there, as is any other backend."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        if interpret:
+            raise ValueError(
+                "flash attention: interpret=True on a TPU backend — the "
+                "Pallas interpreter is for CPU tests only")
+        return False
+    if backend != "cpu":
+        raise NotImplementedError(
+            f"flash attention has a TPU kernel and a CPU interpreter; "
+            f"backend {backend!r} has neither")
+    return True if interpret is None else bool(interpret)
 
 
 # ======================================================================
@@ -133,19 +142,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
 
 def _compiler_params(n_parallel: int):
     """Mark the leading grid axes parallel, the innermost sequential."""
-    if _VMEM is None:  # pragma: no cover
-        return None
-    semantics = ("parallel",) * n_parallel + ("arbitrary",)
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    return cls(dimension_semantics=semantics) if cls else None
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))
 
 
 def _scratch(shape, dtype=jnp.float32):
-    if _VMEM is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU memory spaces unavailable")
-    return _VMEM(shape, dtype)
+    return pltpu.VMEM(shape, dtype)
 
 
 def _fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
@@ -426,8 +428,7 @@ def flash_attention(
     hkv, lk = k.shape[1], k.shape[2]
     assert hq % hkv == 0, f"GQA needs Hq % Hkv == 0, got {hq} % {hkv}"
     assert v.shape == k.shape
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = _resolve_interpret(interpret)
     scale = d ** -0.5
 
     if bias is None:
@@ -512,8 +513,7 @@ def flash_block_fwd(q, k, v, bias2d, causal, block_q=512, block_k=1024,
     outer custom VJP."""
     b, hq, lq, d = q.shape
     lk = k.shape[2]
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = _resolve_interpret(interpret)
     scale = d ** -0.5
     block_q, block_k, pad_q, pad_k = _prepare_padding(
         lq, lk, block_q, block_k, interpret
@@ -536,8 +536,7 @@ def flash_block_bwd(q, k, v, bias2d, out, dout, lse, causal,
     global gradients."""
     b, hq, lq, d = q.shape
     lk = k.shape[2]
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = _resolve_interpret(interpret)
     scale = d ** -0.5
     block_q, block_k, pad_q, pad_k = _prepare_padding(
         lq, lk, block_q, block_k, interpret

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from baton_tpu.ops import aggregation as agg
-from baton_tpu.parallel.compat import shard_map
 from baton_tpu.parallel.engine import FedSim
 
 Params = Any
@@ -177,7 +176,7 @@ class ClusteredFedSim:
             from baton_tpu.parallel.partition import kernel_specs
 
             in_specs, out_specs = kernel_specs("clustered.round")
-            self._jit_cache[key] = jax.jit(shard_map(
+            self._jit_cache[key] = jax.jit(jax.shard_map(
                 self._assign_train_combine(n_epochs,
                                            psum_axis=CLIENT_AXIS),
                 mesh=self.sim.mesh,

@@ -45,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from baton_tpu.ops import aggregation as agg
-from baton_tpu.parallel.compat import shard_map
 from baton_tpu.parallel.engine import FedSim
 from baton_tpu.parallel.mesh import (
     CLIENT_AXIS,
@@ -176,7 +175,7 @@ class FedBuff:
             in_specs, out_specs = kernel_specs("fedbuff.train")
             # donation decided no: the anchor stack is re-read
             # after the dispatch to form the staleness deltas
-            cache[n_epochs] = jax.jit(shard_map(  # batonlint: allow[BTL011]
+            cache[n_epochs] = jax.jit(jax.shard_map(  # batonlint: allow[BTL011]
                 kernel,
                 mesh=mesh,
                 in_specs=in_specs,

@@ -41,6 +41,12 @@ def make_mesh(
     """
     devs = list(devices if devices is not None else jax.devices())
     if n_devices is not None:
+        if n_devices > len(devs):
+            raise ValueError(
+                f"make_mesh({n_devices}) asked for more devices than "
+                f"exist ({len(devs)} {devs[0].platform} device(s)); a "
+                "smaller mesh would run the program on fewer chips than "
+                "the caller believes")
         devs = devs[:n_devices]
     n = len(devs)
     # All devices go on the first axis; callers wanting a factored

@@ -35,7 +35,6 @@ import numpy as np
 
 from baton_tpu.core.partition import PathPredicate, make_partition
 from baton_tpu.ops import aggregation as agg
-from baton_tpu.parallel.compat import shard_map
 from baton_tpu.parallel.engine import FedSim, client_eval_sums
 
 Params = Any
@@ -182,7 +181,7 @@ class FedPer:
             in_specs, out_specs = kernel_specs("personalization.round")
             # donation decided no: the personal stack is caller
             # state, threaded (and possibly re-read) across rounds
-            self._jit_cache[key] = jax.jit(shard_map(  # batonlint: allow[BTL011]
+            self._jit_cache[key] = jax.jit(jax.shard_map(  # batonlint: allow[BTL011]
                 kernel,
                 mesh=self.sim.mesh,
                 in_specs=in_specs,

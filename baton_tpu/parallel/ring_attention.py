@@ -49,7 +49,6 @@ from jax.sharding import Mesh
 
 from baton_tpu.parallel.partition import dim_spec
 
-from baton_tpu.parallel.compat import pcast_varying, shard_map
 
 SEQ_AXIS = "seq"
 
@@ -118,7 +117,7 @@ def ring_attention(q, k, v, axis_name: str = SEQ_AXIS, causal: bool = False,
     # loop (ppermute outputs are varying); mark them varying up front so
     # the fori_loop carry types are stable
     def varying(x):
-        return pcast_varying(x, axis_name)
+        return lax.pcast(x, (axis_name,), to="varying")
 
     if bias is None:
         # locally-created zeros are invariant; the real bias arrives as a
@@ -228,7 +227,7 @@ def _flash_ring_fwd(q, k, v, bias2d, axis_name, causal, block_q, block_k,
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def varying(x):
-        return pcast_varying(x, axis_name)
+        return lax.pcast(x, (axis_name,), to="varying")
 
     if bias2d is None:
         bias2d = varying(jnp.zeros((q.shape[0], k.shape[2]), jnp.float32))
@@ -281,7 +280,7 @@ def _flash_ring_bwd(axis_name, causal, block_q, block_k, interpret,
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def varying(x):
-        return pcast_varying(x, axis_name)
+        return lax.pcast(x, (axis_name,), to="varying")
 
     had_bias = bias2d is not None
     if bias2d is None:
@@ -410,7 +409,7 @@ def _seq_sharded_fn(kernel, mesh: Mesh, axis_name: str, with_bias: bool,
     # annotation; the dense ring/Ulysses kernels keep full VMA checking
     if with_bias:
         @partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(spec, spec, spec, bias_spec), out_specs=spec,
             check_vma=check_vma,
         )
@@ -418,7 +417,7 @@ def _seq_sharded_fn(kernel, mesh: Mesh, axis_name: str, with_bias: bool,
             return kernel(q, k, v, bias=bias2d)
     else:
         @partial(
-            shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+            jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
             out_specs=spec, check_vma=check_vma,
         )
         def sharded(q, k, v):

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from baton_tpu.ops import aggregation as agg
-from baton_tpu.parallel.compat import shard_map
 from baton_tpu.parallel.engine import FedSim, _server_update
 
 Params = Any
@@ -130,7 +129,7 @@ class StatefulClients:
             in_specs, out_specs = kernel_specs("stateful.round")
             # donation decided no: params is the retained anchor and
             # the optimizer-state stack is caller-threaded round state
-            self._jit_cache[key] = jax.jit(shard_map(  # batonlint: allow[BTL011]
+            self._jit_cache[key] = jax.jit(jax.shard_map(  # batonlint: allow[BTL011]
                 kernel,
                 mesh=self.sim.mesh,
                 in_specs=in_specs,

@@ -2797,7 +2797,7 @@ class Experiment:
             if not pks:
                 # observable abort: a silent {} return made a whole
                 # cohort's failure look like "workers never responded"
-                # (C=256 postmortem, CHANGES_r5.md)
+                # (C=256 postmortem)
                 self.metrics.inc("secure_rounds_aborted_keys")
                 _log.warning(
                     "%s: secure round aborted — no member advertised "

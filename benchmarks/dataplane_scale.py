@@ -73,10 +73,9 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-from baton_tpu.utils.profiling import configure_jax_for_bench  # noqa: E402
+from baton_tpu.utils.profiling import enable_compile_cache  # noqa: E402
 
-# MUST run before any backend touch (see secure_round_scale.py)
-configure_jax_for_bench()
+enable_compile_cache()
 
 import numpy as np  # noqa: E402
 from aiohttp import web  # noqa: E402

@@ -1,7 +1,16 @@
 """Break down one bench round's cost on the TPU."""
+import os
+import sys
 import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 import jax, jax.numpy as jnp
 import numpy as np
+
+from baton_tpu.utils.profiling import enable_compile_cache
+
+enable_compile_cache()
 from baton_tpu.models.resnet import resnet18_cifar_model
 from baton_tpu.ops.padding import stack_client_datasets
 from baton_tpu.parallel.engine import FedSim

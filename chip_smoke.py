@@ -1,0 +1,666 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py                  # on a TPU host: every phase, full width
+    python chip_smoke.py --rehearse-cpu   # here: same code path, toy sizes
+
+Drives the main path once through the entry points a user calls —
+``FedSim.run_round`` (vmapped ``LocalTrainer.train`` + the weighted
+fold) on ResNet-18/CIFAR-10 bf16, 32 clients x 48 samples, batch 32 —
+then the Pallas flash kernel (alone against the dense reference, and
+reached through a decoder's ``default_attention`` under the client
+``vmap``), one in-process HTTP federation whose workers train on the
+device, the client mesh when the host has more than one device, and
+the compile cache. Weights are random from a seed, depth is cut, data
+is generated (the chip machine has no network).
+
+One process, no subprocess, no platform or cache directory set here.
+Each phase prints one line; an exception in any phase ends the run
+non-zero. Without ``--rehearse-cpu`` the run refuses any platform but
+``tpu``. The last line of standard output is one JSON object::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The seconds printed by the chip run are set-up facts of the bring-up
+(cold or warm compile), not benchmark metrics; a rehearsal prints none.
+"""
+
+from __future__ import annotations
+
+import argparse
+import asyncio
+import dataclasses
+import importlib.metadata
+import json
+import os
+import socket
+import sys
+import time
+from functools import partial
+
+# the repo's bf16 agreement tolerance (tests/test_flash_attention.py
+# ::test_bfloat16_io), applied relative to the reference's largest entry
+BF16_TOL = 2e-2
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    # fedsim_resnet18 / mesh — bench.py's cell
+    clients: int
+    samples: int
+    batch: int
+    image: int
+    rounds_after_compile: int
+    # flash_kernel, alone: [B, H, L, Dh]
+    flash_shape: tuple
+    # flash_kernel, through the decoder: sequence length (widths are
+    # the 0.9 B preset's on the chip, LlamaConfig.tiny in rehearsal)
+    decoder_len: int
+    # mesh: ring attention [B, H, L, Dh]
+    ring_shape: tuple
+
+
+CHIP = Sizes(clients=32, samples=48, batch=32, image=32,
+             rounds_after_compile=4, flash_shape=(4, 8, 4096, 64),
+             decoder_len=4096, ring_shape=(1, 8, 4096, 64))
+REHEARSAL = Sizes(clients=4, samples=8, batch=4, image=8,
+                  rounds_after_compile=3, flash_shape=(1, 2, 256, 64),
+                  decoder_len=128, ring_shape=(1, 2, 256, 64))
+
+
+@dataclasses.dataclass(frozen=True)
+class Env:
+    sizes: Sizes
+    rehearsal: bool
+    platform: str
+    kind: str
+    count: int
+    cache_dir: str
+    cache_from_env: bool
+
+    def say(self, phase: str, checked: str) -> None:
+        tag = "[chip_smoke rehearsal]" if self.rehearsal else "[chip_smoke]"
+        print(f"{tag} phase={phase} platform={self.platform} "
+              f"device_kind={self.kind!r} devices={self.count}: {checked}",
+              flush=True)
+
+    def seconds(self, s: float) -> str:
+        """A wall time, printed only where it was taken on the chip."""
+        return "not measured (rehearsal)" if self.rehearsal else f"{s:.1f}s"
+
+
+def _check(cond: bool, what: str) -> None:
+    if not cond:
+        raise AssertionError(what)
+
+
+def _rel_err(got, ref) -> float:
+    """max |got - ref| over max(1, max |ref|), both read as float32."""
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    _check(got.shape == ref.shape, f"shape {got.shape} != {ref.shape}")
+    _check(bool(np.isfinite(got).all()), "non-finite values")
+    return float(np.max(np.abs(got - ref)) / max(1.0, np.max(np.abs(ref))))
+
+
+def _on_platform(tree, platform: str) -> bool:
+    import jax
+
+    return all(d.platform == platform
+               for leaf in jax.tree_util.tree_leaves(tree)
+               for d in leaf.devices())
+
+
+# ----------------------------------------------------------------------
+def phase_device(env: Env) -> None:
+    import jax
+    import jaxlib
+
+    from baton_tpu.obs.compute import peak_flops_for
+    from baton_tpu.utils.profiling import hbm_budget_gb
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    dev = jax.devices()[0]
+    peak, why = peak_flops_for(env.kind)
+    if env.rehearsal:
+        # the tables hold accelerators only: the rehearsal checks that
+        # they refuse the CPU instead of defaulting
+        _check(peak is None and bool(why), "peak table answered for a cpu")
+        try:
+            hbm_budget_gb(dev)
+        except ValueError:
+            tables = "both device tables refuse this device, as they must"
+        else:
+            raise AssertionError("hbm_budget_gb answered for a cpu")
+    else:
+        _check(peak is not None, f"obs/compute.py: {why}")
+        tables = (f"peak {peak / 1e12:.0f} TFLOP/s bf16, plan budget "
+                  f"{hbm_budget_gb(dev):.1f} GiB")
+    env.say("device", f"jax {jax.__version__} jaxlib {jaxlib.__version__} "
+            f"libtpu {libtpu}; {tables}")
+
+
+# ----------------------------------------------------------------------
+def _resnet_cell(env: Env):
+    """(model, params, data, n_samples): bench.py's ResNet cell — same
+    shapes and dtypes, hence the same compiled program — with labels
+    that are a fixed function of the images (argmax of a seeded random
+    projection), so that the loss can be required to fall."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models.resnet import resnet18_cifar_model, resnet_model
+    from baton_tpu.ops.padding import stack_client_datasets
+
+    sz = env.sizes
+    rng = np.random.default_rng(0)
+    proj = np.random.default_rng(1).normal(
+        size=(sz.image * sz.image * 3, 10)).astype(np.float32)
+    datasets = []
+    for _ in range(sz.clients):
+        x = rng.normal(
+            size=(sz.samples, sz.image, sz.image, 3)).astype(np.float32)
+        y = np.argmax(x.reshape(sz.samples, -1) @ proj, axis=-1)
+        datasets.append({"x": x, "y": y.astype(np.int32)})
+    data, n_samples = stack_client_datasets(datasets, batch_size=sz.batch)
+    if env.rehearsal:
+        model = resnet_model(blocks_per_stage=(1, 1), n_groups=8,
+                             compute_dtype=jnp.bfloat16,
+                             name="resnet_rehearsal")
+    else:
+        model = resnet18_cifar_model(compute_dtype=jnp.bfloat16)
+    return model, model.init(jax.random.key(0)), data, n_samples
+
+
+def phase_fedsim_resnet18(env: Env) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.parallel.engine import FedSim
+
+    sz = env.sizes
+    model, params, data, n_samples = _resnet_cell(env)
+    data = {k: jax.device_put(jnp.asarray(v)) for k, v in data.items()}
+    n_samples = jnp.asarray(n_samples)
+    sim = FedSim(model, batch_size=sz.batch, learning_rate=0.05)
+    key = jax.random.key(1)
+
+    losses, secs = [], []
+    p = params
+    for i in range(1 + sz.rounds_after_compile):
+        t0 = time.perf_counter()
+        res = sim.run_round(p, data, n_samples, jax.random.fold_in(key, i),
+                            n_epochs=1, collect_client_losses=False)
+        losses.append(float(res.loss_history[-1]))  # host fetch = sync
+        secs.append(time.perf_counter() - t0)
+        p = res.params
+    jax.block_until_ready(p)
+    # read before the checks below put anything else on the device
+    stats = jax.devices()[0].memory_stats() or {}
+
+    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
+    _check(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    init_shapes = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
+    out_shapes = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), p)
+    _check(init_shapes == out_shapes, "round changed the parameter tree")
+    _check(all(bool(jnp.isfinite(leaf).all())
+               for leaf in jax.tree_util.tree_leaves(p)),
+           "non-finite parameters after the last round")
+    _check(_on_platform(p, env.platform),
+           f"round outputs do not live on the {env.platform} device")
+    rec = sim.last_compute
+    _check(rec is not None and rec["device_kind"] == env.kind,
+           f"compute record names another device: {rec}")
+
+    # the TPU runtime counts live arrays (peak_bytes_in_use) and a
+    # running program's temporaries (peak_bytes_reserved) apart; the
+    # CPU keeps no statistics at all
+    peak = stats.get("peak_bytes_in_use", 0)
+    reserved = stats.get("peak_bytes_reserved", 0)
+    if not env.rehearsal:
+        _check(peak > 0 and reserved > 0,
+               f"memory_stats() reports no peak: {stats}")
+        _check(abs(rec["peak_hbm_gb"] - (peak + reserved) / 2**30) < 1e-3,
+               f"compute record does not carry the allocator peak: {rec}")
+    env.say(
+        "fedsim_resnet18",
+        f"{model.name} bf16 {sz.clients}x{sz.samples} b{sz.batch}, "
+        f"{len(losses)} rounds, loss {losses[0]:.4f} -> {losses[-1]:.4f} "
+        f"(finite, falling); outputs on {env.platform}; first round "
+        f"(compile + run) {env.seconds(secs[0])}, then "
+        f"{env.seconds(sum(secs[1:]) / len(secs[1:]))}/round; "
+        f"memory_stats peak_bytes_in_use={peak} "
+        f"peak_bytes_reserved={reserved}")
+
+
+# ----------------------------------------------------------------------
+def _flash_alone(env: Env) -> str:
+    import jax
+    import jax.numpy as jnp
+
+    from baton_tpu.models.transformer import dot_product_attention
+    from baton_tpu.ops.flash_attention import flash_attention
+
+    shape = env.sizes.flash_shape
+    kq, kk, kv, kw = jax.random.split(jax.random.key(7), 4)
+    q = jax.random.normal(kq, shape, jnp.bfloat16)
+    k = jax.random.normal(kk, shape, jnp.bfloat16)
+    v = jax.random.normal(kv, shape, jnp.bfloat16)
+    w = jax.random.normal(kw, shape, jnp.float32)  # cotangent
+    # the default (512, 1024) blocks; interpret only ever in rehearsal
+    attn = partial(flash_attention, causal=True, interpret=env.rehearsal)
+
+    fwd = jax.jit(attn)
+    bwd = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w),
+        argnums=(0, 1, 2)))
+    if not env.rehearsal:
+        for name, fn in (("forward", fwd), ("backward", bwd)):
+            _check("tpu_custom_call" in fn.lower(q, k, v).as_text(),
+                   f"flash {name} lowered without a tpu_custom_call")
+    out = fwd(q, k, v)
+    grads = bwd(q, k, v)
+
+    # dense reference in float32, one batch element at a time (the
+    # [H, L, L] fp32 scores of all four at once would crowd the chip)
+    @jax.jit
+    def ref_one(q1, k1, v1, w1):
+        with jax.default_matmul_precision("highest"):
+            o, vjp = jax.vjp(
+                partial(dot_product_attention, causal=True), q1, k1, v1)
+            return o, vjp(w1)
+
+    errs = {"out": 0.0, "dq": 0.0, "dk": 0.0, "dv": 0.0}
+    f32 = partial(jnp.asarray, dtype=jnp.float32)
+    for b in range(shape[0]):
+        s = slice(b, b + 1)
+        o_ref, g_ref = ref_one(f32(q[s]), f32(k[s]), f32(v[s]), w[s])
+        pairs = (("out", out[s], o_ref), ("dq", grads[0][s], g_ref[0]),
+                 ("dk", grads[1][s], g_ref[1]), ("dv", grads[2][s], g_ref[2]))
+        for name, got, ref in pairs:
+            errs[name] = max(errs[name], _rel_err(got, ref))
+    _check(max(errs.values()) <= BF16_TOL,
+           f"flash vs dense float32 beyond {BF16_TOL}: {errs}")
+    mode = "interpret" if env.rehearsal else "interpret=False, tpu_custom_call"
+    return (f"flash_attention {list(shape)} bf16 causal ({mode}) vs dense "
+            f"float32: " + " ".join(f"{k}={v:.1e}" for k, v in errs.items())
+            + f" (tol {BF16_TOL})")
+
+
+def _flash_through_decoder(env: Env) -> str:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models.llama import LlamaConfig, llama_lm_model
+    from baton_tpu.ops.flash_attention import make_flash_attention_fn
+    from baton_tpu.ops.padding import stack_client_datasets
+    from baton_tpu.parallel.engine import FedSim
+
+    L = env.sizes.decoder_len
+    if env.rehearsal:
+        # default_attention keeps the CPU on the dense path, so the
+        # rehearsal hands the model the interpreted kernel explicitly
+        cfg = LlamaConfig.tiny(max_len=L)
+        model = llama_lm_model(
+            cfg, compute_dtype=jnp.bfloat16, remat=True,
+            attention_fn=make_flash_attention_fn(interpret=True))
+    else:
+        # the 0.9 B preset's widths (benchmarks/tpu_suite.py::child_llama)
+        # at 2 of its 16 layers; attention left to default_attention
+        cfg = LlamaConfig(vocab_size=32000, max_len=L, d_model=2048,
+                          n_layers=2, n_heads=16, n_kv_heads=8, d_ff=5632,
+                          rope_theta=500000.0)
+        model = llama_lm_model(cfg, compute_dtype=jnp.bfloat16, remat=True)
+    rng = np.random.default_rng(0)
+    datasets = []
+    for _ in range(2):
+        ids = rng.integers(0, cfg.vocab_size, size=(1, L + 1))
+        datasets.append({"x": ids[:, :-1].astype(np.int32),
+                         "y": ids[:, 1:].astype(np.int32)})
+    data, n_samples = stack_client_datasets(datasets, batch_size=1)
+    data = {k: jax.device_put(jnp.asarray(v)) for k, v in data.items()}
+    n_samples = jnp.asarray(n_samples)
+    params = model.init(jax.random.key(0))
+    sim = FedSim(model, batch_size=1, learning_rate=1e-3)
+    key = jax.random.key(1)
+
+    if not env.rehearsal:
+        lowered = FedSim._wave_sums_vmap.lower(
+            sim, params, None, data, n_samples, jax.random.split(key, 2), 1)
+        _check("tpu_custom_call" in lowered.as_text(),
+               "decoder round lowered without a tpu_custom_call: something "
+               "stood in for the flash kernel")
+    res = sim.run_round(params, data, n_samples, key, n_epochs=1,
+                        collect_client_losses=False)
+    loss = float(res.loss_history[-1])
+    # random tokens on fresh weights: the loss sits near ln(vocab)
+    _check(np.isfinite(loss) and abs(loss - np.log(cfg.vocab_size)) < 2.0,
+           f"decoder round loss {loss}, ln(vocab) {np.log(cfg.vocab_size)}")
+    _check(all(bool(jnp.isfinite(leaf).all())
+               for leaf in jax.tree_util.tree_leaves(res.params)),
+           "non-finite decoder parameters after the round")
+    return (f"run_round of a {cfg.n_layers}-layer decoder d{cfg.d_model} "
+            f"h{cfg.n_heads}/kv{cfg.n_kv_heads} ff{cfg.d_ff} "
+            f"v{cfg.vocab_size} L{L}, 2 clients x b1, remat: loss "
+            f"{loss:.4f} (ln vocab {np.log(cfg.vocab_size):.2f})")
+
+
+def phase_flash_kernel(env: Env) -> None:
+    env.say("flash_kernel",
+            _flash_alone(env) + "; " + _flash_through_decoder(env))
+
+
+# ----------------------------------------------------------------------
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+async def _federation(env: Env) -> str:
+    import aiohttp
+    import numpy as np
+    from aiohttp import web
+
+    from baton_tpu.core.training import make_local_trainer
+    from baton_tpu.data.synthetic import linear_client_data
+    from baton_tpu.models.linear import linear_regression_model
+    from baton_tpu.server.http_manager import Manager
+    from baton_tpu.server.http_worker import ExperimentWorker
+
+    n_rounds, n_epoch = 2, 4
+    model = linear_regression_model(10)  # the demo's model
+    nprng = np.random.default_rng(0)
+    mport = _free_port()
+    mapp = web.Application()
+    exp = Manager(mapp).register_experiment(
+        model, name="lineartest", round_timeout=120.0)
+    runners = [web.AppRunner(mapp)]
+    await runners[0].setup()
+    workers = []
+    try:
+        await web.TCPSite(runners[0], "127.0.0.1", mport).start()
+        for _ in range(2):
+            wport = _free_port()
+            wdata = linear_client_data(nprng, min_batches=2, max_batches=3)
+            wapp = web.Application()
+            worker = ExperimentWorker(
+                wapp, model, f"127.0.0.1:{mport}", port=wport,
+                heartbeat_time=1.0,
+                trainer=make_local_trainer(model, batch_size=32,
+                                           learning_rate=0.02),
+                get_data=lambda d=wdata: (d, d["x"].shape[0]),
+            )
+            worker.enable_progress_metrics()  # as demo.py does
+            runner = web.AppRunner(wapp)
+            await runner.setup()
+            runners.append(runner)
+            await web.TCPSite(runner, "127.0.0.1", wport).start()
+            workers.append(worker)
+
+        async def wait_for(cond, what, seconds=120.0):
+            deadline = time.monotonic() + seconds
+            while not cond():
+                _check(time.monotonic() < deadline, f"timed out: {what}")
+                await asyncio.sleep(0.05)
+
+        def finished() -> int:
+            return int(exp.metrics.snapshot()["counters"].get(
+                "rounds_finished", 0))
+
+        await wait_for(lambda: len(exp.registry) == 2, "workers registering")
+        base = f"http://127.0.0.1:{mport}/lineartest"
+        async with aiohttp.ClientSession() as session:
+            for r in range(n_rounds):
+                async with session.get(
+                        f"{base}/start_round?n_epoch={n_epoch}") as resp:
+                    _check(resp.status == 200, f"start_round {resp.status}")
+                    acks = await resp.json()
+                    _check(len(acks) == 2 and all(acks.values()),
+                           f"round {r} acks {acks}")
+                await wait_for(lambda: finished() == r + 1,
+                               f"round {r} finishing")
+            async with session.get(f"{base}/loss_history") as resp:
+                history = await resp.json()
+
+        _check(finished() == n_rounds, f"rounds_finished {finished()}")
+        _check(len(history) == n_rounds * n_epoch
+               and all(np.isfinite(history)) and history[-1] < history[0],
+               f"loss_history not falling: {history}")
+        for w in workers:
+            _check(w.n_updates == n_rounds, f"worker sent {w.n_updates}")
+            _check(_on_platform(w.params, env.platform),
+                   f"worker trained off the {env.platform} device")
+            epochs = w.metrics.snapshot()["counters"].get(
+                "train_epochs_completed", 0)
+            _check(epochs == n_rounds * n_epoch,
+                   f"io_callback fired {epochs} times, expected "
+                   f"{n_rounds * n_epoch}")
+        return (f"manager + 2 workers on loopback, {n_rounds} rounds x "
+                f"{n_epoch} epochs through start_round: rounds_finished="
+                f"{finished()}, loss {history[0]:.3f} -> {history[-1]:.3f}, "
+                f"workers' params on {env.platform}, ordered io_callback "
+                f"fired every epoch")
+    finally:
+        for runner in reversed(runners):
+            await runner.cleanup()
+
+
+def _codec_and_fold(env: Env) -> str:
+    """Device arrays, bf16 included, through the BTW1 codec and the
+    streaming fold, against numpy."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.ops.aggregation import StreamingMean
+    from baton_tpu.server import wire
+
+    ka, kb = jax.random.split(jax.random.key(3))
+    updates = [
+        {"w": jax.random.normal(k, (64, 10), jnp.bfloat16),
+         "b": jax.random.normal(k, (10,), jnp.float32)}
+        for k in (ka, kb)
+    ]
+    _check(_on_platform(updates, env.platform), "codec inputs off the device")
+    weights = (96.0, 64.0)
+    fold = StreamingMean()
+    for upd, wt in zip(updates, weights):
+        host = {k: np.asarray(v) for k, v in upd.items()}
+        tensors, meta = wire.decode(wire.encode(host, {"n_samples": wt}))
+        _check(meta == {"n_samples": wt}, f"codec meta {meta}")
+        for name, arr in host.items():
+            _check(tensors[name].dtype == arr.dtype
+                   and tensors[name].tobytes() == arr.tobytes(),
+                   f"codec changed {name} ({arr.dtype})")
+        fold.add(tensors, wt)
+    mean = fold.mean()
+    for name in ("w", "b"):
+        ref = sum(np.asarray(u[name], np.float32) * np.float32(wt)
+                  for u, wt in zip(updates, weights)) / np.float32(sum(weights))
+        _check(np.allclose(mean[name], ref, rtol=1e-6, atol=1e-6),
+               f"streaming fold of {name} disagrees with numpy")
+    return "bf16 + f32 device arrays bit-exact through BTW1, fold = numpy"
+
+
+def phase_http_round(env: Env) -> None:
+    env.say("http_round",
+            asyncio.run(_federation(env)) + "; " + _codec_and_fold(env))
+
+
+# ----------------------------------------------------------------------
+def phase_mesh(env: Env) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.ops.flash_attention import flash_attention
+    from baton_tpu.parallel.engine import FedSim
+    from baton_tpu.parallel.mesh import make_mesh, shard_client_arrays
+    from baton_tpu.parallel.ring_attention import (
+        SEQ_AXIS,
+        make_flash_ring_attention_fn,
+    )
+
+    n = env.count
+    if n == 1:
+        env.say("mesh", "skipped: one device (the phase needs several)")
+        return
+    sz = env.sizes
+    devices = set(jax.devices())
+
+    # --- the ResNet cell, client axis sharded over every device ---
+    model, params, data, n_samples = _resnet_cell(env)
+    data = {k: jnp.asarray(v) for k, v in data.items()}
+    n_samples = jnp.asarray(n_samples)
+    key = jax.random.key(1)
+    one = FedSim(model, batch_size=sz.batch, learning_rate=0.05).run_round(
+        params, data, n_samples, key, n_epochs=1)
+    mesh = make_mesh()
+    sharded = shard_client_arrays(data, mesh)
+    for name, arr in sharded.items():
+        _check(arr.sharding.device_set == devices
+               and len({s.device for s in arr.addressable_shards}) == n,
+               f"client data {name!r} is not spread over {n} devices")
+    sim_mesh = FedSim(model, batch_size=sz.batch, learning_rate=0.05,
+                      mesh=mesh)
+    res = sim_mesh.run_round(params, sharded, n_samples, key, n_epochs=1)
+    jax.block_until_ready(res.params)
+    _check(res.client_losses.sharding.device_set == devices,
+           f"per-client work ran on {res.client_losses.sharding.device_set}")
+    in_use = []
+    for d in jax.devices():
+        stats = d.memory_stats()
+        in_use.append((stats or {}).get("bytes_in_use", 0))
+    if not env.rehearsal:
+        _check(all(b > 0 for b in in_use),
+               f"a device holds no bytes: {in_use}")
+    # same seed, same math, another reduction order (psum over devices)
+    # and bf16 compute: the round's update must agree to the bf16
+    # tolerance, relative to the largest entry of the update itself
+    leaves = jax.tree_util.tree_leaves
+    upd_mesh = [np.asarray(a, np.float32) - np.asarray(p0, np.float32)
+                for a, p0 in zip(leaves(res.params), leaves(params))]
+    upd_one = [np.asarray(b, np.float32) - np.asarray(p0, np.float32)
+               for b, p0 in zip(leaves(one.params), leaves(params))]
+    _check(all(np.isfinite(u).all() for u in upd_mesh),
+           "non-finite parameters from the mesh round")
+    scale = max(float(np.max(np.abs(u))) for u in upd_one)
+    _check(scale > 0, "the one-device round did not move the parameters")
+    diff = max(float(np.max(np.abs(a - b)))
+               for a, b in zip(upd_mesh, upd_one)) / scale
+    _check(diff <= BF16_TOL, f"mesh vs one device: update differs by {diff}")
+    rec = sim_mesh.last_compute
+    _check(rec["n_chips"] == n, f"compute record counts {rec['n_chips']} "
+           f"chips for a round spread over {n}")
+    loss_gap = abs(float(res.loss_history[-1]) - float(one.loss_history[-1]))
+    _check(loss_gap <= BF16_TOL, f"mesh vs one device: loss gap {loss_gap}")
+
+    # --- one step of flash ring attention over a ('seq',) mesh ---
+    shape = sz.ring_shape
+    kq, kk, kv, kw = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(kq, shape, jnp.bfloat16)
+    k = jax.random.normal(kk, shape, jnp.bfloat16)
+    v = jax.random.normal(kv, shape, jnp.bfloat16)
+    w = jax.random.normal(kw, shape, jnp.float32)
+    ring = make_flash_ring_attention_fn(make_mesh(axis_names=(SEQ_AXIS,)))
+
+    def step(attn):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(
+                attn(q, k, v, causal=True).astype(jnp.float32) * w),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    (ring_val, ring_g), (flash_val, flash_g) = step(ring), step(flash_attention)
+    ring_err = max(_rel_err(a, b) for a, b in zip(ring_g, flash_g))
+    _check(ring_err <= BF16_TOL
+           and abs(float(ring_val) - float(flash_val))
+           <= BF16_TOL * max(1.0, abs(float(flash_val))),
+           f"ring vs single-device flash: grads {ring_err}, "
+           f"values {float(ring_val)} / {float(flash_val)}")
+    env.say(
+        "mesh",
+        f"{model.name} {sz.clients} clients over {n} devices: data and "
+        f"per-client losses on all {n}, bytes_in_use per device {in_use}, "
+        f"update vs one device {diff:.1e} of its largest entry, loss gap "
+        f"{loss_gap:.1e}, compute record n_chips={rec['n_chips']}; "
+        f"flash_ring_attention {list(shape)} over ('seq',)x{n} vs "
+        f"single-device flash: grads {ring_err:.1e} (tol {BF16_TOL})")
+
+
+# ----------------------------------------------------------------------
+def phase_cache(env: Env) -> None:
+    _check(os.path.isdir(env.cache_dir),
+           f"compile cache {env.cache_dir} was never created")
+    # JAX keeps an access-time file beside each entry
+    entries = [e.name for e in os.scandir(env.cache_dir)
+               if not e.name.endswith("-atime")]
+    _check(len(entries) > 0, f"compile cache {env.cache_dir} is empty")
+    origin = ("JAX_COMPILATION_CACHE_DIR" if env.cache_from_env
+              else "the checkout's default")
+    env.say("cache", f"{env.cache_dir} (from {origin}) holds "
+            f"{len(entries)} entries")
+
+
+# ----------------------------------------------------------------------
+# in running order
+PHASES = {"device": phase_device, "fedsim_resnet18": phase_fedsim_resnet18,
+          "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
+          "mesh": phase_mesh, "cache": phase_cache}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="run the same code path on the CPU at toy sizes "
+                         "with Pallas in interpret mode (no device claim)")
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help="comma-separated subset of: " + ", ".join(PHASES))
+    args = ap.parse_args(argv)
+    phases = args.phases.split(",")
+    unknown = [p for p in phases if p not in PHASES]
+    if unknown:
+        ap.error(f"unknown phase(s) {unknown}")
+
+    import jax
+
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    cache_dir, cache_from_env = enable_compile_cache()
+    devs = jax.devices()
+    platform = devs[0].platform
+    wanted = "cpu" if args.rehearse_cpu else "tpu"
+    if platform != wanted:
+        print(f"chip_smoke: needs platform {wanted!r}, JAX reports "
+              f"{platform!r} ({devs[0].device_kind}); nothing was run"
+              + ("" if args.rehearse_cpu else
+                 " — use --rehearse-cpu to debug the command off the chip"),
+              file=sys.stderr)
+        return 1
+    env = Env(sizes=REHEARSAL if args.rehearse_cpu else CHIP,
+              rehearsal=args.rehearse_cpu, platform=platform,
+              kind=devs[0].device_kind, count=len(devs),
+              cache_dir=cache_dir, cache_from_env=cache_from_env)
+
+    for name, phase in PHASES.items():
+        if name in phases:
+            phase(env)  # raises on failure: the run ends non-zero
+
+    result = {"ok": True,
+              "device": {"platform": platform, "kind": env.kind,
+                         "count": env.count}}
+    if env.rehearsal:
+        result["rehearsal"] = True
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -21,9 +21,10 @@ Worker flags:
                         deltas with error feedback instead of full
                         weights (ops/compression.py).
 Either role:
-  --cpu                 pin JAX to the host CPU — for smoke-testing the
-                        control plane without (or with a flaky)
-                        accelerator.
+  --cpu                 run this process on the host CPU. A chip belongs
+                        to one process at a time, so on a TPU host the
+                        manager (which only folds, on the host) takes
+                        --cpu and each worker owns one chip.
 
 Same shape as the reference: the manager hosts the "lineartest"
 experiment (a 10→1 linear regressor); each worker invents
@@ -92,12 +93,14 @@ def main() -> None:
         print(f"--compress is a worker flag\n{__doc__}")
         raise SystemExit(1)
 
-    if args.cpu:
-        # must precede the first backend touch; the environment may pin
-        # an accelerator platform via JAX_PLATFORMS, which jax.config
-        # outranks
-        import jax
+    import jax
 
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
+    if args.cpu:
+        # must precede the first backend touch; jax.config outranks a
+        # JAX_PLATFORMS the environment may carry
         jax.config.update("jax_platforms", "cpu")
 
     import numpy as np

@@ -69,6 +69,9 @@ def run(n_clients=4, n_rounds=4, n_epochs=2, batch_size=32,
 
 
 if __name__ == "__main__":
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scale", choices=["tiny", "full"], default="tiny")
     p.add_argument("--mesh", action="store_true",

@@ -86,6 +86,9 @@ def run(n_clients=16, n_total=1024, alpha=0.5, n_rounds=3, n_epochs=1,
 
 
 if __name__ == "__main__":
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scale", choices=["tiny", "full"], default="tiny")
     p.add_argument("--mesh", action="store_true")

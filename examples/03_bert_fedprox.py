@@ -94,6 +94,9 @@ def run(n_clients=8, n_per_client=24, n_rounds=3, n_epochs=2,
 
 
 if __name__ == "__main__":
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scale", choices=["tiny", "full"], default="tiny")
     p.add_argument("--mu", type=float, default=0.1)

@@ -130,6 +130,9 @@ def run(n_clients=4, n_per_client=16, n_rounds=2, n_epochs=1, batch_size=8,
 
 
 if __name__ == "__main__":
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scale", choices=["tiny", "full"], default="tiny")
     p.add_argument("--remat", action="store_true",

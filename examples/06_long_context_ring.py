@@ -80,6 +80,9 @@ def run(n_devices=8, seq_len=64, n_steps=3, batch_size=2, lr=1e-2,
 
 
 if __name__ == "__main__":
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scale", choices=["tiny", "full"], default="tiny")
     p.add_argument("--striped", action="store_true",

@@ -50,6 +50,9 @@ def run(n_clients=8, n_per_client=16, n_rounds=4, n_epochs=2, batch_size=8,
 
 
 if __name__ == "__main__":
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scale", choices=["tiny", "full"], default="tiny")
     args = p.parse_args()

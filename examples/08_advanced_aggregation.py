@@ -141,6 +141,9 @@ def run(n_clients=8, n_rounds=6, seed=0):
 
 
 if __name__ == "__main__":
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scale", choices=["tiny", "full"], default="tiny")
     args = p.parse_args()

@@ -147,6 +147,9 @@ def run(n_workers=4, n_rounds=10, cohort_fraction=1.0, seed=0,
 
 
 if __name__ == "__main__":
+    from baton_tpu.utils.profiling import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--scale", choices=["tiny", "full"], default="tiny")
     args = p.parse_args()

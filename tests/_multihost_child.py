@@ -26,7 +26,6 @@ jax.config.update("jax_platforms", "cpu")
 
 import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 
 from baton_tpu.ops.aggregation import psum_weighted_mean  # noqa: E402
 from baton_tpu.parallel.multihost import (  # noqa: E402
@@ -72,7 +71,7 @@ def main() -> None:
     g_w = garr(weights, P("clients"))
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=({"w": P("clients", None), "b": P("clients")}, P("clients")),
         out_specs={"w": P(), "b": P()},

@@ -65,11 +65,8 @@ def test_psum_weighted_mean_matches_oracle(stacked):
     def kernel(t, w):
         return agg.psum_weighted_mean(t, w, "clients")
 
-    # via the compat shim: jax.shard_map is top-level only on newer JAX
-    from baton_tpu.parallel.compat import shard_map
-
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             kernel,
             mesh=mesh,
             in_specs=(P("clients"), P("clients")),

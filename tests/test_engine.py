@@ -243,12 +243,13 @@ def test_evaluate_clients_fairness(linear_setup):
     assert f["worst"] >= f["mean"]
 
 
-def test_auto_wave_size_from_memory_plan(nprng):
+def test_auto_wave_size_from_memory_plan(nprng, monkeypatch):
     """wave_size="auto" productizes the OOM guard: the wave size comes
     from XLA's static memory plan vs the device budget, halving until
     it fits, with per-shape caching on the run_round path."""
     from baton_tpu.models.linear import linear_regression_model
     from baton_tpu.ops.padding import stack_client_datasets
+    from baton_tpu.utils import profiling
 
     model = linear_regression_model(6)
     datasets = [{
@@ -287,6 +288,13 @@ def test_auto_wave_size_from_memory_plan(nprng):
     with pytest.raises(NotImplementedError, match="wave_size"):
         sim_robust.auto_wave_size(params, data, n, budget_gb=64.0)
 
+    # the budget table holds accelerators only: on a device it does not
+    # know, "auto" asks for a budget instead of guessing one
+    with pytest.raises(ValueError, match="no HBM budget"):
+        sim.run_round(params, data, jnp.asarray(n), jax.random.key(1),
+                      wave_size="auto")
+    monkeypatch.setitem(profiling.HBM_BUDGET_GB, "cpu", 64.0)
+
     # end-to-end through run_round, decision cached per cohort shape
     res = sim.run_round(params, data, jnp.asarray(n), jax.random.key(1),
                         wave_size="auto")
@@ -297,12 +305,15 @@ def test_auto_wave_size_from_memory_plan(nprng):
     assert len(sim._auto_wave_cache) == 1  # same shapes -> cache hit
 
 
-def test_auto_wave_size_mesh_and_fused(nprng):
+def test_auto_wave_size_mesh_and_fused(nprng, monkeypatch):
     """"auto" composes with a clients mesh (the probe lowers the
     per-shard program) and with run_rounds_fused."""
     from baton_tpu.models.linear import linear_regression_model
     from baton_tpu.ops.padding import stack_client_datasets
     from baton_tpu.parallel.mesh import make_mesh
+    from baton_tpu.utils import profiling
+
+    monkeypatch.setitem(profiling.HBM_BUDGET_GB, "cpu", 64.0)
 
     model = linear_regression_model(6)
     datasets = [{

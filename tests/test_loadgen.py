@@ -286,59 +286,6 @@ def test_baseline_deltas_both_directions():
     assert not any(r["regression"] for r in check_baseline(baseline, within))
 
 
-def test_bench_gate_donation_and_wave1024_fields():
-    """The donation-HBM and wave1024 bench fields gate the same way the
-    fused number does: measured passes, null-with-reason skips visibly,
-    null-without-reason regresses (the silent-drop class)."""
-    from baton_tpu.loadgen.slo import check_bench_baseline
-
-    baseline = {"metrics": {
-        "bench:donation_hbm_delta_gb": {
-            "value": 0.0, "direction": "higher_is_better",
-            "tolerance_abs": 0.001},
-        "bench:wave1024_rounds_per_sec": {
-            "value": 0.0, "direction": "higher_is_better"},
-    }}
-    measured = {
-        "donation_hbm": {"donate_on": {"plan_gb": 10.0},
-                         "donate_off": {"plan_gb": 12.5},
-                         "delta_gb": 2.5},
-        "wave1024_recorded": {"rounds_per_sec": 0.41},
-    }
-    results, skips = check_bench_baseline(baseline, measured)
-    assert not any(r["regression"] for r in results)
-    assert not skips
-    by = {r["metric"]: r for r in results}
-    assert by["bench:donation_hbm_delta_gb"]["observed"] == 2.5
-    assert by["bench:wave1024_rounds_per_sec"]["observed"] == 0.41
-
-    excused = {
-        "donation_hbm": None,
-        "donation_hbm_reason": "budget: 5s left < 30s needed",
-        "wave1024_recorded": None,
-        "wave1024_reason": "recorded hardware attempts skipped: "
-                           "static HBM plan exceeds budget",
-    }
-    results, skips = check_bench_baseline(baseline, excused)
-    assert not any(r["regression"] for r in results)
-    assert set(skips) == {"bench:donation_hbm_delta_gb",
-                          "bench:wave1024_rounds_per_sec"}
-
-    silent = {"donation_enabled": True,
-              "donation_hbm": None, "wave1024_recorded": None}
-    results, skips = check_bench_baseline(baseline, silent)
-    assert sum(1 for r in results if r["regression"]) == 2
-    assert not skips
-
-    # a record from before bench.py grew these fields (no
-    # donation_enabled marker) skips with a pre-schema note rather than
-    # failing the gate on history the new code never measured
-    pre_schema = {"value": 0.3, "wave1024_recorded": None}
-    results, skips = check_bench_baseline(baseline, pre_schema)
-    assert not any(r["regression"] for r in results)
-    assert all("predates" in v for v in skips.values())
-
-
 def test_evaluate_slo_gates_on_baseline_regressions():
     slo = SLOSpec(assertions=(SLOAssertion("rounds.total", ">=", 1),))
     baseline = {"metrics": {

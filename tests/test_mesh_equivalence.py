@@ -40,7 +40,6 @@ from baton_tpu.models.lora import lora_trainable, lora_wrap
 from baton_tpu.models.mlp import mlp_classifier_model
 from baton_tpu.ops import aggregation as agg
 from baton_tpu.ops.padding import stack_client_datasets
-from baton_tpu.parallel.compat import shard_map
 from baton_tpu.parallel.engine import FedSim
 from baton_tpu.parallel.mesh import CLIENT_AXIS, make_mesh
 from baton_tpu.parallel.partition import (
@@ -132,7 +131,7 @@ def test_engine_fold_equivalence_on_trained_contributions(nprng):
         return jax.tree_util.tree_map(lambda s: s / wt, ps)
 
     cli = client_spec()
-    mesh_mean = jax.jit(shard_map(
+    mesh_mean = jax.jit(jax.shard_map(
         fold, mesh=mesh, in_specs=(cli, cli),
         out_specs=replicated_spec(), check_vma=False,
     ))(client_params, w)

@@ -311,54 +311,3 @@ def test_vit_remat_matches_no_remat():
         "y": jnp.asarray(rng.integers(0, cfg.n_classes, (4,)), jnp.int32),
     }
     _grad_allclose(vit_model(cfg), vit_model(cfg, remat=True), params, batch)
-
-
-def test_configure_attention_dispatch_from_sweep(tmp_path):
-    """The dispatcher adopts a measured crossover: smallest L whose best
-    flash block config beats dense, with that config's blocks — from
-    TPU-platform artifacts only."""
-    import json
-
-    from baton_tpu.models import transformer as T
-
-    orig = (T._FLASH_MIN_LEN, T._FLASH_BLOCKS)
-    try:
-        sweep = {
-            "platform": "tpu",
-            "results": [
-                # malformed row (null timing) must be skipped, not
-                # abort the whole artifact
-                {"L": 512, "dense_ms": 1.0, "flash": {"128x128": None}},
-                {"L": 1024, "dense_ms": 1.0, "flash": {"128x128": 1.5}},
-                {"L": 2048, "dense_ms": 4.0,
-                 "flash": {"256x512": 3.1, "512x512": 2.9}},
-                {"L": 4096, "dense_ms": 20.0, "flash": {"512x1024": 5.0}},
-            ],
-        }
-        p = tmp_path / "sweep.json"
-        p.write_text(json.dumps(sweep))
-        assert T.configure_attention_dispatch(sweep_path=str(p)) == (
-            2048, (512, 512))
-
-        # a CPU artifact must not steer the TPU dispatch
-        T._FLASH_MIN_LEN, T._FLASH_BLOCKS = orig
-        sweep["platform"] = "cpu"
-        p.write_text(json.dumps(sweep))
-        assert T.configure_attention_dispatch(sweep_path=str(p)) == orig
-
-        # no crossover anywhere -> no change
-        sweep["platform"] = "tpu"
-        for r in sweep["results"]:
-            r["flash"] = {"128x128": r["dense_ms"] * 2}
-        p.write_text(json.dumps(sweep))
-        assert T.configure_attention_dispatch(sweep_path=str(p)) == orig
-
-        # missing artifact -> no change, no raise
-        assert T.configure_attention_dispatch(
-            sweep_path=str(tmp_path / "absent.json")) == orig
-
-        # explicit overrides win
-        assert T.configure_attention_dispatch(
-            min_len=8192, blocks=(1024, 1024)) == (8192, (1024, 1024))
-    finally:
-        T._FLASH_MIN_LEN, T._FLASH_BLOCKS = orig
