@@ -1,0 +1,108 @@
+"""Every cell's control flow on the CPU at its ``tiny`` sizes: one JSON
+line with the contract's keys, counts only, no time under a device
+metric's name; and no result at all without a TPU."""
+
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from fedbench import manifest, run  # noqa: E402
+
+BENCH = manifest.load_manifest(ROOT)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+COUNTS = {"count"}  # units of metrics a CPU run may report
+
+
+def _last_line(capsys):
+    lines = capsys.readouterr().out.strip().splitlines()
+    return lines, json.loads(lines[-1])
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal_ends_in_the_contracts_line(cell, capsys):
+    rc = run.main(["--workload", cell, "--seed", "3", "--seconds", "1",
+                   "--trace", "0", "--rehearse-cpu"])
+    lines, result = _last_line(capsys)
+    assert rc == 0
+    assert set(result) == {"correct", "attempted", "failed", "metrics",
+                           "device"}
+    assert result["correct"] is True
+    assert result["attempted"] > 0 and result["failed"] == 0
+    assert result["device"]["platform"] == "cpu"
+    assert set(result["device"]) == {"platform", "kind", "count",
+                                     "memory_peak_bytes"}
+    assert set(result["metrics"]) == {"samples_per_s_per_chip", "round_s",
+                                      "setup_s"}
+    for name, m in result["metrics"].items():
+        assert m["value"] is None, f"a CPU run wrote {name}"
+    # the probe's disagreement and the loss trajectory are on earlier lines
+    assert any("probe: update disagreement" in l for l in lines)
+    assert any("loss by round" in l for l in lines)
+    # only the last line is JSON
+    assert all(not l.startswith("{") for l in lines[:-1])
+
+
+@pytest.mark.parametrize("cell", ["resnet18_c128_w32", "resnet18_c128_mesh4"])
+def test_traced_rehearsal_reports_counts_only(cell, capsys):
+    rc = run.main(["--workload", cell, "--seed", "4", "--seconds", "1",
+                   "--trace", "1", "--rehearse-cpu"])
+    _, result = _last_line(capsys)
+    assert rc == 0 and result["correct"] is True
+    workload = manifest.load_workload(ROOT, cell)
+    assert result["attempted"] == workload["tiny"]["trace_rounds"]
+    units = {m["name"]: m["unit"] for m in BENCH["per_layer"]}
+    wanted = {m["name"] for m in
+              manifest.metrics_for(BENCH["per_layer"], cell)}
+    assert set(result["metrics"]) == wanted
+    for name, m in result["metrics"].items():
+        if units[name] in COUNTS:
+            assert m["value"] == 0  # compiles_in_window
+        else:
+            assert m["value"] is None, f"a CPU run wrote {name}"
+    # a CPU trace has no device plane: no busy time, no breakdown
+    assert "busy_s" not in result["device"] and "breakdown" not in result
+
+
+def test_without_a_tpu_there_is_no_result(capsys):
+    rc = run.main(["--workload", "resnet18_c32_w1", "--seed", "1",
+                   "--seconds", "1", "--trace", "0"])
+    captured = capsys.readouterr()
+    assert rc != 0
+    assert captured.out.strip() == ""
+    assert "No result" in captured.err
+
+
+def test_window_stamps_follow_the_dispatch_rule():
+    """Round i-2 is settled and stamped before round i is dispatched,
+    the last two rounds are settled without a stamp, and a non-finite
+    loss is a failed round."""
+    import jax.numpy as jnp
+
+    order = []
+
+    class Result:
+        def __init__(self, i):
+            self.params = i
+            self.loss_history = [jnp.asarray(float("nan") if i == 3 else 1.0)]
+
+    class Sim:
+        def run_round(self, params, data, n, key, **kw):
+            order.append(("dispatch", len([o for o in order
+                                           if o[0] == "dispatch"])))
+            return Result(order[-1][1])
+
+    job = {"local_epochs": 1, "wave_size": None}
+    import jax
+
+    _, stamps, losses, attempted, failed = run.run_rounds(
+        Sim(), 0, None, None, jax.random.key(0), job, 0, lambda n: n < 5)
+    assert attempted == 5 and len(losses) == 5 and len(stamps) == 3
+    assert failed == 1
+    assert stamps == sorted(stamps)
