@@ -167,9 +167,11 @@ class LocalTrainer:
             p, os, step_rng = carry
             step_rng, sub = jax.random.split(step_rng)
             if self.dp is not None:
-                grads, ex_losses = dp_sgd_grads(
-                    masked_loss_sum, p, batch, sub, self.dp, self.batch_size
-                )
+                with jax.named_scope("grad"):
+                    grads, ex_losses = dp_sgd_grads(
+                        masked_loss_sum, p, batch, sub, self.dp,
+                        self.batch_size
+                    )
                 if self.regularizer is not None:
                     # the prox term is data-independent: its gradient is
                     # exact (un-noised) and consumes no privacy budget
@@ -184,35 +186,41 @@ class LocalTrainer:
                 loss_sum = jnp.sum(ex_losses)
                 count = jnp.sum(batch["mask"].astype(jnp.float32))
             else:
-                (_, (loss_sum, count)), grads = grad_fn(p, batch, sub)
+                with jax.named_scope("grad"):
+                    (_, (loss_sum, count)), grads = grad_fn(p, batch, sub)
             # An all-padding batch yields exactly-zero grads; gate the
             # update so stateful optimizers (momentum/adam) don't mutate
             # state on phantom steps.
             nonempty = count > 0
-            updates, new_os = self.optimizer.update(grads, os, p)
-            new_p = optax.apply_updates(p, updates)
-            p = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(nonempty, new, old), new_p, p
-            )
-            os = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(nonempty, new, old), new_os, os
-            )
+            with jax.named_scope("optimizer"):
+                updates, new_os = self.optimizer.update(grads, os, p)
+                new_p = optax.apply_updates(p, updates)
+                p = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(nonempty, new, old), new_p, p
+                )
+                os = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(nonempty, new, old), new_os, os
+                )
             return (p, os, step_rng), (loss_sum, count)
 
         def epoch_step(carry, xs):
             epoch_rng, epoch_idx = xs
             p, os = carry
             perm_rng, step_rng = jax.random.split(epoch_rng)
-            perm = jax.random.permutation(perm_rng, capacity)
-            mask = (perm < n_samples).astype(jnp.float32)
-            shuffled = jax.tree_util.tree_map(lambda a: jnp.take(a, perm, axis=0), data)
-            shuffled = dict(shuffled)
-            if "mask" in shuffled:
-                mask = mask * shuffled["mask"].astype(jnp.float32)
-            shuffled["mask"] = mask
-            batched = jax.tree_util.tree_map(
-                lambda a: a.reshape((nb, self.batch_size) + a.shape[1:]), shuffled
-            )
+            # a full copy of the client's data every epoch
+            with jax.named_scope("shuffle"):
+                perm = jax.random.permutation(perm_rng, capacity)
+                mask = (perm < n_samples).astype(jnp.float32)
+                shuffled = jax.tree_util.tree_map(
+                    lambda a: jnp.take(a, perm, axis=0), data)
+                shuffled = dict(shuffled)
+                if "mask" in shuffled:
+                    mask = mask * shuffled["mask"].astype(jnp.float32)
+                shuffled["mask"] = mask
+                batched = jax.tree_util.tree_map(
+                    lambda a: a.reshape((nb, self.batch_size) + a.shape[1:]),
+                    shuffled
+                )
             (p, os, _), (loss_sums, counts) = jax.lax.scan(
                 batch_step, (p, os, step_rng), batched
             )

@@ -103,8 +103,9 @@ def bert_classifier_model(
     def apply(params, batch, rng):
         ids = batch["x"]
         b, l = ids.shape
-        x = params["tok_emb"][ids] + params["pos_emb"][:l]
-        x = x.astype(compute_dtype)
+        with jax.named_scope("embed"):
+            x = params["tok_emb"][ids] + params["pos_emb"][:l]
+            x = x.astype(compute_dtype)
         attn_mask = batch.get("attn_mask")
         bias = None if attn_mask is None else padding_bias(attn_mask)
 
@@ -113,12 +114,15 @@ def bert_classifier_model(
                                        attention_fn=attention_fn)
 
         block_fn = jax.checkpoint(_block) if remat else _block
-        for blk in params["blocks"]:
-            x = block_fn(blk, x, bias)
-        x = layer_norm(x, params["ln_f"])
-        cls = x[:, 0, :].astype(jnp.float32)
-        pooled = jnp.tanh(cls @ params["pooler"]["w"] + params["pooler"]["b"])
-        return pooled @ params["head"]["w"] + params["head"]["b"]
+        for i, blk in enumerate(params["blocks"]):
+            with jax.named_scope(f"block{i}"):
+                x = block_fn(blk, x, bias)
+        with jax.named_scope("head"):
+            x = layer_norm(x, params["ln_f"])
+            cls = x[:, 0, :].astype(jnp.float32)
+            pooled = jnp.tanh(
+                cls @ params["pooler"]["w"] + params["pooler"]["b"])
+            return pooled @ params["head"]["w"] + params["head"]["b"]
 
     def per_example_loss(params, batch, rng):
         return softmax_cross_entropy(apply(params, batch, rng), batch, rng)

@@ -137,10 +137,12 @@ _CONV_IMPLS = {"direct": _conv_direct, "im2col": _conv_im2col,
                "shift": _conv_shift}
 
 
+@jax.named_scope("conv")
 def _conv(x, w, stride=1, impl="direct"):
     return _CONV_IMPLS[impl](x, w, stride)
 
 
+@jax.named_scope("norm")
 def _group_norm(x, p, n_groups=32, eps=1e-5):
     """GroupNorm over NHWC; stats in fp32 regardless of compute dtype."""
     b, h, w, c = x.shape
@@ -172,10 +174,12 @@ def _block_apply(x, p, stride, n_groups, impl="direct"):
     out = jax.nn.relu(_group_norm(out, p["gn1"], n_groups))
     out = _conv(out, p["conv2"], 1, impl)
     out = _group_norm(out, p["gn2"], n_groups)
-    if "proj" in p:
-        x = _group_norm(_conv(x, p["proj"], stride, impl), p["gn_proj"],
-                        n_groups)
-    return jax.nn.relu(out + x)
+    with jax.named_scope("shortcut"):
+        if "proj" in p:
+            x = _group_norm(_conv(x, p["proj"], stride, impl), p["gn_proj"],
+                            n_groups)
+        out = out + x
+    return jax.nn.relu(out)
 
 
 def resnet_model(
@@ -228,18 +232,24 @@ def resnet_model(
     def apply(params, batch, rng):
         x = batch["x"].astype(compute_dtype)
         stem_stride = 2 if imagenet_stem else 1
-        x = _conv(x, params["stem"], stem_stride, conv_impl)
-        x = jax.nn.relu(_group_norm(x, params["gn_stem"], n_groups))
-        if imagenet_stem:
-            x = jax.lax.reduce_window(
-                x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1), "SAME"
-            )
+        # scopes: stem, one a block under its parameter key, head
+        with jax.named_scope("stem"):
+            x = _conv(x, params["stem"], stem_stride, conv_impl)
+            x = jax.nn.relu(_group_norm(x, params["gn_stem"], n_groups))
+            if imagenet_stem:
+                x = jax.lax.reduce_window(
+                    x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
+                    "SAME"
+                )
         for s, n_blocks in enumerate(blocks_per_stage):
             for b in range(n_blocks):
-                x = _block_apply(x, params[f"s{s}b{b}"], stride_of(s, b),
-                                 n_groups, conv_impl)
-        x = jnp.mean(x, axis=(1, 2))
-        logits = x.astype(jnp.float32) @ params["fc"]["w"] + params["fc"]["b"]
+                with jax.named_scope(f"s{s}b{b}"):
+                    x = _block_apply(x, params[f"s{s}b{b}"], stride_of(s, b),
+                                     n_groups, conv_impl)
+        with jax.named_scope("head"):
+            x = jnp.mean(x, axis=(1, 2))
+            logits = (x.astype(jnp.float32) @ params["fc"]["w"]
+                      + params["fc"]["b"])
         return logits
 
     def per_example_loss(params, batch, rng):

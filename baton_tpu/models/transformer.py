@@ -60,6 +60,7 @@ def rms_init(d):
 # norms (fp32 stats regardless of compute dtype)
 
 
+@jax.named_scope("norm")
 def layer_norm(x, p, eps=1e-6):
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
@@ -68,6 +69,7 @@ def layer_norm(x, p, eps=1e-6):
     return (xf * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def rms_norm(x, p, eps=1e-6):
     xf = x.astype(jnp.float32)
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -190,6 +192,7 @@ def mha_init(key, d_model, n_heads, n_kv_heads=None, head_dim=None, out_std=None
     }
 
 
+@jax.named_scope("attention")
 def mha_apply(
     p,
     x,
@@ -232,6 +235,7 @@ def gelu_mlp_init(key, d_model, d_ff):
     }
 
 
+@jax.named_scope("mlp")
 def gelu_mlp_apply(p, x):
     h = x @ p["w1"].astype(x.dtype) + p["b1"].astype(x.dtype)
     h = jax.nn.gelu(h)
@@ -247,6 +251,7 @@ def swiglu_init(key, d_model, d_ff):
     }
 
 
+@jax.named_scope("mlp")
 def swiglu_apply(p, x):
     g = jax.nn.silu(x @ p["w_gate"].astype(x.dtype))
     u = x @ p["w_up"].astype(x.dtype)

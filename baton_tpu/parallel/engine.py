@@ -53,6 +53,7 @@ from baton_tpu.parallel.partition import (
     waved_client_spec,
 )
 from baton_tpu.parallel.tensor_parallel import MODEL_AXIS, shard_params_tp
+from baton_tpu.utils.profiling import annotate
 
 Params = Any
 
@@ -246,11 +247,16 @@ class FedSim:
             )
             return p, losses
 
-        client_params, client_losses = jax.vmap(one_client)(data, n_samples, rngs)
-        w = n_samples.astype(jnp.float32)
-        psum = agg.weighted_tree_sum(client_params, w)
-        lsum = jnp.tensordot(w, client_losses.astype(jnp.float32), axes=(0, 0))
-        return psum, lsum, jnp.sum(w), client_losses
+        with jax.named_scope("local_train"):
+            client_params, client_losses = jax.vmap(one_client)(
+                data, n_samples, rngs)
+        with jax.named_scope("wave_sums"):
+            w = n_samples.astype(jnp.float32)
+            psum = agg.weighted_tree_sum(client_params, w)
+            lsum = jnp.tensordot(w, client_losses.astype(jnp.float32),
+                                 axes=(0, 0))
+            wtot = jnp.sum(w)
+        return psum, lsum, wtot, client_losses
 
     # HBM note on donation: wave INPUTS are deliberately not donated.
     # `params` is reused by every wave of the round (and as the FedProx
@@ -333,9 +339,10 @@ class FedSim:
                         params, frozen, data, n_samples, rngs, n_epochs
                     )
                 )
-                psum = jax.lax.psum(local_psum, CLIENT_AXIS)
-                lsum = jax.lax.psum(local_lsum, CLIENT_AXIS)
-                wtot = jax.lax.psum(local_w, CLIENT_AXIS)
+                with jax.named_scope("wave_psum"):
+                    psum = jax.lax.psum(local_psum, CLIENT_AXIS)
+                    lsum = jax.lax.psum(local_lsum, CLIENT_AXIS)
+                    wtot = jax.lax.psum(local_w, CLIENT_AXIS)
                 return psum, lsum, wtot, client_losses
 
             in_specs, out_specs = kernel_specs("engine.wave_sums")
@@ -354,6 +361,26 @@ class FedSim:
             )
         sharded, jitted = cache[n_epochs]
         return sharded if raw else jitted
+
+    def _wave_program(self, n_epochs: int, robust: bool):
+        """``(program, bind)``: the jitted wave program of this layout
+        and aggregator, and ``bind(params, frozen, data, n_samples,
+        rngs)`` giving its arguments, so that ``program(*bind(...))``
+        runs a wave and ``program.lower(*bind(...))`` lowers the same
+        program. The one place that chooses it, for :meth:`run_round`
+        and :meth:`lower_wave`."""
+        if self.mesh is not None and not self.is_hybrid:
+            program = (self._make_wave_params_sharded(n_epochs) if robust
+                       else self._make_wave_sums_sharded(n_epochs))
+            return program, lambda *wave: wave
+        program = (type(self)._wave_params_vmap if robust
+                   else type(self)._wave_sums_vmap)
+        return program, lambda *wave: (self, *wave, n_epochs)
+
+    def _resolve_wave_size(self, wave_size: Optional[int], c: int) -> int:
+        """Whole cohort when ``None``; a multiple of the wave unit."""
+        n_dev = self._clients_per_wave_unit()
+        return round_up(c if wave_size is None else wave_size, n_dev)
 
     # ------------------------------------------------------------------
     def _pad_wave(self, data, n_samples, rngs, target: int):
@@ -381,6 +408,23 @@ class FedSim:
             [rngs, jnp.repeat(rngs[:1], pad, axis=0)], axis=0
         )
         return data, n_samples, rngs
+
+    def _stage_wave(self, data, n_samples, rngs, start: int, stop: int,
+                    wave_size: int, in_shard):
+        """Clients ``[start, stop)`` as one wave's inputs: sliced, padded
+        to ``wave_size`` with phantom clients and, on a mesh, placed on
+        ``in_shard``."""
+        d = jax.tree_util.tree_map(lambda a: a[start:stop], data)
+        n = n_samples[start:stop]
+        r = rngs[start:stop]
+        d, n, r = self._pad_wave(d, n, r, wave_size)
+        if in_shard is not None:
+            d = jax.tree_util.tree_map(
+                lambda a: jax.device_put(a, in_shard), d
+            )
+            n = jax.device_put(n, in_shard)
+            r = jax.device_put(r, in_shard)
+        return d, n, r
 
     # ------------------------------------------------------------------
     def auto_wave_size(self, params, data, n_samples, key=None,
@@ -481,182 +525,199 @@ class FedSim:
         (:meth:`auto_wave_size`); the decision is cached per cohort
         shape, so repeated rounds pay the plan compiles once.
         """
-        orig_params = params
+        with annotate("baton.round") as round_span:
+            with annotate("baton.round.prepare"):
+                orig_params = params
+                params, frozen = self._split(params)
+                n_samples = jnp.asarray(n_samples)
+                if client_indices is not None:
+                    idx = jnp.asarray(client_indices)
+                    data = jax.tree_util.tree_map(
+                        lambda a: jnp.take(a, idx, axis=0), data)
+                    n_samples = jnp.take(n_samples, idx, axis=0)
+                c = int(n_samples.shape[0])
+                rngs = jax.random.split(rng, c)
+
+                if wave_size == "auto":
+                    cache_key = (
+                        c, n_epochs,
+                        tuple(sorted((k, v.shape, str(v.dtype))
+                                     for k, v in data.items())),
+                    )
+                    cache = getattr(self, "_auto_wave_cache", None)
+                    if cache is None:
+                        cache = self._auto_wave_cache = {}
+                    if cache_key not in cache:
+                        cache[cache_key] = self.auto_wave_size(
+                            orig_params, data, n_samples, n_epochs=n_epochs)
+                    wave_size = cache[cache_key]
+                wave_size = self._resolve_wave_size(wave_size, c)
+
+                robust = self.aggregator[0] != "mean"
+                if robust and self.is_hybrid:
+                    raise NotImplementedError(
+                        "robust aggregators need per-client params stacked "
+                        "along the client axis; the hybrid clients x model "
+                        "mesh shards params over 'model' — run robust rounds "
+                        "on a pure clients mesh"
+                    )
+                if self.is_hybrid:
+                    # hybrid clients×model mesh: plain jit + GSPMD (see
+                    # _place_hybrid) — shard_map would force manual TP
+                    # collectives
+                    params, frozen = self._place_hybrid(params, frozen)
+                program, bind = self._wave_program(n_epochs, robust)
+                in_shard = (client_sharding(self.mesh)
+                            if self.mesh is not None else None)
+                n_waves = -(-c // wave_size)
+                round_span.set_metadata(clients=c, waves=n_waves,
+                                        wave_size=int(wave_size))
+
+            psum_acc = None
+            lsum_acc = None
+            w_acc = None
+            stacked_parts = [] if robust else None
+            per_client = [] if collect_client_losses else None
+            t_waves0 = time.perf_counter()
+            for wave, start in enumerate(range(0, c, wave_size)):
+                stop = min(start + wave_size, c)
+                real = stop - start
+                with annotate("baton.round.stage", wave=wave, real=real,
+                              padded=wave_size - real):
+                    d, n, r = self._stage_wave(
+                        data, n_samples, rngs, start, stop, wave_size,
+                        in_shard)
+                with annotate("baton.round.dispatch", wave=wave):
+                    if robust:
+                        cp, closs = program(*bind(params, frozen, d, n, r))
+                        stacked_parts.append(
+                            jax.tree_util.tree_map(lambda a: a[:real], cp)
+                        )
+                        w_wave = n[:real].astype(jnp.float32)
+                        lsum = jnp.tensordot(w_wave,
+                                             closs[:real].astype(jnp.float32),
+                                             axes=(0, 0))
+                        wtot = jnp.sum(w_wave)
+                    else:
+                        psum, lsum, wtot, closs = program(
+                            *bind(params, frozen, d, n, r))
+                        psum_acc = (
+                            psum if psum_acc is None
+                            else _acc_tree_add(psum_acc, psum)
+                        )
+                    lsum_acc = lsum if lsum_acc is None else lsum_acc + lsum
+                    w_acc = wtot if w_acc is None else w_acc + wtot
+                    if per_client is not None:
+                        per_client.append(closs[:real])
+                    if progress_fn is not None:
+                        jax.block_until_ready(lsum)
+                        progress_fn(wave + 1, n_waves)
+
+            # --- compute record (obs/compute.py) --------------------------
+            # One scalar sync on the loss sum closes the timed window over
+            # the wave loop (compile included on a cache miss — the
+            # tracker's shape signature says whether this shape compiled).
+            # A model with no FLOPs accounting is a reason string inside
+            # the record; a JAX error raised by the sync is the round's
+            # error.
+            with annotate("baton.round.sync"):
+                jax.block_until_ready(lsum_acc)
+            train_s = time.perf_counter() - t_waves0
+            with annotate("baton.round.record"):
+                capacity = next(
+                    (int(a.shape[1]) for a in data.values()
+                     if getattr(a, "ndim", 0) >= 2), 1)
+                bsz = max(1, int(self.trainer.batch_size))
+                sig = (c, int(wave_size), int(n_epochs), robust,
+                       tuple(sorted((k, tuple(v.shape), str(v.dtype))
+                                    for k, v in data.items())))
+                self.last_compute = self.compute_probe.record_round(
+                    key="run_round",
+                    signature=sig,
+                    train_s=train_s,
+                    n_samples=float(np.asarray(n_samples).sum()),
+                    n_epochs=n_epochs,
+                    steps=c * n_epochs * -(-capacity // bsz),
+                    n_chips=(int(self.mesh.devices.size)
+                             if self.mesh is not None else 1),
+                )
+
+            with annotate("baton.round.fold"):
+                denom = jnp.maximum(w_acc, 1e-9)
+                if robust:
+                    stacked = jax.tree_util.tree_map(
+                        lambda *xs: jnp.concatenate(xs, axis=0),
+                        *stacked_parts
+                    )
+                    aggregate = agg.aggregate_stacked(
+                        self.aggregator, stacked, n_samples, params
+                    )
+                else:
+                    aggregate = jax.tree_util.tree_map(
+                        lambda s, ref: (s / denom).astype(ref.dtype),
+                        psum_acc, params
+                    )
+                if self.is_hybrid:
+                    # GSPMD is free to leave the trainable aggregate
+                    # model-sharded (it flows out of matmuls against the
+                    # TP base), but the global state is logically
+                    # replicated — pin it back to the partition layer's
+                    # replicated rule so round outputs carry the same
+                    # layout contract as inputs
+                    aggregate = jax.device_put(
+                        aggregate, replicated_sharding(self.mesh)
+                    )
+                loss_history = lsum_acc / denom
+
+            with annotate("baton.round.update"):
+                if self.server_optimizer is not None:
+                    if server_opt_state is None:
+                        server_opt_state = self.server_optimizer.init(params)
+                    new_params, server_opt_state = _server_update(
+                        self.server_optimizer, params, aggregate,
+                        server_opt_state
+                    )
+                else:
+                    new_params = aggregate
+
+                if self.partition is not None:
+                    new_params = self.partition.merge(new_params, frozen)
+
+                return RoundResult(
+                    params=new_params,
+                    loss_history=loss_history,
+                    client_losses=jnp.concatenate(per_client, axis=0)
+                    if per_client
+                    else None,
+                    n_samples_total=w_acc,
+                    server_opt_state=server_opt_state,
+                )
+
+    def lower_wave(self, params, data, n_samples, rng, n_epochs: int = 1,
+                   wave_size: Optional[int] = None):
+        """Lower, without running it, the program :meth:`run_round`
+        dispatches for the first wave of this round (``.compile()
+        .as_text()`` then holds the instruction names a device trace of
+        the round shows, with their ``op_name`` scopes). The program and
+        the staging of its inputs are ``run_round``'s own
+        (``_wave_program``, ``_stage_wave``). Plain ``vmap`` and
+        clients-mesh weighted-sums paths only."""
+        robust = self.aggregator[0] != "mean"
+        if robust or self.is_hybrid or wave_size == "auto":
+            raise NotImplementedError(
+                "lower_wave lowers the weighted-sums wave program of the "
+                "single-device and clients-mesh layouts at a given wave "
+                "size")
         params, frozen = self._split(params)
         n_samples = jnp.asarray(n_samples)
-        if client_indices is not None:
-            idx = jnp.asarray(client_indices)
-            data = jax.tree_util.tree_map(lambda a: jnp.take(a, idx, axis=0), data)
-            n_samples = jnp.take(n_samples, idx, axis=0)
         c = int(n_samples.shape[0])
         rngs = jax.random.split(rng, c)
-
-        n_dev = self._clients_per_wave_unit()
-        if wave_size == "auto":
-            cache_key = (
-                c, n_epochs,
-                tuple(sorted((k, v.shape, str(v.dtype))
-                             for k, v in data.items())),
-            )
-            cache = getattr(self, "_auto_wave_cache", None)
-            if cache is None:
-                cache = self._auto_wave_cache = {}
-            if cache_key not in cache:
-                cache[cache_key] = self.auto_wave_size(
-                    orig_params, data, n_samples, n_epochs=n_epochs)
-            wave_size = cache[cache_key]
-        if wave_size is None:
-            wave_size = round_up(c, n_dev)
-        else:
-            wave_size = round_up(wave_size, n_dev)
-
-        robust = self.aggregator[0] != "mean"
-        if robust and self.is_hybrid:
-            raise NotImplementedError(
-                "robust aggregators need per-client params stacked along "
-                "the client axis; the hybrid clients x model mesh shards "
-                "params over 'model' — run robust rounds on a pure "
-                "clients mesh"
-            )
-        if self.is_hybrid:
-            # hybrid clients×model mesh: plain jit + GSPMD (see
-            # _place_hybrid) — shard_map would force manual TP collectives
-            params, frozen = self._place_hybrid(params, frozen)
-            call = lambda d, n, r: self._wave_sums_vmap(
-                params, frozen, d, n, r, n_epochs
-            )
-            in_shard = client_sharding(self.mesh)
-        elif self.mesh is not None:
-            if robust:
-                wave_p = self._make_wave_params_sharded(n_epochs)
-                call_p = lambda d, n, r: wave_p(params, frozen, d, n, r)
-            else:
-                wave_fn = self._make_wave_sums_sharded(n_epochs)
-                call = lambda d, n, r: wave_fn(params, frozen, d, n, r)
-            in_shard = client_sharding(self.mesh)
-        else:
-            if robust:
-                call_p = lambda d, n, r: self._wave_params_vmap(
-                    params, frozen, d, n, r, n_epochs
-                )
-            else:
-                call = lambda d, n, r: self._wave_sums_vmap(
-                    params, frozen, d, n, r, n_epochs
-                )
-            in_shard = None
-
-        psum_acc = None
-        lsum_acc = None
-        w_acc = None
-        stacked_parts = [] if robust else None
-        per_client = [] if collect_client_losses else None
-        t_waves0 = time.perf_counter()
-        for start in range(0, c, wave_size):
-            stop = min(start + wave_size, c)
-            d = jax.tree_util.tree_map(lambda a: a[start:stop], data)
-            n = n_samples[start:stop]
-            r = rngs[start:stop]
-            d, n, r = self._pad_wave(d, n, r, wave_size)
-            if in_shard is not None:
-                d = jax.tree_util.tree_map(
-                    lambda a: jax.device_put(a, in_shard), d
-                )
-                n = jax.device_put(n, in_shard)
-                r = jax.device_put(r, in_shard)
-            if robust:
-                cp, closs = call_p(d, n, r)
-                real = stop - start
-                stacked_parts.append(
-                    jax.tree_util.tree_map(lambda a: a[:real], cp)
-                )
-                w_wave = n[:real].astype(jnp.float32)
-                lsum = jnp.tensordot(w_wave,
-                                     closs[:real].astype(jnp.float32),
-                                     axes=(0, 0))
-                wtot = jnp.sum(w_wave)
-            else:
-                psum, lsum, wtot, closs = call(d, n, r)
-                psum_acc = (
-                    psum if psum_acc is None else _acc_tree_add(psum_acc, psum)
-                )
-            lsum_acc = lsum if lsum_acc is None else lsum_acc + lsum
-            w_acc = wtot if w_acc is None else w_acc + wtot
-            if per_client is not None:
-                per_client.append(closs[: stop - start])
-            if progress_fn is not None:
-                jax.block_until_ready(lsum)
-                progress_fn(start // wave_size + 1, -(-c // wave_size))
-
-        # --- compute record (obs/compute.py) ------------------------------
-        # One scalar sync on the loss sum closes the timed window over
-        # the wave loop (compile included on a cache miss — the tracker's
-        # shape signature says whether this shape compiled). A model
-        # with no FLOPs accounting is a reason string inside the record;
-        # a JAX error raised by the sync is the round's error.
-        jax.block_until_ready(lsum_acc)
-        train_s = time.perf_counter() - t_waves0
-        capacity = next(
-            (int(a.shape[1]) for a in data.values()
-             if getattr(a, "ndim", 0) >= 2), 1)
-        bsz = max(1, int(self.trainer.batch_size))
-        sig = (c, int(wave_size), int(n_epochs), robust,
-               tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                            for k, v in data.items())))
-        self.last_compute = self.compute_probe.record_round(
-            key="run_round",
-            signature=sig,
-            train_s=train_s,
-            n_samples=float(np.asarray(n_samples).sum()),
-            n_epochs=n_epochs,
-            steps=c * n_epochs * -(-capacity // bsz),
-            n_chips=(int(self.mesh.devices.size)
-                     if self.mesh is not None else 1),
-        )
-
-        denom = jnp.maximum(w_acc, 1e-9)
-        if robust:
-            stacked = jax.tree_util.tree_map(
-                lambda *xs: jnp.concatenate(xs, axis=0), *stacked_parts
-            )
-            aggregate = agg.aggregate_stacked(
-                self.aggregator, stacked, n_samples, params
-            )
-        else:
-            aggregate = jax.tree_util.tree_map(
-                lambda s, ref: (s / denom).astype(ref.dtype), psum_acc, params
-            )
-        if self.is_hybrid:
-            # GSPMD is free to leave the trainable aggregate
-            # model-sharded (it flows out of matmuls against the TP
-            # base), but the global state is logically replicated —
-            # pin it back to the partition layer's replicated rule so
-            # round outputs carry the same layout contract as inputs
-            aggregate = jax.device_put(
-                aggregate, replicated_sharding(self.mesh)
-            )
-        loss_history = lsum_acc / denom
-
-        if self.server_optimizer is not None:
-            if server_opt_state is None:
-                server_opt_state = self.server_optimizer.init(params)
-            new_params, server_opt_state = _server_update(
-                self.server_optimizer, params, aggregate, server_opt_state
-            )
-        else:
-            new_params = aggregate
-
-        if self.partition is not None:
-            new_params = self.partition.merge(new_params, frozen)
-
-        return RoundResult(
-            params=new_params,
-            loss_history=loss_history,
-            client_losses=jnp.concatenate(per_client, axis=0)
-            if per_client
-            else None,
-            n_samples_total=w_acc,
-            server_opt_state=server_opt_state,
-        )
+        wave_size = self._resolve_wave_size(wave_size, c)
+        program, bind = self._wave_program(n_epochs, robust)
+        in_shard = client_sharding(self.mesh) if self.mesh is not None else None
+        d, n, r = self._stage_wave(data, n_samples, rngs, 0,
+                                   min(wave_size, c), wave_size, in_shard)
+        return program.lower(*bind(params, frozen, d, n, r))
 
     # ------------------------------------------------------------------
     # federated evaluation: sample-weighted mean loss/accuracy over the
@@ -700,16 +761,8 @@ class FedSim:
         totals: Dict[str, float] = {}
         for start in range(0, c, wave):
             stop = min(start + wave, c)
-            d = jax.tree_util.tree_map(lambda a: a[start:stop], data)
-            n = n_samples[start:stop]
-            r = rngs[start:stop]
-            d, n, r = self._pad_wave(d, n, r, wave)
-            if in_shard is not None:
-                d = jax.tree_util.tree_map(
-                    lambda a: jax.device_put(a, in_shard), d
-                )
-                n = jax.device_put(n, in_shard)
-                r = jax.device_put(r, in_shard)
+            d, n, r = self._stage_wave(data, n_samples, rngs, start, stop,
+                                       wave, in_shard)
             sums = self._eval_sums_vmap(params, d, n, r)
             for k, v in sums.items():
                 totals[k] = totals.get(k, 0.0) + float(v)
@@ -759,18 +812,10 @@ class FedSim:
         parts = []
         for start in range(0, c, wave):
             stop = min(start + wave, c)
-            d = jax.tree_util.tree_map(lambda a: a[start:stop], data)
-            n = n_samples[start:stop]
-            r = rngs[start:stop]
-            d, n, r = self._pad_wave(d, n, r, wave)
-            if in_shard is not None:
-                # same client-sharded placement as evaluate_round: the
-                # vmapped forward partitions over the mesh via GSPMD
-                d = jax.tree_util.tree_map(
-                    lambda a: jax.device_put(a, in_shard), d
-                )
-                n = jax.device_put(n, in_shard)
-                r = jax.device_put(r, in_shard)
+            # same client-sharded placement as evaluate_round: the
+            # vmapped forward partitions over the mesh via GSPMD
+            d, n, r = self._stage_wave(data, n_samples, rngs, start, stop,
+                                       wave, in_shard)
             sums = self._eval_sums_per_client(params, d, n, r)
             parts.append(jax.tree_util.tree_map(
                 lambda a: np.asarray(a[: stop - start]), sums

@@ -340,7 +340,9 @@ class ExperimentWorker:
 
     async def _on_cleanup(self, app=None) -> None:
         if self._heartbeat_task is not None:
-            await self._heartbeat_task.stop()
+            # cancel, not stop: with every root gone a tick sits in
+            # heartbeat()'s retry loop and would never finish
+            await self._heartbeat_task.cancel()
         if self._ship_task is not None and not self._ship_task.done():
             self._ship_task.cancel()
             try:

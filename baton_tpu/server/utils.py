@@ -214,6 +214,17 @@ class PeriodicTask:
                 await self._loop_task
             self._loop_task = None
 
+    async def cancel(self) -> None:
+        """Stop, and cancel a tick that is in flight: for a callable that
+        retries until it succeeds (the worker's heartbeat against a root
+        that is gone), where :meth:`stop` would wait forever."""
+        self._stop.set()
+        if self._loop_task is not None:
+            self._loop_task.cancel()
+            with suppress(asyncio.CancelledError):
+                await self._loop_task
+            self._loop_task = None
+
     async def _tick(self) -> None:
         try:
             await self.func()

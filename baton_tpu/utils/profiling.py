@@ -6,7 +6,7 @@ Thin, always-importable wrappers around ``jax.profiler``:
   trace (HLO timelines, per-op device time) to a directory. Enabled
   explicitly or via ``BATON_TPU_PROFILE=<dir>``; a no-op otherwise, so
   call sites can wrap hot paths unconditionally.
-* :func:`annotate` — named region that shows up inside traces.
+* :func:`annotate` — named host span (with attributes) inside traces.
 * :func:`timed` — wall-clock a function with ``block_until_ready`` on
   its outputs, so async XLA dispatch doesn't fake instant completion.
 """
@@ -93,9 +93,17 @@ def forensics_trace():
                 pass
 
 
-def annotate(name: str):
-    """Named trace region (``jax.profiler.TraceAnnotation``)."""
-    return jax.profiler.TraceAnnotation(name)
+def annotate(name: str, **attrs: Any):
+    """Named host span in the profiler's own trace
+    (``jax.profiler.TraceAnnotation``); keyword ``attrs`` become the
+    event's stats. The one host-span primitive of the simulator path
+    (``FedSim.run_round``'s ``baton.round.*`` spans): the span is written
+    by the profiler session that writes the device planes, so it is on
+    their clock, and outside a session it costs a flag test. The
+    ``Tracer`` of ``utils/tracing.py`` and ``utils/metrics.Metrics`` are
+    the HTTP server tier's, on ``time.time()``; no device trace sees
+    them."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 def timed(fn: Callable, *args: Any, **kwargs: Any) -> Tuple[Any, float]:

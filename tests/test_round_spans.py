@@ -1,0 +1,340 @@
+"""The round's host spans and the wave program's scopes (ISSUE 24).
+
+Three things: the compiled wave program (``FedSim.lower_wave``) carries
+the scopes in its ``op_name``s, forward and backward apart; one CPU
+profiler session (the only one these tests start) shows the
+``baton.round.*`` spans of ``FedSim.run_round`` nested and counted; and
+every path through ``run_round`` closes every span it opens."""
+
+import collections
+import contextlib
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from baton_tpu.models import linear_regression_model
+from baton_tpu.models.bert import BertConfig, bert_classifier_model
+from baton_tpu.models.resnet import resnet_model
+from baton_tpu.parallel import engine
+from baton_tpu.parallel.engine import FedSim
+from baton_tpu.parallel.mesh import make_mesh, shard_client_arrays
+
+
+
+def _linear_cohort(n_clients=6, capacity=8, dim=4):
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(n_clients, capacity, dim)).astype(np.float32)
+    y = (x @ np.arange(1, dim + 1, dtype=np.float32))[..., None]
+    n = np.asarray([8, 5, 8, 3, 8, 1][:n_clients], np.int32)
+    return {"x": jnp.asarray(x), "y": jnp.asarray(y)}, n
+
+
+def _linear_sim(**kw):
+    return FedSim(linear_regression_model(4), batch_size=4,
+                  learning_rate=0.05, **kw)
+
+
+# ------------------------------------------------------------ (a) scopes
+def _op_names(sim, data, n, wave_size=None):
+    params = jax.jit(sim.init)(jax.random.key(0))
+    text = sim.lower_wave(params, data, n, jax.random.key(1), 1,
+                          wave_size).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def _images(n_clients=2):
+    return ({"x": jnp.ones((n_clients, 4, 4, 4, 3)),
+             "y": jnp.zeros((n_clients, 4), jnp.int32)},
+            np.asarray([4, 3][:n_clients], np.int32))
+
+
+def _tokens(n_clients=2):
+    return ({"x": jnp.ones((n_clients, 4, 8), jnp.int32),
+             "y": jnp.zeros((n_clients, 4), jnp.int32)},
+            np.asarray([4, 3][:n_clients], np.int32))
+
+
+def _tiny_resnet():
+    return resnet_model(blocks_per_stage=(1, 1), n_groups=2, name="tiny")
+
+
+def _tiny_bert(**kw):
+    return bert_classifier_model(BertConfig.tiny(max_len=8, n_layers=1), **kw)
+
+
+@pytest.fixture(scope="module")
+def resnet_names():
+    return _op_names(FedSim(_tiny_resnet(), batch_size=4), *_images())
+
+
+@pytest.fixture(scope="module")
+def bert_names():
+    return _op_names(FedSim(_tiny_bert(), batch_size=4), *_tokens())
+
+
+def _with(names, *words):
+    return [x for x in names if all(w in x for w in words)]
+
+
+@pytest.mark.parametrize("scope", [
+    "local_train", "shuffle", "grad", "optimizer", "wave_sums"])
+@pytest.mark.parametrize("model", ["resnet", "bert"])
+def test_wave_program_names_the_trainer_and_engine_scopes(
+        model, scope, resnet_names, bert_names):
+    names = resnet_names if model == "resnet" else bert_names
+    assert _with(names, scope), f"no op_name holds {scope!r}"
+
+
+@pytest.mark.parametrize("model,scope", [
+    ("resnet", "stem"), ("resnet", "s0b0"), ("resnet", "s1b0"),
+    ("resnet", "conv"), ("resnet", "norm"), ("resnet", "shortcut"),
+    ("resnet", "head"),
+    ("bert", "embed"), ("bert", "block0"), ("bert", "attention"),
+    ("bert", "mlp"), ("bert", "norm"), ("bert", "head"),
+])
+def test_model_scopes_tell_forward_from_backward(
+        model, scope, resnet_names, bert_names):
+    names = _with(resnet_names if model == "resnet" else bert_names, scope)
+    forward = [x for x in names if "jvp(" in x and "transpose(" not in x]
+    backward = [x for x in names if "transpose(" in x]
+    assert forward, f"{scope}: no forward op among {names[:5]}"
+    if scope != "embed":  # integer inputs: the gather has no cotangent path
+        assert backward, f"{scope}: no backward op among {names[:5]}"
+
+
+def test_scopes_nest_block_then_part(resnet_names):
+    assert any(re.search(r"jvp\(s1b0\)\)?/shortcut/conv/", x)
+               for x in resnet_names)
+    assert any(re.search(r"local_train/.*optimizer/", x) for x in resnet_names)
+
+
+def test_remat_marks_the_recomputed_forward():
+    names = _op_names(FedSim(_tiny_bert(remat=True), batch_size=4),
+                      *_tokens())
+    assert _with(names, "rematted_computation", "attention")
+
+
+def test_mesh_wave_program_names_its_psums():
+    mesh = make_mesh(4)
+    data, n = _linear_cohort(n_clients=4)
+    names = _op_names(_linear_sim(mesh=mesh),
+                      shard_client_arrays(data, mesh), n[:4])
+    assert _with(names, "wave_psum")
+    assert _with(names, "local_train") and _with(names, "wave_sums")
+
+
+def test_lower_wave_is_the_program_run_round_dispatches():
+    """Same jitted callable, same staged shapes: after a round, lowering
+    the wave again adds no entry to the jit's cache."""
+    data, n = _linear_cohort()
+    sim = _linear_sim()
+    params = sim.init(jax.random.key(0))
+    sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
+    program, _ = sim._wave_program(1, robust=False)
+    before = program._cache_size()
+    lowered = sim.lower_wave(params, data, n, jax.random.key(1), 1, 4)
+    assert "_wave_sums_vmap" in lowered.as_text()[:400]
+    sim.run_round(params, data, n, jax.random.key(2), wave_size=4)
+    assert program._cache_size() == before
+
+
+@pytest.mark.parametrize("kwargs", [
+    {"aggregator": "median"}, {"wave_size": "auto"}])
+def test_lower_wave_refuses_what_it_does_not_lower(kwargs):
+    data, n = _linear_cohort()
+    sim = _linear_sim(**{k: v for k, v in kwargs.items()
+                         if k == "aggregator"})
+    with pytest.raises(NotImplementedError):
+        sim.lower_wave(sim.init(jax.random.key(0)), data, n,
+                       jax.random.key(1), 1, kwargs.get("wave_size"))
+
+
+# ------------------------------------------- (b) one profiler session
+@pytest.fixture(scope="module")
+def session(tmp_path_factory):
+    """Two rounds of 6 clients in waves of 4 (the second wave has two
+    phantom clients) under the one CPU profiler session of this file,
+    and the same first round outside any session."""
+    from jax.profiler import ProfileData
+
+    data, n = _linear_cohort()
+    sim = _linear_sim()
+    params = sim.init(jax.random.key(0))
+    outside = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
+    jax.block_until_ready(outside.params)
+
+    tdir = str(tmp_path_factory.mktemp("trace"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(tdir, profiler_options=options)
+    try:
+        first = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
+        second = sim.run_round(first.params, data, n, jax.random.key(2),
+                               wave_size=4)
+        jax.block_until_ready(second.params)
+    finally:
+        jax.profiler.stop_trace()
+    spans = []
+    for path in glob.glob(tdir + "/**/*.xplane.pb", recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                spans += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                           dict(ev.stats))
+                          for ev in line.events if ev.name.startswith("baton.")]
+    return {"spans": sorted(spans, key=lambda s: s[1]),
+            "outside": outside, "inside": first}
+
+
+@pytest.mark.parametrize("name,count", [
+    ("baton.round", 2), ("baton.round.prepare", 2), ("baton.round.stage", 4),
+    ("baton.round.dispatch", 4), ("baton.round.sync", 2),
+    ("baton.round.record", 2), ("baton.round.fold", 2),
+    ("baton.round.update", 2)])
+def test_session_counts_each_span(session, name, count):
+    assert sum(s[0] == name for s in session["spans"]) == count
+
+
+def test_session_spans_nest_in_their_round_in_order(session):
+    rounds = [s for s in session["spans"] if s[0] == "baton.round"]
+    assert [r[3] for r in rounds] == [
+        {"clients": 6, "waves": 2, "wave_size": 4}] * 2
+    for _, r0, r1, _ in rounds:
+        inner = [s for s in session["spans"]
+                 if s[0] != "baton.round" and r0 <= s[1] and s[2] <= r1]
+        assert [s[0].rsplit(".", 1)[1] for s in inner] == [
+            "prepare", "stage", "dispatch", "stage", "dispatch", "sync",
+            "record", "fold", "update"]
+        # siblings: each ends before the next starts
+        assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+    inside_a_round = sum(r0 <= s[1] and s[2] <= r1
+                         for s in session["spans"] if s[0] != "baton.round"
+                         for _, r0, r1, _ in rounds)
+    assert inside_a_round == len(session["spans"]) - len(rounds)
+
+
+def test_session_stage_counts_the_phantom_clients(session):
+    stages = [s[3] for s in session["spans"] if s[0] == "baton.round.stage"]
+    assert stages == [{"wave": 0, "real": 4, "padded": 0},
+                      {"wave": 1, "real": 2, "padded": 2}] * 2
+    dispatches = [s[3] for s in session["spans"]
+                  if s[0] == "baton.round.dispatch"]
+    assert dispatches == [{"wave": 0}, {"wave": 1}] * 2
+
+
+def test_round_is_bit_equal_inside_and_outside_a_session(session):
+    a, b = session["outside"], session["inside"]
+    for x, y in zip(jax.tree_util.tree_leaves((a.params, a.loss_history,
+                                               a.client_losses)),
+                    jax.tree_util.tree_leaves((b.params, b.loss_history,
+                                               b.client_losses))):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+# ------------------------------------ (c) every path closes its spans
+class _Recorder:
+    """Stands in for ``annotate``: what was opened, and what is open."""
+
+    def __init__(self):
+        self.opened, self.open = [], []
+
+    def __call__(self, name, **attrs):
+        recorder = self
+
+        class Span(contextlib.AbstractContextManager):
+            def __enter__(self):
+                recorder.opened.append((name, attrs))
+                recorder.open.append(name)
+                return self
+
+            def __exit__(self, *exc):
+                assert recorder.open.pop() == name  # innermost first
+                return False
+
+            def set_metadata(self, **more):
+                attrs.update(more)
+
+        return Span()
+
+
+@pytest.fixture
+def recorder(monkeypatch):
+    rec = _Recorder()
+    monkeypatch.setattr(engine, "annotate", rec)
+    return rec
+
+
+def _boom(done, total):
+    raise RuntimeError("progress hook failed")
+
+
+PATHS = {
+    "plain": ({}, {}, 6, 2),
+    "one_wave": ({}, {"wave_size": None}, 6, 1),
+    "client_indices": ({}, {"client_indices": np.asarray([0, 2, 3, 5, 1])},
+                       5, 2),
+    "robust": ({"aggregator": "median"}, {}, 6, 2),
+    "progress_fn": ({}, {"progress_fn": lambda done, total: None}, 6, 2),
+    "server_optimizer": ({"server_optimizer": optax.sgd(1.0)}, {}, 6, 2),
+    "mesh": ({"mesh": 2}, {}, 6, 2),
+}
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_every_path_opens_and_closes_its_spans(recorder, path):
+    sim_kw, round_kw, clients, waves = PATHS[path]
+    sim_kw = dict(sim_kw)
+    data, n = _linear_cohort()
+    if "mesh" in sim_kw:
+        sim_kw["mesh"] = make_mesh(sim_kw["mesh"])
+        data = shard_client_arrays(data, sim_kw["mesh"])
+    sim = _linear_sim(**sim_kw)
+    res = sim.run_round(sim.init(jax.random.key(0)), data, n,
+                        jax.random.key(1),
+                        **{"wave_size": 4, **round_kw})
+    assert np.isfinite(np.asarray(res.loss_history)).all()
+    assert recorder.open == []
+    counts = collections.Counter(name for name, _ in recorder.opened)
+    assert counts == {"baton.round": 1, "baton.round.prepare": 1,
+                      "baton.round.stage": waves,
+                      "baton.round.dispatch": waves, "baton.round.sync": 1,
+                      "baton.round.record": 1, "baton.round.fold": 1,
+                      "baton.round.update": 1}
+    name, attrs = recorder.opened[0]
+    assert name == "baton.round" and attrs["clients"] == clients
+    assert attrs["waves"] == waves
+    assert sum(a["real"] for nm, a in recorder.opened
+               if nm == "baton.round.stage") == clients
+
+
+@pytest.mark.parametrize("where,sim_kw,round_kw,error", [
+    ("prepare", {}, {"client_indices": np.asarray([99])}, None),
+    ("prepare", {"aggregator": "median"}, {"wave_size": "auto"},
+     NotImplementedError),
+    ("dispatch", {}, {"progress_fn": _boom}, RuntimeError),
+])
+def test_an_exception_leaves_no_span_open(recorder, where, sim_kw, round_kw,
+                                          error):
+    data, n = _linear_cohort()
+    sim = _linear_sim(**sim_kw)
+    params = sim.init(jax.random.key(0))
+    kwargs = {"wave_size": 4, **round_kw}
+    if error is None:  # an index past the cohort clamps: no exception
+        sim.run_round(params, data, n, jax.random.key(1), **kwargs)
+    else:
+        with pytest.raises(error):
+            sim.run_round(params, data, n, jax.random.key(1), **kwargs)
+        assert recorder.opened[-1][0] == f"baton.round.{where}"
+    assert recorder.open == []
+
+
+def test_annotate_takes_attributes_and_works_outside_a_session():
+    from baton_tpu.utils.profiling import annotate
+
+    with annotate("baton.test", wave=1) as span:
+        span.set_metadata(clients=3)  # no session: nothing is recorded
+    assert isinstance(span, jax.profiler.TraceAnnotation)
