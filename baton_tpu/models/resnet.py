@@ -11,6 +11,9 @@ driver-set north-star workload. Design choices for TPU + federation:
 * **NHWC + optional bfloat16 compute**: convs lower to MXU-tiled
   ``conv_general_dilated``; params stay fp32 (FedAvg accumulates in
   fp32), activations/weights are cast to ``compute_dtype`` per-apply.
+  GroupNorm keeps that layout: per-channel moments over ``(h, w)`` with
+  ``C`` minor, groups formed on ``[b, C]``; a ``c/g``-minor view of the
+  activation does not fit the TPU's 8 x 128 tiles and costs a transpose.
 * **CIFAR stem** (3x3, stride 1, no maxpool) by default; ``imagenet_stem``
   switches to 7x7/stride-2 + maxpool for 224px inputs (ViT-sized runs).
 """
@@ -144,15 +147,61 @@ def _conv(x, w, stride=1, impl="direct"):
 
 @jax.named_scope("norm")
 def _group_norm(x, p, n_groups=32, eps=1e-5):
-    """GroupNorm over NHWC; stats in fp32 regardless of compute dtype."""
-    b, h, w, c = x.shape
+    """GroupNorm over NHWC; float32 statistics whatever the compute dtype.
+
+    The statistics are per-channel sums over ``(h, w)`` with ``C`` left
+    minor, folded into groups on the ``[b, C]`` result. A ``[b, h, w, g,
+    c/g]`` view has a minor dimension of 2..16 where the TPU tiles
+    8 x 128: XLA transposes every activation to reduce over it, forward
+    and backward (71 % of the ResNet wave; PERF.md, PR 25). The backward
+    is written out so that its residuals are ``x`` in its own dtype plus
+    ``[b, C]`` statistics and it reduces per channel too.
+    """
+    _, h, w, c = x.shape
     g = min(n_groups, c)
-    xf = x.astype(jnp.float32).reshape(b, h, w, g, c // g)
-    mean = jnp.mean(xf, axis=(1, 2, 4), keepdims=True)
-    var = jnp.var(xf, axis=(1, 2, 4), keepdims=True)
-    xf = (xf - mean) * jax.lax.rsqrt(var + eps)
-    xf = xf.reshape(b, h, w, c)
-    return (xf * p["scale"] + p["bias"]).astype(x.dtype)
+    per_group = c // g
+    n = h * w * per_group
+
+    def group_mean(per_channel):
+        # [b, C] sums over (h, w) -> each channel's group mean, back on
+        # [b, C]: the one view by group there is, on b * C numbers.
+        # Divide before the repeat: a group total repeated and scaled
+        # afterwards XLA rewrote into a reduce-window over c/g, 4 ms a wave
+        b = per_channel.shape[0]
+        m = jnp.sum(per_channel.reshape(b, g, per_group), axis=-1) / n
+        return jnp.repeat(m, per_group, axis=-1)
+
+    def centre(x, mean):
+        return x.astype(jnp.float32) - mean[:, None, None, :]
+
+    def fwd(x, scale, bias):
+        # two passes, each a per-channel reduction over (h, w): the sum,
+        # then the second moment about the group's mean (a one-pass form
+        # about a guessed centre was 2 % faster a wave and up to 2e-3 off
+        # where the guess is an outlier: PERF.md, PR 25)
+        mean = group_mean(jnp.sum(x, axis=(1, 2), dtype=jnp.float32))
+        d = centre(x, mean)
+        var = group_mean(jnp.sum(d * d, axis=(1, 2)))
+        rstd = jax.lax.rsqrt(var + eps)
+        y = d * (rstd * scale)[:, None, None, :] + bias
+        return y.astype(x.dtype), (x, scale, mean, rstd)
+
+    def bwd(res, dy):
+        x, scale, mean, rstd = res
+        dy = dy.astype(jnp.float32)
+        d = centre(x, mean)
+        s_dy = jnp.sum(dy, axis=(1, 2))
+        s_dyx = jnp.sum(dy * d, axis=(1, 2)) * rstd  # sum of dy * xhat
+        k_dy = rstd * scale
+        k_d = -rstd * rstd * group_mean(s_dyx * scale)
+        k_1 = -rstd * group_mean(s_dy * scale)
+        dx = (dy * k_dy[:, None, None, :] + d * k_d[:, None, None, :]
+              + k_1[:, None, None, :])
+        return dx.astype(x.dtype), jnp.sum(s_dyx, axis=0), jnp.sum(s_dy, axis=0)
+
+    norm = jax.custom_vjp(lambda *args: fwd(*args)[0])
+    norm.defvjp(fwd, bwd)
+    return norm(x, p["scale"], p["bias"])
 
 
 def _block_init(key, cin, cout, stride):

@@ -4,11 +4,15 @@ Uses a narrow 2-stage variant so CPU tests stay fast; the full
 resnet18_cifar_model is exercised for param-count/shape only.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from baton_tpu.models.resnet import resnet_model, resnet18_cifar_model
+from baton_tpu.models.resnet import (
+    _group_norm, resnet_model, resnet18_cifar_model)
 from baton_tpu.ops.padding import stack_client_datasets
 from baton_tpu.parallel.engine import FedSim
 
@@ -217,3 +221,94 @@ def test_shift_conv_bf16_accumulation():
         # internally so they agree to one final-rounding ulp
         scale = np.maximum(np.abs(ref), 1.0)
         np.testing.assert_allclose(got / scale, ref / scale, atol=2e-2)
+
+
+# ---------------------------------------------------------------- GroupNorm
+def _group_norm_5d(x, p, n_groups=32, eps=1e-5):
+    """The formulation ``_group_norm`` had until PR 25, kept as the
+    oracle: float32 statistics over a ``[b, h, w, g, c/g]`` view."""
+    b, h, w, c = x.shape
+    g = min(n_groups, c)
+    xf = x.astype(jnp.float32).reshape(b, h, w, g, c // g)
+    mean = jnp.mean(xf, axis=(1, 2, 4), keepdims=True)
+    var = jnp.var(xf, axis=(1, 2, 4), keepdims=True)
+    xf = (xf - mean) * jax.lax.rsqrt(var + eps)
+    xf = xf.reshape(b, h, w, c)
+    return (xf * p["scale"] + p["bias"]).astype(x.dtype)
+
+
+def _vmapped_value_and_grad(norm, x, p, t):
+    """``(y, dx, dscale, dbias)`` of ``norm`` under a client ``vmap``
+    with per-client ``scale``/``bias``; the cotangent is ``t``."""
+    def loss(x, p):
+        y = jax.vmap(norm)(x, p)
+        return jnp.sum(y.astype(jnp.float32) * t), y
+
+    (_, y), (dx, dp) = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(x, p)
+    return y, dx, dp["scale"], dp["bias"]
+
+
+def _group_norm_case(c, h, w, dtype, shift, clients=3, b=4):
+    k = jax.random.split(jax.random.key(c * 100 + h), 4)
+    x = (jax.random.normal(k[0], (clients, b, h, w, c)) + shift).astype(dtype)
+    p = {"scale": 1.0 + 0.3 * jax.random.normal(k[1], (clients, c)),
+         "bias": 0.3 * jax.random.normal(k[2], (clients, c))}
+    t = jax.random.normal(k[3], (clients, b, h, w, c))
+    return x, p, t
+
+
+@pytest.mark.parametrize(
+    "c,h,w,dtype,shift,tol",
+    [
+        (32, 4, 4, jnp.float32, 0.0, 1e-4),     # c/g = 1
+        (64, 8, 8, jnp.float32, 0.0, 1e-4),     # c/g = 2: stem and stage 0
+        (128, 4, 6, jnp.float32, 0.0, 1e-4),    # c/g = 4, h != w
+        (512, 2, 2, jnp.float32, 0.0, 1e-4),    # c/g = 16
+        (64, 8, 8, jnp.bfloat16, 0.0, 2e-2),
+        (512, 2, 2, jnp.bfloat16, 0.0, 2e-2),
+        # mean / std = 100: a careless E[x^2] - E[x]^2 in float32 fails here
+        (64, 8, 8, jnp.float32, 100.0, 1e-3),
+    ],
+    ids=["cg1", "cg2", "cg4_h_ne_w", "cg16", "cg2_bf16", "cg16_bf16",
+         "cg2_shifted_mean"],
+)
+def test_group_norm_matches_the_5d_formulation(c, h, w, dtype, shift, tol):
+    x, p, t = _group_norm_case(c, h, w, dtype, shift)
+    got = _vmapped_value_and_grad(_group_norm, x, p, t)
+    want = _vmapped_value_and_grad(_group_norm_5d, x, p, t)
+    for name, u, v in zip(("y", "dx", "dscale", "dbias"), got, want):
+        assert u.dtype == v.dtype and u.shape == v.shape, name
+        u, v = np.asarray(u, np.float32), np.asarray(v, np.float32)
+        assert np.max(np.abs(u - v)) <= tol * np.max(np.abs(v)), name
+
+
+def _group_sized_minor_dims(norm, c=64, n_groups=32):
+    """Shapes of the intermediates, anywhere in the jaxpr of the vmapped
+    value-and-grad of ``norm``, that hold at least ``x.size`` elements
+    with a last dimension of ``c / g``."""
+    x, p, t = _group_norm_case(c, 8, 8, jnp.bfloat16, 0.0)
+    minor = c // n_groups
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            for v in eqn.outvars:
+                shape = getattr(v.aval, "shape", ())
+                if (shape and shape[-1] == minor
+                        and int(np.prod(shape)) >= x.size):
+                    found.append((eqn.primitive.name, tuple(shape)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(partial(_vmapped_value_and_grad, norm))(x, p, t).jaxpr)
+    return found
+
+
+def test_group_norm_never_views_the_activation_by_group():
+    # the statistics are per-channel sums in [b, h, w, C], folded into
+    # groups on [b, C]: a minor dimension of c/g (2 here, on 8 x 128
+    # tiles) is what made XLA transpose every activation on the TPU
+    assert _group_sized_minor_dims(_group_norm) == []
+    # and the walk does see such a view where there is one
+    assert _group_sized_minor_dims(_group_norm_5d)
