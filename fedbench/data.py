@@ -1,6 +1,7 @@
 """The one general generator of a cell's cohort: client sizes from the
-cell's distribution spec, inputs and labels from the configuration's
-input spec, everything from ``--seed``.
+cell's distribution spec (``fedbench/cohorts/<kind>.py``), inputs and
+labels from the configuration's input spec
+(``fedbench/inputs/<kind>.py``), everything from ``--seed``.
 
 Inputs are made on the device in one jitted call, in the ``[C,
 capacity, ...]`` layout ``FedSim`` takes
@@ -13,7 +14,6 @@ a falling loss can be required of every cell.
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -45,36 +45,11 @@ def capacity_for(n_samples: np.ndarray, batch: int) -> int:
     return int(math.ceil(int(n_samples.max()) / batch) * batch)
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _images(shape, n_classes, n_clients, capacity, n_samples, key):
-    kx, kp = jax.random.split(key)
-    x = jax.random.normal(kx, (n_clients, capacity) + shape, jnp.float32)
-    proj = jax.random.normal(kp, (math.prod(shape), n_classes), jnp.float32)
-    y = jnp.argmax(x.reshape(n_clients, capacity, -1) @ proj, axis=-1)
-    real = jnp.arange(capacity)[None, :] < n_samples[:, None]
-    x = jnp.where(real.reshape(real.shape + (1,) * len(shape)), x, 0.0)
-    return {"x": x, "y": jnp.where(real, y, 0).astype(jnp.int32)}
-
-
-@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
-def _tokens(vocab, n_classes, seq_len, n_clients, capacity, n_samples, key):
-    x = jax.random.randint(key, (n_clients, capacity, seq_len), 0, vocab,
-                           jnp.int32)
-    real = jnp.arange(capacity)[None, :] < n_samples[:, None]
-    x = jnp.where(real[..., None], x, 0)
-    return {"x": x, "y": (x[..., 0] % n_classes).astype(jnp.int32)}
-
-
-def make_cohort(spec: dict, n_samples: np.ndarray, capacity: int,
+def make_cohort(root: str, spec: dict, n_samples: np.ndarray, capacity: int,
                 seq_len, key) -> dict:
-    """``{"x": [C, capacity, ...], "y": [C, capacity]}`` on the default
-    device. ``spec`` is the configuration's resolved input spec."""
-    n = jnp.asarray(n_samples, jnp.int32)
-    c = int(n_samples.shape[0])
-    if spec["kind"] == "image":
-        return _images(tuple(spec["shape"]), int(spec["n_classes"]), c,
-                       capacity, n, key)
-    if spec["kind"] == "tokens":
-        return _tokens(int(spec["vocab"]), int(spec["n_classes"]),
-                       int(seq_len), c, capacity, n, key)
-    raise ValueError(f"unknown input kind {spec['kind']!r}")
+    """``{"x": [C, capacity, ...], "y": [C, capacity, ...]}`` on the
+    default device, from ``fedbench/inputs/<kind>.py``. ``spec`` is the
+    configuration's resolved input spec."""
+    module = manifest.load_module(root, "inputs", spec["kind"])
+    return module.make(spec, int(n_samples.shape[0]), capacity, seq_len,
+                       jnp.asarray(n_samples, jnp.int32), key)

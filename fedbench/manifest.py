@@ -4,11 +4,18 @@ Everything that belongs to one configuration, one cell, one cohort
 distribution, one FLOP count or one layer metric is a file of its own::
 
     fedbench/configs/<config>.json        sizes, source, builder, input spec
+    fedbench/references/<config>.py       make_loss(config) -> loss(params, x, y, mask)
+    fedbench/flops/<config>.py            required(config, job) -> FLOPs, bytes
+    fedbench/inputs/<kind>.py             make(spec, ...) -> {"x", "y"}
     fedbench/workloads/<cell>.json        cohort, batch, epochs, waves, chips
     fedbench/cohorts/<kind>.py            sizes(spec, n_clients, rng) -> [C]
-    fedbench/flops/<config>.py            required(config, job) -> FLOPs, bytes
     fedbench/layer_metrics/<metric>.py    LAYER, UNIT, MOVES, SOURCE, read(...)
     fedbench/peaks.json                   device peaks by exact device_kind
+
+A configuration or a workload file may hold an ``engine`` block: further
+arguments of ``FedSim`` (``trainable``, ``optimizer``, ``aggregator``,
+...), resolved as a builder's are. A configuration file may hold a
+``scopes`` block: names of its own model for the reduction of a trace.
 
 A later PR adds a cell, a configuration or a layer metric by adding
 files and ``BENCHMARK.json`` entries; nothing here lists them. Every
@@ -22,7 +29,7 @@ import importlib
 import importlib.util
 import json
 import os
-from typing import Any
+from typing import Any, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = "fedbench"
@@ -97,16 +104,33 @@ def load_op_categories(root: str) -> dict:
         os.path.join(root, BENCH_DIR, "op_categories.json"))["rules"]
 
 
+def load_trace_names(root: str, config: Optional[dict] = None) -> dict:
+    """The program's names the reduction of a trace reads
+    (``fedbench/trace_names.json``), with the ``scopes`` block of
+    ``config`` laid over them: its ``parts`` are added, its ``blocks``
+    pattern is one more alternative."""
+    names = _read_json(os.path.join(root, BENCH_DIR, "trace_names.json"))
+    own = (config or {}).get("scopes", {})
+    names["parts"] = names["parts"] + own.get("parts", [])
+    if "blocks" in own:
+        names["blocks"] = f"{names['blocks']}|{own['blocks']}"
+    return names
+
+
 def resolve(spec: Any, config: dict) -> Any:
     """Turn a JSON argument into the Python value a builder takes:
     ``{"$key": "hidden_size"}`` is that top-level size of ``config`` (so
     the builder cannot drift from the published sizes beside it),
-    ``{"$dtype": "bfloat16"}`` is the ``jax.numpy`` type and
-    ``{"$call": "pkg.mod:name", "kwargs": {...}}`` is that callable's
-    result, arguments resolved the same way. Anything else is itself."""
+    ``{"$dtype": "bfloat16"}`` is the ``jax.numpy`` type,
+    ``{"$ref": "pkg.mod:name"}`` is that object itself (a predicate such
+    as ``trainable``) and ``{"$call": "pkg.mod:name", "kwargs": {...}}``
+    is that callable's result, arguments resolved the same way. Anything
+    else is itself."""
     if isinstance(spec, dict):
         if "$key" in spec:
             return config[spec["$key"]]
+        if "$ref" in spec:
+            return by_path(spec["$ref"])
         if "$dtype" in spec:
             import jax.numpy as jnp
 
@@ -126,18 +150,32 @@ def by_path(dotted: str):
     return getattr(importlib.import_module(module), attr)
 
 
-def build_model(config: dict, tiny: bool, reference: bool = False):
-    """The configuration's model through the program's own builder.
-    ``tiny`` applies the CPU-test sizes; ``reference`` the overrides the
-    plain reference runs under (float32 compute)."""
+def sized(config: dict, tiny: bool) -> dict:
+    """The configuration as it is run: the file's content, in a
+    rehearsal with its ``tiny.sizes`` laid over the published sizes, so
+    that the program's builder and the plain reference read one set."""
+    return dict(config, **config["tiny"].get("sizes", {})) if tiny else config
+
+
+def build_model(config: dict, tiny: bool):
+    """The configuration's model through the program's own builder, at
+    the sizes of ``sized(config, tiny)``; a rehearsal may also name
+    another builder and arguments (``tiny.path``, ``tiny.kwargs``)."""
     builder = config["builder"]
     path, kwargs = builder["path"], dict(builder["kwargs"])
     if tiny:
         path = config["tiny"].get("path", path)
-        kwargs.update(config["tiny"]["kwargs"])
-    if reference:
-        kwargs.update(builder["reference_kwargs"])
-    return by_path(path)(**resolve(kwargs, config))
+        kwargs.update(config["tiny"].get("kwargs", {}))
+    return by_path(path)(**resolve(kwargs, sized(config, tiny)))
+
+
+def engine_args(config: dict, workload: dict) -> dict:
+    """``FedSim``'s arguments beyond ``batch_size``, ``learning_rate``
+    and ``mesh``: the configuration's ``engine`` block with the
+    workload's laid over it, resolved. Empty where neither has one."""
+    block = dict(config.get("engine", {}))
+    block.update(workload.get("engine", {}))
+    return resolve(block, config)
 
 
 def input_spec(config: dict, tiny: bool) -> dict:
@@ -146,4 +184,4 @@ def input_spec(config: dict, tiny: bool) -> dict:
     spec = dict(config["input"])
     if tiny:
         spec.update(config["tiny"].get("input", {}))
-    return resolve(spec, config)
+    return resolve(spec, sized(config, tiny))

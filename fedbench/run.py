@@ -4,7 +4,8 @@
         --seconds 30 --trace 0
 
 Builds the cell's model and cohort from the seed, stages them, checks
-one probe round against the plain reference (``fedbench/reference.py``),
+one probe round against the configuration's plain reference
+(``fedbench/references/<config>.py`` through ``fedbench/reference.py``),
 warms up the cell's own shapes, then measures: with ``--trace 0`` a
 window of ``--seconds`` giving the end-to-end metrics, with ``--trace
 1`` a few rounds under ``jax.profiler.trace`` giving the layer metrics
@@ -137,12 +138,12 @@ def run_rounds(sim, params, data, n_samples, key, job, first_index: int,
     return params, stamps, losses, attempted, failed
 
 
-def traced(work, keep_dir, cell: str):
+def traced(work, span_prefixes, keep_dir, cell: str):
     """``(work(), rows)``: run ``work`` under ``jax.profiler.trace`` (the
     Python tracer off: it slows the host it is measuring) and read the
-    trace's rows on the spot; ``rows`` is ``None`` if the profiler left
-    no file. With ``keep_dir`` the rows and the raw trace are copied
-    there."""
+    trace's rows on the spot, before the trace is deleted; ``rows`` is
+    ``None`` if the profiler left no file. With ``keep_dir`` the rows
+    and the raw trace are copied there."""
     import jax
 
     from fedbench import trace_reduce
@@ -159,7 +160,7 @@ def traced(work, keep_dir, cell: str):
                           recursive=True)
         if not found:
             return result, None
-        rows = trace_reduce.read_events(found[0])
+        rows = trace_reduce.read_events(found[0], span_prefixes)
         if keep_dir:
             os.makedirs(keep_dir, exist_ok=True)
             trace_reduce.write_rows(
@@ -168,29 +169,65 @@ def traced(work, keep_dir, cell: str):
     return result, rows
 
 
-# ------------------------------------------------------------------- probe
-def probe(config, job, tiny, seed, sim, model, params, mesh):
-    """Correctness rule 1 (and 3 on a mesh): one round of a seeded probe
-    cohort — 4 clients holding 1/4, 2/4, 3/4 and 4/4 of one batch —
-    through ``FedSim.run_round`` in the cell's layout against the plain
-    reference, and on a mesh against the same round on one device, all
-    from the run's initial ``params``. Returns ``(ok, {name:
-    disagreement})``."""
+# ------------------------------------------------------------------ set-up
+def build_cell(root, config, job, chips, seed, tiny):
+    """The cell's program and inputs from the seed: ``(model, params,
+    n_samples, capacity, data, mesh, sim)``. ``FedSim`` takes the batch
+    size, the learning rate, the mesh and whatever the ``engine`` blocks
+    of the configuration and the workload state."""
     import jax
-    import numpy as np
 
     from baton_tpu.parallel.engine import FedSim
-    from baton_tpu.parallel.mesh import shard_client_arrays
+    from baton_tpu.parallel.mesh import make_mesh, shard_client_arrays
     from fedbench import data as cohort
-    from fedbench import reference
+
+    model = manifest.build_model(config, tiny)
+    params = jax.jit(model.init)(jax.random.key(seed))
+    n_samples = cohort.client_sizes(
+        root, job["samples_per_client"], job["clients"], seed)
+    capacity = cohort.capacity_for(n_samples, job["batch"])
+    data = cohort.make_cohort(
+        root, manifest.input_spec(config, tiny), n_samples, capacity,
+        job.get("seq_len"), cohort.data_key(seed + 1))
+    mesh = make_mesh(chips) if chips > 1 else None
+    if mesh is not None:
+        data = shard_client_arrays(data, mesh)
+    sim = FedSim(model, batch_size=job["batch"],
+                 learning_rate=job["learning_rate"], mesh=mesh,
+                 **manifest.engine_args(config, job))
+    return model, params, n_samples, capacity, data, mesh, sim
+
+
+# ------------------------------------------------------------------- probe
+def probe_cohort(root, config, job, tiny, seed):
+    """``(data, sizes)``: the seeded probe cohort, 4 clients holding 1/4,
+    2/4, 3/4 and 4/4 of one batch of the cell's inputs."""
+    import numpy as np
+
+    from fedbench import data as cohort
 
     batch = job["batch"]
     sizes = np.asarray([batch * k // 4 for k in (1, 2, 3, 4)], np.int32)
-    spec = manifest.input_spec(config, tiny)
-    pdata = cohort.make_cohort(spec, sizes, batch, job.get("seq_len"),
-                               cohort.data_key(seed + 7919))
+    return cohort.make_cohort(
+        root, manifest.input_spec(config, tiny), sizes, batch,
+        job.get("seq_len"), cohort.data_key(seed + 7919)), sizes
+
+
+def probe(root, config, job, tiny, seed, sim, params, mesh):
+    """Correctness rule 1 (and 3 on a mesh): one round of the probe
+    cohort through ``FedSim.run_round`` in the cell's layout against the
+    configuration's plain reference (``fedbench/references/<config>.py``),
+    and on a mesh against the same round on one device, all from the
+    run's initial ``params``. Returns ``(ok, {name: disagreement})``."""
+    import jax
+
+    from baton_tpu.parallel.engine import FedSim
+    from baton_tpu.parallel.mesh import shard_client_arrays
+    from fedbench import reference
+
+    pdata, sizes = probe_cohort(root, config, job, tiny, seed)
     key = jax.random.key(seed + 1299709)
-    lr = job["learning_rate"]
+    batch, lr = job["batch"], job["learning_rate"]
 
     def one_round(engine, placed):
         return engine.run_round(params, placed, sizes, key, n_epochs=1,
@@ -200,21 +237,29 @@ def probe(config, job, tiny, seed, sim, model, params, mesh):
     got = one_round(sim, placed)
     # a mesh round's parameters are replicated; compare on one device
     got_params = jax.device_put(got.params, jax.devices()[0])
-    ref_model = manifest.build_model(config, tiny, reference=True)
+    engine = manifest.engine_args(config, job)
+    loss = manifest.load_module(root, "references", config["name"]
+                                ).make_loss(manifest.sized(config, tiny))
     want, want_loss = reference.reference_round(
-        ref_model.apply, params, pdata, sizes, lr)
+        loss, params, pdata, sizes, lr, engine.get("trainable"))
     found = {"reference": reference.update_disagreement(
-        params, got_params, want)}
+        params, got_params, want),
+        "reference_l2": reference.update_disagreement(
+            params, got_params, want, "l2")}
     loss_gap = abs(float(got.loss_history[-1]) - want_loss)
     if mesh is not None:
-        one = one_round(FedSim(model, batch_size=batch, learning_rate=lr),
-                        pdata)
+        one = one_round(FedSim(sim.model, batch_size=batch, learning_rate=lr,
+                               mesh=None, **engine), pdata)
         found["one_device"] = reference.update_disagreement(
             params, got_params, one.params)
     tol = config["probe_tolerance"]
-    ok = all(v <= tol for v in found.values()) and loss_gap <= tol
-    say(f"probe: update disagreement {found} (relative to the update's "
-        f"largest entry), loss gap {loss_gap:.3g}, tolerance {tol}: "
+    limits = dict.fromkeys(found, tol)
+    limits["reference_l2"] = config.get("probe_l2_tolerance")
+    ok = loss_gap <= tol and all(
+        limits[k] is None or v <= limits[k] for k, v in found.items())
+    say("probe: update disagreement " + ", ".join(
+        f"{k} {v:.4g} (limit {limits[k]})" for k, v in found.items())
+        + f"; loss gap {loss_gap:.3g} (limit {tol}): "
         f"{'ok' if ok else 'FAILED'}")
     return ok, found
 
@@ -261,9 +306,6 @@ def main(argv=None) -> int:
         f"({platform})" + (" REHEARSAL: tiny sizes, no time is reported"
                            if tiny else f", compile cache {cache_dir}"))
 
-    from baton_tpu.parallel.engine import FedSim
-    from baton_tpu.parallel.mesh import make_mesh, shard_client_arrays
-    from fedbench import data as cohort
     from fedbench import trace_reduce
 
     compiles = CompileCounter()
@@ -272,19 +314,8 @@ def main(argv=None) -> int:
 
     # ---- set-up: model, cohort, staging
     t_init = time.perf_counter()
-    model = manifest.build_model(config, tiny)
-    params = jax.jit(model.init)(jax.random.key(args.seed))
-    n_samples = cohort.client_sizes(
-        root, job["samples_per_client"], job["clients"], args.seed)
-    capacity = cohort.capacity_for(n_samples, job["batch"])
-    spec = manifest.input_spec(config, tiny)
-    data = cohort.make_cohort(spec, n_samples, capacity, job.get("seq_len"),
-                              cohort.data_key(args.seed + 1))
-    mesh = make_mesh(chips) if chips > 1 else None
-    if mesh is not None:
-        data = shard_client_arrays(data, mesh)
-    sim = FedSim(model, batch_size=job["batch"],
-                 learning_rate=job["learning_rate"], mesh=mesh)
+    model, params, n_samples, capacity, data, mesh, sim = build_cell(
+        root, config, job, chips, args.seed, tiny)
     jax.block_until_ready((params, data))
     init_s = time.perf_counter() - t_init
 
@@ -302,7 +333,7 @@ def main(argv=None) -> int:
 
     # ---- correctness probe, outside the window
     t_probe = time.perf_counter()
-    probe_ok, _ = probe(config, job, tiny, args.seed, sim, model, params, mesh)
+    probe_ok, _ = probe(root, config, job, tiny, args.seed, sim, params, mesh)
     probe_s = time.perf_counter() - t_probe
 
     # ---- warm-up: the cell's own shapes, first round compiles or loads
@@ -322,14 +353,15 @@ def main(argv=None) -> int:
     compiles.counting = True
     reduced = None
     if args.trace:
+        names = manifest.load_trace_names(root, config)
         n_trace = job["trace_rounds"]
         (params, stamps, losses, attempted, failed), rows = traced(
             lambda: run_rounds(sim, params, data, n_samples, key, job, n_warm,
                                lambda n: n < n_trace),
-            args.keep_trace, args.workload)
+            names["span_prefixes"], args.keep_trace, args.workload)
         if rows is not None:
             reduced = trace_reduce.reduce_rows(
-                rows, manifest.load_op_categories(root))
+                rows, manifest.load_op_categories(root), names)
     else:
         deadline = time.perf_counter() + args.seconds
         params, stamps, losses, attempted, failed = run_rounds(
@@ -353,11 +385,14 @@ def main(argv=None) -> int:
     say("loss by round: " + " ".join(f"{l:.4f}" for l in all_losses[:12])
         + (f" ... {all_losses[-1]:.4f} (round {len(all_losses)})"
            if len(all_losses) > 12 else ""))
-    later = all_losses[min(11, len(all_losses) - 1)]
-    falling = len(all_losses) >= 3 and later < all_losses[0]
+    # round 12 against round 1: at these learning rates the loss rises
+    # for its first three or four rounds, so a traced run's four to seven
+    # rounds cannot be judged by it and rest on the probe
+    falling = len(all_losses) < 12 or all_losses[11] < all_losses[0]
     correct = bool(probe_ok and failed == 0 and falling)
     if not falling:
-        say(f"loss did not fall: round 1 {all_losses[:1]}, later {later}")
+        say(f"loss did not fall: round 1 {all_losses[0]}, round 12 "
+            f"{all_losses[11]}")
 
     intervals = [b - a for a, b in zip(stamps, stamps[1:])]
     end_to_end = {}
@@ -379,6 +414,11 @@ def main(argv=None) -> int:
             f"{setup_s:.2f} s (imports and files {t_init - _T_PROCESS:.2f}, "
             f"init {init_s:.2f}, probe {probe_s:.2f}, first round "
             f"{first_round_s:.2f})")
+        # a stalled host shows here: the mean above carries it, the
+        # median does not
+        slow = [(i, round(v, 5)) for i, v in enumerate(intervals)
+                if v > 1.01 * end_to_end["round_s"]]
+        say(f"intervals over 1.01 x the median (index, s): {slow or 'none'}")
     else:
         say(f"{attempted} rounds attempted and settled, {len(stamps)} stamped")
 
@@ -386,6 +426,11 @@ def main(argv=None) -> int:
         "compiles_in_window": compiles.n,
         "peak_hbm_bytes": peak_bytes,
         "n_waves": n_waves,
+        # a round's real samples, and the sample slots its clients hold
+        # (clients x capacity); the slots of clients that pad a wave are
+        # the program's to count (baton.round.stage's attributes)
+        "real_samples": int(n_samples.sum()),
+        "sample_slots": job["clients"] * capacity,
     }
     if not tiny:
         counters.update(init_s=init_s, first_round_s=first_round_s)

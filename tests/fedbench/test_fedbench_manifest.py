@@ -1,6 +1,7 @@
 """BENCHMARK.json against the contract's limits, every named file in
 place, and the proof that the harness is driven by data: a copy of the
-tree gains a cell and a layer metric by added files and entries alone."""
+tree gains a cell and a layer metric, and then a whole next-token
+configuration, by added files and entries alone."""
 
 import json
 import os
@@ -22,10 +23,16 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = ["resnet18_c32_w1", "resnet18_c128_w32", "resnet18_c128_mesh4",
          "bert_base_c10_l128"]
+CONFIGS = ["resnet18_cifar10", "bert_base"]
 LAYER_METRICS = ["init_s", "first_round_s", "idle_ms_per_round",
                  "compiles_in_window", "wave_ms", "conv_roofline",
                  "matmul_roofline", "nonwave_device_ms", "collective_ms",
                  "device_idle_share", "peak_hbm_gib"]
+SPAN_AND_SCOPE_METRICS = ["fold_idle_ms", "stage_idle_ms",
+                          "sync_record_idle_ms", "fwd_ms", "bwd_ms",
+                          "optimizer_ms", "norm_ms", "padded_slot_share"]
+FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "fixtures", "next_token")
 
 
 @pytest.fixture(scope="module")
@@ -43,12 +50,24 @@ def test_manifest_has_exactly_the_contract_keys(bench):
 
 
 def test_the_cells_and_metrics_of_issue_22_exist(bench):
-    assert [w["name"] for w in bench["workloads"]] == CELLS
-    assert [c["name"] for c in bench["configs"]] == ["resnet18_cifar10",
-                                                     "bert_base"]
+    """Issue 22's names are present, in order; later names may follow.
+    A new end-to-end metric is a ``benchmark`` PR's business."""
+    assert [w["name"] for w in bench["workloads"]][:len(CELLS)] == CELLS
+    assert [c["name"] for c in bench["configs"]][:len(CONFIGS)] == CONFIGS
     assert [m["name"] for m in bench["end_to_end"]] == [
         "samples_per_s_per_chip", "round_s", "setup_s"]
-    assert [m["name"] for m in bench["per_layer"]] == LAYER_METRICS
+    assert [m["name"] for m in bench["per_layer"]][
+        :len(LAYER_METRICS)] == LAYER_METRICS
+
+
+def test_the_layer_metrics_of_issue_26_exist(bench):
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[len(LAYER_METRICS):][:len(SPAN_AND_SCOPE_METRICS)] == \
+        SPAN_AND_SCOPE_METRICS
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert by_name["norm_ms"]["workloads"] == CELLS[:3]
+    assert {by_name[n]["source"] for n in SPAN_AND_SCOPE_METRICS[:3]} == {
+        "program_span"}
 
 
 def test_names_units_and_whys_are_within_limits(bench):
@@ -77,15 +96,18 @@ def test_bounds_and_chip_counts(bench):
         assert 0.01 <= m["bound"] <= 0.1
     assert {m["name"]: m["bound"] for m in bench["end_to_end"]}[
         "setup_s"] == 0.1
-    four = [w["name"] for w in bench["workloads"] if w["chips"] == 4]
-    assert four == ["resnet18_c128_mesh4"]
-    assert all(w["chips"] in (1, 4) for w in bench["workloads"])
+    cells = bench["workloads"]
+    four = [w["name"] for w in cells if w["chips"] == 4]
+    assert "resnet18_c128_mesh4" in four
+    assert len(four) <= max(1, len(cells) // 4) and len(cells) <= 24
+    assert all(w["chips"] in (1, 4) for w in cells)
 
 
 def test_every_cells_and_configurations_files_exist(bench):
     for c in bench["configs"]:
         assert c["file"].startswith("fedbench/configs/")
         config = manifest.load_config(ROOT, bench, c["name"])
+        assert config["name"] == c["name"]
         assert config["source"] == c["source"]
         assert config["reduced"] == c["reduced"]
         # no width may be named as changed
@@ -95,6 +117,10 @@ def test_every_cells_and_configurations_files_exist(bench):
             assert block in config, (c["name"], block)
         assert hasattr(manifest.load_module(ROOT, "flops", c["name"]),
                        "required")
+        assert hasattr(manifest.load_module(ROOT, "references", c["name"]),
+                       "make_loss")
+        assert hasattr(manifest.load_module(ROOT, "inputs",
+                                            config["input"]["kind"]), "make")
     used = set()
     for w in bench["workloads"]:
         workload = manifest.load_workload(ROOT, w["name"])
@@ -131,21 +157,34 @@ def test_roofline_metrics_follow_the_naming_rule(bench):
             assert m["name"].endswith("_roofline") and m["unit"] == "%"
 
 
-def test_a_cell_and_a_layer_metric_are_added_by_files_alone(tmp_path):
-    """Copy the benchmark, add one workload file, one layer-metric file
-    and their BENCHMARK.json entries; the same harness code lists and
-    loads them. No file that was there is edited."""
+def _copy_of_the_benchmark(tmp_path):
+    """``(root, before)``: a copy of ``fedbench/`` and every file's
+    bytes, to show afterwards that none was edited."""
     root = str(tmp_path / "copy")
     shutil.copytree(os.path.join(ROOT, "fedbench"),
                     os.path.join(root, "fedbench"),
                     ignore=shutil.ignore_patterns("__pycache__", "testdata"))
-    bench = manifest.load_manifest(ROOT)
     before = {}
     for folder, _, files in os.walk(root):
         for f in files:
             path = os.path.join(folder, f)
             with open(path, "rb") as fh:
                 before[path] = fh.read()
+    return root, before
+
+
+def _assert_no_file_was_edited(before):
+    for path, content in before.items():
+        with open(path, "rb") as fh:
+            assert fh.read() == content, f"{path} was edited"
+
+
+def test_a_cell_and_a_layer_metric_are_added_by_files_alone(tmp_path):
+    """Copy the benchmark, add one workload file, one layer-metric file
+    and their BENCHMARK.json entries; the same harness code lists and
+    loads them. No file that was there is edited."""
+    root, before = _copy_of_the_benchmark(tmp_path)
+    bench = manifest.load_manifest(ROOT)
 
     workload = manifest.load_workload(ROOT, "resnet18_c32_w1")
     workload.update(name="resnet18_c64_w16", clients=64, wave_size=16)
@@ -181,9 +220,72 @@ def test_a_cell_and_a_layer_metric_are_added_by_files_alone(tmp_path):
         manifest.metrics_for(seen["per_layer"], "resnet18_c32_w1")]
     reader = manifest.load_module(root, "layer_metrics", "waves_per_round")
     assert reader.read(None, {"n_waves": 4}, {}) == 4
-    for path, content in before.items():
-        with open(path, "rb") as fh:
-            assert fh.read() == content, f"{path} was edited"
+    _assert_no_file_was_edited(before)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_configuration_is_added_by_files_alone(trace, tmp_path, monkeypatch,
+                                                 capsys):
+    """Copy the benchmark and lay ``tests/fedbench/fixtures/next_token``
+    over it: a next-token decoder (labels ``y [n, l]``) with its
+    configuration (an ``engine`` block whose ``trainable`` predicate
+    freezes all but the attention projections, a ``scopes`` block),
+    reference, input kind, FLOP count, cell and a layer metric, plus
+    their BENCHMARK.json entries. ``run.main`` on the copy rehearses the
+    new cell to the contract's line with ``correct`` true, untraced and
+    traced, and no file that was there is edited."""
+    from fedbench import run
+
+    root, before = _copy_of_the_benchmark(tmp_path)
+    added = []
+    for folder, _, files in os.walk(os.path.join(FIXTURE, "fedbench")):
+        for f in files:
+            if f.endswith(".pyc"):
+                continue
+            src = os.path.join(folder, f)
+            dst = os.path.join(root, os.path.relpath(src, FIXTURE))
+            assert dst not in before, f"{dst} would replace a file"
+            shutil.copy(src, dst)
+            added.append(os.path.relpath(dst, root))
+    assert sorted({a.split(os.sep)[1] for a in added}) == [
+        "configs", "flops", "inputs", "layer_metrics", "references",
+        "workloads"]
+    bench = manifest.load_manifest(ROOT)
+    with open(os.path.join(FIXTURE, "entries.json")) as f:
+        for group, entries in json.load(f).items():
+            bench[group] += entries
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    monkeypatch.setattr(manifest, "ROOT", root)
+    rc = run.main(["--workload", "tiny_decoder_c4", "--seed", "5",
+                   "--seconds", "1", "--trace", str(trace), "--rehearse-cpu"])
+    lines = capsys.readouterr().out.strip().splitlines()
+    result = json.loads(lines[-1])
+    assert rc == 0 and result["correct"] is True and result["failed"] == 0
+    assert set(result) == {"correct", "attempted", "failed", "metrics",
+                           "device"}
+    assert any("probe: update disagreement" in l for l in lines)
+    if trace:
+        wanted = {m["name"] for m in manifest.metrics_for(
+            bench["per_layer"], "tiny_decoder_c4")}
+        assert set(result["metrics"]) == wanted
+        assert "norm_ms" not in wanted and "fwd_ms" in wanted
+        assert result["metrics"]["real_samples_per_round"]["value"] == 24
+    else:
+        assert set(result["metrics"]) == {"samples_per_s_per_chip", "round_s",
+                                          "setup_s"}
+    # the configuration's own names reach the reduction of a trace
+    config = manifest.load_config(root, bench, "tiny_decoder")
+    names = manifest.load_trace_names(root, config)
+    assert "lm_head" in names["parts"] and "norm" in names["parts"]
+    assert re.fullmatch(names["blocks"], "layer3")
+    assert re.fullmatch(names["blocks"], "s0b1")
+    # only attention projections are trainable: the engine block arrived
+    engine = manifest.engine_args(config, {})
+    assert engine["trainable"]("blocks/0/attn/wq", None)
+    assert not engine["trainable"]("blocks/0/mlp/w_up", None)
+    _assert_no_file_was_edited(before)
 
 
 def test_unknown_names_are_errors():

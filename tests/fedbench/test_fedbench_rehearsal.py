@@ -61,12 +61,19 @@ def test_traced_rehearsal_reports_counts_only(cell, capsys):
     wanted = {m["name"] for m in
               manifest.metrics_for(BENCH["per_layer"], cell)}
     assert set(result["metrics"]) == wanted
+    sources = {m["name"]: m["source"] for m in BENCH["per_layer"]}
     for name, m in result["metrics"].items():
+        # a CPU trace has host spans and no device plane: whatever is
+        # read from a span, a scope or the device trace is null, and only
+        # a count is written
+        if sources[name] in ("program_span", "device_trace"):
+            assert m["value"] is None, f"a CPU run wrote {name}"
         if units[name] in COUNTS:
             assert m["value"] == 0  # compiles_in_window
         else:
             assert m["value"] is None, f"a CPU run wrote {name}"
-    # a CPU trace has no device plane: no busy time, no breakdown
+    assert {"fold_idle_ms", "fwd_ms", "padded_slot_share"} <= wanted
+    # no device plane: no busy time, no breakdown
     assert "busy_s" not in result["device"] and "breakdown" not in result
 
 

@@ -1,6 +1,8 @@
-"""``fedbench/scope_split.py``: the scope rule, the two splits on rows
-and HLO snippets written by hand, and the reader on a small trace file
-built here with ``xplane_pb2`` (no profiler session)."""
+"""The program's spans and scopes in ``trace_reduce``: the scope rule,
+idle time by host span and the wave program by scope on rows written by
+hand, the reader on a small trace file built here with ``xplane_pb2``
+(no profiler session), the layer metrics that read them; and what is
+left of ``fedbench/scope_split.py``, the printer and the HLO text."""
 
 import json
 import os
@@ -16,6 +18,7 @@ if ROOT not in sys.path:
 from fedbench import manifest, scope_split as ss, trace_reduce as tr  # noqa: E402
 
 RULES = manifest.load_op_categories(ROOT)
+NAMES = manifest.load_trace_names(ROOT)
 DEV0, DEV1 = "/device:TPU:0", "/device:TPU:1"
 TRAIN = ("jit(_wave_sums_vmap)/local_train/vmap(jit(train))/"
          "jit(train_with_opt_state)/while/body/closed_call/")
@@ -58,9 +61,22 @@ STEP = TRAIN + "while/body/closed_call/"
     ("", "other", "other", "(none)"),
 ])
 def test_phase_part_and_block_of_a_scope(scope, phase, part, block):
-    assert ss.phase_of(scope) == phase
-    assert ss.part_of(scope) == part
-    assert ss.block_of(scope) == block
+    assert tr.phase_of(scope, NAMES) == phase
+    assert tr.part_of(scope, NAMES) == part
+    assert tr.block_of(scope, NAMES) == block
+
+
+def test_a_configuration_s_own_scopes_are_laid_over_the_names():
+    names = manifest.load_trace_names(
+        ROOT, {"scopes": {"parts": ["router", "experts"],
+                          "blocks": "layer\\d+"}})
+    scope = STEP + "grad/jvp(layer7)/mlp/experts/dot_general"
+    assert tr.part_of(scope, names) == "experts"
+    assert tr.part_of(scope, NAMES) == "mlp"
+    assert tr.block_of(scope, names) == "layer7"
+    assert tr.block_of(scope, NAMES) == "(none)"
+    assert tr.block_of(STEP + "grad/jvp(s0b1)/conv", names) == "s0b1"
+    assert NAMES["parts"] == manifest.load_trace_names(ROOT)["parts"]
 
 
 @pytest.mark.parametrize("scope,scoped", [
@@ -72,7 +88,7 @@ def test_phase_part_and_block_of_a_scope(scope, phase, part, block):
     ("jit(true_divide)/div", False),
 ])
 def test_is_scoped(scope, scoped):
-    assert ss.is_scoped(scope) is scoped
+    assert tr.is_scoped(scope, NAMES) is scoped
 
 
 HLO = '''HloModule jit__wave_sums_vmap, entry_computation_layout={...}
@@ -171,32 +187,34 @@ def _trace(planes=(DEV0,)):
 
 def test_innermost_span_rule():
     spans = _spans(0)
-    assert ss.innermost(50, spans) == "baton.round.prepare"
-    assert ss.innermost(135, spans) == "baton.round.sync"
-    assert ss.innermost(15, spans) == "baton.round"
-    assert ss.innermost(5, spans) == "fedbench.round"
-    assert ss.innermost(5000, spans) == ss.BETWEEN
+    assert tr.innermost(50, spans) == "baton.round.prepare"
+    assert tr.innermost(135, spans) == "baton.round.sync"
+    assert tr.innermost(15, spans) == "baton.round"
+    assert tr.innermost(5, spans) == "fedbench.round"
+    assert tr.innermost(5000, spans) == tr.BETWEEN
 
 
 def test_window_and_rounds_are_the_harness_s():
     spans = [r for r in _trace() if r["plane"] == tr.HOST_PLANE]
-    assert ss.traced_window(spans) == ((0.0, 2100.0), 2)
-    assert ss.traced_window(spans)[0] == tr.traced_window(
+    assert tr.traced_window(spans) == (0.0, 2100.0)
+    # the program's spans move neither the window nor the round count
+    assert tr.traced_window(spans) == tr.traced_window(
         [r for r in spans if r["name"].startswith("fedbench.")])
-    # a trace of another harness: the program's own round spans
+    assert tr.reduce_rows(_trace(), RULES, NAMES)["n_rounds"] == 2
+    # without the harness's round span there is no window
     own = [r for r in spans if r["name"].startswith("baton.")]
-    assert ss.traced_window(own) == ((10.0, 1990.0), 2)
-    assert ss.traced_window([]) == (None, 0)
+    assert tr.traced_window(own) is None
+    assert tr.traced_window([]) is None
 
 
 def test_idle_by_phase_places_every_gap_and_adds_up():
     rows = _trace()
-    out = ss.split(rows, RULES, {})
-    idle = out["idle_by_phase"][DEV0]
+    out = tr.reduce_rows(rows, RULES, NAMES)
+    idle = out["devices"][DEV0]
     # a round: busy 150-600, 620-700, 800-840; the window ends at 2100.
     # The gap 840-1150 crosses from one round's fold into the next
     # round's sync and is cut at every span edge on the way.
-    ns = {k: v * 1e6 * 2 for k, v in idle["ms_per_round"].items()}
+    ns = {k: v * 1e9 for k, v in idle["idle_by_span_s"].items()}
     want = {
         "baton.round.fold": 2 * (50 + 110),      # 750-800, 840-950
         "baton.round.sync": 2 * (20 + 20 + 20),  # 130-150, 600-620, 700-720
@@ -212,30 +230,38 @@ def test_idle_by_phase_places_every_gap_and_adds_up():
     assert ns == pytest.approx(want)
     total = sum(want.values())
     assert total == 2100 - 2 * (450 + 80 + 40)
-    reduced = tr.reduce_rows(
-        [r for r in rows if not r["name"].startswith("baton.")], RULES)
-    assert idle["idle_ms_per_round"] * 2 / 1e3 == pytest.approx(
-        reduced["devices"][DEV0]["idle_s"])
-    assert idle["share_in_a_span_narrower_than_baton_round"] == \
-        pytest.approx(1 - (40 + 40 + 100) / total)
-    assert idle["share_of_baton_round_idle_in_a_narrower_span"] == \
-        pytest.approx(1 - 40 / (total - 40 - 100))
+    assert idle["idle_s"] * 1e9 == pytest.approx(total)
+    # the busy and idle arithmetic does not see the program's spans
+    without = tr.reduce_rows(
+        [r for r in rows if not r["name"].startswith("baton.")], RULES, NAMES)
+    for key in ("busy_s", "idle_s", "window_s", "longest_gap_s"):
+        assert without["devices"][DEV0][key] == idle[key]
+    assert sum(without["devices"][DEV0]["idle_by_span_s"].values()) * 1e9 \
+        == pytest.approx(total)
+    # the readers: ms a round, sync and record together
+    assert tr.idle_ms_in(out, "baton.round.fold") * 1e6 == pytest.approx(160)
+    assert tr.idle_ms_in(out, "baton.round.sync", "baton.round.record") \
+        * 1e6 == pytest.approx(60 + 30)
+    assert tr.idle_ms_in(out, "baton.round.no_such_span") is None
+    assert tr.idle_ms_in(None, "baton.round.fold") is None
+    assert tr.breakdown(out)["idle_gaps"][0] == [
+        "baton.round.fold", pytest.approx(320e-9)]
 
 
 def test_a_gap_inside_one_span_is_placed_as_reduce_device_places_it():
     spans = _spans(0)
     edges = sorted({t for r in spans
                     for t in (r["start_ns"], r["start_ns"] + r["dur_ns"])})
-    assert list(ss.place((760, 790), spans, edges)) == [
+    assert list(tr.place((760, 790), spans, edges)) == [
         ("baton.round.fold", 30)]
-    assert list(ss.place((700, 800), spans, edges)) == [
+    assert list(tr.place((700, 800), spans, edges)) == [
         ("baton.round.sync", 20), ("baton.round.record", 30),
         ("baton.round.fold", 50)]
 
 
 def test_host_ms_by_phase_is_self_time():
-    out = ss.split(_trace(), RULES, {})["host_ms_by_phase"]
-    ns = {k: v * 1e6 for k, v in out.items()}  # a round
+    out = tr.reduce_rows(_trace(), RULES, NAMES)["host_self_s"]
+    ns = {k: v * 1e9 / 2 for k, v in out.items()}  # a round
     assert ns["baton.round.sync"] == pytest.approx(590)
     assert ns["baton.round.fold"] == pytest.approx(200)
     # baton.round: 980 less its seven children's 960
@@ -245,41 +271,63 @@ def test_host_ms_by_phase_is_self_time():
 
 
 def test_stage_attributes_are_reported():
-    out = ss.split(_trace(), RULES, {})
-    assert out["waves"][0] == {"wave": 0, "real": 3, "padded": 1}
+    out = tr.reduce_rows(_trace(), RULES, NAMES)
+    # summed by span name over the window's two rounds
+    assert out["span_attrs"]["baton.round.stage"] == {
+        "wave": 0, "real": 6, "padded": 2}
+    assert out["span_attrs"]["baton.round"] == {
+        "clients": 8, "waves": 2, "wave_size": 8}
+    assert out["span_runs"]["baton.round.stage"] == 2
+    assert "baton.round.fold" not in out["span_attrs"]
     assert out["n_rounds"] == 2
+    # 3 real clients of 4 in a wave, 18 real samples in 3 x 8 slots
+    reader = manifest.load_module(ROOT, "layer_metrics", "padded_slot_share")
+    counters = {"real_samples": 18, "sample_slots": 24}
+    assert reader.read(out, counters, {}) == pytest.approx(
+        100 * (1 - 18 / (4 * 8)))
+    assert reader.read(out, {}, {}) is None
+    assert reader.read(None, counters, {}) is None
 
 
 def test_wave_by_scope_self_time_under_a_while_and_fusion_by_root():
-    wave = ss.split(_trace(), RULES, {})["wave_by_scope"][DEV0]
-    assert wave["wave_module"] == "jit__wave_sums_vmap"
-    assert wave["wave_runs_per_round"] == 1
-    assert wave["module_ms_per_round"] * 1e6 == pytest.approx(600)
+    reduced = tr.reduce_rows(_trace(), RULES, NAMES)
+    wave = reduced["devices"][DEV0]["wave"]
+    assert wave["module"] == "jit__wave_sums_vmap"
+    assert wave["runs"] == 2
     # the while's 450 ns hold 400 ns of children: 50 ns are its own
-    ns = lambda table: {k: v * 1e6 for k, v in table.items()}  # noqa: E731
-    assert ns(wave["phase_ms"]) == pytest.approx({
+    # (seconds over both executions -> ns an execution)
+    ns = lambda table: {k: v * 1e9 / 2 for k, v in table.items()}  # noqa: E731
+    assert ns(wave["phase_s"]) == pytest.approx({
         "other": 50 + 50,  # the while's own time and the unscoped copy
         "forward": 100, "backward": 200, "optimizer": 50, "wave_sums": 80})
-    assert wave["ops_self_ms_per_round"] * 1e6 == pytest.approx(530)
-    assert ns(wave["phase_x_part_ms"]["backward"]) == pytest.approx(
+    assert wave["self_s"] * 1e9 / 2 == pytest.approx(530)
+    assert ns(wave["phase_part_s"]["backward"]) == pytest.approx(
         {"norm": 200})
-    assert ns(wave["phase_x_part_ms"]["forward"]) == pytest.approx(
+    assert ns(wave["phase_part_s"]["forward"]) == pytest.approx(
         {"conv": 100})
-    assert ns(wave["block_x_phase_ms"]["s0b0"]) == pytest.approx(
+    assert ns(wave["block_phase_s"]["s0b0"]) == pytest.approx(
         {"forward": 100, "backward": 200})
-    assert ns(wave["category_x_part_ms"]["mxu"]) == pytest.approx(
+    assert ns(wave["category_part_s"]["mxu"]) == pytest.approx(
         {"conv": 100})
-    assert ns(wave["category_x_part_ms"]["loop_fusion"]) == pytest.approx(
+    assert ns(wave["category_part_s"]["loop_fusion"]) == pytest.approx(
         {"norm": 200, "other": 50 + 80})
-    assert ns(wave["category_x_part_ms"]["copy"]) == pytest.approx(
+    assert ns(wave["category_part_s"]["copy"]) == pytest.approx(
         {"other": 50})
     # only the copy names none of the program's scopes
-    assert wave["unscoped_share"] == pytest.approx(50 / 530)
-    # the divide runs outside the wave module and is not in its tables
-    assert "jit(true_divide)/div" not in json.dumps(wave)
-    total = sum(v for t in wave["phase_x_part_ms"].values()
-                for v in t.values())
-    assert total == pytest.approx(wave["ops_self_ms_per_round"])
+    assert wave["unscoped_s"] / wave["self_s"] == pytest.approx(50 / 530)
+    # the divide runs outside the wave module: 530 of the device's 570
+    assert sum(wave["phase_s"].values()) == pytest.approx(wave["self_s"])
+    assert reduced["devices"][DEV0]["busy_s"] * 1e9 / 2 == pytest.approx(570)
+    # the readers: ms of one execution
+    assert tr.wave_ms_under(reduced, phase="backward") * 1e6 == \
+        pytest.approx(200)
+    assert tr.wave_ms_under(reduced, part="norm") * 1e6 == pytest.approx(200)
+    assert tr.wave_ms_under(reduced, "forward", "conv") * 1e6 == \
+        pytest.approx(100)
+    assert tr.wave_ms_under(reduced) * 1e6 == pytest.approx(530)
+    assert tr.wave_ms_under(reduced, phase="recompute") is None
+    assert tr.wave_ms_under(reduced, part="attention") is None
+    assert tr.wave_ms_under(None, phase="forward") is None
 
 
 def test_join_fallback_gives_scopeless_events_their_hlo_op_name():
@@ -287,18 +335,17 @@ def test_join_fallback_gives_scopeless_events_their_hlo_op_name():
     for r in rows:
         if r["line"] == tr.OP_LINE:
             r["scope"] = ""  # as read with nothing but JAX
-    none = ss.split(rows, RULES, {})["wave_by_scope"][DEV0]
-    assert none["unscoped_share"] == pytest.approx(1.0)
-    assert set(none["phase_ms"]) == {"other"}
-    joined = ss.split(rows, RULES, ss.scopes_from_hlo(HLO))[
-        "wave_by_scope"][DEV0]
-    ns = {k: v * 1e6 for k, v in joined["phase_ms"].items()}
+    none = tr.reduce_rows(rows, RULES, NAMES)["devices"][DEV0]["wave"]
+    assert none["unscoped_s"] == pytest.approx(none["self_s"])
+    assert set(none["phase_s"]) == {"other"}
     # fusion.887 and convolution_fusion.2 are in the text, by their own
     # (root's) op_name; while.1, copy.5, fusion.9, fusion.10 are not
+    assert ss.join_scopes(rows, ss.scopes_from_hlo(HLO)) == 2 * 2
+    joined = tr.reduce_rows(rows, RULES, NAMES)["devices"][DEV0]["wave"]
+    ns = {k: v * 1e9 / 2 for k, v in joined["phase_s"].items()}
     assert ns == pytest.approx({"forward": 100, "backward": 200,
                                 "other": 50 + 50 + 50 + 80})
-    assert joined["scope_from_hlo_join_share"] == pytest.approx(300 / 530)
-    assert joined["unscoped_share"] == pytest.approx(230 / 530)
+    assert joined["unscoped_s"] / joined["self_s"] == pytest.approx(230 / 530)
 
 
 def test_two_device_planes_are_split_apart():
@@ -306,18 +353,22 @@ def test_two_device_planes_are_split_apart():
     for r in rows:  # the second chip's weighted sum ends 50 ns sooner
         if r["plane"] == DEV1 and r["name"] == "fusion.10":
             r["dur_ns"] = 30.0
-    out = ss.split(rows, RULES, {})
-    assert sorted(out["wave_by_scope"]) == [DEV0, DEV1]
-    assert out["wave_by_scope"][DEV0]["phase_ms"]["wave_sums"] * 1e6 == \
-        pytest.approx(80)
-    assert out["wave_by_scope"][DEV1]["phase_ms"]["wave_sums"] * 1e6 == \
-        pytest.approx(30)
+    out = tr.reduce_rows(rows, RULES, NAMES)
+    assert sorted(out["devices"]) == [DEV0, DEV1]
+    d0, d1 = out["devices"][DEV0], out["devices"][DEV1]
+    assert d0["wave"]["phase_s"]["wave_sums"] * 1e9 / 2 == pytest.approx(80)
+    assert d1["wave"]["phase_s"]["wave_sums"] * 1e9 / 2 == pytest.approx(30)
     # the chip that finishes early idles inside the sync, not elsewhere
-    d0 = out["idle_by_phase"][DEV0]["ms_per_round"]
-    d1 = out["idle_by_phase"][DEV1]["ms_per_round"]
-    assert (d1["baton.round.sync"] - d0["baton.round.sync"]) * 1e6 == \
+    assert (d1["idle_by_span_s"]["baton.round.sync"]
+            - d0["idle_by_span_s"]["baton.round.sync"]) * 1e9 / 2 == \
         pytest.approx(50)
-    assert d1["baton.round.fold"] == pytest.approx(d0["baton.round.fold"])
+    assert d1["idle_by_span_s"]["baton.round.fold"] == pytest.approx(
+        d0["idle_by_span_s"]["baton.round.fold"])
+    # a reader takes the mean over the devices
+    assert tr.wave_ms_under(out, phase="wave_sums") * 1e6 == \
+        pytest.approx((80 + 30) / 2)
+    assert tr.idle_ms_in(out, "baton.round.sync") * 1e6 == \
+        pytest.approx(60 + 50 / 2)
 
 
 @pytest.mark.parametrize("rows", [
@@ -326,16 +377,13 @@ def test_two_device_planes_are_split_apart():
     [r for r in _trace() if r["plane"] != tr.HOST_PLANE],   # no spans
 ])
 def test_a_trace_with_nothing_to_split_says_so(rows):
-    assert "error" in ss.split(rows, RULES, {})
+    assert tr.reduce_rows(rows, RULES, NAMES) is None
 
 
 # ------------------------------------------------- the reader, on a file
 @pytest.fixture(scope="module")
 def xplane_file(tmp_path_factory):
-    pb2 = ss._load_xplane_pb2()
-    if pb2 is None:
-        pytest.skip("no xplane_pb2 imports here")
-    space = pb2.XSpace()
+    space = tr.load_xplane_pb2().XSpace()
 
     def plane_of(name):
         plane = space.planes.add(name=name)
@@ -391,8 +439,7 @@ def xplane_file(tmp_path_factory):
 
 
 def test_read_rows_takes_scope_from_the_event_metadata(xplane_file):
-    rows, source = ss.read_rows(xplane_file)
-    assert source == "tf_op"
+    rows = tr.read_events(xplane_file, NAMES["span_prefixes"])
     assert [r["name"] for r in rows if r["plane"] == tr.HOST_PLANE] == [
         "fedbench.round", "baton.round.stage"]
     stage = next(r for r in rows if r["name"] == "baton.round.stage")
@@ -408,16 +455,35 @@ def test_read_rows_takes_scope_from_the_event_metadata(xplane_file):
     assert not [r for r in rows if r["line"] == "Async XLA Ops"]
 
 
-def test_read_rows_without_xplane_pb2_is_the_same_less_the_scopes(
-        xplane_file, monkeypatch):
-    with_scopes, _ = ss.read_rows(xplane_file)
-    monkeypatch.setattr(ss, "_load_xplane_pb2", lambda: None)
-    rows, source = ss.read_rows(xplane_file)
-    assert source == "none"
-    assert all(r["scope"] == "" for r in rows if r["line"] == tr.OP_LINE)
-    strip = lambda rs: [{k: v for k, v in r.items() if k != "scope"}  # noqa: E731
-                        for r in rs]
-    assert strip(rows) == strip(with_scopes)
+def test_read_events_agrees_with_jax_s_own_reader_less_the_scopes(
+        xplane_file):
+    """``jax.profiler.ProfileData`` shows the same events, names, starts
+    and durations (and a span's attributes), but no metadata stat: the
+    scopes are what ``xplane_pb2`` is read for. Loading it does not
+    import tensorflow."""
+    from jax.profiler import ProfileData
+
+    rows = tr.read_events(xplane_file, NAMES["span_prefixes"])
+    own = []
+    for plane in ProfileData.from_file(xplane_file).planes:
+        for line in plane.lines:
+            if plane.name == tr.HOST_PLANE or line.name in (
+                    tr.MODULE_LINE, tr.OP_LINE):
+                own += [(plane.name, line.name, ev.name, float(ev.start_ns),
+                         float(ev.duration_ns), dict(ev.stats))
+                        for ev in line.events
+                        if plane.name != tr.HOST_PLANE
+                        or ev.name.startswith(tuple(NAMES["span_prefixes"]))]
+    assert [(r["plane"], r["line"],
+             r["name"] if "opcode" not in r else None, r["start_ns"],
+             r["dur_ns"]) for r in rows] == [
+        (p, l, n if l != tr.OP_LINE else None, s, d)
+        for p, l, n, s, d, _ in own]
+    assert [r["stats"] for r in rows if r["plane"] == tr.HOST_PLANE] == [
+        st for p, _, _, _, _, st in own if p == tr.HOST_PLANE]
+    assert tr.read_events(xplane_file, ["fedbench."])[-1]["name"] == \
+        "fedbench.round"
+    assert tr.load_xplane_pb2().__name__ == "fedbench_xplane_pb2"
 
 
 def test_main_prints_one_json_object_last(xplane_file, capsys, tmp_path):
@@ -425,11 +491,13 @@ def test_main_prints_one_json_object_last(xplane_file, capsys, tmp_path):
     hlo.write_text(HLO)
     rc = ss.main(["--trace", xplane_file, "--hlo", str(hlo)])
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rc == 0 and out["scope_source"] == "tf_op" and out["n_rounds"] == 1
-    wave = out["wave_by_scope"][DEV0]
-    assert wave["phase_x_part_ms"]["backward"]["norm"] * 1e6 == \
+    assert rc == 0 and out["n_rounds"] == 1
+    wave = out["devices"][DEV0]["wave"]
+    assert wave["phase_part_s"]["backward"]["norm"] * 1e9 == \
         pytest.approx(400)
-    assert wave["unscoped_share"] == pytest.approx(50 / 450)
+    assert wave["unscoped_s"] / wave["self_s"] == pytest.approx(50 / 450)
+    assert out["span_attrs"]["baton.round.stage"]["real"] == 3
+    assert "op_s" not in out["devices"][DEV0]
     with pytest.raises(SystemExit):
         ss.main([])  # neither --trace nor --hlo-of
 
@@ -440,8 +508,8 @@ def test_hlo_of_builds_the_cell_s_wave_program_as_run_py_does():
     text = ss.hlo_of("bert_base_c10_l128", seed=3, rehearse_cpu=True)
     assert text.startswith("HloModule jit__wave_sums_vmap")
     scopes = set(ss.scopes_from_hlo(text).values())
-    phases = {ss.phase_of(s) for s in scopes}
+    phases = {tr.phase_of(s, NAMES) for s in scopes}
     assert {"forward", "backward", "optimizer", "shuffle",
             "wave_sums"} <= phases
     assert {"attention", "mlp", "norm", "embed", "head"} <= {
-        ss.part_of(s) for s in scopes}
+        tr.part_of(s, NAMES) for s in scopes}

@@ -17,6 +17,7 @@ if ROOT not in sys.path:
 from fedbench import manifest, trace_reduce as tr  # noqa: E402
 
 RULES = manifest.load_op_categories(ROOT)
+NAMES = manifest.load_trace_names(ROOT)
 DEV = "/device:TPU:0"
 
 
@@ -122,7 +123,7 @@ def _hand_rows():
 
 
 def test_reduce_rows_on_a_trace_built_by_hand():
-    reduced = tr.reduce_rows(_hand_rows(), RULES)
+    reduced = tr.reduce_rows(_hand_rows(), RULES, NAMES)
     assert reduced["n_rounds"] == 2
     assert reduced["window_s"] == pytest.approx(2000e-9)
     d = reduced["devices"][DEV]
@@ -137,9 +138,15 @@ def test_reduce_rows_on_a_trace_built_by_hand():
     assert d["collective_exposed_s"] == pytest.approx(2 * 60e-9)
     # the gap from the fold of round 1 to the wave of round 2
     assert d["longest_gap_s"] == pytest.approx(250e-9)
-    assert d["gap_s"] == pytest.approx({
-        "inside fedbench.round": 300e-9,
-        "inside fedbench.sync": 400e-9})
+    # the 250 ns gap from the fold of round 1 to the wave of round 2 is
+    # cut at the span edges it crosses: 50 round, 100 sync, 100 round
+    assert d["idle_by_span_s"] == pytest.approx({
+        "fedbench.round": 500e-9, "fedbench.sync": 200e-9})
+    # rows without scopes: the wave program's time is all 'other', unscoped
+    assert d["wave"]["phase_s"] == pytest.approx({"other": 2 * 600e-9})
+    assert d["wave"]["unscoped_s"] == pytest.approx(d["wave"]["self_s"])
+    assert reduced["span_runs"] == {"fedbench.round": 2, "fedbench.sync": 2}
+    assert reduced["span_attrs"] == {}
     assert tr.wave_module(d) == "jit__wave_sums_vmap"
     top = tr.breakdown(reduced)
     assert top["device_ops"][0] == ["convolution.2 fusion kOutput f32[8]",
@@ -148,7 +155,7 @@ def test_reduce_rows_on_a_trace_built_by_hand():
 
 
 def test_layer_metric_readers_on_the_hand_trace():
-    reduced = tr.reduce_rows(_hand_rows(), RULES)
+    reduced = tr.reduce_rows(_hand_rows(), RULES, NAMES)
     cell = {"required": {"kernel": "conv", "kernel_flops_per_round": 30.0,
                          "kernel_bytes_per_round": 1.0},
             "peaks": {"flops_per_s_bf16": 1e9, "hbm_bytes_per_s": 1e9}}
@@ -169,9 +176,10 @@ def test_layer_metric_readers_on_the_hand_trace():
 
 def test_a_trace_without_device_planes_reduces_to_nothing():
     spans = [r for r in _hand_rows() if r["plane"] == tr.HOST_PLANE]
-    assert tr.reduce_rows(spans, RULES) is None
+    assert tr.reduce_rows(spans, RULES, NAMES) is None
     assert tr.reduce_rows([r for r in _hand_rows()
-                           if r["plane"] != tr.HOST_PLANE], RULES) is None
+                           if r["plane"] != tr.HOST_PLANE], RULES,
+                          NAMES) is None
 
 
 # ----------------------------------------------- the reader, on a CPU trace
@@ -196,7 +204,7 @@ def test_reader_walks_a_trace_recorded_here(tmp_path):
     found = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
                       recursive=True)
     assert len(found) == 1
-    rows = tr.read_events(found[0])
+    rows = tr.read_events(found[0], NAMES["span_prefixes"])
     names = [r["name"] for r in rows]
     assert names.count("fedbench.round") == 2
     assert names.count("fedbench.sync") == 2
@@ -204,7 +212,7 @@ def test_reader_walks_a_trace_recorded_here(tmp_path):
     window = tr.traced_window(rows)
     assert window[1] > window[0]
     # the CPU has no device plane: nothing for a device metric to read
-    assert tr.reduce_rows(rows, RULES) is None
+    assert tr.reduce_rows(rows, RULES, NAMES) is None
     path = str(tmp_path / "rows.json.gz")
     tr.write_rows(rows, path)
     assert tr.load_rows(path) == rows
@@ -221,7 +229,7 @@ def chip_reduced():
     PR 22): the rows ``read_events`` took from the ``.xplane.pb`` (6.9 MB
     for five rounds, so the event table is committed instead), cut to
     the first two rounds and rebased to the window's start."""
-    return tr.reduce_rows(tr.load_rows(CHIP_ROWS), RULES)
+    return tr.reduce_rows(tr.load_rows(CHIP_ROWS), RULES, NAMES)
 
 
 def test_chip_trace_busy_idle_and_window(chip_reduced):
@@ -233,11 +241,14 @@ def test_chip_trace_busy_idle_and_window(chip_reduced):
     assert d["idle_s"] == pytest.approx(0.050210764, rel=1e-9)
     assert d["busy_s"] + d["idle_s"] == pytest.approx(d["window_s"], rel=1e-12)
     assert d["longest_gap_s"] == pytest.approx(0.004419947, rel=1e-9)
-    assert d["gap_s"] == pytest.approx({
-        "inside fedbench.round": 0.048393073,
-        "between fedbench spans": 0.001533411,
-        "inside fedbench.sync": 0.00028428}, rel=1e-7)
-    assert sum(d["gap_s"].values()) == pytest.approx(d["idle_s"], rel=1e-9)
+    # each gap cut at the span edges it crosses (until PR 26 a whole gap
+    # went to the span its midpoint fell in: 48.393, 1.533, 0.284 ms)
+    assert d["idle_by_span_s"] == pytest.approx({
+        "fedbench.round": 0.047427998,
+        tr.BETWEEN: 0.002443997,
+        "fedbench.sync": 0.000338769}, rel=1e-7)
+    assert sum(d["idle_by_span_s"].values()) == pytest.approx(d["idle_s"],
+                                                              rel=1e-9)
 
 
 def test_chip_trace_modules(chip_reduced):
