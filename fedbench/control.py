@@ -36,7 +36,8 @@ def readings(root: str, cell: str, seed: int, tiny: bool) -> dict:
     job = run.job_of(manifest.load_workload(root, cell), tiny)
     _, params, _, _, _, mesh, sim = run.build_cell(
         root, config, job, entry["chips"], seed, tiny)
-    _, program = run.probe(root, config, job, tiny, seed, sim, params, mesh)
+    _, compared = run.probe(root, config, job, tiny, seed, sim, params, mesh)
+    program = {name: value for name, (value, _) in compared.items()}
     pdata, sizes = run.probe_cohort(root, config, job, tiny, seed)
     make_loss = manifest.load_module(
         root, "references", entry["config"]).make_loss
@@ -48,7 +49,8 @@ def readings(root: str, cell: str, seed: int, tiny: bool) -> dict:
                    make_loss(sizes_of, reference.rounded_to(
                        jnp.float8_e4m3fn)))]
     control = {norm: reference.update_disagreement(
-        params, rounds[1], rounds[0], norm) for norm in ("max", "l2")}
+        params, rounds[1], rounds[0], norm, trainable)
+        for norm in ("max", "l2")}
     jax.clear_caches()  # every seed builds its programs anew: let them go
     return {"program": program, "control": control}
 

@@ -52,21 +52,70 @@ def _path(key_path) -> str:
         k, "name", k)))) for k in key_path)
 
 
+def accepted(params, trainable=None) -> list:
+    """One flag a leaf of ``params``, in ``tree_leaves`` order: whether
+    ``trainable(path, leaf)`` accepts it. All true without a predicate."""
+    with_path = jax.tree_util.tree_flatten_with_path(params)[0]
+    return [trainable is None or bool(trainable(_path(path), leaf))
+            for path, leaf in with_path]
+
+
+def _chosen(tree, flags, keep: bool = True) -> list:
+    """The leaves of ``tree`` whose flag is ``keep``, as a list."""
+    return [leaf for leaf, flag in zip(jax.tree_util.tree_leaves(tree), flags)
+            if flag is keep]
+
+
+def trainable_grad(loss, params, trainable=None):
+    """``(grad, moving, held, whole)``: the leaves of ``params`` that
+    ``trainable`` accepts and rejects, as two lists; ``whole(moving,
+    held)``, ``params``' tree put together again from two such lists; and
+    the jitted ``grad(moving, held, x, y, mask) -> (loss, d loss / d
+    moving)``. ``held`` is an argument that is not differentiated: no
+    gradient of a rejected leaf is computed, none is returned, and a
+    rejected leaf enters the loss in the dtype it came in."""
+    flags = accepted(params, trainable)
+    treedef = jax.tree_util.tree_structure(params)
+
+    def whole(moving, held):
+        moving, held = iter(moving), iter(held)
+        return jax.tree_util.tree_unflatten(
+            treedef, [next(moving if flag else held) for flag in flags])
+
+    grad = jax.jit(jax.value_and_grad(
+        lambda moving, held, x, y, mask: loss(whole(moving, held), x, y,
+                                              mask)))
+    return grad, _chosen(params, flags), _chosen(params, flags, False), whole
+
+
 def reference_round(loss, params, data, n_samples, learning_rate,
                     trainable=None):
-    """New global parameters (float32, same tree as ``params``) after one
-    round of one local SGD step a client, and the sample-weighted mean
-    loss before the step. ``loss(params, x, y, mask)`` is the
-    configuration's plain loss. Where the cell's ``engine`` block holds a
-    ``trainable(path, leaf)`` predicate, a leaf it rejects is returned as
-    it came.
+    """New global parameters (same tree as ``params``) after one round of
+    one local SGD step a client, and the sample-weighted mean loss
+    before the step. ``loss(params, x, y, mask)`` is the configuration's
+    plain loss.
+
+    Where the cell's ``engine`` block holds a ``trainable(path, leaf)``
+    predicate, the round is over the leaves it accepts alone: the tree is
+    split by it first, the gradient is taken with respect to the
+    accepted leaves (``trainable_grad``), and only they are stepped and
+    averaged. An accepted leaf comes back as a new float32 array; a
+    rejected leaf comes back as the very array that came in, not cast
+    and not copied. So the round's memory beyond the parameters and the
+    loss's own activations is three float32 copies of the *trainable*
+    part (one gradient and two running means, the new one made while
+    the old is alive), whatever the frozen part weighs. The loss's own
+    activations are the reference file's business: over a frozen base
+    held in bfloat16 it has to cast each layer's weights where it uses
+    them, since two float32 copies of the base (forward and backward) do
+    not fit beside it either.
 
     The step and the running weighted mean are float32 ``jax.numpy``
     sums on the device, one client after the other. (In NumPy on the
     host they cost BERT-base 3.5 GB of transfers and half a minute of
     every run's set-up; elementwise float32 is exact on the TPU, only
     matrix products need ``precision="highest"``.)"""
-    grad = jax.jit(jax.value_and_grad(loss))
+    grad, moving, held, whole = trainable_grad(loss, params, trainable)
 
     @jax.jit
     def add_stepped(mean, p, g, w):
@@ -78,43 +127,55 @@ def reference_round(loss, params, data, n_samples, learning_rate,
     n_samples = np.asarray(n_samples)
     capacity = data["x"].shape[1]
     total = float(n_samples.sum())
-    mean = jax.tree_util.tree_map(
-        lambda a: jnp.zeros(a.shape, jnp.float32), params)
+    mean = [jnp.zeros(a.shape, jnp.float32) for a in moving]
     mean_loss = 0.0
     with jax.default_matmul_precision("highest"):
         for c, n in enumerate(n_samples):
             mask = jnp.asarray(np.arange(capacity) < n, jnp.float32)
-            l, g = grad(params, data["x"][c], data["y"][c], mask)
-            mean = add_stepped(mean, params, g, float(n) / total)
+            l, g = grad(moving, held, data["x"][c], data["y"][c], mask)
+            mean = add_stepped(mean, moving, g, float(n) / total)
             mean_loss += float(n) / total * float(l)
-    if trainable is not None:
-        mean = jax.tree_util.tree_map_with_path(
-            lambda path, m, a: m if trainable(_path(path), a)
-            else a.astype(jnp.float32), mean, params)
-    return mean, mean_loss
+    return whole(mean, held), mean_loss
+
+
+def held_unchanged(before, after, trainable) -> tuple:
+    """``(same, held)``: how many of the leaves that ``trainable``
+    rejects are in ``after`` what they were in ``before``, exactly and in
+    their own dtype, and how many it rejects."""
+    flags = accepted(before, trainable)
+    pairs = list(zip(_chosen(before, flags, False),
+                     _chosen(after, flags, False)))
+    alike = [(a, b) for a, b in pairs
+             if a.shape == b.shape and a.dtype == b.dtype]
+    same = jax.jit(lambda ab: [jnp.all(a == b) for a, b in ab])(alike)
+    return sum(bool(s) for s in same), len(pairs)
 
 
 @jax.jit
 def _gaps(before, got, want):
-    """Over all leaves: the largest entry and the sum of squares of
-    ``got - want`` and of ``want - before``."""
+    """Over three lists of leaves: the largest entry and the sum of
+    squares of ``got - want`` and of ``want - before``."""
     def measure(a, b):
         diffs = [x.astype(jnp.float32) - y.astype(jnp.float32)
-                 for x, y in zip(jax.tree_util.tree_leaves(a),
-                                 jax.tree_util.tree_leaves(b))]
+                 for x, y in zip(a, b)]
         return (jnp.max(jnp.stack([jnp.max(jnp.abs(d)) for d in diffs])),
                 sum(jnp.sum(d * d) for d in diffs))
 
     return measure(got, want), measure(want, before)
 
 
-def update_disagreement(before, got, want, norm: str = "max") -> float:
+def update_disagreement(before, got, want, norm: str = "max",
+                        trainable=None) -> float:
     """How far two rounds from the same parameters disagree, relative to
     the wanted update: ``"max"``, max |got - want| over max |want -
     before| (the largest entry); ``"l2"``, the same ratio of the
     Euclidean norms over all parameters, which swings less from seed to
-    seed. Infinite where ``got`` is not finite."""
-    (gap_max, gap_sq), (scale_max, scale_sq) = _gaps(before, got, want)
+    seed. With a ``trainable`` predicate, over the leaves it accepts
+    (``held_unchanged`` holds the others). Infinite where ``got`` is not
+    finite."""
+    flags = accepted(before, trainable)
+    (gap_max, gap_sq), (scale_max, scale_sq) = _gaps(
+        *(_chosen(tree, flags) for tree in (before, got, want)))
     gap, scale = ((float(gap_max), float(scale_max)) if norm == "max"
                   else (float(gap_sq) ** 0.5, float(scale_sq) ** 0.5))
     if not scale > 0:

@@ -8,10 +8,10 @@ learned position embeddings (no segment table, no embedding LayerNorm,
 no dropout); pre-LN blocks, ``x + attention(LN(x))`` then ``x +
 MLP(LN(x))``, of softmax attention over ``num_attention_heads`` heads and
 a GELU feed-forward; a final LayerNorm; a tanh pooler on the first token
-and a linear head. As in the program, and not listed in ``reduced``: the
-four attention projections carry no bias, LayerNorm's eps is 1e-6, GELU
-is its tanh approximation. Imports nothing of ``baton_tpu``; no ``vmap``,
-no ``custom_vjp``, no kernel.
+and a linear head; the four attention projections carry no bias,
+LayerNorm's eps is 1e-6 and GELU is its tanh approximation
+(``attention_bias``, ``layer_norm_eps``, ``hidden_act`` there). Imports
+nothing of ``baton_tpu``; no ``vmap``, no ``custom_vjp``, no kernel.
 """
 
 import math

@@ -10,8 +10,10 @@ warms up the cell's own shapes, then measures: with ``--trace 0`` a
 window of ``--seconds`` giving the end-to-end metrics, with ``--trace
 1`` a few rounds under ``jax.profiler.trace`` giving the layer metrics
 and the breakdown. The last line of standard output is one JSON object
-(``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and,
-traced, ``breakdown``); everything before it is commentary.
+(``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, traced
+also ``breakdown``, and last ``compared``: each number that decided
+``correct`` beside its limit, which are also the last lines of standard
+error); everything before it is commentary.
 
 Without ``--rehearse-cpu`` the run needs a TPU with at least the cell's
 chips and otherwise exits non-zero with no result. ``--rehearse-cpu``
@@ -29,6 +31,7 @@ import argparse  # noqa: E402
 import collections  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import statistics  # noqa: E402
@@ -201,13 +204,16 @@ def build_cell(root, config, job, chips, seed, tiny):
 # ------------------------------------------------------------------- probe
 def probe_cohort(root, config, job, tiny, seed):
     """``(data, sizes)``: the seeded probe cohort, 4 clients holding 1/4,
-    2/4, 3/4 and 4/4 of one batch of the cell's inputs."""
+    2/4, 3/4 and 4/4 of one batch of the cell's inputs, rounded down, and
+    no client fewer than one sample: 1, 1, 2, 3 at batch 3, and at batch
+    1 four clients of one sample each, whose four weights are equal."""
     import numpy as np
 
     from fedbench import data as cohort
 
     batch = job["batch"]
-    sizes = np.asarray([batch * k // 4 for k in (1, 2, 3, 4)], np.int32)
+    sizes = np.asarray([max(1, batch * k // 4) for k in (1, 2, 3, 4)],
+                       np.int32)
     return cohort.make_cohort(
         root, manifest.input_spec(config, tiny), sizes, batch,
         job.get("seq_len"), cohort.data_key(seed + 7919)), sizes
@@ -218,7 +224,11 @@ def probe(root, config, job, tiny, seed, sim, params, mesh):
     cohort through ``FedSim.run_round`` in the cell's layout against the
     configuration's plain reference (``fedbench/references/<config>.py``),
     and on a mesh against the same round on one device, all from the
-    run's initial ``params``. Returns ``(ok, {name: disagreement})``."""
+    run's initial ``params``. Where the cell's ``engine`` block holds a
+    ``trainable`` predicate the disagreements are over the leaves it
+    accepts, and every leaf it rejects has to come out of the round
+    exactly as it went in. Returns ``(ok, {name: (number compared, its
+    limit)})``; a limit of ``None`` is a number printed and not held."""
     import jax
 
     from baton_tpu.parallel.engine import FedSim
@@ -238,30 +248,36 @@ def probe(root, config, job, tiny, seed, sim, params, mesh):
     # a mesh round's parameters are replicated; compare on one device
     got_params = jax.device_put(got.params, jax.devices()[0])
     engine = manifest.engine_args(config, job)
+    trainable = engine.get("trainable")
     loss = manifest.load_module(root, "references", config["name"]
                                 ).make_loss(manifest.sized(config, tiny))
     want, want_loss = reference.reference_round(
-        loss, params, pdata, sizes, lr, engine.get("trainable"))
+        loss, params, pdata, sizes, lr, trainable)
     found = {"reference": reference.update_disagreement(
-        params, got_params, want),
+        params, got_params, want, trainable=trainable),
         "reference_l2": reference.update_disagreement(
-            params, got_params, want, "l2")}
+            params, got_params, want, "l2", trainable)}
     loss_gap = abs(float(got.loss_history[-1]) - want_loss)
     if mesh is not None:
         one = one_round(FedSim(sim.model, batch_size=batch, learning_rate=lr,
                                mesh=None, **engine), pdata)
         found["one_device"] = reference.update_disagreement(
-            params, got_params, one.params)
+            params, got_params, one.params, trainable=trainable)
     tol = config["probe_tolerance"]
-    limits = dict.fromkeys(found, tol)
-    limits["reference_l2"] = config.get("probe_l2_tolerance")
-    ok = loss_gap <= tol and all(
-        limits[k] is None or v <= limits[k] for k, v in found.items())
-    say("probe: update disagreement " + ", ".join(
-        f"{k} {v:.4g} (limit {limits[k]})" for k, v in found.items())
-        + f"; loss gap {loss_gap:.3g} (limit {tol}): "
-        f"{'ok' if ok else 'FAILED'}")
-    return ok, found
+    compared = {k: (v, tol) for k, v in found.items()}
+    compared["reference_l2"] = (found["reference_l2"],
+                                config.get("probe_l2_tolerance"))
+    said = "probe: update disagreement " + ", ".join(
+        f"{k} {v:.4g} (limit {limit})" for k, (v, limit) in compared.items())
+    compared["loss_gap"] = (loss_gap, tol)
+    said += f"; loss gap {loss_gap:.3g} (limit {tol})"
+    if trainable is not None:
+        same, n_held = reference.held_unchanged(params, got_params, trainable)
+        compared["frozen_leaves_changed"] = (n_held - same, 0)
+        said += f"; frozen leaves unchanged: {same} of {n_held}"
+    ok = all(limit is None or v <= limit for v, limit in compared.values())
+    say(f"{said}: {'ok' if ok else 'FAILED'}")
+    return ok, compared
 
 
 # -------------------------------------------------------------------- main
@@ -288,7 +304,6 @@ def main(argv=None) -> int:
     chips = entry["chips"]
 
     import jax
-    import numpy as np
 
     devices = jax.devices()
     platform, kind = devices[0].platform, devices[0].device_kind
@@ -323,8 +338,8 @@ def main(argv=None) -> int:
     n_waves = -(-job["clients"] // (job["wave_size"] or job["clients"]))
     required = manifest.load_module(root, "flops", entry["config"]).required(
         config, dict(job, n_samples=[int(n) for n in n_samples]))
-    n_params = sum(int(np.prod(a.shape))
-                   for a in jax.tree_util.tree_leaves(params))
+    n_params = sum(a.size for a in jax.tree_util.tree_leaves(params))
+    param_bytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(params))
     say(f"{model.name}: {n_params:,} parameters; {job['clients']} clients, "
         f"{int(n_samples.sum())} real samples in {job['clients'] * capacity} "
         f"slots, batch {job['batch']}, {job['local_epochs']} epoch(s), "
@@ -333,8 +348,14 @@ def main(argv=None) -> int:
 
     # ---- correctness probe, outside the window
     t_probe = time.perf_counter()
-    probe_ok, _ = probe(root, config, job, tiny, args.seed, sim, params, mesh)
+    probe_ok, compared = probe(root, config, job, tiny, args.seed, sim,
+                               params, mesh)
     probe_s = time.perf_counter() - t_probe
+    probe_peak = max(((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                      for d in used), default=0)
+    say(f"after the probe: allocator peak_bytes_in_use {probe_peak / 2**30:.3f}"
+        f" GiB on the fullest device (0 where the backend keeps no "
+        f"statistics); the parameters are {param_bytes / 2**30:.3f} GiB")
 
     # ---- warm-up: the cell's own shapes, first round compiles or loads
     key = jax.random.key(args.seed + 2)
@@ -390,9 +411,10 @@ def main(argv=None) -> int:
     # rounds cannot be judged by it and rest on the probe
     falling = len(all_losses) < 12 or all_losses[11] < all_losses[0]
     correct = bool(probe_ok and failed == 0 and falling)
-    if not falling:
-        say(f"loss did not fall: round 1 {all_losses[0]}, round 12 "
-            f"{all_losses[11]}")
+    compared["failed_rounds"] = (failed, 0)
+    if len(all_losses) >= 12:
+        # has to lie below its limit, round 1's loss, and not on it
+        compared["round_12_loss"] = (all_losses[11], all_losses[0])
 
     intervals = [b - a for a, b in zip(stamps, stamps[1:])]
     end_to_end = {}
@@ -460,6 +482,17 @@ def main(argv=None) -> int:
         result["breakdown"] = trace_reduce.breakdown(reduced)
         for line in trace_reduce.commentary(reduced):
             say(line)
+    # what decided ``correct``, each number beside its limit: the last
+    # lines of standard error and the last key of the result's line,
+    # which is all the driver keeps of a run that came out not correct
+    for name, (value, limit) in compared.items():
+        print(f"fedbench compared: {name} {value} (limit {limit})",
+              file=sys.stderr, flush=True)
+    # JSON has no word for a number that is not finite
+    result["compared"] = {
+        name: {"value": value if math.isfinite(value) else str(value),
+               "limit": limit}
+        for name, (value, limit) in compared.items()}
     print(json.dumps(result), flush=True)
     return 0
 

@@ -34,6 +34,39 @@ SPAN_AND_SCOPE_METRICS = ["fold_idle_ms", "stage_idle_ms",
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "fixtures", "next_token")
 
+# What ``reduced`` may never name: a width. A hidden, intermediate,
+# latent, state, projection or window size, a head size, a key that ends
+# in ``_dim``, ``_rank`` or ``_width``, an expansion factor, the experts
+# a token is routed to. Depth, counts of heads or of experts held here
+# and the rows of the vocabulary may be the chip's share (the
+# ``model-configs`` guide, section 4) and pass.
+WIDTH_KEYS = {"d_model", "d_ff", "d_head", "d_state", "d_conv", "expand",
+              "sliding_window", "kv_channels", "top_k"}
+WIDTH_ENDINGS = ("_dim", "_rank", "_width", "head_dim", "headdim",
+                 "hidden_size", "intermediate_size", "latent_size",
+                 "state_size", "projection_size", "window_size", "_d_state",
+                 "_expand", "expansion_factor", "per_tok", "per_token",
+                 "_top_k", "_topk")
+WIDTHS = ["hidden_size", "intermediate_size", "moe_intermediate_size",
+          "head_dim", "linear_key_head_dim", "linear_value_head_dim",
+          "qk_rope_head_dim", "v_head_dim", "kv_lora_rank", "q_lora_rank",
+          "linear_conv_kernel_dim", "stem_width", "sliding_window",
+          "window_size", "ssm_state_size", "mamba_d_state", "mamba_expand",
+          "mlp_expansion_factor", "num_experts_per_tok", "moe_top_k",
+          "ffn_hidden_size", "moe_latent_size"]
+NOT_WIDTHS = ["layers", "num_hidden_layers", "num_layers",
+              "num_attention_heads", "num_key_value_heads",
+              "linear_num_key_heads", "linear_num_value_heads",
+              "n_routed_experts", "num_experts", "num_local_experts",
+              "vocab_size", "hidden_act", "attention_bias", "layer_norm_eps",
+              "max_window_layers", "first_k_dense_replace"]
+
+
+def widths_named(reduced: list) -> list:
+    """The keys of a ``reduced`` list that name a width: none may."""
+    return [key for key in reduced
+            if key in WIDTH_KEYS or key.endswith(WIDTH_ENDINGS)]
+
 
 @pytest.fixture(scope="module")
 def bench():
@@ -111,8 +144,7 @@ def test_every_cells_and_configurations_files_exist(bench):
         assert config["source"] == c["source"]
         assert config["reduced"] == c["reduced"]
         # no width may be named as changed
-        for key in c["reduced"]:
-            assert not re.search(r"(_dim|_rank|hidden|intermediate|head)", key)
+        assert widths_named(c["reduced"]) == []
         for block in ("builder", "input", "tiny", "assumed"):
             assert block in config, (c["name"], block)
         assert hasattr(manifest.load_module(ROOT, "flops", c["name"]),
@@ -131,6 +163,15 @@ def test_every_cells_and_configurations_files_exist(bench):
         assert hasattr(manifest.load_module(ROOT, "cohorts", kind), "sizes")
         used.add(w["config"])
     assert used == {c["name"] for c in bench["configs"]}
+
+
+@pytest.mark.parametrize("key,width", [(k, True) for k in WIDTHS]
+                         + [(k, False) for k in NOT_WIDTHS])
+def test_reduced_refuses_widths_and_nothing_else(key, width):
+    """Among the cuts the guide allows, a width is still refused, and
+    one more key that is no width is not."""
+    cut = ["num_hidden_layers", "num_attention_heads", "vocab_size"]
+    assert widths_named(cut + [key]) == ([key] if width else [])
 
 
 def test_every_layer_metric_names_its_layer_cells_and_target(bench):
@@ -223,19 +264,10 @@ def test_a_cell_and_a_layer_metric_are_added_by_files_alone(tmp_path):
     _assert_no_file_was_edited(before)
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-def test_a_configuration_is_added_by_files_alone(trace, tmp_path, monkeypatch,
-                                                 capsys):
-    """Copy the benchmark and lay ``tests/fedbench/fixtures/next_token``
-    over it: a next-token decoder (labels ``y [n, l]``) with its
-    configuration (an ``engine`` block whose ``trainable`` predicate
-    freezes all but the attention projections, a ``scopes`` block),
-    reference, input kind, FLOP count, cell and a layer metric, plus
-    their BENCHMARK.json entries. ``run.main`` on the copy rehearses the
-    new cell to the contract's line with ``correct`` true, untraced and
-    traced, and no file that was there is edited."""
-    from fedbench import run
-
+def _copy_with_the_fixture(tmp_path):
+    """``(root, before, bench)``: a copy of the benchmark with
+    ``tests/fedbench/fixtures/next_token`` laid over it, files and
+    BENCHMARK.json entries; no file of the copy is replaced."""
     root, before = _copy_of_the_benchmark(tmp_path)
     added = []
     for folder, _, files in os.walk(os.path.join(FIXTURE, "fedbench")):
@@ -256,16 +288,43 @@ def test_a_configuration_is_added_by_files_alone(trace, tmp_path, monkeypatch,
             bench[group] += entries
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
+    return root, before, bench
+
+
+def _rehearse(root, cell, seed, trace, monkeypatch, capsys):
+    """``(rc, lines, result)`` of ``run.main`` on the tree at ``root``."""
+    from fedbench import run
 
     monkeypatch.setattr(manifest, "ROOT", root)
-    rc = run.main(["--workload", "tiny_decoder_c4", "--seed", "5",
-                   "--seconds", "1", "--trace", str(trace), "--rehearse-cpu"])
+    rc = run.main(["--workload", cell, "--seed", str(seed), "--seconds", "1",
+                   "--trace", str(trace), "--rehearse-cpu"])
     lines = capsys.readouterr().out.strip().splitlines()
-    result = json.loads(lines[-1])
+    return rc, lines, json.loads(lines[-1])
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_configuration_is_added_by_files_alone(trace, tmp_path, monkeypatch,
+                                                 capsys):
+    """Copy the benchmark and lay ``tests/fedbench/fixtures/next_token``
+    over it: a next-token decoder (labels ``y [n, l]``) with its
+    configuration (an ``engine`` block whose ``trainable`` predicate
+    freezes all but the attention projections, a ``scopes`` block),
+    reference, input kind, FLOP count, cells and a layer metric, plus
+    their BENCHMARK.json entries. ``run.main`` on the copy rehearses the
+    new cell to the contract's line with ``correct`` true, untraced and
+    traced, and no file that was there is edited."""
+    root, before, bench = _copy_with_the_fixture(tmp_path)
+    rc, lines, result = _rehearse(root, "tiny_decoder_c4", 5, trace,
+                                  monkeypatch, capsys)
     assert rc == 0 and result["correct"] is True and result["failed"] == 0
     assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
-    assert any("probe: update disagreement" in l for l in lines)
+                           "device", "compared"}
+    # 2 layers of 9 leaves, two tables and a norm; 8 projections train
+    assert any("probe: update disagreement" in l
+               and "frozen leaves unchanged: 13 of 13: ok" in l
+               for l in lines)
+    assert result["compared"]["frozen_leaves_changed"] == {"value": 0,
+                                                           "limit": 0}
     if trace:
         wanted = {m["name"] for m in manifest.metrics_for(
             bench["per_layer"], "tiny_decoder_c4")}
@@ -286,6 +345,60 @@ def test_a_configuration_is_added_by_files_alone(trace, tmp_path, monkeypatch,
     assert engine["trainable"]("blocks/0/attn/wq", None)
     assert not engine["trainable"]("blocks/0/mlp/w_up", None)
     _assert_no_file_was_edited(before)
+
+
+def test_the_fixture_rehearses_at_batch_1(tmp_path, monkeypatch, capsys):
+    """A decoder of 7 B's widths trains at one or two sequences a batch:
+    the fixture's second cell has batch 1, where a quarter of a batch is
+    no sample. The probe cohort is then four clients of one sample each,
+    none empty, and the run ends ``correct``."""
+    from fedbench import run
+
+    root, before, bench = _copy_with_the_fixture(tmp_path)
+    rc, lines, result = _rehearse(root, "tiny_decoder_c4_b1", 6, 0,
+                                  monkeypatch, capsys)
+    assert rc == 0 and result["correct"] is True and result["failed"] == 0
+    assert any("frozen leaves unchanged: 13 of 13: ok" in l for l in lines)
+    assert all(c["value"] == c["value"] for c in result["compared"].values())
+    config = manifest.load_config(root, bench, "tiny_decoder")
+    job = run.job_of(manifest.load_workload(root, "tiny_decoder_c4_b1"), True)
+    data, sizes = run.probe_cohort(root, config, job, True, 6)
+    assert list(sizes) == [1, 1, 1, 1] and data["x"].shape[:2] == (4, 1)
+    _assert_no_file_was_edited(before)
+
+
+def test_a_changed_frozen_leaf_is_not_correct(tmp_path, monkeypatch, capsys):
+    """The timed path broken underneath: ``FedSim.run_round`` trains as it
+    should and hands back one leaf that the ``trainable`` predicate
+    rejects with one entry moved by one step of its own dtype. No
+    disagreement over the trainable leaves sees it; the probe's count of
+    frozen leaves does, and ``correct`` is false."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from baton_tpu.parallel.engine import FedSim
+
+    sound = FedSim.run_round
+
+    def nudged(self, params, *args, **kwargs):
+        res = sound(self, params, *args, **kwargs)
+        table = res.params["tok_emb"]
+        moved = dict(res.params, tok_emb=table.at[0, 0].set(
+            jnp.nextafter(table[0, 0], jnp.inf)))
+        return dataclasses.replace(res, params=moved)
+
+    root, _, _ = _copy_with_the_fixture(tmp_path)
+    monkeypatch.setattr(FedSim, "run_round", nudged)
+    rc, lines, result = _rehearse(root, "tiny_decoder_c4", 7, 0, monkeypatch,
+                                  capsys)
+    assert rc == 0 and result["correct"] is False and result["failed"] == 0
+    assert any("frozen leaves unchanged: 12 of 13: FAILED" in l
+               for l in lines)
+    compared = result["compared"]
+    assert compared["frozen_leaves_changed"] == {"value": 1, "limit": 0}
+    assert all(compared[k]["value"] <= compared[k]["limit"]
+               for k in ("reference", "loss_gap"))
 
 
 def test_unknown_names_are_errors():

@@ -21,8 +21,15 @@ COUNTS = {"count"}  # units of metrics a CPU run may report
 
 
 def _last_line(capsys):
-    lines = capsys.readouterr().out.strip().splitlines()
-    return lines, json.loads(lines[-1])
+    captured = capsys.readouterr()
+    lines = captured.out.strip().splitlines()
+    result = json.loads(lines[-1])
+    # the same numbers are the last lines of standard error
+    said = captured.err.strip().splitlines()[-len(result["compared"]):]
+    assert [l.split()[2] for l in said] == list(result["compared"])
+    assert all(l.startswith("fedbench compared: ") and "(limit " in l
+               for l in said)
+    return lines, result
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -31,10 +38,16 @@ def test_rehearsal_ends_in_the_contracts_line(cell, capsys):
                    "--trace", "0", "--rehearse-cpu"])
     lines, result = _last_line(capsys)
     assert rc == 0
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
     assert result["correct"] is True
     assert result["attempted"] > 0 and result["failed"] == 0
+    # each number that decided ``correct`` beside its limit, last in the
+    # line; the probe's and the count of failed rounds in every run
+    assert {"reference", "reference_l2", "loss_gap", "failed_rounds"} <= set(
+        result["compared"])
+    assert all(set(c) == {"value", "limit"}
+               for c in result["compared"].values())
     assert result["device"]["platform"] == "cpu"
     assert set(result["device"]) == {"platform", "kind", "count",
                                      "memory_peak_bytes"}
