@@ -157,6 +157,7 @@ class FedSim:
         # into the round's SLO record). Costs one scalar sync per round.
         self.compute_probe = ComputeProbe(model=model)
         self.last_compute: Optional[dict] = None
+        self._fold_program = self._make_fold_program()
 
     def _ensure_partition(self, params):
         if self.trainable_predicate is None or self.partition is not None:
@@ -266,7 +267,8 @@ class FedSim:
     # across rounds. Donation lives where it is safe and large: the
     # fused round runner donates params+opt state by default
     # (run_rounds_fused, donate_argnums), the wave loop donates its
-    # model-sized psum accumulator (_acc_tree_add), and
+    # model-sized psum accumulator (_acc_tree_add, then the fold
+    # program), and
     # LocalTrainer.train_with_opt_state donates the per-client optimizer
     # state (training.py) — the buffers that would otherwise be
     # double-buffered per round.
@@ -376,6 +378,38 @@ class FedSim:
         program = (type(self)._wave_params_vmap if robust
                    else type(self)._wave_sums_vmap)
         return program, lambda *wave: (self, *wave, n_epochs)
+
+    def _make_fold_program(self):
+        """The mean round's fold as one jitted program of ``(psum_acc,
+        w_acc, lsum_acc, params, server_opt_state)``: :func:`_fold_mean`
+        with this sim's server optimizer closed over, built once per
+        ``FedSim`` as the wave programs are. On the hybrid clients x
+        model mesh its parameters come out replicated."""
+        server_optimizer = self.server_optimizer
+        pin = replicated_sharding(self.mesh) if self.is_hybrid else None
+
+        def fold(psum_acc, w_acc, lsum_acc, params, server_opt_state):
+            new_params, server_opt_state, loss_history = _fold_mean(
+                server_optimizer, psum_acc, w_acc, lsum_acc, params,
+                server_opt_state)
+            if pin is not None:
+                # GSPMD is free to leave the trainable aggregate
+                # model-sharded (it flows out of matmuls against the
+                # TP base), but the global state is logically
+                # replicated — pin it back to the partition layer's
+                # replicated rule so round outputs carry the same
+                # layout contract as inputs
+                new_params = jax.lax.with_sharding_constraint(new_params, pin)
+            return new_params, server_opt_state, loss_history
+
+        # donation decided yes for psum_acc only: it is the wave loop's
+        # own (a wave program's output or _acc_tree_add's, rebound by
+        # run_round and read nowhere after the fold), so a float32 leaf's
+        # aggregate aliases its accumulator and the fold, queued behind
+        # the last wave, allocates nothing model-sized. params and
+        # server_opt_state are the caller's; lsum_acc and w_acc are read
+        # after the dispatch (the sync, RoundResult).
+        return jax.jit(fold, donate_argnums=(0,))
 
     def _resolve_wave_size(self, wave_size: Optional[int], c: int) -> int:
         """Whole cohort when ``None``; a multiple of the wave unit."""
@@ -613,13 +647,38 @@ class FedSim:
                         jax.block_until_ready(lsum)
                         progress_fn(wave + 1, n_waves)
 
+            # The fold goes to the device before the host waits: it is
+            # queued behind the last wave, so the chip runs it the moment
+            # the wave ends instead of idling while the host dispatches.
+            with annotate("baton.round.fold") as fold_span:
+                if robust:
+                    stacked = jax.tree_util.tree_map(
+                        lambda *xs: jnp.concatenate(xs, axis=0),
+                        *stacked_parts
+                    )
+                    new_params = agg.aggregate_stacked(
+                        self.aggregator, stacked, n_samples, params
+                    )
+                    loss_history = lsum_acc / jnp.maximum(w_acc, 1e-9)
+                    if self.server_optimizer is not None:
+                        new_params, server_opt_state = _server_update(
+                            self.server_optimizer, params, new_params,
+                            server_opt_state
+                        )
+                else:
+                    new_params, server_opt_state, loss_history = (
+                        self._fold_program(psum_acc, w_acc, lsum_acc,
+                                           params, server_opt_state))
+                    fold_span.set_metadata(programs=1)
+
             # --- compute record (obs/compute.py) --------------------------
             # One scalar sync on the loss sum closes the timed window over
             # the wave loop (compile included on a cache miss — the
             # tracker's shape signature says whether this shape compiled).
-            # A model with no FLOPs accounting is a reason string inside
-            # the record; a JAX error raised by the sync is the round's
-            # error.
+            # The fold is behind the waves in the device's queue, so the
+            # sync still returns when the waves are done. A model with no
+            # FLOPs accounting is a reason string inside the record; a JAX
+            # error raised by the sync is the round's error.
             with annotate("baton.round.sync"):
                 jax.block_until_ready(lsum_acc)
             train_s = time.perf_counter() - t_waves0
@@ -642,44 +701,7 @@ class FedSim:
                              if self.mesh is not None else 1),
                 )
 
-            with annotate("baton.round.fold"):
-                denom = jnp.maximum(w_acc, 1e-9)
-                if robust:
-                    stacked = jax.tree_util.tree_map(
-                        lambda *xs: jnp.concatenate(xs, axis=0),
-                        *stacked_parts
-                    )
-                    aggregate = agg.aggregate_stacked(
-                        self.aggregator, stacked, n_samples, params
-                    )
-                else:
-                    aggregate = jax.tree_util.tree_map(
-                        lambda s, ref: (s / denom).astype(ref.dtype),
-                        psum_acc, params
-                    )
-                if self.is_hybrid:
-                    # GSPMD is free to leave the trainable aggregate
-                    # model-sharded (it flows out of matmuls against the
-                    # TP base), but the global state is logically
-                    # replicated — pin it back to the partition layer's
-                    # replicated rule so round outputs carry the same
-                    # layout contract as inputs
-                    aggregate = jax.device_put(
-                        aggregate, replicated_sharding(self.mesh)
-                    )
-                loss_history = lsum_acc / denom
-
             with annotate("baton.round.update"):
-                if self.server_optimizer is not None:
-                    if server_opt_state is None:
-                        server_opt_state = self.server_optimizer.init(params)
-                    new_params, server_opt_state = _server_update(
-                        self.server_optimizer, params, aggregate,
-                        server_opt_state
-                    )
-                else:
-                    new_params = aggregate
-
                 if self.partition is not None:
                     new_params = self.partition.merge(new_params, frozen)
 
@@ -968,15 +990,9 @@ class FedSim:
                 (psum, lsum, wtot), _ = jax.lax.scan(
                     wave_body, init, (data_w, n_w, rkeys)
                 )
-                denom = jnp.maximum(wtot, 1e-9)
-                aggregate = jax.tree_util.tree_map(
-                    lambda s, ref: (s / denom).astype(ref.dtype), psum, p
-                )
-                if server_opt is not None:
-                    p2, sos = _server_update(server_opt, p, aggregate, sos)
-                else:
-                    p2 = aggregate
-                return (p2, sos), lsum / denom
+                p2, sos, losses = _fold_mean(server_opt, psum, wtot, lsum,
+                                             p, sos)
+                return (p2, sos), losses
 
             (p, sos), losses = jax.lax.scan(
                 one_round, (params, server_opt_state), jnp.arange(n_rounds)
@@ -1097,9 +1113,30 @@ def _acc_tree_add(acc, delta):
     return agg.tree_add(acc, delta)
 
 
+def _fold_mean(server_optimizer, psum, wtot, lsum, params, server_opt_state):
+    """The mean round's fold, pure: the weighted sums over their weight
+    (a float32 divide, cast to the parameter's dtype), the loss history,
+    and the FedOpt step where there is a server optimizer (its state
+    made there when the caller brought none). Returns ``(new_params,
+    server_opt_state, loss_history)``. Traced inside
+    :meth:`FedSim.run_round`'s fold program and inside the fused rounds'
+    scan."""
+    denom = jnp.maximum(wtot, 1e-9)
+    aggregate = jax.tree_util.tree_map(
+        lambda s, ref: (s / denom).astype(ref.dtype), psum, params
+    )
+    if server_optimizer is not None:
+        aggregate, server_opt_state = _server_update(
+            server_optimizer, params, aggregate, server_opt_state)
+    return aggregate, server_opt_state, lsum / denom
+
+
 def _server_update(server_optimizer, params, aggregate, opt_state):
-    """FedOpt: pseudo-gradient = global − aggregate, fed to optax.
+    """FedOpt: pseudo-gradient = global − aggregate, fed to optax
+    (``opt_state`` None: the optimizer's initial state for ``params``).
     With optax.sgd(1.0) this reduces exactly to FedAvg assignment."""
+    if opt_state is None:
+        opt_state = server_optimizer.init(params)
     pseudo_grad = jax.tree_util.tree_map(
         lambda g, a: (g.astype(jnp.float32) - a.astype(jnp.float32)).astype(g.dtype),
         params,

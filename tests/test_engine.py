@@ -6,6 +6,8 @@ demo-parity linear model must converge to the generating coefficients —
 the TPU-native analogue of watching demo.py losses fall (SURVEY §4).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,8 +17,9 @@ import pytest
 from baton_tpu.data.synthetic import linear_client_data, DEMO_COEF
 from baton_tpu.models.linear import linear_regression_model
 from baton_tpu.ops.padding import stack_client_datasets
-from baton_tpu.parallel.engine import FedSim
-from baton_tpu.parallel.mesh import make_mesh
+from baton_tpu.parallel.engine import FedSim, _acc_tree_add
+from baton_tpu.parallel.mesh import (client_sharding, make_mesh,
+                                     shard_client_arrays)
 
 
 @pytest.fixture
@@ -330,3 +333,137 @@ def test_auto_wave_size_mesh_and_fused(nprng, monkeypatch):
                                     jax.random.key(1), n_rounds=2,
                                     wave_size="auto")
     assert np.isfinite(float(hist[-1]))
+
+
+# ----------------------------------------------------------------------
+# the mean fold as one device program (ISSUE 28)
+def _eager_round(sim, params, data, n_samples, rng, wave_size,
+                 server_opt_state):
+    """The round with the fold it had before it was one program: the wave
+    loop through the sim's own wave program, then every expression of the
+    fold and of the server update dispatched one by one, outside any
+    ``jit``. Returns ``(params, loss_history, n_samples_total,
+    server_opt_state)``."""
+    trainable, frozen = sim._split(params)
+    n_samples = jnp.asarray(n_samples)
+    c = int(n_samples.shape[0])
+    rngs = jax.random.split(rng, c)
+    wave_size = sim._resolve_wave_size(wave_size, c)
+    program, bind = sim._wave_program(1, robust=False)
+    in_shard = client_sharding(sim.mesh) if sim.mesh is not None else None
+    psum_acc = lsum_acc = w_acc = None
+    for start in range(0, c, wave_size):
+        d, n, r = sim._stage_wave(data, n_samples, rngs, start,
+                                  min(start + wave_size, c), wave_size,
+                                  in_shard)
+        psum, lsum, wtot, _ = program(*bind(trainable, frozen, d, n, r))
+        psum_acc = psum if psum_acc is None else _acc_tree_add(psum_acc, psum)
+        lsum_acc = lsum if lsum_acc is None else lsum_acc + lsum
+        w_acc = wtot if w_acc is None else w_acc + wtot
+
+    denom = jnp.maximum(w_acc, 1e-9)
+    aggregate = jax.tree_util.tree_map(
+        lambda s, ref: (s / denom).astype(ref.dtype), psum_acc, trainable)
+    loss_history = lsum_acc / denom
+    new = aggregate
+    if sim.server_optimizer is not None:
+        if server_opt_state is None:
+            server_opt_state = sim.server_optimizer.init(trainable)
+        pseudo_grad = jax.tree_util.tree_map(
+            lambda g, a: (g.astype(jnp.float32)
+                          - a.astype(jnp.float32)).astype(g.dtype),
+            trainable, aggregate)
+        updates, server_opt_state = sim.server_optimizer.update(
+            pseudo_grad, server_opt_state, trainable)
+        new = optax.apply_updates(trainable, updates)
+    if sim.partition is not None:
+        new = sim.partition.merge(new, frozen)
+    return new, loss_history, w_acc, server_opt_state
+
+
+def _bfloat16_bias(model):
+    """The linear model with its bias kept in bfloat16: the fold's cast."""
+
+    def init(rng):
+        p = model.init(rng)
+        return {**p, "b": p["b"].astype(jnp.bfloat16)}
+
+    def apply(params, batch, rng):
+        return model.apply(
+            {**params, "b": params["b"].astype(jnp.float32)}, batch, rng)
+
+    def per_example_loss(params, batch, rng):
+        return model.per_example_loss(
+            {**params, "b": params["b"].astype(jnp.float32)}, batch, rng)
+
+    return dataclasses.replace(model, init=init, apply=apply,
+                               per_example_loss=per_example_loss)
+
+
+# (FedSim arguments, wave size, bit-equal?). One program lets XLA contract
+# a multiply and the add that takes it into one rounding, which the same
+# expressions dispatched one by one cannot have (the fused runner has
+# always computed them so): exact where every product is (the mean's
+# divide and cast, a server step of 1.0 or a power of two), to half a
+# unit in the last place through a moment's `decay * m + g`.
+FOLD_CASES = {
+    "one_wave": ({}, None, True),
+    "waves_short_last": ({}, 3, True),
+    "bfloat16_leaf": ({"model": _bfloat16_bias}, 3, True),
+    "server_sgd": ({"server_optimizer": optax.sgd(1.0)}, 3, True),
+    # stateful: the step count picks the second round's rate
+    "server_scheduled": ({"server_optimizer": optax.sgd(
+        optax.piecewise_constant_schedule(1.0, {1: 0.5}))}, 3, True),
+    "server_adam": ({"server_optimizer": optax.adam(0.05)}, 3, False),
+    "server_momentum": (
+        {"server_optimizer": optax.sgd(0.7, momentum=0.9)}, None, False),
+    "trainable": ({"trainable": lambda path, leaf: path.endswith("w")}, 3,
+                  True),
+    "mesh2": ({"mesh": 2}, 4, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLD_CASES))
+def test_fold_program_is_bit_equal_to_the_eager_fold(linear_setup, case):
+    """Two rounds (the second fed the first's parameters and server
+    state): ``run_round``'s one fold program against the eager
+    expressions it replaced, bit for bit (``FOLD_CASES`` says where a
+    contracted multiply-add allows the last place)."""
+    sim_kw, wave_size, exact = FOLD_CASES[case]
+    sim_kw = dict(sim_kw)
+    model, _, data, n_samples = linear_setup
+    if "model" in sim_kw:
+        model = sim_kw.pop("model")(model)
+    if "mesh" in sim_kw:
+        sim_kw["mesh"] = make_mesh(sim_kw["mesh"])
+        data = shard_client_arrays(data, sim_kw["mesh"])
+    sim = FedSim(model, batch_size=32, learning_rate=0.01, **sim_kw)
+    params = model.init(jax.random.key(0))
+    want_p, want_state = params, None
+    got_p, got_state = params, None
+    for i in range(2):
+        rng = jax.random.key(10 + i)
+        want_p, want_loss, want_n, want_state = _eager_round(
+            sim, want_p, data, n_samples, rng, wave_size, want_state)
+        before = got_p
+        res = sim.run_round(got_p, data, n_samples, rng, wave_size=wave_size,
+                            server_opt_state=got_state)
+        got_p, got_state = res.params, res.server_opt_state
+        got = (got_p, res.loss_history, res.n_samples_total, got_state)
+        want = (want_p, want_loss, want_n, want_state)
+        assert (jax.tree_util.tree_structure(got)
+                == jax.tree_util.tree_structure(want))
+        for x, y in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert x.dtype == y.dtype and x.shape == y.shape
+            if exact:
+                np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+            else:
+                np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                           rtol=2e-6, atol=1e-9)
+        if case == "trainable":  # a frozen leaf is the array it was
+            assert got_p["b"] is before["b"]
+    assert not np.array_equal(np.asarray(got_p["w"]),
+                              np.asarray(params["w"]))
+    if case == "bfloat16_leaf":
+        assert got_p["b"].dtype == jnp.bfloat16

@@ -207,8 +207,8 @@ def test_session_spans_nest_in_their_round_in_order(session):
         inner = [s for s in session["spans"]
                  if s[0] != "baton.round" and r0 <= s[1] and s[2] <= r1]
         assert [s[0].rsplit(".", 1)[1] for s in inner] == [
-            "prepare", "stage", "dispatch", "stage", "dispatch", "sync",
-            "record", "fold", "update"]
+            "prepare", "stage", "dispatch", "stage", "dispatch", "fold",
+            "sync", "record", "update"]
         # siblings: each ends before the next starts
         assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
     inside_a_round = sum(r0 <= s[1] and s[2] <= r1
@@ -224,6 +224,44 @@ def test_session_stage_counts_the_phantom_clients(session):
     dispatches = [s[3] for s in session["spans"]
                   if s[0] == "baton.round.dispatch"]
     assert dispatches == [{"wave": 0}, {"wave": 1}] * 2
+
+
+def test_session_fold_launches_one_program(session):
+    folds = [s[3] for s in session["spans"] if s[0] == "baton.round.fold"]
+    assert folds == [{"programs": 1}] * 2
+
+
+def test_second_round_compiles_nothing_and_donates_nothing_of_the_callers():
+    """The fold program is keyed by shapes alone: a second round of the
+    same shapes builds no program (counted as ``fedbench/run.py`` counts
+    ``compiles_in_window``), and what the caller handed in (parameters,
+    server state) is still readable after it."""
+    compiles = []
+
+    def listener(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    data, n = _linear_cohort()
+    sim = _linear_sim(server_optimizer=optax.sgd(0.5, momentum=0.9))
+    params = sim.init(jax.random.key(0))
+    state = sim.init_server_opt_state(params)
+    first = sim.run_round(params, data, n, jax.random.key(1), wave_size=4,
+                          server_opt_state=state)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        jax.jit(lambda x: x * 3 + 1)(jnp.zeros(3))  # a compile is heard
+        heard = len(compiles)
+        second = sim.run_round(first.params, data, n, jax.random.key(2),
+                               wave_size=4,
+                               server_opt_state=first.server_opt_state)
+        jax.block_until_ready(second.params)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert heard >= 1 and len(compiles) == heard
+    for kept in (params, state, first.params, first.server_opt_state):
+        for leaf in jax.tree_util.tree_leaves(kept):
+            assert np.isfinite(np.asarray(leaf)).all()  # not deleted
 
 
 def test_round_is_bit_equal_inside_and_outside_a_session(session):
