@@ -1,9 +1,8 @@
-"""Observability planes shared by bench tooling and the live fleet.
+"""Observability planes of the live fleet.
 
-``baton_tpu.obs.compute`` is the shared probe behind bench.py's offline
-numbers AND the live round loop's per-round compute records (worker →
-edge → manager → ``rounds.jsonl`` → fleet ledger → SLO gate → ops
-console).
+``baton_tpu.obs.compute`` is the probe behind the round loop's
+per-round compute records (worker → edge → manager → ``rounds.jsonl``
+→ fleet ledger → SLO gate → ops console).
 
 ``baton_tpu.obs.alerts`` watches those measurements: declarative alert
 rules (threshold or multi-window burn-rate) evaluated per node with a

@@ -1,9 +1,8 @@
 """Compute-plane probe: analytic FLOPs/MFU accounting, jit compile
 tracking, and peak-HBM reading for the LIVE training path.
 
-Until this module existed, MFU / compile seconds / peak HBM were
-measured only inside offline ``bench.py`` runs — the round loop itself
-was blind on the compute plane. The probe instruments every local
+Without it the round loop is blind on the compute plane (MFU, compile
+seconds, peak HBM). The probe instruments every local
 training call (worker ``_run_round``, the manager's simulated cohort via
 ``parallel/engine.py``) and emits one *compute record* per round, which
 rides the update metadata to the root, lands in the round's
@@ -13,8 +12,10 @@ fleet ledger, and gates ``compute:*`` SLO metrics in CI.
 Three design rules, each a recorded postmortem:
 
 * **One FLOPs implementation.** The per-model analytic FLOPs constants
-  and the MFU formula live HERE; ``bench.py`` imports them. Bench MFU
-  and live MFU can no longer diverge (they were duplicated before).
+  and the MFU formula of the program live HERE, for every reporter
+  (worker, edge, manager). The benchmark keeps its own yardstick
+  (``fedbench/flops/``, ``fedbench/peaks.json``) and imports nothing
+  from here.
 * **Null-with-reason.** Every ``None`` metric in a compute record
   carries a sibling ``<name>_reason`` / ``<name>_source`` string
   (:func:`validate_record` enforces it): a silent null reads as
@@ -60,7 +61,7 @@ __all__ = [
 ]
 
 # ---------------------------------------------------------------------------
-# Analytic FLOPs accounting (extracted from bench.py — the ONE copy).
+# Analytic FLOPs accounting (the program's ONE copy).
 #
 # ResNet-18 (CIFAR-10 variant, 32x32 input): 0.557 GMAC forward per
 # image = 1.11 GFLOP (x2 MAC->FLOP); training ~3x forward (fwd + 2x
@@ -155,9 +156,8 @@ def compute_mfu(
     flops_per_sample: Optional[float],
     device_kind: str,
 ) -> Tuple[Optional[float], Optional[str]]:
-    """MFU = delivered analytic training FLOPs / chip peak — the exact
-    formula bench.py's headline uses. ``(None, reason)`` when any input
-    is unavailable."""
+    """MFU = delivered analytic training FLOPs / chip peak.
+    ``(None, reason)`` when any input is unavailable."""
     if samples_per_sec_per_chip is None:
         return None, "throughput unmeasured"
     if flops_per_sample is None:

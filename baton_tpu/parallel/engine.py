@@ -53,7 +53,12 @@ from baton_tpu.parallel.partition import (
     waved_client_spec,
 )
 from baton_tpu.parallel.tensor_parallel import MODEL_AXIS, shard_params_tp
-from baton_tpu.utils.profiling import annotate
+from baton_tpu.utils.profiling import (
+    _plan_gb_of,
+    annotate,
+    hbm_budget_gb,
+    is_oom_error,
+)
 
 Params = Any
 
@@ -184,7 +189,7 @@ class FedSim:
     @property
     def partition_rule_set(self) -> str:
         """Name of the :data:`~baton_tpu.parallel.partition.DEFAULT_RULE_SETS`
-        table governing this sim's placement — recorded in bench output."""
+        table governing this sim's placement."""
         if self.is_hybrid:
             return "transformer-tp"
         if self.mesh is not None:
@@ -364,14 +369,17 @@ class FedSim:
         sharded, jitted = cache[n_epochs]
         return sharded if raw else jitted
 
-    def _wave_program(self, n_epochs: int, robust: bool):
+    def _wave_program(self, n_epochs: int, robust: bool,
+                      per_shard: bool = False):
         """``(program, bind)``: the jitted wave program of this layout
         and aggregator, and ``bind(params, frozen, data, n_samples,
         rngs)`` giving its arguments, so that ``program(*bind(...))``
         runs a wave and ``program.lower(*bind(...))`` lowers the same
-        program. The one place that chooses it, for :meth:`run_round`
-        and :meth:`lower_wave`."""
-        if self.mesh is not None and not self.is_hybrid:
+        program. The one place that chooses it, for :meth:`run_round`,
+        :meth:`lower_wave` and :meth:`wave_plan_gb`. ``per_shard`` gives
+        the plain ``vmap`` program on a mesh too: what one device runs
+        of a wave, the collectives aside."""
+        if self.mesh is not None and not self.is_hybrid and not per_shard:
             program = (self._make_wave_params_sharded(n_epochs) if robust
                        else self._make_wave_sums_sharded(n_epochs))
             return program, lambda *wave: wave
@@ -460,6 +468,49 @@ class FedSim:
             r = jax.device_put(r, in_shard)
         return d, n, r
 
+    def _first_wave(self, params, data, n_samples, rng, n_epochs: int,
+                    wave_size: Optional[int], per_shard: bool = False):
+        """``(program, args)`` of the first wave of this round at
+        ``wave_size`` clients (the whole cohort when ``None``), unrun:
+        the weighted-sums program and the staging are
+        :meth:`run_round`'s own (``_wave_program``, ``_stage_wave``).
+        With ``per_shard`` it is one device's share of that wave:
+        ``wave_size`` over the client-axis extent, inputs unplaced."""
+        params, frozen = self._split(params)
+        n_samples = jnp.asarray(n_samples)
+        c = int(n_samples.shape[0])
+        rngs = jax.random.split(rng, c)
+        wave_size = self._resolve_wave_size(wave_size, c)
+        in_shard = client_sharding(self.mesh) if self.mesh is not None else None
+        if per_shard:
+            wave_size = max(1, wave_size // self._clients_per_wave_unit())
+            in_shard = None
+        program, bind = self._wave_program(n_epochs, robust=False,
+                                           per_shard=per_shard)
+        d, n, r = self._stage_wave(data, n_samples, rngs, 0,
+                                   min(wave_size, c), wave_size, in_shard)
+        return program, bind(params, frozen, d, n, r)
+
+    def wave_plan_gb(self, params, data, n_samples, key,
+                     wave_size: Optional[int] = None,
+                     n_epochs: int = 1) -> Optional[float]:
+        """XLA's static HBM plan (GiB) of one device's share of a wave
+        of ``wave_size`` clients (the whole cohort when ``None``),
+        compiled WITHOUT executing — the OOM guard behind
+        :meth:`auto_wave_size`. On a mesh that share is the per-shard
+        program at ``wave_size`` over the client-axis extent. Returns
+        ``None`` when analysis is unavailable (proceed) and
+        ``float("inf")`` when the compile itself RESOURCE_EXHAUSTs (a
+        definitive does-not-fit — guards must skip). A ``wave_size``
+        larger than the cohort is padded to size as ``run_round`` pads
+        its last wave, so no trace error reads as "no analysis"."""
+        try:
+            return _plan_gb_of(*self._first_wave(
+                params, data, n_samples, key, n_epochs, wave_size,
+                per_shard=True))
+        except Exception as e:
+            return float("inf") if is_oom_error(e) else None
+
     # ------------------------------------------------------------------
     def auto_wave_size(self, params, data, n_samples, key=None,
                        n_epochs: int = 1,
@@ -480,17 +531,13 @@ class FedSim:
         memory analysis (some CPU configs), the full cohort is assumed
         to fit — matching the pre-auto behavior. ``budget_gb``
         overrides the per-device-kind plan budget
-        (profiling.hbm_budget_gb, conservative tier), and is required
-        on a device that table does not hold (the CPU).
+        (profiling.hbm_budget_gb), and is required on a device that
+        table does not hold (the CPU).
 
         On a clients mesh the probe lowers the PER-SHARD program (each
-        device executes wave/n_dev clients under shard_map), so the
-        plan is compared against one device's budget."""
-        from baton_tpu.utils.profiling import (
-            fedsim_wave_plan_gb,
-            hbm_budget_gb,
-        )
-
+        device executes wave/n_dev clients under shard_map,
+        :meth:`wave_plan_gb`), so the plan is compared against one
+        device's budget."""
         if self.aggregator[0] != "mean":
             raise NotImplementedError(
                 "auto_wave_size probes the weighted-sums wave kernel; "
@@ -507,14 +554,10 @@ class FedSim:
             key = jax.random.key(0)
         n_samples = jnp.asarray(n_samples)
         unit = self._clients_per_wave_unit()
-        n_dev = unit  # clients mesh: one wave unit = one client per device
-        w = round_up(int(n_samples.shape[0]), unit)
+        full = w = round_up(int(n_samples.shape[0]), unit)
         while True:
-            # per-device footprint: each device runs a wave/n_dev-client
-            # program under shard_map
-            plan = fedsim_wave_plan_gb(
-                self, params, data, n_samples, key,
-                wave_size=max(1, w // n_dev), n_epochs=n_epochs)
+            plan = self.wave_plan_gb(params, data, n_samples, key,
+                                     wave_size=w, n_epochs=n_epochs)
             if plan is None or plan <= budget_gb:
                 break
             if w <= unit:
@@ -524,7 +567,6 @@ class FedSim:
                     f"{plan:.1f} GiB) — shrink the per-client batch or "
                     "dataset instead of risking an OOM")
             w = round_up(max(unit, w // 2), unit)
-        full = round_up(int(n_samples.shape[0]), unit)
         return None if w >= full else w
 
     def run_round(
@@ -730,16 +772,9 @@ class FedSim:
                 "lower_wave lowers the weighted-sums wave program of the "
                 "single-device and clients-mesh layouts at a given wave "
                 "size")
-        params, frozen = self._split(params)
-        n_samples = jnp.asarray(n_samples)
-        c = int(n_samples.shape[0])
-        rngs = jax.random.split(rng, c)
-        wave_size = self._resolve_wave_size(wave_size, c)
-        program, bind = self._wave_program(n_epochs, robust)
-        in_shard = client_sharding(self.mesh) if self.mesh is not None else None
-        d, n, r = self._stage_wave(data, n_samples, rngs, 0,
-                                   min(wave_size, c), wave_size, in_shard)
-        return program.lower(*bind(params, frozen, d, n, r))
+        program, args = self._first_wave(params, data, n_samples, rng,
+                                         n_epochs, wave_size)
+        return program.lower(*args)
 
     # ------------------------------------------------------------------
     # federated evaluation: sample-weighted mean loss/accuracy over the

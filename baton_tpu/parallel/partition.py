@@ -171,9 +171,8 @@ def _divisible(leaf: Any, spec: PartitionSpec, mesh: Mesh) -> bool:
 class RuleSet:
     """A named, ordered rule table — the declarative partition config.
 
-    ``name`` is recorded in bench output (``partition_rule_set``) and in
-    plan_probe's spec-equality report, so a perf record always names the
-    sharding policy that produced it.
+    ``name`` is what ``FedSim.partition_rule_set`` reports, so a record
+    can name the sharding policy that produced it.
     """
 
     name: str
@@ -224,10 +223,10 @@ class RuleSet:
         )
 
     def describe(self, params: Params, mesh: Optional[Mesh] = None) -> Dict[str, str]:
-        """{path: spec-string} — introspection and the plan_probe
-        spec-equality report. With a mesh, the divisibility fallback is
-        applied (what would actually be placed); without, the raw rule
-        outcome."""
+        """{path: spec-string} — introspection and the spec-equality
+        test (tests/test_partition_rules.py). With a mesh, the
+        divisibility fallback is applied (what would actually be
+        placed); without, the raw rule outcome."""
         flat = jax.tree_util.tree_flatten_with_path(params)[0]
         out: Dict[str, str] = {}
         for p, leaf in flat:
@@ -295,7 +294,7 @@ def replicated_rules() -> RuleSet:
     return RuleSet(name="replicated", rules=(Rule(r".*", replicated_spec()),))
 
 
-#: The default rule tables, keyed by the name bench.py records.
+#: The default rule tables, keyed by ``RuleSet.name``.
 DEFAULT_RULE_SETS: Dict[str, Callable[[], RuleSet]] = {
     "transformer-tp": transformer_rules,
     "client-stacked": client_stacked_rules,

@@ -7,20 +7,19 @@ capabilities, flagged as such in SURVEY, built TPU-first:
 
 * :mod:`baton_tpu.utils.checkpoint` — orbax round-granular save/resume.
 * :mod:`baton_tpu.utils.metrics` — counters/gauges/timers + JSON export.
-* :mod:`baton_tpu.utils.profiling` — JAX profiler traces + device timing.
+* :mod:`baton_tpu.utils.profiling` — profiler spans, device timing, memory plans.
 * :mod:`baton_tpu.utils.faults` — HTTP-layer fault injection for
   elasticity tests.
 """
 
 from baton_tpu.utils.checkpoint import Checkpointer, RestoredState
 from baton_tpu.utils.metrics import Metrics
-from baton_tpu.utils.profiling import annotate, profile_trace, timed
+from baton_tpu.utils.profiling import annotate, timed
 
 __all__ = [
     "Checkpointer",
     "RestoredState",
     "Metrics",
     "annotate",
-    "profile_trace",
     "timed",
 ]

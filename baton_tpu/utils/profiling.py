@@ -1,14 +1,17 @@
-"""JAX profiler hooks (SURVEY §5 "Tracing/profiling: absent" — new).
+"""JAX profiler hooks and memory-plan helpers (SURVEY §5
+"Tracing/profiling: absent" — new).
 
-Thin, always-importable wrappers around ``jax.profiler``:
+Thin, always-importable wrappers around ``jax.profiler`` and XLA's
+memory analysis; nothing here knows the engine (``parallel/``):
 
-* :func:`profile_trace` — context manager writing an XLA/TensorBoard
-  trace (HLO timelines, per-op device time) to a directory. Enabled
-  explicitly or via ``BATON_TPU_PROFILE=<dir>``; a no-op otherwise, so
-  call sites can wrap hot paths unconditionally.
 * :func:`annotate` — named host span (with attributes) inside traces.
+* :func:`forensics_trace` — the alert plane's one-shot armed capture.
 * :func:`timed` — wall-clock a function with ``block_until_ready`` on
   its outputs, so async XLA dispatch doesn't fake instant completion.
+* :func:`enable_compile_cache` — the persistent compilation cache.
+* :func:`plan_breakdown_gb`, :func:`peak_hbm_gb`, :func:`hbm_budget_gb`
+  — XLA's static memory plan of any ``(jitted, args)``, the allocator's
+  measured peak, and the per-device-kind plan budget.
 """
 
 from __future__ import annotations
@@ -19,22 +22,6 @@ from contextlib import contextmanager
 from typing import Any, Callable, Optional, Tuple
 
 import jax
-
-ENV_VAR = "BATON_TPU_PROFILE"
-
-
-@contextmanager
-def profile_trace(log_dir: Optional[str] = None):
-    """Trace the enclosed block to ``log_dir`` (or ``$BATON_TPU_PROFILE``).
-
-    No-op when neither is set — safe to leave in production paths.
-    """
-    log_dir = log_dir or os.environ.get(ENV_VAR)
-    if not log_dir:
-        yield
-        return
-    with jax.profiler.trace(log_dir):
-        yield
 
 
 # ---------------------------------------------------------------------------
@@ -97,7 +84,7 @@ def annotate(name: str, **attrs: Any):
     """Named host span in the profiler's own trace
     (``jax.profiler.TraceAnnotation``); keyword ``attrs`` become the
     event's stats. The one host-span primitive of the simulator path
-    (``FedSim.run_round``'s ``baton.round.*`` spans): the span is written
+    (the engine's ``baton.round.*`` spans): the span is written
     by the profiler session that writes the device planes, so it is on
     their clock, and outside a session it costs a flag test. The
     ``Tracer`` of ``utils/tracing.py`` and ``utils/metrics.Metrics`` are
@@ -127,9 +114,9 @@ REPO_CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> Tuple[str, bool]:
     """Turn on JAX's persistent compilation cache; every entry point
-    (``chip_smoke.py``, ``bench.py``, ``benchmarks/*.py``, ``demo.py``,
-    ``examples/*.py``, ``python -m baton_tpu.loadgen``) calls this
-    first. Returns ``(directory, came_from_environment)``.
+    (``chip_smoke.py``, ``fedbench/run.py``, ``benchmarks/*.py``,
+    ``demo.py``, ``examples/*.py``, ``python -m baton_tpu.loadgen``)
+    calls this first. Returns ``(directory, came_from_environment)``.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has read it itself
     and the directory is not touched here — whoever placed the cache
@@ -145,34 +132,6 @@ def enable_compile_cache() -> Tuple[str, bool]:
     # of seconds on the chip); high enough to skip one-op dispatches
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
     return cache_dir, from_env
-
-
-def resolve_artifact_path(out_path: str, run_has_tpu_success: bool,
-                          prior_has_tpu_success) -> str:
-    """Shared artifact-clobber policy for the hardware sweeps
-    (wave_sweep.py, attention_sweep.py): never overwrite an artifact
-    holding TPU measurements with a run that produced none — every cell
-    failing, or a CPU smoke run with plausible-looking numbers. The
-    lesser run is still evidence: it goes to a ``*_failed`` sibling
-    instead.
-
-    ``prior_has_tpu_success`` is a callable applied to the parsed prior
-    JSON (artifact shapes differ per sweep); unreadable/foreign priors
-    are treated as clobber-safe."""
-    import json as _json
-
-    if run_has_tpu_success:
-        return out_path
-    try:
-        with open(out_path) as f:
-            prior = _json.load(f)
-        keep = bool(prior_has_tpu_success(prior))
-    except (OSError, ValueError, TypeError, AttributeError, KeyError):
-        return out_path
-    if not keep:
-        return out_path
-    base, ext = os.path.splitext(out_path)
-    return f"{base}_failed{ext or '.json'}"
 
 
 def is_oom_error(e: Exception) -> bool:
@@ -234,38 +193,11 @@ def _plan_gb_of(jitted, args) -> Optional[float]:
         return float("inf") if is_oom_error(e) else None
 
 
-def _lower_wave_kernel(sim, params, data, n_samples, key,
-                       wave_size: Optional[int] = None, n_epochs: int = 1):
-    """(jitted, args) for ONE wave of ``sim``'s round, honoring a
-    trainable/frozen partition — the program whose memory plan stands in
-    for the round's footprint. A ``wave_size`` larger than the cohort is
-    PADDED to size (run_round pads its last wave the same way) — slicing
-    alone would hand vmap mismatched leading axes, and the resulting
-    trace error must not read as "no analysis, assume it fits"."""
-    import jax
-    import jax.numpy as jnp
-
-    tr, fz = sim._split(params)
-    n_samples = jnp.asarray(n_samples)
-    c = int(n_samples.shape[0])
-    w = wave_size or c
-    take = min(w, c)
-    d0 = jax.tree_util.tree_map(lambda a: a[:take], data)
-    n0 = n_samples[:take]
-    r0 = jax.random.split(key, take)
-    if take < w:
-        d0, n0, r0 = sim._pad_wave(d0, n0, r0, w)
-    jitted = jax.jit(lambda a, b, d, n, r: sim._wave_sums_raw(
-        a, b, d, n, r, n_epochs))
-    return jitted, (tr, fz, d0, n0, r0)
-
-
 def peak_hbm_gb(device) -> Optional[float]:
     """The runtime allocator's high-water mark for this process, in
     GiB; ``None`` on a backend that keeps no allocator statistics (the
-    CPU). A measurement — XLA's static plan (:func:`plan_breakdown_gb`,
-    :func:`fedsim_wave_plan_gb`) is only ever a plan and is never
-    returned under this name.
+    CPU). A measurement — XLA's static plan (:func:`plan_breakdown_gb`)
+    is only ever a plan and is never returned under this name.
 
     The TPU runtime counts live arrays and a running program's
     temporaries apart: ``peak_bytes_in_use`` is arrays only, the
@@ -282,19 +214,8 @@ def peak_hbm_gb(device) -> Optional[float]:
     return round(peak / 2**30, 6)
 
 
-# Plan-space budgets for the OOM guard, in two tiers (ROADMAP D13).
-#
-# Default tier: HBM capacity minus runtime/framework headroom — for
-# kernels whose XLA memory plan tracks the true allocation
-# (matmul-shaped programs: im2col convs, transformers).
-#
-# Anchored tier (ANCHORED_DIRECT_CONV_BUDGET_GB): for the direct-conv
-# ResNet wave kernels the plan overcounts what the runtime reserves.
-# PR 21 chip run, v5e: the wave-32 kernel plans at 14.95 GiB (14.82 of
-# it temporaries) and the runtime reserved 13.49 GiB to run it. The
-# 17.5 GiB figure itself is builder-recorded 2026-07-30/31 (a wave-64
-# plan of 17.42 GiB that executed, a wave-128 that did not) on a stack
-# that no longer exists; record deleted in PR 21.
+# Plan-space budgets for the OOM guard (ROADMAP D13): HBM capacity
+# minus runtime/framework headroom, to set against XLA's static plan.
 HBM_BUDGET_GB = {
     "TPU v4": 29.0,       # 32 GB
     "TPU v5 lite": 13.5,  # v5e, 16 GB
@@ -304,120 +225,18 @@ HBM_BUDGET_GB = {
     "TPU v6 lite": 28.0,  # v6e, 32 GB
     "TPU v6e": 28.0,
 }
-# The anchored overlay applies ONLY to the direct-conv ResNet wave
-# kernel class. It must NOT be used for matmul-shaped kernels (im2col,
-# transformers) whose plans track real allocation.
-ANCHORED_DIRECT_CONV_BUDGET_GB = {
-    "TPU v5 lite": 17.5,
-    "TPU v5e": 17.5,
-}
-
-# The exact kernel identity the anchor covers: the direct-lowering
-# ResNet wave kernel at per-client batch 32. The plan-overcount evidence
-# extends no further — a direct_b48 kernel is a different program whose
-# 16-17.5 GiB plan could be a real over-HBM demand.
-ANCHORED_CONV_KERNEL = {"impl": "direct", "batch_size": 32}
 
 
-def conv_kernel_class(impl: str, batch_size: int = 32) -> str:
-    """OOM-guard kernel class for a per-client-conv wave kernel.
-
-    Returns ``"anchored_direct_conv"`` only for the FULL anchored
-    kernel identity (lowering impl AND per-client batch size matching
-    :data:`ANCHORED_CONV_KERNEL`); every other conv config — im2col,
-    shift, or an unanchored direct batch — gets the conservative
-    ``"default"`` tier."""
-    if (impl == ANCHORED_CONV_KERNEL["impl"]
-            and int(batch_size) == ANCHORED_CONV_KERNEL["batch_size"]):
-        return "anchored_direct_conv"
-    return "default"
-
-
-def hbm_budget_gb(device, kernel_class: str = "default") -> float:
+def hbm_budget_gb(device) -> float:
     """Plan-space OOM-guard budget for ``device``.
-
-    ``kernel_class="anchored_direct_conv"`` selects the calibrated
-    overlay for the direct-conv ResNet wave kernels (see
-    ANCHORED_DIRECT_CONV_BUDGET_GB); every other kernel class gets the
-    conservative capacity-minus-headroom budget, because for
-    matmul-shaped programs the plan is close to the true allocation and
-    admitting plans above physical HBM would execute a real OOM.
 
     A ``device_kind`` the table does not hold is a ``ValueError``, never
     a default: a budget guessed for an unknown device guards nothing."""
     kind = device.device_kind
-    if kernel_class == "anchored_direct_conv":
-        for prefix, budget in ANCHORED_DIRECT_CONV_BUDGET_GB.items():
-            if kind.startswith(prefix):
-                return budget
     for prefix, budget in HBM_BUDGET_GB.items():
         if kind.startswith(prefix):
             return budget
     raise ValueError(
         f"no HBM budget for device kind {kind!r}: add it to "
-        "profiling.HBM_BUDGET_GB with its source, or pass an explicit "
-        "budget (FedSim.auto_wave_size(budget_gb=...))")
-
-
-def fedsim_wave_plan_gb(sim, params, data, n_samples, key,
-                        wave_size: Optional[int] = None,
-                        n_epochs: int = 1) -> Optional[float]:
-    """XLA's static HBM plan (GiB) for one wave's kernel, compiled
-    WITHOUT executing. The OOM guard: benchmark stages check the
-    compiler's own budget first and skip — recording the plan — instead
-    of running a program that cannot fit. Returns None when analysis is
-    unavailable (proceed) and ``float("inf")`` when the compile itself
-    RESOURCE_EXHAUSTs (a definitive does-not-fit — guards must skip)."""
-    try:
-        jitted, args = _lower_wave_kernel(sim, params, data, n_samples,
-                                          key, wave_size, n_epochs)
-        return _plan_gb_of(jitted, args)
-    except Exception as e:
-        return float("inf") if is_oom_error(e) else None
-
-
-def fedsim_fused_donation_plan(sim, params, data, n_samples, key,
-                               n_rounds: int = 2, n_epochs: int = 1,
-                               wave_size: Optional[int] = None) -> dict:
-    """XLA static memory plans for the fused multi-round program
-    compiled WITH and WITHOUT buffer donation — the measured answer to
-    "what does ``donate_argnums`` on the round step actually buy".
-
-    Compiles both variants (never executes); donation shows up in the
-    plan's ``alias_gb`` (the donated params/server-opt inputs alias the
-    outputs, so the globals stop being double-buffered across the
-    dispatch). Returns ``{"donate_on": breakdown, "donate_off":
-    breakdown, "delta_gb": off - on}`` with :func:`plan_breakdown_gb`
-    dicts; raises on compile failure — callers decide whether an
-    unmeasured delta is skippable (and must record why).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from baton_tpu.ops.padding import round_up
-
-    tr, fz = sim._split(params)
-    n_samples = jnp.asarray(n_samples)
-    c = int(n_samples.shape[0])
-    unit = sim._clients_per_wave_unit()
-    wave = round_up(wave_size if wave_size is not None else c, unit)
-    n_waves = -(-c // wave)
-    rngs = jax.random.split(key, c)
-    data, n_samples, _ = sim._pad_wave(data, n_samples, rngs,
-                                       n_waves * wave)
-    data_w = jax.tree_util.tree_map(
-        lambda a: jnp.asarray(a).reshape((n_waves, wave) + a.shape[1:]),
-        data,
-    )
-    n_w = n_samples.reshape(n_waves, wave)
-    sos = (sim.server_optimizer.init(tr)
-           if sim.server_optimizer is not None else None)
-    args = (tr, fz, data_w, n_w, key, sos)
-    out = {}
-    for label, donate in (("donate_on", True), ("donate_off", False)):
-        fn = sim._make_rounds_fused(n_epochs, n_rounds, donate=donate)
-        out[label] = plan_breakdown_gb(fn, args)
-    out["delta_gb"] = round(
-        out["donate_off"]["plan_gb"] - out["donate_on"]["plan_gb"], 6
-    )
-    return out
+        "profiling.HBM_BUDGET_GB with its source, or pass the caller an "
+        "explicit budget_gb")

@@ -44,7 +44,7 @@ BF16_TOL = 2e-2
 
 @dataclasses.dataclass(frozen=True)
 class Sizes:
-    # fedsim_resnet18 / mesh — bench.py's cell
+    # fedsim_resnet18 / mesh — the cell resnet18_c32_w1
     clients: int
     samples: int
     batch: int
@@ -53,7 +53,7 @@ class Sizes:
     # flash_kernel, alone: [B, H, L, Dh]
     flash_shape: tuple
     # flash_kernel, through the decoder: sequence length (widths are
-    # the 0.9 B preset's on the chip, LlamaConfig.tiny in rehearsal)
+    # a 0.9 B Llama's on the chip, LlamaConfig.tiny in rehearsal)
     decoder_len: int
     # mesh: ring attention [B, H, L, Dh]
     ring_shape: tuple
@@ -146,7 +146,7 @@ def phase_device(env: Env) -> None:
 
 # ----------------------------------------------------------------------
 def _resnet_cell(env: Env):
-    """(model, params, data, n_samples): bench.py's ResNet cell — same
+    """(model, params, data, n_samples): the cell resnet18_c32_w1 — same
     shapes and dtypes, hence the same compiled program — with labels
     that are a fixed function of the images (argmax of a seeded random
     projection), so that the loss can be required to fall."""
@@ -312,8 +312,8 @@ def _flash_through_decoder(env: Env) -> str:
             cfg, compute_dtype=jnp.bfloat16, remat=True,
             attention_fn=make_flash_attention_fn(interpret=True))
     else:
-        # the 0.9 B preset's widths (benchmarks/tpu_suite.py::child_llama)
-        # at 2 of its 16 layers; attention left to default_attention
+        # a 0.9 B Llama's widths at 2 of its 16 layers; attention left
+        # to default_attention
         cfg = LlamaConfig(vocab_size=32000, max_len=L, d_model=2048,
                           n_layers=2, n_heads=16, n_kv_heads=8, d_ff=5632,
                           rope_theta=500000.0)

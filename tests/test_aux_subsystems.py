@@ -22,7 +22,7 @@ from baton_tpu.server.state import params_to_state_dict
 from baton_tpu.utils.checkpoint import Checkpointer
 from baton_tpu.utils.faults import FaultInjector
 from baton_tpu.utils.metrics import Metrics
-from baton_tpu.utils.profiling import profile_trace, timed
+from baton_tpu.utils.profiling import timed
 
 
 def run(coro):
@@ -172,19 +172,6 @@ def test_timed_blocks_on_device_work():
     assert secs >= 0.0
 
 
-def test_profile_trace_noop_without_dir(monkeypatch):
-    monkeypatch.delenv("BATON_TPU_PROFILE", raising=False)
-    with profile_trace():  # must be a silent no-op
-        jnp.ones(3).sum()
-
-
-def test_profile_trace_writes(tmp_path):
-    logdir = tmp_path / "prof"
-    with profile_trace(str(logdir)):
-        jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
-    assert any(logdir.rglob("*"))  # trace artifacts exist
-
-
 # ----------------------------------------------------------------------
 # fault injection
 
@@ -327,38 +314,16 @@ def test_hbm_budget_device_mapping():
         def __init__(self, kind):
             self.device_kind = kind
 
-    # default tier: conservative capacity-minus-headroom (plan ~= real
-    # for matmul-shaped kernels — admitting more would execute real OOMs)
+    # capacity minus headroom, by device-kind prefix
     assert hbm_budget_gb(D("TPU v5 lite")) == 13.5
+    assert hbm_budget_gb(D("TPU v5e")) == 13.5
     assert hbm_budget_gb(D("TPU v4")) == 29.0
     assert hbm_budget_gb(D("TPU v5p")) == 90.0
-    # anchored tier: direct-conv wave kernels only, where the plan
-    # overcounts what the runtime reserves
-    assert hbm_budget_gb(D("TPU v5 lite"), "anchored_direct_conv") == 17.5
-    assert hbm_budget_gb(D("TPU v5e"), "anchored_direct_conv") == 17.5
-    # no anchor recorded for other generations: overlay falls through
-    assert hbm_budget_gb(D("TPU v4"), "anchored_direct_conv") == 29.0
     # a device the table does not hold is refused, never defaulted
-    for kclass in ("default", "anchored_direct_conv"):
-        with pytest.raises(ValueError, match="weird accelerator"):
-            hbm_budget_gb(D("weird accelerator"), kclass)
+    with pytest.raises(ValueError, match="weird accelerator"):
+        hbm_budget_gb(D("weird accelerator"))
     with pytest.raises(ValueError, match="'cpu'"):
         hbm_budget_gb(jax.devices()[0])
-
-
-def test_conv_kernel_class_keys_full_anchor_identity():
-    """The anchored plan-overcount overlay is evidence about ONE kernel
-    (direct lowering, per-client batch 32). Any other identity — a
-    different batch, a different lowering — must get the conservative
-    tier: an unanchored direct_b48 config with a 17 GiB plan could be a
-    REAL over-HBM demand."""
-    from baton_tpu.utils.profiling import conv_kernel_class
-
-    assert conv_kernel_class("direct", 32) == "anchored_direct_conv"
-    assert conv_kernel_class("direct", 48) == "default"
-    assert conv_kernel_class("im2col", 32) == "default"
-    assert conv_kernel_class("shift", 32) == "default"
-    assert conv_kernel_class("im2col", 48) == "default"
 
 
 def test_is_oom_error_requires_memory_corroboration():
@@ -387,63 +352,90 @@ def test_is_oom_error_requires_memory_corroboration():
         assert not is_oom_error(e), e
 
 
-def test_plan_gb_treats_compile_oom_as_infinite():
+def test_plan_gb_treats_compile_oom_as_infinite(monkeypatch):
     """A compile-time RESOURCE_EXHAUSTED is XLA *proving* the program
-    exceeds HBM. fedsim_wave_plan_gb must report it as over-any-budget,
+    exceeds HBM. FedSim.wave_plan_gb must report it as over-any-budget,
     not as missing analysis that waves the config through."""
+    from baton_tpu.models.linear import linear_regression_model
+    from baton_tpu.parallel.engine import FedSim
     from baton_tpu.utils import profiling
 
     oom = RuntimeError(
         "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
         "memory in memory space hbm; Allocation type: HLO temp")
+    other = RuntimeError("memory_analysis unsupported")
     assert profiling.is_oom_error(oom)
     assert not profiling.is_oom_error(RuntimeError("tracing error"))
 
-    class _Boom:
+    class _Raises:
+        def __init__(self, error):
+            self.error = error
+
         def lower(self, *a):
-            raise oom
+            raise self.error
 
-    assert profiling._plan_gb_of(_Boom(), ()) == float("inf")
+    assert profiling._plan_gb_of(_Raises(oom), ()) == float("inf")
+    assert profiling._plan_gb_of(_Raises(other), ()) is None
 
-    class _Other:
-        def lower(self, *a):
-            raise RuntimeError("memory_analysis unsupported")
+    # the engine's probe keeps the contract, whether the wave program's
+    # compile or the staging before it is what raises
+    sim = FedSim(linear_regression_model(3), batch_size=4)
+    params = sim.init(jax.random.key(0))
+    data = {"x": jnp.zeros((2, 4, 3)), "y": jnp.zeros((2, 4))}
+    n = jnp.array([4, 4])
+    plan = sim.wave_plan_gb(params, data, n, jax.random.key(1))
+    assert plan is None or plan > 0
+    for error, want in ((oom, float("inf")), (other, None)):
+        monkeypatch.setattr(
+            sim, "_wave_program",
+            lambda *a, error=error, **k: (_Raises(error), lambda *w: w))
+        assert sim.wave_plan_gb(params, data, n, jax.random.key(1)) == want
 
-    assert profiling._plan_gb_of(_Other(), ()) is None
+        def stage_raises(*a, error=error, **k):
+            raise error
+
+        monkeypatch.setattr(sim, "_stage_wave", stage_raises)
+        assert sim.wave_plan_gb(params, data, n, jax.random.key(1)) == want
+        monkeypatch.undo()
 
 
-def test_wave_sweep_never_clobbers_recorded_artifact(tmp_path):
-    """An all-failure sweep must not overwrite an artifact containing
-    real hardware measurements."""
+def test_attention_sweep_never_clobbers_recorded_artifact(tmp_path):
+    """A sweep without one TPU timing must not overwrite an artifact
+    containing real hardware measurements."""
     import importlib.util
     import json
     import pathlib
 
     spec = importlib.util.spec_from_file_location(
-        "wave_sweep_under_test",
+        "attention_sweep_under_test",
         pathlib.Path(__file__).resolve().parent.parent
-        / "benchmarks" / "wave_sweep.py")
-    ws = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ws)
+        / "benchmarks" / "attention_sweep.py")
+    sweep = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(sweep)
 
     out = tmp_path / "sweep.json"
-    good = [{"wave_size": 64, "rounds_per_sec": 0.9, "platform": "tpu"}]
-    smoke = [{"wave_size": 64, "rounds_per_sec": 5.0, "platform": "cpu"}]
-    bad = [{"wave_size": 64, "failed": "timeout"}]
+    failed_path = str(out.with_name("sweep_failed.json"))
+    good = {"platform": "tpu",
+            "results": [{"L": 1024, "flash": {"256x256": 0.9}}]}
+    smoke = {"platform": "cpu",
+             "results": [{"L": 1024, "dense_ms": 5.0, "flash": {}}]}
+    bad = {"platform": "tpu",
+           "results": [{"L": 1024, "dense_error": "timeout", "flash": {}}]}
+    mixed = {"platform": "tpu", "results": good["results"] + bad["results"]}
 
     # no prior artifact: failures may write to the primary path
-    assert ws.resolve_out_path(str(out), bad) == str(out)
+    assert sweep.resolve_artifact_path(str(out), bad) == str(out)
     # prior artifact with TPU numbers: failures are diverted...
-    out.write_text(json.dumps({"results": good}))
-    assert ws.resolve_out_path(str(out), bad) == str(out.with_name(
-        "sweep_failed.json"))
+    out.write_text(json.dumps(good))
+    assert sweep.resolve_artifact_path(str(out), bad) == failed_path
     # ...and so is a CPU smoke run (plausible numbers, wrong platform)
-    assert ws.resolve_out_path(str(out), smoke) == str(out.with_name(
-        "sweep_failed.json"))
+    assert sweep.resolve_artifact_path(str(out), smoke) == failed_path
     # a run with a TPU success always takes the primary path
-    assert ws.resolve_out_path(str(out), good + bad) == str(out)
+    assert sweep.resolve_artifact_path(str(out), mixed) == str(out)
     # prior artifact that was itself TPU-less: overwrite is fine
-    out.write_text(json.dumps({"results": bad}))
-    assert ws.resolve_out_path(str(out), bad) == str(out)
-    out.write_text(json.dumps({"results": smoke}))
-    assert ws.resolve_out_path(str(out), bad) == str(out)
+    for prior in (bad, smoke):
+        out.write_text(json.dumps(prior))
+        assert sweep.resolve_artifact_path(str(out), bad) == str(out)
+    # an unreadable prior is clobber-safe
+    out.write_text("not json")
+    assert sweep.resolve_artifact_path(str(out), bad) == str(out)

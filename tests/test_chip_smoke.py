@@ -26,16 +26,6 @@ def _run_chip_smoke(*args, env=None, cwd=REPO, timeout=600):
         text=True, timeout=timeout, env=full_env, cwd=str(cwd))
 
 
-def _load_script(name):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        f"{name}_ut", REPO / "benchmarks" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def test_chip_smoke_without_a_chip_exits_nonzero_and_reports_nothing():
     proc = _run_chip_smoke()
     assert proc.returncode != 0
@@ -121,26 +111,15 @@ def test_make_mesh_refuses_more_devices_than_exist():
 
 
 def test_peak_table_refuses_an_unknown_device_kind():
-    """The live record keeps a reason; a benchmark turns it into an
-    error (tpu_suite._mfu). Neither ever falls back to some chip's
-    peak."""
-    from baton_tpu.obs.compute import peak_flops_for
+    """The live record keeps a reason in place of the number, and never
+    falls back to some chip's peak."""
+    from baton_tpu.obs.compute import compute_mfu, peak_flops_for
 
     peak, why = peak_flops_for("weird accelerator")
     assert peak is None and "weird accelerator" in why
-    suite = _load_script("tpu_suite")
-
-    class Dev:
-        device_kind = "weird accelerator"
-
-    class V5e:
-        device_kind = "TPU v5 lite"
-
-    assert suite._mfu(197e12 / 4, V5e()) == 0.25
-    assert suite._mfu(None, Dev()) is None
-    assert not suite.SMOKE
-    with pytest.raises(RuntimeError, match="weird accelerator"):
-        suite._mfu(1e12, Dev())
+    assert compute_mfu(197e12 / 4, 1.0, "TPU v5 lite") == (0.25, None)
+    mfu, why = compute_mfu(1e12, 1.0, "weird accelerator")
+    assert mfu is None and "weird accelerator" in why
 
 
 def test_flash_blocks_stay_tile_aligned_off_the_interpreter():
@@ -181,8 +160,7 @@ def test_flash_interpret_is_decided_in_one_place(monkeypatch):
 
 def test_importing_the_package_initialises_no_backend():
     """One process for each chip: a parent that only imports the
-    package (tpu_suite.py, wave_sweep.py) must leave the chip to its
-    children."""
+    package must leave the chip to the child it starts."""
     code = (
         "import baton_tpu, baton_tpu.obs.compute, baton_tpu.utils.profiling\n"
         "import baton_tpu.server.http_manager, baton_tpu.server.http_worker\n"
@@ -192,69 +170,6 @@ def test_importing_the_package_initialises_no_backend():
         [sys.executable, "-c", code], capture_output=True, text=True,
         timeout=300, cwd=str(REPO), env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert proc.returncode == 0, proc.stderr[-2000:]
-
-
-def test_bench_measures_nothing_off_the_tpu():
-    proc = subprocess.run(
-        [sys.executable, str(REPO / "bench.py")], capture_output=True,
-        text=True, timeout=300, cwd=str(REPO),
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""  # no JSON line, no rounds/sec
-    assert "nothing was measured" in proc.stderr
-
-
-class _Proc:
-    def __init__(self, returncode, stdout="", stderr=""):
-        self.returncode, self.stdout, self.stderr = returncode, stdout, stderr
-
-
-def test_tpu_suite_exits_nonzero_when_a_stage_fails(tmp_path, monkeypatch):
-    """A failed stage is recorded AND fails the suite; a stage the plan
-    guard skipped is a result."""
-    suite = _load_script("tpu_suite")
-    monkeypatch.setattr(suite, "OUT_DIR", str(tmp_path))
-    monkeypatch.setattr(suite, "OUT_JSONL", str(tmp_path / "r.jsonl"))
-    outcomes = {
-        "bert": _Proc(1, stderr="XlaRuntimeError: Mosaic failed to compile"),
-        "vit": _Proc(0, stdout=json.dumps(
-            {"stage": "vit", "skipped": "static HBM plan exceeds budget"})),
-    }
-    monkeypatch.setattr(
-        suite.subprocess, "run",
-        lambda args, **kw: outcomes[args[args.index("--child") + 1]])
-
-    monkeypatch.setattr(sys, "argv", ["tpu_suite.py", "--stages", "vit"])
-    suite.main()  # a guarded skip alone: exit 0
-
-    monkeypatch.setattr(sys, "argv", ["tpu_suite.py", "--stages", "bert,vit"])
-    with pytest.raises(SystemExit, match="failed stages: bert"):
-        suite.main()
-    rows = [json.loads(ln) for ln in
-            (tmp_path / "r.jsonl").read_text().splitlines()]
-    assert [r["stage"] for r in rows] == ["vit", "bert", "vit"]
-    assert rows[1]["failed"] == "rc=1" and "Mosaic" in rows[1]["stderr_tail"]
-
-
-def test_wave_sweep_exits_nonzero_when_a_setting_fails(tmp_path, monkeypatch):
-    ws = _load_script("wave_sweep")
-    good = json.dumps({"wave_size": 16, "platform": "tpu",
-                       "rounds_per_sec": 1.0, "peak_hbm_gb": 9.5,
-                       "compile_s": 30.0})
-    monkeypatch.setattr(
-        ws.subprocess, "run",
-        lambda args, **kw: _Proc(0, stdout=good) if args[-1] == "16"
-        else _Proc(1, stderr="RESOURCE_EXHAUSTED: out of memory"))
-    out = tmp_path / "sweep.json"
-    monkeypatch.setattr(sys, "argv", ["wave_sweep.py", "--waves", "16",
-                                      "--out", str(out)])
-    ws.main()
-    monkeypatch.setattr(sys, "argv", ["wave_sweep.py", "--waves", "16,32",
-                                      "--out", str(out)])
-    with pytest.raises(SystemExit, match=r"\[32\] failed"):
-        ws.main()
-    results = json.loads(out.read_text())["results"]
-    assert results[1]["failed"] == "oom"  # still recorded, with its cause
 
 
 def test_compute_record_counts_the_chips_the_round_used(nprng):

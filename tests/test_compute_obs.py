@@ -74,18 +74,6 @@ def test_mfu_formula_matches_bench_headline():
         assert mfu is None and isinstance(why, str) and why
 
 
-def test_bench_imports_the_shared_constants():
-    # bench.py must consume obs/compute.py, not re-declare the math
-    import importlib.util
-    import pathlib
-
-    bench_path = pathlib.Path(__file__).resolve().parents[1] / "bench.py"
-    src = bench_path.read_text(encoding="utf-8")
-    assert "from baton_tpu.obs.compute import" in src
-    # the old duplicated literals must be gone from bench's own body
-    assert src.count("1.11e9") == 0
-
-
 def test_register_model_flops_roundtrip():
     register_model_flops("toynet_test", 123.0, name_prefixes=["toynet"])
     assert model_family_of("toynet_v2") == ("toynet_test", None)

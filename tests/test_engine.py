@@ -269,10 +269,7 @@ def test_auto_wave_size_from_memory_plan(nprng, monkeypatch):
 
     # a budget under the full-cohort plan but above the halved plans:
     # auto must halve at least once and return a smaller wave
-    from baton_tpu.utils.profiling import fedsim_wave_plan_gb
-
-    full_plan = fedsim_wave_plan_gb(sim, params, data, jnp.asarray(n),
-                                    jax.random.key(0))
+    full_plan = sim.wave_plan_gb(params, data, n, jax.random.key(0))
     if full_plan is not None:  # CPU surfaces memory analysis today
         w = sim.auto_wave_size(params, data, n,
                                budget_gb=full_plan * 0.9)

@@ -1,14 +1,17 @@
 """The declarative partition layer (parallel/partition.py): rule
 ordering, unmatched fallback + counter, regex matching over nested and
-LoRA paths, NamedSharding placement round-trips, and the repo-wide ban
-on ad-hoc ``PartitionSpec`` construction outside the one module."""
+LoRA paths, NamedSharding placement round-trips, leaf-for-leaf equality
+with the recorded pre-unification specs, and the repo-wide ban on
+ad-hoc ``PartitionSpec`` construction outside the one module."""
 
 import ast
+import json
 import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from baton_tpu.parallel.partition import (
@@ -17,6 +20,7 @@ from baton_tpu.parallel.partition import (
     DEFAULT_RULE_SETS,
     Rule,
     RuleSet,
+    client_spec,
     client_stacked_rules,
     match_partition_rules,
     replicated_spec,
@@ -84,6 +88,56 @@ def test_default_tables_cover_model_zoo_params():
     reset_unmatched_leaf_count()
     for make in DEFAULT_RULE_SETS.values():
         make().tree_specs(params)
+    assert unmatched_leaf_count() == 0
+
+
+#: the specs the pre-unification ``transformer_tp_spec`` produced,
+#: recorded once before the per-path implementations were deleted
+LEGACY_SPECS = pathlib.Path(__file__).with_name("legacy_partition_specs.json")
+
+
+def _family_params(family):
+    """The param tree the legacy baseline was recorded from (same tiny
+    config, same init key)."""
+    from baton_tpu.models.bert import BertConfig, bert_classifier_model
+    from baton_tpu.models.llama import (
+        LlamaConfig,
+        llama_lm_model,
+        llama_lora_target,
+    )
+    from baton_tpu.models.lora import lora_wrap
+    from baton_tpu.models.moe import MoEConfig
+
+    model = {
+        "llama_tiny": lambda: llama_lm_model(LlamaConfig.tiny()),
+        "llama_tiny_moe": lambda: llama_lm_model(
+            LlamaConfig.tiny(moe=MoEConfig(n_experts=4, top_k=2))),
+        "bert_tiny": lambda: bert_classifier_model(BertConfig.tiny()),
+        "llama_tiny_lora": lambda: lora_wrap(
+            llama_lm_model(LlamaConfig.tiny()), rank=4,
+            target=llama_lora_target),
+    }[family]()
+    return model.init(jax.random.key(0))
+
+
+@pytest.mark.parametrize(
+    "family",
+    ["llama_tiny", "llama_tiny_moe", "bert_tiny", "llama_tiny_lora"])
+def test_unified_rules_reproduce_the_recorded_legacy_specs(family):
+    """The unified rule tables give every leaf of the recorded model
+    families the PartitionSpec the deleted per-path implementations
+    gave it, and none falls through to the unmatched-replicated
+    fallback — a diverged leaf is a silently different sharding."""
+    legacy = json.loads(LEGACY_SPECS.read_text())
+    assert str(client_spec()) == legacy["client_axis_spec"]
+    assert str(replicated_spec()) == legacy["replicated_spec"]
+    reset_unmatched_leaf_count()
+    got = transformer_rules().describe(_family_params(family))
+    want = legacy["families"][family]
+    diverged = {path: (want.get(path), got.get(path))
+                for path in sorted(set(want) | set(got))
+                if want.get(path) != got.get(path)}
+    assert not diverged, f"{family}: {{path: (legacy, unified)}} {diverged}"
     assert unmatched_leaf_count() == 0
 
 
