@@ -9,10 +9,16 @@ update inline — so it can be vmapped over thousands of simulated clients
 and sharded over a TPU mesh with zero Python in the hot path.
 
 Static-shape discipline (XLA): client datasets are padded to a fixed
-``capacity`` divisible by ``batch_size``; a per-row validity mask derived
-from the *dynamic* ``n_samples`` scalar zeroes the loss/grad contribution
-of padding exactly. Shuffling is a ``jax.random.permutation`` of row
-indices per epoch (replaces torch.randperm, demo.py:33).
+``capacity``, real rows first; a per-row validity mask derived from the
+*dynamic* ``n_samples`` scalar zeroes the loss/grad contribution of
+padding exactly. Shuffling is a ``jax.random.permutation`` of row
+indices per epoch (replaces torch.randperm, demo.py:33). Any capacity
+is taken: an epoch is ``ceil(capacity / batch_size)`` optimizer steps
+(as many as ``torch.split`` makes slices, demo.py:34) and its rows are
+shared equally among them, so every step has one shape and the epoch
+stays one scan: ``batch_size`` rows a step where it divides the
+capacity, 24 + 24 for 48 rows at batch 32 (the reference's last slice
+is the short one: 32 + 16).
 
 Loss accounting fixes the reference's biased running mean (utils.py:85-88,
 SURVEY §2.6): per-epoch loss is the exact sample-weighted mean
@@ -38,12 +44,9 @@ Regularizer = Callable[[Params, Params], jax.Array]
 
 
 def num_batches(capacity: int, batch_size: int) -> int:
-    if capacity % batch_size != 0:
-        raise ValueError(
-            f"padded capacity {capacity} must be divisible by batch_size {batch_size}; "
-            "use baton_tpu.ops.padding.pad_dataset"
-        )
-    return capacity // batch_size
+    """Optimizer steps an epoch over ``capacity`` rows takes: the fewest
+    steps of at most ``batch_size`` rows."""
+    return -(-capacity // batch_size)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,8 +106,8 @@ class LocalTrainer:
 
     def steps_per_round(self, capacity: int, n_epochs: int) -> int:
         """Optimizer steps one ``train`` call executes on device: the
-        scan runs every padded batch every epoch (masked no-ops included
-        — they still cost the FLOPs)."""
+        scan runs every batch of the padded capacity every epoch (masked
+        no-ops included — they still cost the FLOPs)."""
         return int(n_epochs) * num_batches(int(capacity), self.batch_size)
 
     # donation decided no: params is the caller's broadcast anchor —
@@ -142,6 +145,10 @@ class LocalTrainer:
         leaves = jax.tree_util.tree_leaves(data)
         capacity = leaves[0].shape[0]
         nb = num_batches(capacity, self.batch_size)
+        # rows a step: the batch size where it divides the capacity, else
+        # the capacity shared equally among the same number of steps
+        step_rows = -(-capacity // nb)
+        n_short = nb * step_rows - capacity  # fewer than nb
         n_samples = jnp.asarray(n_samples, jnp.int32)
 
         def merged(p):
@@ -217,8 +224,13 @@ class LocalTrainer:
                 if "mask" in shuffled:
                     mask = mask * shuffled["mask"].astype(jnp.float32)
                 shuffled["mask"] = mask
+                if n_short:
+                    # equal steps need a few rows more: masked zeros
+                    shuffled = jax.tree_util.tree_map(
+                        lambda a: jnp.concatenate([a, jnp.zeros(
+                            (n_short,) + a.shape[1:], a.dtype)]), shuffled)
                 batched = jax.tree_util.tree_map(
-                    lambda a: a.reshape((nb, self.batch_size) + a.shape[1:]),
+                    lambda a: a.reshape((nb, step_rows) + a.shape[1:]),
                     shuffled
                 )
             (p, os, _), (loss_sums, counts) = jax.lax.scan(

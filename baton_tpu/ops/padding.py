@@ -4,11 +4,16 @@ Clients hold different amounts of data (the reference demo draws
 ``32·randint(5,20)`` samples per client per round, demo.py:52-59). XLA
 wants static shapes, and the sample-weighted FedAvg math wants *exact*
 per-client counts (manager.py:119-126). The contract: every client
-dataset is padded (with zeros) to a shared ``capacity`` divisible by the
-batch size, and the true row count travels alongside as ``n_samples``.
+dataset is padded (with zeros) to a shared ``capacity``, its real rows
+first, and the true row count travels alongside as ``n_samples``.
 Validity masks are derived from ``n_samples`` inside the jitted trainer,
 so padding never contributes to losses, gradients, or aggregation
-weights.
+weights. Any capacity is taken: where the batch size does not divide
+it the trainer shares the rows equally among the epoch's steps
+(core/training.py), and ``FedSim`` computes only the rows its cohort
+holds, whatever the capacity it is handed (parallel/engine.py).
+:func:`stack_client_datasets` still rounds its capacity up to a batch
+multiple, which is what the engines beside ``FedSim`` step through.
 """
 
 from __future__ import annotations

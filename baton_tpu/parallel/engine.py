@@ -62,6 +62,11 @@ from baton_tpu.utils.profiling import (
 
 Params = Any
 
+# Rows are staged in granules of a quarter batch, so that a ragged
+# federation whose largest client changes from round to round compiles
+# at most 4 x capacity / batch_size wave programs.
+ROW_GRANULES_PER_BATCH = 4
+
 
 @dataclasses.dataclass
 class RoundResult:
@@ -109,6 +114,14 @@ class FedSim:
     (see :func:`baton_tpu.ops.padding.stack_client_datasets`) and
     ``n_samples`` is ``[C]`` — client ``c``'s true row count, which is
     also its FedAvg weight (reference manager.py:119-126 semantics).
+    A client's real rows come first: rows ``[n_samples[c], capacity)``
+    are padding, masked out of every loss and gradient and never read
+    for their values. Any ``capacity`` is taken. A round computes only
+    the rows its cohort holds: every wave is staged at the cohort's
+    largest ``n_samples`` rounded up to a quarter of the batch size
+    (:meth:`_rows_to_stage`), never more than ``capacity``, and the
+    trainer shares those rows equally among an epoch's steps where the
+    batch size does not divide them (core/training.py).
     """
 
     def __init__(
@@ -451,12 +464,21 @@ class FedSim:
         )
         return data, n_samples, rngs
 
+    def _rows_to_stage(self, data, n_samples: np.ndarray):
+        """``(rows, capacity)``: the rows a client every wave of this
+        cohort is staged at, and the rows a client ``data`` holds.
+        ``n_samples`` is the cohort's, on the host."""
+        capacity = int(jax.tree_util.tree_leaves(data)[0].shape[1])
+        granule = max(1, self.trainer.batch_size // ROW_GRANULES_PER_BATCH)
+        largest = max(1, int(n_samples.max(initial=0)))
+        return min(capacity, round_up(largest, granule)), capacity
+
     def _stage_wave(self, data, n_samples, rngs, start: int, stop: int,
-                    wave_size: int, in_shard):
-        """Clients ``[start, stop)`` as one wave's inputs: sliced, padded
-        to ``wave_size`` with phantom clients and, on a mesh, placed on
-        ``in_shard``."""
-        d = jax.tree_util.tree_map(lambda a: a[start:stop], data)
+                    wave_size: int, in_shard, rows: int):
+        """Clients ``[start, stop)`` as one wave's inputs: sliced to
+        their first ``rows`` rows, padded to ``wave_size`` with phantom
+        clients and, on a mesh, placed on ``in_shard``."""
+        d = jax.tree_util.tree_map(lambda a: a[start:stop, :rows], data)
         n = n_samples[start:stop]
         r = rngs[start:stop]
         d, n, r = self._pad_wave(d, n, r, wave_size)
@@ -477,6 +499,7 @@ class FedSim:
         With ``per_shard`` it is one device's share of that wave:
         ``wave_size`` over the client-axis extent, inputs unplaced."""
         params, frozen = self._split(params)
+        rows, _ = self._rows_to_stage(data, np.asarray(n_samples))
         n_samples = jnp.asarray(n_samples)
         c = int(n_samples.shape[0])
         rngs = jax.random.split(rng, c)
@@ -488,7 +511,8 @@ class FedSim:
         program, bind = self._wave_program(n_epochs, robust=False,
                                            per_shard=per_shard)
         d, n, r = self._stage_wave(data, n_samples, rngs, 0,
-                                   min(wave_size, c), wave_size, in_shard)
+                                   min(wave_size, c), wave_size, in_shard,
+                                   rows)
         return program, bind(params, frozen, d, n, r)
 
     def wave_plan_gb(self, params, data, n_samples, key,
@@ -605,18 +629,24 @@ class FedSim:
             with annotate("baton.round.prepare"):
                 orig_params = params
                 params, frozen = self._split(params)
+                # the round's one host copy of n_samples (a fetch only
+                # where the caller brought a device array): the rows to
+                # stage here, the compute record after the sync
+                n_host = np.asarray(n_samples)
                 n_samples = jnp.asarray(n_samples)
                 if client_indices is not None:
                     idx = jnp.asarray(client_indices)
                     data = jax.tree_util.tree_map(
                         lambda a: jnp.take(a, idx, axis=0), data)
                     n_samples = jnp.take(n_samples, idx, axis=0)
+                    n_host = n_host[np.asarray(client_indices)]
                 c = int(n_samples.shape[0])
                 rngs = jax.random.split(rng, c)
+                rows, capacity = self._rows_to_stage(data, n_host)
 
                 if wave_size == "auto":
                     cache_key = (
-                        c, n_epochs,
+                        c, n_epochs, rows,
                         tuple(sorted((k, v.shape, str(v.dtype))
                                      for k, v in data.items())),
                     )
@@ -659,10 +689,11 @@ class FedSim:
                 stop = min(start + wave_size, c)
                 real = stop - start
                 with annotate("baton.round.stage", wave=wave, real=real,
-                              padded=wave_size - real):
+                              padded=wave_size - real, rows=rows,
+                              capacity=capacity):
                     d, n, r = self._stage_wave(
                         data, n_samples, rngs, start, stop, wave_size,
-                        in_shard)
+                        in_shard, rows)
                 with annotate("baton.round.dispatch", wave=wave):
                     if robust:
                         cp, closs = program(*bind(params, frozen, d, n, r))
@@ -725,20 +756,16 @@ class FedSim:
                 jax.block_until_ready(lsum_acc)
             train_s = time.perf_counter() - t_waves0
             with annotate("baton.round.record"):
-                capacity = next(
-                    (int(a.shape[1]) for a in data.values()
-                     if getattr(a, "ndim", 0) >= 2), 1)
-                bsz = max(1, int(self.trainer.batch_size))
-                sig = (c, int(wave_size), int(n_epochs), robust,
+                sig = (c, int(wave_size), int(n_epochs), robust, rows,
                        tuple(sorted((k, tuple(v.shape), str(v.dtype))
                                     for k, v in data.items())))
                 self.last_compute = self.compute_probe.record_round(
                     key="run_round",
                     signature=sig,
                     train_s=train_s,
-                    n_samples=float(np.asarray(n_samples).sum()),
+                    n_samples=float(n_host.sum()),
                     n_epochs=n_epochs,
-                    steps=c * n_epochs * -(-capacity // bsz),
+                    steps=c * self.trainer.steps_per_round(rows, n_epochs),
                     n_chips=(int(self.mesh.devices.size)
                              if self.mesh is not None else 1),
                 )
@@ -808,6 +835,7 @@ class FedSim:
         """
         if rng is None:
             rng = jax.random.key(0)
+        rows, _ = self._rows_to_stage(data, np.asarray(n_samples))
         n_samples = jnp.asarray(n_samples)
         c = int(n_samples.shape[0])
         rngs = jax.random.split(rng, c)
@@ -819,7 +847,7 @@ class FedSim:
         for start in range(0, c, wave):
             stop = min(start + wave, c)
             d, n, r = self._stage_wave(data, n_samples, rngs, start, stop,
-                                       wave, in_shard)
+                                       wave, in_shard, rows)
             sums = self._eval_sums_vmap(params, d, n, r)
             for k, v in sums.items():
                 totals[k] = totals.get(k, 0.0) + float(v)
@@ -859,6 +887,7 @@ class FedSim:
         """
         if rng is None:
             rng = jax.random.key(0)
+        rows, _ = self._rows_to_stage(data, np.asarray(n_samples))
         n_samples = jnp.asarray(n_samples)
         c = int(n_samples.shape[0])
         rngs = jax.random.split(rng, c)
@@ -872,7 +901,7 @@ class FedSim:
             # same client-sharded placement as evaluate_round: the
             # vmapped forward partitions over the mesh via GSPMD
             d, n, r = self._stage_wave(data, n_samples, rngs, start, stop,
-                                       wave, in_shard)
+                                       wave, in_shard, rows)
             sums = self._eval_sums_per_client(params, d, n, r)
             parts.append(jax.tree_util.tree_map(
                 lambda a: np.asarray(a[: stop - start]), sums
@@ -1085,7 +1114,9 @@ class FedSim:
         the whole training run: no per-round host round-trip, and XLA
         may overlap the round boundary with compute. Identical math to
         ``run_rounds`` (same fold_in round rngs; bitwise-equal when the
-        cohort needs no phantom padding).
+        cohort needs no phantom padding and fills its capacity: this
+        path computes every row it is handed, where :meth:`run_round`
+        stages the rows the cohort holds and so shuffles fewer).
         """
         if self.aggregator[0] != "mean":
             raise NotImplementedError(

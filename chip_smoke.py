@@ -59,8 +59,11 @@ class Sizes:
     ring_shape: tuple
 
 
+# 12 rounds, as the benchmark judges a loss (round 12 below round 1): at
+# this learning rate it rises for its first three or four rounds, and
+# whether round 5 is back under round 1 hangs on the shuffle (PR 30)
 CHIP = Sizes(clients=32, samples=48, batch=32, image=32,
-             rounds_after_compile=4, flash_shape=(4, 8, 4096, 64),
+             rounds_after_compile=11, flash_shape=(4, 8, 4096, 64),
              decoder_len=4096, ring_shape=(1, 8, 4096, 64))
 REHEARSAL = Sizes(clients=4, samples=8, batch=4, image=8,
                   rounds_after_compile=3, flash_shape=(1, 2, 256, 64),

@@ -342,6 +342,7 @@ def _eager_round(sim, params, data, n_samples, rng, wave_size,
     ``jit``. Returns ``(params, loss_history, n_samples_total,
     server_opt_state)``."""
     trainable, frozen = sim._split(params)
+    rows, _ = sim._rows_to_stage(data, np.asarray(n_samples))
     n_samples = jnp.asarray(n_samples)
     c = int(n_samples.shape[0])
     rngs = jax.random.split(rng, c)
@@ -352,7 +353,7 @@ def _eager_round(sim, params, data, n_samples, rng, wave_size,
     for start in range(0, c, wave_size):
         d, n, r = sim._stage_wave(data, n_samples, rngs, start,
                                   min(start + wave_size, c), wave_size,
-                                  in_shard)
+                                  in_shard, rows)
         psum, lsum, wtot, _ = program(*bind(trainable, frozen, d, n, r))
         psum_acc = psum if psum_acc is None else _acc_tree_add(psum_acc, psum)
         lsum_acc = lsum if lsum_acc is None else lsum_acc + lsum
@@ -464,3 +465,201 @@ def test_fold_program_is_bit_equal_to_the_eager_fold(linear_setup, case):
                               np.asarray(params["w"]))
     if case == "bfloat16_leaf":
         assert got_p["b"].dtype == jnp.bfloat16
+
+
+# ----------------------------------------------------------------------
+# a round computes the rows its cohort holds (ISSUE 30)
+def _padded_cohort(sizes, capacity=64, dim=10, seed=0):
+    """Clients of ``sizes`` real rows in ``capacity``; the rows past a
+    client's own are poisoned, so a row that is read shows."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(len(sizes), capacity, dim)).astype(np.float32)
+    y = x @ np.asarray(DEMO_COEF, np.float32)[:dim]
+    for c, n in enumerate(sizes):
+        x[c, n:], y[c, n:] = 1e4, -1e4
+    return ({"x": jnp.asarray(x), "y": jnp.asarray(y)},
+            np.asarray(sizes, np.int32))
+
+
+def _cut(data, rows):
+    return jax.tree_util.tree_map(lambda a: a[:, :rows], data)
+
+
+def _assert_trees_equal(got, want):
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for x, y in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def _staged(monkeypatch):
+    """The attributes of every ``baton.round.stage`` span opened from
+    here on."""
+    from baton_tpu.parallel import engine
+    stages = []
+    annotate = engine.annotate
+
+    def recording(name, **attrs):
+        if name == "baton.round.stage":
+            stages.append(attrs)
+        return annotate(name, **attrs)
+
+    monkeypatch.setattr(engine, "annotate", recording)
+    return stages
+
+
+# (FedSim arguments, run_round arguments)
+TRIM_CASES = {
+    "one_wave": ({}, {}),
+    "waves_short_last": ({}, {"wave_size": 4}),
+    "client_indices": ({}, {"client_indices": np.asarray([5, 0, 3]),
+                            "wave_size": 2}),
+    "trainable": ({"trainable": lambda path, leaf: path.endswith("w")},
+                  {"wave_size": 4}),
+    "mesh2": ({"mesh": 2}, {}),
+    "robust": ({"aggregator": "median"}, {"wave_size": 4}),
+    "momentum_two_epochs": ({"optimizer": optax.sgd(0.01, momentum=0.9)},
+                            {"n_epochs": 2}),
+    "numpy_arrays": ({"numpy": True}, {"wave_size": 4}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TRIM_CASES))
+def test_round_on_padded_rows_equals_round_on_the_rows_cut_by_the_caller(
+        case, monkeypatch):
+    """6 clients of 48 real rows handed over in 64 (a batch multiple, as
+    ``stack_client_datasets`` pads them) at batch 32: the engine stages
+    48 rows, and the round is the one the caller gets by cutting the
+    arrays to 48 rows first, bit for bit. The trainer takes two steps
+    of 24."""
+    sim_kw, round_kw = TRIM_CASES[case]
+    sim_kw = dict(sim_kw)
+    data, n = _padded_cohort([48] * 6)
+    cut = _cut(data, 48)
+    if sim_kw.pop("numpy", False):
+        data = {k: np.asarray(v) for k, v in data.items()}
+    if "mesh" in sim_kw:
+        sim_kw["mesh"] = make_mesh(sim_kw["mesh"])
+        data = shard_client_arrays(data, sim_kw["mesh"])
+        cut = shard_client_arrays(cut, sim_kw["mesh"])
+    model = linear_regression_model(10)
+    sim = FedSim(model, batch_size=32, learning_rate=0.01, **sim_kw)
+    params = model.init(jax.random.key(0))
+    stages = _staged(monkeypatch)
+    got = sim.run_round(params, data, n, jax.random.key(1), **round_kw)
+    assert stages and all(
+        s["rows"] == 48 and s["capacity"] == 64 for s in stages)
+    del stages[:]
+    want = sim.run_round(params, cut, n, jax.random.key(1), **round_kw)
+    assert all(s["rows"] == 48 and s["capacity"] == 48 for s in stages)
+    _assert_trees_equal(
+        (got.params, got.loss_history, got.client_losses,
+         got.n_samples_total),
+        (want.params, want.loss_history, want.client_losses,
+         want.n_samples_total))
+    assert np.isfinite(np.asarray(got.loss_history)).all()
+    assert sim.last_compute["steps"] == (
+        len(round_kw.get("client_indices", n)) * round_kw.get("n_epochs", 1)
+        * 2)
+
+
+@pytest.mark.parametrize("sizes,rows", [
+    ((5, 17, 33, 48), 48),   # the largest client decides
+    ((5, 17, 33, 41), 48),   # rounded up to a quarter of the batch
+    ((5, 17, 33, 40), 40),
+    ((64, 1, 1, 1), 64),     # never more than the caller handed over
+    ((3, 1, 0, 2), 8),       # fewer rows than a batch: one step of 8
+    ((0, 0, 0, 0), 8),       # an empty cohort still stages a granule
+])
+def test_a_ragged_cohort_stages_its_largest_client_in_quarter_batches(
+        sizes, rows, monkeypatch):
+    data, n = _padded_cohort(sizes)
+    model = linear_regression_model(10)
+    sim = FedSim(model, batch_size=32, learning_rate=0.01)
+    assert sim._rows_to_stage(data, n) == (rows, 64)
+    stages = _staged(monkeypatch)
+    params = model.init(jax.random.key(0))
+    got = sim.run_round(params, data, n, jax.random.key(1), wave_size=2)
+    assert [(s["rows"], s["capacity"]) for s in stages] == [(rows, 64)] * 2
+    want = sim.run_round(params, _cut(data, rows), n, jax.random.key(1),
+                         wave_size=2)
+    _assert_trees_equal((got.params, got.loss_history),
+                        (want.params, want.loss_history))
+    assert float(got.n_samples_total) == sum(sizes)
+
+
+def test_a_smaller_largest_client_in_the_same_granule_compiles_nothing():
+    """Largest client 48, then 41: both rounds stage 48 rows and run one
+    wave program (compile events as ``tests/test_round_spans.py`` and
+    ``fedbench/run.py`` count them)."""
+    compiles = []
+
+    def listener(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    model = linear_regression_model(10)
+    sim = FedSim(model, batch_size=32, learning_rate=0.01)
+    params = model.init(jax.random.key(0))
+    data, n = _padded_cohort((5, 17, 33, 48))
+    first = sim.run_round(params, data, n, jax.random.key(1), wave_size=2)
+    data, n = _padded_cohort((5, 17, 33, 41), seed=1)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        jax.jit(lambda x: x * 5 + 2)(jnp.zeros(3))  # a compile is heard
+        heard = len(compiles)
+        second = sim.run_round(first.params, data, n, jax.random.key(2),
+                               wave_size=2)
+        jax.block_until_ready(second.params)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert heard >= 1 and len(compiles) == heard
+    assert sim.last_compute["cache_hit"]  # the record's signature too
+
+
+@pytest.mark.parametrize("mesh", [None, 2])
+def test_evaluators_score_the_staged_rows_and_return_what_they_did(mesh):
+    """``evaluate_round`` and ``evaluate_clients`` on 48 real rows in 64
+    equal the same calls on the arrays cut to 48 rows, and the masked
+    means written out over all 64."""
+    data, n = _padded_cohort((48, 17, 33, 48))
+    cut = _cut(data, 48)
+    full = data
+    model = linear_regression_model(10)
+    if mesh:
+        mesh = make_mesh(mesh)
+        data, cut = (shard_client_arrays(d, mesh) for d in (data, cut))
+    sim = FedSim(model, batch_size=32, mesh=mesh)
+    params = model.init(jax.random.key(3))
+    got = sim.evaluate_round(params, data, n, wave_size=2)
+    assert got == sim.evaluate_round(params, cut, n, wave_size=2)
+    per = sim.evaluate_clients(params, data, n, wave_size=2)
+    per_cut = sim.evaluate_clients(params, cut, n, wave_size=2)
+    assert per["fairness"] == per_cut["fairness"]
+    _assert_trees_equal(per["per_client"], per_cut["per_client"])
+    losses = np.stack([
+        np.asarray(model.per_example_loss(
+            params, {k: v[c] for k, v in full.items()}, jax.random.key(0)))
+        for c in range(4)])
+    mask = np.arange(64)[None, :] < n[:, None]
+    np.testing.assert_allclose(
+        per["per_client"]["loss"], (losses * mask).sum(1) / n, rtol=1e-6)
+    np.testing.assert_allclose(
+        got["loss"], (losses * mask).sum() / n.sum(), rtol=1e-6)
+    assert got["n"] == n.sum()
+
+
+def test_wave_plan_and_lowering_see_the_staged_rows():
+    data, n = _padded_cohort([48] * 4)
+    model = linear_regression_model(10)
+    sim = FedSim(model, batch_size=32)
+    params = model.init(jax.random.key(0))
+    _, args = sim._first_wave(params, data, n, jax.random.key(1), 1, None)
+    staged = args[3]  # (sim, params, frozen, data, n_samples, rngs, epochs)
+    assert staged["x"].shape == (4, 48, 10) and staged["y"].shape == (4, 48)
+    text = sim.lower_wave(params, data, n, jax.random.key(1)).as_text()
+    assert "4x48x10" in text and "4x64x10" not in text
+    assert sim.wave_plan_gb(params, data, n, jax.random.key(1)) == (
+        sim.wave_plan_gb(params, _cut(data, 48), n, jax.random.key(1)))

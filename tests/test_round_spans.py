@@ -1,9 +1,9 @@
 """The round's host spans and the wave program's scopes (ISSUE 24).
 
 Three things: the compiled wave program (``FedSim.lower_wave``) carries
-the scopes in its ``op_name``s, forward and backward apart; one CPU
-profiler session (the only one these tests start) shows the
-``baton.round.*`` spans of ``FedSim.run_round`` nested and counted; and
+the scopes in its ``op_name``s, forward and backward apart; a CPU
+profiler session shows the ``baton.round.*`` spans of
+``FedSim.run_round`` nested and counted, with their attributes; and
 every path through ``run_round`` closes every span it opens."""
 
 import collections
@@ -155,28 +155,18 @@ def test_lower_wave_refuses_what_it_does_not_lower(kwargs):
 
 
 # ------------------------------------------- (b) one profiler session
-@pytest.fixture(scope="module")
-def session(tmp_path_factory):
-    """Two rounds of 6 clients in waves of 4 (the second wave has two
-    phantom clients) under the one CPU profiler session of this file,
-    and the same first round outside any session."""
+def _profiled(work, tdir):
+    """``(work(), spans)``: ``work`` under a CPU profiler session, and
+    the ``baton.*`` spans it left, ``(name, start, end, attributes)`` in
+    order of their start."""
     from jax.profiler import ProfileData
 
-    data, n = _linear_cohort()
-    sim = _linear_sim()
-    params = sim.init(jax.random.key(0))
-    outside = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
-    jax.block_until_ready(outside.params)
-
-    tdir = str(tmp_path_factory.mktemp("trace"))
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     jax.profiler.start_trace(tdir, profiler_options=options)
     try:
-        first = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
-        second = sim.run_round(first.params, data, n, jax.random.key(2),
-                               wave_size=4)
-        jax.block_until_ready(second.params)
+        result = work()
+        jax.block_until_ready(result)
     finally:
         jax.profiler.stop_trace()
     spans = []
@@ -186,8 +176,29 @@ def session(tmp_path_factory):
                 spans += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
                            dict(ev.stats))
                           for ev in line.events if ev.name.startswith("baton.")]
-    return {"spans": sorted(spans, key=lambda s: s[1]),
-            "outside": outside, "inside": first}
+    return result, sorted(spans, key=lambda s: s[1])
+
+
+@pytest.fixture(scope="module")
+def session(tmp_path_factory):
+    """Two rounds of 6 clients in waves of 4 (the second wave has two
+    phantom clients) under a CPU profiler session, and the same first
+    round outside any session."""
+    data, n = _linear_cohort()
+    sim = _linear_sim()
+    params = sim.init(jax.random.key(0))
+    outside = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
+    jax.block_until_ready(outside.params)
+
+    def two_rounds():
+        first = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
+        second = sim.run_round(first.params, data, n, jax.random.key(2),
+                               wave_size=4)
+        return first, second.params
+
+    (first, _), spans = _profiled(two_rounds,
+                                  str(tmp_path_factory.mktemp("trace")))
+    return {"spans": spans, "outside": outside, "inside": first}
 
 
 @pytest.mark.parametrize("name,count", [
@@ -219,11 +230,33 @@ def test_session_spans_nest_in_their_round_in_order(session):
 
 def test_session_stage_counts_the_phantom_clients(session):
     stages = [s[3] for s in session["spans"] if s[0] == "baton.round.stage"]
-    assert stages == [{"wave": 0, "real": 4, "padded": 0},
-                      {"wave": 1, "real": 2, "padded": 2}] * 2
+    # batch 4 divides the 8 rows the largest client fills: none is cut
+    assert stages == [
+        {"wave": 0, "real": 4, "padded": 0, "rows": 8, "capacity": 8},
+        {"wave": 1, "real": 2, "padded": 2, "rows": 8, "capacity": 8}] * 2
     dispatches = [s[3] for s in session["spans"]
                   if s[0] == "baton.round.dispatch"]
     assert dispatches == [{"wave": 0}, {"wave": 1}] * 2
+
+
+def test_session_stage_says_the_rows_it_staged(tmp_path_factory):
+    """Clients of 48 real rows handed over in 64 at batch 32 (ISSUE 30):
+    in a real session every ``stage`` span carries the 48 rows a client
+    the wave computes beside the 64 it was handed."""
+    x = np.ones((6, 64, 4), np.float32)
+    data = {"x": jnp.asarray(x), "y": jnp.asarray(x.sum(-1, keepdims=True))}
+    n = np.full((6,), 48, np.int32)
+    sim = FedSim(linear_regression_model(4), batch_size=32,
+                 learning_rate=0.05)
+    params = sim.init(jax.random.key(0))
+    _, spans = _profiled(
+        lambda: sim.run_round(params, data, n, jax.random.key(1),
+                              wave_size=4).params,
+        str(tmp_path_factory.mktemp("trace_rows")))
+    stages = [s[3] for s in spans if s[0] == "baton.round.stage"]
+    assert stages == [
+        {"wave": 0, "real": 4, "padded": 0, "rows": 48, "capacity": 64},
+        {"wave": 1, "real": 2, "padded": 2, "rows": 48, "capacity": 64}]
 
 
 def test_session_fold_launches_one_program(session):
@@ -350,7 +383,7 @@ def test_every_path_opens_and_closes_its_spans(recorder, path):
 
 
 @pytest.mark.parametrize("where,sim_kw,round_kw,error", [
-    ("prepare", {}, {"client_indices": np.asarray([99])}, None),
+    ("prepare", {}, {"client_indices": np.asarray([99])}, IndexError),
     ("prepare", {"aggregator": "median"}, {"wave_size": "auto"},
      NotImplementedError),
     ("dispatch", {}, {"progress_fn": _boom}, RuntimeError),
@@ -361,12 +394,11 @@ def test_an_exception_leaves_no_span_open(recorder, where, sim_kw, round_kw,
     sim = _linear_sim(**sim_kw)
     params = sim.init(jax.random.key(0))
     kwargs = {"wave_size": 4, **round_kw}
-    if error is None:  # an index past the cohort clamps: no exception
+    # an index past the cohort is refused on the host since ISSUE 30 (it
+    # had clamped): the round's rows are read from n_samples there
+    with pytest.raises(error):
         sim.run_round(params, data, n, jax.random.key(1), **kwargs)
-    else:
-        with pytest.raises(error):
-            sim.run_round(params, data, n, jax.random.key(1), **kwargs)
-        assert recorder.opened[-1][0] == f"baton.round.{where}"
+    assert recorder.opened[-1][0] == f"baton.round.{where}"
     assert recorder.open == []
 
 
