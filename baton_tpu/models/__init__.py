@@ -4,7 +4,13 @@ from baton_tpu.models.cnn import cnn_mnist_model
 from baton_tpu.models.resnet import resnet_model, resnet18_cifar_model
 from baton_tpu.models.lora import lora_wrap, lora_trainable, merge_lora
 from baton_tpu.models.bert import BertConfig, bert_classifier_model
-from baton_tpu.models.llama import LlamaConfig, llama_lm_model, llama_lora_target
+from baton_tpu.models.llama import (
+    LlamaConfig,
+    decoder_lora_model,
+    llama_lm_model,
+    llama_lora_target,
+    projection_lora_target,
+)
 from baton_tpu.models.lstm import LSTMConfig, lstm_lm_model
 from baton_tpu.models.moe import MoEConfig, moe_apply, moe_init
 from baton_tpu.models.vit import ViTConfig, vit_model
@@ -23,6 +29,10 @@ __all__ = [
     "LlamaConfig",
     "llama_lm_model",
     "llama_lora_target",
+    # the hybrid decoder (layer_types: gated delta-rule layers beside full
+    # attention) as a frozen base under LoRA on every projection
+    "decoder_lora_model",
+    "projection_lora_target",
     "LSTMConfig",
     "lstm_lm_model",
     "MoEConfig",

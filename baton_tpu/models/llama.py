@@ -1,10 +1,14 @@
-"""Llama-class decoder-only LM (BASELINE config 4: LoRA instruction-tune).
+"""Decoder-only LM (BASELINE config 4: LoRA instruction-tune).
 
 The reference has no language models (reference demo.py:15-49 is its whole
 zoo); this decoder exists for the driver-set federated LoRA workload.
 Architecture is the modern decoder recipe — RMSNorm pre-norm, RoPE,
 SwiGLU MLP, grouped-query attention, untied output head — built from the
-TPU-first blocks in :mod:`baton_tpu.models.transformer`:
+TPU-first blocks in :mod:`baton_tpu.models.transformer`. ``layer_types``
+makes it a hybrid: each layer's mixer is full attention or the gated
+delta rule of :mod:`baton_tpu.models.delta_rule` (linear attention with
+a recurrent state), in the pattern the configuration gives; a block is
+traced once a kind, whatever the depth.
 
 * params fp32 / activations ``compute_dtype`` (bf16 on TPU), norms and
   softmax in fp32;
@@ -16,7 +20,12 @@ TPU-first blocks in :mod:`baton_tpu.models.transformer`:
 * for federation, pair with :func:`baton_tpu.models.lora.lora_wrap` and
   ``trainable=lora_trainable`` so simulated clients carry only the
   adapter pytree (see :func:`llama_lora_target` for the standard
-  attention-projection targeting).
+  attention-projection targeting, :func:`projection_lora_target` for
+  every projection of the mixers and MLPs, and
+  :func:`decoder_lora_model` for the two put together over a base held
+  in ``param_dtype``);
+* the loss never holds ``[B, L, V]`` logits whole
+  (:func:`baton_tpu.models.transformer.next_token_loss`).
 
 Batches: ``{"x": int32[B, L] inputs, "y": int32[B, L] next-token targets,
 "loss_mask"?: [B, L] 1.0 = token counts toward the loss}``. The
@@ -27,21 +36,24 @@ as the framework contract requires (core/model.py).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from baton_tpu.core.model import FedModel
+from baton_tpu.models.delta_rule import gated_delta_apply, gated_delta_init
+from baton_tpu.models.lora import lora_wrap
 from baton_tpu.models.moe import MoEConfig, moe_apply, moe_init
 from baton_tpu.models.transformer import (
     AttentionFn,
     dense_init,
     default_attention,
+    matmul,
     mha_apply,
     mha_init,
+    next_token_loss,
     normal_init,
-    per_token_cross_entropy,
     rms_init,
     rms_norm,
     rope_angles,
@@ -59,14 +71,39 @@ class LlamaConfig:
     n_heads: int = 32
     n_kv_heads: int = 8
     d_ff: int = 14336
-    rope_theta: float = 500000.0
+    # None: no rotary embedding (position comes from the recurrent
+    # layers of a hybrid)
+    rope_theta: Optional[float] = 500000.0
     # Mixture-of-Experts: replaces every block's SwiGLU FFN with a
     # routed expert layer (models/moe.py) — the ep axis
     moe: Optional[MoEConfig] = None
+    # RMSNorm of the whole query and key projections in full attention
+    qk_norm: bool = False
+    # the mixer of each layer, "full_attention" or "linear_attention";
+    # the first ``n_layers`` entries count (a depth cut keeps the
+    # published list). None: full attention everywhere
+    layer_types: Optional[Tuple[str, ...]] = None
+    # the linear-attention (gated delta rule) layers' heads
+    linear_n_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_allow_neg_eigval: bool = True
+    linear_chunk: int = 64
+    # deviation of the embedding table's initial normal: the scale of the
+    # residual stream the blocks' outputs are added to
+    embed_std: float = 0.02
+
+    def __post_init__(self):
+        if self.layer_types is not None:  # a JSON list hashes as a tuple
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def kind_of(self, layer: int) -> str:
+        return (self.layer_types[layer] if self.layer_types
+                else "full_attention")
 
     @classmethod
     def llama3_8b(cls, **kw) -> "LlamaConfig":
@@ -89,32 +126,55 @@ def llama_lora_target(path: str, leaf) -> bool:
     return path.rsplit("/", 1)[-1] in ("wq", "wk", "wv", "wo")
 
 
-def _block_init(key, cfg: LlamaConfig):
+_PROJECTIONS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down")
+
+
+def projection_lora_target(path: str, leaf) -> bool:
+    """LoRA target predicate: every projection of the mixers (full and
+    linear attention) and of the MLPs; not the embedding, the head, the
+    linear layers' gate projections ``wa`` / ``wb`` or their
+    convolutions."""
+    return path.rsplit("/", 1)[-1] in _PROJECTIONS
+
+
+def _block_init(key, cfg: LlamaConfig, kind: str = "full_attention"):
+    """A linear-attention block holds its mixer under ``linear_attn``
+    and a full-attention block under ``attn``: the kind of a block is
+    the structure of its parameters."""
     ka, km = jax.random.split(key)
     if cfg.moe is not None:
         mlp = moe_init(km, cfg.d_model, cfg.d_ff, cfg.moe)
     else:
         mlp = swiglu_init(km, cfg.d_model, cfg.d_ff)
-    return {
-        "norm_attn": rms_init(cfg.d_model),
-        "attn": mha_init(
+    out_std = cfg.d_model ** -0.5 / (2 * cfg.n_layers) ** 0.5
+    if kind == "linear_attention":
+        mixer = {"linear_attn": gated_delta_init(
+            ka, cfg.d_model, cfg.linear_n_heads, cfg.linear_key_dim,
+            cfg.linear_value_dim, out_std=out_std)}
+    elif kind == "full_attention":
+        mixer = {"attn": mha_init(
             ka, cfg.d_model, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            out_std=cfg.d_model ** -0.5 / (2 * cfg.n_layers) ** 0.5,
-        ),
-        "norm_mlp": rms_init(cfg.d_model),
-        "mlp": mlp,
-    }
+            out_std=out_std, qk_norm=cfg.qk_norm)}
+    else:
+        raise ValueError(f"unknown layer type {kind!r}")
+    return {"norm_attn": rms_init(cfg.d_model), **mixer,
+            "norm_mlp": rms_init(cfg.d_model), "mlp": mlp}
 
 
 def _block_apply(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
     """Returns (x, aux); aux is the block's MoE load-balance loss (0.0
     for dense blocks) — one output structure for both variants so the
     remat wrapper and the layer loop don't branch."""
-    x = x + mha_apply(
-        p["attn"], rms_norm(x, p["norm_attn"]), cfg.n_heads,
-        n_kv_heads=cfg.n_kv_heads, causal=True, rope=rope,
-        attention_fn=attention_fn,
-    )
+    h = rms_norm(x, p["norm_attn"])
+    if "linear_attn" in p:
+        x = x + gated_delta_apply(
+            p["linear_attn"], h, cfg.linear_n_heads, cfg.linear_chunk,
+            cfg.linear_allow_neg_eigval)
+    else:
+        x = x + mha_apply(
+            p["attn"], h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            causal=True, rope=rope, attention_fn=attention_fn,
+        )
     h = rms_norm(x, p["norm_mlp"])
     if cfg.moe is not None:
         y, aux = moe_apply(p["mlp"], h, cfg.moe)
@@ -128,52 +188,59 @@ def llama_lm_model(
     attention_fn: AttentionFn = default_attention,
     name: str = "llama_lm",
     remat: bool = False,
+    param_dtype=jnp.float32,
 ) -> FedModel:
     """``remat=True`` wraps each decoder block in ``jax.checkpoint``:
     the backward pass recomputes block activations instead of storing
     them, cutting activation memory from O(L·n_layers) to O(L) at ~1/3
     extra FLOPs — what makes long-sequence / large-model training
-    (config 4) fit HBM."""
+    (config 4) fit HBM. ``param_dtype`` is the dtype ``init`` gives the
+    matrices (a base that stays frozen is held in bfloat16); vectors
+    (norm scales, the linear layers' ``a_log`` and ``dt_bias``) are
+    float32."""
     cfg = config or LlamaConfig.llama3_8b()
+    # made once a model: ``jax.checkpoint`` caches its trace on the
+    # function and the arguments' structure, so blocks of one kind share
+    # one trace whatever the depth
+    block_fn = (jax.checkpoint(_block_apply, static_argnums=(2, 4)) if remat
+                else _block_apply)
 
     def init(rng):
         keys = jax.random.split(rng, cfg.n_layers + 2)
-        return {
-            "tok_emb": normal_init(keys[0], (cfg.vocab_size, cfg.d_model), 0.02),
+        params = {
+            "tok_emb": normal_init(keys[0], (cfg.vocab_size, cfg.d_model),
+                                   cfg.embed_std),
             "blocks": [
-                _block_init(keys[1 + i], cfg) for i in range(cfg.n_layers)
+                _block_init(keys[1 + i], cfg, cfg.kind_of(i))
+                for i in range(cfg.n_layers)
             ],
             "norm_f": rms_init(cfg.d_model),
             "lm_head": dense_init(keys[-1], cfg.d_model, cfg.vocab_size),
         }
+        return jax.tree_util.tree_map(
+            lambda a: a.astype(param_dtype) if a.ndim >= 2 else a, params)
 
-    def _apply_with_aux(params, batch, rng):
+    def _hidden(params, batch):
+        """The final norm's output ``[B, L, D]`` and the MoE penalty."""
         ids = batch["x"]
         l = ids.shape[1]
-        rope = rope_angles(l, cfg.head_dim, cfg.rope_theta)
-        x = params["tok_emb"][ids].astype(compute_dtype)
-        block_fn = (
-            jax.checkpoint(_block_apply, static_argnums=(2, 4))
-            if remat
-            else _block_apply
-        )
+        rope = (None if cfg.rope_theta is None
+                else rope_angles(l, cfg.head_dim, cfg.rope_theta))
+        with jax.named_scope("embed"):
+            x = params["tok_emb"][ids].astype(compute_dtype)
         aux_total = jnp.float32(0.0)
-        for blk in params["blocks"]:
-            x, aux = block_fn(blk, x, cfg, rope, attention_fn)
+        for i, blk in enumerate(params["blocks"]):
+            with jax.named_scope(f"block{i}"):
+                x, aux = block_fn(blk, x, cfg, rope, attention_fn)
             aux_total = aux_total + aux
-        x = rms_norm(x, params["norm_f"])
-        # bf16 operands, fp32 accumulation: the vocab projection is the
-        # model's largest matmul — keep it on the fast MXU path
-        logits = jax.lax.dot_general(
-            x, params["lm_head"].astype(x.dtype),
-            (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return logits, aux_total
+        return rms_norm(x, params["norm_f"]), aux_total
 
     def apply(params, batch, rng):
-        """Returns next-token logits [B, L, V] (fp32)."""
-        return _apply_with_aux(params, batch, rng)[0]
+        """Returns next-token logits [B, L, V] (fp32): bf16 operands,
+        fp32 accumulation — the vocab projection is the model's largest
+        matmul, keep it on the fast MXU path."""
+        x, _ = _hidden(params, batch)
+        return matmul(x, params["lm_head"], jnp.float32)
 
     def _add_aux(per_example, aux):
         # the MoE load-balance penalty is a whole-forward scalar; add it
@@ -186,8 +253,8 @@ def llama_lm_model(
         return per_example + cfg.moe.aux_weight * aux
 
     def per_example_loss(params, batch, rng):
-        logits, aux = _apply_with_aux(params, batch, rng)
-        tok_loss = per_token_cross_entropy(logits, batch["y"])  # [B, L]
+        x, aux = _hidden(params, batch)
+        tok_loss = next_token_loss(x, params["lm_head"], batch["y"])  # [B, L]
         loss_mask = batch.get("loss_mask")
         if loss_mask is None:
             return _add_aux(jnp.mean(tok_loss, axis=-1), aux)
@@ -199,3 +266,22 @@ def llama_lm_model(
 
     return FedModel(init=init, apply=apply, per_example_loss=per_example_loss,
                     name=name, aux=cfg)
+
+
+def decoder_lora_model(
+    config: LlamaConfig,
+    compute_dtype=jnp.bfloat16,
+    param_dtype=jnp.bfloat16,
+    rank: int = 16,
+    alpha: Optional[float] = None,
+    b_std: float = 0.0,
+    remat: bool = True,
+) -> FedModel:
+    """The decoder as a frozen base held in ``param_dtype`` with rank-
+    ``rank`` adapters on every projection of its mixers and MLPs
+    (:func:`projection_lora_target`), applied to activations; train it
+    with ``FedSim(..., trainable=lora_trainable)``."""
+    base = llama_lm_model(config, compute_dtype=compute_dtype, remat=remat,
+                          param_dtype=param_dtype, name="decoder_lm")
+    return lora_wrap(base, rank=rank, alpha=alpha,
+                     target=projection_lora_target, b_std=b_std)

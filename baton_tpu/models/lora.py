@@ -8,12 +8,23 @@ payload composes with :class:`baton_tpu.core.partition.ParamPartition` so
 the per-client vmap axis carries just the adapters — the difference
 between C×8B and C×a-few-MB of HBM.
 
-Parameter-space formulation: for every targeted 2-D weight ``W [in,out]``
-the effective weight is ``W + (alpha/rank)·A@B`` with ``A [in,r]`` normal
-/ ``B [r,out]`` zeros (so step 0 is exactly the base model). The wrapped
-model's params are ``{"base": ..., "lora": {path: {"a","b"}}}`` and
-``apply`` merges on the fly — any model whose hot weights are 2-D matmul
-leaves gets LoRA without modifying its code.
+For every targeted 2-D weight ``W [in,out]`` the effective weight is
+``W + (alpha/rank)·A@B`` with ``A [in,r]`` normal / ``B [r,out]`` zeros
+(so step 0 is exactly the base model). The wrapped model's params are
+``{"base": ..., "lora": {path: {"a","b"}}}``.
+
+Training applies the adapters to **activations**: ``apply`` hands the
+wrapped model its base tree with every targeted leaf replaced by an
+:class:`Adapted` weight, which stands where the model's code has ``W``
+and computes ``x W + s (x A) B`` for ``x @ W`` (and ``W[ids] + s A[ids]
+B`` for an embedding lookup). No ``W + s A B`` is ever built, so under
+the client ``vmap`` the base stays the one array ``ParamPartition``
+holds for all clients, in the dtype it was initialised in (a frozen
+bfloat16 base is cast nowhere), and only the rank-r products carry a
+client axis. Any model whose hot weights are 2-D leaves used through
+``@``, :func:`baton_tpu.models.transformer.matmul` or a row lookup gets
+LoRA without modifying its code. :func:`merge_lora` materialises ``W +
+s A B`` for deployment.
 """
 
 from __future__ import annotations
@@ -41,6 +52,58 @@ class LoraSpec:
     @property
     def scale(self) -> float:
         return self.alpha / self.rank
+
+
+@jax.tree_util.register_pytree_node_class
+class Adapted:
+    """A weight ``W [in, out]`` with its adapter factors, standing where
+    a model's code has ``W``: ``x @ adapted`` is ``x W + s (x A) B``,
+    ``adapted[ids]`` the adapted rows, ``astype`` casts ``W`` alone (the
+    factors follow the activations' dtype when they are used)."""
+
+    def __init__(self, w, a, b, scale: float):
+        self.w, self.a, self.b, self.scale = w, a, b, scale
+
+    def tree_flatten(self):
+        return (self.w, self.a, self.b), self.scale
+
+    @classmethod
+    def tree_unflatten(cls, scale, children):
+        return cls(*children, scale)
+
+    shape = property(lambda self: self.w.shape)
+    ndim = property(lambda self: self.w.ndim)
+    dtype = property(lambda self: self.w.dtype)
+
+    def astype(self, dtype):
+        return Adapted(self.w.astype(dtype), self.a, self.b, self.scale)
+
+    def apply_to(self, x, preferred_element_type=None):
+        y = jnp.matmul(x, self.w,
+                       preferred_element_type=preferred_element_type)
+        with jax.named_scope("adapter"):
+            low = jnp.matmul(x, self.a.astype(x.dtype))
+            return y + self.scale * jnp.matmul(
+                low, self.b.astype(x.dtype),
+                preferred_element_type=preferred_element_type)
+
+    def __rmatmul__(self, x):
+        return self.apply_to(x)
+
+    def __getitem__(self, index):
+        with jax.named_scope("adapter"):
+            rows = self.scale * (self.a[index] @ self.b)
+        return self.w[index] + rows.astype(self.w.dtype)
+
+    def merged(self):
+        """``W + s A B`` itself: for a table that is added whole (a
+        position embedding), where there is no activation to adapt."""
+        return self.w + (self.scale * (self.a @ self.b)).astype(self.w.dtype)
+
+    def __add__(self, other):
+        return self.merged() + other
+
+    __radd__ = __add__
 
 
 def default_target(path: str, leaf) -> bool:
@@ -93,12 +156,16 @@ def lora_wrap(
     alpha: Optional[float] = None,
     target: TargetPredicate = default_target,
     name: Optional[str] = None,
+    b_std: float = 0.0,
 ) -> FedModel:
     """Wrap ``model`` with LoRA adapters on every targeted 2-D weight.
 
     Use with ``FedSim(..., trainable=lora_trainable)`` so only adapters
     are per-client/aggregated. ``model.init`` supplies the base weights;
     load pretrained weights by overwriting ``params["base"]`` after init.
+    ``b_std`` draws ``B`` from a normal of that deviation instead of
+    zeros: with ``B = 0`` the first step's gradient of ``A`` is zero, so
+    a one-step comparison that has to exercise both factors sets it.
     """
     if alpha is None:
         alpha = 2.0 * rank
@@ -117,15 +184,28 @@ def lora_wrap(
             adapters[path] = {
                 "a": jax.random.normal(k, (fan_in, rank), jnp.float32)
                 / jnp.sqrt(fan_in),
-                "b": jnp.zeros((rank, fan_out), jnp.float32),
+                "b": b_std * jax.random.normal(
+                    jax.random.fold_in(k, 1), (rank, fan_out), jnp.float32)
+                if b_std else jnp.zeros((rank, fan_out), jnp.float32),
             }
         return {"base": base, "lora": adapters}
 
+    def adapted(params):
+        """The base tree with an :class:`Adapted` weight at every
+        targeted leaf."""
+        lora = params["lora"]
+        path_leaves, treedef = jax.tree_util.tree_flatten_with_path(
+            params["base"])
+        return jax.tree_util.tree_unflatten(treedef, [
+            Adapted(leaf, lora[key]["a"], lora[key]["b"], spec.scale)
+            if (key := path_str(p)) in lora else leaf
+            for p, leaf in path_leaves])
+
     def apply(params, batch, rng):
-        return model.apply(merge_lora(params, alpha, rank), batch, rng)
+        return model.apply(adapted(params), batch, rng)
 
     def per_example_loss(params, batch, rng):
-        return model.per_example_loss(merge_lora(params, alpha, rank), batch, rng)
+        return model.per_example_loss(adapted(params), batch, rng)
 
     return FedModel(
         init=init,

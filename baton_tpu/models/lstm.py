@@ -39,7 +39,8 @@ from baton_tpu.core.model import FedModel
 from baton_tpu.models.transformer import (
     dense_init,
     normal_init,
-    per_token_cross_entropy,
+    matmul,
+    next_token_loss,
 )
 
 
@@ -102,8 +103,8 @@ def lstm_lm_model(
             "out": dense_init(keys[-1], cfg.d_hidden, cfg.vocab_size),
         }
 
-    def apply(params, batch, rng):
-        """Next-char logits fp32 [B, L, V]."""
+    def _hidden(params, batch):
+        """The top layer's hidden states [B, L, H]."""
         ids = batch["x"]
         b, l = ids.shape
         x = params["embed"][ids].astype(compute_dtype)  # [B, L, E]
@@ -124,16 +125,15 @@ def lstm_lm_model(
 
         # scan over time: xs [L, B, E] -> top-layer hiddens [L, B, H]
         _, top = jax.lax.scan(step, (h0, c0), x.swapaxes(0, 1))
-        top = top.swapaxes(0, 1)  # [B, L, H]
-        return jax.lax.dot_general(
-            top, params["out"].astype(top.dtype),
-            (((top.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        return top.swapaxes(0, 1)  # [B, L, H]
+
+    def apply(params, batch, rng):
+        """Next-char logits fp32 [B, L, V]."""
+        return matmul(_hidden(params, batch), params["out"], jnp.float32)
 
     def per_example_loss(params, batch, rng):
-        tok_loss = per_token_cross_entropy(apply(params, batch, rng),
-                                           batch["y"])  # [B, L]
+        tok_loss = next_token_loss(_hidden(params, batch), params["out"],
+                                   batch["y"])  # [B, L]
         loss_mask = batch.get("loss_mask")
         if loss_mask is None:
             return jnp.mean(tok_loss, axis=-1)

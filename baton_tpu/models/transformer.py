@@ -179,17 +179,24 @@ def padding_bias(mask, dtype=jnp.float32):
     return ((1.0 - mask.astype(jnp.float32)) * -1e30)[:, None, None, :].astype(dtype)
 
 
-def mha_init(key, d_model, n_heads, n_kv_heads=None, head_dim=None, out_std=None):
-    """Fused QKV-per-role projection params for (G)MQA attention."""
+def mha_init(key, d_model, n_heads, n_kv_heads=None, head_dim=None,
+             out_std=None, qk_norm: bool = False):
+    """Fused QKV-per-role projection params for (G)MQA attention.
+    ``qk_norm`` adds the RMSNorm scales of the whole query and key
+    projections (all heads together, before the split into heads)."""
     n_kv = n_kv_heads or n_heads
     dh = head_dim or d_model // n_heads
     kq, kk, kv, ko = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": dense_init(kq, d_model, n_heads * dh),
         "wk": dense_init(kk, d_model, n_kv * dh),
         "wv": dense_init(kv, d_model, n_kv * dh),
         "wo": dense_init(ko, n_heads * dh, d_model, stddev=out_std),
     }
+    if qk_norm:
+        p["q_norm"] = rms_init(n_heads * dh)
+        p["k_norm"] = rms_init(n_kv * dh)
+    return p
 
 
 @jax.named_scope("attention")
@@ -208,11 +215,16 @@ def mha_apply(
     n_kv = n_kv_heads or n_heads
     dh = p["wq"].shape[1] // n_heads
 
-    def proj(w, h):
+    def proj(w, h, norm=None):
         y = x @ w.astype(x.dtype)
+        if norm is not None:
+            y = rms_norm(y, norm)
         return y.reshape(b, l, h, dh).transpose(0, 2, 1, 3)  # [B, H, L, Dh]
 
-    q, k, v = proj(p["wq"], n_heads), proj(p["wk"], n_kv), proj(p["wv"], n_kv)
+    # a "q_norm" / "k_norm" in the params is that projection's RMSNorm
+    q = proj(p["wq"], n_heads, p.get("q_norm"))
+    k = proj(p["wk"], n_kv, p.get("k_norm"))
+    v = proj(p["wv"], n_kv)
     if rope is not None:
         cos, sin = rope
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
@@ -280,7 +292,19 @@ def prenorm_block_apply(p, x, n_heads, bias=None,
 
 
 # ---------------------------------------------------------------------------
-# per-example LM loss (used by llama.py; here because it is model-generic)
+# per-token LM loss (used by llama.py and lstm.py; model-generic)
+
+
+def matmul(x, w, preferred_element_type=None):
+    """``x [..., d] @ w [d, n]`` with ``w`` in ``x``'s dtype and the
+    result in ``preferred_element_type`` (float32 for a head's logits:
+    bf16 operands, fp32 accumulation). A weight that applies itself
+    (``models/lora.py::Adapted``) does."""
+    w = w.astype(x.dtype)
+    apply_to = getattr(w, "apply_to", None)
+    if apply_to is not None:
+        return apply_to(x, preferred_element_type)
+    return jnp.matmul(x, w, preferred_element_type=preferred_element_type)
 
 
 def per_token_cross_entropy(logits, labels):
@@ -289,3 +313,39 @@ def per_token_cross_entropy(logits, labels):
     logz = jax.nn.logsumexp(logits, axis=-1)
     ll = jnp.take_along_axis(logits, labels[..., None], axis=-1).squeeze(-1)
     return logz - ll
+
+
+# the float32 logits the head and the loss hold at a time: at a
+# six-figure vocabulary ``[B, L, V]`` whole is the activation peak of a
+# step (and under a client ``vmap`` once a client)
+_LOGITS_BLOCK_BYTES = 128 * 1024 ** 2
+
+
+@jax.named_scope("lm_loss")
+def next_token_loss(x, w_head, labels):
+    """Per-token cross-entropy ``[B, L]`` (fp32) of the head ``x [B, L,
+    D] @ w_head [D, V]`` against ``labels [B, L]``, what
+    :func:`per_token_cross_entropy` gives for the whole ``[B, L, V]``
+    logits, computed in blocks of tokens: a ``lax.scan`` over the
+    fewest equal blocks whose logits stay under
+    ``_LOGITS_BLOCK_BYTES``, each under ``jax.checkpoint`` so the
+    backward recomputes a block's logits instead of keeping every
+    block's. One block (a small vocabulary) is the plain computation."""
+    b, l, d = x.shape
+
+    def block(xb, yb):
+        return per_token_cross_entropy(
+            matmul(xb, w_head, jnp.float32), yb)
+
+    n = min(l, -(-(4 * b * l * w_head.shape[1]) // _LOGITS_BLOCK_BYTES))
+    if n <= 1:
+        return block(x, labels)
+    t = -(-l // n)
+    if n * t != l:  # the tail block's padding is cut off again below
+        x = jnp.pad(x, ((0, 0), (0, n * t - l), (0, 0)))
+        labels = jnp.pad(labels, ((0, 0), (0, n * t - l)))
+    xs = jnp.moveaxis(x.reshape(b, n, t, d), 1, 0)
+    ys = jnp.moveaxis(labels.reshape(b, n, t), 1, 0)
+    _, tok = jax.lax.scan(
+        lambda _, xy: (None, jax.checkpoint(block)(*xy)), None, (xs, ys))
+    return jnp.moveaxis(tok, 0, 1).reshape(b, n * t)[:, :l]

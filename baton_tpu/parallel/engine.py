@@ -676,8 +676,12 @@ class FedSim:
                 in_shard = (client_sharding(self.mesh)
                             if self.mesh is not None else None)
                 n_waves = -(-c // wave_size)
-                round_span.set_metadata(clients=c, waves=n_waves,
-                                        wave_size=int(wave_size))
+                # shapes only, no device fetch: what a round holds once
+                # for all clients, and what it holds (and folds) a client
+                round_span.set_metadata(
+                    clients=c, waves=n_waves, wave_size=int(wave_size),
+                    frozen_bytes=_tree_bytes(frozen),
+                    trainable_bytes=_tree_bytes(params))
 
             psum_acc = None
             lsum_acc = None
@@ -1167,6 +1171,12 @@ class FedSim:
         if return_server_opt_state:
             return new_params, history, server_opt_state
         return new_params, history
+
+
+def _tree_bytes(tree) -> int:
+    """Bytes of the arrays of ``tree`` (0 for ``None``), from shapes."""
+    return sum(int(a.size) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(tree))
 
 
 # The model-sized accumulator of the non-fused wave loop: the previous

@@ -8,7 +8,8 @@ Drives the main path once through the entry points a user calls —
 fold) on ResNet-18/CIFAR-10 bf16, 32 clients x 48 samples, batch 32 —
 then the Pallas flash kernel (alone against the dense reference, and
 reached through a decoder's ``default_attention`` under the client
-``vmap``), one in-process HTTP federation whose workers train on the
+``vmap``), two LoRA rounds of the tiny hybrid decoder (gated delta-rule
+layers and full attention over a frozen bfloat16 base), one in-process HTTP federation whose workers train on the
 device, the client mesh when the host has more than one device, and
 the compile cache. Weights are random from a seed, depth is cut, data
 is generated (the chip machine has no network).
@@ -240,6 +241,66 @@ def phase_fedsim_resnet18(env: Env) -> None:
         f"{env.seconds(sum(secs[1:]) / len(secs[1:]))}/round; "
         f"memory_stats peak_bytes_in_use={peak} "
         f"peak_bytes_reserved={reserved}")
+
+
+# ----------------------------------------------------------------------
+def phase_hybrid_lora(env: Env) -> None:
+    """The hybrid decoder at a tiny size, bfloat16 over a bfloat16 base
+    with adapters on activations, two rounds through ``FedSim``: a broken
+    scan (the chunked delta rule, its triangular solve, its backward
+    under the client ``vmap``) shows here before the benchmark meets it.
+    The same size on the chip and in rehearsal: this phase asks whether
+    the program runs, not how fast."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models.llama import LlamaConfig, decoder_lora_model
+    from baton_tpu.models.lora import lora_trainable
+    from baton_tpu.parallel.engine import FedSim
+
+    period = ("linear_attention",) * 3 + ("full_attention",)
+    cfg = LlamaConfig(
+        vocab_size=96, max_len=160, d_model=64, n_layers=4, n_heads=4,
+        n_kv_heads=4, d_ff=128, rope_theta=None, qk_norm=True,
+        layer_types=period, linear_n_heads=4, linear_key_dim=8,
+        linear_value_dim=16, linear_chunk=64, embed_std=1.0)
+    model = decoder_lora_model(cfg, rank=4, b_std=0.02)
+    params = jax.jit(model.init)(jax.random.key(0))
+    # 160 tokens: two whole chunks of 64 and a padded tail
+    first = jax.random.randint(jax.random.key(1), (4, 2, 1), 0, 96)
+    tokens = (first + 7 * jnp.arange(161)) % 96
+    data = {"x": tokens[..., :-1], "y": tokens[..., 1:]}
+    n_samples = np.asarray([2, 2, 2, 1], np.int32)
+    sim = FedSim(model, batch_size=1, learning_rate=0.05,
+                 trainable=lora_trainable)
+    losses, p = [], params
+    for i in range(2):
+        res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
+                            n_epochs=1, collect_client_losses=False)
+        losses.append(float(res.loss_history[-1]))
+        p = res.params
+    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
+    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    base = list(zip(jax.tree_util.tree_leaves(params["base"]),
+                    jax.tree_util.tree_leaves(p["base"])))
+    _check(all(a is b for a, b in base),
+           "a round copied or cast a leaf of the frozen base")
+    _check({a.dtype for a, _ in base if a.ndim >= 2} == {jnp.dtype(
+        jnp.bfloat16)}, "the base's matrices are not held in bfloat16")
+    moved = [float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+        jax.tree_util.tree_leaves(params["lora"]),
+        jax.tree_util.tree_leaves(p["lora"]))]
+    _check(all(np.isfinite(moved)) and min(moved) > 0,
+           "an adapter factor did not move or is not finite")
+    _check(_on_platform(p, env.platform),
+           f"round outputs do not live on the {env.platform} device")
+    env.say("hybrid_lora",
+            f"{model.name}: 3 gated delta-rule layers + 1 full attention, "
+            f"bf16 over a frozen bf16 base, {len(moved)} adapter factors on "
+            f"activations, 4 clients x 160 tokens (chunks of 64), 2 rounds, "
+            f"loss {losses[0]:.4f} -> {losses[1]:.4f}; {len(base)} base "
+            f"leaves handed back as the arrays they were")
 
 
 # ----------------------------------------------------------------------
@@ -615,7 +676,7 @@ def phase_cache(env: Env) -> None:
 # ----------------------------------------------------------------------
 # in running order
 PHASES = {"device": phase_device, "fedsim_resnet18": phase_fedsim_resnet18,
-          "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
+          "hybrid_lora": phase_hybrid_lora, "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
           "mesh": phase_mesh, "cache": phase_cache}
 
 

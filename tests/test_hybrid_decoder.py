@@ -1,0 +1,437 @@
+"""The hybrid decoder's mechanisms at test sizes on the CPU: the chunked
+gated delta rule against the recurrence written token by token (values
+and gradients, lengths that are and are not a multiple of the chunk,
+under a client ``vmap``); a block of one kind traced once whatever the
+depth; adapters on activations against the merged weight, the base kept
+as the arrays it was given; the next-token loss in blocks against the
+unblocked one; the ``baton.round`` span's byte counts."""
+
+import contextlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from baton_tpu.models import delta_rule, llama, transformer
+from baton_tpu.models.bert import BertConfig, bert_classifier_model
+from baton_tpu.models.delta_rule import chunked_delta_rule, gated_delta_init
+from baton_tpu.models.llama import (
+    LlamaConfig,
+    decoder_lora_model,
+    llama_lm_model,
+    projection_lora_target,
+)
+from baton_tpu.models.lora import (
+    lora_trainable,
+    lora_wrap,
+    merge_lora_model,
+)
+from baton_tpu.models.lstm import LSTMConfig, lstm_lm_model
+from baton_tpu.models.mlp import mlp_classifier_model
+from baton_tpu.models.transformer import (
+    next_token_loss,
+    per_token_cross_entropy,
+)
+from baton_tpu.models.vit import ViTConfig, vit_model
+from baton_tpu.parallel import engine
+from baton_tpu.parallel.engine import FedSim
+
+PERIOD = ("linear_attention",) * 3 + ("full_attention",)
+
+
+def _hybrid(n_layers=4, chunk=4, **kw):
+    return LlamaConfig.tiny(
+        vocab_size=96, max_len=32, d_model=64, n_layers=n_layers, n_heads=4,
+        n_kv_heads=4, d_ff=128, rope_theta=None, qk_norm=True,
+        layer_types=PERIOD * 2, linear_n_heads=4, linear_key_dim=8,
+        linear_value_dim=16, linear_chunk=chunk, **kw)
+
+
+def _token_by_token(q, k, v, g, beta):
+    """``S_t = alpha_t (I - beta_t k_t k_t^T) S_{t-1} + beta_t k_t v_t^T``,
+    ``o_t = S_t^T q_t``: one ``lax.scan`` over the positions."""
+    b, l, h, d_k = q.shape
+
+    def step(state, at):
+        q_t, k_t, v_t, g_t, b_t = at
+        state = state * jnp.exp(g_t)[..., None, None]
+        seen = jnp.einsum("bhd,bhde->bhe", k_t, state)
+        state = state + jnp.einsum("bhd,bhe->bhde", k_t * b_t[..., None],
+                                   v_t - seen)
+        return state, jnp.einsum("bhd,bhde->bhe", q_t, state)
+
+    by_position = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, d_k, v.shape[-1])),
+                        by_position)
+    return jnp.moveaxis(o, 0, 1)
+
+
+def _scan_inputs(seed, lead, l, h=3, d_k=8, d_v=16):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    return (unit(jax.random.normal(ks[0], lead + (l, h, d_k))) * d_k ** -0.5,
+            unit(jax.random.normal(ks[1], lead + (l, h, d_k))),
+            jax.random.normal(ks[2], lead + (l, h, d_v)),
+            -1.5 * jax.random.uniform(ks[3], lead + (l, h)),
+            2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], lead + (l, h))))
+
+
+def _close(got, want, rtol=2e-5):
+    scale = max(float(jnp.max(jnp.abs(want))), 1e-12)
+    assert float(jnp.max(jnp.abs(got - want))) <= rtol * scale
+
+
+@pytest.mark.parametrize("length,chunk", [(16, 4), (10, 4), (7, 64),
+                                          (130, 64), (64, 64)])
+def test_chunked_delta_rule_is_the_recurrence_token_by_token(length, chunk):
+    """Values and all five gradients; 10 and 130 tokens leave a tail
+    chunk that is padded, 7 tokens are one chunk shorter than 64."""
+    args = _scan_inputs(length, (2,), length)
+
+    def through(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3, 4))
+
+    with jax.default_matmul_precision("highest"):
+        want_o = _token_by_token(*args)
+        got_o = chunked_delta_rule(*args, chunk)
+        want, want_g = through(_token_by_token)(*args)
+        got, got_g = through(
+            lambda *a: chunked_delta_rule(*a, chunk))(*args)
+    assert got_o.shape == want_o.shape == (2, length, 3, 16)
+    _close(got_o, want_o)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g, w in zip(got_g, want_g):
+        _close(g, w)
+
+
+def test_chunked_delta_rule_under_a_client_vmap():
+    """Vmapped over a client axis, values and gradients are each
+    client's own."""
+    args = _scan_inputs(3, (3, 2), 10)
+
+    def loss(*a):
+        return jnp.sum(jnp.sin(chunked_delta_rule(*a, 4)))
+
+    with jax.default_matmul_precision("highest"):
+        got_o = jax.vmap(lambda *a: chunked_delta_rule(*a, 4))(*args)
+        got_g = jax.vmap(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args)
+        for c in range(3):
+            own = tuple(a[c] for a in args)
+            _close(got_o[c], _token_by_token(*own))
+            want_g = jax.grad(
+                lambda *a: jnp.sum(jnp.sin(_token_by_token(*a))),
+                argnums=(0, 1, 2, 3, 4))(*own)
+            for g, w in zip(got_g, want_g):
+                _close(g[c], w)
+
+
+def test_a_masked_row_of_zeros_costs_a_step_nothing():
+    """A padded row (token 0 throughout, mask 0) gives a finite loss and
+    leaves the step's gradient what the real rows alone give."""
+    model = decoder_lora_model(_hybrid(), compute_dtype=jnp.float32,
+                               param_dtype=jnp.float32, rank=2, b_std=0.02)
+    params = model.init(jax.random.key(0))
+    x = jax.random.randint(jax.random.key(1), (3, 11), 1, 96)
+    real = {"x": x[:, :-1], "y": x[:, 1:]}
+    padded = {"x": jnp.concatenate([real["x"], jnp.zeros((1, 10), jnp.int32)]),
+              "y": jnp.concatenate([real["y"], jnp.zeros((1, 10), jnp.int32)]),
+              "mask": jnp.asarray([1.0, 1.0, 1.0, 0.0])}
+
+    def grad(batch):
+        return jax.value_and_grad(lambda lora: model.masked_loss(
+            {"base": params["base"], "lora": lora}, batch, None))(
+                params["lora"])
+
+    (want, want_g), (got, got_g) = grad(real), grad(padded)
+    assert np.isfinite(np.asarray(model.per_example_loss(
+        params, padded, None))).all()
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for g, w in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        _close(g, w, rtol=1e-5)
+
+
+def test_the_layer_pattern_decides_each_blocks_mixer():
+    cfg = _hybrid(n_layers=8)
+    params = jax.eval_shape(llama_lm_model(cfg).init, jax.random.key(0))
+    kinds = ["linear_attn" if "linear_attn" in b else "attn"
+             for b in params["blocks"]]
+    assert kinds == (["linear_attn"] * 3 + ["attn"]) * 2
+    assert set(params["blocks"][3]["attn"]) == {"wq", "wk", "wv", "wo",
+                                                "q_norm", "k_norm"}
+    lin = params["blocks"][0]["linear_attn"]
+    assert lin["wq"].shape == (64, 32) and lin["wv"].shape == (64, 64)
+    assert lin["conv_k"].shape == (4, 32) and lin["a_log"].shape == (4,)
+    with pytest.raises(ValueError):
+        llama_lm_model(LlamaConfig.tiny(
+            layer_types=("sliding",) * 2)).init(jax.random.key(0))
+
+
+def test_a_base_in_bfloat16_keeps_its_vectors_in_float32():
+    model = decoder_lora_model(_hybrid(), rank=2)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    dtypes = {leaf.ndim >= 2: set() for leaf in
+              jax.tree_util.tree_leaves(params["base"])}
+    for leaf in jax.tree_util.tree_leaves(params["base"]):
+        dtypes[leaf.ndim >= 2].add(leaf.dtype)
+    assert dtypes == {True: {jnp.dtype(jnp.bfloat16)},
+                      False: {jnp.dtype(jnp.float32)}}
+    assert {a.dtype for a in jax.tree_util.tree_leaves(params["lora"])} == {
+        jnp.dtype(jnp.float32)}
+    # every projection of the mixers and MLPs, nothing else
+    assert len(params["lora"]) == 3 * (5 + 3) + (4 + 3)
+    assert not [k for k in params["lora"]
+                if k.rsplit("/", 1)[-1] in ("wa", "wb", "tok_emb", "lm_head")
+                or "conv" in k]
+    assert projection_lora_target("blocks/0/linear_attn/wg", None)
+    assert not projection_lora_target("blocks/0/linear_attn/conv_q", None)
+
+
+def test_a_block_is_traced_once_a_kind_whatever_the_depth(monkeypatch):
+    """Eight layers, six of them linear: under ``remat`` the mixer of
+    each kind runs its Python once in a trace of the loss."""
+    calls = {"linear": 0, "full": 0}
+
+    def counted(name, fn):
+        def wrapper(*a, **kw):
+            calls[name] += 1
+            return fn(*a, **kw)
+        return wrapper
+
+    monkeypatch.setattr(llama, "gated_delta_apply",
+                        counted("linear", delta_rule.gated_delta_apply))
+    monkeypatch.setattr(llama, "mha_apply",
+                        counted("full", transformer.mha_apply))
+    model = decoder_lora_model(_hybrid(n_layers=8), compute_dtype=jnp.float32,
+                               param_dtype=jnp.float32, rank=2, remat=True)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    batch = {"x": jnp.zeros((2, 8), jnp.int32), "y": jnp.zeros((2, 8), jnp.int32)}
+    jax.make_jaxpr(lambda p: model.per_example_loss(p, batch, None))(params)
+    assert calls == {"linear": 1, "full": 1}
+
+
+# ------------------------------------------------- adapters on activations
+def _models():
+    cfg = _hybrid()
+    lstm = LSTMConfig.tiny(vocab_size=32)
+    return {
+        "mlp": (mlp_classifier_model(8, (16,), 4), None,
+                lambda key: {"x": jax.random.normal(key, (5, 8)),
+                             "y": jnp.zeros((5,), jnp.int32)}),
+        "hybrid": (llama_lm_model(cfg), projection_lora_target,
+                   lambda key: {"x": jax.random.randint(key, (2, 10), 0, 96),
+                                "y": jnp.zeros((2, 10), jnp.int32)}),
+        "decoder_every_matrix": (
+            llama_lm_model(LlamaConfig.tiny()), None,
+            lambda key: {"x": jax.random.randint(key, (2, 10), 0, 256),
+                         "y": jnp.zeros((2, 10), jnp.int32)}),
+        # a position table sliced by rows (``pos_emb[:l]``)
+        "bert_every_matrix": (
+            bert_classifier_model(BertConfig.tiny()), None,
+            lambda key: {"x": jax.random.randint(key, (3, 10), 0, 128),
+                         "y": jnp.zeros((3,), jnp.int32)}),
+        # a position table added whole: nothing to adapt but the table
+        "vit_every_matrix": (
+            vit_model(ViTConfig.tiny()), None,
+            lambda key: {"x": jax.random.normal(key, (2, 16, 16, 3)),
+                         "y": jnp.zeros((2,), jnp.int32)}),
+        "lstm_every_matrix": (
+            lstm_lm_model(lstm), None,
+            lambda key: {"x": jax.random.randint(key, (2, 10), 0, 32),
+                         "y": jnp.zeros((2, 10), jnp.int32)}),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_models()))
+def test_adapters_on_activations_equal_the_merged_weight(name):
+    """``x W + s (x A) B`` (and for a table ``W[ids] + s A[ids] B``)
+    against the model run on ``merge_lora``'s ``W + s A B``, outputs and
+    the gradients of both factors, to float32 rounding."""
+    base, target, make_batch = _models()[name]
+    kw = {} if target is None else {"target": target}
+    model = lora_wrap(base, rank=3, b_std=0.05, **kw)
+    params = model.init(jax.random.key(0))
+    batch = make_batch(jax.random.key(1))
+
+    def merged_loss(lora):
+        whole = merge_lora_model(model, {"base": params["base"], "lora": lora})
+        return jnp.mean(base.per_example_loss(whole, batch, None))
+
+    def adapted_loss(lora):
+        return jnp.mean(model.per_example_loss(
+            {"base": params["base"], "lora": lora}, batch, None))
+
+    with jax.default_matmul_precision("highest"):
+        want_out = base.apply(merge_lora_model(model, params), batch, None)
+        got_out = model.apply(params, batch, None)
+        want, want_g = jax.value_and_grad(merged_loss)(params["lora"])
+        got, got_g = jax.value_and_grad(adapted_loss)(params["lora"])
+    _close(got_out, want_out, rtol=1e-4)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g, w in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        _close(g, w, rtol=1e-4)
+    assert any(float(jnp.max(jnp.abs(g))) > 0
+               for g in jax.tree_util.tree_leaves(got_g))
+
+
+def test_no_merged_weight_is_built_in_training():
+    """The loss's program has no array of a base weight's shape with a
+    client axis in front: under the client ``vmap`` only the rank-r
+    products are per client."""
+    model = decoder_lora_model(_hybrid(), compute_dtype=jnp.float32,
+                               param_dtype=jnp.float32, rank=2, remat=False)
+    params = model.init(jax.random.key(0))
+    clients = 3
+    lora = jax.tree_util.tree_map(
+        lambda a: jnp.broadcast_to(a, (clients,) + a.shape), params["lora"])
+    batch = {"x": jnp.zeros((clients, 2, 8), jnp.int32),
+             "y": jnp.zeros((clients, 2, 8), jnp.int32)}
+
+    def wave(lora, base, batch):
+        return jax.vmap(lambda lo, b: jax.grad(lambda q: jnp.mean(
+            model.per_example_loss({"base": base, "lora": q}, b, None)))(lo),
+            in_axes=(0, 0))(lora, batch)
+
+    jaxpr = jax.make_jaxpr(wave)(lora, params["base"], batch)
+    weights = {leaf.shape for leaf in
+               jax.tree_util.tree_leaves(params["base"]) if leaf.ndim == 2}
+    made = set()
+
+    def walk(j):
+        for eqn in j.eqns:
+            made.update(v.aval.shape for v in eqn.outvars
+                        if hasattr(v.aval, "shape"))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert not {(clients,) + w for w in weights} & made
+    assert not {(clients,) + w[::-1] for w in weights} & made
+
+
+class _Recorder:
+    def __init__(self):
+        self.opened = []
+
+    def __call__(self, name, **attrs):
+        recorder = self
+
+        class Span(contextlib.AbstractContextManager):
+            def __enter__(self):
+                recorder.opened.append((name, attrs))
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+            def set_metadata(self, **more):
+                attrs.update(more)
+
+        return Span()
+
+
+def test_a_round_hands_the_base_back_and_says_what_it_weighs(monkeypatch):
+    """After ``FedSim.run_round`` with ``trainable=lora_trainable`` every
+    base leaf is the very array that went in (bfloat16, no cast, no
+    copy), the adapters moved, and ``baton.round`` carries the bytes held
+    once and the bytes held a client."""
+    recorder = _Recorder()
+    monkeypatch.setattr(engine, "annotate", recorder)
+    model = decoder_lora_model(_hybrid(), rank=2, b_std=0.02)
+    params = model.init(jax.random.key(0))
+    x = jax.random.randint(jax.random.key(1), (3, 2, 11), 0, 96)
+    data = {"x": x[..., :-1], "y": x[..., 1:]}
+    sim = FedSim(model, batch_size=1, learning_rate=0.05,
+                 trainable=lora_trainable)
+    res = sim.run_round(params, data, np.asarray([2, 2, 1], np.int32),
+                        jax.random.key(2), n_epochs=1,
+                        collect_client_losses=False)
+    assert np.isfinite(float(res.loss_history[-1]))
+    before = jax.tree_util.tree_leaves(params["base"])
+    after = jax.tree_util.tree_leaves(res.params["base"])
+    assert len(before) == len(after)
+    assert all(a is b for a, b in zip(before, after))
+    assert all(float(jnp.max(jnp.abs(a - b))) > 0 for a, b in zip(
+        jax.tree_util.tree_leaves(res.params["lora"]),
+        jax.tree_util.tree_leaves(params["lora"])))
+    name, attrs = recorder.opened[0]
+    assert name == "baton.round"
+    assert attrs["frozen_bytes"] == sum(a.nbytes for a in before)
+    assert attrs["trainable_bytes"] == sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(params["lora"]))
+
+
+# ----------------------------------------------------- the loss in blocks
+@pytest.mark.parametrize("length", [12, 13])
+@pytest.mark.parametrize("masked", [False, True])
+def test_the_loss_in_blocks_is_the_unblocked_loss(length, masked, monkeypatch):
+    """Values and gradients (of the hidden states and of the head), with
+    blocks that divide the length and with a padded tail block; through
+    the decoder with and without a ``loss_mask``."""
+    b, d, v = 3, 16, 50
+    kx, kw, ky = jax.random.split(jax.random.key(length), 3)
+    x = jax.random.normal(kx, (b, length, d))
+    w = jax.random.normal(kw, (d, v)) * d ** -0.5
+    y = jax.random.randint(ky, (b, length), 0, v)
+    weight = jnp.arange(1.0, b * length + 1).reshape(b, length)
+
+    def unblocked(x, w):
+        return per_token_cross_entropy(x @ w, y)
+
+    def through(fn):
+        return jax.value_and_grad(lambda x, w: jnp.sum(fn(x, w) * weight),
+                                  argnums=(0, 1))(x, w)
+
+    with jax.default_matmul_precision("highest"):
+        want_tok, (want, want_g) = unblocked(x, w), through(unblocked)
+        # one block's budget: 4 tokens of 3 rows -> 3 blocks (12), 4 (13)
+        monkeypatch.setattr(transformer, "_LOGITS_BLOCK_BYTES",
+                            4 * b * 4 * v)
+        blocked = lambda x, w: next_token_loss(x, w, y)  # noqa: E731
+        text = str(jax.make_jaxpr(blocked)(x, w))
+        assert "scan" in text and f"f32[{b},{length},{v}]" not in text
+        got_tok, (got, got_g) = blocked(x, w), through(blocked)
+    assert got_tok.shape == (b, length)
+    _close(got_tok, want_tok, rtol=1e-6)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for g, wg in zip(got_g, want_g):
+        _close(g, wg, rtol=1e-5)
+
+    # the decoder's per-example loss, blocked and not
+    model = llama_lm_model(LlamaConfig.tiny(vocab_size=v))
+    params = model.init(jax.random.key(1))
+    batch = {"x": y, "y": jnp.roll(y, -1, axis=1)}
+    if masked:
+        batch["loss_mask"] = (jnp.arange(length) < length // 2).astype(
+            jnp.float32)[None].repeat(b, 0)
+    got_loss = model.per_example_loss(params, batch, None)
+    monkeypatch.setattr(transformer, "_LOGITS_BLOCK_BYTES", 2 ** 40)
+    want_loss = model.per_example_loss(params, batch, None)
+    np.testing.assert_allclose(np.asarray(got_loss), np.asarray(want_loss),
+                               rtol=1e-5)
+
+
+def test_block_count_follows_the_shapes():
+    """The published head (100,352 ids) at 1,024 tokens a row is four
+    blocks of 256; a small vocabulary is one block and no scan."""
+    small = str(jax.make_jaxpr(lambda x, w: next_token_loss(
+        x, w, jnp.zeros((2, 16), jnp.int32)))(
+            jnp.zeros((2, 16, 8)), jnp.zeros((8, 96))))
+    assert "scan" not in small
+    big = jax.make_jaxpr(lambda x, w: next_token_loss(
+        x, w, jnp.zeros((1, 1024), jnp.int32)))(
+            jax.ShapeDtypeStruct((1, 1024, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((64, 100352), jnp.bfloat16))
+    scans = [e for e in big.jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1 and scans[0].params["length"] == 4
+
+
+def test_gated_delta_init_draws_the_gates_as_the_papers_code_does():
+    p = gated_delta_init(jax.random.key(0), 64, 4, 8, 16)
+    a = np.exp(np.asarray(p["a_log"]))
+    assert ((a > 0) & (a < 16)).all()
+    dt = np.log1p(np.exp(np.asarray(p["dt_bias"])))  # softplus
+    assert ((dt > 9e-4) & (dt < 0.11)).all()

@@ -212,8 +212,11 @@ def test_session_counts_each_span(session, name, count):
 
 def test_session_spans_nest_in_their_round_in_order(session):
     rounds = [s for s in session["spans"] if s[0] == "baton.round"]
+    # no partition: nothing is held once, and the linear model's five
+    # float32 parameters are what a client holds
     assert [r[3] for r in rounds] == [
-        {"clients": 6, "waves": 2, "wave_size": 4}] * 2
+        {"clients": 6, "waves": 2, "wave_size": 4, "frozen_bytes": 0,
+         "trainable_bytes": 20}] * 2
     for _, r0, r1, _ in rounds:
         inner = [s for s in session["spans"]
                  if s[0] != "baton.round" and r0 <= s[1] and s[2] <= r1]
