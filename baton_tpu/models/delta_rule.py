@@ -14,13 +14,20 @@ Per head, with ``x`` the block's normalised input and ``t`` the position::
     y       = [rms_norm_head(o_t) * silu(x Wg)] Wo
 
 The recurrence is computed in chunks (the WY form of arXiv:2412.06464
-section 3): inside a chunk matrix products and one unit-triangular
-solve, between chunks the state carried by a ``lax.scan`` that does the
+section 3): inside a chunk matrix products and the inverse of one
+unit-lower matrix, ``T = (I + A)^-1``, by block recursion, once a chunk
+(``_unit_lower_inverse``: six levels of small products, no row-by-row
+solve); between chunks the state carried by a ``lax.scan`` that does the
 sequential part alone (the state at each chunk's start and the chunk's
 corrected values); every chunk's output is then one batched product.
-State, gates and the solve are float32, the matrix operands are in the
-activations' dtype. The backward is JAX's through that scan. Nothing
-here knows a client axis: the mixer vmaps like any other block.
+State, gates, the inverse and ``T [beta v | beta k alpha]`` are float32:
+the inverse's products are elementwise and that ``dot`` and its
+backward's say ``precision=HIGHEST`` themselves, because a TPU rounds
+the operands of a float32 ``dot`` to bfloat16 by default. The other
+matrix operands are in the activations' dtype. The backward of
+``T rhs`` is written by hand and reuses ``T`` (``_solve_unit_lower``);
+the rest is JAX's through that scan. Nothing here knows a client axis:
+the mixer vmaps like any other block.
 """
 
 from __future__ import annotations
@@ -31,6 +38,7 @@ import jax.numpy as jnp
 from baton_tpu.models.transformer import dense_init, matmul
 
 CONV_TAPS = 4
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def gated_delta_init(key, d_model, n_heads, d_k, d_v, out_std=None):
@@ -81,6 +89,76 @@ def _l2_normalised(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
+def _unit_lower_inverse(a):
+    """``a^-1`` for ``a [..., C, C]`` float32 with a unit diagonal and
+    nothing above it, any ``C``, by block recursion: a block of one row
+    is its own inverse, and sizes double, ``[[L11, 0], [A21, L22]]^-1 =
+    [[T11, 0], [-T22 A21 T11, T22]]``, two products a level over every
+    pair of diagonal blocks of every matrix at once. Every factor is
+    bounded like the inverse itself, so this is as stable as forward
+    substitution (the product form ``(I - A)(I + A^2)(I + A^4)...`` is
+    exact on paper and loses every digit in float32 once keys
+    correlate). The blocks have 1 to ``C / 2`` rows, a corner of the
+    MXU's 128 x 128 tile and of a vector register's 128 lanes, so the
+    leading axes go on the lanes and a level's products are sums of
+    elementwise products there: float32 as written, whatever the
+    backend's matmul precision."""
+    lead, c = a.shape[:-2], a.shape[-1]
+    m = 1 << (c - 1).bit_length()
+    # rows of zeros below: the padded blocks invert to the identity, and
+    # the result's first C rows and columns never read them
+    a = jnp.pad(a, ((0, 0),) * len(lead) + ((0, m - c),) * 2)
+    a = jnp.moveaxis(a.reshape((-1, m, m)), 0, -1)  # [m, m, B]
+    # A21 of every pair of diagonal blocks of m / 2, m / 4, ... 1 rows,
+    # each [pairs, s, s, B], from the diagonal blocks of twice the rows
+    below, d = [], a[None]
+    while d.shape[1] > 1:
+        half = d.shape[1] // 2
+        below.append(d[:, half:, :half])
+        d = jnp.stack([d[:, :half, :half], d[:, half:, half:]], axis=1
+                      ).reshape((-1, half, half, d.shape[-1]))
+
+    def product(x, y):  # [P, s, s, B] each, over the two middle axes
+        return jnp.sum(x[:, :, :, None] * y[:, None, :, :], axis=2)
+
+    t = jnp.ones_like(d)  # m blocks of one row
+    for a21 in reversed(below):
+        pairs = t.reshape((-1, 2) + a21.shape[1:])
+        t11, t22 = pairs[:, 0], pairs[:, 1]
+        t21 = -product(product(t22, a21), t11)
+        t = jnp.concatenate(
+            [jnp.concatenate([t11, jnp.zeros_like(t11)], axis=2),
+             jnp.concatenate([t21, t22], axis=2)], axis=1)
+    return jnp.moveaxis(t[0, :c, :c], -1, 0).reshape(lead + (c, c))
+
+
+@jax.custom_vjp
+def _solve_unit_lower(a, rhs):
+    """``a^-1 rhs`` for a unit-lower ``a [..., C, C]`` and ``rhs [..., C,
+    R]``, float32. The backward reuses the forward's inverse: with ``x =
+    a^-1 rhs``, ``d rhs = a^-T d x`` and ``d a = -(d rhs) x^T`` below the
+    diagonal."""
+    return _solve_unit_lower_fwd(a, rhs)[0]
+
+
+def _solve_unit_lower_fwd(a, rhs):
+    t = _unit_lower_inverse(a)
+    solved = jnp.matmul(t, rhs, precision=_HIGHEST)
+    return solved, (t, solved)
+
+
+def _solve_unit_lower_bwd(residuals, d_solved):
+    t, solved = residuals
+    d_rhs = jnp.einsum("...sc,...sr->...cr", t, d_solved,
+                       precision=_HIGHEST)
+    d_a = -jnp.einsum("...cr,...sr->...cs", d_rhs, solved,
+                      precision=_HIGHEST)
+    return jnp.tril(d_a, -1), d_rhs
+
+
+_solve_unit_lower.defvjp(_solve_unit_lower_fwd, _solve_unit_lower_bwd)
+
+
 @jax.named_scope("delta_scan")
 def chunked_delta_rule(q, k, v, g, beta, chunk: int):
     """``o [B, L, H, d_v]`` of the gated delta rule from a zero state.
@@ -118,8 +196,7 @@ def chunked_delta_rule(q, k, v, g, beta, chunk: int):
     rhs = jnp.concatenate(
         [v.astype(f32) * beta[..., None],
          k_beta * jnp.exp(decay_to)[..., None]], axis=-1)
-    solved = jax.lax.linalg.triangular_solve(
-        a, rhs, left_side=True, lower=True, unit_diagonal=True)
+    solved = _solve_unit_lower(a, rhs)
     u, w = solved[..., :d_v].astype(dtype), solved[..., d_v:].astype(dtype)
     to_end = decay_to[..., -1:]
     k_end = (k.astype(f32) * jnp.exp(to_end - decay_to)[..., None]

@@ -247,14 +247,18 @@ def phase_fedsim_resnet18(env: Env) -> None:
 def phase_hybrid_lora(env: Env) -> None:
     """The hybrid decoder at a tiny size, bfloat16 over a bfloat16 base
     with adapters on activations, two rounds through ``FedSim``: a broken
-    scan (the chunked delta rule, its triangular solve, its backward
-    under the client ``vmap``) shows here before the benchmark meets it.
-    The same size on the chip and in rehearsal: this phase asks whether
-    the program runs, not how fast."""
+    scan (the chunked delta rule, its chunk inverse, its hand-written
+    backward under the client ``vmap``) shows here before the benchmark
+    meets it. Then the chunk inverse and ``T rhs`` at the benchmark
+    cell's shapes on keys that correlate, against XLA's
+    ``triangular_solve``: a float32 product that lost its ``precision``
+    reads 4e-3 here, on a TPU alone. The same size on the chip and in
+    rehearsal: this phase asks whether the program runs, not how fast."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from baton_tpu.models import delta_rule
     from baton_tpu.models.llama import LlamaConfig, decoder_lora_model
     from baton_tpu.models.lora import lora_trainable
     from baton_tpu.parallel.engine import FedSim
@@ -295,12 +299,45 @@ def phase_hybrid_lora(env: Env) -> None:
            "an adapter factor did not move or is not finite")
     _check(_on_platform(p, env.platform),
            f"round outputs do not live on the {env.platform} device")
+
+    # 4 clients x 30 heads x 16 chunks of 64, keys of 96 within 0.3 of one
+    # direction a chunk, beta 1.9, a decay within 1 % of 1
+    lead, c, d_k, d_v = (4, 30, 16), 64, 96, 192
+    kd, kn, kg, kr = jax.random.split(jax.random.key(3), 4)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    keys = unit(unit(jax.random.normal(kd, lead + (1, d_k)))
+                + 0.3 * unit(jax.random.normal(kn, lead + (c, d_k))))
+    since = jnp.cumsum(
+        jnp.log1p(-0.01 * jax.random.uniform(kg, lead + (c,))), axis=-1)
+    a = 1.9 * jnp.einsum("...cd,...sd->...cs", keys, keys,
+                         precision="highest")
+    a = jnp.tril(a * jnp.exp(since[..., :, None] - since[..., None, :]), -1)
+    a = a + jnp.eye(c)
+    rhs = jax.random.normal(kr, lead + (c, d_v + d_k))
+
+    def solve(b):
+        return jax.lax.linalg.triangular_solve(
+            a, b, left_side=True, lower=True, unit_diagonal=True)
+
+    def gap(got, want):
+        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+    inverse_gap = gap(jax.jit(delta_rule._unit_lower_inverse)(a),
+                      solve(jnp.broadcast_to(jnp.eye(c), a.shape)))
+    solved_gap = gap(jax.jit(delta_rule._solve_unit_lower)(a, rhs),
+                     solve(rhs))
+    _check(inverse_gap <= 1e-5, f"the chunk inverse is {inverse_gap:.2e} of "
+           f"its largest entry from triangular_solve's (limit 1e-5)")
+    _check(solved_gap <= 1e-5, f"T rhs is {solved_gap:.2e} of its largest "
+           f"entry from triangular_solve's (limit 1e-5)")
     env.say("hybrid_lora",
             f"{model.name}: 3 gated delta-rule layers + 1 full attention, "
             f"bf16 over a frozen bf16 base, {len(moved)} adapter factors on "
             f"activations, 4 clients x 160 tokens (chunks of 64), 2 rounds, "
             f"loss {losses[0]:.4f} -> {losses[1]:.4f}; {len(base)} base "
-            f"leaves handed back as the arrays they were")
+            f"leaves handed back as the arrays they were; {a.shape} chunk "
+            f"inverses on correlated keys within {inverse_gap:.1e} of "
+            f"triangular_solve's, T rhs within {solved_gap:.1e}")
 
 
 # ----------------------------------------------------------------------

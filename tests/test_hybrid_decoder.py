@@ -1,8 +1,9 @@
 """The hybrid decoder's mechanisms at test sizes on the CPU: the chunked
 gated delta rule against the recurrence written token by token (values
 and gradients, lengths that are and are not a multiple of the chunk,
-under a client ``vmap``); a block of one kind traced once whatever the
-depth; adapters on activations against the merged weight, the base kept
+keys that correlate, under a client ``vmap``); the chunk's inverse
+against float64 and what its trace holds; a block of one kind traced
+once whatever the depth; adapters on activations against the merged weight, the base kept
 as the arrays it was given; the next-token loss in blocks against the
 unblocked one; the ``baton.round`` span's byte counts."""
 
@@ -77,9 +78,56 @@ def _scan_inputs(seed, lead, l, h=3, d_k=8, d_v=16):
             2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], lead + (l, h))))
 
 
+def _keyed_inputs(keys, seed, lead, l, h=3, d_k=8, d_v=16):
+    """``_scan_inputs`` with keys, gates and decays a trained mixer can
+    produce and uncorrelated draws never do: ``correlated`` keys lie
+    within 0.3 of one direction a head, ``beta = 1.9``, a decay within
+    1 % of 1; ``identical`` keys are all the first unit vector, ``beta =
+    2``, no decay (the chunk's ``A`` is exactly 2 everywhere below the
+    diagonal, the largest the mixer can make)."""
+    q, k, v, g, beta = _scan_inputs(seed, lead, l, h, d_k, d_v)
+    if keys == "random":
+        return q, k, v, g, beta
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa
+    k0, k1 = jax.random.split(jax.random.key(seed + 100))
+    direction = unit(jax.random.normal(k0, lead + (1, h, d_k)))
+    if keys == "correlated":
+        k = unit(direction + 0.3 * k)
+        return (q, k, v, jnp.log1p(-0.01 * jax.random.uniform(k1, g.shape)),
+                jnp.full_like(beta, 1.9))
+    assert keys == "identical"
+    return (q, jnp.zeros_like(k).at[..., 0].set(1.0), v, jnp.zeros_like(g),
+            jnp.full_like(beta, 2.0))
+
+
+def _chunk_matrix(k, g, beta):
+    """``I + A`` of one chunk a batch element and head, in float64 on the
+    host: ``A[t, s] = beta_t (k_t . k_s) alpha_(s+1) ... alpha_t`` below
+    the diagonal. ``[B, H, L, L]`` from ``k [B, L, H, d_k]``."""
+    k, g, beta = (np.moveaxis(np.asarray(a, np.float64), 1, 2)
+                  for a in (k, g, beta))
+    since = np.cumsum(g, axis=-1)
+    decay = np.exp(since[..., :, None] - since[..., None, :])
+    a = np.einsum("bhtd,bhsd->bhts", k * beta[..., None], k) * decay
+    return np.tril(a, -1) + np.eye(a.shape[-1])
+
+
 def _close(got, want, rtol=2e-5):
     scale = max(float(jnp.max(jnp.abs(want))), 1e-12)
     assert float(jnp.max(jnp.abs(got - want))) <= rtol * scale
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _value_and_grads(fn):
+    return jax.value_and_grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                              argnums=(0, 1, 2, 3, 4))
 
 
 @pytest.mark.parametrize("length,chunk", [(16, 4), (10, 4), (7, 64),
@@ -88,22 +136,88 @@ def test_chunked_delta_rule_is_the_recurrence_token_by_token(length, chunk):
     """Values and all five gradients; 10 and 130 tokens leave a tail
     chunk that is padded, 7 tokens are one chunk shorter than 64."""
     args = _scan_inputs(length, (2,), length)
-
-    def through(fn):
-        return jax.value_and_grad(
-            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3, 4))
-
     with jax.default_matmul_precision("highest"):
         want_o = _token_by_token(*args)
         got_o = chunked_delta_rule(*args, chunk)
-        want, want_g = through(_token_by_token)(*args)
-        got, got_g = through(
+        want, want_g = _value_and_grads(_token_by_token)(*args)
+        got, got_g = _value_and_grads(
             lambda *a: chunked_delta_rule(*a, chunk))(*args)
     assert got_o.shape == want_o.shape == (2, length, 3, 16)
     _close(got_o, want_o)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for g, w in zip(got_g, want_g):
         _close(g, w)
+
+
+@pytest.mark.parametrize("keys,rtol", [("correlated", 2e-5),
+                                       ("identical", 2e-4)])
+def test_chunked_delta_rule_on_keys_that_correlate(keys, rtol):
+    """Two chunks of 64 whose ``I + A`` is far from the identity, values
+    and all five gradients. With identical keys the recurrence itself is
+    ill-conditioned, the float32 token-by-token scan no less: that limit
+    says no worse than forward substitution, not exact."""
+    args = _keyed_inputs(keys, 5, (2,), 128)
+    with jax.default_matmul_precision("highest"):
+        want_o = _token_by_token(*args)
+        got_o = chunked_delta_rule(*args, 64)
+        _, want_g = _value_and_grads(_token_by_token)(*args)
+        _, got_g = _value_and_grads(
+            lambda *a: chunked_delta_rule(*a, 64))(*args)
+    _close(got_o, want_o, rtol)
+    for g, w in zip(got_g, want_g):
+        _close(g, w, rtol)
+
+
+@pytest.mark.parametrize("size", [1, 4, 7, 24, 50, 64])
+@pytest.mark.parametrize("keys", ["random", "correlated", "identical"])
+def test_the_chunk_inverse_against_float64(keys, size):
+    """``_unit_lower_inverse`` of one chunk's ``I + A`` against
+    ``numpy.linalg.inv`` in float64, to 1e-5 of the inverse's largest
+    entry: a single row, the tests' chunk of 4, three sizes that are no
+    power of two (7, 24 and 50 are padded to 8, 32 and 64) and the
+    cell's 64."""
+    _, k, _, g, beta = _keyed_inputs(keys, size, (2,), size)
+    a = _chunk_matrix(k, g, beta).astype(np.float32)
+    want = np.linalg.inv(a.astype(np.float64))
+    got = np.asarray(delta_rule._unit_lower_inverse(jnp.asarray(a)))
+    assert got.shape == a.shape and got.dtype == np.float32
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+    assert not np.triu(got, 1).any()
+
+
+def test_the_gradient_inverts_a_chunk_once_by_products_at_highest(monkeypatch):
+    """What no CPU run shows by value. The trace of value and gradients
+    (activations in bfloat16, as the cell runs them) holds no
+    ``triangular_solve``; its products of two float32 operands are ``T
+    rhs`` and the two of the hand-written backward (every other product
+    has an operand in bfloat16) and each says ``Precision.HIGHEST``
+    itself, because a TPU's default rounds float32 operands to bfloat16;
+    the inverse holds no ``dot_general`` at all (its levels are sums of
+    elementwise products, float32 on any backend); and the backward
+    reuses the forward's inverse."""
+    calls = []
+    inverse = delta_rule._unit_lower_inverse
+    monkeypatch.setattr(delta_rule, "_unit_lower_inverse",
+                        lambda a: calls.append(a.shape) or inverse(a))
+    q, k, v, g, beta = _keyed_inputs("correlated", 1, (2,), 128)
+    q, k, v = (a.astype(jnp.bfloat16) for a in (q, k, v))
+    traced = jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: jnp.sum(chunked_delta_rule(*a, 64).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4)))(q, k, v, g, beta)
+    assert calls == [(2, 3, 2, 64, 64)]
+    eqns = list(_equations(traced.jaxpr))
+    assert "triangular_solve" not in {e.primitive.name for e in eqns}
+    in_float32 = [
+        e for e in eqns if e.primitive.name == "dot_general"
+        and all(x.aval.dtype == jnp.float32 for x in e.invars)]
+    assert len(in_float32) == 3
+    for e in in_float32:
+        precision = e.params["precision"]
+        pair = precision if isinstance(precision, tuple) else (precision,) * 2
+        assert all(p == jax.lax.Precision.HIGHEST for p in pair), e
+    alone = jax.make_jaxpr(inverse)(jnp.zeros((2, 64, 64)))
+    assert "dot_general" not in {
+        e.primitive.name for e in _equations(alone.jaxpr)}
 
 
 def test_chunked_delta_rule_under_a_client_vmap():
@@ -298,16 +412,8 @@ def test_no_merged_weight_is_built_in_training():
     jaxpr = jax.make_jaxpr(wave)(lora, params["base"], batch)
     weights = {leaf.shape for leaf in
                jax.tree_util.tree_leaves(params["base"]) if leaf.ndim == 2}
-    made = set()
-
-    def walk(j):
-        for eqn in j.eqns:
-            made.update(v.aval.shape for v in eqn.outvars
-                        if hasattr(v.aval, "shape"))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-
-    walk(jaxpr.jaxpr)
+    made = {v.aval.shape for eqn in _equations(jaxpr.jaxpr)
+            for v in eqn.outvars if hasattr(v.aval, "shape")}
     assert not {(clients,) + w for w in weights} & made
     assert not {(clients,) + w[::-1] for w in weights} & made
 

@@ -106,17 +106,19 @@ def test_outbox_retries_503_until_delivered():
         assert await _wait(lambda: len(exp.registry) == 1)
 
         # warm-up: compile the trainer outside the faulted window
+        # the manager closes the round on the POST; the worker counts the
+        # delivery when the 200 reaches it, a moment later
         await _start_round(mport, name)
-        assert await _wait(lambda: not exp.rounds.in_progress)
-        assert workers[0].n_updates == 1
+        assert await _wait(lambda: not exp.rounds.in_progress
+                           and workers[0].n_updates == 1)
 
         rule = inj.error(f"/{name}/update", status=503, times=3)
         acks = await _start_round(mport, name)
         assert all(acks.values())
-        assert await _wait(lambda: not exp.rounds.in_progress)
+        assert await _wait(lambda: not exp.rounds.in_progress
+                           and workers[0].n_updates == 2)
         # delivery happened on the attempt AFTER the injected refusals
         assert rule.hits == 3
-        assert workers[0].n_updates == 2
         snap = workers[0].metrics.snapshot()
         assert snap["counters"]["update_retries"] >= 3
         assert snap["counters"]["updates_delivered"] == 2
