@@ -51,6 +51,11 @@ class FedModel:
     # hashable model metadata (e.g. LoraSpec) — FedModel rides inside
     # jit-static trainer fields, so anything here must hash/eq by value
     aux: Any = None
+    # static facts of the model's layers as ``(name, number)`` pairs,
+    # written on the ``baton.round`` span beside the round's own
+    # (``FedSim.run_round``): an expert layer's ``experts_held`` and
+    # ``experts_total``
+    span_attrs: tuple = ()
 
     def masked_loss(self, params: Params, batch: Batch, rng: PRNGKey) -> jax.Array:
         """Mean loss over *real* (unmasked) examples.

@@ -5,13 +5,18 @@ zoo); this decoder exists for the driver-set federated LoRA workload.
 Architecture is the modern decoder recipe — RMSNorm pre-norm, RoPE,
 SwiGLU MLP, grouped-query attention, untied output head — built from the
 TPU-first blocks in :mod:`baton_tpu.models.transformer`. ``layer_types``
-makes it a hybrid: each layer's mixer is full attention or the gated
+makes it a hybrid: each layer's mixer is full attention, the gated
 delta rule of :mod:`baton_tpu.models.delta_rule` (linear attention with
-a recurrent state), in the pattern the configuration gives; a block is
-traced once a kind, whatever the depth.
+a recurrent state) or latent attention (``mla``: keys and values from a
+low-rank latent, :func:`baton_tpu.models.transformer.mla_apply`), in
+the pattern the configuration gives. With ``moe`` the layers after the
+first ``first_dense_layers`` replace their SwiGLU by the expert layer of
+:mod:`baton_tpu.models.moe`, which holds ``moe.experts_held`` of the
+router's experts and computes their part. A block is traced once a
+kind (of mixer and of feed-forward), whatever the depth.
 
-* params fp32 / activations ``compute_dtype`` (bf16 on TPU), norms and
-  softmax in fp32;
+* params fp32 / activations ``compute_dtype`` (bf16 on TPU), norms,
+  softmax and the experts' router in fp32;
 * causal masking is static inside the attention kernel; an optional
   per-token ``loss_mask`` weights the LM loss (instruction tuning
   masks the prompt);
@@ -42,16 +47,21 @@ import jax
 import jax.numpy as jnp
 
 from baton_tpu.core.model import FedModel
+from baton_tpu.core.partition import path_str
 from baton_tpu.models.delta_rule import gated_delta_apply, gated_delta_init
 from baton_tpu.models.lora import lora_wrap
 from baton_tpu.models.moe import MoEConfig, moe_apply, moe_init
 from baton_tpu.models.transformer import (
     AttentionFn,
+    MLAConfig,
     dense_init,
     default_attention,
     matmul,
     mha_apply,
     mha_init,
+    mla_apply,
+    mla_init,
+    mla_rope_angles,
     next_token_loss,
     normal_init,
     rms_init,
@@ -74,14 +84,20 @@ class LlamaConfig:
     # None: no rotary embedding (position comes from the recurrent
     # layers of a hybrid)
     rope_theta: Optional[float] = 500000.0
-    # Mixture-of-Experts: replaces every block's SwiGLU FFN with a
-    # routed expert layer (models/moe.py) — the ep axis
+    # the expert layer (models/moe.py) that stands for the SwiGLU of
+    # width ``d_ff`` in every layer after the first
+    # ``first_dense_layers``; its own width is ``moe.d_ff``
     moe: Optional[MoEConfig] = None
+    first_dense_layers: int = 0
+    # latent attention's sizes; the mixer of a "latent_attention" layer,
+    # and of every layer where ``layer_types`` is None
+    mla: Optional[MLAConfig] = None
     # RMSNorm of the whole query and key projections in full attention
     qk_norm: bool = False
-    # the mixer of each layer, "full_attention" or "linear_attention";
-    # the first ``n_layers`` entries count (a depth cut keeps the
-    # published list). None: full attention everywhere
+    # the mixer of each layer, "full_attention", "linear_attention" or
+    # "latent_attention"; the first ``n_layers`` entries count (a depth
+    # cut keeps the published list). None: one mixer everywhere, latent
+    # attention with ``mla``, else full attention
     layer_types: Optional[Tuple[str, ...]] = None
     # the linear-attention (gated delta rule) layers' heads
     linear_n_heads: int = 0
@@ -102,8 +118,12 @@ class LlamaConfig:
         return self.d_model // self.n_heads
 
     def kind_of(self, layer: int) -> str:
-        return (self.layer_types[layer] if self.layer_types
-                else "full_attention")
+        if self.layer_types:
+            return self.layer_types[layer]
+        return "latent_attention" if self.mla else "full_attention"
+
+    def has_experts(self, layer: int) -> bool:
+        return self.moe is not None and layer >= self.first_dense_layers
 
     @classmethod
     def llama3_8b(cls, **kw) -> "LlamaConfig":
@@ -126,28 +146,36 @@ def llama_lora_target(path: str, leaf) -> bool:
     return path.rsplit("/", 1)[-1] in ("wq", "wk", "wv", "wo")
 
 
-_PROJECTIONS = ("wq", "wk", "wv", "wo", "wg", "w_gate", "w_up", "w_down")
+_PROJECTIONS = ("wq", "wk", "wv", "wo", "wg", "wkv_a", "wkv_b",
+                "w_gate", "w_up", "w_down")
 
 
 def projection_lora_target(path: str, leaf) -> bool:
-    """LoRA target predicate: every projection of the mixers (full and
-    linear attention) and of the MLPs; not the embedding, the head, the
-    linear layers' gate projections ``wa`` / ``wb`` or their
-    convolutions."""
-    return path.rsplit("/", 1)[-1] in _PROJECTIONS
+    """LoRA target predicate: every 2-D projection of the mixers (full,
+    linear and latent attention), of the MLPs and of a shared expert;
+    not the embedding, the head, the linear layers' gate projections
+    ``wa`` / ``wb`` or their convolutions, and not an expert layer's
+    router or its 3-D stacks of routed experts."""
+    return (getattr(leaf, "ndim", 2) == 2
+            and path.rsplit("/", 1)[-1] in _PROJECTIONS)
 
 
-def _block_init(key, cfg: LlamaConfig, kind: str = "full_attention"):
-    """A linear-attention block holds its mixer under ``linear_attn``
-    and a full-attention block under ``attn``: the kind of a block is
-    the structure of its parameters."""
+def _block_init(key, cfg: LlamaConfig, kind: str = "full_attention",
+                experts: bool = False):
+    """A linear-attention block holds its mixer under ``linear_attn``,
+    a latent-attention block under ``mla`` and a full-attention block
+    under ``attn``; an expert layer's ``mlp`` holds a ``router``: the
+    kind of a block is the structure of its parameters."""
     ka, km = jax.random.split(key)
-    if cfg.moe is not None:
+    if experts:
         mlp = moe_init(km, cfg.d_model, cfg.d_ff, cfg.moe)
     else:
         mlp = swiglu_init(km, cfg.d_model, cfg.d_ff)
     out_std = cfg.d_model ** -0.5 / (2 * cfg.n_layers) ** 0.5
-    if kind == "linear_attention":
+    if kind == "latent_attention":
+        mixer = {"mla": mla_init(ka, cfg.d_model, cfg.n_heads, cfg.mla,
+                                 out_std=out_std)}
+    elif kind == "linear_attention":
         mixer = {"linear_attn": gated_delta_init(
             ka, cfg.d_model, cfg.linear_n_heads, cfg.linear_key_dim,
             cfg.linear_value_dim, out_std=out_std)}
@@ -162,11 +190,10 @@ def _block_init(key, cfg: LlamaConfig, kind: str = "full_attention"):
 
 
 def _block_apply(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
-    """Returns (x, aux); aux is the block's MoE load-balance loss (0.0
-    for dense blocks) — one output structure for both variants so the
-    remat wrapper and the layer loop don't branch."""
     h = rms_norm(x, p["norm_attn"])
-    if "linear_attn" in p:
+    if "mla" in p:
+        x = x + mla_apply(p["mla"], h, cfg.n_heads, cfg.mla, rope)
+    elif "linear_attn" in p:
         x = x + gated_delta_apply(
             p["linear_attn"], h, cfg.linear_n_heads, cfg.linear_chunk,
             cfg.linear_allow_neg_eigval)
@@ -176,10 +203,9 @@ def _block_apply(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
             causal=True, rope=rope, attention_fn=attention_fn,
         )
     h = rms_norm(x, p["norm_mlp"])
-    if cfg.moe is not None:
-        y, aux = moe_apply(p["mlp"], h, cfg.moe)
-        return x + y, aux
-    return x + swiglu_apply(p["mlp"], h), jnp.float32(0.0)
+    if "router" in p["mlp"]:
+        return x + moe_apply(p["mlp"], h, cfg.moe)
+    return x + swiglu_apply(p["mlp"], h)
 
 
 def llama_lm_model(
@@ -195,9 +221,10 @@ def llama_lm_model(
     them, cutting activation memory from O(L·n_layers) to O(L) at ~1/3
     extra FLOPs — what makes long-sequence / large-model training
     (config 4) fit HBM. ``param_dtype`` is the dtype ``init`` gives the
-    matrices (a base that stays frozen is held in bfloat16); vectors
-    (norm scales, the linear layers' ``a_log`` and ``dt_bias``) are
-    float32."""
+    matrices and an expert layer's 3-D stacks (a base that stays frozen
+    is held in bfloat16); vectors (norm scales, the linear layers'
+    ``a_log`` and ``dt_bias``, a router's bias) and the router itself
+    are float32."""
     cfg = config or LlamaConfig.llama3_8b()
     # made once a model: ``jax.checkpoint`` caches its trace on the
     # function and the arguments' structure, so blocks of one kind share
@@ -211,61 +238,54 @@ def llama_lm_model(
             "tok_emb": normal_init(keys[0], (cfg.vocab_size, cfg.d_model),
                                    cfg.embed_std),
             "blocks": [
-                _block_init(keys[1 + i], cfg, cfg.kind_of(i))
+                _block_init(keys[1 + i], cfg, cfg.kind_of(i),
+                            cfg.has_experts(i))
                 for i in range(cfg.n_layers)
             ],
             "norm_f": rms_init(cfg.d_model),
             "lm_head": dense_init(keys[-1], cfg.d_model, cfg.vocab_size),
         }
-        return jax.tree_util.tree_map(
-            lambda a: a.astype(param_dtype) if a.ndim >= 2 else a, params)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, a: a.astype(param_dtype) if a.ndim >= 2
+            and not path_str(path).endswith("/router") else a, params)
 
     def _hidden(params, batch):
-        """The final norm's output ``[B, L, D]`` and the MoE penalty."""
+        """The final norm's output ``[B, L, D]``."""
         ids = batch["x"]
         l = ids.shape[1]
-        rope = (None if cfg.rope_theta is None
-                else rope_angles(l, cfg.head_dim, cfg.rope_theta))
+        if cfg.mla is not None:
+            rope = mla_rope_angles(l, cfg.mla)
+        else:
+            rope = (None if cfg.rope_theta is None
+                    else rope_angles(l, cfg.head_dim, cfg.rope_theta))
         with jax.named_scope("embed"):
             x = params["tok_emb"][ids].astype(compute_dtype)
-        aux_total = jnp.float32(0.0)
         for i, blk in enumerate(params["blocks"]):
             with jax.named_scope(f"block{i}"):
-                x, aux = block_fn(blk, x, cfg, rope, attention_fn)
-            aux_total = aux_total + aux
-        return rms_norm(x, params["norm_f"]), aux_total
+                x = block_fn(blk, x, cfg, rope, attention_fn)
+        return rms_norm(x, params["norm_f"])
 
     def apply(params, batch, rng):
         """Returns next-token logits [B, L, V] (fp32): bf16 operands,
         fp32 accumulation — the vocab projection is the model's largest
         matmul, keep it on the fast MXU path."""
-        x, _ = _hidden(params, batch)
-        return matmul(x, params["lm_head"], jnp.float32)
-
-    def _add_aux(per_example, aux):
-        # the MoE load-balance penalty is a whole-forward scalar; add it
-        # to EVERY example so the mean loss (what every consumer — the
-        # trainer objective, DP-SGD's per-example path, the evaluator —
-        # optimizes) gains exactly aux_weight·aux, independent of batch
-        # size
-        if cfg.moe is None:
-            return per_example
-        return per_example + cfg.moe.aux_weight * aux
+        return matmul(_hidden(params, batch), params["lm_head"], jnp.float32)
 
     def per_example_loss(params, batch, rng):
-        x, aux = _hidden(params, batch)
+        x = _hidden(params, batch)
         tok_loss = next_token_loss(x, params["lm_head"], batch["y"])  # [B, L]
         loss_mask = batch.get("loss_mask")
         if loss_mask is None:
-            return _add_aux(jnp.mean(tok_loss, axis=-1), aux)
+            return jnp.mean(tok_loss, axis=-1)
         m = loss_mask.astype(jnp.float32)
-        loss = jnp.sum(tok_loss * m, axis=-1) / jnp.maximum(
+        return jnp.sum(tok_loss * m, axis=-1) / jnp.maximum(
             jnp.sum(m, axis=-1), 1.0
         )
-        return _add_aux(loss, aux)
 
+    facts = () if cfg.moe is None else (
+        ("experts_held", cfg.moe.held), ("experts_total", cfg.moe.n_experts))
     return FedModel(init=init, apply=apply, per_example_loss=per_example_loss,
-                    name=name, aux=cfg)
+                    name=name, aux=cfg, span_attrs=facts)
 
 
 def decoder_lora_model(

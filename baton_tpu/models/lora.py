@@ -213,4 +213,5 @@ def lora_wrap(
         per_example_loss=per_example_loss,
         name=name or f"{model.name}_lora{rank}",
         aux=spec,
+        span_attrs=model.span_attrs,
     )

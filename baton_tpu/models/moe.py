@@ -1,164 +1,330 @@
-"""Mixture-of-Experts FFN with expert parallelism — the ``ep`` axis.
+"""Expert layer: a routed feed-forward that is told which experts it
+holds.
 
-The reference has nothing remotely like this (its model zoo is a 10→1
-linear layer, reference demo.py:15-49); this layer exists so the
-decoder scales parameters past one chip the TPU way, completing the
-framework's parallelism axes (dp=clients, tp=model, sp=seq, ep=experts).
+The router scores every token against all ``n_experts`` (sigmoid
+scores in float32, DeepSeek-V3's convention, arXiv:2412.19437 section
+2.1): the ``top_k`` largest of ``s + router_bias`` are chosen (the bias
+chooses, it does not weigh), their weights are ``routed_scale * s_i /
+sum of the chosen s``. The layer holds the ``experts_held`` experts
+from ``first_held`` on, as one expert-parallel rank does, and computes
+its own experts' part of the result::
 
-TPU-first design:
+    y = sum over chosen i that are held of g_i E_i(h)  +  E_shared(h)
+    E(h) = (SiLU(h w_gate) * (h w_up)) w_down
 
-* **Static shapes throughout** — top-k routing uses the GShard/Switch
-  dispatch-tensor formulation: every expert gets a fixed capacity
-  ``C = ceil(capacity_factor · K · L / E)`` and tokens beyond it are
-  dropped (their gate mass is simply not added back — the residual
-  stream carries them unchanged). No dynamic shapes, so the whole layer
-  jits, vmaps over clients, and remats.
-* **Everything is einsum** — dispatch [B,S,E,C] · tokens [B,S,D] feeds
-  the stacked expert weights [E, D, F] in one batched contraction the
-  MXU tiles; combine is the transpose einsum weighted by the gates.
-  The dispatch tensor costs O(B·K·L·E·C) fp32 — fine for the
-  federated/long-context regimes this zoo targets; for trillion-scale
-  routing you would move to ragged all-to-all dispatch.
-* **Expert parallelism is a sharding annotation, not collectives** —
-  the stacked expert dim E is sharded over the ``model`` mesh axis
-  (parallel/tensor_parallel.py rules); GSPMD partitions the expert
-  einsums and inserts the all-to-alls. The router stays replicated.
-* **Load-balance aux loss** (Switch Transformer): ``E · Σ_e f_e · P_e``
-  where f_e is the fraction of tokens whose top-1 choice is e and P_e
-  the mean router probability — minimized (=1) at uniform routing.
-  :func:`baton_tpu.models.llama.llama_lm_model` folds it into the
-  per-example loss with ``moe.aux_weight``.
+A choice that falls on an expert held elsewhere adds nothing here; the
+exchange between ranks (an all-to-all of tokens) is not written. No
+token is dropped and there is no capacity: the ``top_k`` assignments of
+every token are sorted by expert, each projection is one grouped matrix
+product over the held experts' rows (:func:`grouped_matmul`), and the
+rows go back to their tokens weighted by ``g``.
+
+Under a ``vmap`` over clients (``FedSim``'s wave) the expert stacks
+carry no client axis when they are frozen: the routed part is a
+``jax.custom_vjp`` whose forward and backward are ``custom_vmap``
+functions, and their rule folds the client axis into the token axis
+(``[C, T, D] -> [C T, D]``), so every client's rows go through one
+grouped product a projection and each expert's weights are read once a
+pass, not once a client. Stacks that do carry a client axis (experts
+that train) take a ``lax.map`` over clients instead. The backward
+computes the cotangents of the activations and of the gates; the
+stacks' own gradients are computed only where something asks for them
+(under ``jit`` they are dead code over a frozen base).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
-from baton_tpu.models.transformer import dense_init
+from baton_tpu.models.transformer import dense_init, swiglu, swiglu_init
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    # the router's width: every expert of the layer, held here or not
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
-    aux_weight: float = 0.01
+    # width of one expert (and of the shared expert); None: the
+    # decoder's ``d_ff``
+    d_ff: Optional[int] = None
+    # the experts this rank holds: ``experts_held`` from ``first_held``
+    # on; None: all of them
+    experts_held: Optional[int] = None
+    first_held: int = 0
+    routed_scale: float = 1.0
+    # shared experts, computed for every token beside the routed ones,
+    # as one SwiGLU of ``n_shared * d_ff``
+    n_shared: int = 0
+    # a per-expert bias added to the scores for the choice alone, drawn
+    # uniform in +-this range (a trained model's balances the load;
+    # zeros could not tell choosing from weighing); None: no bias
+    router_bias_range: Optional[float] = None
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.experts_held is None else self.experts_held
 
 
 def moe_init(key, d_model: int, d_ff: int, cfg: MoEConfig):
-    kr, kg, ku, kd = jax.random.split(key, 4)
-    e = cfg.n_experts
+    kr, kb, kg, ku, kd, ks = jax.random.split(key, 6)
+    d_ff = cfg.d_ff or d_ff
 
     def stack(k, d_in, d_out):
-        return jax.vmap(lambda kk: dense_init(kk, d_in, d_out))(
-            jax.random.split(k, e)
-        )
+        # the held experts' draws are those the whole layer would give
+        # them: a rank's stack is a slice of the uncut layer's
+        keys = jax.random.split(k, cfg.n_experts)[
+            cfg.first_held:cfg.first_held + cfg.held]
+        return jax.vmap(lambda kk: dense_init(kk, d_in, d_out))(keys)
 
-    return {
-        "router": dense_init(kr, d_model, e),
-        "w_gate": stack(kg, d_model, d_ff),   # [E, D, F]
-        "w_up": stack(ku, d_model, d_ff),     # [E, D, F]
-        "w_down": stack(kd, d_ff, d_model),   # [E, F, D]
+    p = {
+        "router": dense_init(kr, d_model, cfg.n_experts),
+        "w_gate": stack(kg, d_model, d_ff),   # [E_held, D, F]
+        "w_up": stack(ku, d_model, d_ff),     # [E_held, D, F]
+        "w_down": stack(kd, d_ff, d_model),   # [E_held, F, D]
     }
+    if cfg.router_bias_range is not None:
+        p["router_bias"] = jax.random.uniform(
+            kb, (cfg.n_experts,), jnp.float32, -cfg.router_bias_range,
+            cfg.router_bias_range)
+    if cfg.n_shared:
+        p["shared"] = swiglu_init(ks, d_model, cfg.n_shared * d_ff)
+    return p
 
 
-def moe_capacity(cfg: MoEConfig, seq_len: int) -> int:
-    return max(
-        1, math.ceil(cfg.capacity_factor * cfg.top_k * seq_len / cfg.n_experts)
-    )
+@jax.named_scope("router")
+def route(p, x, cfg: MoEConfig):
+    """``(idx, gate)``, each ``[..., top_k]``: the experts every token
+    chose among all ``n_experts`` and their weights, float32."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "...d,de->...e", x.astype(jnp.float32), p["router"],
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + p.get("router_bias", 0.0), cfg.top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, cfg.routed_scale * chosen / jnp.sum(chosen, -1, keepdims=True)
 
 
+# ------------------------------------------------------- grouped products
+# (rows, contraction, columns) a tile of the Pallas grouped product. Read
+# on a v5e at 16,384 rows in 32 groups of 4,096 x 2,048 in bfloat16 (my
+# chip run, PR 33): 3.00 ms, 92 TFLOP/s; (512, 1024, 1024) 3.13;
+# (512, 512, 512) 3.81; the kernel's default (128, 128, 128) 29.8;
+# ``jax.lax.ragged_dot`` 3.76, and 5.51 against a transposed stack
+_GMM_TILING = (256, 1024, 1024)
+
+
+def _gmm(x, w, sizes, transpose_rhs: bool, interpret: bool = False):
+    """The Pallas ``megablox`` grouped product that ships with JAX (it
+    visits the tiles that hold rows and no others), at ``_GMM_TILING``.
+    ``interpret``: the kernel's body as plain JAX, for a test off the
+    chip."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    n, k = x.shape
+    m = w.shape[1] if transpose_rhs else w.shape[2]
+    if n % _GMM_TILING[0] or k % 128 or m % 128:
+        raise ValueError(
+            f"grouped product of {n} rows, {k} x {m}: the rows have to be "
+            f"a multiple of {_GMM_TILING[0]}, both widths of 128")
+    tiling = (_GMM_TILING[0], min(_GMM_TILING[1], k), min(_GMM_TILING[2], m))
+    return gmm(x, w, sizes, preferred_element_type=x.dtype, tiling=tiling,
+               transpose_rhs=transpose_rhs, interpret=interpret)
+
+
+@jax.named_scope("expert_matmul")
+def grouped_matmul(x, w, sizes, transpose_rhs: bool = False):
+    """``x [N, K]``, its rows sorted by expert, ``sizes [E]`` rows an
+    expert, times that expert's ``w [E, K, M]`` (``[E, M, K]`` with
+    ``transpose_rhs``); rows past ``sum(sizes)`` come out zero. The
+    result is in ``x``'s dtype, accumulated in float32. On a TPU the
+    Pallas kernel (:func:`_gmm`; sizes its tiles do not divide are
+    refused, not taken elsewhere); off it ``jax.lax.ragged_dot``."""
+    w = w.astype(x.dtype)
+    if jax.default_backend() == "tpu":
+        out = _gmm(x, w, sizes, transpose_rhs)
+    else:
+        if transpose_rhs:
+            w = jnp.swapaxes(w, 1, 2)
+        out = jax.lax.ragged_dot(x, w, sizes, preferred_element_type=x.dtype)
+    return _rows_of_the_groups(out, sizes)
+
+
+def _rows_of_the_groups(out, sizes):
+    """Neither product writes the rows past the groups: what is there
+    is whatever the memory held (on the chip, NaN as soon as not)."""
+    return jnp.where((jnp.arange(out.shape[0]) < jnp.sum(sizes))[:, None],
+                     out, 0)
+
+
+@jax.named_scope("expert_matmul")
+def _grouped_outer(x, dy, sizes):
+    """``[E, K, M]``: for each expert the sum over its rows of ``x
+    [N, K]`` outer ``dy [N, M]``, the gradient of a stack."""
+    numbers = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    live = (jnp.arange(x.shape[0]) < jnp.sum(sizes))[:, None]
+    return jax.lax.ragged_dot_general(
+        jnp.where(live, x, 0), dy, sizes, numbers,
+        preferred_element_type=jnp.float32)
+
+
+def _sorted_rows(idx, n_held: int):
+    """The assignments ``idx [T, K]`` (``n_held`` where the expert is
+    not held) in expert order, the absent ones last: ``(order, place,
+    sizes, live)``: the assignment (``token * K + choice``) of each
+    sorted row, each assignment's place in the sorted order ``[T, K]``,
+    the rows a held expert, and which sorted rows are of a held
+    expert."""
+    t, k = idx.shape
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    place = jnp.zeros(t * k, jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32)).reshape(t, k)
+    sizes = jnp.zeros(n_held + 1, jnp.int32).at[flat].add(1)[:n_held]
+    return order, place, sizes, flat[order] < n_held
+
+
+def _routed_forward(x, idx, gate, w_gate, w_up, w_down):
+    """``x [T, D]``, local expert ids ``idx [T, K]``, weights ``gate
+    [T, K]``: the held experts' part of the layer's result ``[T, D]``,
+    as a tuple of one (``_over_clients`` takes tuples)."""
+    order, place, sizes, _ = _sorted_rows(idx, w_gate.shape[0])
+    rows = x[order // idx.shape[1]]
+    mid = jax.nn.silu(grouped_matmul(rows, w_gate, sizes)) \
+        * grouped_matmul(rows, w_up, sizes)
+    out = grouped_matmul(mid, w_down, sizes)
+    return (jnp.sum(out[place].astype(jnp.float32) * gate[..., None],
+                    axis=1).astype(x.dtype),)
+
+
+def _routed_backward(x, idx, gate, dy, w_gate, w_up, w_down):
+    """The cotangents of ``x``, ``gate`` and the three stacks, the
+    forward's products recomputed (the block is under ``remat``
+    anyway). Gathers only: a sorted row reads its token's ``dy``, a
+    token sums its ``K`` rows."""
+    order, place, sizes, live = _sorted_rows(idx, w_gate.shape[0])
+    token = order // idx.shape[1]
+    rows = x[token]
+    a = grouped_matmul(rows, w_gate, sizes).astype(jnp.float32)
+    b = grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
+    sig = jax.nn.sigmoid(a)
+    mid = a * sig * b
+    dy_rows = dy[token]
+    # d out . w_down^T once, unweighted: its product with mid is the
+    # gate's cotangent, weighted by the gate it is mid's
+    u = grouped_matmul(dy_rows, w_down, sizes, transpose_rhs=True
+                       ).astype(jnp.float32)
+    g_rows = jnp.where(live, gate.reshape(-1)[order], 0.0)[:, None]
+    d_gate = jnp.sum(u * mid, axis=-1)[place]
+    d_mid = u * g_rows
+    d_a = (d_mid * b * sig * (1.0 + a * (1.0 - sig))).astype(x.dtype)
+    d_b = (d_mid * a * sig).astype(x.dtype)
+    d_rows = grouped_matmul(d_a, w_gate, sizes, transpose_rhs=True) \
+        + grouped_matmul(d_b, w_up, sizes, transpose_rhs=True)
+    d_x = jnp.sum(d_rows[place].astype(jnp.float32), axis=1).astype(x.dtype)
+    d_out = (dy_rows.astype(jnp.float32) * g_rows).astype(x.dtype)
+    stacks = (_grouped_outer(rows, d_a, sizes).astype(w_gate.dtype),
+              _grouped_outer(rows, d_b, sizes).astype(w_up.dtype),
+              _grouped_outer(mid.astype(x.dtype), d_out, sizes
+                             ).astype(w_down.dtype))
+    return (d_x, d_gate) + stacks
+
+
+def _over_clients(fn, n_out_acts: int):
+    """``fn(x, idx, gate, ..., w_gate, w_up, w_down) -> tuple`` as a
+    ``custom_vmap`` function whose rule folds a client axis on the
+    activations into their row axis where the stacks carry none; the
+    first ``n_out_acts`` results are per row and unfold again, the rest
+    (a stack's gradient a client) come from a ``lax.map`` over clients,
+    as does everything where a stack carries the axis."""
+    wrapped = custom_vmap(fn)
+
+    @wrapped.def_vmap
+    def rule(axis_size, in_batched, *args):
+        def along_clients(arrays, batched):
+            return [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                    for a, b in zip(arrays, batched)]
+
+        acts = along_clients(args[:-3], in_batched[:-3])
+        stacks = args[-3:]
+        if any(in_batched[-3:]):
+            out = jax.lax.map(lambda a: fn(*a), (
+                *acts, *along_clients(stacks, in_batched[-3:])))
+            return out, (True,) * len(out)
+        folded = wrapped(*(a.reshape((-1,) + a.shape[2:]) for a in acts),
+                         *stacks)
+        out = tuple(o.reshape((axis_size, -1) + o.shape[1:])
+                    for o in folded[:n_out_acts])
+        if len(folded) > n_out_acts:
+            out += tuple(jax.lax.map(
+                lambda a: fn(*a, *stacks)[n_out_acts:], tuple(acts)))
+        return out, (True,) * len(out)
+
+    return wrapped
+
+
+_forward_folded = _over_clients(_routed_forward, 1)
+_backward_folded = _over_clients(_routed_backward, 2)
+
+
+@jax.custom_vjp
+def routed_experts(x, idx, gate, w_gate, w_up, w_down):
+    """The held experts' part of the layer: ``x [T, D]`` in the compute
+    dtype, ``idx [T, K]`` the chosen experts counted from the first one
+    held (``E_held`` or more: not held here), ``gate [T, K]`` float32."""
+    return _forward_folded(x, idx, gate, w_gate, w_up, w_down)[0]
+
+
+def _routed_fwd(*args):
+    return routed_experts(*args), args
+
+
+def _routed_bwd(res, dy):
+    x, idx, gate, *stacks = res
+    d_x, d_gate, *d_stacks = _backward_folded(x, idx, gate, dy, *stacks)
+    return (d_x, None, d_gate, *d_stacks)
+
+
+routed_experts.defvjp(_routed_fwd, _routed_bwd)
+
+
+@jax.named_scope("moe")
 def moe_apply(p, x, cfg: MoEConfig):
-    """x [B, L, D] -> (y [B, L, D] in x.dtype, aux fp32 scalar).
-
-    Routing math is fp32 regardless of compute dtype; the expert
-    matmuls keep x's dtype with fp32 accumulation (MXU bf16 path).
-    """
+    """``x [B, L, D] -> y [B, L, D]`` in ``x``'s dtype: the held
+    experts' part of the routed result plus the shared expert."""
     b, l, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
-    c = moe_capacity(cfg, l)
-
-    logits = jnp.einsum(
-        "bld,de->ble", x.astype(jnp.float32), p["router"]
-    )
-    probs = jax.nn.softmax(logits, axis=-1)                  # [B, L, E]
-    gate, idx = jax.lax.top_k(probs, k)                      # [B, L, K]
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
-
-    # flatten choices k-major (s = k·L + l): every token's 1st choice
-    # claims capacity before any token's 2nd choice — the Switch/GShard
-    # priority order
-    idx_f = jnp.swapaxes(idx, 1, 2).reshape(b, k * l)        # [B, S]
-    gate_f = jnp.swapaxes(gate, 1, 2).reshape(b, k * l)
-    mask = jax.nn.one_hot(idx_f, e, dtype=jnp.float32)       # [B, S, E]
-    pos = jnp.sum(
-        (jnp.cumsum(mask, axis=1) - 1.0) * mask, axis=-1
-    ).astype(jnp.int32)
-    # over-capacity slots (pos >= C) one_hot to an all-zero row — the
-    # token is dropped with no extra masking needed
-    disp = (
-        mask[..., None]
-        * jax.nn.one_hot(pos, c, dtype=jnp.float32)[:, :, None, :]
-    )                                                        # [B, S, E, C]
-
-    # expose the k axis on the dispatch tensor instead of materializing
-    # k copies of x (s = k·L + l is k-major, so the reshape is exact)
-    disp_x = disp.astype(x.dtype)
-    expert_in = jnp.einsum(
-        "bklec,bld->becd", disp_x.reshape(b, k, l, e, c), x
-    )                                                        # [B, E, C, D]
-    h_gate = jnp.einsum(
-        "becd,edf->becf", expert_in, p["w_gate"].astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    h_up = jnp.einsum(
-        "becd,edf->becf", expert_in, p["w_up"].astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    h = (jax.nn.silu(h_gate) * h_up).astype(x.dtype)
-    expert_out = jnp.einsum(
-        "becf,efd->becd", h, p["w_down"].astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )                                                        # fp32
-
-    comb = disp * gate_f[..., None, None]                    # [B, S, E, C]
-    y = jnp.einsum("bsec,becd->bsd", comb, expert_out)       # fp32 [B, S, D]
-    y = y.reshape(b, k, l, d).sum(axis=1)                    # fold choices
-
-    # Switch load-balance aux over top-1 assignments
-    top1 = jax.nn.one_hot(idx[..., 0], e, dtype=jnp.float32)
-    frac_tokens = jnp.mean(top1, axis=(0, 1))                # [E]
-    mean_prob = jnp.mean(probs, axis=(0, 1))                 # [E]
-    aux = e * jnp.sum(frac_tokens * mean_prob)
-    return y.astype(x.dtype), aux
+    idx, gate = route(p, x, cfg)
+    local = idx - cfg.first_held
+    local = jnp.where((local >= 0) & (local < cfg.held), local, cfg.held)
+    y = routed_experts(
+        x.reshape(b * l, d), local.reshape(b * l, -1).astype(jnp.int32),
+        gate.reshape(b * l, -1), p["w_gate"], p["w_up"], p["w_down"]
+    ).reshape(b, l, d)
+    if "shared" in p:
+        with jax.named_scope("shared_expert"):
+            y = y + swiglu(p["shared"], x)
+    return y
 
 
 def moe_dense_oracle(p, x, cfg: MoEConfig):
-    """Reference implementation with NO capacity dropping: every token
-    is processed by its top-k experts densely — what :func:`moe_apply`
-    must equal whenever capacity is ample (tests)."""
-    probs = jax.nn.softmax(
-        jnp.einsum("bld,de->ble", x.astype(jnp.float32), p["router"]),
-        axis=-1,
-    )
-    gate, idx = jax.lax.top_k(probs, cfg.top_k)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
-
-    def ffn(xe, e):
-        g = jax.nn.silu(
-            xe.astype(jnp.float32) @ p["w_gate"][e].astype(jnp.float32)
-        )
-        u = xe.astype(jnp.float32) @ p["w_up"][e].astype(jnp.float32)
-        return (g * u) @ p["w_down"][e].astype(jnp.float32)
-
-    all_out = jnp.stack(
-        [ffn(x, e) for e in range(cfg.n_experts)], axis=2
-    )  # [B, L, E, D]
-    sel = jnp.take_along_axis(
-        all_out, idx[..., None], axis=2
-    )  # [B, L, K, D]
-    return jnp.sum(sel * gate[..., None], axis=2).astype(x.dtype)
+    """The same layer the plain way, for the CPU tests: every held
+    expert computes every token in float32, masked by the token's
+    weight for it. No sort, no grouped product."""
+    xf = x.astype(jnp.float32)
+    idx, gate = route(p, x, cfg)
+    y = jnp.zeros_like(xf)
+    for e in range(cfg.held):
+        w = jnp.sum(jnp.where(idx == cfg.first_held + e, gate, 0.0), -1)
+        g = jax.nn.silu(xf @ p["w_gate"][e].astype(jnp.float32))
+        u = xf @ p["w_up"][e].astype(jnp.float32)
+        y = y + w[..., None] * ((g * u) @ p["w_down"][e].astype(jnp.float32))
+    if "shared" in p:
+        s = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                   p["shared"])
+        y = y + swiglu(s, xf)
+    return y.astype(x.dtype)

@@ -23,6 +23,8 @@ driver-set workloads that need one. These blocks are written TPU-first:
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Callable, Optional
 
 import jax
@@ -69,11 +71,17 @@ def layer_norm(x, p, eps=1e-6):
     return (xf * p["scale"] + p["bias"]).astype(x.dtype)
 
 
-@jax.named_scope("norm")
-def rms_norm(x, p, eps=1e-6):
+def rms_normalize(x, scale, eps=1e-6):
+    """RMSNorm over the last axis under no scope of its own: a norm
+    inside a mixer counts as the mixer's."""
     xf = x.astype(jnp.float32)
     ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    return (xf * jax.lax.rsqrt(ms + eps) * p["scale"]).astype(x.dtype)
+    return (xf * jax.lax.rsqrt(ms + eps) * scale).astype(x.dtype)
+
+
+@jax.named_scope("norm")
+def rms_norm(x, p, eps=1e-6):
+    return rms_normalize(x, p["scale"], eps)
 
 
 # ---------------------------------------------------------------------------
@@ -234,6 +242,178 @@ def mha_apply(
 
 
 # ---------------------------------------------------------------------------
+# latent attention (MLA: DeepSeek-V2, arXiv:2405.04434 section 2.1)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Latent attention's sizes: keys and values come from a normalised
+    latent of ``kv_rank`` channels, one rotary key of ``rope_dim`` is
+    shared by all heads; queries and keys are ``nope_dim + rope_dim``
+    wide a head, values ``v_dim``."""
+
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    # RMSNorm of each head's query and key over their whole width, one
+    # scale vector for all heads, before the rotation
+    qk_norm: bool = False
+    rope_theta: float = 10000.0
+    # the published ``rope_scaling`` group of a ``deepseek_yarn`` model
+    # (``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    # ``beta_slow``, ``mscale``, ``mscale_all_dim``), or None for plain
+    # rotary frequencies
+    rope_scaling: Optional[tuple] = None
+    # queries a block of the core: a block's float32 scores against its
+    # causal prefix of keys are held at a time, never ``[L, L]`` whole
+    block: int = 512
+
+    def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):  # a JSON group, hashable
+            if self.rope_scaling.get("type") != "deepseek_yarn":
+                raise ValueError(f"unknown rope_scaling {self.rope_scaling}")
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def yarn(self) -> Optional[dict]:
+        return dict(self.rope_scaling) if self.rope_scaling else None
+
+    @property
+    def softmax_scale(self) -> float:
+        """``qk_dim ** -0.5``, under YaRN times ``m ** 2`` with ``m = 0.1
+        mscale_all_dim ln(factor) + 1``."""
+        m = 1.0
+        if self.yarn and self.yarn.get("mscale_all_dim"):
+            m = 0.1 * self.yarn["mscale_all_dim"] \
+                * math.log(self.yarn["factor"]) + 1.0
+        return self.qk_dim ** -0.5 * m * m
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float):
+    """``deepseek_yarn``'s ``dim / 2`` rotary frequencies: each a blend
+    of ``theta ** (-2i / dim)`` and the same over ``factor``, by a
+    linear ramp between the two correction dims (the channels that turn
+    ``beta_fast`` and ``beta_slow`` times over ``original_len``
+    positions): fast channels keep their frequency, slow ones are
+    interpolated."""
+    def correction_dim(turns):
+        return dim * math.log(original_len / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    base = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return base / factor * ramp + base * (1.0 - ramp)
+
+
+def mla_rope_angles(seq_len: int, cfg: MLAConfig):
+    """``(cos, sin)``, each ``[L, rope_dim / 2]``, of the shared rotary
+    key and the queries' rotary part."""
+    yarn = cfg.yarn
+    if yarn:
+        if yarn.get("mscale", 1) != yarn.get("mscale_all_dim", 1):
+            raise NotImplementedError("mscale != mscale_all_dim scales cos "
+                                      "and sin; no configuration needs it")
+        inv_freq = yarn_inv_freq(
+            cfg.rope_dim, cfg.rope_theta, yarn["factor"],
+            yarn["original_max_position_embeddings"], yarn["beta_fast"],
+            yarn["beta_slow"])
+    else:
+        inv_freq = cfg.rope_theta ** (
+            -jnp.arange(0, cfg.rope_dim, 2, dtype=jnp.float32) / cfg.rope_dim)
+    ang = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32), inv_freq)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def mla_init(key, d_model: int, n_heads: int, cfg: MLAConfig, out_std=None):
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    p = {
+        "wq": dense_init(kq, d_model, n_heads * cfg.qk_dim),
+        "wkv_a": dense_init(ka, d_model, cfg.kv_rank + cfg.rope_dim),
+        "kv_norm": rms_init(cfg.kv_rank),
+        "wkv_b": dense_init(kb, cfg.kv_rank,
+                            n_heads * (cfg.nope_dim + cfg.v_dim)),
+        "wo": dense_init(ko, n_heads * cfg.v_dim, d_model, stddev=out_std),
+    }
+    if cfg.qk_norm:
+        p["q_norm"] = rms_init(cfg.qk_dim)
+        p["k_norm"] = rms_init(cfg.qk_dim)
+    return p
+
+
+@jax.named_scope("mla_core")
+def causal_core(q, k, v, scale: float, block: int):
+    """Causal softmax attention ``[B, H, L, Dv]`` of ``q, k [B, H, L,
+    Dqk]`` and ``v [B, H, L, Dv]`` whose widths differ: a block of
+    ``block`` queries at a time against its causal prefix of keys, the
+    scores and the softmax in float32. A block is under
+    ``jax.checkpoint``: the backward recomputes its scores, so no
+    ``[L, L]`` tensor is held, forward or backward. ``L <= block`` is
+    the plain computation."""
+    l = q.shape[2]
+
+    def one(qb, kb, vb, start):
+        s = jnp.einsum("bhqd,bhkd->bhqk", qb, kb,
+                       preferred_element_type=jnp.float32) * scale
+        seen = (start + jnp.arange(qb.shape[2]))[:, None] \
+            >= jnp.arange(kb.shape[2])[None, :]
+        p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(vb.dtype), vb)
+
+    if l <= block:
+        return one(q, k, v, 0)
+    one = jax.checkpoint(one, static_argnums=(3,))
+    return jnp.concatenate(
+        [one(q[:, :, s:s + block], k[:, :, :s + block], v[:, :, :s + block],
+             s) for s in range(0, l, block)], axis=2)
+
+
+@jax.named_scope("latent_attention")
+def mla_apply(p, x, n_heads: int, cfg: MLAConfig, rope):
+    """Latent attention over ``x [B, L, D] -> [B, L, D]``, causal.
+    ``rope`` is :func:`mla_rope_angles`'s pair. The rotation pairs
+    channel ``i`` of the rotary part with ``i + rope_dim / 2``
+    (:func:`apply_rope`)."""
+    b, l, _ = x.shape
+    cos, sin = rope
+
+    def heads(y, width):
+        return y.reshape(b, l, n_heads, width).transpose(0, 2, 1, 3)
+
+    q = heads(x @ p["wq"].astype(x.dtype), cfg.qk_dim)
+    c = x @ p["wkv_a"].astype(x.dtype)
+    latent = rms_normalize(c[..., :cfg.kv_rank], p["kv_norm"]["scale"])
+    kv = heads(latent @ p["wkv_b"].astype(x.dtype), cfg.nope_dim + cfg.v_dim)
+    k_rope = jnp.broadcast_to(c[:, None, :, cfg.kv_rank:],
+                              (b, n_heads, l, cfg.rope_dim))
+    k = jnp.concatenate([kv[..., :cfg.nope_dim], k_rope], axis=-1)
+    v = kv[..., cfg.nope_dim:]
+    if cfg.qk_norm:
+        q = rms_normalize(q, p["q_norm"]["scale"])
+        k = rms_normalize(k, p["k_norm"]["scale"])
+
+    def rotated(y):
+        return jnp.concatenate(
+            [y[..., :cfg.nope_dim],
+             apply_rope(y[..., cfg.nope_dim:], cos, sin)], axis=-1)
+
+    out = causal_core(rotated(q), rotated(k), v, cfg.softmax_scale, cfg.block)
+    out = out.transpose(0, 2, 1, 3).reshape(b, l, n_heads * cfg.v_dim)
+    return out @ p["wo"].astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
 # MLPs
 
 
@@ -263,11 +443,13 @@ def swiglu_init(key, d_model, d_ff):
     }
 
 
-@jax.named_scope("mlp")
-def swiglu_apply(p, x):
+def swiglu(p, x):
     g = jax.nn.silu(x @ p["w_gate"].astype(x.dtype))
     u = x @ p["w_up"].astype(x.dtype)
     return (g * u) @ p["w_down"].astype(x.dtype)
+
+
+swiglu_apply = jax.named_scope("mlp")(swiglu)
 
 
 # ---------------------------------------------------------------------------

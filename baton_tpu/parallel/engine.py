@@ -681,7 +681,8 @@ class FedSim:
                 round_span.set_metadata(
                     clients=c, waves=n_waves, wave_size=int(wave_size),
                     frozen_bytes=_tree_bytes(frozen),
-                    trainable_bytes=_tree_bytes(params))
+                    trainable_bytes=_tree_bytes(params),
+                    **dict(self.model.span_attrs))
 
             psum_acc = None
             lsum_acc = None

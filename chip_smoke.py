@@ -9,7 +9,10 @@ fold) on ResNet-18/CIFAR-10 bf16, 32 clients x 48 samples, batch 32 —
 then the Pallas flash kernel (alone against the dense reference, and
 reached through a decoder's ``default_attention`` under the client
 ``vmap``), two LoRA rounds of the tiny hybrid decoder (gated delta-rule
-layers and full attention over a frozen bfloat16 base), one in-process HTTP federation whose workers train on the
+layers and full attention over a frozen bfloat16 base), two more of a
+tiny decoder of latent attention and expert layers with the routing
+of ``sarvam_105b`` at its published widths (``moe_mla_lora``), one
+in-process HTTP federation whose workers train on the
 device, the client mesh when the host has more than one device, and
 the compile cache. Weights are random from a seed, depth is cut, data
 is generated (the chip machine has no network).
@@ -341,6 +344,184 @@ def phase_hybrid_lora(env: Env) -> None:
 
 
 # ----------------------------------------------------------------------
+def phase_moe_mla_lora(env: Env) -> None:
+    """Latent attention and the expert layer. First the bfloat16 path at
+    a tiny size (the benchmark's ``tiny`` rehearsal computes in
+    float32): a leading dense layer and two expert layers holding 3 of 8
+    experts over a frozen bfloat16 base, two rounds through ``FedSim``;
+    on a TPU the grouped products are the Pallas kernel's, and the wave
+    program may hold no float32 array of an expert stack's shape. Then
+    the routing of ``sarvam_105b`` at the published widths (in rehearsal
+    at its ``tiny`` sizes) on the inputs of ``sarvam_105b_c4_l2048``,
+    one forward of a local step's four sequences: a layer, the held
+    experts' largest and mean rows and the share of assignments that
+    fell on experts held elsewhere; and on the first sequence the share
+    of assignments on which the program's router (bfloat16 activations,
+    grouped products) and the same layers in float32 at ``highest``
+    (the oracle's loop over experts) disagree, which is what stands
+    between the probe's two sides beside rounding; and, to tell the two
+    apart, the distance between the two streams after the stage, a
+    token, over the tokens whose every choice agrees and over the
+    others."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models import moe
+    from baton_tpu.models.llama import LlamaConfig, decoder_lora_model
+    from baton_tpu.models.lora import lora_trainable
+    from baton_tpu.models.transformer import (
+        MLAConfig,
+        mla_apply,
+        mla_rope_angles,
+        rms_norm,
+        swiglu_apply,
+    )
+    from baton_tpu.parallel.engine import FedSim
+    from fedbench import data as cohort, manifest
+
+    yarn = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+            "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+            "type": "deepseek_yarn"}
+    cfg = LlamaConfig(
+        vocab_size=96, max_len=128, d_model=128, n_layers=3, n_heads=4,
+        n_kv_heads=4, d_ff=256, first_dense_layers=1, embed_std=1.0,
+        mla=MLAConfig(kv_rank=32, nope_dim=16, rope_dim=8, v_dim=16,
+                      qk_norm=True, rope_scaling=yarn, block=64),
+        moe=moe.MoEConfig(n_experts=8, top_k=2, d_ff=256, experts_held=3,
+                          first_held=2, routed_scale=2.5, n_shared=1,
+                          router_bias_range=0.1))
+    model = decoder_lora_model(cfg, rank=4, b_std=0.02)
+    params = jax.jit(model.init)(jax.random.key(0))
+    # 128 tokens: two blocks of the core; 4 x 128 x 2 = 1,024 sorted rows
+    first = jax.random.randint(jax.random.key(1), (4, 2, 1), 0, 96)
+    tokens = (first + 7 * jnp.arange(129)) % 96
+    data = {"x": tokens[..., :-1], "y": tokens[..., 1:]}
+    n_samples = np.asarray([2, 2, 2, 2], np.int32)
+    sim = FedSim(model, batch_size=1, learning_rate=0.05,
+                 trainable=lora_trainable)
+    losses, p = [], params
+    for i in range(2):
+        res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
+                            n_epochs=1, collect_client_losses=False)
+        losses.append(float(res.loss_history[-1]))
+        p = res.params
+    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
+    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    base = list(zip(jax.tree_util.tree_leaves(params["base"]),
+                    jax.tree_util.tree_leaves(p["base"])))
+    _check(all(a is b for a, b in base),
+           "a round copied or cast a leaf of the frozen base")
+    text = sim.lower_wave(params, data, n_samples, jax.random.key(2), 1,
+                          None).compile().as_text()
+    kernels = text.count("tpu_custom_call")
+    if not env.rehearsal:
+        _check(kernels > 0, "no Pallas grouped product in the wave program")
+        # three held experts of [128, 256]: no activation has that shape
+        made = set(re.findall(r"= (\w+)\[([\d,]+)\]", text))
+        stack = r"3,(128,256|256,128)"
+        _check(not [m for m in made if re.fullmatch(rf"\d+,{stack}", m[1])
+                    or (m[0] != "bf16" and re.fullmatch(stack, m[1]))],
+            "the wave program holds an expert stack in float32 or with a "
+            "client axis")
+
+    # ---- the routing of sarvam_105b on its cell's inputs
+    root = manifest.ROOT
+    bench = manifest.load_manifest(root)
+    config = manifest.load_config(root, bench, "sarvam_105b")
+    job = manifest.load_workload(root, "sarvam_105b_c4_l2048")
+    tiny = env.rehearsal
+    if tiny:
+        job.update(job["tiny"])
+    sized = manifest.sized(config, tiny)
+    held, total, top_k = (sized["num_experts"], sized["num_experts_published"],
+                          sized["num_experts_per_tok"])
+    first_held = sized["first_expert_held"]
+    seed = 11
+    big = manifest.build_model(config, tiny)
+    big_params = jax.jit(big.init)(jax.random.key(seed))
+    step = cohort.make_cohort(
+        root, manifest.input_spec(config, tiny), np.asarray([1] * 4, np.int32),
+        1, job["seq_len"], cohort.data_key(seed + 1))
+    batch = {k: v[:, 0] for k, v in step.items()}  # [4, L]
+    decoder = manifest.resolve(config["builder"]["kwargs"]["config"], sized)
+    rope = mla_rope_angles(job["seq_len"], decoder.mla)
+
+    def choices(ids, dtype, plain: bool):
+        """The chosen experts ``[n, L, top_k]`` of every expert layer,
+        and the stream ``[n, L, D]`` after the last, in
+        one forward of the frozen base over ``ids [n, L]`` (the
+        adapters, drawn at deviation 0.02, left out), activations in
+        ``dtype``: the blocks' own parts, wired as ``_block_apply``
+        wires them, the routed experts by the program's grouped
+        products or, ``plain``, by the oracle's loop."""
+        base = big_params["base"]
+        experts = moe.moe_dense_oracle if plain else moe.moe_apply
+
+        @jax.jit
+        def block(blk, x):
+            x = x + mla_apply(blk["mla"], rms_norm(x, blk["norm_attn"]),
+                              decoder.n_heads, decoder.mla, rope)
+            h = rms_norm(x, blk["norm_mlp"])
+            if "router" not in blk["mlp"]:
+                return x + swiglu_apply(blk["mlp"], h), None
+            return (x + experts(blk["mlp"], h, decoder.moe),
+                    moe.route(blk["mlp"], h, decoder.moe)[0])
+
+        x, found = base["tok_emb"][ids].astype(dtype), []
+        for blk in base["blocks"]:
+            x, idx = block(blk, x)
+            if idx is not None:
+                found.append(np.asarray(idx))
+        return found, np.asarray(x, np.float32)
+
+    program, stream = choices(batch["x"], jnp.float32 if tiny else jnp.bfloat16,
+                      False)
+    _check(len(program) == sized["num_hidden_layers"]
+           - sized["first_k_dense_replace"], f"{len(program)} expert layers")
+    with jax.default_matmul_precision("highest"):
+        in_float32, plain_stream = choices(batch["x"][:1], jnp.float32, True)
+    agree = np.all([np.sort(a[0], -1) == np.sort(b[0], -1)
+                    for a, b in zip(program, in_float32)], axis=(0, 2))
+    apart = np.linalg.norm(stream[0] - plain_stream[0], axis=-1) \
+        / np.linalg.norm(plain_stream[0], axis=-1)
+
+    def _mean(a):
+        return float(a.mean()) if a.size else float("nan")
+
+    lines = []
+    for layer, (idx, ref) in enumerate(zip(program, in_float32)):
+        rows = np.bincount(idx.ravel(), minlength=total)[
+            first_held:first_held + held]
+        absent = 1.0 - rows.sum() / idx.size
+        got = np.sort(idx[0], -1)
+        want = np.sort(ref[0], -1)
+        differ = np.mean([len(set(a) - set(b)) for a, b in zip(got, want)]) \
+            / top_k
+        lines.append(
+            f"expert layer {layer + 1}: rows a held expert max "
+            f"{rows.max()} mean {rows.mean():.1f} min {rows.min()}, "
+            f"{100 * absent:.2f} % of assignments on experts held elsewhere "
+            f"(expected {100 * (1 - held / total):.2f}), the router in "
+            f"bfloat16 and in float32 differ on {100 * differ:.3f} %")
+        _check(rows.sum() > 0, "no assignment fell on a held expert")
+    env.say("moe_mla_lora",
+            f"{model.name}: latent attention, a dense layer and 2 expert "
+            f"layers holding 3 of 8, bf16 over a frozen bf16 base, 4 clients "
+            f"x 128 tokens, 2 rounds, loss {losses[0]:.4f} -> "
+            f"{losses[1]:.4f}; {len(base)} base leaves handed back as the "
+            f"arrays they were; {kernels} Pallas calls in the wave program; "
+            f"sarvam_105b at {'tiny' if tiny else 'the published'} sizes, "
+            f"{held} of {total} experts held, 4 sequences of "
+            f"{job['seq_len']} tokens, seed {seed}: " + "; ".join(lines)
+            + f"; the two streams after the stage lie apart by "
+            f"{_mean(apart[agree]):.4f} of the float32 one's norm, a token, "
+            f"over the {agree.sum()} tokens whose every choice agrees, by "
+            f"{_mean(apart[~agree]):.4f} over the other {(~agree).sum()}")
+
+
 def _flash_alone(env: Env) -> str:
     import jax
     import jax.numpy as jnp
@@ -713,7 +894,9 @@ def phase_cache(env: Env) -> None:
 # ----------------------------------------------------------------------
 # in running order
 PHASES = {"device": phase_device, "fedsim_resnet18": phase_fedsim_resnet18,
-          "hybrid_lora": phase_hybrid_lora, "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
+          "hybrid_lora": phase_hybrid_lora,
+          "moe_mla_lora": phase_moe_mla_lora,
+          "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
           "mesh": phase_mesh, "cache": phase_cache}
 
 

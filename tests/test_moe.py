@@ -1,74 +1,251 @@
-"""Mixture-of-Experts layer (models/moe.py) + expert parallelism.
+"""The expert layer (models/moe.py): a router over all the experts, the
+held experts' part by grouped products over sorted rows, no token
+dropped, beside a shared expert.
 
-Oracle: with ample capacity, the dispatch-tensor MoE must EXACTLY equal
-the dense per-token top-k computation (outputs and gradients). Capacity
-dropping, the Switch aux loss, the Llama integration (training + remat),
-and GSPMD expert-parallel placement are covered separately.
+Oracle: :func:`moe_dense_oracle`, every held expert computing every
+token, masked by the token's weight for it. The layer has to equal it
+under any routing, however uneven, forward and in every gradient; the
+shares of all ranks have to add up to the uncut layer; under a ``vmap``
+over clients the frozen stacks gain no client axis and no gradient.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from baton_tpu.models.llama import LlamaConfig, llama_lm_model
+from baton_tpu.models.llama import (
+    LlamaConfig,
+    decoder_lora_model,
+    llama_lm_model,
+    projection_lora_target,
+)
 from baton_tpu.models.moe import (
     MoEConfig,
+    _gmm,
+    _rows_of_the_groups,
+    grouped_matmul,
     moe_apply,
-    moe_capacity,
     moe_dense_oracle,
     moe_init,
+    route,
 )
 
-
-@pytest.fixture
-def moe_params(nprng):
-    cfg = MoEConfig(n_experts=4, top_k=2, capacity_factor=8.0)
-    return moe_init(jax.random.key(0), 16, 32, cfg), cfg
-
-
-def test_moe_matches_dense_oracle(moe_params, nprng):
-    p, cfg = moe_params
-    x = jnp.asarray(nprng.normal(size=(2, 12, 16)), jnp.float32)
-    y, aux = moe_apply(p, x, cfg)
-    np.testing.assert_allclose(
-        np.asarray(y), np.asarray(moe_dense_oracle(p, x, cfg)),
-        rtol=1e-5, atol=1e-5,
-    )
-    assert 1.0 <= float(aux) <= cfg.n_experts
+D, F = 16, 32
+WHOLE = MoEConfig(n_experts=8, top_k=2, d_ff=F, routed_scale=2.5, n_shared=1,
+                  router_bias_range=0.1)
+SHARE = MoEConfig(n_experts=8, top_k=2, d_ff=F, experts_held=4, first_held=2,
+                  routed_scale=2.5, n_shared=1, router_bias_range=0.1)
 
 
-def test_moe_grads_match_dense_oracle(moe_params, nprng):
-    p, cfg = moe_params
-    x = jnp.asarray(nprng.normal(size=(2, 8, 16)), jnp.float32)
-    g = jax.grad(lambda p: jnp.sum(moe_apply(p, x, cfg)[0] ** 2))(p)
-    g_o = jax.grad(lambda p: jnp.sum(moe_dense_oracle(p, x, cfg) ** 2))(p)
-    for a, b in zip(jax.tree_util.tree_leaves(g),
-                    jax.tree_util.tree_leaves(g_o)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-5)
+def _params(cfg, seed=0):
+    return moe_init(jax.random.key(seed), D, 4 * F, cfg)
 
 
-def test_moe_capacity_dropping_zeroes_overflow(nprng):
-    """Deterministic overflow: route every token to expert 0 with
-    capacity 1 — exactly the first token is processed, the rest get an
-    exact zero (the residual stream carries them unchanged)."""
-    cfg = MoEConfig(n_experts=2, top_k=1, capacity_factor=0.1)
-    assert moe_capacity(cfg, 8) == 1
-    p = moe_init(jax.random.key(0), 16, 32, cfg)
-    # zero router => tied logits => lax.top_k deterministically picks
-    # expert 0 for every token
-    p = dict(p, router=jnp.zeros_like(p["router"]))
-    x = jnp.asarray(nprng.normal(size=(1, 8, 16)), jnp.float32)
-    y, _ = moe_apply(p, x, cfg)
-    assert float(jnp.sum(jnp.abs(y[0, 0]))) > 0.0
-    np.testing.assert_array_equal(np.asarray(y[0, 1:]), 0.0)
+def _uneven(p, cfg, most: int, none: int):
+    """The router turned so that expert ``most`` takes nearly every
+    token's first choice and expert ``none`` is never chosen."""
+    bias = jnp.zeros(cfg.n_experts).at[most].set(5.0).at[none].set(-5.0)
+    return dict(p, router_bias=bias)
 
 
-def test_moe_capacity_formula():
-    assert moe_capacity(MoEConfig(8, 2, 1.0), 64) == 16
-    assert moe_capacity(MoEConfig(8, 2, 1.25), 64) == 20
-    assert moe_capacity(MoEConfig(64, 1, 1.0), 8) == 1  # floor at 1
+def _close(got, want, rtol=2e-5):
+    scale = max(float(jnp.max(jnp.abs(want))), 1e-6)
+    assert float(jnp.max(jnp.abs(got - want))) <= rtol * scale
+
+
+@pytest.mark.parametrize("cfg", [WHOLE, SHARE], ids=["whole", "share"])
+def test_the_layer_is_the_oracle_under_uneven_routing(cfg, nprng):
+    """One held expert is given most rows and one none: no capacity, so
+    no token is dropped; forward and the gradient of the input."""
+    p = _uneven(_params(cfg), cfg, most=cfg.first_held + 1,
+                none=cfg.first_held + 2)
+    x = jnp.asarray(nprng.normal(size=(2, 24, D)), jnp.float32)
+    idx, _ = route(p, x, cfg)
+    counts = np.bincount(np.asarray(idx).ravel(), minlength=cfg.n_experts)
+    assert counts[cfg.first_held + 1] >= 40 and counts[cfg.first_held + 2] == 0
+    _close(moe_apply(p, x, cfg), moe_dense_oracle(p, x, cfg))
+    weight = jnp.asarray(nprng.normal(size=x.shape), jnp.float32)
+
+    def grad(fn):
+        return jax.grad(lambda x: jnp.sum(fn(p, x, cfg) * weight))(x)
+
+    _close(grad(moe_apply), grad(moe_dense_oracle))
+
+
+@pytest.mark.parametrize("cfg", [WHOLE, SHARE], ids=["whole", "share"])
+def test_every_gradient_leaf_is_the_oracles(cfg, nprng):
+    """The stacks' and the router's gradients too, where something asks
+    for them (experts that train)."""
+    p = _params(cfg)
+    x = jnp.asarray(nprng.normal(size=(2, 12, D)), jnp.float32)
+
+    def grads(fn):
+        return jax.grad(lambda p: jnp.sum(fn(p, x, cfg) ** 2))(p)
+
+    got, want = grads(moe_apply), grads(moe_dense_oracle)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        _close(g, w, rtol=1e-4)
+    assert float(jnp.max(jnp.abs(got["w_down"]))) > 0
+
+
+def test_the_shares_add_up_to_the_uncut_layer(nprng):
+    """The guide's test of the cut: the routed parts that the four
+    ranks of two experts each compute, plus the shared expert once,
+    are what the uncut layer gives."""
+    p = _params(WHOLE)
+    x = jnp.asarray(nprng.normal(size=(2, 16, D)), jnp.float32)
+    routed = jnp.zeros_like(x)
+    for first in range(0, 8, 2):
+        cut = MoEConfig(n_experts=8, top_k=2, d_ff=F, experts_held=2,
+                        first_held=first, routed_scale=2.5, n_shared=0,
+                        router_bias_range=0.1)
+        held = {k: (v[first:first + 2] if k.startswith("w_") else v)
+                for k, v in p.items() if k != "shared"}
+        # a rank's own init draws the very experts the uncut layer has
+        drawn = moe_init(jax.random.key(0), D, 4 * F, cut)
+        assert jnp.array_equal(drawn["w_up"], held["w_up"])
+        routed = routed + moe_apply(held, x, cut)
+    shared_only = dict(p, **{k: jnp.zeros_like(p[k])
+                             for k in ("w_gate", "w_up", "w_down")})
+    _close(routed + moe_apply(shared_only, x, WHOLE), moe_apply(p, x, WHOLE))
+    _close(moe_apply(p, x, WHOLE), moe_dense_oracle(p, x, WHOLE))
+
+
+def test_the_bias_chooses_and_does_not_weigh(nprng):
+    p = _params(WHOLE)
+    x = jnp.asarray(nprng.normal(size=(1, 64, D)), jnp.float32)
+    idx, gate = route(p, x, WHOLE)
+    plain_idx, _ = route(dict(p, router_bias=jnp.zeros(8)), x, WHOLE)
+    assert not jnp.array_equal(jnp.sort(idx, -1), jnp.sort(plain_idx, -1))
+    s = jax.nn.sigmoid(x @ p["router"])
+    chosen = jnp.take_along_axis(s, idx, -1)
+    _close(gate, 2.5 * chosen / chosen.sum(-1, keepdims=True))
+    _close(gate.sum(-1), jnp.full(gate.shape[:-1], 2.5))
+    # weighing by the biased score is another layer
+    biased = jnp.take_along_axis(s + p["router_bias"], idx, -1)
+    wrong = 2.5 * biased / biased.sum(-1, keepdims=True)
+    assert float(jnp.max(jnp.abs(wrong - gate))) > 1e-3
+
+
+@pytest.mark.parametrize("cfg", [WHOLE, SHARE], ids=["whole", "share"])
+def test_under_a_client_vmap_in_a_gradient_in_a_checkpoint(cfg, nprng):
+    """``jit(vmap(value_and_grad(checkpoint(loss))))`` over clients, the
+    stacks shared, equals a Python loop over the clients."""
+    p = _params(cfg)
+    xs = jnp.asarray(nprng.normal(size=(3, 2, 12, D)), jnp.float32)
+
+    def loss(x):
+        return jnp.sum(jax.checkpoint(lambda x: moe_apply(p, x, cfg))(x) ** 2)
+
+    def plain(x):
+        return jnp.sum(moe_dense_oracle(p, x, cfg) ** 2)
+
+    got = jax.jit(jax.vmap(jax.value_and_grad(loss)))(xs)
+    for c in range(3):
+        want = jax.value_and_grad(plain)(xs[c])
+        assert float(got[0][c]) == pytest.approx(float(want[0]), rel=1e-5)
+        _close(got[1][c], want[1], rtol=1e-4)
+
+
+def test_stacks_that_carry_the_client_axis_take_the_map(nprng):
+    """Experts that train are a client's own: their gradients under the
+    ``vmap`` are each client's, as the loop gives them; and a client's
+    gradient of stacks that are shared is its own too."""
+    p = _params(SHARE)
+    xs = jnp.asarray(nprng.normal(size=(3, 1, 12, D)), jnp.float32)
+    ps = jax.tree_util.tree_map(
+        lambda a: jnp.stack([a, 1.1 * a, 0.9 * a]), p)
+
+    def grad(fn):
+        return jax.grad(lambda p, x: jnp.sum(fn(p, x, SHARE) ** 2))
+
+    got = jax.jit(jax.vmap(grad(moe_apply)))(ps, xs)
+    shared = jax.jit(jax.vmap(grad(moe_apply), in_axes=(None, 0)))(p, xs)
+    for c in range(3):
+        own = jax.tree_util.tree_map(lambda a: a[c], ps)
+        want = grad(moe_dense_oracle)(own, xs[c])
+        for name in ("w_gate", "w_up", "w_down", "router"):
+            _close(got[name][c], want[name], rtol=1e-4)
+        _close(shared["w_down"][c],
+               grad(moe_dense_oracle)(p, xs[c])["w_down"], rtol=1e-4)
+
+
+def test_rows_past_the_groups_come_out_zero(nprng):
+    x = jnp.asarray(nprng.normal(size=(10, 4)), jnp.float32)
+    w = jnp.asarray(nprng.normal(size=(3, 4, 5)), jnp.float32)
+    sizes = jnp.asarray([2, 0, 4], jnp.int32)
+    got = grouped_matmul(x, w, sizes)
+    want = np.zeros((10, 5), np.float32)
+    want[:2] = np.asarray(x[:2] @ w[0])
+    want[2:6] = np.asarray(x[2:6] @ w[2])
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+    back = grouped_matmul(got, w, sizes, transpose_rhs=True)
+    assert back.shape == x.shape and not np.any(np.asarray(back[6:]))
+
+
+def _recorder(opened: list):
+    """Stands in for ``engine.annotate``: the spans opened, with their
+    attributes."""
+    import contextlib
+
+    def annotate(name, **attrs):
+        class Span(contextlib.AbstractContextManager):
+            def __enter__(self):
+                opened.append((name, attrs))
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+            def set_metadata(self, **more):
+                attrs.update(more)
+
+        return Span()
+
+    return annotate
+
+
+def _experts_after_a_dense_layer(**kw):
+    return LlamaConfig.tiny(
+        n_layers=3, first_dense_layers=1, embed_std=1.0,
+        moe=MoEConfig(n_experts=8, top_k=2, d_ff=32, experts_held=4,
+                      routed_scale=2.5, n_shared=1, router_bias_range=0.1),
+        **kw)
+
+
+def test_a_leading_dense_layer_then_expert_layers():
+    """Block 0 holds a SwiGLU of the dense width, the others a router,
+    the held stacks and a shared expert of the expert width; a base in
+    bfloat16 keeps its router, the bias and every vector float32; the
+    adapters go on 2-D projections alone."""
+    cfg = _experts_after_a_dense_layer()
+    model = decoder_lora_model(cfg, rank=2)
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    blocks = shapes["base"]["blocks"]
+    assert "router" not in blocks[0]["mlp"]
+    assert blocks[0]["mlp"]["w_up"].shape == (64, 128)
+    for b in blocks[1:]:
+        assert b["mlp"]["router"].shape == (64, 8)
+        assert b["mlp"]["w_up"].shape == (4, 64, 32)
+        assert b["mlp"]["shared"]["w_up"].shape == (64, 32)
+        assert b["mlp"]["router"].dtype == b["mlp"]["router_bias"].dtype \
+            == jnp.float32
+        assert b["mlp"]["w_down"].dtype == jnp.bfloat16
+    assert {a.dtype for a in jax.tree_util.tree_leaves(shapes["base"])
+            if a.ndim == 1} == {jnp.dtype(jnp.float32)}
+    assert "blocks/1/mlp/shared/w_up" in shapes["lora"]
+    assert not [k for k in shapes["lora"]
+                if re.search(r"mlp/(w_|router)", k) and "blocks/0" not in k]
+    assert projection_lora_target("blocks/1/mlp/shared/w_up",
+                                  blocks[1]["mlp"]["shared"]["w_up"])
+    assert not projection_lora_target("blocks/1/mlp/w_up",
+                                      blocks[1]["mlp"]["w_up"])
 
 
 def test_llama_moe_trains(nprng):
@@ -87,7 +264,7 @@ def test_llama_moe_trains(nprng):
 
 
 def test_llama_moe_remat_grads(nprng):
-    cfg = LlamaConfig.tiny(n_layers=1, moe=MoEConfig(n_experts=2, top_k=1))
+    cfg = _experts_after_a_dense_layer()
     plain = llama_lm_model(cfg)
     remat = llama_lm_model(cfg, remat=True, name="llama_moe_remat")
     params = plain.init(jax.random.key(0))
@@ -107,9 +284,11 @@ def test_llama_moe_remat_grads(nprng):
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_expert_parallel_sharding_matches_replicated(nprng):
-    """GSPMD expert parallelism: experts sharded over a 4-way 'model'
-    axis produce bit-compatible outputs with the replicated run."""
+def test_experts_sharded_over_a_mesh_axis_equal_the_replicated_layer(nprng):
+    """GSPMD expert parallelism, as ``__graft_entry__`` runs tp + ep:
+    the rules put the stacks' expert axis on the ``model`` axis, and
+    the layer over stacks so sharded is the replicated one, forward and
+    in every gradient."""
     from baton_tpu.parallel.mesh import make_mesh
     from baton_tpu.parallel.tensor_parallel import (
         shard_params_tp,
@@ -117,19 +296,98 @@ def test_expert_parallel_sharding_matches_replicated(nprng):
     )
     from jax.sharding import PartitionSpec as P
 
-    cfg = MoEConfig(n_experts=4, top_k=2, capacity_factor=4.0)
+    cfg = MoEConfig(n_experts=4, top_k=2, routed_scale=2.5, n_shared=1,
+                    router_bias_range=0.1)
     p = moe_init(jax.random.key(0), 16, 32, cfg)
-    # the sharding rules route stacked expert weights onto the axis
     assert transformer_tp_spec("blocks/0/mlp/w_gate", p["w_gate"]) == P(
-        "model", None, None
-    )
+        "model", None, None)
     assert transformer_tp_spec("blocks/0/mlp/router", p["router"]) == P()
 
-    mesh = make_mesh(4, axis_names=("model",))
+    sharded = shard_params_tp(p, make_mesh(4, axis_names=("model",)),
+                              axis="model")
+    assert sharded["w_down"].sharding.spec == P("model", None, None)
     x = jnp.asarray(nprng.normal(size=(2, 12, 16)), jnp.float32)
-    y_rep, aux_rep = jax.jit(lambda p, x: moe_apply(p, x, cfg))(p, x)
-    p_sharded = shard_params_tp(p, mesh, axis="model")
-    y_ep, aux_ep = jax.jit(lambda p, x: moe_apply(p, x, cfg))(p_sharded, x)
-    np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_rep),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(float(aux_ep), float(aux_rep), rtol=1e-6)
+    apply = jax.jit(lambda p, x: moe_apply(p, x, cfg))
+    np.testing.assert_allclose(np.asarray(apply(sharded, x)),
+                               np.asarray(apply(p, x)), rtol=1e-5, atol=1e-6)
+    grad = jax.jit(jax.grad(lambda p, x: jnp.sum(moe_apply(p, x, cfg) ** 2)))
+    for got, want in zip(jax.tree_util.tree_leaves(grad(sharded, x)),
+                         jax.tree_util.tree_leaves(grad(p, x))):
+        _close(got, want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("transpose_rhs", [False, True],
+                         ids=["stack", "transposed"])
+def test_the_chips_grouped_product_is_ragged_dot(transpose_rhs, nprng):
+    """The Pallas kernel a TPU runs, its body interpreted here: the
+    groups' rows are ``ragged_dot``'s (one group empty, one straddling
+    a tile of 256 rows), the rows past them are not written and come
+    out zero through the same mask; sizes its tiles do not divide are
+    refused."""
+    x = jnp.asarray(nprng.normal(size=(512, 128)), jnp.float32)
+    w = jnp.asarray(nprng.normal(size=(3, 128, 256)), jnp.float32)
+    sizes = jnp.asarray([200, 0, 120], jnp.int32)
+    want = _rows_of_the_groups(jax.lax.ragged_dot(x, w, sizes), sizes)
+    if transpose_rhs:
+        w = jnp.swapaxes(w, 1, 2)
+    got = _rows_of_the_groups(_gmm(x, w, sizes, transpose_rhs, interpret=True),
+                              sizes)
+    _close(got, want, rtol=1e-5)
+    assert not np.any(np.asarray(got[320:]))
+    with pytest.raises(ValueError, match="multiple of 256"):
+        _gmm(x[:500], w, sizes, transpose_rhs, interpret=True)
+
+
+def test_the_wave_program_holds_the_stacks_once_and_takes_no_gradient(
+        monkeypatch):
+    """Over a frozen bfloat16 base, in the compiled wave program of
+    ``FedSim``: no array of an expert stack's shape with a client axis
+    before it and no gradient of that shape (nothing of a stack's shape
+    is computed at all)."""
+    from baton_tpu.models.lora import lora_trainable
+    from baton_tpu.parallel import engine
+    from baton_tpu.parallel.engine import FedSim
+
+    cfg = _experts_after_a_dense_layer(d_model=48, d_ff=96)
+    model = decoder_lora_model(cfg, rank=2, b_std=0.02)
+    params = model.init(jax.random.key(0))
+    clients = 3
+    x = jax.random.randint(jax.random.key(1), (clients, 2, 13), 0, 256)
+    data = {"x": x[..., :-1], "y": x[..., 1:]}
+    sim = FedSim(model, batch_size=1, learning_rate=0.05,
+                 trainable=lora_trainable)
+    text = sim.lower_wave(params, data, np.asarray([2, 2, 1], np.int32),
+                          jax.random.key(2), 1, None).compile().as_text()
+    made = re.findall(r"= (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(", text)
+    stacks = {"4,48,32", "4,32,48"}
+    assert len(made) > 1000  # the pattern finds the program's instructions
+    assert not [m for m in made
+                if any(m[1].endswith("," + k) for k in stacks)]
+    # what is of a stack's shape is a parameter or a view of one: no
+    # dot, no fusion, no reduce, no loop writes such an array, so no
+    # gradient of a stack is formed. (The CPU's compiler has no
+    # bfloat16 product and converts each operand where it is used, so
+    # here a float32 array of that shape is a ``convert`` of a
+    # parameter; on the chip there is none: chip_smoke.py's
+    # ``moe_mla_lora`` phase holds the TPU's program to that.)
+    written = {(dtype, op) for dtype, shape, op in made if shape in stacks}
+    assert {d for d, _ in written} <= {"bf16", "f32"}
+    assert {op for d, op in written if d == "bf16"} <= {
+        "parameter", "copy", "bitcast", "get-tuple-element", "transpose"}
+    assert {op for d, op in written if d == "f32"} <= {"convert"}
+    spans = []
+    monkeypatch.setattr(engine, "annotate", _recorder(spans))
+    res = sim.run_round(params, data, np.asarray([2, 2, 1], np.int32),
+                        jax.random.key(2), n_epochs=1,
+                        collect_client_losses=False)
+    assert np.isfinite(float(res.loss_history[-1]))
+    # the round's span says what the layer holds, beside the bytes held
+    # once: the bfloat16 base and nothing more
+    name, attrs = spans[0]
+    assert name == "baton.round"
+    assert (attrs["experts_held"], attrs["experts_total"]) == (4, 8)
+    assert attrs["frozen_bytes"] == sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(params["base"]))
+    assert all(a is b for a, b in zip(
+        jax.tree_util.tree_leaves(params["base"]),
+        jax.tree_util.tree_leaves(res.params["base"])))
