@@ -265,8 +265,10 @@ class MLAConfig:
     # ``beta_slow``, ``mscale``, ``mscale_all_dim``), or None for plain
     # rotary frequencies
     rope_scaling: Optional[tuple] = None
-    # queries a block of the core: a block's float32 scores against its
-    # causal prefix of keys are held at a time, never ``[L, L]`` whole
+    # queries a block of the core where it is plain JAX (off a TPU, or a
+    # length the Pallas kernel's blocks do not divide: ``causal_core``):
+    # a block's float32 scores against its causal prefix of keys are
+    # held at a time, never ``[L, L]`` whole
     block: int = 512
 
     def __post_init__(self):
@@ -352,12 +354,22 @@ def mla_init(key, d_model: int, n_heads: int, cfg: MLAConfig, out_std=None):
     return p
 
 
-@jax.named_scope("mla_core")
-def causal_core(q, k, v, scale: float, block: int):
-    """Causal softmax attention ``[B, H, L, Dv]`` of ``q, k [B, H, L,
-    Dqk]`` and ``v [B, H, L, Dv]`` whose widths differ: a block of
-    ``block`` queries at a time against its causal prefix of keys, the
-    scores and the softmax in float32. A block is under
+# blocks (queries, keys) of the flash kernel under ``causal_core``: the
+# fastest on a v5e at [4, 64, 2048, 192 / 128] (PERF.md section 5, PR 34)
+_CORE_KERNEL_BLOCKS = (1024, 1024)
+
+
+def core_runs_the_kernel(backend: str, length: int, block: int) -> bool:
+    """Whether :func:`causal_core` is one call of the Pallas kernel: on
+    a TPU, over more than one of the kernel's blocks, which divide the
+    length. Anywhere else (the CPU of the tests, a length that would be
+    padded) it is the blocked plain computation."""
+    return backend == "tpu" and length > block and length % block == 0
+
+
+def blocked_causal_core(q, k, v, scale: float, block: int):
+    """:func:`causal_core` in plain JAX: a block of ``block`` queries at
+    a time against its causal prefix of keys. A block is under
     ``jax.checkpoint``: the backward recomputes its scores, so no
     ``[L, L]`` tensor is held, forward or backward. ``L <= block`` is
     the plain computation."""
@@ -377,6 +389,27 @@ def causal_core(q, k, v, scale: float, block: int):
     return jnp.concatenate(
         [one(q[:, :, s:s + block], k[:, :, :s + block], v[:, :, :s + block],
              s) for s in range(0, l, block)], axis=2)
+
+
+@jax.named_scope("mla_core")
+def causal_core(q, k, v, scale: float, block: int):
+    """Causal softmax attention ``[B, H, L, Dv]`` of ``q, k [B, H, L,
+    Dk]`` and ``v [B, H, L, Dv]`` whose widths differ, the scores and
+    the softmax in float32, the probabilities cast to ``v``'s dtype.
+
+    On a TPU (:func:`core_runs_the_kernel`) it is one call of
+    ``ops/flash_attention.py``: a tile of scores lives in VMEM, forward
+    and backward, and the kernel's ``custom_vjp`` keeps ``q, k, v``, the
+    output and the log-sum-exp, nothing ``[L, L]``. Elsewhere it is
+    :func:`blocked_causal_core` in blocks of ``block`` queries."""
+    block_q, block_k = _CORE_KERNEL_BLOCKS
+    if core_runs_the_kernel(jax.default_backend(), q.shape[2],
+                            max(block_q, block_k)):
+        from baton_tpu.ops.flash_attention import flash_attention
+
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               block_q=block_q, block_k=block_k)
+    return blocked_causal_core(q, k, v, scale, block)
 
 
 @jax.named_scope("latent_attention")

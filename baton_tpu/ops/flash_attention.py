@@ -20,6 +20,14 @@ replacing the dense ``dot_product_attention`` einsum path
   the backward pass either;
 * **GQA for free**: the kv-head block index map sends query head ``h``
   to kv head ``h // (Hq//Hkv)`` — no ``jnp.repeat`` materialization;
+* **values as wide as they are**: ``Dv`` is read from ``v`` and need not
+  be the keys' ``Dk`` (latent attention: 192 / 128,
+  ``transformer.py::causal_core``); the value-side blocks (``v``, the
+  output, its cotangent, ``dv``, the accumulator) are ``Dv`` wide, the
+  key-side ones (``q``, ``k``, ``dq``, ``dk``) ``Dk``, and where ``Dk``
+  is not whole lane tiles they are handed to the kernels with the
+  sequence minor (:func:`_keys_ride_sublanes`); the softmax ``scale``
+  is an argument (``Dk ** -0.5`` unless given);
 * matches the seam contract ``attention_fn(q, k, v, bias, causal)``
   (transformer.py:31-32): additive per-key bias [B, 1, 1, L], static
   causal masking from global positions.
@@ -38,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
@@ -68,12 +77,111 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
 
 
 # ======================================================================
-# forward kernel: grid (B, Hq, Lq/block_q)
+# what the kernels share: a tile's scores, and which tiles a causal mask
+# leaves anything of
+
+
+def _keys_ride_sublanes(dk: int) -> bool:
+    """Whether the kernels take q and k (and hand back dq and dk) with
+    the head's width second-minor, ``[B, H, Dk, L]``: a width past one
+    lane tile that does not fill its last one (latent attention's 192).
+    As the minor dim it is padded to the next 128 lanes in HBM, in VMEM
+    and by every XLA op that makes q and k, and the rotary part's 64
+    beside it to twice itself: 160 ms a wave of ``sarvam_105b_c4_l2048``
+    (PERF.md section 6, PR 34). With the sequence minor nothing is
+    padded, and XLA, asked for that layout (:func:`_sequence_minor`),
+    makes the ``swapaxes`` around the kernels move nothing. Whole tiles,
+    and widths under one tile (not measured), stay ``[B, H, L, Dk]``."""
+    return dk > 128 and dk % 128 != 0
+
+
+_SEQUENCE_MINOR = Layout(major_to_minor=(0, 1, 3, 2))
+
+
+def _sequence_minor(x):
+    """``x [B, H, L, Dk]`` as ``[B, H, Dk, L]``, asking XLA to have laid
+    ``x`` out that way already: the ops that made it then write no lane
+    padding and the ``swapaxes`` moves nothing."""
+    return jnp.swapaxes(with_layout_constraint(x, _SEQUENCE_MINOR), 2, 3)
+
+
+def _from_sequence_minor(xt):
+    """The way back for a gradient ``[B, H, Dk, L]``."""
+    return with_layout_constraint(jnp.swapaxes(xt, 2, 3), _SEQUENCE_MINOR)
+
+
+def _key_side_spec(d_major, block, dk, index_map):
+    """The block of q, k, dq or dk that ``index_map`` (grid indices ->
+    batch, head, block of the sequence) names, in either layout."""
+    def place(*grid):
+        b_, h, s = index_map(*grid)
+        return (b_, h, 0, s) if d_major else (b_, h, s, 0)
+
+    shape = (None, None, dk, block) if d_major else (None, None, block, dk)
+    return _spec(shape, place)
+
+
+def _scores(q, k, bias, *, scale, causal, d_major, i, j, block_q, block_k):
+    """One tile's float32 scores ``[block_q, block_k]`` of ``q [bq, Dk]``
+    and ``k [bk, Dk]`` (``[Dk, bq]`` and ``[Dk, bk]`` with ``d_major``)
+    plus the keys' ``bias [1, bk]``: the operands stay in their dtype
+    (the MXU multiplies bfloat16 natively and accumulates in float32;
+    upcasting first would force 4-8x slower float32 passes), contracted
+    by ``dot_general`` (an explicit ``k.T`` would force a Mosaic relayout
+    before the MXU op). ``causal`` masks by the tile's global
+    positions."""
+    over = 0 if d_major else 1
+    s = lax.dot_general(
+        q, k, (((over,), (over,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale + bias
+    if causal:
+        q_pos = i * block_q + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0
+        )
+        k_pos = j * block_k + lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1
+        )
+        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    return s
+
+
+def _on_needed_tiles(body, *, causal, i, j, block_q, block_k):
+    """Run ``body()`` on tile ``(i, j)`` of queries and keys. Under a
+    causal mask a tile strictly in every query's future contributes
+    nothing and is skipped, forward and backward (about half the FLOPs);
+    :func:`_last_needed_k` / :func:`_first_needed_q` keep its DMAs away
+    too."""
+    if causal:
+        pl.when(j * block_k <= i * block_q + (block_q - 1))(body)
+    else:
+        body()
+
+
+def _last_needed_k(causal, i, j, block_q, block_k):
+    """The block of keys the grid step ``(i, j)`` asks for: under a
+    causal mask a skipped step names the last needed block again, and
+    Pallas fetches nothing for a block index that did not change."""
+    if not causal:
+        return j
+    return jnp.minimum(j, (i * block_q + (block_q - 1)) // block_k)
+
+
+def _first_needed_q(causal, i, j, block_q, block_k):
+    """The same for a grid whose inner axis runs over blocks of queries:
+    the skipped steps come first and name the first needed block."""
+    if not causal:
+        return i
+    return jnp.maximum(i, (j * block_k) // block_q)
+
+
+# ======================================================================
+# forward kernel: grid (B, Hq, Lq/block_q, Lk/block_k)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
-                *, scale, causal, block_q, block_k, nk):
+                *, scale, causal, d_major, block_q, block_k, nk):
     # Grid (B, Hq, Lq/bq, Lk/bk) with the kv axis INNERMOST ('arbitrary'):
     # the online-softmax state (acc/m/l) lives in VMEM scratch across the
     # j loop while Mosaic double-buffers the k/v block DMAs — the r2
@@ -90,36 +198,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # causal: a kv block strictly in every query's future contributes
-    # nothing — skip its matmuls (≈half the FLOPs on average)
-    needed = True
-    if causal:
-        needed = j * block_k <= i * block_q + (block_q - 1)
-
-    @pl.when(needed)
     def _accumulate():
-        # operands stay in the input dtype (bf16 on the bf16 path): the
-        # MXU multiplies bf16 natively with fp32 accumulation via
-        # preferred_element_type — upcasting first would force 4-8x
-        # slower fp32 MXU passes. Softmax statistics are fp32 throughout.
-        q = q_ref[...]                                   # [bq, D]
-        kj = k_ref[...]                                  # [bk, D]
-        vj = v_ref[...]
-        # contract D via dot_general — an explicit kj.T would force a
-        # Mosaic relayout before the MXU op
-        s = lax.dot_general(
-            q, kj, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        s = s + b_ref[...]                               # [1, bk] bias
-        if causal:
-            q_pos = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        vj = v_ref[...]                                  # [bk, Dv]
+        # softmax statistics are fp32 throughout
+        s = _scores(q_ref[...], k_ref[...], b_ref[...], scale=scale,
+                    causal=causal, d_major=d_major, i=i, j=j,
+                    block_q=block_q, block_k=block_k)
         m_prev = m_ref[:, :1]                            # [bq, 1]
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -132,6 +216,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
+    _on_needed_tiles(_accumulate, causal=causal, i=i, j=j,
+                     block_q=block_q, block_k=block_k)
+
     @pl.when(j == nk - 1)
     def _finalize():
         m = m_ref[:, :1]
@@ -140,10 +227,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         lse_ref[0, :] = (m + jnp.log(l))[:, 0]
 
 
-def _compiler_params(n_parallel: int):
+def _compiler_params(n_parallel: int, n_sequential: int = 1, **kwargs):
     """Mark the leading grid axes parallel, the innermost sequential."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))
+        dimension_semantics=("parallel",) * n_parallel
+        + ("arbitrary",) * n_sequential, **kwargs)
 
 
 def _scratch(shape, dtype=jnp.float32):
@@ -151,37 +239,44 @@ def _scratch(shape, dtype=jnp.float32):
 
 
 def _fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
-    b, hq, lq, d = q.shape
-    hkv, lk = k.shape[1], k.shape[2]
+    b, hq, lq, dk = q.shape
+    hkv, lk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     nk = lk // block_k
     grid = (b, hq, lq // block_q, nk)
+    d_major = _keys_ride_sublanes(dk)
+    if d_major:
+        q, k = _sequence_minor(q), _sequence_minor(k)
+
+    def kj(i, j):
+        return _last_needed_k(causal, i, j, block_q, block_k)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
+        _fwd_kernel, scale=scale, causal=causal, d_major=d_major,
         block_q=block_q, block_k=block_k, nk=nk,
     )
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            _spec((None, None, block_q, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            _spec((None, None, block_k, d),
-                  lambda b_, h, i, j: (b_, h // group, j, 0)),
-            _spec((None, None, block_k, d),
-                  lambda b_, h, i, j: (b_, h // group, j, 0)),
-            _spec((None, 1, block_k), lambda b_, h, i, j: (b_, 0, j)),
+            _key_side_spec(d_major, block_q, dk,
+                           lambda b_, h, i, j: (b_, h, i)),
+            _key_side_spec(d_major, block_k, dk,
+                           lambda b_, h, i, j: (b_, h // group, kj(i, j))),
+            _spec((None, None, block_k, dv),
+                  lambda b_, h, i, j: (b_, h // group, kj(i, j), 0)),
+            _spec((None, 1, block_k), lambda b_, h, i, j: (b_, 0, kj(i, j))),
         ],
         out_specs=[
-            _spec((None, None, block_q, d), lambda b_, h, i, j: (b_, h, i, 0)),
+            _spec((None, None, block_q, dv), lambda b_, h, i, j: (b_, h, i, 0)),
             _spec((None, None, 1, block_q), lambda b_, h, i, j: (b_, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, lq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, lq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, 1, lq), jnp.float32),
         ],
         scratch_shapes=[
-            _scratch((block_q, d)),
+            _scratch((block_q, dv)),
             _scratch((block_q, 128)),
             _scratch((block_q, 128)),
         ],
@@ -192,19 +287,72 @@ def _fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
 
 
 # ======================================================================
-# backward: the standard two-pass flash-attention backward, blockwise
-# recompute of p from the saved lse (no O(L²) residuals). Pass 1 grids
-# (B, Hq, Lk/block_k, Lq/block_q) and accumulates dk/dv/db over the
-# innermost q axis; pass 2 grids (B, Hq, Lq/block_q, Lk/block_k) and
-# accumulates dq over the innermost kv axis. Only block-sized tiles are
-# ever VMEM-resident, so VMEM is O(block²), independent of L (the r1
-# single-program-per-head version held ~7 full [L, d] buffers).
-# delta = rowsum(do·o) is precomputed outside pallas.
+# backward: blockwise recompute of p from the saved lse (no O(L²)
+# residuals); delta = rowsum(do·o) is precomputed outside pallas. dq and
+# dk are as wide as the keys, dv and do as wide as the values. Two forms,
+# chosen by the shapes (:func:`_dq_fits_vmem`):
+#
+# * ONE kernel where a head's whole float32 dq [Lq, Dk] fits in VMEM:
+#   grid (B, Hq, Lk/block_k, Lq/block_q), q innermost; dk/dv/db
+#   accumulate in their output blocks over the q axis, dq's output block
+#   is the head's, resident over both inner axes, and every tile adds
+#   into its rows. A tile's p and ds are computed once for the three
+#   gradients: five products a tile for the two-pass form's seven, and
+#   half its vector work (the vector unit, not the MXU, paces these
+#   kernels on a v5e);
+# * the standard two passes for longer sequences: pass 1 on the same
+#   grid accumulates dk/dv/db, pass 2 grids (B, Hq, Lq/block_q,
+#   Lk/block_k) and accumulates dq over the innermost kv axis. Only
+#   block-sized tiles are ever VMEM-resident, so VMEM is O(block²),
+#   independent of L (the r1 single-program-per-head version held ~7
+#   full [L, d] buffers).
+
+# what the one-kernel form may hold of VMEM for dq, twice (Pallas
+# double-buffers an output block): L = 8,192 at Dk = 128. Its [bq, bk]
+# float32 temporaries (scores, p, dp, ds) are past the compiler's default
+# scoped limit at blocks of 1,024, so the kernel states its own; a v5e's
+# VMEM is 128 MiB.
+_DQ_RESIDENT_BYTES = 8 * 1024 ** 2
+_ONE_KERNEL_VMEM_LIMIT_BYTES = 64 * 1024 ** 2
+
+
+def _dq_fits_vmem(lq: int, dk: int) -> bool:
+    return 2 * 4 * lq * dk <= _DQ_RESIDENT_BYTES
+
+
+def _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref, *,
+              scale, causal, d_major, i, j, block_q, block_k):
+    """A tile's probabilities from the saved log-sum-exp, and the
+    gradient of its scores, both float32 ``[bq, bk]``."""
+    s = _scores(q_ref[...], k_ref[...], b_ref[...], scale=scale,
+                causal=causal, d_major=d_major, i=i, j=j, block_q=block_q,
+                block_k=block_k)
+    p = jnp.exp(s - lse_ref[0][:, None])                       # [bq, bk]
+    dp = lax.dot_general(
+        do_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p, p * (dp - delta_ref[0][:, None])
+
+
+def _grad_of_keys(x, ds, over: int, d_major: bool):
+    """``ds [bq, bk]`` contracted over its axis ``over`` with ``x``, the
+    block of q (for dk, ``over = 0``) or of k (for dq, ``over = 1``):
+    ``[b, Dk]`` of ``x [b', Dk]``, or ``[Dk, b]`` of ``x [Dk, b']`` with
+    ``d_major``, float32."""
+    if d_major:
+        dims = (((1,), (over,)), ((), ()))
+        return lax.dot_general(x, ds, dims,
+                               preferred_element_type=jnp.float32)
+    dims = (((over,), (0,)), ((), ()))
+    return lax.dot_general(ds, x, dims, preferred_element_type=jnp.float32)
 
 
 def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
-                    dk_ref, dv_ref, db_ref, *, scale, causal,
-                    block_q, block_k):
+                    *out_refs, scale, causal, d_major, block_q, block_k):
+    # out_refs is (dk, dv, db) in the two-pass form; the one-kernel form
+    # puts the head's whole dq [Lq, Dk] first
+    *dq_ref, dk_ref, dv_ref, db_ref = out_refs
     j = pl.program_id(2)
     i = pl.program_id(3)
 
@@ -214,46 +362,42 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
         dv_ref[...] = jnp.zeros_like(dv_ref[...])
         db_ref[...] = jnp.zeros_like(db_ref[...])
 
-    qi = q_ref[...]                                            # [bq, D]
-    doi = do_ref[...]                                          # [bq, D]
-    lsei = lse_ref[0][:, None]                                 # [bq, 1]
-    delta = delta_ref[0][:, None]                              # [bq, 1]
-    kj = k_ref[...]                                            # [bk, D]
-    vj = v_ref[...]
-    bj = b_ref[...]                                            # [1, bk]
+    def _accumulate():
+        qi = q_ref[...]                              # [bq, Dk] ([Dk, bq])
+        doi = do_ref[...]                                      # [bq, Dv]
+        p, ds = _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                          b_ref, scale=scale, causal=causal, d_major=d_major,
+                          i=i, j=j, block_q=block_q, block_k=block_k)
+        # contract the bq axis directly (p^T·do, ds^T·q without transposes)
+        dv_ref[...] += lax.dot_general(
+            p.astype(doi.dtype), doi, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        db_ref[...] += ds.sum(axis=0)[None, :]
+        ds = ds.astype(qi.dtype)
+        dk_ref[...] += scale * _grad_of_keys(qi, ds, 0, d_major)
+        if not dq_ref:
+            return
+        dq = scale * _grad_of_keys(k_ref[...], ds, 1, d_major)
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        at = (slice(None), rows) if d_major else (rows, slice(None))
 
-    s = (lax.dot_general(
-        qi, kj, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + bj)
-    if causal:
-        q_pos = i * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    p = jnp.exp(s - lsei)                                      # [bq, bk]
-    dp = lax.dot_general(
-        doi, vj, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - delta)                                      # [bq, bk]
-    # contract the bq axis directly (p^T·do, ds^T·q without transposes)
-    dv_ref[...] += lax.dot_general(
-        p.astype(doi.dtype), doi, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dk_ref[...] += scale * lax.dot_general(
-        ds.astype(qi.dtype), qi, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    db_ref[...] += ds.sum(axis=0)[None, :]
+        # the first block of keys is visible to every block of queries,
+        # causal or not: it sets the rows, the later ones add to them
+        @pl.when(j == 0)
+        def _set():
+            dq_ref[0][at] = dq
+
+        @pl.when(j > 0)
+        def _add():
+            dq_ref[0][at] += dq
+
+    _on_needed_tiles(_accumulate, causal=causal, i=i, j=j,
+                     block_q=block_q, block_k=block_k)
 
 
 def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
-                   dq_ref, *, scale, causal, block_q, block_k):
+                   dq_ref, *, scale, causal, d_major, block_q, block_k):
     i = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -261,43 +405,28 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
     def _init():
         dq_ref[...] = jnp.zeros_like(dq_ref[...])
 
-    qi = q_ref[...]
-    doi = do_ref[...]
-    lsei = lse_ref[0][:, None]
-    delta = delta_ref[0][:, None]
-    kj = k_ref[...]
-    vj = v_ref[...]
-    bj = b_ref[...]
+    def _accumulate():
+        kj = k_ref[...]
+        _, ds = _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                          b_ref, scale=scale, causal=causal, d_major=d_major,
+                          i=i, j=j, block_q=block_q, block_k=block_k)
+        dq_ref[...] += scale * _grad_of_keys(kj, ds.astype(kj.dtype), 1,
+                                             d_major)
 
-    s = (lax.dot_general(
-        qi, kj, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + bj)
-    if causal:
-        q_pos = i * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    p = jnp.exp(s - lsei)
-    dp = lax.dot_general(
-        doi, vj, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - delta)
-    dq_ref[...] += scale * jnp.dot(
-        ds.astype(kj.dtype), kj, preferred_element_type=jnp.float32
-    )
+    _on_needed_tiles(_accumulate, causal=causal, i=i, j=j,
+                     block_q=block_q, block_k=block_k)
 
 
 def _bwd_call(q, k, v, bias2d, out, dout, lse,
               causal, scale, block_q, block_k, interpret):
-    b, hq, lq, d = q.shape
-    hkv, lk = k.shape[1], k.shape[2]
+    b, hq, lq, dk_ = q.shape
+    hkv, lk, dv_ = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
     nq, nk = lq // block_q, lk // block_k
+    one_kernel = _dq_fits_vmem(lq, dk_)
+    d_major = _keys_ride_sublanes(dk_)
+    if d_major:
+        q, k = _sequence_minor(q), _sequence_minor(k)
 
     # delta [B, Hq, Lq] in fp32 — cheap elementwise reduce, let XLA fuse it
     delta = jnp.sum(
@@ -305,64 +434,90 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
     )
     # low-rank operands get an explicit size-1 second-minor dim so their
     # kept last-two block dims satisfy Mosaic's (8, 128) tiling rule
-    lse4 = lse.reshape(b, hq, 1, lq)
-    delta4 = delta.reshape(b, hq, 1, lq)
-    bias3 = bias2d.reshape(b, 1, lk)
+    operands = (q, dout, lse.reshape(b, hq, 1, lq),
+                delta.reshape(b, hq, 1, lq), k, v, bias2d.reshape(b, 1, lk))
 
     def in_specs(qi, kj):
         """Common input specs; ``qi``/``kj`` pick the q/kv block index out
         of the two trailing grid axes (x, y)."""
-        q_spec = _spec((None, None, block_q, d),
-                       lambda b_, h, x, y: (b_, h, qi(x, y), 0))
         lse_spec = _spec((None, None, 1, block_q),
                          lambda b_, h, x, y: (b_, h, 0, qi(x, y)))
-        kv_spec = _spec((None, None, block_k, d),
-                        lambda b_, h, x, y: (b_, h // group, kj(x, y), 0))
-        bias_spec = _spec((None, 1, block_k),
-                          lambda b_, h, x, y: (b_, 0, kj(x, y)))
-        return [q_spec, q_spec, lse_spec, lse_spec,
-                kv_spec, kv_spec, bias_spec]
+        return [
+            _key_side_spec(d_major, block_q, dk_,
+                           lambda b_, h, x, y: (b_, h, qi(x, y))),
+            _spec((None, None, block_q, dv_),
+                  lambda b_, h, x, y: (b_, h, qi(x, y), 0)),
+            lse_spec, lse_spec,
+            _key_side_spec(d_major, block_k, dk_,
+                           lambda b_, h, x, y: (b_, h // group, kj(x, y))),
+            _spec((None, None, block_k, dv_),
+                  lambda b_, h, x, y: (b_, h // group, kj(x, y), 0)),
+            _spec((None, 1, block_k), lambda b_, h, x, y: (b_, 0, kj(x, y))),
+        ]
 
-    # pass 1: dk/dv/db — grid (…, kv, q), q innermost (accumulated over)
-    dk_h, dv_h, db_h = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+    def key_side_shape(length):
+        return jax.ShapeDtypeStruct(
+            (b, hq, dk_, length) if d_major else (b, hq, length, dk_),
+            jnp.float32)
+
+    kernel_args = dict(scale=scale, causal=causal, d_major=d_major,
+                       block_q=block_q, block_k=block_k)
+
+    # dk/dv/db (and dq where it fits) — grid (…, kv, q), q innermost
+    # (accumulated over)
+    out_specs = [
+        _key_side_spec(d_major, block_k, dk_, lambda b_, h, x, y: (b_, h, x)),
+        _spec((None, None, block_k, dv_), lambda b_, h, x, y: (b_, h, x, 0)),
+        _spec((None, None, 1, block_k), lambda b_, h, x, y: (b_, h, 0, x)),
+    ]
+    out_shape = [
+        key_side_shape(lk),
+        jax.ShapeDtypeStruct((b, hq, lk, dv_), jnp.float32),
+        jax.ShapeDtypeStruct((b, hq, 1, lk), jnp.float32),
+    ]
+    if one_kernel:
+        out_specs.insert(0, _key_side_spec(d_major, lq, dk_,
+                                           lambda b_, h, x, y: (b_, h, 0)))
+        out_shape.insert(0, key_side_shape(lq))
+        params = _compiler_params(
+            2, 2, vmem_limit_bytes=_ONE_KERNEL_VMEM_LIMIT_BYTES)
+    else:
+        params = _compiler_params(3)
+    *dq, dk_h, dv_h, db_h = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, **kernel_args),
         grid=(b, hq, nk, nq),
-        in_specs=in_specs(qi=lambda x, y: y, kj=lambda x, y: x),
-        out_specs=[
-            _spec((None, None, block_k, d), lambda b_, h, x, y: (b_, h, x, 0)),
-            _spec((None, None, block_k, d), lambda b_, h, x, y: (b_, h, x, 0)),
-            _spec((None, None, 1, block_k), lambda b_, h, x, y: (b_, h, 0, x)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, lk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, lk, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hq, 1, lk), jnp.float32),
-        ],
-        compiler_params=None if interpret else _compiler_params(3),
+        in_specs=in_specs(
+            qi=lambda x, y: _first_needed_q(causal, y, x, block_q, block_k),
+            kj=lambda x, y: x),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=None if interpret else params,
         interpret=interpret,
-    )(q, dout, lse4, delta4, k, v, bias3)
+    )(*operands)
 
-    # pass 2: dq — grid (…, q, kv), kv innermost (accumulated over)
-    (dq,) = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        grid=(b, hq, nq, nk),
-        in_specs=in_specs(qi=lambda x, y: x, kj=lambda x, y: y),
-        out_specs=[
-            _spec((None, None, block_q, d), lambda b_, h, x, y: (b_, h, x, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, lq, d), jnp.float32),
-        ],
-        compiler_params=None if interpret else _compiler_params(3),
-        interpret=interpret,
-    )(q, dout, lse4, delta4, k, v, bias3)
+    if not one_kernel:
+        # pass 2: dq — grid (…, q, kv), kv innermost (accumulated over)
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, **kernel_args),
+            grid=(b, hq, nq, nk),
+            in_specs=in_specs(
+                qi=lambda x, y: x,
+                kj=lambda x, y: _last_needed_k(causal, x, y, block_q,
+                                               block_k)),
+            out_specs=[_key_side_spec(d_major, block_q, dk_,
+                                      lambda b_, h, x, y: (b_, h, x))],
+            out_shape=[key_side_shape(lq)],
+            compiler_params=None if interpret else _compiler_params(3),
+            interpret=interpret,
+        )(*operands)
+    dq = dq[0]
 
     # per-query-head kv grads fold back onto the Hkv axis (GQA)
-    dk = dk_h.reshape(b, hkv, group, lk, d).sum(axis=2)
-    dv = dv_h.reshape(b, hkv, group, lk, d).sum(axis=2)
+    dk = dk_h.reshape((b, hkv, group) + dk_h.shape[2:]).sum(axis=2)
+    dv = dv_h.reshape(b, hkv, group, lk, dv_).sum(axis=2)
     dbias = db_h[:, :, 0].sum(axis=1)                          # [B, Lk]
+    if d_major:
+        dq, dk = _from_sequence_minor(dq), _from_sequence_minor(dk)
     return dq, dk, dv, dbias
 
 
@@ -413,11 +568,15 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 1024,
     interpret: Optional[bool] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Flash attention matching ``dot_product_attention`` semantics
-    (transformer.py:105-133): q [B, Hq, L, Dh], k/v [B, Hkv, L, Dh],
-    optional additive per-key ``bias`` [B, 1, 1, L], fp32 softmax,
-    returns [B, Hq, L, Dh] in q's dtype. Differentiable via Pallas
+    (transformer.py:105-133): q [B, Hq, L, Dk], k [B, Hkv, L, Dk],
+    v [B, Hkv, L, Dv] (``Dv`` is read from ``v`` and need not be ``Dk``:
+    latent attention's values are narrower than its keys), optional
+    additive per-key ``bias`` [B, 1, 1, L], fp32 softmax of the scores
+    times ``scale`` (``Dk ** -0.5`` unless given), returns
+    [B, Hq, L, Dv] in q's dtype. Differentiable via Pallas
     forward+backward kernels.
 
     Sequence lengths are padded to the block size internally (padded
@@ -427,9 +586,11 @@ def flash_attention(
     b, hq, lq, d = q.shape
     hkv, lk = k.shape[1], k.shape[2]
     assert hq % hkv == 0, f"GQA needs Hq % Hkv == 0, got {hq} % {hkv}"
-    assert v.shape == k.shape
+    assert k.shape == (b, hkv, lk, d) and v.shape[:3] == k.shape[:3], (
+        f"q {q.shape}, k {k.shape}, v {v.shape}")
     interpret = _resolve_interpret(interpret)
-    scale = d ** -0.5
+    if scale is None:
+        scale = d ** -0.5
 
     if bias is None:
         bias2d = jnp.zeros((b, lk), jnp.float32)

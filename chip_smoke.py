@@ -56,6 +56,9 @@ class Sizes:
     rounds_after_compile: int
     # flash_kernel, alone: [B, H, L, Dh]
     flash_shape: tuple
+    # flash_kernel, the latent-attention core of sarvam_105b_c4_l2048:
+    # [clients, H, L, Dk, Dv], values narrower than keys
+    core_shape: tuple
     # flash_kernel, through the decoder: sequence length (widths are
     # a 0.9 B Llama's on the chip, LlamaConfig.tiny in rehearsal)
     decoder_len: int
@@ -68,9 +71,11 @@ class Sizes:
 # whether round 5 is back under round 1 hangs on the shuffle (PR 30)
 CHIP = Sizes(clients=32, samples=48, batch=32, image=32,
              rounds_after_compile=11, flash_shape=(4, 8, 4096, 64),
+             core_shape=(4, 64, 2048, 192, 128),
              decoder_len=4096, ring_shape=(1, 8, 4096, 64))
 REHEARSAL = Sizes(clients=4, samples=8, batch=4, image=8,
                   rounds_after_compile=3, flash_shape=(1, 2, 256, 64),
+                  core_shape=(1, 2, 2048, 24, 16),
                   decoder_len=128, ring_shape=(1, 2, 256, 64))
 
 
@@ -575,6 +580,66 @@ def _flash_alone(env: Env) -> str:
             + f" (tol {BF16_TOL})")
 
 
+def _latent_core(env: Env) -> str:
+    """The core of latent attention as ``mla_apply`` calls it, against
+    the blocked plain core on the same device: output and the three
+    gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    from baton_tpu.models import transformer
+    from baton_tpu.ops.flash_attention import flash_attention
+
+    c, h, l, dk, dv = env.sizes.core_shape
+    kq, kk, kv, kw = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(kq, (c, h, l, dk), jnp.bfloat16)
+    k = jax.random.normal(kk, (c, h, l, dk), jnp.bfloat16)
+    v = jax.random.normal(kv, (c, h, l, dv), jnp.bfloat16)
+    w = jax.random.normal(kw, (c, h, l, dv), jnp.float32)  # cotangent
+    scale = 1.874 * dk ** -0.5  # sarvam_105b's: YaRN's m ** 2 on Dk ** -0.5
+    blocked = partial(transformer.blocked_causal_core, scale=scale, block=512)
+    if env.rehearsal:
+        # causal_core keeps the CPU on the blocked core, so the
+        # rehearsal calls the interpreted kernel at the core's blocks
+        block_q, block_k = transformer._CORE_KERNEL_BLOCKS
+        kernel = partial(flash_attention, causal=True, scale=scale,
+                         block_q=block_q, block_k=block_k, interpret=True)
+    else:
+        kernel = partial(transformer.causal_core, scale=scale, block=512)
+
+    def both(core):
+        def weighted(q, k, v):
+            out = core(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+
+        return jax.jit(jax.value_and_grad(weighted, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    if not env.rehearsal:
+        text = both(kernel).lower(q, k, v).as_text()
+        # the forward kernel and the one backward kernel (a head's dq
+        # fits in VMEM at this length)
+        _check(text.count("tpu_custom_call") >= 2,
+               "causal_core lowered without the flash kernels")
+    results, ms = {}, {}
+    for name, core in (("kernel", kernel), ("blocked", blocked)):
+        fn = both(core)
+        jax.block_until_ready(fn(q, k, v))
+        t0 = time.perf_counter()
+        (_, out), grads = jax.block_until_ready(fn(q, k, v))
+        ms[name] = 1e3 * (time.perf_counter() - t0)
+        results[name] = (out,) + grads
+    rel = {n: _rel_err(got, ref) for n, got, ref in zip(
+        ("out", "dq", "dk", "dv"), results["kernel"], results["blocked"])}
+    _check(max(rel.values()) <= BF16_TOL,
+           f"flash core vs blocked plain core beyond {BF16_TOL}: {rel}")
+    took = ("not measured (rehearsal)" if env.rehearsal else
+            f"{ms['kernel']:.1f} ms against {ms['blocked']:.1f}")
+    return (f"causal_core [{c}, {h}, {l}, {dk}/{dv}] bf16 vs the blocked "
+            f"plain core: " + " ".join(f"{n}={e:.1e}" for n, e in rel.items())
+            + f" (tol {BF16_TOL}); forward and backward {took}")
+
+
 def _flash_through_decoder(env: Env) -> str:
     import jax
     import jax.numpy as jnp
@@ -636,7 +701,8 @@ def _flash_through_decoder(env: Env) -> str:
 
 def phase_flash_kernel(env: Env) -> None:
     env.say("flash_kernel",
-            _flash_alone(env) + "; " + _flash_through_decoder(env))
+            _flash_alone(env) + "; " + _latent_core(env) + "; "
+            + _flash_through_decoder(env))
 
 
 # ----------------------------------------------------------------------

@@ -60,6 +60,8 @@ def test_chip_smoke_rehearsal_runs_every_phase(tmp_path):
                   "http_round", "mesh", "cache"):
         assert any(f"phase={phase} " in ln for ln in phase_lines), phase
     assert not any("skipped" in ln for ln in phase_lines)
+    assert any("phase=flash_kernel " in ln and "causal_core [1, 2, 2048, 24/16]"
+               in ln for ln in phase_lines)
     # a rehearsal prints no time taken on the CPU under any name
     assert "not measured (rehearsal)" in proc.stdout
     assert any(cache.iterdir())
@@ -140,6 +142,25 @@ def test_flash_blocks_stay_tile_aligned_off_the_interpreter():
         assert (lq + pad_q) % bq == 0 and (lk + pad_k) % bk == 0
     # interpret mode may shrink below a tile — CPU tests only
     assert _pick_blocks(16, 16, 512, 1024, interpret=True) == (16, 16)
+
+
+def test_the_flash_phase_holds_the_cells_latent_core():
+    """On the chip the phase runs the core of ``sarvam_105b_c4_l2048`` at
+    its shape, values narrower than keys, against the blocked plain
+    core; the rehearsal the same code over two of the core's blocks a
+    side, interpreted."""
+    sys.path.insert(0, str(REPO))
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(str(REPO))
+    assert chip_smoke.CHIP.core_shape == (4, 64, 2048, 192, 128)
+    env = chip_smoke.Env(sizes=chip_smoke.REHEARSAL, rehearsal=True,
+                         platform="cpu", kind="cpu", count=1, cache_dir="",
+                         cache_from_env=False)
+    line = chip_smoke._latent_core(env)
+    assert "causal_core [1, 2, 2048, 24/16] bf16" in line
+    assert "not measured (rehearsal)" in line and " ms " not in line
 
 
 def test_flash_interpret_is_decided_in_one_place(monkeypatch):

@@ -67,8 +67,30 @@ def test_forward_unpadded_length():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.fixture(params=["one_kernel", "two_pass"])
+def backward_form(request, monkeypatch):
+    """Both forms of the backward: where a head's dq fits in VMEM one
+    kernel computes the three gradients, past that the two passes do
+    (forced here by a budget of nothing)."""
+    from baton_tpu.ops import flash_attention as fa
+
+    if request.param == "two_pass":
+        monkeypatch.setattr(fa, "_DQ_RESIDENT_BYTES", 0)
+    assert fa._dq_fits_vmem(32, 16) is (request.param == "one_kernel")
+    return request.param
+
+
+def test_the_backward_is_one_kernel_while_dq_fits_in_vmem():
+    from baton_tpu.ops.flash_attention import _dq_fits_vmem
+
+    assert _dq_fits_vmem(2048, 192)      # latent attention's core
+    assert _dq_fits_vmem(8192, 128)
+    assert not _dq_fits_vmem(16384, 128)  # long contexts: two passes
+    assert not _dq_fits_vmem(32768, 64)
+
+
 @pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_dense(causal):
+def test_gradients_match_dense(causal, backward_form):
     q, k, v = _qkv(4, 2, 4, 2, 16, 8)
     mask = jnp.concatenate([jnp.ones((2, 13)), jnp.zeros((2, 3))], axis=1)
     bias = padding_bias(mask)
@@ -89,7 +111,7 @@ def test_gradients_match_dense(causal):
                                    rtol=1e-4, atol=1e-4)
 
 
-def test_gradients_gqa_fold():
+def test_gradients_gqa_fold(backward_form):
     # kv grads must fold the query-head group correctly (sum over group)
     q, k, v = _qkv(5, 1, 4, 1, 8, 8)
 
@@ -145,3 +167,127 @@ def test_seam_in_model():
     assert np.isfinite(losses["flash"])
     np.testing.assert_allclose(losses["flash"], losses["dense"],
                                rtol=1e-3, atol=1e-3)
+
+
+# ----------------------------------------------------------------------
+# values narrower than keys (latent attention: 192 / 128), an explicit
+# scale. Oracle: the whole [L, L] scores at once in float32, written here.
+
+
+def _plain(q, k, v, causal, scale):
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * scale
+    if causal:
+        l = q.shape[2]
+        s = jnp.where(jnp.tril(jnp.ones((l, l), bool)), s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+def _qkv_widths(seed, b, hq, hkv, l, dk, dv):
+    q, k, _ = _qkv(seed, b, hq, hkv, l, dk)
+    return q, k, _rand(jax.random.key(seed + 100), b, hkv, l, dv)
+
+
+WIDTHS = [(24, 16), (192, 128)]
+
+
+@pytest.mark.parametrize("dk,dv", WIDTHS)
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("hq,hkv", [(2, 2), (4, 2)], ids=["mha", "gqa"])
+def test_narrower_values_forward(dk, dv, causal, hq, hkv):
+    q, k, v = _qkv_widths(7, 2, hq, hkv, 32, dk, dv)
+    got = flash_attention(q, k, v, causal=causal, scale=0.11,
+                          block_q=8, block_k=16)
+    assert got.shape == (2, hq, 32, dv)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_plain(q, k, v, causal, 0.11)),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dk,dv", WIDTHS)
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("hq,hkv", [(2, 2), (4, 2)], ids=["mha", "gqa"])
+def test_narrower_values_gradients(dk, dv, causal, hq, hkv, backward_form):
+    """dq and dk as wide as the keys, dv as wide as the values; blocks
+    of 8 queries and 16 keys, so a causal backward skips tiles."""
+    q, k, v = _qkv_widths(8, 1, hq, hkv, 32, dk, dv)
+    weight = _rand(jax.random.key(9), 1, hq, 32, dv)
+
+    def grads(attend):
+        return jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) * weight),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, scale=0.11, block_q=8, block_k=16))
+    want = grads(lambda q, k, v: _plain(q, k, v, causal, 0.11))
+    for g, w, x in zip(got, want, (q, k, v)):
+        assert g.shape == x.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_the_default_scale_is_the_keys_width():
+    q, k, v = _qkv_widths(10, 1, 2, 2, 16, 24, 16)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, block_q=8, block_k=8)),
+        np.asarray(flash_attention(q, k, v, scale=24 ** -0.5,
+                                   block_q=8, block_k=8)), rtol=0, atol=0)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, block_q=8, block_k=8)),
+        np.asarray(_plain(q, k, v, False, 24 ** -0.5)), rtol=1e-5, atol=1e-5)
+
+
+def test_narrower_values_with_a_key_bias_and_a_padded_length():
+    # L = 20 pads to the blocks; the bias masks the last four real keys
+    q, k, v = _qkv_widths(11, 2, 2, 2, 20, 24, 16)
+    bias = padding_bias(jnp.concatenate(
+        [jnp.ones((2, 16)), jnp.zeros((2, 4))], axis=1))
+    got = flash_attention(q, k, v, bias=bias, causal=True, scale=0.2,
+                          block_q=8, block_k=8)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * 0.2
+    seen = (jnp.arange(20)[:, None] >= jnp.arange(20)[None, :]) \
+        & (jnp.arange(20) < 16)[None, :]
+    want = jnp.einsum("bhqk,bhkd->bhqd",
+                      jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1), v,
+                      precision="highest")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_equal_widths_are_what_they_were(causal, backward_form):
+    """``Dv == Dk``: the public kernel and the ring's block entry points
+    against the dense oracle the file has always been held to, outputs,
+    log-sum-exp and the four gradients."""
+    from baton_tpu.ops.flash_attention import flash_block_bwd, flash_block_fwd
+
+    q, k, v = _qkv(12, 2, 4, 2, 32, 16)
+    bias2d = 0.5 * _rand(jax.random.key(13), 2, 32)
+    bias = bias2d[:, None, None, :]
+    weight = _rand(jax.random.key(14), 2, 4, 32, 16)
+
+    def dense(q, k, v, bias):
+        return dot_product_attention(q, k, v, bias=bias, causal=causal)
+
+    want, vjp = jax.vjp(dense, q, k, v, bias)
+    want_g = vjp(weight)
+    out, lse = flash_block_fwd(q, k, v, bias2d, causal, block_q=8, block_k=16)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1),
+                   precision="highest") * 16 ** -0.5 + bias
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((32, 32), bool)), s, -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(jax.nn.logsumexp(s, axis=-1)),
+        rtol=1e-5, atol=1e-5)
+    got_g = flash_block_bwd(q, k, v, bias2d, out, weight, lse, causal,
+                            block_q=8, block_k=16)
+    public = flash_attention(q, k, v, bias=bias, causal=causal,
+                             block_q=8, block_k=16)
+    np.testing.assert_array_equal(np.asarray(public), np.asarray(out))
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(g).reshape(w.shape),
+                                   np.asarray(w), rtol=1e-4, atol=1e-5)

@@ -14,9 +14,12 @@ import numpy as np
 import pytest
 
 from baton_tpu.models.llama import LlamaConfig, llama_lm_model
+from baton_tpu.models import transformer
 from baton_tpu.models.transformer import (
     MLAConfig,
+    blocked_causal_core,
     causal_core,
+    core_runs_the_kernel,
     mla_apply,
     mla_init,
     mla_rope_angles,
@@ -165,6 +168,102 @@ def test_the_blocked_core_has_the_unblocked_cores_gradients(nprng):
     out = causal_core(q, k, v, 0.3, 4)
     np.testing.assert_allclose(np.asarray(out[:, :, 0]),
                                np.asarray(v[:, :, 0]), rtol=1e-6)
+
+
+@pytest.mark.parametrize("backend,length,block,kernel", [
+    ("tpu", 2048, 512, True),     # the cell's sequences
+    ("tpu", 1024, 512, True),
+    ("cpu", 2048, 512, False),    # tier-1's backend
+    ("gpu", 2048, 512, False),
+    ("tpu", 2000, 512, False),    # a length the blocks do not divide
+    ("tpu", 512, 512, False),     # one block: the plain computation
+    ("tpu", 12, 512, False),
+])
+def test_the_core_is_the_kernel_on_a_tpu_over_whole_blocks(
+        backend, length, block, kernel):
+    assert core_runs_the_kernel(backend, length, block) is kernel
+
+
+def _core_operands(nprng, lead=()):
+    q, k = (jnp.asarray(nprng.normal(size=lead + (1, 3, 32, 24)), jnp.float32)
+            for _ in range(2))
+    v, weight = (jnp.asarray(nprng.normal(size=lead + (1, 3, 32, 16)),
+                             jnp.float32) for _ in range(2))
+    return q, k, v, weight
+
+
+@pytest.fixture
+def kernel_core(monkeypatch):
+    """``causal_core`` on its kernel branch: the selector says yes, the
+    kernel's blocks are 8 queries by 16 keys, and off a TPU
+    ``flash_attention`` interprets itself."""
+    monkeypatch.setattr(transformer, "core_runs_the_kernel",
+                        lambda backend, length, block: True)
+    monkeypatch.setattr(transformer, "_CORE_KERNEL_BLOCKS", (8, 16))
+    return causal_core
+
+
+def _shapes(jaxpr):
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield getattr(var.aval, "shape", ())
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _shapes(sub)
+
+
+def _assert_kernel_ran(fn, *args):
+    assert "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+def test_the_kernel_branch_is_the_blocked_core(kernel_core, nprng):
+    q, k, v, weight = _core_operands(nprng)
+
+    def through(core):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(core(q, k, v, 0.3, 8) * weight),
+            argnums=(0, 1, 2))(q, k, v)
+
+    _assert_kernel_ran(lambda q, k, v: kernel_core(q, k, v, 0.3, 8), q, k, v)
+    # the gradient's program holds q, k, v, the output and the
+    # log-sum-exp, and no array of scores outside the kernels
+    held = set(_shapes(jax.make_jaxpr(jax.grad(
+        lambda q: jnp.sum(kernel_core(q, k, v, 0.3, 8))))(q).jaxpr))
+    assert (1, 3, 32) in held and (1, 3, 32, 32) not in held
+    (want, want_g), (got, got_g) = through(blocked_causal_core), \
+        through(kernel_core)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(kernel_core(q, k, v, 0.3, 8)),
+        np.asarray(blocked_causal_core(q, k, v, 0.3, 8)), rtol=1e-5,
+        atol=1e-5)
+
+
+def test_the_kernel_branch_under_the_wave_programs_nesting(kernel_core,
+                                                           nprng):
+    """``jax.vmap`` over a client axis of 2 around ``jax.checkpoint``
+    (the decoder block's ``remat``) around the core: the kernel's grid
+    takes the client axis, its forward is recomputed for the backward."""
+    q, k, v, weight = _core_operands(nprng, lead=(2,))
+
+    def through(core):
+        def client(q, k, v, weight):
+            block = jax.checkpoint(lambda q, k, v: core(q, k, v, 0.3, 8))
+            return jax.value_and_grad(
+                lambda q, k, v: jnp.sum(block(q, k, v) * weight),
+                argnums=(0, 1, 2))(q, k, v)
+        return jax.vmap(client)(q, k, v, weight)
+
+    _assert_kernel_ran(
+        jax.vmap(lambda q, k, v: kernel_core(q, k, v, 0.3, 8)), q, k, v)
+    (want, want_g), (got, got_g) = through(blocked_causal_core), \
+        through(kernel_core)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
 
 
 def test_no_score_tensor_is_held_whole_past_a_block():
