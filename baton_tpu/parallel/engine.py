@@ -176,6 +176,8 @@ class FedSim:
         self.compute_probe = ComputeProbe(model=model)
         self.last_compute: Optional[dict] = None
         self._fold_program = self._make_fold_program()
+        # _param_shapes' result and the tree structures it is of
+        self._param_shape_attrs: Optional[tuple] = None
 
     def _ensure_partition(self, params):
         if self.trainable_predicate is None or self.partition is not None:
@@ -189,6 +191,22 @@ class FedSim:
             return params, None
         self._ensure_partition(params)
         return self.partition.split(params)
+
+    def _param_shapes(self, params, frozen):
+        """``(leaves, trainable_bytes, frozen_bytes)`` of a round's two
+        parameter trees, for its spans' attributes. Shapes do not change
+        from round to round: the trees are walked when their structure
+        is new to this ``FedSim`` (its first round, or a model rebuilt
+        with other leaves) and otherwise not, in or out of a profiler
+        session."""
+        structure = jax.tree_util.tree_structure((params, frozen))
+        if (self._param_shape_attrs is None
+                or self._param_shape_attrs[0] != structure):
+            self._param_shape_attrs = (
+                structure,
+                (structure.num_leaves, _tree_bytes(params),
+                 _tree_bytes(frozen)))
+        return self._param_shape_attrs[1]
 
     # ------------------------------------------------------------------
     @property
@@ -635,13 +653,15 @@ class FedSim:
                 n_host = np.asarray(n_samples)
                 n_samples = jnp.asarray(n_samples)
                 if client_indices is not None:
-                    idx = jnp.asarray(client_indices)
-                    data = jax.tree_util.tree_map(
-                        lambda a: jnp.take(a, idx, axis=0), data)
-                    n_samples = jnp.take(n_samples, idx, axis=0)
+                    with annotate("baton.round.prepare.select"):
+                        idx = jnp.asarray(client_indices)
+                        data = jax.tree_util.tree_map(
+                            lambda a: jnp.take(a, idx, axis=0), data)
+                        n_samples = jnp.take(n_samples, idx, axis=0)
                     n_host = n_host[np.asarray(client_indices)]
                 c = int(n_samples.shape[0])
-                rngs = jax.random.split(rng, c)
+                with annotate("baton.round.prepare.keys"):
+                    rngs = jax.random.split(rng, c)
                 rows, capacity = self._rows_to_stage(data, n_host)
 
                 if wave_size == "auto":
@@ -678,11 +698,18 @@ class FedSim:
                 n_waves = -(-c // wave_size)
                 # shapes only, no device fetch: what a round holds once
                 # for all clients, and what it holds (and folds) a client
+                param_leaves, trainable_bytes, frozen_bytes = (
+                    self._param_shapes(params, frozen))
                 round_span.set_metadata(
                     clients=c, waves=n_waves, wave_size=int(wave_size),
-                    frozen_bytes=_tree_bytes(frozen),
-                    trainable_bytes=_tree_bytes(params),
+                    frozen_bytes=frozen_bytes,
+                    trainable_bytes=trainable_bytes,
                     **dict(self.model.span_attrs))
+                # the arrays a wave program is handed: both parameter
+                # trees, the wave's data, its n_samples and its keys
+                launch_leaves = (
+                    param_leaves
+                    + jax.tree_util.tree_structure(data).num_leaves + 2)
 
             psum_acc = None
             lsum_acc = None
@@ -700,27 +727,40 @@ class FedSim:
                         data, n_samples, rngs, start, stop, wave_size,
                         in_shard, rows)
                 with annotate("baton.round.dispatch", wave=wave):
-                    if robust:
-                        cp, closs = program(*bind(params, frozen, d, n, r))
-                        stacked_parts.append(
-                            jax.tree_util.tree_map(lambda a: a[:real], cp)
-                        )
-                        w_wave = n[:real].astype(jnp.float32)
-                        lsum = jnp.tensordot(w_wave,
-                                             closs[:real].astype(jnp.float32),
-                                             axes=(0, 0))
-                        wtot = jnp.sum(w_wave)
-                    else:
-                        psum, lsum, wtot, closs = program(
-                            *bind(params, frozen, d, n, r))
-                        psum_acc = (
-                            psum if psum_acc is None
-                            else _acc_tree_add(psum_acc, psum)
-                        )
-                    lsum_acc = lsum if lsum_acc is None else lsum_acc + lsum
-                    w_acc = wtot if w_acc is None else w_acc + wtot
-                    if per_client is not None:
-                        per_client.append(closs[:real])
+                    args = bind(params, frozen, d, n, r)
+                    with annotate("baton.round.dispatch.launch", wave=wave,
+                                  leaves=launch_leaves) as launch_span:
+                        out = program(*args)
+                        # the jit's fast-path entries, where the program
+                        # counts them: a count that grows from round to
+                        # round is a call that misses the fast path though
+                        # nothing compiles
+                        entries = getattr(program, "_cache_size", None)
+                        if entries is not None:
+                            launch_span.set_metadata(cache_entries=entries())
+                    with annotate("baton.round.dispatch.accumulate",
+                                  wave=wave):
+                        if robust:
+                            cp, closs = out
+                            stacked_parts.append(
+                                jax.tree_util.tree_map(lambda a: a[:real], cp)
+                            )
+                            w_wave = n[:real].astype(jnp.float32)
+                            lsum = jnp.tensordot(
+                                w_wave, closs[:real].astype(jnp.float32),
+                                axes=(0, 0))
+                            wtot = jnp.sum(w_wave)
+                        else:
+                            psum, lsum, wtot, closs = out
+                            psum_acc = (
+                                psum if psum_acc is None
+                                else _acc_tree_add(psum_acc, psum)
+                            )
+                        lsum_acc = (lsum if lsum_acc is None
+                                    else lsum_acc + lsum)
+                        w_acc = wtot if w_acc is None else w_acc + wtot
+                        if per_client is not None:
+                            per_client.append(closs[:real])
                     if progress_fn is not None:
                         jax.block_until_ready(lsum)
                         progress_fn(wave + 1, n_waves)

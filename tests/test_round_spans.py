@@ -155,10 +155,15 @@ def test_lower_wave_refuses_what_it_does_not_lower(kwargs):
 
 
 # ------------------------------------------- (b) one profiler session
+#: the CPU runtime's host event for one execution of a compiled program
+EXECUTE = "PjRtCpuExecutable::Execute"
+
+
 def _profiled(work, tdir):
-    """``(work(), spans)``: ``work`` under a CPU profiler session, and
-    the ``baton.*`` spans it left, ``(name, start, end, attributes)`` in
-    order of their start."""
+    """``(work(), spans, executions)``: ``work`` under a CPU profiler
+    session, the ``baton.*`` spans it left, ``(name, start, end,
+    attributes)`` in order of their start, and the runtime's ``EXECUTE``
+    events in the same form."""
     from jax.profiler import ProfileData
 
     options = jax.profiler.ProfileOptions()
@@ -169,14 +174,23 @@ def _profiled(work, tdir):
         jax.block_until_ready(result)
     finally:
         jax.profiler.stop_trace()
-    spans = []
+    spans, executions = [], []
     for path in glob.glob(tdir + "/**/*.xplane.pb", recursive=True):
         for plane in ProfileData.from_file(path).planes:
             for line in plane.lines:
-                spans += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
-                           dict(ev.stats))
-                          for ev in line.events if ev.name.startswith("baton.")]
-    return result, sorted(spans, key=lambda s: s[1])
+                for ev in line.events:
+                    end = ev.start_ns + ev.duration_ns
+                    if ev.name.startswith("baton."):
+                        spans.append((ev.name, ev.start_ns, end,
+                                      dict(ev.stats)))
+                    elif ev.name == EXECUTE:
+                        executions.append((ev.name, ev.start_ns, end, {}))
+    return result, sorted(spans, key=lambda s: s[1]), executions
+
+
+def _executed_inside(span, executions) -> int:
+    """Executions of a compiled program that lie inside ``span``."""
+    return sum(span[1] <= e[1] and e[2] <= span[2] for e in executions)
 
 
 @pytest.fixture(scope="module")
@@ -196,18 +210,46 @@ def session(tmp_path_factory):
                                wave_size=4)
         return first, second.params
 
-    (first, _), spans = _profiled(two_rounds,
-                                  str(tmp_path_factory.mktemp("trace")))
-    return {"spans": spans, "outside": outside, "inside": first}
+    (first, _), spans, executions = _profiled(
+        two_rounds, str(tmp_path_factory.mktemp("trace")))
+    return {"spans": spans, "executions": executions, "outside": outside,
+            "inside": first}
 
 
 @pytest.mark.parametrize("name,count", [
     ("baton.round", 2), ("baton.round.prepare", 2), ("baton.round.stage", 4),
     ("baton.round.dispatch", 4), ("baton.round.sync", 2),
     ("baton.round.record", 2), ("baton.round.fold", 2),
-    ("baton.round.update", 2)])
+    ("baton.round.update", 2), ("baton.round.dispatch.launch", 4),
+    ("baton.round.dispatch.accumulate", 4), ("baton.round.prepare.keys", 2),
+    ("baton.round.prepare.select", 0)])
 def test_session_counts_each_span(session, name, count):
     assert sum(s[0] == name for s in session["spans"]) == count
+
+
+def test_session_opens_select_once_a_round_for_a_chosen_cohort(
+        tmp_path_factory):
+    data, n = _linear_cohort()
+    sim = _linear_sim()
+    params = sim.init(jax.random.key(0))
+    chosen = np.asarray([0, 2, 3, 5, 1])
+
+    def two_rounds():
+        first = sim.run_round(params, data, n, jax.random.key(1),
+                              wave_size=4, client_indices=chosen)
+        return sim.run_round(first.params, data, n, jax.random.key(2),
+                             wave_size=4, client_indices=chosen).params
+
+    _, spans, executions = _profiled(
+        two_rounds, str(tmp_path_factory.mktemp("trace_select")))
+    prepares = [s for s in spans if s[0] == "baton.round.prepare"]
+    selects = [s for s in spans if s[0] == "baton.round.prepare.select"]
+    assert len(prepares) == len(selects) == 2
+    for prepare, select in zip(prepares, selects):
+        assert prepare[1] <= select[1] and select[2] <= prepare[2]
+        # the three takes (x, y, n_samples) and the indices' own cast
+        if executions:
+            assert _executed_inside(select, executions) >= 3
 
 
 def test_session_spans_nest_in_their_round_in_order(session):
@@ -220,11 +262,24 @@ def test_session_spans_nest_in_their_round_in_order(session):
     for _, r0, r1, _ in rounds:
         inner = [s for s in session["spans"]
                  if s[0] != "baton.round" and r0 <= s[1] and s[2] <= r1]
-        assert [s[0].rsplit(".", 1)[1] for s in inner] == [
-            "prepare", "stage", "dispatch", "stage", "dispatch", "fold",
-            "sync", "record", "update"]
-        # siblings: each ends before the next starts
-        assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+        assert [s[0][len("baton.round."):] for s in inner] == [
+            "prepare", "prepare.keys",
+            "stage", "dispatch", "dispatch.launch", "dispatch.accumulate",
+            "stage", "dispatch", "dispatch.launch", "dispatch.accumulate",
+            "fold", "sync", "record", "update"]
+        # a child lies inside the span it is named after, and of two
+        # spans of one parent each ends before the next starts
+        for i, b in enumerate(inner):
+            parent = b[0].rsplit(".", 1)[0]
+            if parent != "baton.round":
+                a = [s for s in inner[:i] if s[0] == parent][-1]
+                assert a[1] <= b[1] and b[2] <= a[2]
+        for depth in (3, 4):
+            level = [s for s in inner if s[0].count(".") + 1 == depth]
+            assert all(a[2] <= b[1] for a, b in zip(level, level[1:]))
+        # stage, sync, record and fold keep their idle: no child under them
+        assert not [s for s in inner if s[0].count(".") > 2
+                    and s[0].split(".")[2] not in ("prepare", "dispatch")]
     inside_a_round = sum(r0 <= s[1] and s[2] <= r1
                          for s in session["spans"] if s[0] != "baton.round"
                          for _, r0, r1, _ in rounds)
@@ -233,13 +288,49 @@ def test_session_spans_nest_in_their_round_in_order(session):
 
 def test_session_stage_counts_the_phantom_clients(session):
     stages = [s[3] for s in session["spans"] if s[0] == "baton.round.stage"]
-    # batch 4 divides the 8 rows the largest client fills: none is cut
+    # batch 4 divides the 8 rows the largest client fills: no row is cut
     assert stages == [
         {"wave": 0, "real": 4, "padded": 0, "rows": 8, "capacity": 8},
         {"wave": 1, "real": 2, "padded": 2, "rows": 8, "capacity": 8}] * 2
     dispatches = [s[3] for s in session["spans"]
                   if s[0] == "baton.round.dispatch"]
     assert dispatches == [{"wave": 0}, {"wave": 1}] * 2
+
+
+def test_session_launch_says_what_it_handed_over(session):
+    launches = [s[3] for s in session["spans"]
+                if s[0] == "baton.round.dispatch.launch"]
+    # w and b, x and y, n_samples, the keys
+    assert [(a["wave"], a["leaves"]) for a in launches] == [(0, 6), (1, 6)] * 2
+    # a call that hits the jit's fast path adds no entry: the second
+    # round's count is the first's
+    entries = [a["cache_entries"] for a in launches]
+    assert entries[0] >= 1 and len(set(entries)) == 1
+
+
+def test_session_launches_are_the_runtimes_own_by_span(session):
+    """What a span launched is read from the runtime's own events inside
+    it, not from an attribute: the spans carry no count of programs."""
+    accumulates = [s for s in session["spans"]
+                   if s[0] == "baton.round.dispatch.accumulate"]
+    assert [s[3] for s in accumulates] == [{"wave": 0}, {"wave": 1}] * 2
+    if not session["executions"]:
+        pytest.skip(f"this runtime's trace has no {EXECUTE} event")
+    executions = session["executions"]
+    # wave 0 adds to nothing and its losses are whole; wave 1:
+    # _acc_tree_add, the two adds, and the slice of the padded losses
+    assert [_executed_inside(s, executions) for s in accumulates] == [
+        0, 4] * 2
+    stages = [s for s in session["spans"] if s[0] == "baton.round.stage"]
+    # wave 0 is cut from the cohort (x, y, n_samples, the keys: several
+    # programs on a typed key array); wave 1 is cut and padded too
+    inside = [_executed_inside(s, executions) for s in stages]
+    assert inside[0] >= 4 and inside[1] > inside[0]
+    assert inside[2:] == inside[:2]
+    for span in session["spans"]:
+        if span[0] == "baton.round.dispatch.launch":
+            # the jitted call and nothing else
+            assert _executed_inside(span, executions) == 1
 
 
 def test_session_stage_says_the_rows_it_staged(tmp_path_factory):
@@ -252,7 +343,7 @@ def test_session_stage_says_the_rows_it_staged(tmp_path_factory):
     sim = FedSim(linear_regression_model(4), batch_size=32,
                  learning_rate=0.05)
     params = sim.init(jax.random.key(0))
-    _, spans = _profiled(
+    _, spans, _ = _profiled(
         lambda: sim.run_round(params, data, n, jax.random.key(1),
                               wave_size=4).params,
         str(tmp_path_factory.mktemp("trace_rows")))
@@ -373,11 +464,25 @@ def test_every_path_opens_and_closes_its_spans(recorder, path):
     assert np.isfinite(np.asarray(res.loss_history)).all()
     assert recorder.open == []
     counts = collections.Counter(name for name, _ in recorder.opened)
-    assert counts == {"baton.round": 1, "baton.round.prepare": 1,
-                      "baton.round.stage": waves,
-                      "baton.round.dispatch": waves, "baton.round.sync": 1,
-                      "baton.round.record": 1, "baton.round.fold": 1,
-                      "baton.round.update": 1}
+    expected = {"baton.round": 1, "baton.round.prepare": 1,
+                "baton.round.prepare.keys": 1,
+                "baton.round.stage": waves,
+                "baton.round.dispatch": waves,
+                "baton.round.dispatch.launch": waves,
+                "baton.round.dispatch.accumulate": waves,
+                "baton.round.sync": 1,
+                "baton.round.record": 1, "baton.round.fold": 1,
+                "baton.round.update": 1}
+    if "client_indices" in round_kw:
+        expected["baton.round.prepare.select"] = 1
+    assert counts == expected
+    for nm, a in recorder.opened:  # every launch and accumulate is whole
+        if nm == "baton.round.dispatch.launch":
+            assert a["leaves"] == 6 and a["cache_entries"] >= 1
+        if nm == "baton.round.dispatch.accumulate":
+            assert set(a) == {"wave"}
+        if nm == "baton.round.stage":
+            assert set(a) == {"wave", "real", "padded", "rows", "capacity"}
     name, attrs = recorder.opened[0]
     assert name == "baton.round" and attrs["clients"] == clients
     assert attrs["waves"] == waves
@@ -385,14 +490,133 @@ def test_every_path_opens_and_closes_its_spans(recorder, path):
                if nm == "baton.round.stage") == clients
 
 
-@pytest.mark.parametrize("where,sim_kw,round_kw,error", [
-    ("prepare", {}, {"client_indices": np.asarray([99])}, IndexError),
-    ("prepare", {"aggregator": "median"}, {"wave_size": "auto"},
+# the eager programs inside ``accumulate`` by path, a wave, as the CPU
+# runtime counts them on one device. 6 clients in waves of 4
+LAUNCHED = {
+    # wave 0 adds to nothing and its losses are whole; wave 1's sums are
+    # added to wave 0's (_acc_tree_add, the loss, the weight) and its
+    # padded losses cut
+    "mean": ({}, {}, [0, 4]),
+    "mean_without_client_losses": (
+        {}, {"collect_client_losses": False}, [0, 3]),
+    # the whole cohort: nothing is added and nothing cut
+    "one_wave": ({}, {"wave_size": None}, [0]),
+    # a cast, a product and a sum a wave; wave 1 adds the loss and the
+    # weight and cuts w, b, n_samples and the losses (twice: the
+    # product's and the clients')
+    "robust": ({"aggregator": "median"}, {}, [3, 10]),
+    # 5 chosen clients: three phantoms in wave 1
+    "phantom_clients": (
+        {}, {"client_indices": np.asarray([0, 2, 3, 5, 1])}, [0, 4]),
+}
+
+
+@pytest.mark.parametrize("path", sorted(LAUNCHED))
+def test_launch_and_accumulate_bound_what_the_runtime_executes(
+        tmp_path, path):
+    """``launch`` holds the jitted call and nothing else, and
+    ``accumulate`` the eager programs after it, on every path: counted
+    from the runtime's own events inside each span."""
+    sim_kw, round_kw, waves = LAUNCHED[path]
+    data, n = _linear_cohort()
+    sim = _linear_sim(**sim_kw)
+    params = sim.init(jax.random.key(0))
+    kwargs = {"wave_size": 4, **round_kw}
+    sim.run_round(params, data, n, jax.random.key(1), **kwargs)
+    _, spans, executions = _profiled(
+        lambda: sim.run_round(params, data, n, jax.random.key(2),
+                              **kwargs).params, str(tmp_path))
+    if not executions:
+        pytest.skip(f"this runtime's trace has no {EXECUTE} event")
+    inside = {name: [_executed_inside(s, executions)
+                     for s in spans if s[0] == name]
+              for name in ("baton.round.dispatch.launch",
+                           "baton.round.dispatch.accumulate")}
+    assert inside == {"baton.round.dispatch.launch": [1] * len(waves),
+                      "baton.round.dispatch.accumulate": waves}
+
+
+@pytest.mark.parametrize("partition", [False, True])
+def test_outside_a_session_no_parameter_tree_is_walked_after_round_one(
+        monkeypatch, partition):
+    """The spans' byte and leaf counts are shapes, and shapes do not
+    change: a ``FedSim`` walks its parameter trees in its first round
+    and keeps what it found."""
+    walked = []
+    tree_bytes = engine._tree_bytes
+    monkeypatch.setattr(engine, "_tree_bytes",
+                        lambda tree: walked.append(1) or tree_bytes(tree))
+    data, n = _linear_cohort()
+    sim = _linear_sim(trainable=(lambda path, leaf: path == "w")
+                      if partition else None)
+    params = sim.init(jax.random.key(0))
+    first = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
+    assert len(walked) == 2  # the trainable tree and the frozen one
+    second = sim.run_round(first.params, data, n, jax.random.key(2),
+                           wave_size=4)
+    sim.run_round(second.params, data, n, jax.random.key(3), wave_size=None)
+    assert len(walked) == 2
+
+
+def test_the_cached_shapes_are_the_trees_own(recorder):
+    data, n = _linear_cohort()
+    sim = _linear_sim(trainable=lambda path, leaf: path == "w")
+    params = sim.init(jax.random.key(0))
+    for key in (1, 2):
+        params = sim.run_round(params, data, n, jax.random.key(key),
+                               wave_size=4).params
+    rounds = [a for nm, a in recorder.opened if nm == "baton.round"]
+    # w is four float32 a client, b one float32 held once
+    assert [(a["trainable_bytes"], a["frozen_bytes"]) for a in rounds] == [
+        (16, 4)] * 2
+    assert {a["leaves"] for nm, a in recorder.opened
+            if nm == "baton.round.dispatch.launch"} == {6}
+
+
+def test_trees_of_another_structure_are_walked_anew(monkeypatch):
+    """The kept shapes are of one structure of the two trees: a tree
+    with other leaves is walked again, and is never read as the last."""
+    walked = []
+    tree_bytes = engine._tree_bytes
+    monkeypatch.setattr(engine, "_tree_bytes",
+                        lambda tree: walked.append(1) or tree_bytes(tree))
+    sim = _linear_sim()
+    base = {"w": jnp.zeros((4, 1)), "b": jnp.zeros((1,))}
+    adapted = {**base, "a": jnp.zeros((4, 2), jnp.bfloat16)}
+    assert sim._param_shapes(base, {}) == (2, 20, 0)
+    assert sim._param_shapes(base, {}) == (2, 20, 0)
+    assert len(walked) == 2
+    assert sim._param_shapes(adapted, {}) == (3, 36, 0)
+    assert sim._param_shapes([base["w"]], [base["b"]]) == (2, 16, 4)
+    assert len(walked) == 6
+
+
+def test_a_wave_program_that_is_no_jit_object_runs_the_round(recorder,
+                                                             monkeypatch):
+    """``cache_entries`` is the jitted program's own count where it has
+    one: a wave program without it runs the round all the same."""
+    data, n = _linear_cohort()
+    sim = _linear_sim()
+    params = sim.init(jax.random.key(0))
+    jitted, bind = sim._wave_program(1, robust=False)
+    monkeypatch.setattr(
+        sim, "_wave_program",
+        lambda n_epochs, robust: (lambda *args: jitted(*args), bind))
+    res = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
+    assert np.isfinite(np.asarray(res.loss_history)).all()
+    launches = [a for nm, a in recorder.opened
+                if nm == "baton.round.dispatch.launch"]
+    assert [set(a) for a in launches] == [{"wave", "leaves"}] * 2
+
+
+@pytest.mark.parametrize("last_opened,sim_kw,round_kw,error", [
+    ("prepare.select", {}, {"client_indices": np.asarray([99])}, IndexError),
+    ("prepare.keys", {"aggregator": "median"}, {"wave_size": "auto"},
      NotImplementedError),
-    ("dispatch", {}, {"progress_fn": _boom}, RuntimeError),
+    ("dispatch.accumulate", {}, {"progress_fn": _boom}, RuntimeError),
 ])
-def test_an_exception_leaves_no_span_open(recorder, where, sim_kw, round_kw,
-                                          error):
+def test_an_exception_leaves_no_span_open(recorder, last_opened, sim_kw,
+                                          round_kw, error):
     data, n = _linear_cohort()
     sim = _linear_sim(**sim_kw)
     params = sim.init(jax.random.key(0))
@@ -401,7 +625,27 @@ def test_an_exception_leaves_no_span_open(recorder, where, sim_kw, round_kw,
     # had clamped): the round's rows are read from n_samples there
     with pytest.raises(error):
         sim.run_round(params, data, n, jax.random.key(1), **kwargs)
-    assert recorder.opened[-1][0] == f"baton.round.{where}"
+    assert recorder.opened[-1][0] == f"baton.round.{last_opened}"
+    assert recorder.open == []
+
+
+def test_a_wave_program_that_raises_leaves_no_span_open(recorder,
+                                                        monkeypatch):
+    data, n = _linear_cohort()
+    sim = _linear_sim()
+    params = sim.init(jax.random.key(0))
+    _, bind = sim._wave_program(1, robust=False)
+
+    def program(*args):
+        raise FloatingPointError("the wave program failed")
+
+    monkeypatch.setattr(sim, "_wave_program",
+                        lambda n_epochs, robust: (program, bind))
+    with pytest.raises(FloatingPointError):
+        sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
+    assert [name for name, _ in recorder.opened][-2:] == [
+        "baton.round.dispatch", "baton.round.dispatch.launch"]
+    assert "cache_entries" not in recorder.opened[-1][1]
     assert recorder.open == []
 
 
