@@ -197,6 +197,11 @@ class CompileTracker:
         self._sigs: Dict[Any, set] = {}
         self._recent: Dict[Any, deque] = {}
 
+    def seen(self, key: Any, signature: Any) -> bool:
+        """Whether ``observe`` has had ``signature`` for ``key``: a call
+        with one it has not had will compile."""
+        return signature in self._sigs.get(key, ())
+
     def observe(
         self,
         key: Any,
@@ -265,9 +270,12 @@ def build_record(
     peak_hbm_gb: Optional[float] = None,
     peak_hbm_source: Optional[str] = None,
     peak_hbm_reason: Optional[str] = None,
+    train_s_source: Optional[str] = None,
 ) -> dict:
     """Assemble one round's compute record, deriving throughput and MFU
-    and enforcing the null-with-reason invariant by construction."""
+    and enforcing the null-with-reason invariant by construction.
+    ``train_s_source`` says how ``train_s`` was taken, where the caller
+    has more than one way (the engine: ``FedSim._settle``)."""
     train_s = float(train_s)
     n_chips = max(1, int(n_chips))
     rec: dict = {
@@ -276,6 +284,8 @@ def build_record(
         "n_chips": n_chips,
         "device_kind": device_kind,
     }
+    if train_s_source:
+        rec["train_s_source"] = train_s_source
     if model_family is not None:
         rec["model_family"] = model_family
     else:
@@ -381,10 +391,12 @@ class ComputeProbe:
         steps: Optional[int] = None,
         device: Any = None,
         n_chips: int = 1,
+        train_s_source: Optional[str] = None,
     ) -> dict:
         """``n_chips`` is the number of devices the timed work ran on —
         one unless the caller spread it over a mesh — not the number the
-        host has: throughput per chip divides by it."""
+        host has: throughput per chip divides by it. ``train_s_source``
+        goes into the record beside ``train_s``."""
         if device is not None:
             dev = device
         else:
@@ -408,6 +420,7 @@ class ComputeProbe:
             peak_hbm_gb=hbm_gb,
             peak_hbm_source=hbm_src,
             peak_hbm_reason=hbm_why,
+            train_s_source=train_s_source,
         )
 
 

@@ -62,6 +62,21 @@ from baton_tpu.utils.profiling import (
 
 Params = Any
 
+# The size of FedSim.run_round's interpreter frame, in words (locals,
+# cells and operand stack). CPython 3.12 keeps a thread's frames in 16 KiB
+# chunks and maps a new chunk, and unmaps it again, on every call made
+# from a frame that ends at a chunk's edge. JAX lowers a wave program by
+# a recursion some 80 frames deep under the launch, so where its loops
+# fall relative to those edges depends on the size of every frame above
+# them, run_round's among them: at 76 words and more the shard_map
+# program's lowering made 17,800 such calls where at 74 it makes 3,400,
+# 2.7 s more for each of the two warm-up rounds of resnet18_c128_mesh4
+# on the chip's host (PERF.md section 6, PR 37;
+# scripts/lowering_faults/ counts them on the CPU). tests/
+# test_round_settle.py holds run_round to this size: a change that needs
+# another local counts the faults again and moves the number with it.
+RUN_ROUND_FRAME_WORDS = 74
+
 # Rows are staged in granules of a quarter batch, so that a ragged
 # federation whose largest client changes from round to round compiles
 # at most 4 x capacity / batch_size wave programs.
@@ -78,6 +93,29 @@ class RoundResult:
     client_losses: Optional[jax.Array]  # [C, n_epochs]
     n_samples_total: jax.Array
     server_opt_state: Any = None
+
+
+@dataclasses.dataclass
+class _PendingRound:
+    """A round whose programs are queued and that no one has waited for
+    yet: what :meth:`FedSim._settle` waits on, and what the round's
+    compute record is written from."""
+
+    index: int  # counted from the FedSim's first round, which is 1
+    loss_sum: jax.Array  # ready when the round's waves are done
+    t_waves0: float  # host clock, before the first wave was staged
+    record: Dict[str, Any]  # ComputeProbe.record_round's, but the times
+    # a launch of this round compiled or loaded a program, or its shape
+    # is new to the compute tracker: the round settles itself
+    own: bool
+
+
+def _cache_entries(program) -> Optional[int]:
+    """The jit's fast-path entries, where ``program`` counts them (a
+    jitted function does). A count that a call left larger is a call
+    that traced and then compiled, or loaded from the compile cache."""
+    entries = getattr(program, "_cache_size", None)
+    return None if entries is None else entries()
 
 
 def client_eval_sums(model: FedModel, params, d, n, r):
@@ -169,15 +207,99 @@ class FedSim:
         # params seen (structure unknown until then).
         self.trainable_predicate = trainable
         self.partition = None
-        # compute-plane probe: run_round leaves its per-round compute
-        # record (MFU/compile/HBM, null-with-reason) in ``last_compute``
-        # for the caller (the manager's simulated-cohort path ships it
-        # into the round's SLO record). Costs one scalar sync per round.
+        # compute-plane probe: every round leaves its compute record
+        # (MFU/compile/HBM, null-with-reason) in ``last_compute`` for
+        # the caller (the manager's simulated-cohort path ships it into
+        # the round's SLO record). The record needs the round's end. A
+        # round whose launches all hit the jit's fast path does not wait
+        # for its own: it stays in ``_pending`` until the next run_round
+        # has queued its programs, or until ``last_compute`` is read
+        # (:meth:`_settle`). A round that compiled or loaded a program
+        # waits at its own end. One scalar sync per round either way, a
+        # round late in a steady run.
         self.compute_probe = ComputeProbe(model=model)
-        self.last_compute: Optional[dict] = None
+        self._last_compute: Optional[dict] = None
+        self._pending: Optional[_PendingRound] = None
+        self._rounds_queued = 0
+        # the last settled round's end as the host saw it (perf_counter)
+        self._last_done = float("-inf")
         self._fold_program = self._make_fold_program()
         # _param_shapes' result and the tree structures it is of
         self._param_shape_attrs: Optional[tuple] = None
+
+    @property
+    def last_compute(self) -> Optional[dict]:
+        """The compute record of the newest round this ``FedSim`` ran
+        (``None`` before the first). Reading it waits for that round if
+        nothing has yet, and raises that round's device error if it had
+        one."""
+        self._settle()
+        return self._last_compute
+
+    def _pend(self, loss_sum, t_waves0, own, signature, n_host, n_epochs,
+              c, rows) -> None:
+        """Keep the round just queued as the one pending round (its
+        programs are on the device's queue; nothing has waited for it).
+
+        A method of its own so that :meth:`run_round`'s frame stays the
+        size it was (``RUN_ROUND_FRAME_WORDS``): building the record
+        inline deepens that frame's operand stack, and everything JAX
+        traces and lowers under a launch then sits elsewhere on the
+        interpreter's data stack (PERF.md section 6, PR 37)."""
+        self._rounds_queued += 1
+        self._pending = _PendingRound(
+            index=self._rounds_queued,
+            loss_sum=loss_sum,
+            t_waves0=t_waves0,
+            record=dict(
+                key="run_round",
+                signature=signature,
+                n_samples=float(n_host.sum()),
+                n_epochs=n_epochs,
+                steps=c * self.trainer.steps_per_round(rows, n_epochs),
+                n_chips=(int(self.mesh.devices.size)
+                         if self.mesh is not None else 1),
+            ),
+            own=own)
+
+    def _settle(self) -> None:
+        """Wait for the pending round, if there is one, and write its
+        compute record: the one sync a round has. Called by the next
+        :meth:`run_round` once its own waves and fold are queued, so the
+        device goes from one round into the next; by a round that
+        compiled or loaded a program, for itself, before it returns
+        (``own``); and by a read of ``last_compute``. The pending round
+        is forgotten before the wait: an error the wait raises is raised
+        once.
+
+        ``train_s`` is the round's end as the host saw it less the later
+        of its own start and the end of the round before it: the host
+        runs a round ahead, and a wall time from the round's own start
+        would count the wave before it twice. For a round that settles
+        itself with nothing pending before it, which every round that
+        compiled is, that is its own wall time. Where the loss sum was
+        ready when the host arrived the round ended earlier than it was
+        seen to, and ``train_s_source`` says that the time is an upper
+        bound."""
+        pending, self._pending = self._pending, None
+        if pending is None:
+            return
+        ready = pending.loss_sum.is_ready()
+        # own 0 and ready 0 on every round of a run: the host's head is
+        # hidden behind the wave before it and the chip sets the pace;
+        # ready 1: the host does
+        with annotate("baton.round.sync", settles=pending.index,
+                      ready=int(ready), own=int(pending.own)):
+            jax.block_until_ready(pending.loss_sum)
+        done = time.perf_counter()
+        train_s = done - max(pending.t_waves0, self._last_done)
+        self._last_done = done
+        with annotate("baton.round.record"):
+            self._last_compute = self.compute_probe.record_round(
+                train_s=train_s,
+                train_s_source=("found_ready_upper_bound" if ready
+                                else "host_waited"),
+                **pending.record)
 
     def _ensure_partition(self, params):
         if self.trainable_predicate is None or self.partition is not None:
@@ -642,183 +764,223 @@ class FedSim:
         ``wave_size="auto"`` sizes waves from XLA's static memory plan
         (:meth:`auto_wave_size`); the decision is cached per cohort
         shape, so repeated rounds pay the plan compiles once.
+
+        **A steady round does not wait for itself.** A round whose
+        launches all hit the jit's fast path returns when its waves and
+        fold are on the device's queue, with a :class:`RoundResult` of
+        arrays the device has yet to fill, and the next ``run_round`` on
+        this ``FedSim`` waits for it only after queueing its own programs
+        (:meth:`_settle`): called back to back with the parameters fed
+        forward, the device goes from one round into the next and the
+        host is never more than a round ahead of it. A caller that reads
+        a result, or ``last_compute``, waits there. A round in which a
+        launch compiled or loaded a program (its ``cache_entries`` grew),
+        or whose shape the compute tracker had not seen, waits for itself
+        before it returns and leaves nothing pending: nothing is queued
+        behind the first execution of a program just loaded, and
+        ``compile_s`` is that round's own wall time; a shape new to the
+        tracker also settles the round before it ahead of its own first
+        wave, since what follows compiles. So **a device error of a
+        steady round is raised where the round is settled**: in the next
+        ``run_round`` (whose own round is then lost with it), at a read
+        of ``last_compute``, or wherever the result is fetched; one of a
+        round that settles itself is raised here. An error on the host
+        is raised here, as ever, after the round before this one is
+        settled, and nothing of this round stays pending.
         """
         with annotate("baton.round") as round_span:
-            with annotate("baton.round.prepare"):
-                orig_params = params
-                params, frozen = self._split(params)
-                # the round's one host copy of n_samples (a fetch only
-                # where the caller brought a device array): the rows to
-                # stage here, the compute record after the sync
-                n_host = np.asarray(n_samples)
-                n_samples = jnp.asarray(n_samples)
-                if client_indices is not None:
-                    with annotate("baton.round.prepare.select"):
-                        idx = jnp.asarray(client_indices)
-                        data = jax.tree_util.tree_map(
-                            lambda a: jnp.take(a, idx, axis=0), data)
-                        n_samples = jnp.take(n_samples, idx, axis=0)
-                    n_host = n_host[np.asarray(client_indices)]
-                c = int(n_samples.shape[0])
-                with annotate("baton.round.prepare.keys"):
-                    rngs = jax.random.split(rng, c)
-                rows, capacity = self._rows_to_stage(data, n_host)
+            try:
+                with annotate("baton.round.prepare"):
+                    orig_params = params
+                    params, frozen = self._split(params)
+                    # the round's one host copy of n_samples (a fetch only
+                    # where the caller brought a device array): the rows to
+                    # stage here, the compute record after the sync
+                    n_host = np.asarray(n_samples)
+                    n_samples = jnp.asarray(n_samples)
+                    if client_indices is not None:
+                        with annotate("baton.round.prepare.select"):
+                            idx = jnp.asarray(client_indices)
+                            data = jax.tree_util.tree_map(
+                                lambda a: jnp.take(a, idx, axis=0), data)
+                            n_samples = jnp.take(n_samples, idx, axis=0)
+                        n_host = n_host[np.asarray(client_indices)]
+                    c = int(n_samples.shape[0])
+                    with annotate("baton.round.prepare.keys"):
+                        rngs = jax.random.split(rng, c)
+                    rows, capacity = self._rows_to_stage(data, n_host)
 
-                if wave_size == "auto":
-                    cache_key = (
-                        c, n_epochs, rows,
-                        tuple(sorted((k, v.shape, str(v.dtype))
-                                     for k, v in data.items())),
-                    )
-                    cache = getattr(self, "_auto_wave_cache", None)
-                    if cache is None:
-                        cache = self._auto_wave_cache = {}
-                    if cache_key not in cache:
-                        cache[cache_key] = self.auto_wave_size(
-                            orig_params, data, n_samples, n_epochs=n_epochs)
-                    wave_size = cache[cache_key]
-                wave_size = self._resolve_wave_size(wave_size, c)
-
-                robust = self.aggregator[0] != "mean"
-                if robust and self.is_hybrid:
-                    raise NotImplementedError(
-                        "robust aggregators need per-client params stacked "
-                        "along the client axis; the hybrid clients x model "
-                        "mesh shards params over 'model' — run robust rounds "
-                        "on a pure clients mesh"
-                    )
-                if self.is_hybrid:
-                    # hybrid clients×model mesh: plain jit + GSPMD (see
-                    # _place_hybrid) — shard_map would force manual TP
-                    # collectives
-                    params, frozen = self._place_hybrid(params, frozen)
-                program, bind = self._wave_program(n_epochs, robust)
-                in_shard = (client_sharding(self.mesh)
-                            if self.mesh is not None else None)
-                n_waves = -(-c // wave_size)
-                # shapes only, no device fetch: what a round holds once
-                # for all clients, and what it holds (and folds) a client
-                param_leaves, trainable_bytes, frozen_bytes = (
-                    self._param_shapes(params, frozen))
-                round_span.set_metadata(
-                    clients=c, waves=n_waves, wave_size=int(wave_size),
-                    frozen_bytes=frozen_bytes,
-                    trainable_bytes=trainable_bytes,
-                    **dict(self.model.span_attrs))
-                # the arrays a wave program is handed: both parameter
-                # trees, the wave's data, its n_samples and its keys
-                launch_leaves = (
-                    param_leaves
-                    + jax.tree_util.tree_structure(data).num_leaves + 2)
-
-            psum_acc = None
-            lsum_acc = None
-            w_acc = None
-            stacked_parts = [] if robust else None
-            per_client = [] if collect_client_losses else None
-            t_waves0 = time.perf_counter()
-            for wave, start in enumerate(range(0, c, wave_size)):
-                stop = min(start + wave_size, c)
-                real = stop - start
-                with annotate("baton.round.stage", wave=wave, real=real,
-                              padded=wave_size - real, rows=rows,
-                              capacity=capacity):
-                    d, n, r = self._stage_wave(
-                        data, n_samples, rngs, start, stop, wave_size,
-                        in_shard, rows)
-                with annotate("baton.round.dispatch", wave=wave):
-                    args = bind(params, frozen, d, n, r)
-                    with annotate("baton.round.dispatch.launch", wave=wave,
-                                  leaves=launch_leaves) as launch_span:
-                        out = program(*args)
-                        # the jit's fast-path entries, where the program
-                        # counts them: a count that grows from round to
-                        # round is a call that misses the fast path though
-                        # nothing compiles
-                        entries = getattr(program, "_cache_size", None)
-                        if entries is not None:
-                            launch_span.set_metadata(cache_entries=entries())
-                    with annotate("baton.round.dispatch.accumulate",
-                                  wave=wave):
-                        if robust:
-                            cp, closs = out
-                            stacked_parts.append(
-                                jax.tree_util.tree_map(lambda a: a[:real], cp)
-                            )
-                            w_wave = n[:real].astype(jnp.float32)
-                            lsum = jnp.tensordot(
-                                w_wave, closs[:real].astype(jnp.float32),
-                                axes=(0, 0))
-                            wtot = jnp.sum(w_wave)
-                        else:
-                            psum, lsum, wtot, closs = out
-                            psum_acc = (
-                                psum if psum_acc is None
-                                else _acc_tree_add(psum_acc, psum)
-                            )
-                        lsum_acc = (lsum if lsum_acc is None
-                                    else lsum_acc + lsum)
-                        w_acc = wtot if w_acc is None else w_acc + wtot
-                        if per_client is not None:
-                            per_client.append(closs[:real])
-                    if progress_fn is not None:
-                        jax.block_until_ready(lsum)
-                        progress_fn(wave + 1, n_waves)
-
-            # The fold goes to the device before the host waits: it is
-            # queued behind the last wave, so the chip runs it the moment
-            # the wave ends instead of idling while the host dispatches.
-            with annotate("baton.round.fold") as fold_span:
-                if robust:
-                    stacked = jax.tree_util.tree_map(
-                        lambda *xs: jnp.concatenate(xs, axis=0),
-                        *stacked_parts
-                    )
-                    new_params = agg.aggregate_stacked(
-                        self.aggregator, stacked, n_samples, params
-                    )
-                    loss_history = lsum_acc / jnp.maximum(w_acc, 1e-9)
-                    if self.server_optimizer is not None:
-                        new_params, server_opt_state = _server_update(
-                            self.server_optimizer, params, new_params,
-                            server_opt_state
+                    if wave_size == "auto":
+                        cache_key = (
+                            c, n_epochs, rows,
+                            tuple(sorted((k, v.shape, str(v.dtype))
+                                         for k, v in data.items())),
                         )
-                else:
-                    new_params, server_opt_state, loss_history = (
-                        self._fold_program(psum_acc, w_acc, lsum_acc,
-                                           params, server_opt_state))
-                    fold_span.set_metadata(programs=1)
+                        cache = getattr(self, "_auto_wave_cache", None)
+                        if cache is None:
+                            cache = self._auto_wave_cache = {}
+                        if cache_key not in cache:
+                            cache[cache_key] = self.auto_wave_size(
+                                orig_params, data, n_samples,
+                                n_epochs=n_epochs)
+                        wave_size = cache[cache_key]
+                    wave_size = self._resolve_wave_size(wave_size, c)
+
+                    robust = self.aggregator[0] != "mean"
+                    if robust and self.is_hybrid:
+                        raise NotImplementedError(
+                            "robust aggregators need per-client params "
+                            "stacked along the client axis; the hybrid "
+                            "clients x model mesh shards params over "
+                            "'model' — run robust rounds on a pure clients "
+                            "mesh"
+                        )
+                    if self.is_hybrid:
+                        # hybrid clients×model mesh: plain jit + GSPMD (see
+                        # _place_hybrid) — shard_map would force manual TP
+                        # collectives
+                        params, frozen = self._place_hybrid(params, frozen)
+                    program, bind = self._wave_program(n_epochs, robust)
+                    in_shard = (client_sharding(self.mesh)
+                                if self.mesh is not None else None)
+                    n_waves = -(-c // wave_size)
+                    # shapes only, no device fetch: what a round holds once
+                    # for all clients, and what it holds (and folds) a client
+                    param_leaves, trainable_bytes, frozen_bytes = (
+                        self._param_shapes(params, frozen))
+                    round_span.set_metadata(
+                        clients=c, waves=n_waves, wave_size=int(wave_size),
+                        frozen_bytes=frozen_bytes,
+                        trainable_bytes=trainable_bytes,
+                        **dict(self.model.span_attrs))
+                    # the arrays a wave program is handed: both parameter
+                    # trees, the wave's data, its n_samples and its keys
+                    launch_leaves = (
+                        param_leaves
+                        + jax.tree_util.tree_structure(data).num_leaves + 2)
+                    signature = (
+                        c, int(wave_size), int(n_epochs), robust, rows,
+                        tuple(sorted((k, tuple(v.shape), str(v.dtype))
+                                     for k, v in data.items())))
+
+                # A launch that compiles or loads its program makes this
+                # round settle itself (below). The tracker knows a new
+                # shape before anything is launched: such a round waits
+                # for the round before it here, ahead of the compile, so
+                # its own wall time is its own.
+                missed = not self.compute_probe.tracker.seen(
+                    "run_round", signature)
+                if missed:
+                    self._settle()
+
+                psum_acc = None
+                lsum_acc = None
+                w_acc = None
+                stacked_parts = [] if robust else None
+                per_client = [] if collect_client_losses else None
+                t_waves0 = time.perf_counter()
+                for wave, start in enumerate(range(0, c, wave_size)):
+                    stop = min(start + wave_size, c)
+                    real = stop - start
+                    with annotate("baton.round.stage", wave=wave, real=real,
+                                  padded=wave_size - real, rows=rows,
+                                  capacity=capacity):
+                        d, n, r = self._stage_wave(
+                            data, n_samples, rngs, start, stop, wave_size,
+                            in_shard, rows)
+                    with annotate("baton.round.dispatch", wave=wave):
+                        args = bind(params, frozen, d, n, r)
+                        with annotate("baton.round.dispatch.launch", wave=wave,
+                                      leaves=launch_leaves) as launch_span:
+                            entries = _cache_entries(program)
+                            out = program(*args)
+                            # a count that the call left larger is a call
+                            # that missed the fast path: it compiled, or
+                            # loaded from the compile cache (read twice and
+                            # kept in no local: RUN_ROUND_FRAME_WORDS)
+                            if entries is not None:
+                                missed |= _cache_entries(program) > entries
+                                launch_span.set_metadata(
+                                    cache_entries=_cache_entries(program))
+                        with annotate("baton.round.dispatch.accumulate",
+                                      wave=wave):
+                            if robust:
+                                cp, closs = out
+                                stacked_parts.append(jax.tree_util.tree_map(
+                                    lambda a: a[:real], cp))
+                                w_wave = n[:real].astype(jnp.float32)
+                                lsum = jnp.tensordot(
+                                    w_wave, closs[:real].astype(jnp.float32),
+                                    axes=(0, 0))
+                                wtot = jnp.sum(w_wave)
+                            else:
+                                psum, lsum, wtot, closs = out
+                                psum_acc = (
+                                    psum if psum_acc is None
+                                    else _acc_tree_add(psum_acc, psum)
+                                )
+                            lsum_acc = (lsum if lsum_acc is None
+                                        else lsum_acc + lsum)
+                            w_acc = wtot if w_acc is None else w_acc + wtot
+                            if per_client is not None:
+                                per_client.append(closs[:real])
+                        if progress_fn is not None:
+                            jax.block_until_ready(lsum)
+                            progress_fn(wave + 1, n_waves)
+
+                # The fold goes to the device before the host waits: it is
+                # queued behind the last wave, so the chip runs it the moment
+                # the wave ends instead of idling while the host dispatches.
+                with annotate("baton.round.fold") as fold_span:
+                    if robust:
+                        stacked = jax.tree_util.tree_map(
+                            lambda *xs: jnp.concatenate(xs, axis=0),
+                            *stacked_parts
+                        )
+                        new_params = agg.aggregate_stacked(
+                            self.aggregator, stacked, n_samples, params
+                        )
+                        loss_history = lsum_acc / jnp.maximum(w_acc, 1e-9)
+                        if self.server_optimizer is not None:
+                            new_params, server_opt_state = _server_update(
+                                self.server_optimizer, params, new_params,
+                                server_opt_state
+                            )
+                    else:
+                        entries = _cache_entries(self._fold_program)
+                        new_params, server_opt_state, loss_history = (
+                            self._fold_program(psum_acc, w_acc, lsum_acc,
+                                               params, server_opt_state))
+                        fold_span.set_metadata(programs=1)
+                        if entries is not None:
+                            missed |= (
+                                _cache_entries(self._fold_program) > entries)
+
+            finally:
+                # The one wait of a steady round is for the round before
+                # it, and comes after this round's waves and fold are
+                # queued: the device has its next programs before the
+                # running wave ends, and the host is never more than a
+                # round ahead of it. A round that raised on the host
+                # settles the round before it too and leaves nothing
+                # pending of its own.
+                self._settle()
 
             # --- compute record (obs/compute.py) --------------------------
-            # One scalar sync on the loss sum closes the timed window over
-            # the wave loop (compile included on a cache miss — the
-            # tracker's shape signature says whether this shape compiled).
-            # The fold is behind the waves in the device's queue, so the
-            # sync still returns when the waves are done. A model with no
-            # FLOPs accounting is a reason string inside the record; a JAX
-            # error raised by the sync is the round's error.
-            with annotate("baton.round.sync"):
-                jax.block_until_ready(lsum_acc)
-            train_s = time.perf_counter() - t_waves0
-            with annotate("baton.round.record"):
-                sig = (c, int(wave_size), int(n_epochs), robust, rows,
-                       tuple(sorted((k, tuple(v.shape), str(v.dtype))
-                                    for k, v in data.items())))
-                self.last_compute = self.compute_probe.record_round(
-                    key="run_round",
-                    signature=sig,
-                    train_s=train_s,
-                    n_samples=float(n_host.sum()),
-                    n_epochs=n_epochs,
-                    steps=c * self.trainer.steps_per_round(rows, n_epochs),
-                    n_chips=(int(self.mesh.devices.size)
-                             if self.mesh is not None else 1),
-                )
+            # What the record needs is kept with the loss sum, which is
+            # ready when the waves are done (the fold is behind them in
+            # the device's queue); _settle writes it from there. A model
+            # with no FLOPs accounting is a reason string inside it.
+            self._pend(lsum_acc, t_waves0, missed, signature, n_host,
+                       n_epochs, c, rows)
+            if missed:
+                # the first execution of a program just compiled or
+                # loaded: wait for it here, with nothing queued behind it
+                self._settle()
 
             with annotate("baton.round.update"):
                 if self.partition is not None:
                     new_params = self.partition.merge(new_params, frozen)
-
                 return RoundResult(
                     params=new_params,
                     loss_history=loss_history,

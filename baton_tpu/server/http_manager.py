@@ -3333,7 +3333,7 @@ class Experiment:
             # would render "0 of <old total>" until the first wave lands
             self.metrics.set_gauge("sim_wave", 0)
             self.metrics.set_gauge("sim_waves_total", 0)
-            return self.simulator.run_round(
+            result = self.simulator.run_round(
                 start_params,
                 args["data"],
                 args["n_samples"],
@@ -3343,9 +3343,14 @@ class Experiment:
                 collect_client_losses=False,
                 progress_fn=on_wave,
             )
+            # run_round returns with its programs queued; the engine's
+            # compute record (MFU/compile/HBM) needs the round's end,
+            # and reading last_compute waits for it: in this thread,
+            # off the event loop
+            return result, getattr(self.simulator, "last_compute", None)
 
         try:
-            result = await asyncio.to_thread(run)
+            result, sim_compute = await asyncio.to_thread(run)
         except Exception as exc:  # XLA/shape/OOM errors must not hang the round
             _log.exception(
                 "simulated cohort failed in %s: %s", round_name, exc
@@ -3360,12 +3365,9 @@ class Experiment:
             "n_samples": float(result.n_samples_total),
             "loss_history": [float(x) for x in np.asarray(result.loss_history)],
         }
-        # the engine leaves its per-round compute record (MFU/compile/
-        # HBM) in last_compute; fold it through the same sanitizer the
-        # wire path uses so the SLO record sees one schema
-        sim_compute = _clean_compute(
-            getattr(self.simulator, "last_compute", None)
-        )
+        # fold the engine's record through the same sanitizer the wire
+        # path uses so the SLO record sees one schema
+        sim_compute = _clean_compute(sim_compute)
         if sim_compute is not None:
             response["compute"] = sim_compute
         result_sd = params_to_state_dict(result.params)

@@ -196,30 +196,38 @@ def _executed_inside(span, executions) -> int:
 @pytest.fixture(scope="module")
 def session(tmp_path_factory):
     """Two rounds of 6 clients in waves of 4 (the second wave has two
-    phantom clients) under a CPU profiler session, and the same first
-    round outside any session."""
+    phantom clients) and then a read of ``last_compute`` under a CPU
+    profiler session, and the same round twice outside any session: the
+    ``FedSim``'s first, which compiles and so settles itself, and its
+    second, which hits the jit's fast path and is left pending. A steady
+    round is settled by the round after it (ISSUE 37): the session's
+    first round settles the one pending outside it, its second the
+    first, and the read the second."""
     data, n = _linear_cohort()
     sim = _linear_sim()
     params = sim.init(jax.random.key(0))
     outside = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
-    jax.block_until_ready(outside.params)
+    assert sim._pending is None
+    jax.block_until_ready(
+        sim.run_round(params, data, n, jax.random.key(1), wave_size=4).params)
+    assert sim._pending.index == 2
 
     def two_rounds():
         first = sim.run_round(params, data, n, jax.random.key(1), wave_size=4)
         second = sim.run_round(first.params, data, n, jax.random.key(2),
                                wave_size=4)
-        return first, second.params
+        return first, second.params, sim.last_compute
 
-    (first, _), spans, executions = _profiled(
+    (first, _, record), spans, executions = _profiled(
         two_rounds, str(tmp_path_factory.mktemp("trace")))
     return {"spans": spans, "executions": executions, "outside": outside,
-            "inside": first}
+            "inside": first, "record": record}
 
 
 @pytest.mark.parametrize("name,count", [
     ("baton.round", 2), ("baton.round.prepare", 2), ("baton.round.stage", 4),
-    ("baton.round.dispatch", 4), ("baton.round.sync", 2),
-    ("baton.round.record", 2), ("baton.round.fold", 2),
+    ("baton.round.dispatch", 4), ("baton.round.sync", 3),
+    ("baton.round.record", 3), ("baton.round.fold", 2),
     ("baton.round.update", 2), ("baton.round.dispatch.launch", 4),
     ("baton.round.dispatch.accumulate", 4), ("baton.round.prepare.keys", 2),
     ("baton.round.prepare.select", 0)])
@@ -227,8 +235,10 @@ def test_session_counts_each_span(session, name, count):
     assert sum(s[0] == name for s in session["spans"]) == count
 
 
-def test_session_opens_select_once_a_round_for_a_chosen_cohort(
-        tmp_path_factory):
+@pytest.fixture(scope="module")
+def chosen_session(tmp_path_factory):
+    """A ``FedSim``'s first two rounds, of a chosen cohort, under a
+    profiler session: the first compiles."""
     data, n = _linear_cohort()
     sim = _linear_sim()
     params = sim.init(jax.random.key(0))
@@ -242,6 +252,12 @@ def test_session_opens_select_once_a_round_for_a_chosen_cohort(
 
     _, spans, executions = _profiled(
         two_rounds, str(tmp_path_factory.mktemp("trace_select")))
+    return spans, executions
+
+
+def test_session_opens_select_once_a_round_for_a_chosen_cohort(
+        chosen_session):
+    spans, executions = chosen_session
     prepares = [s for s in spans if s[0] == "baton.round.prepare"]
     selects = [s for s in spans if s[0] == "baton.round.prepare.select"]
     assert len(prepares) == len(selects) == 2
@@ -250,6 +266,24 @@ def test_session_opens_select_once_a_round_for_a_chosen_cohort(
         # the three takes (x, y, n_samples) and the indices' own cast
         if executions:
             assert _executed_inside(select, executions) >= 3
+
+
+def test_session_a_round_that_compiled_settles_itself_inside_its_round(
+        chosen_session):
+    spans, _ = chosen_session
+    first, second = [s for s in spans if s[0] == "baton.round"]
+    inner = [s for s in spans if s[0].count(".") == 2
+             and first[1] <= s[1] and s[2] <= first[2]]
+    assert [s[0][len("baton.round."):] for s in inner][-4:] == [
+        "fold", "sync", "record", "update"]
+    assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+    sync, = [s for s in spans if s[0] == "baton.round.sync"]
+    assert sync in inner
+    assert sync[3] == {"settles": 1, "ready": sync[3]["ready"], "own": 1}
+    # the second round hit the fast path: it waits for no one, and no one
+    # has waited for it yet
+    assert len([s for s in spans if s[0] == "baton.round.record"]) == 1
+    assert not [s for s in spans if s[1] >= second[2]]
 
 
 def test_session_spans_nest_in_their_round_in_order(session):
@@ -283,7 +317,37 @@ def test_session_spans_nest_in_their_round_in_order(session):
     inside_a_round = sum(r0 <= s[1] and s[2] <= r1
                          for s in session["spans"] if s[0] != "baton.round"
                          for _, r0, r1, _ in rounds)
-    assert inside_a_round == len(session["spans"]) - len(rounds)
+    # the read of last_compute after the last round opens the two spans
+    # that are in no round
+    assert inside_a_round == len(session["spans"]) - len(rounds) - 2
+
+
+def test_session_sync_says_which_round_it_settled(session):
+    """``settles`` counts this ``FedSim``'s rounds from 1, ``ready`` is
+    whether the round was done when the host came to wait for it (the
+    round outside the session had been waited for by the test itself),
+    and ``own`` whether the round waited for is the one that waits."""
+    syncs = [s[3] for s in session["spans"] if s[0] == "baton.round.sync"]
+    assert [a["settles"] for a in syncs] == [2, 3, 4]
+    assert all(set(a) == {"settles", "ready", "own"} and a["ready"] in (0, 1)
+               for a in syncs)
+    # none of the three launched a program the jit had not run before
+    assert [a["own"] for a in syncs] == [0, 0, 0]
+    assert syncs[0]["ready"] == 1
+    records = [s[3] for s in session["spans"] if s[0] == "baton.round.record"]
+    assert records == [{}] * 3
+
+
+def test_session_a_read_of_last_compute_settles_outside_any_round(session):
+    rounds = [s for s in session["spans"] if s[0] == "baton.round"]
+    last = [s for s in session["spans"] if s[1] >= rounds[-1][2]]
+    assert [s[0] for s in last] == ["baton.round.sync", "baton.round.record"]
+    assert last[0][2] <= last[1][1]
+    # the record the read returned is the second round's, whole
+    record = session["record"]
+    assert record["steps"] == 6 * 2 and record["cache_hit"]
+    assert record["train_s_source"] in ("host_waited",
+                                        "found_ready_upper_bound")
 
 
 def test_session_stage_counts_the_phantom_clients(session):
@@ -458,24 +522,71 @@ def test_every_path_opens_and_closes_its_spans(recorder, path):
         sim_kw["mesh"] = make_mesh(sim_kw["mesh"])
         data = shard_client_arrays(data, sim_kw["mesh"])
     sim = _linear_sim(**sim_kw)
+    kwargs = {"wave_size": 4, **round_kw}
     res = sim.run_round(sim.init(jax.random.key(0)), data, n,
-                        jax.random.key(1),
-                        **{"wave_size": 4, **round_kw})
+                        jax.random.key(1), **kwargs)
     assert np.isfinite(np.asarray(res.loss_history)).all()
     assert recorder.open == []
     counts = collections.Counter(name for name, _ in recorder.opened)
-    expected = {"baton.round": 1, "baton.round.prepare": 1,
-                "baton.round.prepare.keys": 1,
-                "baton.round.stage": waves,
-                "baton.round.dispatch": waves,
-                "baton.round.dispatch.launch": waves,
-                "baton.round.dispatch.accumulate": waves,
-                "baton.round.sync": 1,
-                "baton.round.record": 1, "baton.round.fold": 1,
-                "baton.round.update": 1}
+    # a FedSim's first round compiles: it waits for itself and writes its
+    # own record, between its fold and its update
+    steady = {"baton.round": 1, "baton.round.prepare": 1,
+              "baton.round.prepare.keys": 1,
+              "baton.round.stage": waves,
+              "baton.round.dispatch": waves,
+              "baton.round.dispatch.launch": waves,
+              "baton.round.dispatch.accumulate": waves,
+              "baton.round.fold": 1,
+              "baton.round.update": 1}
     if "client_indices" in round_kw:
-        expected["baton.round.prepare.select"] = 1
-    assert counts == expected
+        steady["baton.round.prepare.select"] = 1
+    settling = {**steady, "baton.round.sync": 1, "baton.round.record": 1}
+    assert counts == settling
+    first_round = list(recorder.opened)
+
+    def settled():
+        names = [name for name, _ in recorder.opened]
+        assert names[-4:] == ["baton.round.fold", "baton.round.sync",
+                              "baton.round.record", "baton.round.update"]
+        return dict(recorder.opened)["baton.round.sync"]
+
+    assert settled() == {"settles": 1, "ready": settled()["ready"], "own": 1}
+    assert sim._pending is None
+    # the second hits the jit's fast path, waits for nothing and is left
+    # pending; on a mesh its parameters come committed to the devices by
+    # the fold, the launch adds an entry, and it settles itself as well
+    del recorder.opened[:]
+    res = sim.run_round(res.params, data, n, jax.random.key(2), **kwargs)
+    assert recorder.open == []
+    counts = collections.Counter(name for name, _ in recorder.opened)
+    if path == "mesh":
+        assert counts == settling and settled()["own"] == 1
+        assert sim._pending is None
+    else:
+        assert counts == steady and sim._pending.index == 2
+    # the third settles the second where that is pending, after its own
+    # fold, and is itself left pending
+    del recorder.opened[:]
+    sim.run_round(res.params, data, n, jax.random.key(3), **kwargs)
+    assert recorder.open == [] and sim._pending.index == 3
+    counts = collections.Counter(name for name, _ in recorder.opened)
+    if path == "mesh":
+        assert counts == steady
+    else:
+        assert counts == settling
+        sync = settled()
+        assert (sync["settles"], sync["own"]) == (2, 0)
+        assert sync["ready"] in (0, 1)
+    # and a read of last_compute settles the last one, in no round; once
+    del recorder.opened[:]
+    assert sim.last_compute["steps"] == clients * 2
+    assert [name for name, _ in recorder.opened] == [
+        "baton.round.sync", "baton.round.record"]
+    assert recorder.opened[0][1]["settles"] == 3
+    assert recorder.opened[0][1]["own"] == 0
+    assert sim.last_compute["steps"] == clients * 2
+    assert len(recorder.opened) == 2 and recorder.open == []
+    recorder.opened[:] = first_round
     for nm, a in recorder.opened:  # every launch and accumulate is whole
         if nm == "baton.round.dispatch.launch":
             assert a["leaves"] == 6 and a["cache_entries"] >= 1
