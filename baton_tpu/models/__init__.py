@@ -13,7 +13,7 @@ from baton_tpu.models.llama import (
 )
 from baton_tpu.models.lstm import LSTMConfig, lstm_lm_model
 from baton_tpu.models.moe import MoEConfig, moe_apply, moe_init
-from baton_tpu.models.transformer import MLAConfig
+from baton_tpu.models.transformer import IndexerConfig, MLAConfig
 from baton_tpu.models.vit import ViTConfig, vit_model
 
 __all__ = [
@@ -41,6 +41,7 @@ __all__ = [
     "MoEConfig",
     "moe_apply",
     "moe_init",
+    "IndexerConfig",
     "MLAConfig",
     "ViTConfig",
     "vit_model",

@@ -8,7 +8,9 @@ TPU-first blocks in :mod:`baton_tpu.models.transformer`. ``layer_types``
 makes it a hybrid: each layer's mixer is full attention, the gated
 delta rule of :mod:`baton_tpu.models.delta_rule` (linear attention with
 a recurrent state) or latent attention (``mla``: keys and values from a
-low-rank latent, :func:`baton_tpu.models.transformer.mla_apply`), in
+low-rank latent, with ``mla.q_rank`` the queries from one of their own,
+with ``mla.indexer`` each query attending the keys a learned index
+chose for it, :func:`baton_tpu.models.transformer.mla_apply`), in
 the pattern the configuration gives. With ``moe`` the layers after the
 first ``first_dense_layers`` replace their SwiGLU by the expert layer of
 :mod:`baton_tpu.models.moe`, which holds ``moe.experts_held`` of the
@@ -108,6 +110,9 @@ class LlamaConfig:
     # deviation of the embedding table's initial normal: the scale of the
     # residual stream the blocks' outputs are added to
     embed_std: float = 0.02
+    # the RMSNorms before each sub-layer and before the head (a latent
+    # mixer's own norms have ``mla.norm_eps``)
+    norm_eps: float = 1e-6
 
     def __post_init__(self):
         if self.layer_types is not None:  # a JSON list hashes as a tuple
@@ -146,17 +151,18 @@ def llama_lora_target(path: str, leaf) -> bool:
     return path.rsplit("/", 1)[-1] in ("wq", "wk", "wv", "wo")
 
 
-_PROJECTIONS = ("wq", "wk", "wv", "wo", "wg", "wkv_a", "wkv_b",
-                "w_gate", "w_up", "w_down")
+_PROJECTIONS = ("wq", "wq_a", "wq_b", "wk", "wv", "wo", "wg", "wkv_a",
+                "wkv_b", "w_gate", "w_up", "w_down")
 
 
 def projection_lora_target(path: str, leaf) -> bool:
     """LoRA target predicate: every 2-D projection of the mixers (full,
     linear and latent attention), of the MLPs and of a shared expert;
     not the embedding, the head, the linear layers' gate projections
-    ``wa`` / ``wb`` or their convolutions, and not an expert layer's
-    router or its 3-D stacks of routed experts."""
-    return (getattr(leaf, "ndim", 2) == 2
+    ``wa`` / ``wb`` or their convolutions, not an expert layer's
+    router or its 3-D stacks of routed experts, and nothing of a latent
+    mixer's ``indexer`` (no gradient reaches the choice of keys)."""
+    return (getattr(leaf, "ndim", 2) == 2 and "/indexer/" not in path
             and path.rsplit("/", 1)[-1] in _PROJECTIONS)
 
 
@@ -189,23 +195,39 @@ def _block_init(key, cfg: LlamaConfig, kind: str = "full_attention",
             "norm_mlp": rms_init(cfg.d_model), "mlp": mlp}
 
 
-def _block_apply(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
-    h = rms_norm(x, p["norm_attn"])
+def _mixer_keeps_its_inputs(p, cfg: LlamaConfig, length: int) -> bool:
+    """Whether the block's mixer recomputes itself in the backward from
+    its own inputs: latent attention whose queries choose their keys
+    (``transformer.py::_choosing_mla``)."""
+    return "mla" in p and cfg.mla.selects(length)
+
+
+def _mix(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
+    if _mixer_keeps_its_inputs(p, cfg, x.shape[1]):
+        return x + mla_apply(p["mla"], x, cfg.n_heads, cfg.mla, rope,
+                             pre_norm=p["norm_attn"])
+    h = rms_norm(x, p["norm_attn"], cfg.norm_eps)
     if "mla" in p:
-        x = x + mla_apply(p["mla"], h, cfg.n_heads, cfg.mla, rope)
-    elif "linear_attn" in p:
-        x = x + gated_delta_apply(
+        return x + mla_apply(p["mla"], h, cfg.n_heads, cfg.mla, rope)
+    if "linear_attn" in p:
+        return x + gated_delta_apply(
             p["linear_attn"], h, cfg.linear_n_heads, cfg.linear_chunk,
             cfg.linear_allow_neg_eigval)
-    else:
-        x = x + mha_apply(
-            p["attn"], h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            causal=True, rope=rope, attention_fn=attention_fn,
-        )
-    h = rms_norm(x, p["norm_mlp"])
+    return x + mha_apply(
+        p["attn"], h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+        causal=True, rope=rope, attention_fn=attention_fn,
+    )
+
+
+def _feed_forward(p, x, cfg: LlamaConfig):
+    h = rms_norm(x, p["norm_mlp"], cfg.norm_eps)
     if "router" in p["mlp"]:
         return x + moe_apply(p["mlp"], h, cfg.moe)
     return x + swiglu_apply(p["mlp"], h)
+
+
+def _block_apply(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
+    return _feed_forward(p, _mix(p, x, cfg, rope, attention_fn), cfg)
 
 
 def llama_lm_model(
@@ -231,6 +253,10 @@ def llama_lm_model(
     # one trace whatever the depth
     block_fn = (jax.checkpoint(_block_apply, static_argnums=(2, 4)) if remat
                 else _block_apply)
+    # a mixer that keeps its own inputs for the backward stands outside
+    # the block's checkpoint, which would make it a third time
+    ff_fn = (jax.checkpoint(_feed_forward, static_argnums=(2,)) if remat
+             else _feed_forward)
 
     def init(rng):
         keys = jax.random.split(rng, cfg.n_layers + 2)
@@ -262,8 +288,12 @@ def llama_lm_model(
             x = params["tok_emb"][ids].astype(compute_dtype)
         for i, blk in enumerate(params["blocks"]):
             with jax.named_scope(f"block{i}"):
-                x = block_fn(blk, x, cfg, rope, attention_fn)
-        return rms_norm(x, params["norm_f"])
+                if _mixer_keeps_its_inputs(blk, cfg, l):
+                    x = ff_fn(blk, _mix(blk, x, cfg, rope, attention_fn),
+                              cfg)
+                else:
+                    x = block_fn(blk, x, cfg, rope, attention_fn)
+        return rms_norm(x, params["norm_f"], cfg.norm_eps)
 
     def apply(params, batch, rng):
         """Returns next-token logits [B, L, V] (fp32): bf16 operands,

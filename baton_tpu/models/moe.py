@@ -235,13 +235,24 @@ def _routed_backward(x, idx, gate, dy, w_gate, w_up, w_down):
     return (d_x, d_gate) + stacks
 
 
+# what the sorted copy of the folded clients' rows (every assignment's,
+# held here or not: ``[C T K, D]``) may hold before the layer goes a
+# client at a time. sarvam_105b_c4_l2048 folds 512 MiB; four clients of
+# 8,192 tokens at 6,144 channels would sort 3 GiB, twice in the
+# backward, beside a 5 GiB base (compiled for a v5e, PR 39: 17.0 of
+# 15.75 GiB)
+_FOLDED_ROWS_BYTES = 1024 ** 3
+
+
 def _over_clients(fn, n_out_acts: int):
     """``fn(x, idx, gate, ..., w_gate, w_up, w_down) -> tuple`` as a
     ``custom_vmap`` function whose rule folds a client axis on the
-    activations into their row axis where the stacks carry none; the
-    first ``n_out_acts`` results are per row and unfold again, the rest
-    (a stack's gradient a client) come from a ``lax.map`` over clients,
-    as does everything where a stack carries the axis."""
+    activations into their row axis where the stacks carry none and the
+    folded rows stay under ``_FOLDED_ROWS_BYTES``; the first
+    ``n_out_acts`` results are per row and unfold again, the rest (a
+    stack's gradient a client) come from a ``lax.map`` over clients, as
+    does everything where a stack carries the axis or the fold would be
+    too large."""
     wrapped = custom_vmap(fn)
 
     @wrapped.def_vmap
@@ -255,6 +266,10 @@ def _over_clients(fn, n_out_acts: int):
         if any(in_batched[-3:]):
             out = jax.lax.map(lambda a: fn(*a), (
                 *acts, *along_clients(stacks, in_batched[-3:])))
+            return out, (True,) * len(out)
+        x, idx = acts[:2]
+        if idx.size * x.shape[-1] * x.dtype.itemsize > _FOLDED_ROWS_BYTES:
+            out = jax.lax.map(lambda a: fn(*a, *stacks), tuple(acts))
             return out, (True,) * len(out)
         folded = wrapped(*(a.reshape((-1,) + a.shape[2:]) for a in acts),
                          *stacks)

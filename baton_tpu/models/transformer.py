@@ -24,11 +24,13 @@ driver-set workloads that need one. These blocks are written TPU-first:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 # attention_fn(q, k, v, bias, causal) -> out
 #   q [B, Hq, L, Dh], k/v [B, Hkv, L, Dh], bias None or [B, 1, 1, L] additive
@@ -62,13 +64,17 @@ def rms_init(d):
 # norms (fp32 stats regardless of compute dtype)
 
 
-@jax.named_scope("norm")
-def layer_norm(x, p, eps=1e-6):
+def layer_normalize(x, p, eps=1e-6):
+    """LayerNorm over the last axis under no scope of its own (see
+    :func:`rms_normalize`)."""
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
     xf = (xf - mean) * jax.lax.rsqrt(var + eps)
     return (xf * p["scale"] + p["bias"]).astype(x.dtype)
+
+
+layer_norm = jax.named_scope("norm")(layer_normalize)
 
 
 def rms_normalize(x, scale, eps=1e-6):
@@ -246,11 +252,30 @@ def mha_apply(
 
 
 @dataclasses.dataclass(frozen=True)
+class IndexerConfig:
+    """The lightning indexer's sizes (DeepSeek-V3.2-Exp's sparse
+    attention): ``heads`` index queries of ``dim`` channels a token
+    against one index key of ``dim`` shared by them, the first
+    ``rope_dim`` channels of both rotated; a query attends the ``topk``
+    keys of its causal prefix that score highest."""
+
+    heads: int = 32
+    dim: int = 128
+    topk: int = 2048
+    rope_dim: int = 64
+
+
+@dataclasses.dataclass(frozen=True)
 class MLAConfig:
     """Latent attention's sizes: keys and values come from a normalised
     latent of ``kv_rank`` channels, one rotary key of ``rope_dim`` is
     shared by all heads; queries and keys are ``nope_dim + rope_dim``
-    wide a head, values ``v_dim``."""
+    wide a head, values ``v_dim`` (narrower than the keys, 192 / 128,
+    or as wide, 256 / 256). With ``q_rank`` the queries come from a
+    normalised latent of their own; with ``indexer`` a query attends
+    the keys a learned index chose for it (:func:`index_scores`,
+    :func:`select_keys`), one set a query for all heads. Neither is in
+    the parameters or the program of a configuration that names none."""
 
     kv_rank: int = 512
     nope_dim: int = 128
@@ -266,10 +291,16 @@ class MLAConfig:
     # rotary frequencies
     rope_scaling: Optional[tuple] = None
     # queries a block of the core where it is plain JAX (off a TPU, or a
-    # length the Pallas kernel's blocks do not divide: ``causal_core``):
-    # a block's float32 scores against its causal prefix of keys are
-    # held at a time, never ``[L, L]`` whole
+    # length the Pallas kernel's blocks do not divide: ``causal_core``),
+    # and of the index scores anywhere: a block's float32 scores against
+    # its causal prefix of keys are held at a time, never ``[L, L]`` a
+    # head
     block: int = 512
+    # the queries' latent (``wq_a``, an RMSNorm, ``wq_b``); None: one
+    # projection ``wq``
+    q_rank: Optional[int] = None
+    indexer: Optional[IndexerConfig] = None
+    norm_eps: float = 1e-6
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):  # a JSON group, hashable
@@ -277,10 +308,20 @@ class MLAConfig:
                 raise ValueError(f"unknown rope_scaling {self.rope_scaling}")
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(self.rope_scaling.items())))
+        if self.indexer is not None \
+                and self.indexer.rope_dim != self.rope_dim:
+            raise NotImplementedError(
+                "the indexer's rotary width is the keys': both are turned "
+                "by one table of angles")
 
     @property
     def qk_dim(self) -> int:
         return self.nope_dim + self.rope_dim
+
+    def selects(self, length: int) -> bool:
+        """Whether a sequence of ``length`` has a query that may not see
+        its whole causal prefix."""
+        return self.indexer is not None and self.indexer.topk < length
 
     @property
     def yarn(self) -> Optional[dict]:
@@ -340,22 +381,41 @@ def mla_rope_angles(seq_len: int, cfg: MLAConfig):
 
 def mla_init(key, d_model: int, n_heads: int, cfg: MLAConfig, out_std=None):
     kq, ka, kb, ko = jax.random.split(key, 4)
-    p = {
-        "wq": dense_init(kq, d_model, n_heads * cfg.qk_dim),
+    if cfg.q_rank is None:
+        p = {"wq": dense_init(kq, d_model, n_heads * cfg.qk_dim)}
+    else:
+        p = {"wq_a": dense_init(kq, d_model, cfg.q_rank),
+             "q_a_norm": rms_init(cfg.q_rank),
+             "wq_b": dense_init(jax.random.fold_in(kq, 1), cfg.q_rank,
+                                n_heads * cfg.qk_dim)}
+    p.update({
         "wkv_a": dense_init(ka, d_model, cfg.kv_rank + cfg.rope_dim),
         "kv_norm": rms_init(cfg.kv_rank),
         "wkv_b": dense_init(kb, cfg.kv_rank,
                             n_heads * (cfg.nope_dim + cfg.v_dim)),
         "wo": dense_init(ko, n_heads * cfg.v_dim, d_model, stddev=out_std),
-    }
+    })
     if cfg.qk_norm:
         p["q_norm"] = rms_init(cfg.qk_dim)
         p["k_norm"] = rms_init(cfg.qk_dim)
+    if cfg.indexer is not None:
+        ix = cfg.indexer
+        kiq, kik, kiw = jax.random.split(jax.random.fold_in(key, 1), 3)
+        p["indexer"] = {
+            # index queries come from the queries' latent where there is one
+            "wq": dense_init(kiq, cfg.q_rank or d_model, ix.heads * ix.dim),
+            "wk": dense_init(kik, d_model, ix.dim),
+            "k_norm": ln_init(ix.dim),
+            "w_heads": dense_init(kiw, d_model, ix.heads),
+        }
     return p
 
 
 # blocks (queries, keys) of the flash kernel under ``causal_core``: the
 # fastest on a v5e at [4, 64, 2048, 192 / 128] (PERF.md section 5, PR 34)
+# and at [1, 64, 8192, 256 / 256] with a choice of keys (PR 39, forward,
+# ms a call: 1,024 x 1,024 18.65; 512 x 1,024 20.77; 1,024 x 2,048 20.09;
+# 2,048 x 1,024 19.33; 17.82 without the choice)
 _CORE_KERNEL_BLOCKS = (1024, 1024)
 
 
@@ -367,83 +427,307 @@ def core_runs_the_kernel(backend: str, length: int, block: int) -> bool:
     return backend == "tpu" and length > block and length % block == 0
 
 
-def blocked_causal_core(q, k, v, scale: float, block: int):
+def blocked_causal_core(q, k, v, scale: float, block: int, chosen=None):
     """:func:`causal_core` in plain JAX: a block of ``block`` queries at
     a time against its causal prefix of keys. A block is under
     ``jax.checkpoint``: the backward recomputes its scores, so no
-    ``[L, L]`` tensor is held, forward or backward. ``L <= block`` is
-    the plain computation."""
+    ``[L, L]`` tensor is held a head, forward or backward. ``L <=
+    block`` is the plain computation."""
     l = q.shape[2]
 
-    def one(qb, kb, vb, start):
+    def one(qb, kb, vb, cb, start):
         s = jnp.einsum("bhqd,bhkd->bhqk", qb, kb,
                        preferred_element_type=jnp.float32) * scale
         seen = (start + jnp.arange(qb.shape[2]))[:, None] \
             >= jnp.arange(kb.shape[2])[None, :]
+        if cb is not None:
+            seen = seen & (cb[:, None] != 0)
         p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
         return jnp.einsum("bhqk,bhkd->bhqd", p.astype(vb.dtype), vb)
 
     if l <= block:
-        return one(q, k, v, 0)
-    one = jax.checkpoint(one, static_argnums=(3,))
+        return one(q, k, v, chosen, 0)
+    one = jax.checkpoint(one, static_argnums=(4,))
     return jnp.concatenate(
         [one(q[:, :, s:s + block], k[:, :, :s + block], v[:, :, :s + block],
+             None if chosen is None else chosen[:, s:s + block, :s + block],
              s) for s in range(0, l, block)], axis=2)
 
 
 @jax.named_scope("mla_core")
-def causal_core(q, k, v, scale: float, block: int):
+def causal_core(q, k, v, scale: float, block: int, chosen=None):
     """Causal softmax attention ``[B, H, L, Dv]`` of ``q, k [B, H, L,
-    Dk]`` and ``v [B, H, L, Dv]`` whose widths differ, the scores and
-    the softmax in float32, the probabilities cast to ``v``'s dtype.
+    Dk]`` and ``v [B, H, L, Dv]`` (``Dv`` as wide as ``Dk`` or
+    narrower), the scores and the softmax in float32, the probabilities
+    cast to ``v``'s dtype. With ``chosen [B, L, L]`` (nonzero: query
+    ``t`` may see key ``s``; one choice for all heads,
+    :func:`chosen_keys`) the softmax runs over the chosen keys of the
+    causal prefix alone; every query has to have chosen one.
 
     On a TPU (:func:`core_runs_the_kernel`) it is one call of
     ``ops/flash_attention.py``: a tile of scores lives in VMEM, forward
     and backward, and the kernel's ``custom_vjp`` keeps ``q, k, v``, the
-    output and the log-sum-exp, nothing ``[L, L]``. Elsewhere it is
-    :func:`blocked_causal_core` in blocks of ``block`` queries."""
+    choice, the output and the log-sum-exp, no float ``[L, L]``; it
+    visits every causal tile and masks what was not chosen. Elsewhere
+    it is :func:`blocked_causal_core` in blocks of ``block`` queries."""
     block_q, block_k = _CORE_KERNEL_BLOCKS
     if core_runs_the_kernel(jax.default_backend(), q.shape[2],
                             max(block_q, block_k)):
         from baton_tpu.ops.flash_attention import flash_attention
 
         return flash_attention(q, k, v, causal=True, scale=scale,
-                               block_q=block_q, block_k=block_k)
-    return blocked_causal_core(q, k, v, scale, block)
+                               block_q=block_q, block_k=block_k,
+                               chosen=chosen)
+    return blocked_causal_core(q, k, v, scale, block, chosen)
 
 
-@jax.named_scope("latent_attention")
-def mla_apply(p, x, n_heads: int, cfg: MLAConfig, rope):
-    """Latent attention over ``x [B, L, D] -> [B, L, D]``, causal.
-    ``rope`` is :func:`mla_rope_angles`'s pair. The rotation pairs
-    channel ``i`` of the rotary part with ``i + rope_dim / 2``
-    (:func:`apply_rope`)."""
+# ------------------------------------------------- the keys a query chose
+# (DeepSeek-V3.2-Exp's sparse attention: a lightning indexer scores every
+# causal pair, a query attends its ``topk`` best keys)
+
+
+@jax.named_scope("indexer")
+def index_scores(p, x, q_in, cfg: MLAConfig, rope):
+    """The index scores ``I [B, L, L]`` in float32, ``-inf`` where key
+    ``s`` lies after query ``t``::
+
+        I[t, s] = sum_j w[t, j] ReLU(q_I[t, j] . k_I[s])
+
+    with ``q_I = q_in W_q`` (``heads`` index queries of ``dim``), ``k_I
+    = LayerNorm(x W_k)`` (one key a token), ``w = x W_heads heads^-1/2
+    dim^-1/2``, the first ``rope_dim`` channels of queries and key
+    turned by ``rope``. The products are of ``x``'s dtype and summed in
+    float32, a block of ``cfg.block`` queries against its causal prefix
+    at a time (``[heads, block, L]`` is what is held). ``p`` is the
+    mixer's ``indexer`` group, ``q_in`` the queries' latent (``x``
+    where there is none)."""
+    ix = cfg.indexer
+    b, l, _ = x.shape
+    cos, sin = rope
+
+    def turned(y):  # [..., L, dim]
+        return jnp.concatenate(
+            [apply_rope(y[..., :ix.rope_dim], cos, sin),
+             y[..., ix.rope_dim:]], axis=-1)
+
+    q = turned((q_in @ p["wq"].astype(x.dtype))
+               .reshape(b, l, ix.heads, ix.dim).transpose(0, 2, 1, 3))
+    k = turned(layer_normalize(x @ p["wk"].astype(x.dtype), p["k_norm"],
+                               cfg.norm_eps))
+    w = jnp.einsum("bld,dh->bhl", x, p["w_heads"].astype(x.dtype),
+                   preferred_element_type=jnp.float32) \
+        * (ix.heads ** -0.5 * ix.dim ** -0.5)
+    rows = []
+    for s in range(0, l, cfg.block):
+        e = min(s + cfg.block, l)
+        hit = jax.nn.relu(jnp.einsum(
+            "bhqd,bkd->bhqk", q[:, :, s:e], k[:, :e],
+            preferred_element_type=jnp.float32))
+        score = jnp.sum(hit * w[:, :, s:e, None], axis=1)
+        seen = jnp.arange(s, e)[:, None] >= jnp.arange(e)[None, :]
+        score = jnp.where(seen, score, -jnp.inf)
+        rows.append(jnp.pad(score, ((0, 0), (0, 0), (0, l - e)),
+                            constant_values=-jnp.inf))
+    return jnp.concatenate(rows, axis=1)
+
+
+def _ordered_bits(scores):
+    """Float32 as uint32 in the same order (no NaN; -0.0, which a sum
+    of nothing but -0.0 is, counts as the 0.0 it equals)."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0, 0.0, scores), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+@jax.named_scope("index_select")
+def select_keys(scores, topk: int):
+    """``(tau, cut)``, each ``[B, L]``: query ``t`` chooses the ``topk``
+    keys of largest ``scores[t]`` among its causal prefix (``scores
+    [B, L, L]``, ``-inf`` past it), equal scores to the lower index,
+    and all of the prefix while it holds no more than ``topk``. The
+    choice is the keys that score above ``tau`` and those that score
+    ``tau`` at an index up to ``cut`` (:func:`chosen_keys`): exactly
+    ``min(t + 1, topk)`` a query.
+
+    ``tau`` is found without a sort, by bisection over the ordered bits
+    of a float: 32 counting passes over ``scores`` fix the ``topk``-th
+    largest value bit by bit from the top, ``log2 L`` more the index at
+    which the keys that equal it run out (a sort of ``[L, L]`` is some
+    ``log2(L)^2 / 2`` compare-exchange passes, each read and written:
+    PERF.md section 6, PR 39, has both on the chip)."""
+    b, l, _ = scores.shape
+    keys = _ordered_bits(scores)
+    at = jnp.arange(l, dtype=jnp.int32)
+
+    def count(hit):
+        return jnp.sum(hit, axis=-1, dtype=jnp.int32)
+
+    def value_bit(i, found):
+        trial = found | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        enough = count(keys >= trial[..., None]) >= topk
+        return jnp.where(enough, trial, found)
+
+    kth = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((b, l), jnp.uint32))
+    equal = keys == kth[..., None]
+    wanted = topk - count(keys > kth[..., None])
+    n_bits = max(l - 1, 1).bit_length()
+
+    def index_bit(i, found):
+        trial = found | (jnp.int32(1 << (n_bits - 1)) >> i)
+        short = count(equal & (at < trial[..., None])) < wanted
+        return jnp.where(short, trial, found)
+
+    cut = jax.lax.fori_loop(0, n_bits, index_bit,
+                            jnp.zeros((b, l), jnp.int32))
+    tau = jax.lax.bitcast_convert_type(
+        jnp.where(kth >> 31 == 1, kth & jnp.uint32(0x7fffffff), ~kth),
+        jnp.float32)
+    whole = at < topk  # the prefix of query t holds t + 1 keys
+    return (jnp.where(whole, -jnp.inf, tau),
+            jnp.where(whole, l - 1, cut))
+
+
+@jax.named_scope("index_select")
+def chosen_keys(scores, tau, cut):
+    """``[B, L, L]`` int8, 1 where query ``t`` chose key ``s``: what
+    :func:`select_keys` found, laid over the causal rule."""
+    at = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+    t, c = tau[..., None], cut[..., None]
+    chosen = (scores > t) | ((scores == t) & (at <= c))
+    return (chosen & (at[:, None] >= at[None, :])).astype(jnp.int8)
+
+
+def _a_client_at_a_time(fn):
+    """``fn`` as a ``custom_vmap`` function whose rule is a ``lax.map``
+    over the mapped axis: under ``FedSim``'s client ``vmap`` the arrays
+    of one client are live at a time (at 8,192 tokens the queries, keys
+    and values of four clients are 3 x 1.07 GB a layer and direction),
+    and what carries no client axis (the frozen base) is read where it
+    lies."""
+    wrapped = custom_vmap(fn)
+
+    @wrapped.def_vmap
+    def rule(axis_size, in_batched, *args):
+        flat, tree = jax.tree_util.tree_flatten(args)
+        mapped = jax.tree_util.tree_leaves(in_batched)
+
+        def one(rows):
+            rows = iter(rows)
+            return fn(*jax.tree_util.tree_unflatten(tree, [
+                next(rows) if m else a for a, m in zip(flat, mapped)]))
+
+        out = jax.lax.map(one, [a for a, m in zip(flat, mapped) if m])
+        return out, jax.tree_util.tree_map(lambda _: True, out)
+
+    return wrapped
+
+
+def _mla(p, x, rope, choice, n_heads: int, cfg: MLAConfig, pre_norm=None):
+    """The mixer itself: ``(y, choice)``. ``choice`` is ``(tau, cut)``
+    of :func:`select_keys`, made here where it is None and the
+    sequence has a query that chooses (``cfg.selects``), else None."""
+    if pre_norm is not None:
+        x = rms_normalize(x, pre_norm["scale"], cfg.norm_eps)
     b, l, _ = x.shape
     cos, sin = rope
 
     def heads(y, width):
         return y.reshape(b, l, n_heads, width).transpose(0, 2, 1, 3)
 
-    q = heads(x @ p["wq"].astype(x.dtype), cfg.qk_dim)
+    if cfg.q_rank is None:
+        q_in = x
+        q = heads(x @ p["wq"].astype(x.dtype), cfg.qk_dim)
+    else:
+        q_in = rms_normalize(x @ p["wq_a"].astype(x.dtype),
+                             p["q_a_norm"]["scale"], cfg.norm_eps)
+        q = heads(q_in @ p["wq_b"].astype(x.dtype), cfg.qk_dim)
     c = x @ p["wkv_a"].astype(x.dtype)
-    latent = rms_normalize(c[..., :cfg.kv_rank], p["kv_norm"]["scale"])
+    latent = rms_normalize(c[..., :cfg.kv_rank], p["kv_norm"]["scale"],
+                           cfg.norm_eps)
     kv = heads(latent @ p["wkv_b"].astype(x.dtype), cfg.nope_dim + cfg.v_dim)
     k_rope = jnp.broadcast_to(c[:, None, :, cfg.kv_rank:],
                               (b, n_heads, l, cfg.rope_dim))
     k = jnp.concatenate([kv[..., :cfg.nope_dim], k_rope], axis=-1)
     v = kv[..., cfg.nope_dim:]
     if cfg.qk_norm:
-        q = rms_normalize(q, p["q_norm"]["scale"])
-        k = rms_normalize(k, p["k_norm"]["scale"])
+        q = rms_normalize(q, p["q_norm"]["scale"], cfg.norm_eps)
+        k = rms_normalize(k, p["k_norm"]["scale"], cfg.norm_eps)
 
     def rotated(y):
         return jnp.concatenate(
             [y[..., :cfg.nope_dim],
              apply_rope(y[..., cfg.nope_dim:], cos, sin)], axis=-1)
 
-    out = causal_core(rotated(q), rotated(k), v, cfg.softmax_scale, cfg.block)
+    chosen = None
+    if cfg.selects(l):
+        # nothing differentiates the index or the choice (V3.2 trains
+        # its indexer by a loss of its own, on detached inputs; over a
+        # frozen base nothing trains it)
+        scores = index_scores(
+            *jax.lax.stop_gradient((p["indexer"], x, q_in)), cfg, rope)
+        if choice is None:
+            choice = select_keys(scores, cfg.indexer.topk)
+        chosen = chosen_keys(scores, *choice)
+    out = causal_core(rotated(q), rotated(k), v, cfg.softmax_scale, cfg.block,
+                      chosen)
     out = out.transpose(0, 2, 1, 3).reshape(b, l, n_heads * cfg.v_dim)
-    return out @ p["wo"].astype(x.dtype)
+    return out @ p["wo"].astype(x.dtype), choice
+
+
+@functools.lru_cache(maxsize=None)
+def _choosing_mla(n_heads: int, cfg: MLAConfig):
+    """:func:`_mla` where queries choose their keys, as a ``custom_vjp``
+    whose two directions run a client at a time. The forward keeps the
+    mixer's inputs and the choice (a threshold and an index a query),
+    nothing else; the backward makes the mixer again with that choice
+    (the index scores are computed again, nothing is selected twice)
+    and differentiates it. A decoder block therefore leaves this mixer
+    out of its ``remat`` (``llama.py``)."""
+    forward = _a_client_at_a_time(
+        lambda p, x, rope, pre_norm: _mla(p, x, rope, None, n_heads, cfg,
+                                          pre_norm))
+
+    def pullback(p, x, rope, pre_norm, choice, dy):
+        _, back = jax.vjp(
+            lambda p, x, pre_norm: _mla(p, x, rope, choice, n_heads, cfg,
+                                        pre_norm)[0], p, x, pre_norm)
+        return back(dy)
+
+    backward = _a_client_at_a_time(pullback)
+
+    @jax.custom_vjp
+    def apply(p, x, rope, pre_norm):
+        return forward(p, x, rope, pre_norm)[0]
+
+    def fwd(p, x, rope, pre_norm):
+        y, choice = forward(p, x, rope, pre_norm)
+        return y, (p, x, rope, pre_norm, choice)
+
+    def bwd(res, dy):
+        d_p, d_x, d_norm = backward(*res, dy)
+        return d_p, d_x, None, d_norm
+
+    apply.defvjp(fwd, bwd)
+    return apply
+
+
+@jax.named_scope("latent_attention")
+def mla_apply(p, x, n_heads: int, cfg: MLAConfig, rope, pre_norm=None):
+    """Latent attention over ``x [B, L, D] -> [B, L, D]``, causal.
+    ``rope`` is :func:`mla_rope_angles`'s pair. The rotation pairs
+    channel ``i`` of the rotary part with ``i + rope_dim / 2``
+    (:func:`apply_rope`). ``pre_norm``: the RMSNorm that stands before
+    the mixer, applied here (so that a mixer that keeps its own inputs
+    for the backward keeps the block's and not a normalised copy too).
+
+    Where the configuration has an indexer and the sequence is longer
+    than its ``topk`` (``cfg.selects``), a query attends the keys
+    :func:`select_keys` chose for it, the mixer runs a client at a time
+    under a client ``vmap`` and recomputes itself in the backward
+    (:func:`_choosing_mla`). Anywhere else this is the mixer it was."""
+    if cfg.selects(x.shape[1]):
+        return _choosing_mla(n_heads, cfg)(p, x, rope, pre_norm)
+    return _mla(p, x, rope, None, n_heads, cfg, pre_norm)[0]
 
 
 # ---------------------------------------------------------------------------

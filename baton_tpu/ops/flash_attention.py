@@ -21,7 +21,7 @@ replacing the dense ``dot_product_attention`` einsum path
 * **GQA for free**: the kv-head block index map sends query head ``h``
   to kv head ``h // (Hq//Hkv)`` — no ``jnp.repeat`` materialization;
 * **values as wide as they are**: ``Dv`` is read from ``v`` and need not
-  be the keys' ``Dk`` (latent attention: 192 / 128,
+  be the keys' ``Dk`` (latent attention: 192 / 128 or 256 / 256,
   ``transformer.py::causal_core``); the value-side blocks (``v``, the
   output, its cotangent, ``dv``, the accumulator) are ``Dv`` wide, the
   key-side ones (``q``, ``k``, ``dq``, ``dk``) ``Dk``, and where ``Dk``
@@ -30,7 +30,16 @@ replacing the dense ``dot_product_attention`` einsum path
   is an argument (``Dk ** -0.5`` unless given);
 * matches the seam contract ``attention_fn(q, k, v, bias, causal)``
   (transformer.py:31-32): additive per-key bias [B, 1, 1, L], static
-  causal masking from global positions.
+  causal masking from global positions;
+* **a choice of keys a query** beyond that contract: ``chosen
+  [B, Lq, Lk]`` (int8, one for all heads; latent attention whose
+  learned index chose each query's keys, ``transformer.py::
+  chosen_keys``) masks a tile's scores beside the causal rule in the
+  forward and both backward kernels. Every tile the causal rule leaves
+  is still visited (a learned choice scatters over the prefix; a tile
+  no query chose anything of could be skipped and is not), so the work
+  is the dense causal core's. A call without one builds the kernels it
+  built before there was any.
 
 Interpret mode exists for the CPU tests (same code path, same math)
 and is refused on a TPU backend — :func:`_resolve_interpret` is the one
@@ -121,10 +130,13 @@ def _key_side_spec(d_major, block, dk, index_map):
     return _spec(shape, place)
 
 
-def _scores(q, k, bias, *, scale, causal, d_major, i, j, block_q, block_k):
+def _scores(q, k, bias, chosen, *, scale, causal, d_major, i, j, block_q,
+            block_k):
     """One tile's float32 scores ``[block_q, block_k]`` of ``q [bq, Dk]``
     and ``k [bk, Dk]`` (``[Dk, bq]`` and ``[Dk, bk]`` with ``d_major``)
-    plus the keys' ``bias [1, bk]``: the operands stay in their dtype
+    plus the keys' ``bias [1, bk]``, masked where the tile of ``chosen
+    [bq, bk]`` (int8, or None: every key) is zero: the operands stay in
+    their dtype
     (the MXU multiplies bfloat16 natively and accumulates in float32;
     upcasting first would force 4-8x slower float32 passes), contracted
     by ``dot_general`` (an explicit ``k.T`` would force a Mosaic relayout
@@ -143,6 +155,8 @@ def _scores(q, k, bias, *, scale, causal, d_major, i, j, block_q, block_k):
             jnp.int32, (block_q, block_k), 1
         )
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    if chosen is not None:
+        s = jnp.where(chosen.astype(jnp.int32) != 0, s, NEG_INF)
     return s
 
 
@@ -179,9 +193,11 @@ def _first_needed_q(causal, i, j, block_q, block_k):
 # forward kernel: grid (B, Hq, Lq/block_q, Lk/block_k)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref,
-                *, scale, causal, d_major, block_q, block_k, nk):
+def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest,
+                scale, causal, d_major, block_q, block_k, nk):
+    # rest: the tile of chosen keys where the call has one, then the
+    # outputs and the scratch
+    *c_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
     # Grid (B, Hq, Lq/bq, Lk/bk) with the kv axis INNERMOST ('arbitrary'):
     # the online-softmax state (acc/m/l) lives in VMEM scratch across the
     # j loop while Mosaic double-buffers the k/v block DMAs — the r2
@@ -201,7 +217,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
     def _accumulate():
         vj = v_ref[...]                                  # [bk, Dv]
         # softmax statistics are fp32 throughout
-        s = _scores(q_ref[...], k_ref[...], b_ref[...], scale=scale,
+        s = _scores(q_ref[...], k_ref[...], b_ref[...],
+                    c_ref[0][...] if c_ref else None, scale=scale,
                     causal=causal, d_major=d_major, i=i, j=j,
                     block_q=block_q, block_k=block_k)
         m_prev = m_ref[:, :1]                            # [bq, 1]
@@ -227,6 +244,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         lse_ref[0, :] = (m + jnp.log(l))[:, 0]
 
 
+def _vmem_limit(chosen) -> dict:
+    """What a kernel with a tile of chosen keys says of VMEM: at blocks
+    of 1,024 and heads of 256 / 256 its tiles are 1.8 MB past the
+    compiler's default scoped limit (compiled for a v5e, PR 39). A
+    kernel without one says what it said."""
+    return ({} if chosen is None
+            else {"vmem_limit_bytes": _ONE_KERNEL_VMEM_LIMIT_BYTES})
+
+
 def _compiler_params(n_parallel: int, n_sequential: int = 1, **kwargs):
     """Mark the leading grid axes parallel, the innermost sequential."""
     return pltpu.CompilerParams(
@@ -238,7 +264,18 @@ def _scratch(shape, dtype=jnp.float32):
     return pltpu.VMEM(shape, dtype)
 
 
-def _fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
+def _chosen_spec(block_q, block_k, place):
+    """The tile of ``chosen [B, Lq, Lk]`` a grid step reads; ``place``:
+    grid indices -> (block of queries, block of keys)."""
+    def at(b_, h, x, y):
+        qi, kj = place(x, y)
+        return (b_, qi, kj)
+
+    return _spec((None, block_q, block_k), at)
+
+
+def _fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
+         interpret):
     b, hq, lq, dk = q.shape
     hkv, lk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
@@ -266,7 +303,8 @@ def _fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
             _spec((None, None, block_k, dv),
                   lambda b_, h, i, j: (b_, h // group, kj(i, j), 0)),
             _spec((None, 1, block_k), lambda b_, h, i, j: (b_, 0, kj(i, j))),
-        ],
+        ] + ([] if chosen is None else [
+            _chosen_spec(block_q, block_k, lambda i, j: (i, kj(i, j)))]),
         out_specs=[
             _spec((None, None, block_q, dv), lambda b_, h, i, j: (b_, h, i, 0)),
             _spec((None, None, 1, block_q), lambda b_, h, i, j: (b_, h, 0, i)),
@@ -280,9 +318,11 @@ def _fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
             _scratch((block_q, 128)),
             _scratch((block_q, 128)),
         ],
-        compiler_params=None if interpret else _compiler_params(3),
+        compiler_params=None if interpret else _compiler_params(
+            3, **_vmem_limit(chosen)),
         interpret=interpret,
-    )(q, k, v, bias2d.reshape(b, 1, lk))
+    )(q, k, v, bias2d.reshape(b, 1, lk),
+      *(() if chosen is None else (chosen,)))
     return out, lse.reshape(b, hq, lq)
 
 
@@ -308,11 +348,15 @@ def _fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
 #   full [L, d] buffers).
 
 # what the one-kernel form may hold of VMEM for dq, twice (Pallas
-# double-buffers an output block): L = 8,192 at Dk = 128. Its [bq, bk]
-# float32 temporaries (scores, p, dp, ds) are past the compiler's default
-# scoped limit at blocks of 1,024, so the kernel states its own; a v5e's
-# VMEM is 128 MiB.
-_DQ_RESIDENT_BYTES = 8 * 1024 ** 2
+# double-buffers an output block): L = 8,192 at Dk = 256, 16,384 at 128.
+# Its [bq, bk] float32 temporaries (scores, p, dp, ds) are past the
+# compiler's default scoped limit at blocks of 1,024, so the kernel
+# states its own; a v5e's VMEM is 128 MiB. 8 MiB (PR 34, sized at
+# 192 / 128 and 2,048 keys) put [1, 64, 8192, 256 / 256] on the two
+# passes: forward and backward 74.3 ms a call there against 58.6 in one
+# kernel (my chip run, PR 39; 72.5 against 57.7 without a choice of
+# keys)
+_DQ_RESIDENT_BYTES = 16 * 1024 ** 2
 _ONE_KERNEL_VMEM_LIMIT_BYTES = 64 * 1024 ** 2
 
 
@@ -320,11 +364,13 @@ def _dq_fits_vmem(lq: int, dk: int) -> bool:
     return 2 * 4 * lq * dk <= _DQ_RESIDENT_BYTES
 
 
-def _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref, *,
-              scale, causal, d_major, i, j, block_q, block_k):
+def _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref, c_ref,
+              *, scale, causal, d_major, i, j, block_q, block_k):
     """A tile's probabilities from the saved log-sum-exp, and the
-    gradient of its scores, both float32 ``[bq, bk]``."""
-    s = _scores(q_ref[...], k_ref[...], b_ref[...], scale=scale,
+    gradient of its scores, both float32 ``[bq, bk]``. ``c_ref``: the
+    tile of chosen keys, or None."""
+    s = _scores(q_ref[...], k_ref[...], b_ref[...],
+                None if c_ref is None else c_ref[...], scale=scale,
                 causal=causal, d_major=d_major, i=i, j=j, block_q=block_q,
                 block_k=block_k)
     p = jnp.exp(s - lse_ref[0][:, None])                       # [bq, bk]
@@ -349,9 +395,12 @@ def _grad_of_keys(x, ds, over: int, d_major: bool):
 
 
 def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
-                    *out_refs, scale, causal, d_major, block_q, block_k):
-    # out_refs is (dk, dv, db) in the two-pass form; the one-kernel form
-    # puts the head's whole dq [Lq, Dk] first
+                    *rest, scale, causal, d_major, block_q, block_k,
+                    has_chosen):
+    # rest: the tile of chosen keys where the call has one, then (dk, dv,
+    # db) in the two-pass form; the one-kernel form puts the head's whole
+    # dq [Lq, Dk] first
+    c_ref, out_refs = (rest[0], rest[1:]) if has_chosen else (None, rest)
     *dq_ref, dk_ref, dv_ref, db_ref = out_refs
     j = pl.program_id(2)
     i = pl.program_id(3)
@@ -366,8 +415,9 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
         qi = q_ref[...]                              # [bq, Dk] ([Dk, bq])
         doi = do_ref[...]                                      # [bq, Dv]
         p, ds = _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                          b_ref, scale=scale, causal=causal, d_major=d_major,
-                          i=i, j=j, block_q=block_q, block_k=block_k)
+                          b_ref, c_ref, scale=scale, causal=causal,
+                          d_major=d_major, i=i, j=j, block_q=block_q,
+                          block_k=block_k)
         # contract the bq axis directly (p^T·do, ds^T·q without transposes)
         dv_ref[...] += lax.dot_general(
             p.astype(doi.dtype), doi, (((0,), (0,)), ((), ())),
@@ -397,7 +447,9 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
 
 
 def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
-                   dq_ref, *, scale, causal, d_major, block_q, block_k):
+                   *rest, scale, causal, d_major, block_q, block_k,
+                   has_chosen):
+    c_ref, dq_ref = rest if has_chosen else (None, rest[0])
     i = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -408,8 +460,9 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
     def _accumulate():
         kj = k_ref[...]
         _, ds = _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                          b_ref, scale=scale, causal=causal, d_major=d_major,
-                          i=i, j=j, block_q=block_q, block_k=block_k)
+                          b_ref, c_ref, scale=scale, causal=causal,
+                          d_major=d_major, i=i, j=j, block_q=block_q,
+                          block_k=block_k)
         dq_ref[...] += scale * _grad_of_keys(kj, ds.astype(kj.dtype), 1,
                                              d_major)
 
@@ -418,7 +471,7 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
 
 
 def _bwd_call(q, k, v, bias2d, out, dout, lse,
-              causal, scale, block_q, block_k, interpret):
+              causal, scale, block_q, block_k, interpret, chosen=None):
     b, hq, lq, dk_ = q.shape
     hkv, lk, dv_ = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
@@ -436,6 +489,8 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
     # kept last-two block dims satisfy Mosaic's (8, 128) tiling rule
     operands = (q, dout, lse.reshape(b, hq, 1, lq),
                 delta.reshape(b, hq, 1, lq), k, v, bias2d.reshape(b, 1, lk))
+    if chosen is not None:
+        operands += (chosen,)
 
     def in_specs(qi, kj):
         """Common input specs; ``qi``/``kj`` pick the q/kv block index out
@@ -453,7 +508,8 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
             _spec((None, None, block_k, dv_),
                   lambda b_, h, x, y: (b_, h // group, kj(x, y), 0)),
             _spec((None, 1, block_k), lambda b_, h, x, y: (b_, 0, kj(x, y))),
-        ]
+        ] + ([] if chosen is None else [_chosen_spec(
+            block_q, block_k, lambda x, y: (qi(x, y), kj(x, y)))])
 
     def key_side_shape(length):
         return jax.ShapeDtypeStruct(
@@ -461,7 +517,8 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
             jnp.float32)
 
     kernel_args = dict(scale=scale, causal=causal, d_major=d_major,
-                       block_q=block_q, block_k=block_k)
+                       block_q=block_q, block_k=block_k,
+                       has_chosen=chosen is not None)
 
     # dk/dv/db (and dq where it fits) — grid (…, kv, q), q innermost
     # (accumulated over)
@@ -482,7 +539,7 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
         params = _compiler_params(
             2, 2, vmem_limit_bytes=_ONE_KERNEL_VMEM_LIMIT_BYTES)
     else:
-        params = _compiler_params(3)
+        params = _compiler_params(3, **_vmem_limit(chosen))
     *dq, dk_h, dv_h, db_h = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kernel_args),
         grid=(b, hq, nk, nq),
@@ -507,7 +564,8 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
             out_specs=[_key_side_spec(d_major, block_q, dk_,
                                       lambda b_, h, x, y: (b_, h, x))],
             out_shape=[key_side_shape(lq)],
-            compiler_params=None if interpret else _compiler_params(3),
+            compiler_params=None if interpret else _compiler_params(
+                3, **_vmem_limit(chosen)),
             interpret=interpret,
         )(*operands)
     dq = dq[0]
@@ -525,30 +583,34 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
 # custom-vjp core (static: causal/scale/blocks/interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
-    out, _ = _fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
+           interpret):
+    out, _ = _fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
+                  interpret)
     return out
 
 
-def _flash_fwd(q, k, v, bias2d, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
+               interpret):
     out, lse = _fwd(
-        q, k, v, bias2d, causal, scale, block_q, block_k, interpret
+        q, k, v, bias2d, chosen, causal, scale, block_q, block_k, interpret
     )
-    return out, (q, k, v, bias2d, out, lse)
+    return out, (q, k, v, bias2d, chosen, out, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, dout):
-    q, k, v, bias2d, out, lse = res
+    q, k, v, bias2d, chosen, out, lse = res
     dq, dk, dv, dbias = _bwd_call(
         q, k, v, bias2d, out, dout, lse,
-        causal, scale, block_q, block_k, interpret,
+        causal, scale, block_q, block_k, interpret, chosen,
     )
     return (
         dq.astype(q.dtype),
         dk.astype(k.dtype),
         dv.astype(v.dtype),
         dbias.astype(bias2d.dtype),
+        None,  # the choice is no function of anything that trains
     )
 
 
@@ -569,15 +631,22 @@ def flash_attention(
     block_k: int = 1024,
     interpret: Optional[bool] = None,
     scale: Optional[float] = None,
+    chosen: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Flash attention matching ``dot_product_attention`` semantics
     (transformer.py:105-133): q [B, Hq, L, Dk], k [B, Hkv, L, Dk],
     v [B, Hkv, L, Dv] (``Dv`` is read from ``v`` and need not be ``Dk``:
-    latent attention's values are narrower than its keys), optional
-    additive per-key ``bias`` [B, 1, 1, L], fp32 softmax of the scores
-    times ``scale`` (``Dk ** -0.5`` unless given), returns
-    [B, Hq, L, Dv] in q's dtype. Differentiable via Pallas
-    forward+backward kernels.
+    latent attention's values are as wide as its keys or narrower,
+    256 / 256 and 192 / 128), optional additive per-key ``bias``
+    [B, 1, 1, L], optional ``chosen`` [B, Lq, Lk] (int8; a query
+    attends the keys where it is nonzero, one choice for all heads,
+    beside the causal rule and the bias; every query has to have
+    chosen a key it may see), fp32 softmax of the scores times
+    ``scale`` (``Dk ** -0.5`` unless given), returns [B, Hq, L, Dv] in
+    q's dtype. Differentiable via Pallas forward+backward kernels.
+    The kernels visit every tile the causal rule leaves and mask what
+    was not chosen; with no ``chosen`` they take the operands and are
+    the kernels they were.
 
     Sequence lengths are padded to the block size internally (padded
     keys get -inf bias; padded query rows are sliced off), so any L
@@ -606,8 +675,14 @@ def flash_attention(
     q = _pad_len(q, pad_q)
     k, v = _pad_len(k, pad_k), _pad_len(v, pad_k)
     bias2d = _pad_bias2d(bias2d, pad_k)
+    if chosen is not None:
+        assert chosen.shape == (b, lq, lk), (
+            f"chosen must be [B, Lq, Lk], got {chosen.shape}")
+        chosen = jnp.pad(chosen.astype(jnp.int8),
+                         ((0, 0), (0, pad_q), (0, pad_k)))
 
-    out = _flash(q, k, v, bias2d, causal, scale, block_q, block_k, interpret)
+    out = _flash(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
+                 interpret)
     if pad_q:
         out = out[:, :, :lq, :]
     return out
@@ -682,7 +757,7 @@ def flash_block_fwd(q, k, v, bias2d, causal, block_q=512, block_k=1024,
     q = _pad_len(q, pad_q)
     k, v = _pad_len(k, pad_k), _pad_len(v, pad_k)
     bias2d = _pad_bias2d(bias2d, pad_k)
-    out, lse = _fwd(q, k, v, bias2d.astype(jnp.float32), causal, scale,
+    out, lse = _fwd(q, k, v, bias2d.astype(jnp.float32), None, causal, scale,
                     block_q, block_k, interpret)
     if pad_q:
         out = out[:, :, :lq, :]

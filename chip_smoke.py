@@ -527,6 +527,138 @@ def phase_moe_mla_lora(env: Env) -> None:
             f"{_mean(apart[~agree]):.4f} over the other {(~agree).sum()}")
 
 
+def phase_dsa_mla_lora(env: Env) -> None:
+    """Latent attention whose queries choose their keys: one expert
+    layer of ``glm_5`` at the published widths (in rehearsal at its
+    ``tiny`` sizes) on one sequence of ``glm5_c4_l8192``, the frozen
+    base alone. The program's layer (bfloat16 activations, the index
+    scores in blocks, the choice by bisection, the core the flash
+    kernel with the choice as a mask on a TPU, grouped products) beside
+    the same layer in float32 at ``highest`` (the blocked plain core,
+    the choice by ``lax.top_k``, the oracle's loop over experts):
+    the share of a query's chosen keys on which the two agree, which is
+    what stands between the probe's two sides beside rounding and the
+    router; the distance between the two outputs, a token, over the
+    queries that chose alike and over the others; and the time of the
+    choice by either method on the program's scores, which have to give
+    the same threshold and index."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models import llama, moe, transformer
+    from fedbench import data as cohort, manifest
+
+    root = manifest.ROOT
+    config = manifest.load_config(root, manifest.load_manifest(root), "glm_5")
+    job = manifest.load_workload(root, "glm5_c4_l8192")
+    tiny = env.rehearsal
+    if tiny:
+        job.update(job["tiny"])
+    sized = dict(manifest.sized(config, tiny), num_hidden_layers=1,
+                 first_k_dense_replace=0)
+    decoder = manifest.resolve(config["builder"]["kwargs"]["config"], sized)
+    mla, length, topk = decoder.mla, job["seq_len"], decoder.mla.indexer.topk
+    seed = 13
+    model = llama.llama_lm_model(
+        decoder, param_dtype=jnp.float32 if tiny else jnp.bfloat16)
+    base = jax.jit(model.init)(jax.random.key(seed))
+    blk = base["blocks"][0]
+    ids = cohort.make_cohort(
+        root, manifest.input_spec(config, tiny), np.asarray([1], np.int32),
+        1, length, cohort.data_key(seed + 1))["x"][0]            # [1, L]
+    rope = transformer.mla_rope_angles(length, mla)
+
+    def index_of(x):
+        h = transformer.rms_normalize(x, blk["norm_attn"]["scale"],
+                                      mla.norm_eps)
+        q_in = transformer.rms_normalize(
+            h @ blk["mla"]["wq_a"].astype(x.dtype),
+            blk["mla"]["q_a_norm"]["scale"], mla.norm_eps)
+        return transformer.index_scores(blk["mla"]["indexer"], h, q_in, mla,
+                                        rope)
+
+    def by_sort(scores):
+        """``select_keys`` by a sort: the ``topk`` largest in order, ties
+        to the lower index, so the last of them is the threshold and the
+        index at which its equals run out."""
+        value, at = jax.lax.top_k(scores, topk)
+        whole = jnp.arange(length) < topk
+        return (jnp.where(whole, -jnp.inf, value[..., -1]),
+                jnp.where(whole, length - 1, at[..., -1]))
+
+    def layer(x, plain: bool):
+        if not plain:
+            return llama._block_apply(blk, x, decoder, rope, None)
+        x = x + transformer.mla_apply(blk["mla"], x, decoder.n_heads, mla,
+                                      rope, pre_norm=blk["norm_attn"])
+        return x + moe.moe_dense_oracle(
+            blk["mlp"], transformer.rms_norm(x, blk["norm_mlp"],
+                                             decoder.norm_eps), decoder.moe)
+
+    def timed(fn, *args):
+        out = jax.block_until_ready(fn(*args))  # compiled here
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        return out, time.perf_counter() - t0
+
+    dtype = jnp.float32 if tiny else jnp.bfloat16
+    x = base["tok_emb"][ids].astype(dtype)
+    scores = jax.jit(index_of)(x)
+    bisect, t_bisect = timed(jax.jit(
+        lambda s: transformer.select_keys(s, topk)), scores)
+    sort, t_sort = timed(jax.jit(by_sort), scores)
+    _check(all(bool(jnp.array_equal(a, b)) for a, b in zip(bisect, sort)),
+           "bisection and sort chose differently on the same scores")
+    chose = np.asarray(transformer.chosen_keys(scores, *bisect))[0] != 0
+    out = np.asarray(jax.jit(partial(layer, plain=False))(x)[0], np.float32)
+
+    with jax.default_matmul_precision("highest"):
+        x32 = base["tok_emb"][ids].astype(jnp.float32)
+        scores32 = jax.jit(index_of)(x32)
+        chose32 = np.asarray(transformer.chosen_keys(
+            scores32, *jax.jit(by_sort)(scores32)))[0] != 0
+        # the oracle's core is the blocked plain one, on a TPU too
+        kernel, transformer.core_runs_the_kernel = (
+            transformer.core_runs_the_kernel, lambda *_: False)
+        try:
+            out32 = np.asarray(jax.jit(partial(layer, plain=True))(x32)[0])
+        finally:
+            transformer.core_runs_the_kernel = kernel
+    n_keys = np.minimum(np.arange(length) + 1, topk)
+    _check((chose.sum(-1) == n_keys).all() and (chose32.sum(-1) == n_keys).all(),
+           "a query chose another number of keys than min(t + 1, topk)")
+    common = (chose & chose32).sum(-1)
+    chooses = np.arange(length) >= topk
+    alike = common == n_keys
+    apart = np.linalg.norm(out - out32, axis=-1) \
+        / np.linalg.norm(out32, axis=-1)
+    _check(np.isfinite(apart).all(), "a non-finite output")
+    _check(tiny or float(apart.mean()) < 10 * BF16_TOL,
+           f"the two layers lie {apart.mean():.3f} apart")
+
+    def _mean(a):
+        return float(a.mean()) if a.size else float("nan")
+
+    def ms(seconds):
+        return ("not measured (rehearsal)" if env.rehearsal
+                else f"{1e3 * seconds:.1f} ms")
+
+    env.say("dsa_mla_lora",
+            f"one expert layer of glm_5 at {'tiny' if tiny else 'the published'} "
+            f"sizes, {length} tokens, {topk} keys a query, seed {seed}: "
+            f"{'float32' if tiny else 'bfloat16'} and float32 at highest "
+            f"agree on {100 * _mean(common[chooses] / topk):.3f} % of a "
+            f"choosing query's keys ({int(alike[chooses].sum())} of "
+            f"{int(chooses.sum())} choosing queries chose alike); the two "
+            f"outputs lie apart by {_mean(apart[alike]):.4f} of the float32 "
+            f"one's norm, a token, over the {int(alike.sum())} queries that "
+            f"chose alike, by {_mean(apart[~alike]):.4f} over the other "
+            f"{int((~alike).sum())}; the choice of [{length}, {length}] "
+            f"scores by bisection {ms(t_bisect)}, by sort {ms(t_sort)}, the "
+            f"same threshold and index a query")
+
+
 def _flash_alone(env: Env) -> str:
     import jax
     import jax.numpy as jnp
@@ -962,6 +1094,7 @@ def phase_cache(env: Env) -> None:
 PHASES = {"device": phase_device, "fedsim_resnet18": phase_fedsim_resnet18,
           "hybrid_lora": phase_hybrid_lora,
           "moe_mla_lora": phase_moe_mla_lora,
+          "dsa_mla_lora": phase_dsa_mla_lora,
           "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
           "mesh": phase_mesh, "cache": phase_cache}
 

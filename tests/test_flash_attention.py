@@ -84,9 +84,10 @@ def test_the_backward_is_one_kernel_while_dq_fits_in_vmem():
     from baton_tpu.ops.flash_attention import _dq_fits_vmem
 
     assert _dq_fits_vmem(2048, 192)      # latent attention's core
+    assert _dq_fits_vmem(8192, 256)      # and where its queries choose
     assert _dq_fits_vmem(8192, 128)
-    assert not _dq_fits_vmem(16384, 128)  # long contexts: two passes
-    assert not _dq_fits_vmem(32768, 64)
+    assert not _dq_fits_vmem(16384, 256)  # long contexts: two passes
+    assert not _dq_fits_vmem(32768, 128)
 
 
 @pytest.mark.parametrize("causal", [False, True])

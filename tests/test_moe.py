@@ -153,6 +153,43 @@ def test_under_a_client_vmap_in_a_gradient_in_a_checkpoint(cfg, nprng):
         _close(got[1][c], want[1], rtol=1e-4)
 
 
+def test_a_fold_too_large_goes_a_client_at_a_time(nprng, monkeypatch):
+    """Past ``_FOLDED_ROWS_BYTES`` of sorted rows (four clients of 8,192
+    tokens at 6,144 channels would fold 3 GiB) the shared stacks are
+    read a client at a time: a ``while`` over the clients whose grouped
+    products see one client's rows, with the values and gradients the
+    fold gives."""
+    from baton_tpu.models import moe
+
+    p = _params(SHARE)
+    xs = jnp.asarray(nprng.normal(size=(3, 2, 12, D)), jnp.float32)
+
+    def step(xs):
+        return jax.vmap(jax.value_and_grad(
+            lambda x: jnp.sum(moe_apply(p, x, SHARE) ** 2)))(xs)
+
+    folded = jax.jit(step)(xs)
+    text = str(jax.make_jaxpr(step)(xs))
+    # 3 clients x 24 tokens x 2 choices, folded into one product's rows
+    assert "f32[144,16]" in text
+    # 3 x 24 x 2 assignments of 16 float32 channels are 9,216 bytes
+    monkeypatch.setattr(moe, "_FOLDED_ROWS_BYTES", 9215)
+    jax.clear_caches()  # the limit is read when the rule is traced
+    text = str(jax.make_jaxpr(step)(xs))
+    assert "f32[144,16]" not in text and "f32[48,16]" in text
+    mapped = jax.jit(lambda xs: step(xs))(xs)
+    _close(mapped[0], folded[0], rtol=1e-5)
+    _close(mapped[1], folded[1], rtol=1e-4)
+    monkeypatch.setattr(moe, "_FOLDED_ROWS_BYTES", 9216)
+    jax.clear_caches()
+    assert "f32[144,16]" in str(jax.make_jaxpr(step)(xs))
+    jax.clear_caches()
+    # the cells: sarvam_105b_c4_l2048 folds, glm5_c4_l8192 does not
+    limit = 1024 ** 3
+    assert 4 * 2048 * 8 * 4096 * 2 <= limit < 4 * 8192 * 8 * 6144 * 2
+    assert 8192 * 8 * 6144 * 2 <= limit
+
+
 def test_stacks_that_carry_the_client_axis_take_the_map(nprng):
     """Experts that train are a client's own: their gradients under the
     ``vmap`` are each client's, as the loop gives them; and a client's
