@@ -52,7 +52,7 @@ from baton_tpu.core.model import FedModel
 from baton_tpu.core.partition import path_str
 from baton_tpu.models.delta_rule import gated_delta_apply, gated_delta_init
 from baton_tpu.models.lora import lora_wrap
-from baton_tpu.models.moe import MoEConfig, moe_apply, moe_init
+from baton_tpu.models.moe import MoEConfig, moe_apply, moe_init, rows_bound
 from baton_tpu.models.transformer import (
     AttentionFn,
     MLAConfig,
@@ -312,8 +312,12 @@ def llama_lm_model(
             jnp.sum(m, axis=-1), 1.0
         )
 
+    # what an expert layer holds, and the sorted rows it handles at a
+    # time for every 1,024 tokens it sorts together (it grows with them)
     facts = () if cfg.moe is None else (
-        ("experts_held", cfg.moe.held), ("experts_total", cfg.moe.n_experts))
+        ("experts_held", cfg.moe.held), ("experts_total", cfg.moe.n_experts),
+        ("routed_rows_bound", rows_bound(1024 * cfg.moe.top_k, cfg.moe.held,
+                                         cfg.moe.n_experts)))
     return FedModel(init=init, apply=apply, per_example_loss=per_example_loss,
                     name=name, aux=cfg, span_attrs=facts)
 

@@ -19,6 +19,21 @@ every token are sorted by expert, each projection is one grouped matrix
 product over the held experts' rows (:func:`grouped_matmul`), and the
 rows go back to their tokens weighted by ``g``.
 
+Only the sort runs over every assignment (``N = T top_k`` int32s).
+What follows it, the rows gathered from the tokens, the grouped
+products, the SiLU and the backward's float32 passes, the return,
+handles a block of ``R`` consecutive sorted rows at a time, where ``R``
+(:func:`rows_bound`) is the held rows a uniform router would give,
+``N held / n_experts``, times 2, rounded up to the grouped product's
+row tile, at most ``N``: a quarter of the assignments where 16 of 128
+experts are held, a sixteenth where 8 of 256 are. The held rows come
+first in the sorted order, so the first block holds them all unless the
+routing gives this rank more than twice its share; then further blocks
+run while held rows are left (a ``lax.while_loop``; a block's group
+sizes are the running sizes clipped to its window). ``R`` bounds the
+arrays, never the rows computed. A layer that holds every expert is
+one block over every row, with no loop.
+
 Under a ``vmap`` over clients (``FedSim``'s wave) the expert stacks
 carry no client axis when they are frozen: the routed part is a
 ``jax.custom_vjp`` whose forward and backward are ``custom_vmap``
@@ -35,6 +50,7 @@ stacks' own gradients are computed only where something asks for them
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional
 
 import jax
@@ -189,70 +205,161 @@ def _sorted_rows(idx, n_held: int):
     return order, place, sizes, flat[order] < n_held
 
 
-def _routed_forward(x, idx, gate, w_gate, w_up, w_down):
+# ------------------------------------------------- the rows a rank holds
+# the room a block of sorted rows has over the held rows a uniform
+# router would give, ``N held / n_experts``. The cells' seeds fill 0.97
+# to 1.05 of that expectation (chip_smoke.py prints it a layer), so one
+# block holds them with room to spare; a routing that does not fit runs
+# further blocks, it does not lose rows
+_ROWS_HEADROOM = 2
+
+
+def rows_bound(n: int, held: int, n_experts: int) -> int:
+    """``R``: the sorted rows that everything past the sort handles at a
+    time, of ``n`` assignments where ``held`` of ``n_experts`` experts
+    are held: the expected held rows times ``_ROWS_HEADROOM``, rounded
+    up to the grouped product's row tile, at most ``n`` (every expert
+    held: ``n``, and the layer is one block over every row)."""
+    tile = _GMM_TILING[0]
+    expected = -(-n * held // n_experts)
+    return min(n, -(-_ROWS_HEADROOM * expected // tile) * tile)
+
+
+def _block_sizes(sizes, lo, r: int):
+    """The rows of each held expert inside the window of sorted rows
+    ``[lo, lo + r)``: the running sizes clipped to it. They sum to the
+    held rows in the window."""
+    ends = jnp.cumsum(sizes)
+    return jnp.clip(ends, lo, lo + r) - jnp.clip(ends - sizes, lo, lo + r)
+
+
+def _over_blocks(block, r: int, zeros, order, live, sizes):
+    """``block(acc, order_j, live_j, sizes_j) -> acc`` over the blocks
+    ``j`` of ``r`` consecutive sorted rows that hold a held expert's
+    row, from ``acc = zeros()`` on: ``ceil(sum(sizes) / r)`` trips of a
+    ``lax.while_loop`` (nothing differentiates it: both directions of
+    the layer are written by hand). With ``r`` every row there is one
+    block and no loop: ``block(None, order, live, sizes)``."""
+    n = order.shape[0]
+    with jax.named_scope("routed_block"):
+        if r == n:
+            return block(None, order, live, sizes)
+        pad = -n % r  # a last window past ``n``: rows of no expert
+        order, live = jnp.pad(order, (0, pad)), jnp.pad(live, (0, pad))
+
+        def one(carry):
+            j, acc = carry
+            lo = j * r
+            return j + 1, block(
+                acc, jax.lax.dynamic_slice_in_dim(order, lo, r),
+                jax.lax.dynamic_slice_in_dim(live, lo, r),
+                _block_sizes(sizes, lo, r))
+
+        return jax.lax.while_loop(
+            lambda carry: carry[0] * r < jnp.sum(sizes), one,
+            (jnp.int32(0), zeros()))[1]
+
+
+def _routed_forward(n_experts, x, idx, gate, w_gate, w_up, w_down):
     """``x [T, D]``, local expert ids ``idx [T, K]``, weights ``gate
     [T, K]``: the held experts' part of the layer's result ``[T, D]``,
     as a tuple of one (``_over_clients`` takes tuples)."""
-    order, place, sizes, _ = _sorted_rows(idx, w_gate.shape[0])
-    rows = x[order // idx.shape[1]]
-    mid = jax.nn.silu(grouped_matmul(rows, w_gate, sizes)) \
-        * grouped_matmul(rows, w_up, sizes)
-    out = grouped_matmul(mid, w_down, sizes)
-    return (jnp.sum(out[place].astype(jnp.float32) * gate[..., None],
-                    axis=1).astype(x.dtype),)
+    t, k = idx.shape
+    order, place, sizes, live = _sorted_rows(idx, w_gate.shape[0])
+
+    def block(acc, order, live, sizes):
+        rows = x[order // k]
+        mid = jax.nn.silu(grouped_matmul(rows, w_gate, sizes)) \
+            * grouped_matmul(rows, w_up, sizes)
+        out = grouped_matmul(mid, w_down, sizes)
+        if acc is None:  # every row is here: a token reads its own K
+            return jnp.sum(out[place].astype(jnp.float32) * gate[..., None],
+                           axis=1)
+        # a block's rows are summed into their tokens. On a v5e at the
+        # cells' shapes (my chip run, PR 40) that is 4.27 ms for 16,384
+        # rows of 4,096 where the gather of [T, K] rows, most of them
+        # zero rows past the block, is 5.97, and the layer's three
+        # passes 32.0 ms for 38.7 (108.4 for 154.8 at 6,144 channels)
+        return acc.at[order // k].add(out.astype(jnp.float32)
+                                      * gate.reshape(-1)[order][:, None])
+
+    y = _over_blocks(
+        block, rows_bound(t * k, w_gate.shape[0], n_experts),
+        lambda: jnp.zeros(x.shape, jnp.float32), order, live, sizes)
+    return (y.astype(x.dtype),)
 
 
-def _routed_backward(x, idx, gate, dy, w_gate, w_up, w_down):
+def _routed_backward(n_experts, x, idx, gate, dy, w_gate, w_up, w_down):
     """The cotangents of ``x``, ``gate`` and the three stacks, the
     forward's products recomputed (the block is under ``remat``
-    anyway). Gathers only: a sorted row reads its token's ``dy``, a
-    token sums its ``K`` rows."""
+    anyway). A sorted row reads its token's ``x`` and ``dy``."""
+    t, k = idx.shape
     order, place, sizes, live = _sorted_rows(idx, w_gate.shape[0])
-    token = order // idx.shape[1]
-    rows = x[token]
-    a = grouped_matmul(rows, w_gate, sizes).astype(jnp.float32)
-    b = grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
-    sig = jax.nn.sigmoid(a)
-    mid = a * sig * b
-    dy_rows = dy[token]
-    # d out . w_down^T once, unweighted: its product with mid is the
-    # gate's cotangent, weighted by the gate it is mid's
-    u = grouped_matmul(dy_rows, w_down, sizes, transpose_rhs=True
-                       ).astype(jnp.float32)
-    g_rows = jnp.where(live, gate.reshape(-1)[order], 0.0)[:, None]
-    d_gate = jnp.sum(u * mid, axis=-1)[place]
-    d_mid = u * g_rows
-    d_a = (d_mid * b * sig * (1.0 + a * (1.0 - sig))).astype(x.dtype)
-    d_b = (d_mid * a * sig).astype(x.dtype)
-    d_rows = grouped_matmul(d_a, w_gate, sizes, transpose_rhs=True) \
-        + grouped_matmul(d_b, w_up, sizes, transpose_rhs=True)
-    d_x = jnp.sum(d_rows[place].astype(jnp.float32), axis=1).astype(x.dtype)
-    d_out = (dy_rows.astype(jnp.float32) * g_rows).astype(x.dtype)
-    stacks = (_grouped_outer(rows, d_a, sizes).astype(w_gate.dtype),
-              _grouped_outer(rows, d_b, sizes).astype(w_up.dtype),
-              _grouped_outer(mid.astype(x.dtype), d_out, sizes
-                             ).astype(w_down.dtype))
-    return (d_x, d_gate) + stacks
+
+    def block(acc, order, live, sizes):
+        token = order // k
+        rows = x[token]
+        a = grouped_matmul(rows, w_gate, sizes).astype(jnp.float32)
+        b = grouped_matmul(rows, w_up, sizes).astype(jnp.float32)
+        sig = jax.nn.sigmoid(a)
+        mid = a * sig * b
+        dy_rows = dy[token]
+        # d out . w_down^T once, unweighted: its product with mid is the
+        # gate's cotangent, weighted by the gate it is mid's
+        u = grouped_matmul(dy_rows, w_down, sizes, transpose_rhs=True
+                           ).astype(jnp.float32)
+        g_rows = jnp.where(live, gate.reshape(-1)[order], 0.0)[:, None]
+        d_gate = jnp.sum(u * mid, axis=-1)
+        if acc is None:  # every row is here: an assignment reads its own
+            d_gate = d_gate[place]
+        d_mid = u * g_rows
+        d_a = (d_mid * b * sig * (1.0 + a * (1.0 - sig))).astype(x.dtype)
+        d_b = (d_mid * a * sig).astype(x.dtype)
+        d_rows = grouped_matmul(d_a, w_gate, sizes, transpose_rhs=True) \
+            + grouped_matmul(d_b, w_up, sizes, transpose_rhs=True)
+        if acc is None:
+            d_x = jnp.sum(d_rows[place].astype(jnp.float32), axis=1)
+        else:
+            d_x = acc[0].at[token].add(d_rows.astype(jnp.float32))
+            d_gate = acc[1].at[token, order % k].add(d_gate)
+        d_out = (dy_rows.astype(jnp.float32) * g_rows).astype(x.dtype)
+        d_stacks = (_grouped_outer(rows, d_a, sizes),
+                    _grouped_outer(rows, d_b, sizes),
+                    _grouped_outer(mid.astype(x.dtype), d_out, sizes))
+        if acc is not None:
+            d_stacks = tuple(s + d for s, d in zip(acc[2:], d_stacks))
+        return (d_x, d_gate) + d_stacks
+
+    d_x, d_gate, *d_stacks = _over_blocks(
+        block, rows_bound(t * k, w_gate.shape[0], n_experts),
+        lambda: tuple(jnp.zeros(a.shape, jnp.float32)
+                      for a in (x, gate, w_gate, w_up, w_down)),
+        order, live, sizes)
+    return (d_x.astype(x.dtype), d_gate) + tuple(
+        d.astype(w.dtype) for d, w in zip(d_stacks, (w_gate, w_up, w_down)))
 
 
-# what the sorted copy of the folded clients' rows (every assignment's,
-# held here or not: ``[C T K, D]``) may hold before the layer goes a
-# client at a time. sarvam_105b_c4_l2048 folds 512 MiB; four clients of
-# 8,192 tokens at 6,144 channels would sort 3 GiB, twice in the
-# backward, beside a 5 GiB base (compiled for a v5e, PR 39: 17.0 of
-# 15.75 GiB)
+# what the sorted copy of the folded clients' rows may hold before the
+# layer goes a client at a time: ``rows_bound`` rows of the clients'
+# ``C T K`` assignments, ``[R, D]``. sarvam_105b_c4_l2048 folds 128 MiB
+# (16,384 rows of 4,096), glm5_c4_l8192 192 MiB (16,384 of 6,144);
+# before the rows were bounded every assignment was sorted and copied,
+# held here or not, and glm5's four clients would have folded 3 GiB,
+# twice in the backward (compiled for a v5e, PR 39: 17.0 of 15.75 GiB)
 _FOLDED_ROWS_BYTES = 1024 ** 3
 
 
-def _over_clients(fn, n_out_acts: int):
-    """``fn(x, idx, gate, ..., w_gate, w_up, w_down) -> tuple`` as a
-    ``custom_vmap`` function whose rule folds a client axis on the
+def _over_clients(direction, n_out_acts: int, n_experts: int):
+    """``direction(n_experts, x, idx, gate, ..., w_gate, w_up, w_down)
+    -> tuple`` as a ``custom_vmap`` function of the arrays whose rule
+    folds a client axis on the
     activations into their row axis where the stacks carry none and the
-    folded rows stay under ``_FOLDED_ROWS_BYTES``; the first
-    ``n_out_acts`` results are per row and unfold again, the rest (a
-    stack's gradient a client) come from a ``lax.map`` over clients, as
-    does everything where a stack carries the axis or the fold would be
-    too large."""
+    folded block of sorted rows stays under ``_FOLDED_ROWS_BYTES``; the
+    first ``n_out_acts`` results are per row and unfold again, the rest
+    (a stack's gradient a client) come from a ``lax.map`` over clients,
+    as does everything where a stack carries the axis or the fold would
+    be too large."""
+    fn = partial(direction, n_experts)
     wrapped = custom_vmap(fn)
 
     @wrapped.def_vmap
@@ -268,7 +375,8 @@ def _over_clients(fn, n_out_acts: int):
                 *acts, *along_clients(stacks, in_batched[-3:])))
             return out, (True,) * len(out)
         x, idx = acts[:2]
-        if idx.size * x.shape[-1] * x.dtype.itemsize > _FOLDED_ROWS_BYTES:
+        rows = rows_bound(idx.size, stacks[0].shape[0], n_experts)
+        if rows * x.shape[-1] * x.dtype.itemsize > _FOLDED_ROWS_BYTES:
             out = jax.lax.map(lambda a: fn(*a, *stacks), tuple(acts))
             return out, (True,) * len(out)
         folded = wrapped(*(a.reshape((-1,) + a.shape[2:]) for a in acts),
@@ -283,25 +391,25 @@ def _over_clients(fn, n_out_acts: int):
     return wrapped
 
 
-_forward_folded = _over_clients(_routed_forward, 1)
-_backward_folded = _over_clients(_routed_backward, 2)
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def routed_experts(n_experts, x, idx, gate, w_gate, w_up, w_down):
+    """The held experts' part of the layer: ``n_experts`` the router's
+    width (with the stacks' leading axis, what bounds the rows handled:
+    :func:`rows_bound`), ``x [T, D]`` in the compute dtype, ``idx
+    [T, K]`` the chosen experts counted from the first one held
+    (``E_held`` or more: not held here), ``gate [T, K]`` float32."""
+    return _over_clients(_routed_forward, 1, n_experts)(
+        x, idx, gate, w_gate, w_up, w_down)[0]
 
 
-@jax.custom_vjp
-def routed_experts(x, idx, gate, w_gate, w_up, w_down):
-    """The held experts' part of the layer: ``x [T, D]`` in the compute
-    dtype, ``idx [T, K]`` the chosen experts counted from the first one
-    held (``E_held`` or more: not held here), ``gate [T, K]`` float32."""
-    return _forward_folded(x, idx, gate, w_gate, w_up, w_down)[0]
+def _routed_fwd(n_experts, *args):
+    return routed_experts(n_experts, *args), args
 
 
-def _routed_fwd(*args):
-    return routed_experts(*args), args
-
-
-def _routed_bwd(res, dy):
+def _routed_bwd(n_experts, res, dy):
     x, idx, gate, *stacks = res
-    d_x, d_gate, *d_stacks = _backward_folded(x, idx, gate, dy, *stacks)
+    d_x, d_gate, *d_stacks = _over_clients(_routed_backward, 2, n_experts)(
+        x, idx, gate, dy, *stacks)
     return (d_x, None, d_gate, *d_stacks)
 
 
@@ -317,9 +425,9 @@ def moe_apply(p, x, cfg: MoEConfig):
     local = idx - cfg.first_held
     local = jnp.where((local >= 0) & (local < cfg.held), local, cfg.held)
     y = routed_experts(
-        x.reshape(b * l, d), local.reshape(b * l, -1).astype(jnp.int32),
-        gate.reshape(b * l, -1), p["w_gate"], p["w_up"], p["w_down"]
-    ).reshape(b, l, d)
+        cfg.n_experts, x.reshape(b * l, d),
+        local.reshape(b * l, -1).astype(jnp.int32), gate.reshape(b * l, -1),
+        p["w_gate"], p["w_up"], p["w_down"]).reshape(b, l, d)
     if "shared" in p:
         with jax.named_scope("shared_expert"):
             y = y + swiglu(p["shared"], x)

@@ -359,8 +359,10 @@ def phase_moe_mla_lora(env: Env) -> None:
     the routing of ``sarvam_105b`` at the published widths (in rehearsal
     at its ``tiny`` sizes) on the inputs of ``sarvam_105b_c4_l2048``,
     one forward of a local step's four sequences: a layer, the held
-    experts' largest and mean rows and the share of assignments that
-    fell on experts held elsewhere; and on the first sequence the share
+    experts' largest and mean rows, the held rows beside the block of
+    sorted rows the layer handles at a time (``moe.rows_bound``) with
+    the blocks that makes, and the share of assignments that fell on
+    experts held elsewhere; and on the first sequence the share
     of assignments on which the program's router (bfloat16 activations,
     grouped products) and the same layers in float32 at ``highest``
     (the oracle's loop over experts) disagree, which is what stands
@@ -505,9 +507,12 @@ def phase_moe_mla_lora(env: Env) -> None:
         want = np.sort(ref[0], -1)
         differ = np.mean([len(set(a) - set(b)) for a, b in zip(got, want)]) \
             / top_k
+        bound = moe.rows_bound(idx.size, held, total)
         lines.append(
             f"expert layer {layer + 1}: rows a held expert max "
             f"{rows.max()} mean {rows.mean():.1f} min {rows.min()}, "
+            f"{rows.sum()} held rows of {idx.size} assignments against a "
+            f"block of {bound} ({-(-rows.sum() // bound)} block(s) run), "
             f"{100 * absent:.2f} % of assignments on experts held elsewhere "
             f"(expected {100 * (1 - held / total):.2f}), the router in "
             f"bfloat16 and in float32 differ on {100 * differ:.3f} %")
@@ -538,7 +543,9 @@ def phase_dsa_mla_lora(env: Env) -> None:
     the choice by ``lax.top_k``, the oracle's loop over experts):
     the share of a query's chosen keys on which the two agree, which is
     what stands between the probe's two sides beside rounding and the
-    router; the distance between the two outputs, a token, over the
+    router; the sequence's held rows beside the block of sorted rows
+    the expert layer handles at a time; the distance between the two
+    outputs, a token, over the
     queries that chose alike and over the others; and the time of the
     choice by either method on the program's scores, which have to give
     the same threshold and index."""
@@ -612,6 +619,15 @@ def phase_dsa_mla_lora(env: Env) -> None:
            "bisection and sort chose differently on the same scores")
     chose = np.asarray(transformer.chosen_keys(scores, *bisect))[0] != 0
     out = np.asarray(jax.jit(partial(layer, plain=False))(x)[0], np.float32)
+    routed = np.asarray(jax.jit(lambda x: moe.route(
+        blk["mlp"], transformer.rms_norm(
+            llama._mix(blk, x, decoder, rope, None), blk["norm_mlp"],
+            decoder.norm_eps), decoder.moe)[0])(x))
+    cut = decoder.moe
+    held_rows = int(((routed >= cut.first_held)
+                     & (routed < cut.first_held + cut.held)).sum())
+    bound = moe.rows_bound(routed.size, cut.held, cut.n_experts)
+    _check(held_rows > 0, "no assignment fell on a held expert")
 
     with jax.default_matmul_precision("highest"):
         x32 = base["tok_emb"][ids].astype(jnp.float32)
@@ -647,6 +663,9 @@ def phase_dsa_mla_lora(env: Env) -> None:
     env.say("dsa_mla_lora",
             f"one expert layer of glm_5 at {'tiny' if tiny else 'the published'} "
             f"sizes, {length} tokens, {topk} keys a query, seed {seed}: "
+            f"{held_rows} held rows of {routed.size} assignments "
+            f"({cut.held} of {cut.n_experts} experts held) against a block "
+            f"of {bound} ({-(-held_rows // bound)} block(s) run); "
             f"{'float32' if tiny else 'bfloat16'} and float32 at highest "
             f"agree on {100 * _mean(common[chooses] / topk):.3f} % of a "
             f"choosing query's keys ({int(alike[chooses].sum())} of "

@@ -24,6 +24,7 @@ from baton_tpu.models.llama import (
 )
 from baton_tpu.models.moe import (
     MoEConfig,
+    _block_sizes,
     _gmm,
     _rows_of_the_groups,
     grouped_matmul,
@@ -31,6 +32,7 @@ from baton_tpu.models.moe import (
     moe_dense_oracle,
     moe_init,
     route,
+    rows_bound,
 )
 
 D, F = 16, 32
@@ -154,9 +156,9 @@ def test_under_a_client_vmap_in_a_gradient_in_a_checkpoint(cfg, nprng):
 
 
 def test_a_fold_too_large_goes_a_client_at_a_time(nprng, monkeypatch):
-    """Past ``_FOLDED_ROWS_BYTES`` of sorted rows (four clients of 8,192
-    tokens at 6,144 channels would fold 3 GiB) the shared stacks are
-    read a client at a time: a ``while`` over the clients whose grouped
+    """Past ``_FOLDED_ROWS_BYTES`` in a block of the folded clients'
+    sorted rows (here every expert is held and the block is every
+    assignment) the shared stacks are read a client at a time: a ``while`` over the clients whose grouped
     products see one client's rows, with the values and gradients the
     fold gives."""
     from baton_tpu.models import moe
@@ -184,10 +186,160 @@ def test_a_fold_too_large_goes_a_client_at_a_time(nprng, monkeypatch):
     jax.clear_caches()
     assert "f32[144,16]" in str(jax.make_jaxpr(step)(xs))
     jax.clear_caches()
-    # the cells: sarvam_105b_c4_l2048 folds, glm5_c4_l8192 does not
+    # the cells, now that the sorted copy holds a block of rows and not
+    # every assignment: both fold, sarvam_105b_c4_l2048 128 MiB and
+    # glm5_c4_l8192 192 MiB; unbounded, glm5's four clients were 3 GiB
     limit = 1024 ** 3
+    assert rows_bound(4 * 2048 * 8, 16, 128) * 4096 * 2 == 128 * 1024 ** 2
+    assert rows_bound(4 * 8192 * 8, 8, 256) * 6144 * 2 == 192 * 1024 ** 2
     assert 4 * 2048 * 8 * 4096 * 2 <= limit < 4 * 8192 * 8 * 6144 * 2
-    assert 8192 * 8 * 6144 * 2 <= limit
+
+
+# --------------------------------------------- the rows a rank holds
+# 2 of 16 experts held, 512 tokens of 2 choices: 1,024 assignments, 128
+# of them expected here, so a block holds 256 sorted rows
+FEW = MoEConfig(n_experts=16, top_k=2, d_ff=F, experts_held=2, first_held=2,
+                routed_scale=2.5, n_shared=1, router_bias_range=0.1)
+
+
+def _crowded(blocks: int):
+    """``FEW``'s parameters with the router turned so that the held
+    experts take ``blocks`` blocks of rows: every token's first choice
+    falls on the first held expert (two full blocks a sequence of 512),
+    and the second held expert is never chosen or keeps its own share
+    (a third block)."""
+    bias = jnp.zeros(FEW.n_experts).at[FEW.first_held].set(5.0)
+    if blocks == 2:
+        bias = bias.at[FEW.first_held + 1].set(-5.0)
+    return dict(_params(FEW), router_bias=bias)
+
+
+def _blocks_run(p, x, cfg):
+    """The blocks of sorted rows that hold a held expert's row, with
+    every leading axis of ``x`` folded into the tokens."""
+    idx = np.asarray(route(p, x, cfg)[0])
+    rows = int(((idx >= cfg.first_held)
+                & (idx < cfg.first_held + cfg.held)).sum())
+    return -(-rows // rows_bound(idx.size, cfg.held, cfg.n_experts))
+
+
+@pytest.mark.parametrize("blocks", [2, 3])
+def test_more_held_rows_than_a_block_are_the_oracles(blocks, nprng):
+    """No capacity: a routing that gives the held experts more rows
+    than the bound runs further blocks and drops nothing. Forward, the
+    input's gradient and every leaf's."""
+    p = _crowded(blocks)
+    x = jnp.asarray(nprng.normal(size=(1, 512, D)), jnp.float32)
+    assert rows_bound(1024, FEW.held, FEW.n_experts) == 256
+    assert _blocks_run(p, x, FEW) == blocks
+    _close(moe_apply(p, x, FEW), moe_dense_oracle(p, x, FEW))
+
+    def grads(fn):
+        return jax.grad(lambda p, x: jnp.sum(fn(p, x, FEW) ** 2),
+                        argnums=(0, 1))(p, x)
+
+    got, want = jax.jit(lambda: grads(moe_apply))(), grads(moe_dense_oracle)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        _close(g, w, rtol=1e-4)
+    assert float(jnp.max(jnp.abs(got[0]["w_down"]))) > 0
+
+
+@pytest.mark.parametrize("blocks", [2, 3])
+def test_more_held_rows_than_a_block_under_the_client_vmap(blocks, nprng):
+    """The same under ``jit(vmap(value_and_grad(checkpoint(..))))``:
+    three clients folded into one sort of 3,072 assignments whose
+    blocks hold 768 rows."""
+    p = _crowded(blocks)
+    xs = jnp.asarray(nprng.normal(size=(3, 1, 512, D)), jnp.float32)
+    assert _blocks_run(p, xs, FEW) == blocks
+
+    def loss(x):
+        return jnp.sum(jax.checkpoint(lambda x: moe_apply(p, x, FEW))(x) ** 2)
+
+    def plain(x):
+        return jnp.sum(moe_dense_oracle(p, x, FEW) ** 2)
+
+    step = jax.vmap(jax.value_and_grad(loss))
+    assert "f32[768,16]" in str(jax.make_jaxpr(step)(xs))
+    got = jax.jit(step)(xs)
+    for c in range(3):
+        want = jax.value_and_grad(plain)(xs[c])
+        assert float(got[0][c]) == pytest.approx(float(want[0]), rel=1e-5)
+        _close(got[1][c], want[1], rtol=1e-4)
+
+
+def _made(jaxpr):
+    """``(primitive, shape)`` of everything a jaxpr computes, its inner
+    jaxprs (a loop's body, a custom rule's) with it."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield eqn.primitive.name, tuple(getattr(v.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _made(sub)
+
+
+@pytest.mark.parametrize("cfg", [FEW, WHOLE], ids=["few", "whole"])
+def test_past_the_sort_only_a_block_of_rows_is_held(cfg, nprng):
+    """Where a share of the experts is held, the layer and its gradient
+    make no array of every assignment's rows (``[N, D]``, ``[N, F]``,
+    ``[T, K, D]``), only of a block's; where every expert is held the
+    one block is every row and nothing loops or slices: the program is
+    the one the layer had before the rows were bounded."""
+    p = _params(cfg)
+    x = jnp.asarray(nprng.normal(size=(1, 512, D)), jnp.float32)
+    n = 512 * cfg.top_k
+    r = rows_bound(n, cfg.held, cfg.n_experts)
+
+    def step(p, x):
+        return jax.value_and_grad(
+            lambda p, x: jnp.sum(moe_apply(p, x, cfg) ** 2),
+            argnums=(0, 1))(p, x)
+
+    made = set(_made(jax.make_jaxpr(step)(p, x).jaxpr))
+    shapes = {shape for _, shape in made}
+    loops = {name for name, _ in made} & {"while", "dynamic_slice", "cond"}
+    every = {(n, D), (n, F), (512, cfg.top_k, D)}
+    if cfg is FEW:
+        assert r == 256 and not shapes & every
+        assert {(r, D), (r, F)} <= shapes and "while" in loops
+    else:
+        assert r == n and every <= shapes and not loops
+
+
+@pytest.mark.parametrize("tokens, top_k, held, n_experts, want", [
+    # sarvam_105b_c4_l2048: four clients' sequences of 2,048 folded
+    (4 * 2048, 8, 16, 128, 16384),
+    # glm5_c4_l8192: a client's sequence of 8,192, and the four folded
+    (8192, 8, 8, 256, 4096),
+    (4 * 8192, 8, 8, 256, 16384),
+    # every expert held, and a share too large to bound: every row
+    (2048, 8, 128, 128, 16384),
+    (24, 2, 4, 8, 48),
+    # the tile rounds up; a block is never more than the rows there are
+    (300, 2, 2, 16, 256),
+    (100, 2, 2, 16, 200),
+], ids=["sarvam", "glm5-client", "glm5-folded", "all-held", "half-held",
+        "rounded-up", "capped"])
+def test_the_bound_on_the_rows_a_block_holds(tokens, top_k, held, n_experts,
+                                             want):
+    n = tokens * top_k
+    r = rows_bound(n, held, n_experts)
+    assert r == want and r <= n and (r == n or r % 256 == 0)
+    assert r >= min(n, 2 * n * held // n_experts)
+
+
+@pytest.mark.parametrize("block", [0, 1, 2, 3])
+def test_a_blocks_group_sizes_sum_to_the_rows_in_its_window(block):
+    """Sizes that straddle a block's edges: 200 + 0 + 120 + 300 held
+    rows in windows of 256."""
+    sizes = jnp.asarray([200, 0, 120, 300], jnp.int32)
+    got = np.asarray(_block_sizes(sizes, block * 256, 256))
+    want = [[200, 0, 56, 0], [0, 0, 64, 192], [0, 0, 0, 108], [0, 0, 0, 0]]
+    assert got.tolist() == want[block]
+    assert got.sum() == np.clip(620 - block * 256, 0, 256)
 
 
 def test_stacks_that_carry_the_client_axis_take_the_map(nprng):
@@ -423,6 +575,8 @@ def test_the_wave_program_holds_the_stacks_once_and_takes_no_gradient(
     name, attrs = spans[0]
     assert name == "baton.round"
     assert (attrs["experts_held"], attrs["experts_total"]) == (4, 8)
+    # half the experts held: a block is every row, 2 a token
+    assert attrs["routed_rows_bound"] == 2048
     assert attrs["frozen_bytes"] == sum(
         a.nbytes for a in jax.tree_util.tree_leaves(params["base"]))
     assert all(a is b for a, b in zip(
