@@ -10,11 +10,19 @@ delta rule of :mod:`baton_tpu.models.delta_rule` (linear attention with
 a recurrent state) or latent attention (``mla``: keys and values from a
 low-rank latent, with ``mla.q_rank`` the queries from one of their own,
 with ``mla.indexer`` each query attending the keys a learned index
-chose for it, :func:`baton_tpu.models.transformer.mla_apply`), in
+chose for it, :func:`baton_tpu.models.transformer.mla_apply`) or
+compressed convolutional attention (``cca``: a few heads in a latent
+narrower than the model, mixed along the sequence by two convolutions,
+:func:`baton_tpu.models.transformer.cca_apply`), in
 the pattern the configuration gives. With ``moe`` the layers after the
 first ``first_dense_layers`` replace their SwiGLU by the expert layer of
 :mod:`baton_tpu.models.moe`, which holds ``moe.experts_held`` of the
-router's experts and computes their part. A block is traced once a
+router's experts and computes their part; where its router carries a
+state (``moe.router_hidden``) a block takes and hands on two streams,
+the tokens' and the routers', and the first block is handed zeros. With
+``residual_merge`` a sub-layer's output joins the stream by a learned
+affine merge a channel and not a plain add; with ``tie_embeddings`` the
+head is the embedding table itself. A block is traced once a
 kind (of mixer and of feed-forward), whatever the depth.
 
 * params fp32 / activations ``compute_dtype`` (bf16 on TPU), norms,
@@ -52,10 +60,19 @@ from baton_tpu.core.model import FedModel
 from baton_tpu.core.partition import path_str
 from baton_tpu.models.delta_rule import gated_delta_apply, gated_delta_init
 from baton_tpu.models.lora import lora_wrap
-from baton_tpu.models.moe import MoEConfig, moe_apply, moe_init, rows_bound
+from baton_tpu.models.moe import (
+    MoEConfig,
+    moe_apply,
+    moe_apply_with_state,
+    moe_init,
+    rows_bound,
+)
 from baton_tpu.models.transformer import (
     AttentionFn,
+    CCAConfig,
     MLAConfig,
+    cca_apply,
+    cca_init,
     dense_init,
     default_attention,
     matmul,
@@ -71,6 +88,7 @@ from baton_tpu.models.transformer import (
     rope_angles,
     swiglu_apply,
     swiglu_init,
+    tied_logits,
 )
 
 
@@ -94,10 +112,14 @@ class LlamaConfig:
     # latent attention's sizes; the mixer of a "latent_attention" layer,
     # and of every layer where ``layer_types`` is None
     mla: Optional[MLAConfig] = None
+    # compressed convolutional attention's sizes; the mixer of a
+    # "compressed_attention" layer
+    cca: Optional[CCAConfig] = None
     # RMSNorm of the whole query and key projections in full attention
     qk_norm: bool = False
-    # the mixer of each layer, "full_attention", "linear_attention" or
-    # "latent_attention"; the first ``n_layers`` entries count (a depth
+    # the mixer of each layer, "full_attention", "linear_attention",
+    # "latent_attention" or "compressed_attention"; the first
+    # ``n_layers`` entries count (a depth
     # cut keeps the published list). None: one mixer everywhere, latent
     # attention with ``mla``, else full attention
     layer_types: Optional[Tuple[str, ...]] = None
@@ -113,6 +135,12 @@ class LlamaConfig:
     # the RMSNorms before each sub-layer and before the head (a latent
     # mixer's own norms have ``mla.norm_eps``)
     norm_eps: float = 1e-6
+    # a sub-layer's output ``y`` joins the stream ``x`` as ``a_x (x +
+    # b_x) + a_y (y + b_y)``, four frozen float32 vectors a sub-layer,
+    # and not as ``x + y``
+    residual_merge: bool = False
+    # the head is the embedding table transposed; no ``lm_head`` leaf
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         if self.layer_types is not None:  # a JSON list hashes as a tuple
@@ -152,15 +180,18 @@ def llama_lora_target(path: str, leaf) -> bool:
 
 
 _PROJECTIONS = ("wq", "wq_a", "wq_b", "wk", "wv", "wo", "wg", "wkv_a",
-                "wkv_b", "w_gate", "w_up", "w_down")
+                "wkv_b", "w_gate", "w_up", "w_down", "linear_q", "linear_k",
+                "val_proj1", "val_proj2", "o_proj")
 
 
 def projection_lora_target(path: str, leaf) -> bool:
     """LoRA target predicate: every 2-D projection of the mixers (full,
-    linear and latent attention), of the MLPs and of a shared expert;
+    linear, latent and compressed attention), of the MLPs and of a
+    shared expert;
     not the embedding, the head, the linear layers' gate projections
-    ``wa`` / ``wb`` or their convolutions, not an expert layer's
-    router or its 3-D stacks of routed experts, and nothing of a latent
+    ``wa`` / ``wb`` or any mixer's convolutions, not an expert layer's
+    router (a matrix or an MLP) or its 3-D stacks of routed experts,
+    and nothing of a latent
     mixer's ``indexer`` (no gradient reaches the choice of keys)."""
     return (getattr(leaf, "ndim", 2) == 2 and "/indexer/" not in path
             and path.rsplit("/", 1)[-1] in _PROJECTIONS)
@@ -169,9 +200,11 @@ def projection_lora_target(path: str, leaf) -> bool:
 def _block_init(key, cfg: LlamaConfig, kind: str = "full_attention",
                 experts: bool = False):
     """A linear-attention block holds its mixer under ``linear_attn``,
-    a latent-attention block under ``mla`` and a full-attention block
-    under ``attn``; an expert layer's ``mlp`` holds a ``router``: the
-    kind of a block is the structure of its parameters."""
+    a latent-attention block under ``mla``, a compressed-attention
+    block under ``cca`` and a full-attention block
+    under ``attn``; an expert layer's ``mlp`` holds a ``router``; with
+    ``residual_merge`` the block holds ``merge_attn`` and ``merge_mlp``:
+    the kind of a block is the structure of its parameters."""
     ka, km = jax.random.split(key)
     if experts:
         mlp = moe_init(km, cfg.d_model, cfg.d_ff, cfg.moe)
@@ -185,14 +218,41 @@ def _block_init(key, cfg: LlamaConfig, kind: str = "full_attention",
         mixer = {"linear_attn": gated_delta_init(
             ka, cfg.d_model, cfg.linear_n_heads, cfg.linear_key_dim,
             cfg.linear_value_dim, out_std=out_std)}
+    elif kind == "compressed_attention":
+        mixer = {"cca": cca_init(ka, cfg.d_model, cfg.cca, out_std=out_std)}
     elif kind == "full_attention":
         mixer = {"attn": mha_init(
             ka, cfg.d_model, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             out_std=out_std, qk_norm=cfg.qk_norm)}
     else:
         raise ValueError(f"unknown layer type {kind!r}")
+    if cfg.residual_merge:
+        k1, k2 = jax.random.split(jax.random.fold_in(key, 2))
+        mixer.update(merge_attn=_merge_init(k1, cfg.d_model),
+                     merge_mlp=_merge_init(k2, cfg.d_model))
     return {"norm_attn": rms_init(cfg.d_model), **mixer,
             "norm_mlp": rms_init(cfg.d_model), "mlp": mlp}
+
+
+def _merge_init(key, d: int):
+    """A trained merge lies near ``a = 1``, ``b = 0``; the scales are
+    drawn uniform in [0.5, 1.5] and the shifts at deviation 0.02, so
+    that a test can tell a merge from a plain add."""
+    ks, kb = jax.random.split(key)
+    a_x, a_y = jax.random.uniform(ks, (2, d), jnp.float32, 0.5, 1.5)
+    b_x, b_y = normal_init(kb, (2, d), 0.02)
+    return {"a_x": a_x, "b_x": b_x, "a_y": a_y, "b_y": b_y}
+
+
+def _joined(p, name: str, x, y):
+    """The sub-layer's output ``y`` joined to the stream ``x``: the
+    block's affine merge ``name`` where it holds one, in float32, else
+    the plain add."""
+    if name not in p:
+        return x + y
+    m = p[name]
+    return (m["a_x"] * (x.astype(jnp.float32) + m["b_x"])
+            + m["a_y"] * (y.astype(jnp.float32) + m["b_y"])).astype(x.dtype)
 
 
 def _mixer_keeps_its_inputs(p, cfg: LlamaConfig, length: int) -> bool:
@@ -204,30 +264,42 @@ def _mixer_keeps_its_inputs(p, cfg: LlamaConfig, length: int) -> bool:
 
 def _mix(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
     if _mixer_keeps_its_inputs(p, cfg, x.shape[1]):
-        return x + mla_apply(p["mla"], x, cfg.n_heads, cfg.mla, rope,
-                             pre_norm=p["norm_attn"])
+        return _joined(p, "merge_attn", x, mla_apply(
+            p["mla"], x, cfg.n_heads, cfg.mla, rope,
+            pre_norm=p["norm_attn"]))
     h = rms_norm(x, p["norm_attn"], cfg.norm_eps)
-    if "mla" in p:
-        return x + mla_apply(p["mla"], h, cfg.n_heads, cfg.mla, rope)
-    if "linear_attn" in p:
-        return x + gated_delta_apply(
+    if "cca" in p:
+        y = cca_apply(p["cca"], h, cfg.cca, rope)
+    elif "mla" in p:
+        y = mla_apply(p["mla"], h, cfg.n_heads, cfg.mla, rope)
+    elif "linear_attn" in p:
+        y = gated_delta_apply(
             p["linear_attn"], h, cfg.linear_n_heads, cfg.linear_chunk,
             cfg.linear_allow_neg_eigval)
-    return x + mha_apply(
-        p["attn"], h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-        causal=True, rope=rope, attention_fn=attention_fn,
-    )
+    else:
+        y = mha_apply(
+            p["attn"], h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            causal=True, rope=rope, attention_fn=attention_fn,
+        )
+    return _joined(p, "merge_attn", x, y)
 
 
-def _feed_forward(p, x, cfg: LlamaConfig):
+def _feed_forward(p, x, r, cfg: LlamaConfig):
+    """``(x, r)``: the stream after the feed-forward, and the routers'
+    state, which an expert layer whose router carries one (``r`` not
+    None) takes from the layer before and hands to the next."""
     h = rms_norm(x, p["norm_mlp"], cfg.norm_eps)
-    if "router" in p["mlp"]:
-        return x + moe_apply(p["mlp"], h, cfg.moe)
-    return x + swiglu_apply(p["mlp"], h)
+    if r is not None:
+        y, r = moe_apply_with_state(p["mlp"], h, r, cfg.moe)
+    elif "router" in p["mlp"]:
+        y = moe_apply(p["mlp"], h, cfg.moe)
+    else:
+        y = swiglu_apply(p["mlp"], h)
+    return _joined(p, "merge_mlp", x, y), r
 
 
-def _block_apply(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
-    return _feed_forward(p, _mix(p, x, cfg, rope, attention_fn), cfg)
+def _block_apply(p, x, r, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
+    return _feed_forward(p, _mix(p, x, cfg, rope, attention_fn), r, cfg)
 
 
 def llama_lm_model(
@@ -251,12 +323,17 @@ def llama_lm_model(
     # made once a model: ``jax.checkpoint`` caches its trace on the
     # function and the arguments' structure, so blocks of one kind share
     # one trace whatever the depth
-    block_fn = (jax.checkpoint(_block_apply, static_argnums=(2, 4)) if remat
+    block_fn = (jax.checkpoint(_block_apply, static_argnums=(3, 5)) if remat
                 else _block_apply)
     # a mixer that keeps its own inputs for the backward stands outside
     # the block's checkpoint, which would make it a third time
-    ff_fn = (jax.checkpoint(_feed_forward, static_argnums=(2,)) if remat
+    ff_fn = (jax.checkpoint(_feed_forward, static_argnums=(3,)) if remat
              else _feed_forward)
+    stateful = cfg.moe is not None and cfg.moe.router_hidden is not None
+    if stateful and cfg.first_dense_layers:
+        raise NotImplementedError(
+            "a router's state runs through every layer: no dense layer "
+            "stands among expert layers whose router carries one")
 
     def init(rng):
         keys = jax.random.split(rng, cfg.n_layers + 2)
@@ -269,11 +346,19 @@ def llama_lm_model(
                 for i in range(cfg.n_layers)
             ],
             "norm_f": rms_init(cfg.d_model),
-            "lm_head": dense_init(keys[-1], cfg.d_model, cfg.vocab_size),
         }
+        if cfg.tie_embeddings:
+            # the table is the head too: the last norm takes the stream
+            # to the scale at which its logits have the deviation an
+            # untied head's have, 1, as a trained scale would
+            params["norm_f"]["scale"] /= cfg.embed_std * cfg.d_model ** 0.5
+        else:
+            params["lm_head"] = dense_init(keys[-1], cfg.d_model,
+                                           cfg.vocab_size)
+        # a router, one matrix or an MLP's leaves, stays float32
         return jax.tree_util.tree_map_with_path(
             lambda path, a: a.astype(param_dtype) if a.ndim >= 2
-            and not path_str(path).endswith("/router") else a, params)
+            and "/router/" not in path_str(path) + "/" else a, params)
 
     def _hidden(params, batch):
         """The final norm's output ``[B, L, D]``."""
@@ -281,29 +366,40 @@ def llama_lm_model(
         l = ids.shape[1]
         if cfg.mla is not None:
             rope = mla_rope_angles(l, cfg.mla)
+        elif cfg.cca is not None:
+            rope = rope_angles(l, cfg.cca.rope_dim, cfg.cca.rope_theta)
         else:
             rope = (None if cfg.rope_theta is None
                     else rope_angles(l, cfg.head_dim, cfg.rope_theta))
         with jax.named_scope("embed"):
             x = params["tok_emb"][ids].astype(compute_dtype)
+        # the routers' state: the first layer's router is handed zeros,
+        # so that every layer is one kind of block
+        r = (jnp.zeros(ids.shape + (cfg.moe.router_hidden,), jnp.float32)
+             if stateful else None)
         for i, blk in enumerate(params["blocks"]):
             with jax.named_scope(f"block{i}"):
                 if _mixer_keeps_its_inputs(blk, cfg, l):
-                    x = ff_fn(blk, _mix(blk, x, cfg, rope, attention_fn),
-                              cfg)
+                    x, r = ff_fn(blk, _mix(blk, x, cfg, rope, attention_fn),
+                                 r, cfg)
                 else:
-                    x = block_fn(blk, x, cfg, rope, attention_fn)
+                    x, r = block_fn(blk, x, r, cfg, rope, attention_fn)
         return rms_norm(x, params["norm_f"], cfg.norm_eps)
 
     def apply(params, batch, rng):
         """Returns next-token logits [B, L, V] (fp32): bf16 operands,
         fp32 accumulation — the vocab projection is the model's largest
         matmul, keep it on the fast MXU path."""
-        return matmul(_hidden(params, batch), params["lm_head"], jnp.float32)
+        x = _hidden(params, batch)
+        if cfg.tie_embeddings:
+            return tied_logits(x, params["tok_emb"])
+        return matmul(x, params["lm_head"], jnp.float32)
 
     def per_example_loss(params, batch, rng):
         x = _hidden(params, batch)
-        tok_loss = next_token_loss(x, params["lm_head"], batch["y"])  # [B, L]
+        tok_loss = next_token_loss(
+            x, params["tok_emb" if cfg.tie_embeddings else "lm_head"],
+            batch["y"], tied=cfg.tie_embeddings)  # [B, L]
         loss_mask = batch.get("loss_mask")
         if loss_mask is None:
             return jnp.mean(tok_loss, axis=-1)
@@ -317,7 +413,14 @@ def llama_lm_model(
     facts = () if cfg.moe is None else (
         ("experts_held", cfg.moe.held), ("experts_total", cfg.moe.n_experts),
         ("routed_rows_bound", rows_bound(1024 * cfg.moe.top_k, cfg.moe.held,
-                                         cfg.moe.n_experts)))
+                                         cfg.moe.router_outputs)))
+    if cfg.moe is not None and cfg.moe.skip:
+        facts += (("router_outputs", cfg.moe.router_outputs),
+                  ("skip_expert", cfg.moe.n_experts))
+    if cfg.cca is not None:
+        facts += (("latent_q", cfg.cca.latent_q),
+                  ("latent_kv", cfg.cca.latent_kv),
+                  ("conv_taps", f"{cfg.cca.time0}+{cfg.cca.time1}"))
     return FedModel(init=init, apply=apply, per_example_loss=per_example_loss,
                     name=name, aux=cfg, span_attrs=facts)
 

@@ -1,18 +1,27 @@
 """Expert layer: a routed feed-forward that is told which experts it
 holds.
 
-The router scores every token against all ``n_experts`` (sigmoid
-scores in float32, DeepSeek-V3's convention, arXiv:2412.19437 section
-2.1): the ``top_k`` largest of ``s + router_bias`` are chosen (the bias
-chooses, it does not weigh), their weights are ``routed_scale * s_i /
-sum of the chosen s``. The layer holds the ``experts_held`` experts
-from ``first_held`` on, as one expert-parallel rank does, and computes
-its own experts' part of the result::
+One of two routers, by the configuration, chooses ``top_k`` of a token's
+router outputs and weighs them, in float32. :func:`route` scores every
+token against all ``n_experts`` (sigmoid scores, DeepSeek-V3's
+convention, arXiv:2412.19437 section 2.1): the ``top_k`` largest of ``s
++ router_bias`` are chosen (the bias chooses, it does not weigh), their
+weights are ``routed_scale * s_i / sum of the chosen s``.
+:func:`route_mlp` (``router_hidden``; ZAYA1's, arXiv:2511.17127) takes a
+token down to ``router_hidden`` channels, adds the state the layer
+before's router left, scaled a channel, and hands the sum on as its
+own; an RMSNorm and a three-layer GELU MLP of it give a softmax over
+the router's outputs, ``n_experts`` and with ``skip`` one more that is
+no expert; the ``top_k`` largest of ``p + router_bias`` are chosen and
+weigh ``routed_scale * p_i``, not renormalised. The layer holds the
+``experts_held`` experts from ``first_held`` on, as one expert-parallel
+rank does, and computes its own experts' part of the result::
 
     y = sum over chosen i that are held of g_i E_i(h)  +  E_shared(h)
     E(h) = (SiLU(h w_gate) * (h w_up)) w_down
 
-A choice that falls on an expert held elsewhere adds nothing here; the
+A choice that falls on an expert held elsewhere, or on the skip, which
+no rank holds, adds nothing here; the
 exchange between ranks (an all-to-all of tokens) is not written. No
 token is dropped and there is no capacity: the ``top_k`` assignments of
 every token are sorted by expert, each projection is one grouped matrix
@@ -57,7 +66,13 @@ import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
 
-from baton_tpu.models.transformer import dense_init, swiglu, swiglu_init
+from baton_tpu.models.transformer import (
+    dense_init,
+    normal_init,
+    rms_normalize,
+    swiglu,
+    swiglu_init,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,10 +95,22 @@ class MoEConfig:
     # uniform in +-this range (a trained model's balances the load;
     # zeros could not tell choosing from weighing); None: no bias
     router_bias_range: Optional[float] = None
+    # the width of the MLP router and of the state it hands to the next
+    # layer's (:func:`route_mlp`); None: the sigmoid router
+    # (:func:`route`)
+    router_hidden: Optional[int] = None
+    router_norm_eps: float = 1e-6
+    # one more router output after the experts' that stands for no
+    # expert: a token that chooses it skips the layer
+    skip: bool = False
 
     @property
     def held(self) -> int:
         return self.n_experts if self.experts_held is None else self.experts_held
+
+    @property
+    def router_outputs(self) -> int:
+        return self.n_experts + self.skip
 
 
 def moe_init(key, d_model: int, d_ff: int, cfg: MoEConfig):
@@ -98,14 +125,15 @@ def moe_init(key, d_model: int, d_ff: int, cfg: MoEConfig):
         return jax.vmap(lambda kk: dense_init(kk, d_in, d_out))(keys)
 
     p = {
-        "router": dense_init(kr, d_model, cfg.n_experts),
+        "router": dense_init(kr, d_model, cfg.n_experts)
+        if cfg.router_hidden is None else _mlp_router_init(kr, d_model, cfg),
         "w_gate": stack(kg, d_model, d_ff),   # [E_held, D, F]
         "w_up": stack(ku, d_model, d_ff),     # [E_held, D, F]
         "w_down": stack(kd, d_ff, d_model),   # [E_held, F, D]
     }
     if cfg.router_bias_range is not None:
         p["router_bias"] = jax.random.uniform(
-            kb, (cfg.n_experts,), jnp.float32, -cfg.router_bias_range,
+            kb, (cfg.router_outputs,), jnp.float32, -cfg.router_bias_range,
             cfg.router_bias_range)
     if cfg.n_shared:
         p["shared"] = swiglu_init(ks, d_model, cfg.n_shared * d_ff)
@@ -122,6 +150,67 @@ def route(p, x, cfg: MoEConfig):
     _, idx = jax.lax.top_k(s + p.get("router_bias", 0.0), cfg.top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
     return idx, cfg.routed_scale * chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
+# the MLP router's three matrices are drawn at this many times the
+# fan-in deviation: random weights stand for trained ones, and a trained
+# router that chooses one expert is sure of its choice (at 1 the softmax
+# is all but flat and ``router_bias`` alone chooses)
+_MLP_ROUTER_GAIN = 2.0
+
+
+def _mlp_router_init(key, d_model: int, cfg: MoEConfig):
+    """:func:`route_mlp`'s leaves, float32 all (no name of theirs is a
+    projection's: none takes an adapter). The biases are drawn at
+    deviation 0.02 and the state's scale uniform in [0.5, 1.5], not
+    zeros and ones: a test has to tell a term from its absence."""
+    h = cfg.router_hidden
+    std = _MLP_ROUTER_GAIN * h ** -0.5
+    k_in, k1, k2, k3, kb, ks = jax.random.split(key, 6)
+
+    def centred(w):  # every column sums to nothing
+        return w - jnp.mean(w, axis=0)
+
+    b_in, b1, b2 = (normal_init(k, (h,), 0.02)
+                    for k in jax.random.split(kb, 3))
+    return {
+        "w_in": dense_init(k_in, d_model, h), "b_in": b_in,
+        "state_scale": jax.random.uniform(ks, (h,), jnp.float32, 0.5, 1.5),
+        "norm": jnp.ones((h,), jnp.float32),
+        "w1": dense_init(k1, h, h, std), "b1": b1,
+        "w2": centred(dense_init(k2, h, h, std)), "b2": b2,
+        "w3": centred(dense_init(k3, h, cfg.router_outputs, std)),
+    }
+
+
+@jax.named_scope("router")
+def route_mlp(p, x, state, cfg: MoEConfig):
+    """``(idx, gate, state_out)``: the router outputs every token chose
+    (``[..., top_k]``; ``n_experts`` is the skip's) and their weights,
+    float32, and the router's state ``[..., router_hidden]`` for the
+    next layer's router, which is all it goes to::
+
+        state_out = x W_in + b_in + state_scale * state
+        p = softmax(W3 gelu(W2 gelu(W1 RMSNorm(state_out) + b1) + b2))
+
+    ``state`` is the layer before's ``state_out`` (None: none, the
+    first layer's)."""
+    r = p["router"]
+    hi = jax.lax.Precision.HIGHEST
+
+    def dense(y, w):
+        return jnp.einsum("...d,dh->...h", y, w, precision=hi)
+
+    s = dense(x.astype(jnp.float32), r["w_in"]) + r["b_in"]
+    if state is not None:
+        s = s + r["state_scale"] * state
+    z = rms_normalize(s, r["norm"], cfg.router_norm_eps)
+    z = jax.nn.gelu(dense(z, r["w1"]) + r["b1"], approximate=False)
+    z = jax.nn.gelu(dense(z, r["w2"]) + r["b2"], approximate=False)
+    prob = jax.nn.softmax(dense(z, r["w3"]), axis=-1)
+    _, idx = jax.lax.top_k(prob + p.get("router_bias", 0.0), cfg.top_k)
+    gate = cfg.routed_scale * jnp.take_along_axis(prob, idx, axis=-1)
+    return idx, gate, s
 
 
 # ------------------------------------------------------- grouped products
@@ -416,16 +505,14 @@ def _routed_bwd(n_experts, res, dy):
 routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
-@jax.named_scope("moe")
-def moe_apply(p, x, cfg: MoEConfig):
-    """``x [B, L, D] -> y [B, L, D]`` in ``x``'s dtype: the held
-    experts' part of the routed result plus the shared expert."""
+def _held_part(p, x, idx, gate, cfg: MoEConfig):
+    """The held experts' part of what the router chose, plus the shared
+    expert."""
     b, l, d = x.shape
-    idx, gate = route(p, x, cfg)
     local = idx - cfg.first_held
     local = jnp.where((local >= 0) & (local < cfg.held), local, cfg.held)
     y = routed_experts(
-        cfg.n_experts, x.reshape(b * l, d),
+        cfg.router_outputs, x.reshape(b * l, d),
         local.reshape(b * l, -1).astype(jnp.int32), gate.reshape(b * l, -1),
         p["w_gate"], p["w_up"], p["w_down"]).reshape(b, l, d)
     if "shared" in p:
@@ -434,12 +521,29 @@ def moe_apply(p, x, cfg: MoEConfig):
     return y
 
 
-def moe_dense_oracle(p, x, cfg: MoEConfig):
+@jax.named_scope("moe")
+def moe_apply(p, x, cfg: MoEConfig):
+    """``x [B, L, D] -> y [B, L, D]`` in ``x``'s dtype: the held
+    experts' part of the routed result plus the shared expert."""
+    return _held_part(p, x, *route(p, x, cfg), cfg)
+
+
+@jax.named_scope("moe")
+def moe_apply_with_state(p, x, state, cfg: MoEConfig):
+    """:func:`moe_apply` under the router that carries a state
+    (:func:`route_mlp`): ``(y, state_out)``."""
+    idx, gate, state = route_mlp(p, x, state, cfg)
+    return _held_part(p, x, idx, gate, cfg), state
+
+
+def moe_dense_oracle(p, x, cfg: MoEConfig, state=None):
     """The same layer the plain way, for the CPU tests: every held
     expert computes every token in float32, masked by the token's
-    weight for it. No sort, no grouped product."""
+    weight for it (``state``: the MLP router's). No sort, no grouped
+    product."""
     xf = x.astype(jnp.float32)
-    idx, gate = route(p, x, cfg)
+    idx, gate = (route(p, x, cfg) if cfg.router_hidden is None
+                 else route_mlp(p, x, state, cfg)[:2])
     y = jnp.zeros_like(xf)
     for e in range(cfg.held):
         w = jnp.sum(jnp.where(idx == cfg.first_held + e, gate, 0.0), -1)

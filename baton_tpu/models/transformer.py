@@ -436,14 +436,23 @@ def blocked_causal_core(q, k, v, scale: float, block: int, chosen=None):
     l = q.shape[2]
 
     def one(qb, kb, vb, cb, start):
-        s = jnp.einsum("bhqd,bhkd->bhqk", qb, kb,
+        hq, hkv = qb.shape[1], kb.shape[1]
+        scores, mix = "bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd"
+        if hq != hkv:
+            # grouped heads: query head i on key-value head i // group,
+            # the group an axis beside the heads, no key repeated
+            qb = qb.reshape(qb.shape[0], hkv, hq // hkv, *qb.shape[2:])
+            scores, mix = "bhgqd,bhkd->bhgqk", "bhgqk,bhkd->bhgqd"
+            cb = None if cb is None else cb[:, None]
+        s = jnp.einsum(scores, qb, kb,
                        preferred_element_type=jnp.float32) * scale
-        seen = (start + jnp.arange(qb.shape[2]))[:, None] \
+        seen = (start + jnp.arange(qb.shape[-2]))[:, None] \
             >= jnp.arange(kb.shape[2])[None, :]
         if cb is not None:
             seen = seen & (cb[:, None] != 0)
         p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(vb.dtype), vb)
+        out = jnp.einsum(mix, p.astype(vb.dtype), vb)
+        return out.reshape(out.shape[0], hq, *out.shape[-2:])
 
     if l <= block:
         return one(q, k, v, chosen, 0)
@@ -454,11 +463,12 @@ def blocked_causal_core(q, k, v, scale: float, block: int, chosen=None):
              s) for s in range(0, l, block)], axis=2)
 
 
-@jax.named_scope("mla_core")
-def causal_core(q, k, v, scale: float, block: int, chosen=None):
-    """Causal softmax attention ``[B, H, L, Dv]`` of ``q, k [B, H, L,
-    Dk]`` and ``v [B, H, L, Dv]`` (``Dv`` as wide as ``Dk`` or
-    narrower), the scores and the softmax in float32, the probabilities
+def _causal_core(q, k, v, scale: float, block: int, chosen=None):
+    """Causal softmax attention ``[B, H, L, Dv]`` of ``q [B, H, L,
+    Dk]``, ``k [B, Hkv, L, Dk]`` and ``v [B, Hkv, L, Dv]`` (``Dv`` as
+    wide as ``Dk`` or narrower; ``H`` a multiple of ``Hkv``: query head
+    ``i`` attends key-value head ``i // (H / Hkv)``), the scores and
+    the softmax in float32, the probabilities
     cast to ``v``'s dtype. With ``chosen [B, L, L]`` (nonzero: query
     ``t`` may see key ``s``; one choice for all heads,
     :func:`chosen_keys`) the softmax runs over the chosen keys of the
@@ -479,6 +489,11 @@ def causal_core(q, k, v, scale: float, block: int, chosen=None):
                                block_q=block_q, block_k=block_k,
                                chosen=chosen)
     return blocked_causal_core(q, k, v, scale, block, chosen)
+
+
+# one core under the scope of the mixer that calls it
+causal_core = jax.named_scope("mla_core")(_causal_core)
+cca_core = jax.named_scope("cca_core")(_causal_core)
 
 
 # ------------------------------------------------- the keys a query chose
@@ -731,6 +746,178 @@ def mla_apply(p, x, n_heads: int, cfg: MLAConfig, rope, pre_norm=None):
 
 
 # ---------------------------------------------------------------------------
+# compressed convolutional attention (CCA: Zyphra, arXiv:2510.04476)
+
+
+@dataclasses.dataclass(frozen=True)
+class CCAConfig:
+    """Attention that never leaves its latent: ``n_heads`` query heads
+    and ``n_kv_heads`` key heads of ``head_dim`` come from one
+    projection each (``n_heads * head_dim`` is narrower than the
+    model), are mixed along the sequence by two causal convolutions
+    over the joined channels (``time0`` taps a channel, then ``time1``
+    taps a head), and the output projection widens the heads' outputs
+    to the model again. The first ``rope_dim`` channels of a head are
+    rotated."""
+
+    n_heads: int = 8
+    n_kv_heads: int = 2
+    head_dim: int = 128
+    time0: int = 2
+    time1: int = 2
+    rotary_factor: float = 0.5
+    rope_theta: float = 5e6
+    # queries a block of the core where it is plain JAX
+    # (:func:`blocked_causal_core`)
+    block: int = 512
+
+    def __post_init__(self):
+        if self.n_kv_heads != 2 or self.n_heads % 2:
+            raise NotImplementedError(
+                "the two value heads are the token's own values and the "
+                "token before's: two key-value heads, an even number of "
+                "query heads")
+
+    @property
+    def rope_dim(self) -> int:
+        return int(self.head_dim * self.rotary_factor)
+
+    @property
+    def latent_q(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def latent_kv(self) -> int:
+        return self.n_kv_heads * self.head_dim
+
+
+def cca_init(key, d_model: int, cfg: CCAConfig, out_std=None):
+    """The convolutions' biases are drawn at deviation 0.02, not zeros:
+    a test has to tell a term that is applied from one left out. The
+    keys' temperatures are drawn uniform in [1.5, 4.5]: random weights
+    stand for trained ones and trained attention is peaked. The scores
+    of random unit-length queries and keys have the temperature's
+    deviation; near 1 the softmax over thousands of keys is flat, a
+    query's output is the mean of its prefix's values and next to no
+    gradient reaches the mixer's adapters."""
+    kq, kk, kv1, kv2, ko, kc0, kc1, kb0, kb1, kt = jax.random.split(key, 10)
+    d, heads = cfg.head_dim, cfg.n_heads + cfg.n_kv_heads
+    return {
+        "linear_q": dense_init(kq, d_model, cfg.latent_q),
+        "linear_k": dense_init(kk, d_model, cfg.latent_kv),
+        "val_proj1": dense_init(kv1, d_model, d),
+        "val_proj2": dense_init(kv2, d_model, d),
+        "o_proj": dense_init(ko, cfg.latent_q, d_model, stddev=out_std),
+        # a channel's taps, oldest first; then a head's [d, d] a tap
+        "conv0_w": normal_init(kc0, (cfg.time0, heads * d),
+                               cfg.time0 ** -0.5),
+        "conv0_b": normal_init(kb0, (heads * d,), 0.02),
+        "conv1_w": normal_init(kc1, (cfg.time1, heads, d, d),
+                               (cfg.time1 * d) ** -0.5),
+        "conv1_b": normal_init(kb1, (heads * d,), 0.02),
+        "temp": jax.random.uniform(kt, (cfg.n_kv_heads,), jnp.float32,
+                                   1.5, 4.5),
+    }
+
+
+def _shifted(y, by: int = 1):
+    """``y [B, L, ...]`` a token later: row ``t`` holds row ``t - by``,
+    the first ``by`` rows zeros."""
+    pad = [(0, 0)] * y.ndim
+    pad[1] = (by, 0)
+    return jnp.pad(y, pad)[:, :y.shape[1]]
+
+
+def cca_convolve(p, u, cfg: CCAConfig):
+    """The two causal convolutions over ``u [B, L, C]`` (``C`` the
+    joined query and key channels) in float32, the second one's products
+    of operands in ``u``'s dtype: ``time0 - 1 + time1 -
+    1`` zeros stand before the sequence, then, neither padded again,
+
+        y[t] = sum_j w0[j] * u[t - (time0 - 1) + j] + b0   a channel
+        z[t] = sum_j y[t - (time1 - 1) + j] W1[j] + b1     a head, [d, d]
+
+    so the second one's oldest tap sees ``b0`` before the sequence, as
+    a padded input through two unpadded convolutions gives it. Written
+    as slices of the padded sequence, products and one ``einsum`` a
+    head with the taps joined in the contraction, not as ``lax.conv``:
+    under a client ``vmap`` that would be a grouped convolution over
+    the clients."""
+    b, l, c = u.shape
+    d, dtype = cfg.head_dim, u.dtype
+    t0, t1 = cfg.time0, cfg.time1
+    u = jnp.pad(u.astype(jnp.float32), ((0, 0), (t0 + t1 - 2, 0), (0, 0)))
+    w0 = p["conv0_w"].astype(jnp.float32)
+    n = l + t1 - 1
+    y = sum(w0[j] * u[:, j:j + n] for j in range(t0)) + p["conv0_b"]
+    # the heads lead: a batched product as every backend has it
+    y = jnp.moveaxis(y.reshape(b, n, c // d, d), 2, 0)
+    taps = jnp.concatenate([y[:, :, j:j + l] for j in range(t1)], axis=-1)
+    w1 = jnp.moveaxis(p["conv1_w"], 0, 1).reshape(c // d, t1 * d, d)
+    z = jnp.einsum("hblk,hkd->hbld", taps.astype(dtype), w1.astype(dtype),
+                   preferred_element_type=jnp.float32)
+    return jnp.moveaxis(z, 0, 2).reshape(b, l, c) + p["conv1_b"]
+
+
+# under the root of a head's squared length: a guard for a vector of
+# zeros, far under any latent's
+_UNIT_EPS = 1e-12
+
+
+@jax.named_scope("compressed_attention")
+def cca_apply(p, x, cfg: CCAConfig, rope):
+    """Compressed convolutional attention over ``x [B, L, D] -> [B, L,
+    D]``, causal; ``rope`` is ``rope_angles(L, cfg.rope_dim,
+    cfg.rope_theta)``. With ``g`` query heads a key head::
+
+        q~, k~ = x W_q, x W_k                        the latents
+        q^, k^ = split(conv1(conv0([q~ ; k~])))      :func:`cca_convolve`
+        q = q^ + (q~ + repeat_g(k~)) / 2
+        k = k^ + (mean over its g query heads of q~ + k~) / 2
+        v = [x_t W_v1 ; x_{t-1} W_v2]                a head each
+        q, k = sqrt(d) q / |q|,  temp sqrt(d) k / |k|   a head, float32
+        the first rope_dim channels of q and k rotated
+        y = softmax_causal(q k^T / sqrt(d)) v W_o    query head i on key
+                                                     head i // g
+
+    Everything between the projections is ``latent_q`` or ``latent_kv``
+    wide. The core is :func:`cca_core` (the flash kernel with grouped
+    heads on a TPU, the blocked plain computation elsewhere)."""
+    b, l, _ = x.shape
+    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q_lat = x @ p["linear_q"].astype(x.dtype)
+    k_lat = x @ p["linear_k"].astype(x.dtype)
+    v_own = x @ p["val_proj1"].astype(x.dtype)
+    v_before = x @ p["val_proj2"].astype(x.dtype)
+    with jax.named_scope("cca_mix"):
+        mixed = cca_convolve(p, jnp.concatenate([q_lat, k_lat], -1), cfg)
+        q_lat = q_lat.astype(jnp.float32).reshape(b, l, hkv, hq // hkv, d)
+        k_lat = k_lat.astype(jnp.float32).reshape(b, l, hkv, 1, d)
+        q = mixed[..., :hq * d].reshape(q_lat.shape) \
+            + 0.5 * (q_lat + k_lat)
+        k = mixed[..., hq * d:].reshape(k_lat.shape) \
+            + 0.5 * (jnp.mean(q_lat, axis=3, keepdims=True) + k_lat)
+
+        def unit(y):  # sqrt(d) y / |y|
+            return y * jax.lax.rsqrt(
+                jnp.mean(y * y, axis=-1, keepdims=True) + _UNIT_EPS)
+
+        def heads(y, n):  # [B, n, L, d], the rotary part turned
+            y = y.reshape(b, l, n, d).transpose(0, 2, 1, 3)
+            return jnp.concatenate(
+                [apply_rope(y[..., :cfg.rope_dim], *rope),
+                 y[..., cfg.rope_dim:]], axis=-1).astype(x.dtype)
+
+        q = heads(unit(q), hq)
+        k = heads(unit(k) * p["temp"][:, None, None], hkv)
+        # h_{t-1} W_v2 is (h W_v2) a token later
+        v = jnp.stack([v_own, _shifted(v_before)], axis=1)
+    out = cca_core(q, k, v, d ** -0.5, cfg.block)
+    out = out.transpose(0, 2, 1, 3).reshape(b, l, hq * d)
+    return out @ p["o_proj"].astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
 # MLPs
 
 
@@ -820,10 +1007,19 @@ def per_token_cross_entropy(logits, labels):
 _LOGITS_BLOCK_BYTES = 128 * 1024 ** 2
 
 
+def tied_logits(x, table):
+    """``x [..., D]`` against the embedding ``table [V, D]`` itself,
+    float32: the head of a model whose embeddings are tied, contracted
+    over the table's second axis where it lies (no ``[D, V]`` copy)."""
+    return jnp.einsum("...d,vd->...v", x, table.astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+
+
 @jax.named_scope("lm_loss")
-def next_token_loss(x, w_head, labels):
+def next_token_loss(x, w_head, labels, tied: bool = False):
     """Per-token cross-entropy ``[B, L]`` (fp32) of the head ``x [B, L,
-    D] @ w_head [D, V]`` against ``labels [B, L]``, what
+    D] @ w_head [D, V]`` (``tied``: ``w_head`` is the embedding ``[V,
+    D]``, :func:`tied_logits`) against ``labels [B, L]``, what
     :func:`per_token_cross_entropy` gives for the whole ``[B, L, V]``
     logits, computed in blocks of tokens: a ``lax.scan`` over the
     fewest equal blocks whose logits stay under
@@ -834,9 +1030,11 @@ def next_token_loss(x, w_head, labels):
 
     def block(xb, yb):
         return per_token_cross_entropy(
-            matmul(xb, w_head, jnp.float32), yb)
+            tied_logits(xb, w_head) if tied
+            else matmul(xb, w_head, jnp.float32), yb)
 
-    n = min(l, -(-(4 * b * l * w_head.shape[1]) // _LOGITS_BLOCK_BYTES))
+    vocab = w_head.shape[0 if tied else 1]
+    n = min(l, -(-(4 * b * l * vocab) // _LOGITS_BLOCK_BYTES))
     if n <= 1:
         return block(x, labels)
     t = -(-l // n)

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import dataclasses
 import importlib.metadata
 import json
@@ -114,6 +115,20 @@ def _rel_err(got, ref) -> float:
     _check(got.shape == ref.shape, f"shape {got.shape} != {ref.shape}")
     _check(bool(np.isfinite(got).all()), "non-finite values")
     return float(np.max(np.abs(got - ref)) / max(1.0, np.max(np.abs(ref))))
+
+
+@contextlib.contextmanager
+def _plain_core():
+    """The attention core as the blocked plain computation, on a TPU
+    too: what an oracle runs beside the kernels."""
+    from baton_tpu.models import transformer
+
+    kernel, transformer.core_runs_the_kernel = (
+        transformer.core_runs_the_kernel, lambda *_: False)
+    try:
+        yield
+    finally:
+        transformer.core_runs_the_kernel = kernel
 
 
 def _on_platform(tree, platform: str) -> bool:
@@ -596,7 +611,7 @@ def phase_dsa_mla_lora(env: Env) -> None:
 
     def layer(x, plain: bool):
         if not plain:
-            return llama._block_apply(blk, x, decoder, rope, None)
+            return llama._block_apply(blk, x, None, decoder, rope, None)[0]
         x = x + transformer.mla_apply(blk["mla"], x, decoder.n_heads, mla,
                                       rope, pre_norm=blk["norm_attn"])
         return x + moe.moe_dense_oracle(
@@ -634,13 +649,8 @@ def phase_dsa_mla_lora(env: Env) -> None:
         scores32 = jax.jit(index_of)(x32)
         chose32 = np.asarray(transformer.chosen_keys(
             scores32, *jax.jit(by_sort)(scores32)))[0] != 0
-        # the oracle's core is the blocked plain one, on a TPU too
-        kernel, transformer.core_runs_the_kernel = (
-            transformer.core_runs_the_kernel, lambda *_: False)
-        try:
+        with _plain_core():
             out32 = np.asarray(jax.jit(partial(layer, plain=True))(x32)[0])
-        finally:
-            transformer.core_runs_the_kernel = kernel
     n_keys = np.minimum(np.arange(length) + 1, topk)
     _check((chose.sum(-1) == n_keys).all() and (chose32.sum(-1) == n_keys).all(),
            "a query chose another number of keys than min(t + 1, topk)")
@@ -676,6 +686,171 @@ def phase_dsa_mla_lora(env: Env) -> None:
             f"{int((~alike).sum())}; the choice of [{length}, {length}] "
             f"scores by bisection {ms(t_bisect)}, by sort {ms(t_sort)}, the "
             f"same threshold and index a query")
+
+
+# ----------------------------------------------------------------------
+def phase_cca_lora(env: Env) -> None:
+    """Compressed convolutional attention and the router that carries a
+    state. First the bfloat16 path at small widths on the kernel branch
+    (the benchmark's ``tiny`` rehearsal computes in float32 and never
+    leaves the blocked plain core): two blocks of 4 query on 2 key-value
+    heads of 128, 4 experts and the skip, over a frozen bfloat16 base,
+    two rounds through ``FedSim`` at 2,048 tokens (in rehearsal 32, the
+    plain core); on a TPU the wave program holds the flash kernels with
+    grouped heads and the Pallas grouped products, and the mixer's
+    bfloat16 output lies within the repo's tolerance of the same mixer
+    in float32 on the blocked plain core. Then ``zaya1_8b`` at the
+    published widths (in rehearsal at its ``tiny`` sizes) on one
+    sequence of ``zaya1_c4_l8192``, the frozen base alone, bfloat16
+    beside float32 at ``highest`` (the plain core, the oracle's loop
+    over experts): a layer, the rows the fullest and the emptiest expert
+    saw and the share of tokens that skipped, and the share of tokens
+    whose one choice differs between the two streams, which is what
+    stands between the probe's two sides beside rounding: a flipped
+    choice swaps a whole expert's output, where a flip among 8 swaps an
+    eighth; and the distance between the two streams after the stage
+    over the tokens that chose alike in every layer and over the
+    others."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models import llama, moe, transformer
+    from baton_tpu.models.lora import lora_trainable
+    from baton_tpu.parallel.engine import FedSim
+    from fedbench import data as cohort, manifest
+
+    tiny = env.rehearsal
+    length = 32 if tiny else 2048
+    cca = transformer.CCAConfig(n_heads=4, n_kv_heads=2, head_dim=128,
+                                block=16 if tiny else 512)
+    cfg = llama.LlamaConfig(
+        vocab_size=512, max_len=length, d_model=256, n_layers=2, n_heads=4,
+        n_kv_heads=2, d_ff=256, rope_theta=5e6, embed_std=1.0, norm_eps=1e-5,
+        layer_types=("compressed_attention",) * 2, cca=cca,
+        moe=moe.MoEConfig(n_experts=4, top_k=1, d_ff=256, router_hidden=128,
+                          skip=True, router_bias_range=0.1,
+                          router_norm_eps=1e-5),
+        residual_merge=True, tie_embeddings=True)
+    model = llama.decoder_lora_model(cfg, rank=4, b_std=0.02)
+    params = jax.jit(model.init)(jax.random.key(0))
+    first = jax.random.randint(jax.random.key(1), (2, 1, 1), 0, 512)
+    tokens = (first + 7 * jnp.arange(length + 1)) % 512
+    data = {"x": tokens[..., :-1], "y": tokens[..., 1:]}
+    n_samples = np.asarray([1, 1], np.int32)
+    sim = FedSim(model, batch_size=1, learning_rate=0.05,
+                 trainable=lora_trainable)
+    losses, p = [], params
+    for i in range(2):
+        res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
+                            n_epochs=1, collect_client_losses=False)
+        losses.append(float(res.loss_history[-1]))
+        p = res.params
+    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
+    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    _check(all(a is b for a, b in zip(
+        jax.tree_util.tree_leaves(params["base"]),
+        jax.tree_util.tree_leaves(p["base"]))),
+        "a round copied or cast a leaf of the frozen base")
+    kernels = sim.lower_wave(params, data, n_samples, jax.random.key(2), 1,
+                             None).compile().as_text().count("tpu_custom_call")
+    _check(tiny or kernels > 0, "no Pallas kernel in the wave program")
+    # the mixer alone: bfloat16 through the kernel beside float32 on the
+    # blocked plain core
+    blk = params["base"]["blocks"][0]["cca"]
+    rope = transformer.rope_angles(length, cca.rope_dim, cca.rope_theta)
+    h = jax.random.normal(jax.random.key(5), (2, length, 256))
+    got = jax.jit(lambda h: transformer.cca_apply(blk, h, cca, rope))(
+        h.astype(jnp.bfloat16))
+    with jax.default_matmul_precision("highest"), _plain_core():
+        want = jax.jit(lambda h: transformer.cca_apply(
+            jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), blk),
+            h, cca, rope))(h)
+    err = _rel_err(got, want)
+    _check(err < 2 * BF16_TOL, f"the bfloat16 mixer lies {err:.4f} from the "
+           f"float32 one")
+
+    # ---- zaya1_8b at the published widths: who chooses what
+    root = manifest.ROOT
+    config = manifest.load_config(root, manifest.load_manifest(root),
+                                  "zaya1_8b")
+    job = manifest.load_workload(root, "zaya1_c4_l8192")
+    if tiny:
+        job.update(job["tiny"])
+    sized = manifest.sized(config, tiny)
+    decoder = manifest.resolve(config["builder"]["kwargs"]["config"], sized)
+    seed, seq = 17, job["seq_len"]
+    big = llama.llama_lm_model(
+        decoder, param_dtype=jnp.float32 if tiny else jnp.bfloat16)
+    base = jax.jit(big.init)(jax.random.key(seed))
+    ids = cohort.make_cohort(
+        root, manifest.input_spec(config, tiny), np.asarray([1], np.int32),
+        1, seq, cohort.data_key(seed + 1))["x"][0]            # [1, L]
+    big_rope = transformer.rope_angles(seq, decoder.cca.rope_dim,
+                                       decoder.cca.rope_theta)
+
+    def stage(dtype, plain: bool):
+        """Every layer's choice ``[layers, L]`` and the stream after the
+        stage, the blocks' own parts wired as ``_block_apply`` wires
+        them; ``plain``: the oracle's loop over the experts."""
+        @jax.jit
+        def run(base):
+            x = base["tok_emb"][ids].astype(dtype)
+            r = jnp.zeros(ids.shape + (decoder.moe.router_hidden,),
+                          jnp.float32)
+            chose = []
+            for blk in base["blocks"]:
+                x = llama._mix(blk, x, decoder, big_rope, None)
+                hn = transformer.rms_norm(x, blk["norm_mlp"], decoder.norm_eps)
+                idx, _, r_out = moe.route_mlp(blk["mlp"], hn, r, decoder.moe)
+                y = (moe.moe_dense_oracle(blk["mlp"], hn, decoder.moe, r)
+                     if plain else
+                     moe.moe_apply_with_state(blk["mlp"], hn, r,
+                                              decoder.moe)[0])
+                x, r = llama._joined(blk, "merge_mlp", x, y), r_out
+                chose.append(idx[0, :, 0])
+            return jnp.stack(chose), x[0]
+        return run(base)
+
+    dtype = jnp.float32 if tiny else jnp.bfloat16
+    chose, out = stage(dtype, plain=False)
+    with jax.default_matmul_precision("highest"), _plain_core():
+        chose32, out32 = stage(jnp.float32, plain=True)
+    chose, chose32 = np.asarray(chose), np.asarray(chose32)
+    out, out32 = np.asarray(out, np.float32), np.asarray(out32)
+    _check(np.isfinite(out).all() and np.isfinite(out32).all(),
+           "a non-finite stream")
+    n_out = decoder.moe.router_outputs
+    rows = [np.bincount(c, minlength=n_out) for c in chose]
+    differs = (chose != chose32).mean(axis=1)
+    alike = (chose == chose32).all(axis=0)
+    apart = np.linalg.norm(out - out32, axis=-1) \
+        / np.linalg.norm(out32, axis=-1)
+    _check(all(r[:-1].min() > 0 for r in rows) or tiny,
+           f"an expert saw no row: {[r.tolist() for r in rows]}")
+
+    def _mean(a):
+        return float(a.mean()) if a.size else float("nan")
+
+    env.say("cca_lora",
+            f"two blocks of 4 on 2 heads of 128, 4 experts and the skip, "
+            f"bfloat16 base, {length} tokens: losses {losses[0]:.4f} -> "
+            f"{losses[1]:.4f}, {kernels} Pallas calls in the wave program, the "
+            f"bfloat16 mixer {err:.4f} from the float32 one (of its largest "
+            f"entry). zaya1_8b at {'tiny' if tiny else 'the published'} "
+            f"sizes, {len(rows)} layers, {seq} tokens, seed {seed}: a layer "
+            f"(fullest expert, emptiest, skipped) "
+            + " ".join(f"({r[:-1].max()}, {r[:-1].min()}, {r[-1]})"
+                       for r in rows)
+            + f" of {seq / n_out:.0f} expected; "
+            f"{'float32' if tiny else 'bfloat16'} and float32 at highest "
+            f"choose differently on " + " ".join(
+                f"{100 * d:.2f}" for d in differs)
+            + f" % of a layer's tokens (mean {100 * differs.mean():.3f} %); "
+            f"{int(alike.sum())} of {seq} tokens chose alike in every layer; "
+            f"the two streams lie apart by {_mean(apart[alike]):.4f} of the "
+            f"float32 one's norm over those, by {_mean(apart[~alike]):.4f} "
+            f"over the others")
 
 
 def _flash_alone(env: Env) -> str:
@@ -1113,7 +1288,7 @@ def phase_cache(env: Env) -> None:
 PHASES = {"device": phase_device, "fedsim_resnet18": phase_fedsim_resnet18,
           "hybrid_lora": phase_hybrid_lora,
           "moe_mla_lora": phase_moe_mla_lora,
-          "dsa_mla_lora": phase_dsa_mla_lora,
+          "dsa_mla_lora": phase_dsa_mla_lora, "cca_lora": phase_cca_lora,
           "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
           "mesh": phase_mesh, "cache": phase_cache}
 

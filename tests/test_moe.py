@@ -7,8 +7,14 @@ token, masked by the token's weight for it. The layer has to equal it
 under any routing, however uneven, forward and in every gradient; the
 shares of all ranks have to add up to the uncut layer; under a ``vmap``
 over clients the frozen stacks gain no client axis and no gradient.
+The second router (an MLP over a state carried from layer to layer, a
+softmax with one output more than there are experts, the skip) is held
+to the same: its choice and weight against the formulas, the state's
+way down three layers, a skipped token's exact zero and absent row, the
+shares of two ranks against the uncut layer.
 """
 
+import dataclasses
 import re
 
 import jax
@@ -27,11 +33,14 @@ from baton_tpu.models.moe import (
     _block_sizes,
     _gmm,
     _rows_of_the_groups,
+    _sorted_rows,
     grouped_matmul,
     moe_apply,
+    moe_apply_with_state,
     moe_dense_oracle,
     moe_init,
     route,
+    route_mlp,
     rows_bound,
 )
 
@@ -582,3 +591,151 @@ def test_the_wave_program_holds_the_stacks_once_and_takes_no_gradient(
     assert all(a is b for a, b in zip(
         jax.tree_util.tree_leaves(params["base"]),
         jax.tree_util.tree_leaves(res.params["base"])))
+
+
+# --------------------------------------- the MLP router, its state, the skip
+STATEFUL = MoEConfig(n_experts=4, top_k=1, d_ff=F, router_hidden=8, skip=True,
+                     router_bias_range=0.1, router_norm_eps=1e-5)
+
+
+def _erf_gelu(x):
+    return 0.5 * x * (1.0 + jax.lax.erf(x / np.sqrt(2.0)))
+
+
+def _plain_router(p, x, state):
+    r = p["router"]
+    s = x @ r["w_in"] + r["b_in"] + r["state_scale"] * state
+    z = s / jnp.sqrt(jnp.mean(s * s, -1, keepdims=True) + 1e-5) * r["norm"]
+    z = _erf_gelu(z @ r["w1"] + r["b1"])
+    z = _erf_gelu(z @ r["w2"] + r["b2"])
+    return jax.nn.softmax(z @ r["w3"], axis=-1), s
+
+
+def test_the_mlp_router_chooses_by_p_plus_bias_and_weighs_by_p(nprng):
+    p = _params(STATEFUL)
+    assert p["router"]["w3"].shape == (8, 5) and p["router_bias"].shape == (5,)
+    assert STATEFUL.router_outputs == 5
+    # the second and third matrices' columns sum to nothing
+    for name in ("w2", "w3"):
+        assert float(jnp.max(jnp.abs(p["router"][name].sum(0)))) < 1e-5
+    x = jnp.asarray(nprng.normal(size=(2, 64, D)), jnp.float32)
+    state = jnp.asarray(nprng.normal(size=(2, 64, 8)), jnp.float32)
+    idx, gate, out = route_mlp(p, x, state, STATEFUL)
+    prob, want_state = _plain_router(p, x, state)
+    _close(out, want_state)
+    assert idx.shape == gate.shape == (2, 64, 1)
+    assert jnp.array_equal(idx[..., 0],
+                           jnp.argmax(prob + p["router_bias"], -1))
+    # the weight is the probability itself: not renormalised (that
+    # would be 1), not the biased score
+    _close(gate, jnp.take_along_axis(prob, idx, -1))
+    assert float(jnp.min(gate)) < 0.9
+    unbiased, _, _ = route_mlp(dict(p, router_bias=jnp.zeros(5)), x, state,
+                               STATEFUL)
+    assert not jnp.array_equal(idx, unbiased)
+    # every output is chosen by someone, the skip among them
+    assert set(np.asarray(idx).ravel()) == set(range(5))
+    # no state is zeros of state
+    none = route_mlp(p, x, None, STATEFUL)
+    zeros = route_mlp(p, x, jnp.zeros_like(state), STATEFUL)
+    for a, b in zip(none, zeros):
+        assert jnp.array_equal(a, b)
+
+
+def test_the_state_carried_down_three_layers_changes_the_third_choice(nprng):
+    """Three layers' routers fed the same tokens: with the state handed
+    on, layer 2's router sees what layers 0 and 1 left; cut the way
+    (zeros in) and some of its tokens choose another expert."""
+    layers = [_params(STATEFUL, seed) for seed in range(3)]
+    x = jnp.asarray(nprng.normal(size=(1, 96, D)), jnp.float32)
+    state = None
+    for p in layers:
+        idx, _, state = route_mlp(p, x, state, STATEFUL)
+    alone, _, own = route_mlp(layers[2], x, None, STATEFUL)
+    assert int(jnp.sum(idx != alone)) > 5
+    # the state out is this layer's projection plus the scaled state in
+    _, _, before = route_mlp(layers[1], x, route_mlp(
+        layers[0], x, None, STATEFUL)[2], STATEFUL)
+    _close(state, own + layers[2]["router"]["state_scale"] * before)
+    # and it carries a gradient to the layer before's input
+    def through(x0):
+        s = route_mlp(layers[0], x0, None, STATEFUL)[2]
+        return jnp.sum(route_mlp(layers[1], x, s, STATEFUL)[1])
+    assert float(jnp.max(jnp.abs(jax.grad(through)(x)))) > 0
+
+
+def test_a_skipped_token_adds_exactly_zero_and_gets_no_row(nprng):
+    p = _params(STATEFUL)
+    x = jnp.asarray(nprng.normal(size=(2, 48, D)), jnp.float32)
+    idx, gate, _ = route_mlp(p, x, None, STATEFUL)
+    skipped = np.asarray(idx[..., 0] == STATEFUL.n_experts)
+    assert 0 < skipped.sum() < skipped.size
+    y, state = moe_apply_with_state(p, x, None, STATEFUL)
+    assert (np.asarray(y)[skipped] == 0).all()
+    assert (np.abs(np.asarray(y)[~skipped]).max(-1) > 0).all()
+    _close(y, moe_dense_oracle(p, x, STATEFUL))
+    # no row in the grouped products: the held experts' sizes sum to the
+    # tokens that did not skip, and the skipped assignments sort last
+    local = jnp.asarray(idx.reshape(-1, 1), jnp.int32)
+    order, _, sizes, live = _sorted_rows(local, STATEFUL.held)
+    assert int(sizes.sum()) == int((~skipped).sum()) == int(live.sum())
+    assert not np.asarray(live)[int(sizes.sum()):].any()
+    assert skipped.reshape(-1)[np.asarray(order)[int(sizes.sum()):]].all()
+    # one block over every row, no loop: the router is 5 wide over 4 held
+    assert rows_bound(96, 4, 5) == 96
+    # the gradient of a skipped token's input through the layer is the
+    # router's alone (its state goes on), and the oracle's
+    weight = jnp.asarray(nprng.normal(size=x.shape), jnp.float32)
+
+    def grad(fn):
+        return jax.grad(lambda x: jnp.sum(fn(x) * weight))(x)
+
+    _close(grad(lambda x: moe_apply_with_state(p, x, None, STATEFUL)[0]),
+           grad(lambda x: moe_dense_oracle(p, x, STATEFUL)), rtol=1e-4)
+
+
+def test_two_ranks_of_two_experts_and_the_skip_add_up(nprng):
+    """The guide's test of a share, kept although the cell holds all 16:
+    ranks holding experts 0-1 and 2-3 route over all five outputs; their
+    parts add up to the uncut layer's result, and the skip is nobody's."""
+    p = _params(STATEFUL)
+    x = jnp.asarray(nprng.normal(size=(2, 40, D)), jnp.float32)
+    state = jnp.asarray(nprng.normal(size=(2, 40, 8)), jnp.float32)
+    want, want_state = moe_apply_with_state(p, x, state, STATEFUL)
+    total = jnp.zeros_like(x)
+    for first in (0, 2):
+        cut = dataclasses.replace(STATEFUL, experts_held=2, first_held=first)
+        drawn = moe_init(jax.random.key(0), D, 4 * F, cut)
+        assert jnp.array_equal(drawn["w_up"], p["w_up"][first:first + 2])
+        assert jnp.array_equal(drawn["router"]["w3"], p["router"]["w3"])
+        part, got_state = moe_apply_with_state(drawn, x, state, cut)
+        assert jnp.array_equal(got_state, want_state)
+        _close(part, moe_dense_oracle(drawn, x, cut, state))
+        total = total + part
+    _close(total, want)
+    assert float(jnp.max(jnp.abs(total))) > 0
+
+
+def test_the_stateful_layer_under_the_wave_programs_nesting(nprng):
+    """``jit(vmap(value_and_grad(checkpoint)))`` over clients with the
+    state an input: the oracle's loss and both gradients a client."""
+    p = _params(STATEFUL)
+    xs = jnp.asarray(nprng.normal(size=(3, 1, 24, D)), jnp.float32)
+    states = jnp.asarray(nprng.normal(size=(3, 1, 24, 8)), jnp.float32)
+
+    def loss(x, r):
+        y, r = jax.checkpoint(
+            lambda x, r: moe_apply_with_state(p, x, r, STATEFUL))(x, r)
+        return jnp.sum(y ** 2) + jnp.sum(r ** 2)
+
+    def plain(x, r):
+        return jnp.sum(moe_dense_oracle(p, x, STATEFUL, r) ** 2) \
+            + jnp.sum(route_mlp(p, x, r, STATEFUL)[2] ** 2)
+
+    got = jax.jit(jax.vmap(jax.value_and_grad(loss, argnums=(0, 1))))(
+        xs, states)
+    for c in range(3):
+        want = jax.value_and_grad(plain, argnums=(0, 1))(xs[c], states[c])
+        assert float(got[0][c]) == pytest.approx(float(want[0]), rel=1e-5)
+        for g, w in zip(got[1], want[1]):
+            _close(g[c], w, rtol=1e-4)
