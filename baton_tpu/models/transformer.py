@@ -994,10 +994,20 @@ def matmul(x, w, preferred_element_type=None):
 
 
 def per_token_cross_entropy(logits, labels):
-    """logits [B, L, V], labels int32 [B, L] -> fp32 [B, L]."""
+    """logits [B, L, V], labels int32 [B, L] -> fp32 [B, L].
+
+    ``labels`` are ids in ``[0, V)``. A token's own logit is picked by
+    comparing an iota over the vocabulary with its label and summing
+    the one term left, not by a gather: a gather's transpose is a
+    scatter, which a TPU runs on a flat copy of the whole ``[B, L, V]``
+    cotangent, while the comparison's is a ``where`` that fuses into
+    the softmax's gradient. A label outside ``[0, V)`` matches no id,
+    so its logit reads 0 and the loss is the log-sum-exp alone (the
+    gather filled with NaN past the end and wrapped a negative one)."""
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, labels[..., None], axis=-1).squeeze(-1)
+    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    ll = jnp.sum(jnp.where(ids == labels[..., None], logits, 0.0), axis=-1)
     return logz - ll
 
 
@@ -1025,7 +1035,10 @@ def next_token_loss(x, w_head, labels, tied: bool = False):
     fewest equal blocks whose logits stay under
     ``_LOGITS_BLOCK_BYTES``, each under ``jax.checkpoint`` so the
     backward recomputes a block's logits instead of keeping every
-    block's. One block (a small vocabulary) is the plain computation."""
+    block's. One block (a small vocabulary) is the plain computation.
+    ``labels`` are ids in ``[0, V)`` (one outside reads a logit of 0:
+    :func:`per_token_cross_entropy`); the tail block's padding carries
+    label 0, which is in range."""
     b, l, d = x.shape
 
     def block(xb, yb):

@@ -535,6 +535,64 @@ def test_block_count_follows_the_shapes():
     assert len(scans) == 1 and scans[0].params["length"] == 4
 
 
+@pytest.mark.parametrize("under_vmap", [False, True])
+@pytest.mark.parametrize("shape", [(2, 16, 128), (4, 61, 203)])
+def test_a_tokens_own_logit_by_comparison_is_the_gathers(shape, under_vmap):
+    """Values and the gradient with respect to the logits against
+    ``logz - take_along_axis``, at an aligned and a misaligned shape;
+    a label outside ``[0, V)`` reads a logit of 0."""
+    v = shape[-1]
+    kl, ky, kg = jax.random.split(jax.random.key(v), 3)
+    logits = 3.0 * jax.random.normal(kl, shape)
+    labels = jax.random.randint(ky, shape[:-1], 0, v)
+    weight = jax.random.normal(kg, shape[:-1])
+
+    def gathered(logits, labels):
+        own = jnp.take_along_axis(logits, labels[..., None], axis=-1)
+        return jax.nn.logsumexp(logits, axis=-1) - own[..., 0]
+
+    wrap = jax.vmap if under_vmap else (lambda fn: fn)
+    ours, gathers = wrap(per_token_cross_entropy), wrap(gathered)
+
+    def gradient(fn):
+        return jax.grad(lambda z: jnp.sum(fn(z, labels) * weight))(logits)
+
+    np.testing.assert_allclose(
+        np.asarray(ours(logits, labels)),
+        np.asarray(gathers(logits, labels)), rtol=0, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(gradient(ours)), np.asarray(gradient(gathers)),
+        rtol=0, atol=1e-6)
+
+    outside = labels.at[0, 0].set(v).at[-1, -1].set(-1)
+    tok = np.asarray(ours(logits, outside))
+    logz = np.asarray(jax.nn.logsumexp(logits, axis=-1))
+    assert tok[0, 0] == logz[0, 0] and tok[-1, -1] == logz[-1, -1]
+    np.testing.assert_array_equal(
+        tok.ravel()[1:-1], np.asarray(ours(logits, labels)).ravel()[1:-1])
+
+
+@pytest.mark.parametrize("scanned", [False, True])
+@pytest.mark.parametrize("tied", [False, True])
+def test_the_losss_gradient_lowers_without_a_scatter(tied, scanned,
+                                                     monkeypatch):
+    """A gather's transpose is a scatter-add into the whole block of
+    logits; the comparison's is a ``where``."""
+    b, l, d, v = 2, 12, 16, 50
+    if scanned:  # blocks of 4 tokens
+        monkeypatch.setattr(transformer, "_LOGITS_BLOCK_BYTES",
+                            4 * b * 4 * v)
+    y = jnp.zeros((b, l), jnp.int32)
+
+    def loss(x, w):
+        return jnp.sum(next_token_loss(x, w, y, tied=tied))
+
+    args = jnp.zeros((b, l, d)), jnp.zeros((v, d) if tied else (d, v))
+    assert ("scan" in str(jax.make_jaxpr(loss)(*args))) == scanned
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).as_text()
+    assert "dot_general" in lowered and "scatter" not in lowered
+
+
 def test_gated_delta_init_draws_the_gates_as_the_papers_code_does():
     p = gated_delta_init(jax.random.key(0), 64, 4, 8, 16)
     a = np.exp(np.asarray(p["a_log"]))
