@@ -54,7 +54,8 @@ class FedModel:
     # static facts of the model's layers as ``(name, number)`` pairs,
     # written on the ``baton.round`` span beside the round's own
     # (``FedSim.run_round``): an expert layer's ``experts_held`` and
-    # ``experts_total``
+    # ``experts_total``. Read afresh every round: a decoder's also yield
+    # what its last trace learned of the batch (``models/llama.py``)
     span_attrs: tuple = ()
 
     def masked_loss(self, params: Params, batch: Batch, rng: PRNGKey) -> jax.Array:

@@ -73,6 +73,8 @@ from baton_tpu.models.transformer import (
     MLAConfig,
     cca_apply,
     cca_init,
+    core_is_the_kernel,
+    dense_gives_way,
     dense_init,
     default_attention,
     matmul,
@@ -302,6 +304,59 @@ def _block_apply(p, x, r, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
     return _feed_forward(p, _mix(p, x, cfg, rope, attention_fn), r, cfg)
 
 
+def _checkpointed_block():
+    """:func:`_block_apply` as a ``remat`` model runs it: nothing of a
+    block survives to its backward but a flash kernel's two outputs,
+    where its attention is one (as much memory again as the block's
+    input or so, and the dearest thing in a block to make again: a
+    second run of the forward kernel). Where none runs nothing carries
+    those names and the checkpoint is the bare one."""
+    from baton_tpu.ops.flash_attention import KEPT_OUTPUTS
+
+    return jax.checkpoint(
+        _block_apply, static_argnums=(3, 5),
+        policy=jax.checkpoint_policies.save_only_these_names(*KEPT_OUTPUTS))
+
+
+def core_outputs_kept(cfg: LlamaConfig, backend: str, batch: int, length: int,
+                      attention_fn: AttentionFn = default_attention) -> int:
+    """The blocks whose checkpoint (``remat=True``) keeps a flash
+    kernel's output and log-sum-exp for its backward, on sequences of
+    ``length`` tokens, ``batch`` at a time: those whose mixer stands
+    under the block's checkpoint and is the kernel there. None on the
+    CPU, under one of the kernel's blocks, or where full attention is
+    dense."""
+    def kernel(layer: int) -> bool:
+        kind = cfg.kind_of(layer)
+        if kind == "compressed_attention":
+            return core_is_the_kernel(backend, length)
+        if kind == "latent_attention":
+            return (not cfg.mla.selects(length)
+                    and core_is_the_kernel(backend, length))
+        return (kind == "full_attention"
+                and attention_fn is default_attention
+                and dense_gives_way(backend, batch, cfg.n_heads, length,
+                                    length))
+
+    return sum(kernel(i) for i in range(cfg.n_layers))
+
+
+class _ModelFacts(tuple):
+    """A decoder's ``FedModel.span_attrs``: the configuration's facts,
+    which it equals and hashes as, and after them, when read, what the
+    model's last trace learned from its batch (``seen``: a model meets
+    its sequences' length only where it is traced)."""
+
+    def __new__(cls, static, seen: dict):
+        self = super().__new__(cls, static)
+        self.seen = seen
+        return self
+
+    def __iter__(self):
+        yield from super().__iter__()
+        yield from self.seen.items()
+
+
 def llama_lm_model(
     config: Optional[LlamaConfig] = None,
     compute_dtype=jnp.float32,
@@ -314,22 +369,23 @@ def llama_lm_model(
     the backward pass recomputes block activations instead of storing
     them, cutting activation memory from O(L·n_layers) to O(L) at ~1/3
     extra FLOPs — what makes long-sequence / large-model training
-    (config 4) fit HBM. ``param_dtype`` is the dtype ``init`` gives the
-    matrices and an expert layer's 3-D stacks (a base that stays frozen
-    is held in bfloat16); vectors (norm scales, the linear layers'
+    (config 4) fit HBM; a flash kernel's output and log-sum-exp are kept
+    (:func:`_checkpointed_block`). ``param_dtype`` is the dtype ``init``
+    gives the matrices and an expert layer's 3-D stacks (a base that
+    stays frozen is held in bfloat16); vectors (norm scales, the linear layers'
     ``a_log`` and ``dt_bias``, a router's bias) and the router itself
     are float32."""
     cfg = config or LlamaConfig.llama3_8b()
     # made once a model: ``jax.checkpoint`` caches its trace on the
     # function and the arguments' structure, so blocks of one kind share
     # one trace whatever the depth
-    block_fn = (jax.checkpoint(_block_apply, static_argnums=(3, 5)) if remat
-                else _block_apply)
+    block_fn = _checkpointed_block() if remat else _block_apply
     # a mixer that keeps its own inputs for the backward stands outside
     # the block's checkpoint, which would make it a third time
     ff_fn = (jax.checkpoint(_feed_forward, static_argnums=(3,)) if remat
              else _feed_forward)
     stateful = cfg.moe is not None and cfg.moe.router_hidden is not None
+    seen = {}  # what ``_hidden`` learned of the batch it was last traced on
     if stateful and cfg.first_dense_layers:
         raise NotImplementedError(
             "a router's state runs through every layer: no dense layer "
@@ -364,6 +420,8 @@ def llama_lm_model(
         """The final norm's output ``[B, L, D]``."""
         ids = batch["x"]
         l = ids.shape[1]
+        seen["core_outputs_kept"] = 0 if not remat else core_outputs_kept(
+            cfg, jax.default_backend(), *ids.shape, attention_fn)
         if cfg.mla is not None:
             rope = mla_rope_angles(l, cfg.mla)
         elif cfg.cca is not None:
@@ -422,7 +480,7 @@ def llama_lm_model(
                   ("latent_kv", cfg.cca.latent_kv),
                   ("conv_taps", f"{cfg.cca.time0}+{cfg.cca.time1}"))
     return FedModel(init=init, apply=apply, per_example_loss=per_example_loss,
-                    name=name, aux=cfg, span_attrs=facts)
+                    name=name, aux=cfg, span_attrs=_ModelFacts(facts, seen))
 
 
 def decoder_lora_model(

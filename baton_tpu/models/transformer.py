@@ -157,6 +157,16 @@ _FLASH_MIN_LEN = 4096
 _DENSE_SCORES_BUDGET_BYTES = 512 * 1024 ** 2
 
 
+def dense_gives_way(backend: str, batch: int, heads: int, lq: int,
+                    lk: int) -> bool:
+    """Whether :func:`default_attention` leaves the dense path for the
+    flash kernel (given a bias the kernel takes): on a TPU, at or past
+    ``_FLASH_MIN_LEN`` keys or past the score-tensor budget."""
+    return backend == "tpu" and (
+        lk >= _FLASH_MIN_LEN
+        or 4 * batch * heads * lq * lk > _DENSE_SCORES_BUDGET_BYTES)
+
+
 def default_attention(q, k, v, bias=None, causal=False):
     """Backend-dispatching attention — the model zoo's default kernel.
 
@@ -175,16 +185,13 @@ def default_attention(q, k, v, bias=None, causal=False):
     bias falls back to the dense kernel, which accepts anything
     broadcastable to [B, Hq, L, L].
     """
-    if jax.default_backend() == "tpu":
-        b, hq, lq, _ = q.shape
-        lk = k.shape[2]
-        scores_bytes = 4 * b * hq * lq * lk
-        if (lk >= _FLASH_MIN_LEN or scores_bytes > _DENSE_SCORES_BUDGET_BYTES) and (
-            bias is None or bias.shape == (b, 1, 1, lk)
-        ):
-            from baton_tpu.ops.flash_attention import flash_attention
+    b, hq, lq, _ = q.shape
+    lk = k.shape[2]
+    if dense_gives_way(jax.default_backend(), b, hq, lq, lk) and (
+            bias is None or bias.shape == (b, 1, 1, lk)):
+        from baton_tpu.ops.flash_attention import flash_attention
 
-            return flash_attention(q, k, v, bias=bias, causal=causal)
+        return flash_attention(q, k, v, bias=bias, causal=causal)
     return dot_product_attention(q, k, v, bias=bias, causal=causal)
 
 
@@ -427,6 +434,12 @@ def core_runs_the_kernel(backend: str, length: int, block: int) -> bool:
     return backend == "tpu" and length > block and length % block == 0
 
 
+def core_is_the_kernel(backend: str, length: int) -> bool:
+    """:func:`core_runs_the_kernel` at the blocks the core gives the
+    kernel."""
+    return core_runs_the_kernel(backend, length, max(_CORE_KERNEL_BLOCKS))
+
+
 def blocked_causal_core(q, k, v, scale: float, block: int, chosen=None):
     """:func:`causal_core` in plain JAX: a block of ``block`` queries at
     a time against its causal prefix of keys. A block is under
@@ -477,12 +490,13 @@ def _causal_core(q, k, v, scale: float, block: int, chosen=None):
     On a TPU (:func:`core_runs_the_kernel`) it is one call of
     ``ops/flash_attention.py``: a tile of scores lives in VMEM, forward
     and backward, and the kernel's ``custom_vjp`` keeps ``q, k, v``, the
-    choice, the output and the log-sum-exp, no float ``[L, L]``; it
+    choice, the output and the log-sum-exp, no float ``[L, L]`` (the
+    last two under names that a decoder block's checkpoint saves:
+    ``llama.py::_checkpointed_block``); it
     visits every causal tile and masks what was not chosen. Elsewhere
     it is :func:`blocked_causal_core` in blocks of ``block`` queries."""
     block_q, block_k = _CORE_KERNEL_BLOCKS
-    if core_runs_the_kernel(jax.default_backend(), q.shape[2],
-                            max(block_q, block_k)):
+    if core_is_the_kernel(jax.default_backend(), q.shape[2]):
         from baton_tpu.ops.flash_attention import flash_attention
 
         return flash_attention(q, k, v, causal=True, scale=scale,

@@ -17,7 +17,9 @@ replacing the dense ``dot_product_attention`` einsum path
 * **trains**: a custom VJP with a Pallas backward kernel recomputes
   p = exp(s − lse) blockwise from the saved logsumexp — the standard
   flash-attention backward — so the O(L²) probs are never stored for
-  the backward pass either;
+  the backward pass either; the forward kernel's two outputs carry
+  names (``KEPT_OUTPUTS``), so that a ``jax.checkpoint`` around the
+  call can save them and not run the forward kernel a second time;
 * **GQA for free**: the kv-head block index map sends query head ``h``
   to kv head ``h // (Hq//Hkv)`` — no ``jnp.repeat`` materialization;
 * **values as wide as they are**: ``Dv`` is read from ``v`` and need not
@@ -54,6 +56,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.layout import Layout, with_layout_constraint
 from jax.experimental.pallas import tpu as pltpu
@@ -591,11 +594,20 @@ def _flash(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
     return out
 
 
+# the names the forward kernel's two outputs carry where the backward
+# kernel keeps them (``jax.ad_checkpoint.checkpoint_name``): a
+# ``jax.checkpoint`` whose policy saves these two runs the forward
+# kernel once, and makes q, k and v again as it makes everything else;
+# under a bare one, and outside any, a name does nothing
+KEPT_OUTPUTS = ("flash_out", "flash_lse")
+
+
 def _flash_fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
                interpret):
     out, lse = _fwd(
         q, k, v, bias2d, chosen, causal, scale, block_q, block_k, interpret
     )
+    out, lse = map(checkpoint_name, (out, lse), KEPT_OUTPUTS)
     return out, (q, k, v, bias2d, chosen, out, lse)
 
 

@@ -131,6 +131,29 @@ def _plain_core():
         transformer.core_runs_the_kernel = kernel
 
 
+def _core_kernels(text: str, model, scope: str, layers: int,
+                  rehearsal: bool) -> int:
+    """The Pallas kernels under ``scope`` in ``text``, the compiled wave
+    program of one local step of ``model``: a block whose checkpoint
+    keeps the core's output and log-sum-exp holds two a layer, the
+    forward kernel and the one backward kernel (a bare checkpoint's
+    three: the forward made again), and the model says so of every layer
+    (``core_outputs_kept``). In rehearsal the core is the plain
+    computation: none, and none kept."""
+    import re
+
+    found = len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="[^"]*/'
+        + scope + "/", text))
+    kept = dict(model.span_attrs)["core_outputs_kept"]
+    want = 0 if rehearsal else layers
+    _check((found, kept) == (2 * want, want),
+           f"{found} kernels under {scope} in a step of {layers} layers, "
+           f"{kept} blocks said to keep its outputs: wanted {2 * want} "
+           f"and {want}")
+    return found
+
+
 def _on_platform(tree, platform: str) -> bool:
     import jax
 
@@ -449,6 +472,22 @@ def phase_moe_mla_lora(env: Env) -> None:
             "the wave program holds an expert stack in float32 or with a "
             "client axis")
 
+    # ---- the same decoder with the cell's heads (192 / 128) where its
+    # core is the flash kernel, 2,048 tokens: compiled, not run
+    wide = decoder_lora_model(dataclasses.replace(
+        cfg, mla=dataclasses.replace(cfg.mla, nope_dim=128, rope_dim=64,
+                                     v_dim=128)), rank=4, b_std=0.02)
+    long = (first[:, :1] + 7 * jnp.arange(
+        (128 if env.rehearsal else 2048) + 1)) % 96
+    core_kernels = _core_kernels(
+        FedSim(wide, batch_size=1, learning_rate=0.05,
+               trainable=lora_trainable).lower_wave(
+            jax.eval_shape(wide.init, jax.random.key(0)),
+            {"x": long[..., :-1], "y": long[..., 1:]},
+            np.asarray([1, 1, 1, 1], np.int32), jax.random.key(2), 1,
+            None).compile().as_text(),
+        wide, "mla_core", cfg.n_layers, env.rehearsal)
+
     # ---- the routing of sarvam_105b on its cell's inputs
     root = manifest.ROOT
     bench = manifest.load_manifest(root)
@@ -538,6 +577,9 @@ def phase_moe_mla_lora(env: Env) -> None:
             f"x 128 tokens, 2 rounds, loss {losses[0]:.4f} -> "
             f"{losses[1]:.4f}; {len(base)} base leaves handed back as the "
             f"arrays they were; {kernels} Pallas calls in the wave program; "
+            f"with heads of 192 / 128 at {long.shape[-1] - 1} tokens "
+            f"{core_kernels} kernels under mla_core in a step of "
+            f"{cfg.n_layers} layers; "
             f"sarvam_105b at {'tiny' if tiny else 'the published'} sizes, "
             f"{held} of {total} experts held, 4 sequences of "
             f"{job['seq_len']} tokens, seed {seed}: " + "; ".join(lines)
@@ -752,9 +794,11 @@ def phase_cca_lora(env: Env) -> None:
         jax.tree_util.tree_leaves(params["base"]),
         jax.tree_util.tree_leaves(p["base"]))),
         "a round copied or cast a leaf of the frozen base")
-    kernels = sim.lower_wave(params, data, n_samples, jax.random.key(2), 1,
-                             None).compile().as_text().count("tpu_custom_call")
+    text = sim.lower_wave(params, data, n_samples, jax.random.key(2), 1,
+                          None).compile().as_text()
+    kernels = text.count("tpu_custom_call")
     _check(tiny or kernels > 0, "no Pallas kernel in the wave program")
+    core_kernels = _core_kernels(text, model, "cca_core", cfg.n_layers, tiny)
     # the mixer alone: bfloat16 through the kernel beside float32 on the
     # blocked plain core
     blk = params["base"]["blocks"][0]["cca"]
@@ -835,7 +879,8 @@ def phase_cca_lora(env: Env) -> None:
     env.say("cca_lora",
             f"two blocks of 4 on 2 heads of 128, 4 experts and the skip, "
             f"bfloat16 base, {length} tokens: losses {losses[0]:.4f} -> "
-            f"{losses[1]:.4f}, {kernels} Pallas calls in the wave program, the "
+            f"{losses[1]:.4f}, {kernels} Pallas calls in the wave program, "
+            f"{core_kernels} of them under cca_core, the "
             f"bfloat16 mixer {err:.4f} from the float32 one (of its largest "
             f"entry). zaya1_8b at {'tiny' if tiny else 'the published'} "
             f"sizes, {len(rows)} layers, {seq} tokens, seed {seed}: a layer "

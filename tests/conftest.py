@@ -28,6 +28,22 @@ def nprng():
     return np.random.default_rng(0)
 
 
+def flash_kernels(fn, *args):
+    """``(forward, backward)``: the flash kernels in ``fn``'s jaxpr at
+    ``args``, whatever they are nested in. A forward kernel has two
+    outputs, the output and the log-sum-exp; a backward kernel three or
+    four gradients, or ``dq`` alone."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield len(eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    outputs = list(calls(jax.make_jaxpr(fn)(*args).jaxpr))
+    return outputs.count(2), len(outputs) - outputs.count(2)
+
+
 def counter(metrics, name, default=0.0):
     """Read one counter from a Metrics registry (0.0 when never inc'd)."""
     return metrics.snapshot()["counters"].get(name, default)

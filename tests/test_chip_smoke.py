@@ -59,7 +59,9 @@ def test_chip_smoke_rehearsal_runs_every_phase(tmp_path):
                   "cca_lora", "flash_kernel",
                   "http_round", "mesh", "cache"):
         assert any(f"phase={phase} " in ln for ln in phase_lines), phase
-    assert not any("skipped" in ln for ln in phase_lines)
+    # ("skipped:" is a phase that did not run; cca_lora counts the
+    # tokens that skipped their expert)
+    assert not any("skipped:" in ln for ln in phase_lines)
     assert any("phase=flash_kernel " in ln and "causal_core [1, 2, 2048, 24/16]"
                in ln for ln in phase_lines)
     # a rehearsal prints no time taken on the CPU under any name

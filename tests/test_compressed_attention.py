@@ -15,11 +15,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from baton_tpu.models import transformer
+from baton_tpu.models import llama, transformer
 from baton_tpu.models.llama import (
     LlamaConfig,
     _joined,
     _merge_init,
+    core_outputs_kept,
     decoder_lora_model,
     llama_lm_model,
 )
@@ -36,6 +37,7 @@ from baton_tpu.models.transformer import (
     rope_angles,
     tied_logits,
 )
+from conftest import flash_kernels
 
 D = 24
 CFG = CCAConfig(n_heads=4, n_kv_heads=2, head_dim=8, block=4, rope_theta=100.0)
@@ -505,3 +507,62 @@ def test_a_decoder_of_compressed_attention_trains():
     assert float(loss(lora)) < first
     logits = model.apply(params, batch, None)
     assert logits.shape == (2, 12, 48) and logits.dtype == jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# the block's checkpoint keeps the kernel's output and log-sum-exp
+
+
+def test_a_block_of_two_streams_runs_the_forward_kernel_once(kernel_core):
+    """A block of ``DECODER`` (the tokens' stream and the routers')
+    whose core is the kernel, two clients under ``vmap``: one forward
+    kernel under the model's checkpoint, two under a bare one, and the
+    same gradients bit for bit."""
+    length = 32
+    p = llama._block_init(jax.random.key(3), DECODER, DECODER.kind_of(0), True)
+    x = jax.random.normal(jax.random.key(4), (2, 1, length, D))
+    rope = rope_angles(length, CFG.rope_dim, CFG.rope_theta)
+
+    def grad(block):
+        def client(p, x):
+            r = jnp.zeros(x.shape[:2] + (DECODER.moe.router_hidden,))
+            y, r = block(p, x, r, DECODER, rope, None)
+            return jnp.sum(y ** 2) + jnp.sum(r ** 2)
+
+        return jax.grad(lambda p, x: jnp.sum(
+            jax.vmap(client, in_axes=(None, 0))(p, x)), argnums=(0, 1))
+
+    kept = grad(llama._checkpointed_block())
+    bare = grad(jax.checkpoint(llama._block_apply, static_argnums=(3, 5)))
+    assert flash_kernels(kept, p, x) == (1, 1)
+    assert flash_kernels(bare, p, x) == (2, 1)
+    for g, w in zip(*(jax.tree_util.tree_leaves(fn(p, x))
+                      for fn in (kept, bare))):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("backend,length,kept", [
+    ("tpu", 8192, 3),    # zaya1_c4_l8192's sequences: every block
+    ("cpu", 8192, 0),
+    ("tpu", 1024, 0),    # one of the kernel's blocks
+    ("tpu", 8200, 0),    # a length the blocks do not divide
+])
+def test_the_blocks_that_keep_a_cores_outputs(backend, length, kept):
+    assert core_outputs_kept(DECODER, backend, 1, length) == kept
+    assert (kept > 0) is transformer.core_is_the_kernel(backend, length)
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_model_says_what_its_last_trace_keeps(remat, kernel_core):
+    """``span_attrs`` gains ``core_outputs_kept`` where the model is
+    traced and meets a length: with the selector forced, every block of
+    a ``remat`` model, none of one that holds no checkpoint."""
+    model = llama_lm_model(DECODER, remat=remat)
+    static = dict(model.span_attrs)
+    assert "core_outputs_kept" not in static
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    jax.eval_shape(model.per_example_loss, params, _batch(1, length=32), None)
+    assert dict(model.span_attrs) == {
+        **static, "core_outputs_kept": 3 if remat else 0}
+    assert model.span_attrs == tuple(static.items())
+    assert hash(model.span_attrs) == hash(tuple(static.items()))

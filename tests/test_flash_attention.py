@@ -13,7 +13,12 @@ import numpy as np
 import pytest
 
 from baton_tpu.models.transformer import dot_product_attention, padding_bias
-from baton_tpu.ops.flash_attention import flash_attention, make_flash_attention_fn
+from baton_tpu.ops.flash_attention import (
+    KEPT_OUTPUTS,
+    flash_attention,
+    make_flash_attention_fn,
+)
+from conftest import flash_kernels
 
 
 def _rand(key, *shape, dtype=jnp.float32):
@@ -292,3 +297,47 @@ def test_equal_widths_are_what_they_were(causal, backward_form):
     for g, w in zip(got_g, want_g):
         np.testing.assert_allclose(np.asarray(g).reshape(w.shape),
                                    np.asarray(w), rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# under a checkpoint: the forward kernel's two outputs carry names
+
+
+def _checkpointed_grad(policy, hkv=2):
+    """The gradient of a checkpointed call of the kernel whose output
+    something after it needs, and its operands."""
+    q, k, v = _qkv(11, 1, 4, hkv, 32, 16)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=8, block_k=16)
+
+    if policy != "no_checkpoint":
+        attend = jax.checkpoint(attend, policy=policy)
+    return jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) ** 2),
+                    argnums=(0, 1, 2)), (q, k, v)
+
+
+@pytest.mark.parametrize("kept,forwards", [(True, 1), (False, 2)],
+                         ids=["the_two_names_saved", "a_bare_checkpoint"])
+def test_a_checkpoint_that_keeps_the_outputs_runs_the_forward_once(
+        kept, forwards, backward_form):
+    """A bare ``jax.checkpoint`` makes the output and the log-sum-exp
+    again for the backward kernel, a second run of the forward kernel;
+    one whose policy saves ``KEPT_OUTPUTS`` hands it the first run's.
+    The same kernels on the same operands: the same gradients, bit for
+    bit."""
+    policy = (jax.checkpoint_policies.save_only_these_names(*KEPT_OUTPUTS)
+              if kept else None)
+    grad, operands = _checkpointed_grad(policy)
+    backwards = 1 if backward_form == "one_kernel" else 2
+    assert flash_kernels(grad, *operands) == (forwards, backwards)
+    plain, _ = _checkpointed_grad("no_checkpoint")
+    assert flash_kernels(plain, *operands) == (1, backwards)
+    for got, want in zip(grad(*operands), plain(*operands)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_policy_of_other_names_keeps_nothing_of_the_kernel():
+    grad, operands = _checkpointed_grad(
+        jax.checkpoint_policies.save_only_these_names("context"))
+    assert flash_kernels(grad, *operands) == (2, 1)

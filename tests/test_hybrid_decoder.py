@@ -470,6 +470,46 @@ def test_a_round_hands_the_base_back_and_says_what_it_weighs(monkeypatch):
         a.nbytes for a in jax.tree_util.tree_leaves(params["lora"]))
 
 
+def test_a_round_says_how_many_blocks_keep_a_cores_outputs(monkeypatch):
+    """The model meets its sequences' length where it is traced, after
+    the first round's span has its facts; every later round says how
+    many blocks keep a kernel's outputs. None here: no kernel on the
+    CPU."""
+    recorder = _Recorder()
+    monkeypatch.setattr(engine, "annotate", recorder)
+    model = decoder_lora_model(_hybrid(), rank=2, b_std=0.02)
+    params = model.init(jax.random.key(0))
+    x = jax.random.randint(jax.random.key(1), (2, 1, 11), 0, 96)
+    sim = FedSim(model, batch_size=1, learning_rate=0.05,
+                 trainable=lora_trainable)
+    for i in range(2):
+        sim.run_round(params, {"x": x[..., :-1], "y": x[..., 1:]},
+                      np.asarray([1, 1], np.int32), jax.random.key(2 + i),
+                      n_epochs=1, collect_client_losses=False)
+    first, second = (attrs for name, attrs in recorder.opened
+                     if name == "baton.round")
+    assert "core_outputs_kept" not in first
+    assert second["core_outputs_kept"] == 0
+
+
+@pytest.mark.parametrize("backend,batch,length,kept", [
+    ("tpu", 1, 1024, 0),     # olmo_hybrid_c4_l1024: 134 MB of scores, dense
+    ("tpu", 1, 4096, 2),     # the two full-attention layers of eight
+    ("tpu", 64, 1024, 2),    # 1 GiB of scores: past the dense budget
+    ("cpu", 1, 4096, 0),
+])
+def test_full_attention_keeps_a_kernels_outputs_where_dense_gives_way(
+        backend, batch, length, kept):
+    from baton_tpu.models.llama import core_outputs_kept
+    from baton_tpu.models.transformer import dot_product_attention
+
+    cfg = _hybrid(n_layers=8)
+    assert core_outputs_kept(cfg, backend, batch, length) == kept
+    # an attention of the caller's is not known to be a kernel
+    assert core_outputs_kept(cfg, backend, batch, length,
+                             dot_product_attention) == 0
+
+
 # ----------------------------------------------------- the loss in blocks
 @pytest.mark.parametrize("length", [12, 13])
 @pytest.mark.parametrize("masked", [False, True])

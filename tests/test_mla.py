@@ -13,8 +13,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from baton_tpu.models.llama import LlamaConfig, llama_lm_model
-from baton_tpu.models import transformer
+from baton_tpu.models import llama, transformer
+from baton_tpu.models.llama import (
+    LlamaConfig,
+    core_outputs_kept,
+    llama_lm_model,
+)
 from baton_tpu.models.transformer import (
     MLAConfig,
     blocked_causal_core,
@@ -25,6 +29,7 @@ from baton_tpu.models.transformer import (
     mla_rope_angles,
     yarn_inv_freq,
 )
+from conftest import flash_kernels
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
         "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
@@ -301,3 +306,77 @@ def test_a_decoder_of_latent_attention_trains(nprng):
     _, _, hist = trainer.train(params, data, jnp.asarray(2),
                                jax.random.key(1), 4)
     assert float(hist[-1]) < float(hist[0])
+
+
+# ---------------------------------------------------------------------------
+# the block's checkpoint keeps the kernel's output and log-sum-exp
+
+
+def _block_grads(block, cfg, length, clients=2):
+    """``(gradient function, operands)`` of one decoder block of ``cfg``
+    under ``block``'s checkpoint, ``clients`` under ``vmap``, with
+    something after the block that needs its output."""
+    p = llama._block_init(jax.random.key(3), cfg, cfg.kind_of(0))
+    x = jax.random.normal(jax.random.key(4), (clients, 1, length, cfg.d_model))
+    rope = mla_rope_angles(length, cfg.mla)
+
+    def loss(p, x):
+        def client(x):
+            y, _ = block(p, x, None, cfg, rope, transformer.default_attention)
+            return jnp.sum(y ** 2)
+        return jnp.sum(jax.vmap(client)(x))
+
+    return jax.grad(loss, argnums=(0, 1)), (p, x)
+
+
+def _bare_block():
+    return jax.checkpoint(llama._block_apply, static_argnums=(3, 5))
+
+
+def test_a_block_runs_the_cores_forward_kernel_once(kernel_core):
+    """A block of latent attention whose core is the kernel, clients
+    under ``vmap`` as in the wave program: under the model's checkpoint
+    one forward kernel where a bare checkpoint has two, and the bare
+    checkpoint's gradients bit for bit."""
+    cfg = LlamaConfig.tiny(mla=_cfg(8))
+    kept, operands = _block_grads(llama._checkpointed_block(), cfg, 32)
+    bare, _ = _block_grads(_bare_block(), cfg, 32)
+    assert flash_kernels(kept, *operands) == (1, 1)
+    assert flash_kernels(bare, *operands) == (2, 1)
+    got, want = kept(*operands), bare(*operands)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for g, w in zip(*map(jax.tree_util.tree_leaves, (got, want))):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_a_block_without_a_kernel_is_the_block_it_was():
+    """On the CPU the core is the blocked plain computation: nothing
+    carries the kernel's names, and the gradient's program is the bare
+    checkpoint's to the letter."""
+    import re
+
+    # (another length than the test above: ``jax.checkpoint`` keeps its
+    # trace of ``_block_apply`` by the arguments' shapes, selector and all)
+    cfg = LlamaConfig.tiny(mla=_cfg(8))
+    kept, operands = _block_grads(llama._checkpointed_block(), cfg, 24)
+    bare, _ = _block_grads(_bare_block(), cfg, 24)
+    assert flash_kernels(kept, *operands) == (0, 0)
+    texts = [re.sub(r"policy=\S.*", "policy", str(jax.make_jaxpr(fn)(*operands)))
+             for fn in (kept, bare)]
+    assert "policy" in texts[0] and texts[0] == texts[1]
+
+
+@pytest.mark.parametrize("backend,length,kept", [
+    ("tpu", 2048, 2),    # sarvam_105b_c4_l2048's sequences: every block
+    ("tpu", 8192, 2),
+    ("cpu", 2048, 0),    # tier-1's backend: no kernel, nothing to keep
+    ("tpu", 1024, 0),    # one of the kernel's blocks: the plain core
+    ("tpu", 2000, 0),    # a length the blocks do not divide
+])
+def test_the_blocks_that_keep_a_cores_outputs_are_those_of_the_kernel(
+        backend, length, kept):
+    cfg = LlamaConfig.tiny(mla=_cfg(8))
+    assert core_outputs_kept(cfg, backend, 1, length) == kept
+    assert (kept == cfg.n_layers) is transformer.core_is_the_kernel(
+        backend, length)

@@ -510,6 +510,18 @@ def test_a_sequence_no_longer_than_topk_keeps_the_blocks_checkpoint():
     assert "i8[1,16,16]" in long and "i8[" not in short
 
 
+@pytest.mark.parametrize("length,kept", [(2048, 2), (4096, 0)])
+def test_a_mixer_outside_the_blocks_checkpoint_keeps_nothing_there(
+        length, kept):
+    """Where queries choose (2,048 of more keys) the mixer stands
+    outside the block's checkpoint and keeps its own residuals; where
+    none does the block is whole and keeps its core's two outputs."""
+    from baton_tpu.models.llama import core_outputs_kept
+
+    assert core_outputs_kept(_decoder(_cfg(topk=2048)), "tpu", 1,
+                             length) == kept
+
+
 def test_a_decoder_whose_queries_choose_trains(nprng):
     from baton_tpu.core.training import make_local_trainer
 

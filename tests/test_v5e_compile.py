@@ -65,3 +65,51 @@ def test_the_tied_losss_backward_is_the_scan_alone(one_chip):
     assert len(re.findall(r" while\(", text)) == 1
     assert " scatter(" not in text
     assert f"[{block}]" not in text
+
+
+@pytest.mark.parametrize("kept,forwards", [(True, 1), (False, 2)],
+                         ids=["the_blocks_policy", "a_bare_checkpoint"])
+def test_a_block_that_keeps_the_cores_outputs_runs_the_forward_kernel_once(
+        one_chip, kept, forwards):
+    """The gradient of one checkpointed decoder block whose attention
+    is the flash kernel at ``[4, 8, 2048, 128]``, four clients under
+    ``vmap``. Under the checkpoint a ``remat`` model gives its blocks
+    the program holds the forward kernel and the one backward kernel;
+    under a bare ``jax.checkpoint`` a second forward kernel, the
+    ``rematted_computation``'s, whose two outputs are the first's."""
+    from baton_tpu.models import llama
+    from baton_tpu.models.transformer import rope_angles
+    from baton_tpu.ops.flash_attention import make_flash_attention_fn
+
+    clients, length = 4, 2048
+    cfg = llama.LlamaConfig(vocab_size=256, d_model=1024, n_layers=1,
+                            n_heads=8, n_kv_heads=8, d_ff=512)
+    attend = make_flash_attention_fn(interpret=False)
+    rope = rope_angles(length, cfg.head_dim, cfg.rope_theta)
+    block = (llama._checkpointed_block() if kept else jax.checkpoint(
+        llama._block_apply, static_argnums=(3, 5)))
+
+    def loss(p, x):
+        def client(x):
+            y, _ = block(p, x, None, cfg, rope, attend)
+            # the next block needs the stream: the forward cannot go
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jnp.sum(jax.vmap(client)(x))
+
+    p = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda key: llama._block_init(key, cfg),
+                       jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((clients, 1, length, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        p, x).compile().as_text()
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    backward = [k for k in kernels if "transpose(" in k
+                and "rematted_computation" not in k]
+    assert len(backward) == 1
+    assert len(kernels) - len(backward) == forwards
+    assert sum("rematted_computation" in k for k in kernels) == forwards - 1
