@@ -13,7 +13,7 @@ from baton_tpu.models.llama import (
 )
 from baton_tpu.models.lstm import LSTMConfig, lstm_lm_model
 from baton_tpu.models.moe import MoEConfig, moe_apply, moe_init
-from baton_tpu.models.transformer import IndexerConfig, MLAConfig
+from baton_tpu.models.transformer import CCAConfig, IndexerConfig, MLAConfig
 from baton_tpu.models.vit import ViTConfig, vit_model
 
 __all__ = [
@@ -36,11 +36,13 @@ __all__ = [
     "projection_lora_target",
     "LSTMConfig",
     "lstm_lm_model",
-    # the expert layer that holds some of its router's experts, and
-    # latent attention's sizes (LlamaConfig.moe, LlamaConfig.mla)
+    # the expert layer that holds some of its router's experts, and the
+    # sizes of latent and of compressed convolutional attention
+    # (LlamaConfig.moe, LlamaConfig.mla, LlamaConfig.cca)
     "MoEConfig",
     "moe_apply",
     "moe_init",
+    "CCAConfig",
     "IndexerConfig",
     "MLAConfig",
     "ViTConfig",

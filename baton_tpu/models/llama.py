@@ -5,16 +5,12 @@ zoo); this decoder exists for the driver-set federated LoRA workload.
 Architecture is the modern decoder recipe — RMSNorm pre-norm, RoPE,
 SwiGLU MLP, grouped-query attention, untied output head — built from the
 TPU-first blocks in :mod:`baton_tpu.models.transformer`. ``layer_types``
-makes it a hybrid: each layer's mixer is full attention, the gated
-delta rule of :mod:`baton_tpu.models.delta_rule` (linear attention with
-a recurrent state) or latent attention (``mla``: keys and values from a
-low-rank latent, with ``mla.q_rank`` the queries from one of their own,
-with ``mla.indexer`` each query attending the keys a learned index
-chose for it, :func:`baton_tpu.models.transformer.mla_apply`) or
-compressed convolutional attention (``cca``: a few heads in a latent
-narrower than the model, mixed along the sequence by two convolutions,
-:func:`baton_tpu.models.transformer.cca_apply`), in
-the pattern the configuration gives. With ``moe`` the layers after the
+makes it a hybrid: each layer's mixer is one of :data:`MIXERS` (full
+attention, the gated delta rule's linear attention with a recurrent
+state, latent attention in a low-rank latent whose queries may choose
+their keys, compressed convolutional attention), in the pattern the
+configuration gives. The table is all this module knows of a mixer: a
+further one is one more entry. With ``moe`` the layers after the
 first ``first_dense_layers`` replace their SwiGLU by the expert layer of
 :mod:`baton_tpu.models.moe`, which holds ``moe.experts_held`` of the
 router's experts and computes their part; where its router carries a
@@ -51,7 +47,7 @@ as the framework contract requires (core/model.py).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,37 +57,13 @@ from baton_tpu.core.partition import path_str
 from baton_tpu.models.delta_rule import gated_delta_apply, gated_delta_init
 from baton_tpu.models.lora import lora_wrap
 from baton_tpu.models.moe import (
-    MoEConfig,
-    moe_apply,
-    moe_apply_with_state,
-    moe_init,
-    rows_bound,
-)
+    MoEConfig, moe_apply, moe_apply_with_state, moe_init, rows_bound)
 from baton_tpu.models.transformer import (
-    AttentionFn,
-    CCAConfig,
-    MLAConfig,
-    cca_apply,
-    cca_init,
-    core_is_the_kernel,
-    dense_gives_way,
-    dense_init,
-    default_attention,
-    matmul,
-    mha_apply,
-    mha_init,
-    mla_apply,
-    mla_init,
-    mla_rope_angles,
-    next_token_loss,
-    normal_init,
-    rms_init,
-    rms_norm,
-    rope_angles,
-    swiglu_apply,
-    swiglu_init,
-    tied_logits,
-)
+    AttentionFn, CCAConfig, MLAConfig, attention_is_kernel, cca_apply,
+    cca_core_is_kernel, cca_init, default_attention, dense_init, matmul,
+    mha_apply, mha_init, mla_apply, mla_core_is_kernel, mla_init,
+    mla_rope_angles, next_token_loss, normal_init, rms_init, rms_norm,
+    rope_angles, swiglu_apply, swiglu_init, tied_logits)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,19 +83,15 @@ class LlamaConfig:
     # ``first_dense_layers``; its own width is ``moe.d_ff``
     moe: Optional[MoEConfig] = None
     first_dense_layers: int = 0
-    # latent attention's sizes; the mixer of a "latent_attention" layer,
-    # and of every layer where ``layer_types`` is None
+    # the sizes of latent and of compressed convolutional attention
     mla: Optional[MLAConfig] = None
-    # compressed convolutional attention's sizes; the mixer of a
-    # "compressed_attention" layer
     cca: Optional[CCAConfig] = None
     # RMSNorm of the whole query and key projections in full attention
     qk_norm: bool = False
-    # the mixer of each layer, "full_attention", "linear_attention",
-    # "latent_attention" or "compressed_attention"; the first
-    # ``n_layers`` entries count (a depth
-    # cut keeps the published list). None: one mixer everywhere, latent
-    # attention with ``mla``, else full attention
+    # the mixer of each layer, a key of ``MIXERS``; the first
+    # ``n_layers`` entries count (a depth cut keeps the published
+    # list). None: one mixer everywhere, latent attention with ``mla``,
+    # else full attention
     layer_types: Optional[Tuple[str, ...]] = None
     # the linear-attention (gated delta rule) layers' heads
     linear_n_heads: int = 0
@@ -161,10 +129,6 @@ class LlamaConfig:
         return self.moe is not None and layer >= self.first_dense_layers
 
     @classmethod
-    def llama3_8b(cls, **kw) -> "LlamaConfig":
-        return cls(**kw)
-
-    @classmethod
     def tiny(cls, **kw) -> "LlamaConfig":
         """Test-sized config (CI / CPU-mesh tests)."""
         defaults = dict(
@@ -181,53 +145,121 @@ def llama_lora_target(path: str, leaf) -> bool:
     return path.rsplit("/", 1)[-1] in ("wq", "wk", "wv", "wo")
 
 
-_PROJECTIONS = ("wq", "wq_a", "wq_b", "wk", "wv", "wo", "wg", "wkv_a",
-                "wkv_b", "w_gate", "w_up", "w_down", "linear_q", "linear_k",
-                "val_proj1", "val_proj2", "o_proj")
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    """What a decoder block and its model ask of a mixer, and all they
+    ask; ``cfg`` is the :class:`LlamaConfig`. One that keeps its inputs
+    recomputes itself in the backward from them: it stands outside the
+    block's checkpoint, and its ``apply`` takes the stream itself and
+    ``pre_norm=``, the norm before it. ``core_is_kernel`` is defined
+    beside the mixer's own dispatch, in the mixer's module."""
+
+    key: str  # its name in the block's parameters
+    init: Callable[..., Any]  # (rng, cfg, out_std) -> its parameters
+    # (p, h, cfg, rope, attention_fn) -> [B, L, D] from the normed stream
+    apply: Callable[..., jax.Array]
+    # its 2-D leaves that LoRA adapts, by name (none in a tree inside it)
+    projections: Tuple[str, ...]
+    # (cfg, length) -> its rotation's (cos, sin), or None
+    rope: Callable[..., Optional[tuple]] = lambda cfg, length: None
+    keeps_its_inputs: Callable[..., bool] = lambda cfg, length: False
+    # (cfg, backend, batch, length, attention_fn): its core is the flash
+    # kernel there, whose two outputs the block's checkpoint then keeps
+    core_is_kernel: Callable[..., bool] = lambda *where: False
+    facts: Callable[..., tuple] = lambda cfg: ()  # for baton.round's span
+
+
+MIXERS = {
+    "full_attention": Mixer(
+        key="attn",
+        init=lambda rng, cfg, out_std: mha_init(
+            rng, cfg.d_model, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            out_std=out_std, qk_norm=cfg.qk_norm),
+        apply=lambda p, h, cfg, rope, attention_fn: mha_apply(
+            p, h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads, causal=True,
+            rope=rope, attention_fn=attention_fn),
+        projections=("wq", "wk", "wv", "wo"),
+        # None: position comes from the recurrent layers of a hybrid
+        rope=lambda cfg, length: None if cfg.rope_theta is None
+        else rope_angles(length, cfg.head_dim, cfg.rope_theta),
+        core_is_kernel=lambda cfg, backend, batch, length, fn:
+        attention_is_kernel(fn, backend, batch, cfg.n_heads, length)),
+    "linear_attention": Mixer(
+        key="linear_attn",
+        init=lambda rng, cfg, out_std: gated_delta_init(
+            rng, cfg.d_model, cfg.linear_n_heads, cfg.linear_key_dim,
+            cfg.linear_value_dim, out_std=out_std),
+        apply=lambda p, h, cfg, rope, attention_fn: gated_delta_apply(
+            p, h, cfg.linear_n_heads, cfg.linear_chunk,
+            cfg.linear_allow_neg_eigval),
+        # not the gates' ``wa`` / ``wb``, not the convolutions
+        projections=("wq", "wk", "wv", "wg", "wo")),
+    "latent_attention": Mixer(
+        key="mla",
+        init=lambda rng, cfg, out_std: mla_init(
+            rng, cfg.d_model, cfg.n_heads, cfg.mla, out_std=out_std),
+        apply=lambda p, h, cfg, rope, attention_fn, pre_norm=None: mla_apply(
+            p, h, cfg.n_heads, cfg.mla, rope, pre_norm=pre_norm),
+        projections=("wq", "wq_a", "wq_b", "wkv_a", "wkv_b", "wo"),
+        rope=lambda cfg, length: mla_rope_angles(length, cfg.mla),
+        # where queries choose their keys: ``transformer._choosing_mla``
+        keeps_its_inputs=lambda cfg, length: cfg.mla.selects(length),
+        core_is_kernel=lambda cfg, backend, batch, length, fn:
+        mla_core_is_kernel(cfg.mla, backend, length)),
+    "compressed_attention": Mixer(
+        key="cca",
+        init=lambda rng, cfg, out_std: cca_init(
+            rng, cfg.d_model, cfg.cca, out_std=out_std),
+        apply=lambda p, h, cfg, rope, attention_fn: cca_apply(
+            p, h, cfg.cca, rope),
+        projections=("linear_q", "linear_k", "val_proj1", "val_proj2",
+                     "o_proj"),
+        rope=lambda cfg, length: rope_angles(length, cfg.cca.rope_dim,
+                                             cfg.cca.rope_theta),
+        core_is_kernel=lambda cfg, backend, batch, length, fn:
+        cca_core_is_kernel(backend, length),
+        facts=lambda cfg: (
+            ("latent_q", cfg.cca.latent_q), ("latent_kv", cfg.cca.latent_kv),
+            ("conv_taps", f"{cfg.cca.time0}+{cfg.cca.time1}"))),
+}
+
+_MLP_PROJECTIONS = ("w_gate", "w_up", "w_down")  # a shared expert's too
+
+
+def _mixer(kind: str) -> Mixer:
+    if kind not in MIXERS:
+        raise ValueError(
+            f"unknown layer type {kind!r}: MIXERS holds {sorted(MIXERS)}")
+    return MIXERS[kind]
 
 
 def projection_lora_target(path: str, leaf) -> bool:
-    """LoRA target predicate: every 2-D projection of the mixers (full,
-    linear, latent and compressed attention), of the MLPs and of a
-    shared expert;
-    not the embedding, the head, the linear layers' gate projections
-    ``wa`` / ``wb`` or any mixer's convolutions, not an expert layer's
-    router (a matrix or an MLP) or its 3-D stacks of routed experts,
-    and nothing of a latent
-    mixer's ``indexer`` (no gradient reaches the choice of keys)."""
-    return (getattr(leaf, "ndim", 2) == 2 and "/indexer/" not in path
-            and path.rsplit("/", 1)[-1] in _PROJECTIONS)
+    """LoRA target predicate: the 2-D leaves each mixer of
+    :data:`MIXERS` lists as its ``projections``, directly under its
+    ``key``, and those of the MLPs and of a shared expert; not the
+    embedding, the head, an expert layer's router (a matrix or an MLP)
+    or its 3-D stacks of routed experts."""
+    *_, parent, name = [""] + path.split("/")
+    return getattr(leaf, "ndim", 2) == 2 and (
+        name in _MLP_PROJECTIONS or any(
+            parent == m.key and name in m.projections
+            for m in MIXERS.values()))
 
 
-def _block_init(key, cfg: LlamaConfig, kind: str = "full_attention",
+def _block_init(key, cfg: LlamaConfig, kind: Optional[str] = None,
                 experts: bool = False):
-    """A linear-attention block holds its mixer under ``linear_attn``,
-    a latent-attention block under ``mla``, a compressed-attention
-    block under ``cca`` and a full-attention block
-    under ``attn``; an expert layer's ``mlp`` holds a ``router``; with
-    ``residual_merge`` the block holds ``merge_attn`` and ``merge_mlp``:
-    the kind of a block is the structure of its parameters."""
+    """A block holds its mixer (``kind``, a key of :data:`MIXERS`; None:
+    the first layer's) under that mixer's ``key``; an expert layer's
+    ``mlp`` holds a ``router``; with ``residual_merge`` the block holds
+    ``merge_attn`` and ``merge_mlp``."""
     ka, km = jax.random.split(key)
     if experts:
         mlp = moe_init(km, cfg.d_model, cfg.d_ff, cfg.moe)
     else:
         mlp = swiglu_init(km, cfg.d_model, cfg.d_ff)
-    out_std = cfg.d_model ** -0.5 / (2 * cfg.n_layers) ** 0.5
-    if kind == "latent_attention":
-        mixer = {"mla": mla_init(ka, cfg.d_model, cfg.n_heads, cfg.mla,
-                                 out_std=out_std)}
-    elif kind == "linear_attention":
-        mixer = {"linear_attn": gated_delta_init(
-            ka, cfg.d_model, cfg.linear_n_heads, cfg.linear_key_dim,
-            cfg.linear_value_dim, out_std=out_std)}
-    elif kind == "compressed_attention":
-        mixer = {"cca": cca_init(ka, cfg.d_model, cfg.cca, out_std=out_std)}
-    elif kind == "full_attention":
-        mixer = {"attn": mha_init(
-            ka, cfg.d_model, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            out_std=out_std, qk_norm=cfg.qk_norm)}
-    else:
-        raise ValueError(f"unknown layer type {kind!r}")
+    m = _mixer(kind or cfg.kind_of(0))
+    mixer = {m.key: m.init(
+        ka, cfg, cfg.d_model ** -0.5 / (2 * cfg.n_layers) ** 0.5)}
     if cfg.residual_merge:
         k1, k2 = jax.random.split(jax.random.fold_in(key, 2))
         mixer.update(merge_attn=_merge_init(k1, cfg.d_model),
@@ -257,32 +289,15 @@ def _joined(p, name: str, x, y):
             + m["a_y"] * (y.astype(jnp.float32) + m["b_y"])).astype(x.dtype)
 
 
-def _mixer_keeps_its_inputs(p, cfg: LlamaConfig, length: int) -> bool:
-    """Whether the block's mixer recomputes itself in the backward from
-    its own inputs: latent attention whose queries choose their keys
-    (``transformer.py::_choosing_mla``)."""
-    return "mla" in p and cfg.mla.selects(length)
-
-
 def _mix(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
-    if _mixer_keeps_its_inputs(p, cfg, x.shape[1]):
-        return _joined(p, "merge_attn", x, mla_apply(
-            p["mla"], x, cfg.n_heads, cfg.mla, rope,
-            pre_norm=p["norm_attn"]))
-    h = rms_norm(x, p["norm_attn"], cfg.norm_eps)
-    if "cca" in p:
-        y = cca_apply(p["cca"], h, cfg.cca, rope)
-    elif "mla" in p:
-        y = mla_apply(p["mla"], h, cfg.n_heads, cfg.mla, rope)
-    elif "linear_attn" in p:
-        y = gated_delta_apply(
-            p["linear_attn"], h, cfg.linear_n_heads, cfg.linear_chunk,
-            cfg.linear_allow_neg_eigval)
+    # the kind of a block is the structure of its parameters
+    m = next(m for m in MIXERS.values() if m.key in p)
+    if m.keeps_its_inputs(cfg, x.shape[1]):
+        y = m.apply(p[m.key], x, cfg, rope, attention_fn,
+                    pre_norm=p["norm_attn"])
     else:
-        y = mha_apply(
-            p["attn"], h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            causal=True, rope=rope, attention_fn=attention_fn,
-        )
+        y = m.apply(p[m.key], rms_norm(x, p["norm_attn"], cfg.norm_eps), cfg,
+                    rope, attention_fn)
     return _joined(p, "merge_attn", x, y)
 
 
@@ -323,22 +338,12 @@ def core_outputs_kept(cfg: LlamaConfig, backend: str, batch: int, length: int,
     """The blocks whose checkpoint (``remat=True``) keeps a flash
     kernel's output and log-sum-exp for its backward, on sequences of
     ``length`` tokens, ``batch`` at a time: those whose mixer stands
-    under the block's checkpoint and is the kernel there. None on the
-    CPU, under one of the kernel's blocks, or where full attention is
-    dense."""
-    def kernel(layer: int) -> bool:
-        kind = cfg.kind_of(layer)
-        if kind == "compressed_attention":
-            return core_is_the_kernel(backend, length)
-        if kind == "latent_attention":
-            return (not cfg.mla.selects(length)
-                    and core_is_the_kernel(backend, length))
-        return (kind == "full_attention"
-                and attention_fn is default_attention
-                and dense_gives_way(backend, batch, cfg.n_heads, length,
-                                    length))
-
-    return sum(kernel(i) for i in range(cfg.n_layers))
+    under the block's checkpoint and says its core is the kernel there
+    (none does on the CPU)."""
+    return sum(
+        _mixer(cfg.kind_of(i)).core_is_kernel(cfg, backend, batch, length,
+                                              attention_fn)
+        for i in range(cfg.n_layers))
 
 
 class _ModelFacts(tuple):
@@ -375,7 +380,8 @@ def llama_lm_model(
     stays frozen is held in bfloat16); vectors (norm scales, the linear layers'
     ``a_log`` and ``dt_bias``, a router's bias) and the router itself
     are float32."""
-    cfg = config or LlamaConfig.llama3_8b()
+    cfg = config or LlamaConfig()
+    mixers = [_mixer(cfg.kind_of(i)) for i in range(cfg.n_layers)]
     # made once a model: ``jax.checkpoint`` caches its trace on the
     # function and the arguments' structure, so blocks of one kind share
     # one trace whatever the depth
@@ -422,22 +428,18 @@ def llama_lm_model(
         l = ids.shape[1]
         seen["core_outputs_kept"] = 0 if not remat else core_outputs_kept(
             cfg, jax.default_backend(), *ids.shape, attention_fn)
-        if cfg.mla is not None:
-            rope = mla_rope_angles(l, cfg.mla)
-        elif cfg.cca is not None:
-            rope = rope_angles(l, cfg.cca.rope_dim, cfg.cca.rope_theta)
-        else:
-            rope = (None if cfg.rope_theta is None
-                    else rope_angles(l, cfg.head_dim, cfg.rope_theta))
+        # a layer's angles are its kind's, made once a kind a trace
+        ropes = {m: m.rope(cfg, l) for m in dict.fromkeys(mixers)}
         with jax.named_scope("embed"):
             x = params["tok_emb"][ids].astype(compute_dtype)
         # the routers' state: the first layer's router is handed zeros,
         # so that every layer is one kind of block
         r = (jnp.zeros(ids.shape + (cfg.moe.router_hidden,), jnp.float32)
              if stateful else None)
-        for i, blk in enumerate(params["blocks"]):
+        for i, (m, blk) in enumerate(zip(mixers, params["blocks"])):
+            rope = ropes[m]
             with jax.named_scope(f"block{i}"):
-                if _mixer_keeps_its_inputs(blk, cfg, l):
+                if m.keeps_its_inputs(cfg, l):
                     x, r = ff_fn(blk, _mix(blk, x, cfg, rope, attention_fn),
                                  r, cfg)
                 else:
@@ -475,10 +477,8 @@ def llama_lm_model(
     if cfg.moe is not None and cfg.moe.skip:
         facts += (("router_outputs", cfg.moe.router_outputs),
                   ("skip_expert", cfg.moe.n_experts))
-    if cfg.cca is not None:
-        facts += (("latent_q", cfg.cca.latent_q),
-                  ("latent_kv", cfg.cca.latent_kv),
-                  ("conv_taps", f"{cfg.cca.time0}+{cfg.cca.time1}"))
+    for m in dict.fromkeys(mixers):
+        facts += m.facts(cfg)
     return FedModel(init=init, apply=apply, per_example_loss=per_example_loss,
                     name=name, aux=cfg, span_attrs=_ModelFacts(facts, seen))
 

@@ -195,6 +195,16 @@ def default_attention(q, k, v, bias=None, causal=False):
     return dot_product_attention(q, k, v, bias=bias, causal=causal)
 
 
+def attention_is_kernel(attention_fn, backend: str, batch: int, heads: int,
+                        length: int) -> bool:
+    """Whether causal self-attention over ``length`` tokens by
+    ``attention_fn`` is the flash kernel: :func:`default_attention`
+    where dense gives way (no bias stands in the kernel's way there);
+    an attention of the caller's is not known to be one."""
+    return attention_fn is default_attention and dense_gives_way(
+        backend, batch, heads, length, length)
+
+
 def padding_bias(mask, dtype=jnp.float32):
     """[B, L] 1/0 validity mask -> additive [B, 1, 1, L] attention bias."""
     return ((1.0 - mask.astype(jnp.float32)) * -1e30)[:, None, None, :].astype(dtype)
@@ -759,6 +769,14 @@ def mla_apply(p, x, n_heads: int, cfg: MLAConfig, rope, pre_norm=None):
     return _mla(p, x, rope, None, n_heads, cfg, pre_norm)[0]
 
 
+def mla_core_is_kernel(cfg: MLAConfig, backend: str, length: int) -> bool:
+    """Whether :func:`mla_apply`'s core is the flash kernel where a
+    checkpoint around the caller sees it: not where queries choose
+    (:func:`_choosing_mla` keeps its own residuals behind a
+    ``custom_vjp``), else where :func:`causal_core` is the kernel."""
+    return not cfg.selects(length) and core_is_the_kernel(backend, length)
+
+
 # ---------------------------------------------------------------------------
 # compressed convolutional attention (CCA: Zyphra, arXiv:2510.04476)
 
@@ -929,6 +947,12 @@ def cca_apply(p, x, cfg: CCAConfig, rope):
     out = cca_core(q, k, v, d ** -0.5, cfg.block)
     out = out.transpose(0, 2, 1, 3).reshape(b, l, hq * d)
     return out @ p["o_proj"].astype(x.dtype)
+
+
+def cca_core_is_kernel(backend: str, length: int) -> bool:
+    """Whether :func:`cca_apply`'s core, :func:`cca_core`, is the flash
+    kernel."""
+    return core_is_the_kernel(backend, length)
 
 
 # ---------------------------------------------------------------------------

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh
 
 from baton_tpu.core.model import FedModel
 from baton_tpu.core.partition import PathPredicate, make_partition
@@ -46,12 +46,7 @@ from baton_tpu.obs.compute import ComputeProbe
 from baton_tpu.ops import aggregation as agg
 from baton_tpu.ops.padding import round_up
 from baton_tpu.parallel.mesh import CLIENT_AXIS, client_sharding, replicated_sharding
-from baton_tpu.parallel.partition import (
-    client_spec,
-    kernel_specs,
-    replicated_spec,
-    waved_client_spec,
-)
+from baton_tpu.parallel.partition import kernel_specs
 from baton_tpu.parallel.tensor_parallel import MODEL_AXIS, shard_params_tp
 from baton_tpu.utils.profiling import (
     _plan_gb_of,
@@ -423,10 +418,8 @@ class FedSim:
     # when a round fits in one wave (jnp identity slices return the same
     # buffer), so donating them would invalidate data the caller reuses
     # across rounds. Donation lives where it is safe and large: the
-    # fused round runner donates params+opt state by default
-    # (run_rounds_fused, donate_argnums), the wave loop donates its
-    # model-sized psum accumulator (_acc_tree_add, then the fold
-    # program), and
+    # wave loop donates its model-sized psum accumulator
+    # (_acc_tree_add, then the fold program), and
     # LocalTrainer.train_with_opt_state donates the per-client optimizer
     # state (training.py) — the buffers that would otherwise be
     # double-buffered per round.
@@ -482,7 +475,7 @@ class FedSim:
             ))
         return cache[n_epochs]
 
-    def _make_wave_sums_sharded(self, n_epochs: int, raw: bool = False):
+    def _make_wave_sums_sharded(self, n_epochs: int):
         # Cache per n_epochs: rebuilding the shard_map closure every round
         # would hand jit a fresh function and force an XLA recompile.
         cache = getattr(self, "_sharded_cache", None)
@@ -506,21 +499,16 @@ class FedSim:
                 return psum, lsum, wtot, client_losses
 
             in_specs, out_specs = kernel_specs("engine.wave_sums")
-            sharded = jax.shard_map(
+            # donation decided no: params is the caller-retained
+            # anchor, re-read across waves
+            cache[n_epochs] = jax.jit(jax.shard_map(  # batonlint: allow[BTL011]
                 kernel,
                 mesh=mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
                 check_vma=False,
-            )
-            # donation decided no: params is the caller-retained
-            # anchor, re-read across waves
-            cache[n_epochs] = (
-                sharded,
-                jax.jit(sharded),  # batonlint: allow[BTL011]
-            )
-        sharded, jitted = cache[n_epochs]
-        return sharded if raw else jitted
+            ))
+        return cache[n_epochs]
 
     def _wave_program(self, n_epochs: int, robust: bool,
                       per_shard: bool = False):
@@ -1215,174 +1203,13 @@ class FedSim:
         return params, history
 
 
-    # ------------------------------------------------------------------
-    # fused rounds: the whole multi-round federated loop as ONE compiled
-    # XLA program — lax.scan over rounds, lax.scan over waves inside.
-    def _make_rounds_fused(self, n_epochs: int, n_rounds: int,
-                           donate: bool = True):
-        cache = getattr(self, "_fused_cache", None)
-        if cache is None:
-            cache = self._fused_cache = {}
-        key = (n_epochs, n_rounds, donate)
-        if key in cache:
-            return cache[key]
-        if self.mesh is not None and not self.is_hybrid:
-            kernel = self._make_wave_sums_sharded(n_epochs, raw=True)
-        else:
-            # single-device AND hybrid mesh: raw vmap math; on the hybrid
-            # mesh GSPMD partitions it from the input placements
-            kernel = partial(self._wave_sums_raw, n_epochs=n_epochs)
-        server_opt = self.server_optimizer
-
-        def run(params, frozen, data_w, n_w, rng, server_opt_state):
-            # data_w leaves [n_waves, wave, cap, ...]; n_w [n_waves, wave]
-            n_waves, wave = n_w.shape
-            zeros = jax.tree_util.tree_map(
-                lambda l: jnp.zeros(l.shape, jnp.float32), params
-            )
-
-            def one_round(carry, r):
-                p, sos = carry
-                rkeys = jax.random.split(
-                    jax.random.fold_in(rng, r), n_waves * wave
-                ).reshape(n_waves, wave)
-
-                def wave_body(acc, xs):
-                    d, n, rr = xs
-                    psum, lsum, wtot, _ = kernel(p, frozen, d, n, rr)
-                    return (
-                        agg.tree_add(acc[0], psum),
-                        acc[1] + lsum,
-                        acc[2] + wtot,
-                    ), None
-
-                init = (zeros, jnp.zeros((n_epochs,), jnp.float32),
-                        jnp.float32(0.0))
-                (psum, lsum, wtot), _ = jax.lax.scan(
-                    wave_body, init, (data_w, n_w, rkeys)
-                )
-                p2, sos, losses = _fold_mean(server_opt, psum, wtot, lsum,
-                                             p, sos)
-                return (p2, sos), losses
-
-            (p, sos), losses = jax.lax.scan(
-                one_round, (params, server_opt_state), jnp.arange(n_rounds)
-            )
-            return p, sos, losses  # losses [n_rounds, n_epochs]
-
-        # donate=True (the default) aliases the incoming params/server-opt
-        # buffers into the outputs — HBM hygiene: no double-buffered
-        # global state across the dispatch. frozen (argnum 1) is NOT
-        # donated: partition.merge reads it after the call. Callers that
-        # must keep the old globals pass donate_buffers=False.
-        fn = jax.jit(run, donate_argnums=(0, 5) if donate else ())
-        cache[key] = fn
-        return fn
-
-    def run_rounds_fused(
-        self,
-        params: Params,
-        data,
-        n_samples,
-        rng: jax.Array,
-        n_rounds: int,
-        n_epochs: int = 1,
-        wave_size=None,
-        server_opt_state=None,
-        return_server_opt_state: bool = False,
-        donate_buffers: bool = True,
-    ):
-        """``run_rounds`` as a single XLA dispatch.
-
-        Robust aggregators are not supported here (the fused kernel
-        streams weighted sums; order statistics would need every
-        client's params live inside the scan) — use :meth:`run_round` /
-        :meth:`run_rounds`, which apply them per round.
-
-        ``donate_buffers`` (default True) donates the params/server-opt
-        input buffers to XLA — the returned arrays alias them, so the
-        old globals are never double-buffered across the dispatch. On
-        accelerator backends the caller's ``params`` (and any
-        ``server_opt_state`` passed in) are INVALID after this returns;
-        pass ``donate_buffers=False`` to keep them (e.g. to re-run from
-        the same initial params). CPU ignores donation, so CPU tests are
-        unaffected either way.
-
-        Donation-safety audit (aliased buffers never read after the
-        fused call): argnum 0 is the post-``_split`` trainable tree and
-        argnum 5 the server opt state — neither local is read below the
-        ``fn(...)`` call; ``frozen`` IS read by ``partition.merge`` and
-        is deliberately not donated.
-
-        The per-round Python of :meth:`run_round` (slicing, accumulation,
-        the aggregate divide, the server update) all becomes traced code
-        inside one jit: ``lax.scan`` over rounds, ``lax.scan`` over HBM
-        waves within a round. One host→device dispatch and one fetch for
-        the whole training run: no per-round host round-trip, and XLA
-        may overlap the round boundary with compute. Identical math to
-        ``run_rounds`` (same fold_in round rngs; bitwise-equal when the
-        cohort needs no phantom padding and fills its capacity: this
-        path computes every row it is handed, where :meth:`run_round`
-        stages the rows the cohort holds and so shuffles fewer).
-        """
-        if self.aggregator[0] != "mean":
-            raise NotImplementedError(
-                "run_rounds_fused streams weighted sums and cannot apply "
-                f"the {self.aggregator[0]!r} aggregator; use run_round/"
-                "run_rounds for robust aggregation"
-            )
-        if wave_size == "auto":
-            # the fused scan adds only params/opt/accumulator carries on
-            # top of the wave kernel auto probes — small next to the
-            # conservative plan budget
-            wave_size = self.auto_wave_size(params, data, n_samples,
-                                            n_epochs=n_epochs)
-        params, frozen = self._split(params)
-        n_samples = jnp.asarray(n_samples)
-        c = int(n_samples.shape[0])
-        n_dev = self._clients_per_wave_unit()
-        wave = round_up(wave_size if wave_size is not None else c, n_dev)
-        n_waves = -(-c // wave)
-        c_pad = n_waves * wave
-
-        rngs = jax.random.split(rng, c)  # only shape matters for padding
-        data, n_samples, _ = self._pad_wave(data, n_samples, rngs, c_pad)
-        data_w = jax.tree_util.tree_map(
-            lambda a: jnp.asarray(a).reshape((n_waves, wave) + a.shape[1:]),
-            data,
-        )
-        n_w = n_samples.reshape(n_waves, wave)
-        if self.mesh is not None:
-            shard = NamedSharding(self.mesh, waved_client_spec())
-            data_w = jax.tree_util.tree_map(
-                lambda a: jax.device_put(a, shard), data_w
-            )
-            n_w = jax.device_put(n_w, shard)
-        if self.is_hybrid:
-            params, frozen = self._place_hybrid(params, frozen)
-
-        if self.server_optimizer is not None and server_opt_state is None:
-            server_opt_state = self.server_optimizer.init(params)
-
-        fn = self._make_rounds_fused(n_epochs, n_rounds, donate=donate_buffers)
-        new_params, server_opt_state, losses = fn(
-            params, frozen, data_w, n_w, rng, server_opt_state
-        )
-        if self.partition is not None:
-            new_params = self.partition.merge(new_params, frozen)
-        history = np.asarray(losses).reshape(-1).tolist()
-        if return_server_opt_state:
-            return new_params, history, server_opt_state
-        return new_params, history
-
-
 def _tree_bytes(tree) -> int:
     """Bytes of the arrays of ``tree`` (0 for ``None``), from shapes."""
     return sum(int(a.size) * a.dtype.itemsize
                for a in jax.tree_util.tree_leaves(tree))
 
 
-# The model-sized accumulator of the non-fused wave loop: the previous
+# The model-sized accumulator of the wave loop: the previous
 # partial sum is donated into the add, so the loop holds ONE psum buffer
 # instead of two (old + new) at the accumulation point. Safe by
 # construction — the donated array is the previous wave's kernel output,
@@ -1398,8 +1225,7 @@ def _fold_mean(server_optimizer, psum, wtot, lsum, params, server_opt_state):
     and the FedOpt step where there is a server optimizer (its state
     made there when the caller brought none). Returns ``(new_params,
     server_opt_state, loss_history)``. Traced inside
-    :meth:`FedSim.run_round`'s fold program and inside the fused rounds'
-    scan."""
+    :meth:`FedSim.run_round`'s fold program."""
     denom = jnp.maximum(wtot, 1e-9)
     aggregate = jax.tree_util.tree_map(
         lambda s, ref: (s / denom).astype(ref.dtype), psum, params

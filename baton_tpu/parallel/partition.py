@@ -18,9 +18,9 @@ divide the mesh axis sizes also falls back to replicated (correct, just
 not sharded) — the same safety valve the old per-leaf heuristics had.
 
 Every other ``parallel/`` module builds its specs from the helpers here
-(``replicated_spec`` / ``client_spec`` / ``waved_client_spec`` /
-``dim_spec``); ``tests/test_partition_rules.py`` enforces that no
-``PartitionSpec`` is constructed ad hoc outside this file.
+(``replicated_spec`` / ``client_spec`` / ``dim_spec``);
+``tests/test_partition_rules.py`` enforces that no ``PartitionSpec`` is
+constructed ad hoc outside this file.
 """
 
 from __future__ import annotations
@@ -58,12 +58,6 @@ def replicated_spec() -> PartitionSpec:
 def client_spec(axis: str = CLIENT_AXIS) -> PartitionSpec:
     """``[C, ...]`` stacked client arrays: dim 0 over the client axis."""
     return PartitionSpec(axis)
-
-
-def waved_client_spec(axis: str = CLIENT_AXIS) -> PartitionSpec:
-    """``[W, C, ...]`` wave-major client stacks (the fused round step's
-    data layout): dim 1 over the client axis, waves replicated."""
-    return PartitionSpec(None, axis)
 
 
 def dim_spec(axis: str, dim: int, ndim: int) -> PartitionSpec:

@@ -125,6 +125,27 @@ def test_federated_convergence_to_true_coefficients(nprng):
     )
 
 
+def test_rounds_learn_classification(nprng):
+    """The MLP classifier over rounds to an accuracy: the only test that
+    trains a non-linear model through ``run_rounds`` to a metric."""
+    from baton_tpu.data.synthetic import synthetic_classification_clients
+    from baton_tpu.models.mlp import mlp_classifier_model
+
+    datasets, _ = synthetic_classification_clients(nprng, 8)
+    data, n_samples = stack_client_datasets(datasets, batch_size=32)
+    data = {k: jnp.asarray(v) for k, v in data.items()}
+    model = mlp_classifier_model(32, (64,), 10)
+    sim = FedSim(model, batch_size=32, learning_rate=0.3)
+    params = sim.init(jax.random.key(0))
+    params, history = sim.run_rounds(
+        params, data, jnp.asarray(n_samples), jax.random.key(1),
+        n_rounds=10, n_epochs=2,
+    )
+    assert history[-1] < history[0] * 0.5
+    metrics = sim.evaluate_round(params, data, jnp.asarray(n_samples))
+    assert metrics["accuracy"] > 0.7
+
+
 def test_server_optimizer_fedavg_identity(linear_setup):
     """FedOpt with sgd(1.0) must reduce exactly to FedAvg assignment."""
     model, params, data, n_samples = linear_setup
@@ -305,9 +326,9 @@ def test_auto_wave_size_from_memory_plan(nprng, monkeypatch):
     assert len(sim._auto_wave_cache) == 1  # same shapes -> cache hit
 
 
-def test_auto_wave_size_mesh_and_fused(nprng, monkeypatch):
+def test_auto_wave_size_mesh_and_rounds(nprng, monkeypatch):
     """"auto" composes with a clients mesh (the probe lowers the
-    per-shard program) and with run_rounds_fused."""
+    per-shard program) and with run_rounds."""
     from baton_tpu.models.linear import linear_regression_model
     from baton_tpu.ops.padding import stack_client_datasets
     from baton_tpu.parallel.mesh import make_mesh
@@ -326,9 +347,9 @@ def test_auto_wave_size_mesh_and_fused(nprng, monkeypatch):
     params = sim.init(jax.random.key(0))
 
     assert sim.auto_wave_size(params, data, n, budget_gb=64.0) is None
-    p2, hist = sim.run_rounds_fused(params, data, jnp.asarray(n),
-                                    jax.random.key(1), n_rounds=2,
-                                    wave_size="auto")
+    p2, hist = sim.run_rounds(params, data, jnp.asarray(n),
+                              jax.random.key(1), n_rounds=2,
+                              wave_size="auto")
     assert np.isfinite(float(hist[-1]))
 
 
@@ -400,8 +421,7 @@ def _bfloat16_bias(model):
 
 # (FedSim arguments, wave size, bit-equal?). One program lets XLA contract
 # a multiply and the add that takes it into one rounding, which the same
-# expressions dispatched one by one cannot have (the fused runner has
-# always computed them so): exact where every product is (the mean's
+# expressions dispatched one by one cannot have: exact where every product is (the mean's
 # divide and cast, a server step of 1.0 or a power of two), to half a
 # unit in the last place through a moment's `decay * m + g`.
 FOLD_CASES = {

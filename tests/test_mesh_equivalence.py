@@ -506,8 +506,8 @@ def test_hybrid_round_semantic_guardrail():
                                rtol=5e-2)
 
 
-def test_fused_phantom_padding_semantic_guardrail(nprng):
-    """The fused runner auto-pads a 5-client cohort on the 8-device
+def test_rounds_phantom_padding_semantic_guardrail(nprng):
+    """``run_rounds`` auto-pads a 5-client cohort on the 8-device
     mesh; the padded mesh program must stay in the semantic band of the
     unpadded vmap program (phantom weightlessness is asserted exactly,
     per compiled kernel, in test_engine_sharded_wave_phantom_rows_*)."""
@@ -517,10 +517,9 @@ def test_fused_phantom_padding_semantic_guardrail(nprng):
                    mesh=make_mesh(8))
     sim_v = FedSim(model, batch_size=32, learning_rate=0.02)
     params = sim_v.init(jax.random.key(0))
-    p_m, h_m = sim_m.run_rounds_fused(params, data, n_samples,
-                                      jax.random.key(1), n_rounds=2,
-                                      donate_buffers=False)
-    p_v, h_v = sim_v.run_rounds_fused(params, data, n_samples,
-                                      jax.random.key(1), n_rounds=2)
+    p_m, h_m = sim_m.run_rounds(params, data, n_samples,
+                                jax.random.key(1), n_rounds=2)
+    p_v, h_v = sim_v.run_rounds(params, data, n_samples,
+                                jax.random.key(1), n_rounds=2)
     _tree_close(p_m, p_v, rtol=5e-2, atol=5e-2)
     np.testing.assert_allclose(h_m, h_v, rtol=5e-2)

@@ -14,8 +14,7 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 #: internals: a ``utils`` module that names one builds what the engine
 #: builds, a second time
 UPPER_LAYERS = ("baton_tpu.parallel", "baton_tpu.server", "baton_tpu.obs")
-ENGINE_INTERNALS = {"_split", "_pad_wave", "_wave_sums_raw",
-                    "_make_rounds_fused"}
+ENGINE_INTERNALS = {"_split", "_pad_wave", "_wave_sums_raw"}
 
 
 def test_utils_reaches_into_no_layer_above_it():
