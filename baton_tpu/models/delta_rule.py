@@ -74,14 +74,19 @@ def gated_delta_init(key, d_model, n_heads, d_k, d_v, out_std=None):
     }
 
 
-def _causal_conv_silu(x, taps):
+def _causal_conv_silu(x, taps, bias=None):
     """Depthwise causal convolution over ``x [B, L, ch]`` with ``taps
-    [T, ch]`` (tap ``T-1`` meets the current token), then SiLU; float32."""
+    [T, ch]`` (tap ``T-1`` meets the current token) and a ``bias [ch]``
+    where one is given, then SiLU; float32. Slices of the padded
+    sequence and products, not ``lax.conv``: under a client ``vmap``
+    that would be a grouped convolution over the clients."""
     x = x.astype(jnp.float32)
     n_taps, l = taps.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (n_taps - 1, 0), (0, 0)))
     y = sum(padded[:, j:j + l] * taps[j].astype(jnp.float32)
             for j in range(n_taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y)
 
 

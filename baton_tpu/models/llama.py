@@ -8,9 +8,10 @@ TPU-first blocks in :mod:`baton_tpu.models.transformer`. ``layer_types``
 makes it a hybrid: each layer's mixer is one of :data:`MIXERS` (full
 attention, the gated delta rule's linear attention with a recurrent
 state, latent attention in a low-rank latent whose queries may choose
-their keys, compressed convolutional attention), in the pattern the
-configuration gives. The table is all this module knows of a mixer: a
-further one is one more entry. With ``moe`` the layers after the
+their keys, compressed convolutional attention, a Mamba-2 state-space
+branch and an attention branch side by side on one normed input), in
+the pattern the configuration gives. The table is all this module
+knows of a mixer: a further one is one more entry. With ``moe`` the layers after the
 first ``first_dense_layers`` replace their SwiGLU by the expert layer of
 :mod:`baton_tpu.models.moe`, which holds ``moe.experts_held`` of the
 router's experts and computes their part; where its router carries a
@@ -18,7 +19,10 @@ state (``moe.router_hidden``) a block takes and hands on two streams,
 the tokens' and the routers', and the first block is handed zeros. With
 ``residual_merge`` a sub-layer's output joins the stream by a learned
 affine merge a channel and not a plain add; with ``tie_embeddings`` the
-head is the embedding table itself. A block is traced once a
+head is the embedding table itself. ``multipliers`` are the fixed
+scalars a model publishes for its projections, the embedding and the
+logits (:class:`~baton_tpu.models.transformer.Multipliers`; at 1, as
+every other model has them, they add no op). A block is traced once a
 kind (of mixer and of feed-forward), whatever the depth.
 
 * params fp32 / activations ``compute_dtype`` (bf16 on TPU), norms,
@@ -58,12 +62,13 @@ from baton_tpu.models.delta_rule import gated_delta_apply, gated_delta_init
 from baton_tpu.models.lora import lora_wrap
 from baton_tpu.models.moe import (
     MoEConfig, moe_apply, moe_apply_with_state, moe_init, rows_bound)
+from baton_tpu.models.state_space import SSMConfig, mamba2_apply, mamba2_init
 from baton_tpu.models.transformer import (
-    AttentionFn, CCAConfig, MLAConfig, attention_is_kernel, cca_apply,
-    cca_core_is_kernel, cca_init, default_attention, dense_init, matmul,
-    mha_apply, mha_init, mla_apply, mla_core_is_kernel, mla_init,
+    AttentionFn, CCAConfig, MLAConfig, Multipliers, attention_is_kernel,
+    cca_apply, cca_core_is_kernel, cca_init, default_attention, dense_init,
+    matmul, mha_apply, mha_init, mla_apply, mla_core_is_kernel, mla_init,
     mla_rope_angles, next_token_loss, normal_init, rms_init, rms_norm,
-    rope_angles, swiglu_apply, swiglu_init, tied_logits)
+    rope_angles, scaled, swiglu_apply, swiglu_init, tied_logits)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,6 +79,8 @@ class LlamaConfig:
     n_layers: int = 32
     n_heads: int = 32
     n_kv_heads: int = 8
+    # of a full-attention head; None: ``d_model // n_heads``
+    head_dim: Optional[int] = None
     d_ff: int = 14336
     # None: no rotary embedding (position comes from the recurrent
     # layers of a hybrid)
@@ -83,9 +90,11 @@ class LlamaConfig:
     # ``first_dense_layers``; its own width is ``moe.d_ff``
     moe: Optional[MoEConfig] = None
     first_dense_layers: int = 0
-    # the sizes of latent and of compressed convolutional attention
+    # the sizes of latent attention, of compressed convolutional
+    # attention and of a state-space branch
     mla: Optional[MLAConfig] = None
     cca: Optional[CCAConfig] = None
+    ssm: Optional[SSMConfig] = None
     # RMSNorm of the whole query and key projections in full attention
     qk_norm: bool = False
     # the mixer of each layer, a key of ``MIXERS``; the first
@@ -99,8 +108,9 @@ class LlamaConfig:
     linear_value_dim: int = 0
     linear_allow_neg_eigval: bool = True
     linear_chunk: int = 64
-    # deviation of the embedding table's initial normal: the scale of the
-    # residual stream the blocks' outputs are added to
+    # deviation of the embedded tokens (the table's initial normal times
+    # ``multipliers.embedding``): the scale of the residual stream the
+    # blocks' outputs are added to
     embed_std: float = 0.02
     # the RMSNorms before each sub-layer and before the head (a latent
     # mixer's own norms have ``mla.norm_eps``)
@@ -111,14 +121,13 @@ class LlamaConfig:
     residual_merge: bool = False
     # the head is the embedding table transposed; no ``lm_head`` leaf
     tie_embeddings: bool = False
+    multipliers: Multipliers = Multipliers()
 
     def __post_init__(self):
         if self.layer_types is not None:  # a JSON list hashes as a tuple
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
 
     def kind_of(self, layer: int) -> str:
         if self.layer_types:
@@ -158,7 +167,7 @@ class Mixer:
     init: Callable[..., Any]  # (rng, cfg, out_std) -> its parameters
     # (p, h, cfg, rope, attention_fn) -> [B, L, D] from the normed stream
     apply: Callable[..., jax.Array]
-    # its 2-D leaves that LoRA adapts, by name (none in a tree inside it)
+    # its 2-D leaves that LoRA adapts, by their path under ``key``
     projections: Tuple[str, ...]
     # (cfg, length) -> its rotation's (cos, sin), or None
     rope: Callable[..., Optional[tuple]] = lambda cfg, length: None
@@ -167,23 +176,63 @@ class Mixer:
     # kernel there, whose two outputs the block's checkpoint then keeps
     core_is_kernel: Callable[..., bool] = lambda *where: False
     facts: Callable[..., tuple] = lambda cfg: ()  # for baton.round's span
+    # (cfg, length) -> what a trace learns of its sequences, for the same
+    seen: Callable[..., dict] = lambda cfg, length: {}
+
+
+def _attention_init(rng, cfg, out_std):
+    """Full attention's parameters, each projection drawn against the
+    multipliers on its way (:class:`Multipliers`)."""
+    m = cfg.multipliers
+    p = mha_init(rng, cfg.d_model, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                 head_dim=cfg.head_dim, out_std=out_std / m.attention_out,
+                 qk_norm=cfg.qk_norm)
+    for name, by in (("wq", m.attention_in), ("wk", m.attention_in * m.key),
+                     ("wv", m.attention_in)):
+        if by != 1:
+            p[name] = p[name] / by
+    return p
+
+
+def _attention_apply(p, h, cfg, rope, attention_fn):
+    m = cfg.multipliers
+    return scaled(mha_apply(
+        p, scaled(h, m.attention_in), cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+        causal=True, rope=rope, attention_fn=attention_fn,
+        key_multiplier=m.key), m.attention_out)
+
+
+def _attention_rope(cfg, length):
+    # None: position comes from the recurrent layers of a hybrid
+    return None if cfg.rope_theta is None else rope_angles(
+        length, cfg.head_dim, cfg.rope_theta)
+
+
+def _attention_is_kernel(cfg, backend, batch, length, attention_fn):
+    return attention_is_kernel(attention_fn, backend, batch, cfg.n_heads,
+                               length)
+
+
+def _parallel_init(rng, cfg, out_std):
+    k_ssm, k_attn = jax.random.split(rng)
+    return {"ssm": mamba2_init(k_ssm, cfg.d_model, cfg.ssm, cfg.multipliers,
+                               out_std),
+            "attention": _attention_init(k_attn, cfg, out_std)}
+
+
+@jax.named_scope("parallel_mixer")
+def _parallel_apply(p, h, cfg, rope, attention_fn):
+    """A state-space branch and an attention branch over the same
+    normed input, each under its multipliers, summed."""
+    return mamba2_apply(p["ssm"], h, cfg.ssm, cfg.multipliers) \
+        + _attention_apply(p["attention"], h, cfg, rope, attention_fn)
 
 
 MIXERS = {
     "full_attention": Mixer(
-        key="attn",
-        init=lambda rng, cfg, out_std: mha_init(
-            rng, cfg.d_model, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-            out_std=out_std, qk_norm=cfg.qk_norm),
-        apply=lambda p, h, cfg, rope, attention_fn: mha_apply(
-            p, h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads, causal=True,
-            rope=rope, attention_fn=attention_fn),
-        projections=("wq", "wk", "wv", "wo"),
-        # None: position comes from the recurrent layers of a hybrid
-        rope=lambda cfg, length: None if cfg.rope_theta is None
-        else rope_angles(length, cfg.head_dim, cfg.rope_theta),
-        core_is_kernel=lambda cfg, backend, batch, length, fn:
-        attention_is_kernel(fn, backend, batch, cfg.n_heads, length)),
+        key="attn", init=_attention_init, apply=_attention_apply,
+        projections=("wq", "wk", "wv", "wo"), rope=_attention_rope,
+        core_is_kernel=_attention_is_kernel),
     "linear_attention": Mixer(
         key="linear_attn",
         init=lambda rng, cfg, out_std: gated_delta_init(
@@ -221,6 +270,20 @@ MIXERS = {
         facts=lambda cfg: (
             ("latent_q", cfg.cca.latent_q), ("latent_kv", cfg.cca.latent_kv),
             ("conv_taps", f"{cfg.cca.time0}+{cfg.cca.time1}"))),
+    # two mixers over one normed input: the rotation, the kernel and its
+    # kept outputs are the attention branch's
+    "parallel_ssm_attention": Mixer(
+        key="parallel", init=_parallel_init, apply=_parallel_apply,
+        # not the convolution, not ``a_log``, ``d``, ``dt_bias`` or a norm
+        projections=("ssm/in_proj", "ssm/out_proj", "attention/wq",
+                     "attention/wk", "attention/wv", "attention/wo"),
+        rope=_attention_rope, core_is_kernel=_attention_is_kernel,
+        facts=lambda cfg: (
+            ("ssm_heads", cfg.ssm.n_heads), ("ssm_state", cfg.ssm.d_state),
+            ("ssm_groups", cfg.ssm.n_groups), ("ssm_chunk", cfg.ssm.chunk),
+            ("conv_taps", cfg.ssm.conv_taps)),
+        seen=lambda cfg, length: {
+            "ssm_chunks": -(-length // min(cfg.ssm.chunk, length))}),
 }
 
 _MLP_PROJECTIONS = ("w_gate", "w_up", "w_down")  # a shared expert's too
@@ -235,15 +298,15 @@ def _mixer(kind: str) -> Mixer:
 
 def projection_lora_target(path: str, leaf) -> bool:
     """LoRA target predicate: the 2-D leaves each mixer of
-    :data:`MIXERS` lists as its ``projections``, directly under its
-    ``key``, and those of the MLPs and of a shared expert; not the
-    embedding, the head, an expert layer's router (a matrix or an MLP)
-    or its 3-D stacks of routed experts."""
-    *_, parent, name = [""] + path.split("/")
+    :data:`MIXERS` lists as its ``projections``, by their path under its
+    ``key`` (``wq``, or ``ssm/in_proj`` where the mixer is a pair), and
+    those of the MLPs and of a shared expert; not the embedding, the
+    head, an expert layer's router (a matrix or an MLP) or its 3-D
+    stacks of routed experts."""
     return getattr(leaf, "ndim", 2) == 2 and (
-        name in _MLP_PROJECTIONS or any(
-            parent == m.key and name in m.projections
-            for m in MIXERS.values()))
+        path.rsplit("/", 1)[-1] in _MLP_PROJECTIONS or any(
+            f"/{path}".endswith(f"/{m.key}/{name}")
+            for m in MIXERS.values() for name in m.projections))
 
 
 def _block_init(key, cfg: LlamaConfig, kind: Optional[str] = None,
@@ -256,7 +319,7 @@ def _block_init(key, cfg: LlamaConfig, kind: Optional[str] = None,
     if experts:
         mlp = moe_init(km, cfg.d_model, cfg.d_ff, cfg.moe)
     else:
-        mlp = swiglu_init(km, cfg.d_model, cfg.d_ff)
+        mlp = swiglu_init(km, cfg.d_model, cfg.d_ff, cfg.multipliers.mlp)
     m = _mixer(kind or cfg.kind_of(0))
     mixer = {m.key: m.init(
         ka, cfg, cfg.d_model ** -0.5 / (2 * cfg.n_layers) ** 0.5)}
@@ -311,7 +374,7 @@ def _feed_forward(p, x, r, cfg: LlamaConfig):
     elif "router" in p["mlp"]:
         y = moe_apply(p["mlp"], h, cfg.moe)
     else:
-        y = swiglu_apply(p["mlp"], h)
+        y = swiglu_apply(p["mlp"], h, cfg.multipliers.mlp)
     return _joined(p, "merge_mlp", x, y), r
 
 
@@ -391,6 +454,7 @@ def llama_lm_model(
     ff_fn = (jax.checkpoint(_feed_forward, static_argnums=(3,)) if remat
              else _feed_forward)
     stateful = cfg.moe is not None and cfg.moe.router_hidden is not None
+    on = cfg.multipliers
     seen = {}  # what ``_hidden`` learned of the batch it was last traced on
     if stateful and cfg.first_dense_layers:
         raise NotImplementedError(
@@ -399,9 +463,10 @@ def llama_lm_model(
 
     def init(rng):
         keys = jax.random.split(rng, cfg.n_layers + 2)
+        table_std = cfg.embed_std / on.embedding
         params = {
             "tok_emb": normal_init(keys[0], (cfg.vocab_size, cfg.d_model),
-                                   cfg.embed_std),
+                                   table_std),
             "blocks": [
                 _block_init(keys[1 + i], cfg, cfg.kind_of(i),
                             cfg.has_experts(i))
@@ -413,10 +478,12 @@ def llama_lm_model(
             # the table is the head too: the last norm takes the stream
             # to the scale at which its logits have the deviation an
             # untied head's have, 1, as a trained scale would
-            params["norm_f"]["scale"] /= cfg.embed_std * cfg.d_model ** 0.5
+            params["norm_f"]["scale"] /= (
+                table_std * on.lm_head * cfg.d_model ** 0.5)
         else:
-            params["lm_head"] = dense_init(keys[-1], cfg.d_model,
-                                           cfg.vocab_size)
+            params["lm_head"] = dense_init(
+                keys[-1], cfg.d_model, cfg.vocab_size,
+                cfg.d_model ** -0.5 / on.lm_head)
         # a router, one matrix or an MLP's leaves, stays float32
         return jax.tree_util.tree_map_with_path(
             lambda path, a: a.astype(param_dtype) if a.ndim >= 2
@@ -430,8 +497,11 @@ def llama_lm_model(
             cfg, jax.default_backend(), *ids.shape, attention_fn)
         # a layer's angles are its kind's, made once a kind a trace
         ropes = {m: m.rope(cfg, l) for m in dict.fromkeys(mixers)}
+        for m in ropes:
+            seen.update(m.seen(cfg, l))
         with jax.named_scope("embed"):
-            x = params["tok_emb"][ids].astype(compute_dtype)
+            x = scaled(params["tok_emb"][ids], on.embedding).astype(
+                compute_dtype)
         # the routers' state: the first layer's router is handed zeros,
         # so that every layer is one kind of block
         r = (jnp.zeros(ids.shape + (cfg.moe.router_hidden,), jnp.float32)
@@ -452,14 +522,15 @@ def llama_lm_model(
         matmul, keep it on the fast MXU path."""
         x = _hidden(params, batch)
         if cfg.tie_embeddings:
-            return tied_logits(x, params["tok_emb"])
-        return matmul(x, params["lm_head"], jnp.float32)
+            return scaled(tied_logits(x, params["tok_emb"]), on.lm_head)
+        return scaled(matmul(x, params["lm_head"], jnp.float32), on.lm_head)
 
     def per_example_loss(params, batch, rng):
         x = _hidden(params, batch)
         tok_loss = next_token_loss(
             x, params["tok_emb" if cfg.tie_embeddings else "lm_head"],
-            batch["y"], tied=cfg.tie_embeddings)  # [B, L]
+            batch["y"], tied=cfg.tie_embeddings,
+            multiplier=on.lm_head)  # [B, L]
         loss_mask = batch.get("loss_mask")
         if loss_mask is None:
             return jnp.mean(tok_loss, axis=-1)

@@ -52,6 +52,42 @@ def dense_init(key, d_in, d_out, stddev=None):
     return normal_init(key, (d_in, d_out), stddev)
 
 
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """The fixed scalars a maximal-update parametrisation puts on a
+    model's projections (Falcon-H1's ``*_multiplier`` keys), applied
+    where the published code applies them. A multiplier of 1 adds no op
+    (:func:`scaled`). A draw from the seed stands for trained weights,
+    and the multipliers were tuned with the model's own initial
+    deviations: every multiplied matrix is drawn at its usual deviation
+    over its multiplier, so that the multiplied projection has the scale
+    the other models' have."""
+
+    embedding: float = 1.0  # on the looked-up rows of the table
+    lm_head: float = 1.0  # on the logits
+    key: float = 1.0  # on the key projection's output
+    attention_in: float = 1.0  # on an attention branch's input
+    attention_out: float = 1.0  # and on its output
+    ssm_in: float = 1.0  # on a state-space branch's input
+    ssm_out: float = 1.0  # and on its output
+    # on the five parts of its input projection: z, x, B, C, dt
+    ssm: tuple = (1.0,) * 5
+    mlp: tuple = (1.0, 1.0)  # on the gate's pre-activation, on the output
+
+    def __post_init__(self):  # a JSON list hashes as a tuple
+        for name in ("ssm", "mlp"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+
+
+def scaled(x, multiplier: float):
+    """``multiplier * x`` in float32, in ``x``'s dtype; ``x`` itself at
+    a multiplier of 1 (a bfloat16 product would round the multiplier to
+    eight bits too, the same way for every entry)."""
+    if multiplier == 1:
+        return x
+    return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
+
+
 def ln_init(d):
     return {"scale": jnp.ones((d,), jnp.float32), "bias": jnp.zeros((d,), jnp.float32)}
 
@@ -95,9 +131,11 @@ def rms_norm(x, p, eps=1e-6):
 
 
 def rope_angles(seq_len: int, head_dim: int, theta: float = 10000.0):
-    """Returns (cos, sin) each [L, Dh/2], fp32."""
+    """Returns (cos, sin) each [L, Dh/2], fp32. ``theta`` may be an
+    integer past 32 bits (a configuration file's 100000000000)."""
     inv_freq = 1.0 / (
-        theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        float(theta)
+        ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
     pos = jnp.arange(seq_len, dtype=jnp.float32)
     ang = jnp.outer(pos, inv_freq)  # [L, Dh/2]
@@ -240,21 +278,23 @@ def mha_apply(
     causal: bool = False,
     rope: Optional[tuple] = None,
     attention_fn: AttentionFn = default_attention,
+    key_multiplier: float = 1.0,
 ):
-    """Multi-head attention over x [B, L, D] -> [B, L, D]."""
+    """Multi-head attention over x [B, L, D] -> [B, L, D]; a head is
+    as wide as ``wq`` makes it (``d_model / n_heads`` or not)."""
     b, l, _ = x.shape
     n_kv = n_kv_heads or n_heads
     dh = p["wq"].shape[1] // n_heads
 
-    def proj(w, h, norm=None):
-        y = x @ w.astype(x.dtype)
+    def proj(w, h, norm=None, multiplier=1.0):
+        y = scaled(x @ w.astype(x.dtype), multiplier)
         if norm is not None:
             y = rms_norm(y, norm)
         return y.reshape(b, l, h, dh).transpose(0, 2, 1, 3)  # [B, H, L, Dh]
 
     # a "q_norm" / "k_norm" in the params is that projection's RMSNorm
     q = proj(p["wq"], n_heads, p.get("q_norm"))
-    k = proj(p["wk"], n_kv, p.get("k_norm"))
+    k = proj(p["wk"], n_kv, p.get("k_norm"), key_multiplier)
     v = proj(p["wv"], n_kv)
     if rope is not None:
         cos, sin = rope
@@ -976,19 +1016,24 @@ def gelu_mlp_apply(p, x):
     return h @ p["w2"].astype(x.dtype) + p["b2"].astype(x.dtype)
 
 
-def swiglu_init(key, d_model, d_ff):
+def swiglu_init(key, d_model, d_ff, multipliers=(1.0, 1.0)):
+    """``multipliers``: :func:`swiglu`'s, each drawn against
+    (:class:`Multipliers`)."""
     kg, ku, kd = jax.random.split(key, 3)
+    on_gate, on_down = multipliers
     return {
-        "w_gate": dense_init(kg, d_model, d_ff),
+        "w_gate": dense_init(kg, d_model, d_ff, d_model ** -0.5 / on_gate),
         "w_up": dense_init(ku, d_model, d_ff),
-        "w_down": dense_init(kd, d_ff, d_model),
+        "w_down": dense_init(kd, d_ff, d_model, d_ff ** -0.5 / on_down),
     }
 
 
-def swiglu(p, x):
-    g = jax.nn.silu(x @ p["w_gate"].astype(x.dtype))
+def swiglu(p, x, multipliers=(1.0, 1.0)):
+    """``on_down ((silu(on_gate (x W_gate)) * (x W_up)) W_down)``."""
+    on_gate, on_down = multipliers
+    g = jax.nn.silu(scaled(x @ p["w_gate"].astype(x.dtype), on_gate))
     u = x @ p["w_up"].astype(x.dtype)
-    return (g * u) @ p["w_down"].astype(x.dtype)
+    return scaled((g * u) @ p["w_down"].astype(x.dtype), on_down)
 
 
 swiglu_apply = jax.named_scope("mlp")(swiglu)
@@ -1064,10 +1109,12 @@ def tied_logits(x, table):
 
 
 @jax.named_scope("lm_loss")
-def next_token_loss(x, w_head, labels, tied: bool = False):
+def next_token_loss(x, w_head, labels, tied: bool = False,
+                    multiplier: float = 1.0):
     """Per-token cross-entropy ``[B, L]`` (fp32) of the head ``x [B, L,
     D] @ w_head [D, V]`` (``tied``: ``w_head`` is the embedding ``[V,
-    D]``, :func:`tied_logits`) against ``labels [B, L]``, what
+    D]``, :func:`tied_logits`; the logits times ``multiplier``,
+    :class:`Multipliers`) against ``labels [B, L]``, what
     :func:`per_token_cross_entropy` gives for the whole ``[B, L, V]``
     logits, computed in blocks of tokens: a ``lax.scan`` over the
     fewest equal blocks whose logits stay under
@@ -1080,9 +1127,9 @@ def next_token_loss(x, w_head, labels, tied: bool = False):
     b, l, d = x.shape
 
     def block(xb, yb):
-        return per_token_cross_entropy(
+        return per_token_cross_entropy(scaled(
             tied_logits(xb, w_head) if tied
-            else matmul(xb, w_head, jnp.float32), yb)
+            else matmul(xb, w_head, jnp.float32), multiplier), yb)
 
     vocab = w_head.shape[0 if tied else 1]
     n = min(l, -(-(4 * b * l * vocab) // _LOGITS_BLOCK_BYTES))

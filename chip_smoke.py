@@ -11,7 +11,9 @@ reached through a decoder's ``default_attention`` under the client
 ``vmap``), two LoRA rounds of the tiny hybrid decoder (gated delta-rule
 layers and full attention over a frozen bfloat16 base), two more of a
 tiny decoder of latent attention and expert layers with the routing
-of ``sarvam_105b`` at its published widths (``moe_mla_lora``), one
+of ``sarvam_105b`` at its published widths (``moe_mla_lora``), two of a
+small decoder whose blocks run a Mamba-2 state-space branch beside
+attention under Falcon-H1-34B's multipliers (``ssm_lora``), one
 in-process HTTP federation whose workers train on the
 device, the client mesh when the host has more than one device, and
 the compile cache. Weights are random from a seed, depth is cut, data
@@ -898,6 +900,97 @@ def phase_cca_lora(env: Env) -> None:
             f"over the others")
 
 
+def phase_ssm_lora(env: Env) -> None:
+    """A state-space branch beside attention, the bfloat16 path at small
+    sizes (the benchmark's ``tiny`` rehearsal computes in float32): two
+    blocks of a Mamba-2 branch (4 heads of 128, a state of 256, 2
+    groups, chunks of 128) and 5 query heads on 1 key-value head of 128
+    under Falcon-H1-34B's multipliers, over a frozen bfloat16 base, two
+    rounds through ``FedSim`` at 4,096 tokens (in rehearsal 80 tokens
+    in chunks of 32: two whole chunks and a padded tail): a broken
+    chunked scan under the client ``vmap``, a multiplier that flattens
+    a branch or a group of 5 the flash kernels refuse shows here before
+    the benchmark meets it. On a TPU the wave program holds the flash
+    kernels under ``attention``, whose outputs every block keeps. Then
+    the branch alone, bfloat16 beside float32 at ``highest``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models import llama, state_space
+    from baton_tpu.models.lora import lora_trainable
+    from baton_tpu.parallel.engine import FedSim
+    from fedbench import manifest
+
+    tiny = env.rehearsal
+    length = 80 if tiny else 4096
+    config = manifest.load_config(
+        manifest.ROOT, manifest.load_manifest(manifest.ROOT), "falcon_h1_34b")
+    on = manifest.resolve(  # the published ones, from the file
+        config["builder"]["kwargs"]["config"]["kwargs"]["multipliers"], config)
+    ssm = state_space.SSMConfig(n_heads=4, head_dim=128, d_state=256,
+                                n_groups=2, chunk=32 if tiny else 128)
+    cfg = llama.LlamaConfig(
+        vocab_size=512, max_len=length, d_model=256, n_layers=2, n_heads=5,
+        n_kv_heads=1, head_dim=128, d_ff=512, rope_theta=100000000000,
+        embed_std=1.0, norm_eps=1e-5,
+        layer_types=("parallel_ssm_attention",) * 2, ssm=ssm, multipliers=on)
+    model = llama.decoder_lora_model(cfg, rank=4, b_std=0.02)
+    params = jax.jit(model.init)(jax.random.key(0))
+    first = jax.random.randint(jax.random.key(1), (2, 1, 1), 0, 512)
+    tokens = (first + 7 * jnp.arange(length + 1)) % 512
+    data = {"x": tokens[..., :-1], "y": tokens[..., 1:]}
+    n_samples = np.asarray([1, 1], np.int32)
+    sim = FedSim(model, batch_size=1, learning_rate=0.05,
+                 trainable=lora_trainable)
+    losses, p = [], params
+    for i in range(2):
+        res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
+                            n_epochs=1, collect_client_losses=False)
+        losses.append(float(res.loss_history[-1]))
+        p = res.params
+    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
+    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    _check(all(a is b for a, b in zip(
+        jax.tree_util.tree_leaves(params["base"]),
+        jax.tree_util.tree_leaves(p["base"]))),
+        "a round copied or cast a leaf of the frozen base")
+    moved = [float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+        jax.tree_util.tree_leaves(params["lora"]),
+        jax.tree_util.tree_leaves(p["lora"]))]
+    _check(len(moved) == 2 * 9 * 2 and all(np.isfinite(moved))
+           and min(moved) > 0,
+           "an adapter factor of the nine projections did not move or is "
+           "not finite")
+    text = sim.lower_wave(params, data, n_samples, jax.random.key(2), 1,
+                          None).compile().as_text()
+    core_kernels = _core_kernels(text, model, "attention", cfg.n_layers, tiny)
+    chunks = dict(model.span_attrs)["ssm_chunks"]
+    _check(chunks == -(-length // ssm.chunk), f"{chunks} chunks a sequence")
+    # the branch alone: bfloat16 beside float32
+    blk = params["base"]["blocks"][0]["parallel"]["ssm"]
+    h = jax.random.normal(jax.random.key(5), (2, length, 256))
+    got = jax.jit(lambda h: state_space.mamba2_apply(blk, h, ssm, on))(
+        h.astype(jnp.bfloat16))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda h: state_space.mamba2_apply(
+            jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), blk),
+            h, ssm, on))(h)
+    err = _rel_err(got, want)
+    _check(err < 2 * BF16_TOL, f"the bfloat16 branch lies {err:.4f} from the "
+           f"float32 one")
+    env.say("ssm_lora",
+            f"{model.name}: two blocks of a Mamba-2 branch (4 heads of 128, "
+            f"state 256, {chunks} chunks of {ssm.chunk}) beside 5 query "
+            f"heads on 1 key-value head of 128, Falcon-H1-34B's multipliers, "
+            f"bf16 over a frozen bf16 base, 2 clients x {length} tokens, 2 "
+            f"rounds, loss {losses[0]:.4f} -> {losses[1]:.4f}; "
+            f"{len(moved)} adapter factors moved; {core_kernels} Pallas "
+            f"calls under attention in the wave program; the bfloat16 "
+            f"branch {err:.4f} from the float32 one (of its largest entry)")
+
+
+# ----------------------------------------------------------------------
 def _flash_alone(env: Env) -> str:
     import jax
     import jax.numpy as jnp
@@ -1334,6 +1427,7 @@ PHASES = {"device": phase_device, "fedsim_resnet18": phase_fedsim_resnet18,
           "hybrid_lora": phase_hybrid_lora,
           "moe_mla_lora": phase_moe_mla_lora,
           "dsa_mla_lora": phase_dsa_mla_lora, "cca_lora": phase_cca_lora,
+          "ssm_lora": phase_ssm_lora,
           "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
           "mesh": phase_mesh, "cache": phase_cache}
 

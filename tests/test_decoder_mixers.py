@@ -30,8 +30,8 @@ from baton_tpu.models.transformer import dense_init
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 LAYER_TYPES = ("full_attention", "linear_attention", "latent_attention",
-               "compressed_attention")
-PARAMETER_KEYS = ("attn", "linear_attn", "mla", "cca")
+               "compressed_attention", "parallel_ssm_attention")
+PARAMETER_KEYS = ("attn", "linear_attn", "mla", "cca", "parallel")
 
 
 def _accepted(name: str, tiny: bool) -> LlamaConfig:
@@ -57,6 +57,7 @@ def _accepted(name: str, tiny: bool) -> LlamaConfig:
         ("latent_attention", "sarvam_105b", 2048, 5, False, True),
         ("latent_attention", "glm_5", 8192, 0, True, False),
         ("compressed_attention", "zaya1_8b", 8192, 10, False, True),
+        ("parallel_ssm_attention", "falcon_h1_34b", 4096, 6, False, True),
     ])
 def test_an_entry_answers_what_the_accepted_configuration_runs(
         kind, config, cell_length, cell_kept, keeps_at_8192, kernel_at_8192):

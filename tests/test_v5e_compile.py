@@ -113,3 +113,55 @@ def test_a_block_that_keeps_the_cores_outputs_runs_the_forward_kernel_once(
     assert len(backward) == 1
     assert len(kernels) - len(backward) == forwards
     assert sum("rematted_computation" in k for k in kernels) == forwards - 1
+
+
+def test_the_pairs_block_keeps_its_attention_branchs_kernel_outputs(one_chip):
+    """The gradient of one checkpointed block of a state-space branch
+    beside attention, four clients under ``vmap`` at 4,096 tokens: 5
+    query heads on 1 key-value head of 128 (Falcon-H1's group of 5) go
+    through the flash kernels, the block's checkpoint sees the kept
+    output and log-sum-exp inside the pair (one forward kernel and the
+    one backward kernel, no second forward), and the chunked recurrence
+    compiles at a state of 256 x 128 in chunks of 128 with its scan the
+    only loops."""
+    from baton_tpu.models import llama, state_space
+    from baton_tpu.models.transformer import default_attention, rope_angles
+
+    clients, length = 4, 4096
+    cfg = llama.LlamaConfig(
+        vocab_size=256, d_model=512, n_layers=1, n_heads=5, n_kv_heads=1,
+        head_dim=128, d_ff=512, rope_theta=100000000000,
+        layer_types=("parallel_ssm_attention",),
+        ssm=state_space.SSMConfig(n_heads=4, head_dim=128, d_state=256,
+                                  n_groups=2, chunk=128))
+    rope = rope_angles(length, cfg.head_dim, cfg.rope_theta)
+    block = llama._checkpointed_block()
+
+    def loss(p, x):
+        def client(x):
+            y, _ = block(p, x, None, cfg, rope, default_attention)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jnp.sum(jax.vmap(client)(x))
+
+    p = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16 if a.ndim == 2 else a.dtype,
+            sharding=one_chip),
+        jax.eval_shape(lambda key: llama._block_init(key, cfg),
+                       jax.random.key(0)))
+    x = jax.ShapeDtypeStruct((clients, 1, length, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:  # ``default_attention`` asks the backend where it is traced
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            p, x).compile().as_text()
+    finally:
+        jax.default_backend = backend
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    assert len(kernels) == 2 and all("/attention/" in k for k in kernels)
+    assert sum("transpose(" in k for k in kernels) == 1
+    assert not any("rematted_computation" in k for k in kernels)
+    loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
+    assert loops and all("/ssd_scan/" in name for name in loops)
