@@ -39,8 +39,9 @@ kind (of mixer and of feed-forward), whatever the depth.
   every projection of the mixers and MLPs, and
   :func:`decoder_lora_model` for the two put together over a base held
   in ``param_dtype``);
-* the loss never holds ``[B, L, V]`` logits whole
-  (:func:`baton_tpu.models.transformer.next_token_loss`).
+* the loss never holds ``[B, L, V]`` logits whole, and over a head that
+  takes no gradient (a base under adapters) it makes ``dx`` in its
+  forward (:func:`baton_tpu.models.transformer.next_token_loss`).
 
 Batches: ``{"x": int32[B, L] inputs, "y": int32[B, L] next-token targets,
 "loss_mask"?: [B, L] 1.0 = token counts toward the loss}``. The
@@ -66,9 +67,10 @@ from baton_tpu.models.state_space import SSMConfig, mamba2_apply, mamba2_init
 from baton_tpu.models.transformer import (
     AttentionFn, CCAConfig, MLAConfig, Multipliers, attention_is_kernel,
     cca_apply, cca_core_is_kernel, cca_init, default_attention, dense_init,
-    matmul, mha_apply, mha_init, mla_apply, mla_core_is_kernel, mla_init,
-    mla_rope_angles, next_token_loss, normal_init, rms_init, rms_norm,
-    rope_angles, scaled, swiglu_apply, swiglu_init, tied_logits)
+    head_products_a_block, matmul, mha_apply, mha_init, mla_apply,
+    mla_core_is_kernel, mla_init, mla_rope_angles, next_token_loss,
+    normal_init, rms_init, rms_norm, rope_angles, scaled, swiglu_apply,
+    swiglu_init, tied_logits)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -531,6 +533,10 @@ def llama_lm_model(
             x, params["tok_emb" if cfg.tie_embeddings else "lm_head"],
             batch["y"], tied=cfg.tie_embeddings,
             multiplier=on.lm_head)  # [B, L]
+        # which side of the loss's gradient this trace took, where it
+        # took one: the rule has run by the time the call returns
+        if (products := head_products_a_block()) is not None:
+            seen["head_products_a_block"] = products
         loss_mask = batch.get("loss_mask")
         if loss_mask is None:
             return jnp.mean(tok_loss, axis=-1)

@@ -1076,6 +1076,18 @@ def matmul(x, w, preferred_element_type=None):
     return jnp.matmul(x, w, preferred_element_type=preferred_element_type)
 
 
+def _cross_entropy_parts(logits, labels):
+    """:func:`per_token_cross_entropy` with what it was made of: the
+    loss ``[B, L]``, the float32 logits, their log-sum-exp ``[B, L]``
+    and the mask ``[B, L, V]`` of each token's own id."""
+    logits = logits.astype(jnp.float32)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    own = ids == labels[..., None]
+    ll = jnp.sum(jnp.where(own, logits, 0.0), axis=-1)
+    return logz - ll, logits, logz, own
+
+
 def per_token_cross_entropy(logits, labels):
     """logits [B, L, V], labels int32 [B, L] -> fp32 [B, L].
 
@@ -1087,11 +1099,7 @@ def per_token_cross_entropy(logits, labels):
     the softmax's gradient. A label outside ``[0, V)`` matches no id,
     so its logit reads 0 and the loss is the log-sum-exp alone (the
     gather filled with NaN past the end and wrapped a negative one)."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
-    ll = jnp.sum(jnp.where(ids == labels[..., None], logits, 0.0), axis=-1)
-    return logz - ll
+    return _cross_entropy_parts(logits, labels)[0]
 
 
 # the float32 logits the head and the loss hold at a time: at a
@@ -1108,6 +1116,20 @@ def tied_logits(x, table):
                       preferred_element_type=jnp.float32)
 
 
+# what the last call of ``next_token_loss`` was traced as: the products
+# over a block's logits that its gradient makes, 2 where the head took
+# no gradient, 3 where it did, None where no gradient of a blocked loss
+# was traced (one block, or values alone)
+_LOSS_TRACED = {"head_products": None}
+
+
+def head_products_a_block():
+    """What the last :func:`next_token_loss` traced learned of its
+    gradient (a model's ``span_attrs`` read it straight after their
+    call): 2, 3 or None."""
+    return _LOSS_TRACED["head_products"]
+
+
 @jax.named_scope("lm_loss")
 def next_token_loss(x, w_head, labels, tied: bool = False,
                     multiplier: float = 1.0):
@@ -1118,29 +1140,87 @@ def next_token_loss(x, w_head, labels, tied: bool = False,
     :func:`per_token_cross_entropy` gives for the whole ``[B, L, V]``
     logits, computed in blocks of tokens: a ``lax.scan`` over the
     fewest equal blocks whose logits stay under
-    ``_LOGITS_BLOCK_BYTES``, each under ``jax.checkpoint`` so the
-    backward recomputes a block's logits instead of keeping every
-    block's. One block (a small vocabulary) is the plain computation.
-    ``labels`` are ids in ``[0, V)`` (one outside reads a logit of 0:
-    :func:`per_token_cross_entropy`); the tail block's padding carries
-    label 0, which is in range."""
-    b, l, d = x.shape
+    ``_LOGITS_BLOCK_BYTES``. One block (a small vocabulary) is the
+    plain computation. ``labels`` are ids in ``[0, V)`` (one outside
+    reads a logit of 0: :func:`per_token_cross_entropy`); the tail
+    block's padding carries label 0, which is in range.
 
-    def block(xb, yb):
-        return per_token_cross_entropy(scaled(
-            tied_logits(xb, w_head) if tied
-            else matmul(xb, w_head, jnp.float32), multiplier), yb)
+    The gradient is a ``custom_vjp`` that looks at one thing, whether
+    ``w_head`` is being differentiated. Where it is not (a frozen head
+    under adapters), a token's loss depends on its own row of ``x``
+    alone, so ``dx`` is the cotangent ``g [B, L]`` times a row that
+    needs nothing of the backward: the forward's scan makes ``(softmax
+    - onehot) @ w_head^T`` beside each block's logits and keeps it, one
+    float32 array of ``x``'s shape, and the backward is that array times
+    ``g``: two products over a block's logits and one log-sum-exp, no
+    block made again. Where the head takes a gradient, rows with
+    different ``g`` mix in it: each block is under ``jax.checkpoint``
+    and the backward recomputes its logits instead of keeping every
+    block's, three products."""
+    b, l, d = x.shape
+    _LOSS_TRACED["head_products"] = None
+
+    def logits(xb, w):
+        return scaled(tied_logits(xb, w) if tied
+                      else matmul(xb, w, jnp.float32), multiplier)
+
+    def block(xb, yb, w, slope=False):
+        tok, z, logz, own = _cross_entropy_parts(logits(xb, w), yb)
+        if not slope:
+            return tok
+        # the loss's derivative by the logits, in the stream's dtype as
+        # the head's other operands are, back through the head
+        p = jnp.exp(z - logz[..., None])
+        p = jnp.where(own, p - 1.0, p).astype(xb.dtype)
+        back = jnp.einsum("...v,vd->...d" if tied else "...v,dv->...d", p,
+                          w.astype(xb.dtype),
+                          preferred_element_type=jnp.float32)
+        return tok, back * multiplier
 
     vocab = w_head.shape[0 if tied else 1]
     n = min(l, -(-(4 * b * l * vocab) // _LOGITS_BLOCK_BYTES))
     if n <= 1:
-        return block(x, labels)
+        return block(x, labels, w_head)
     t = -(-l // n)
-    if n * t != l:  # the tail block's padding is cut off again below
-        x = jnp.pad(x, ((0, 0), (0, n * t - l), (0, 0)))
-        labels = jnp.pad(labels, ((0, 0), (0, n * t - l)))
-    xs = jnp.moveaxis(x.reshape(b, n, t, d), 1, 0)
-    ys = jnp.moveaxis(labels.reshape(b, n, t), 1, 0)
-    _, tok = jax.lax.scan(
-        lambda _, xy: (None, jax.checkpoint(block)(*xy)), None, (xs, ys))
-    return jnp.moveaxis(tok, 0, 1).reshape(b, n * t)[:, :l]
+
+    def blocks(a):  # [B, L, ...] -> [n, B, t, ...], the tail padded
+        if n * t != l:
+            a = jnp.pad(a, ((0, 0), (0, n * t - l)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape(b, n, t, *a.shape[2:]), 1, 0)
+
+    def whole(a):  # and back, the padding cut off again
+        return jnp.moveaxis(a, 0, 1).reshape(b, n * t, *a.shape[3:])[:, :l]
+
+    def over_blocks(fn, xs, w, ys):
+        _, out = jax.lax.scan(lambda _, xy: (None, fn(*xy, w)), None,
+                              (blocks(xs), blocks(ys)))
+        return jax.tree.map(whole, out)
+
+    def primal(xs, w, ys):
+        return over_blocks(jax.checkpoint(block), xs, w, ys)
+
+    def fwd(xs, w, ys):
+        takes_gradient = any(a.perturbed for a in jax.tree.leaves(w))
+        xs, w, ys = jax.tree.map(lambda a: a.value, (xs, w, ys))
+        # a weight that applies itself offers no product back to the
+        # stream: the checkpointed blocks differentiate it as it is
+        if takes_gradient or hasattr(w, "apply_to"):
+            _LOSS_TRACED["head_products"] = 3
+            tok, pull = jax.vjp(lambda xs, w: primal(xs, w, ys), xs, w)
+            return tok, (None, pull)
+        _LOSS_TRACED["head_products"] = 2
+        tok, back = over_blocks(
+            functools.partial(block, slope=True), xs, w, ys)
+        return tok, (back, None)
+
+    def bwd(kept, g):
+        back, pull = kept
+        if isinstance(g, jax.custom_derivatives.SymbolicZero):
+            return None, None, None
+        if pull is not None:
+            return (*pull(g), None)
+        return (g[..., None] * back).astype(x.dtype), None, None
+
+    loss = jax.custom_vjp(primal)
+    loss.defvjp(fwd, bwd, symbolic_zeros=True)
+    return loss(x, w_head, labels)

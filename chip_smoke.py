@@ -133,6 +133,40 @@ def _plain_core():
         transformer.core_runs_the_kernel = kernel
 
 
+def _lora_rounds(sim, vocab: int, params, data, n_samples,
+                 block_tokens: int):
+    """Two rounds of a decoder under adapters through ``sim``, the loss
+    falling: ``(losses, the parameters after, head_products_a_block)``.
+    The rounds are traced with the loss's budget cut to ``block_tokens``
+    tokens of a one-row batch, so that a phase's small vocabulary takes
+    ``next_token_loss`` in blocks as a cell's 65,000 ids do (one block
+    is the plain computation, and its ``custom_vjp`` never runs). The
+    base holds the head, which takes no gradient: the forward makes the
+    gradient's row beside a block's logits and nothing makes the logits
+    again, 2 products a block, which the model says where it is traced."""
+    from unittest import mock
+
+    import jax
+    import numpy as np
+
+    from baton_tpu.models import transformer
+
+    losses, p = [], params
+    with mock.patch.object(transformer, "_LOGITS_BLOCK_BYTES",
+                           4 * block_tokens * vocab):
+        for i in range(2):
+            res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
+                                n_epochs=1, collect_client_losses=False)
+            losses.append(float(res.loss_history[-1]))
+            p = res.params
+    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
+    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    said = dict(sim.model.span_attrs).get("head_products_a_block")
+    _check(said == 2, f"the blocked loss over a frozen head says {said} "
+           f"products a block: wanted 2")
+    return losses, p, said
+
+
 def _core_kernels(text: str, model, scope: str, layers: int,
                   rehearsal: bool) -> int:
     """The Pallas kernels under ``scope`` in ``text``, the compiled wave
@@ -326,14 +360,8 @@ def phase_hybrid_lora(env: Env) -> None:
     n_samples = np.asarray([2, 2, 2, 1], np.int32)
     sim = FedSim(model, batch_size=1, learning_rate=0.05,
                  trainable=lora_trainable)
-    losses, p = [], params
-    for i in range(2):
-        res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
-                            n_epochs=1, collect_client_losses=False)
-        losses.append(float(res.loss_history[-1]))
-        p = res.params
-    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
-    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    losses, p, head_products = _lora_rounds(
+        sim, cfg.vocab_size, params, data, n_samples, 64)
     base = list(zip(jax.tree_util.tree_leaves(params["base"]),
                     jax.tree_util.tree_leaves(p["base"])))
     _check(all(a is b for a, b in base),
@@ -382,7 +410,8 @@ def phase_hybrid_lora(env: Env) -> None:
             f"{model.name}: 3 gated delta-rule layers + 1 full attention, "
             f"bf16 over a frozen bf16 base, {len(moved)} adapter factors on "
             f"activations, 4 clients x 160 tokens (chunks of 64), 2 rounds, "
-            f"loss {losses[0]:.4f} -> {losses[1]:.4f}; {len(base)} base "
+            f"loss {losses[0]:.4f} -> {losses[1]:.4f} (in 3 blocks, "
+            f"{head_products} products a block); {len(base)} base "
             f"leaves handed back as the arrays they were; {a.shape} chunk "
             f"inverses on correlated keys within {inverse_gap:.1e} of "
             f"triangular_solve's, T rhs within {solved_gap:.1e}")
@@ -449,14 +478,8 @@ def phase_moe_mla_lora(env: Env) -> None:
     n_samples = np.asarray([2, 2, 2, 2], np.int32)
     sim = FedSim(model, batch_size=1, learning_rate=0.05,
                  trainable=lora_trainable)
-    losses, p = [], params
-    for i in range(2):
-        res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
-                            n_epochs=1, collect_client_losses=False)
-        losses.append(float(res.loss_history[-1]))
-        p = res.params
-    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
-    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    losses, p, head_products = _lora_rounds(
+        sim, cfg.vocab_size, params, data, n_samples, 64)
     base = list(zip(jax.tree_util.tree_leaves(params["base"]),
                     jax.tree_util.tree_leaves(p["base"])))
     _check(all(a is b for a, b in base),
@@ -577,7 +600,8 @@ def phase_moe_mla_lora(env: Env) -> None:
             f"{model.name}: latent attention, a dense layer and 2 expert "
             f"layers holding 3 of 8, bf16 over a frozen bf16 base, 4 clients "
             f"x 128 tokens, 2 rounds, loss {losses[0]:.4f} -> "
-            f"{losses[1]:.4f}; {len(base)} base leaves handed back as the "
+            f"{losses[1]:.4f} (in 2 blocks, {head_products} products a "
+            f"block); {len(base)} base leaves handed back as the "
             f"arrays they were; {kernels} Pallas calls in the wave program; "
             f"with heads of 192 / 128 at {long.shape[-1] - 1} tokens "
             f"{core_kernels} kernels under mla_core in a step of "
@@ -784,14 +808,8 @@ def phase_cca_lora(env: Env) -> None:
     n_samples = np.asarray([1, 1], np.int32)
     sim = FedSim(model, batch_size=1, learning_rate=0.05,
                  trainable=lora_trainable)
-    losses, p = [], params
-    for i in range(2):
-        res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
-                            n_epochs=1, collect_client_losses=False)
-        losses.append(float(res.loss_history[-1]))
-        p = res.params
-    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
-    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    losses, p, head_products = _lora_rounds(
+        sim, cfg.vocab_size, params, data, n_samples, length // 4)
     _check(all(a is b for a, b in zip(
         jax.tree_util.tree_leaves(params["base"]),
         jax.tree_util.tree_leaves(p["base"]))),
@@ -881,7 +899,8 @@ def phase_cca_lora(env: Env) -> None:
     env.say("cca_lora",
             f"two blocks of 4 on 2 heads of 128, 4 experts and the skip, "
             f"bfloat16 base, {length} tokens: losses {losses[0]:.4f} -> "
-            f"{losses[1]:.4f}, {kernels} Pallas calls in the wave program, "
+            f"{losses[1]:.4f} (in 4 blocks, {head_products} products a "
+            f"block), {kernels} Pallas calls in the wave program, "
             f"{core_kernels} of them under cca_core, the "
             f"bfloat16 mixer {err:.4f} from the float32 one (of its largest "
             f"entry). zaya1_8b at {'tiny' if tiny else 'the published'} "
@@ -943,14 +962,8 @@ def phase_ssm_lora(env: Env) -> None:
     n_samples = np.asarray([1, 1], np.int32)
     sim = FedSim(model, batch_size=1, learning_rate=0.05,
                  trainable=lora_trainable)
-    losses, p = [], params
-    for i in range(2):
-        res = sim.run_round(p, data, n_samples, jax.random.key(2 + i),
-                            n_epochs=1, collect_client_losses=False)
-        losses.append(float(res.loss_history[-1]))
-        p = res.params
-    _check(all(np.isfinite(losses)), f"non-finite loss in {losses}")
-    _check(losses[1] < losses[0], f"loss did not fall: {losses}")
+    losses, p, head_products = _lora_rounds(
+        sim, cfg.vocab_size, params, data, n_samples, -(-length // 3))
     _check(all(a is b for a, b in zip(
         jax.tree_util.tree_leaves(params["base"]),
         jax.tree_util.tree_leaves(p["base"]))),
@@ -984,7 +997,8 @@ def phase_ssm_lora(env: Env) -> None:
             f"state 256, {chunks} chunks of {ssm.chunk}) beside 5 query "
             f"heads on 1 key-value head of 128, Falcon-H1-34B's multipliers, "
             f"bf16 over a frozen bf16 base, 2 clients x {length} tokens, 2 "
-            f"rounds, loss {losses[0]:.4f} -> {losses[1]:.4f}; "
+            f"rounds, loss {losses[0]:.4f} -> {losses[1]:.4f} (in 3 blocks "
+            f"with a padded tail, {head_products} products a block); "
             f"{len(moved)} adapter factors moved; {core_kernels} Pallas "
             f"calls under attention in the wave program; the bfloat16 "
             f"branch {err:.4f} from the float32 one (of its largest entry)")

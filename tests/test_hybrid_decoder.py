@@ -571,7 +571,7 @@ def test_block_count_follows_the_shapes():
         x, w, jnp.zeros((1, 1024), jnp.int32)))(
             jax.ShapeDtypeStruct((1, 1024, 64), jnp.bfloat16),
             jax.ShapeDtypeStruct((64, 100352), jnp.bfloat16))
-    scans = [e for e in big.jaxpr.eqns if e.primitive.name == "scan"]
+    scans = [e for e in _equations(big.jaxpr) if e.primitive.name == "scan"]
     assert len(scans) == 1 and scans[0].params["length"] == 4
 
 
@@ -631,6 +631,157 @@ def test_the_losss_gradient_lowers_without_a_scatter(tied, scanned,
     assert ("scan" in str(jax.make_jaxpr(loss)(*args))) == scanned
     lowered = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).as_text()
     assert "dot_general" in lowered and "scatter" not in lowered
+
+
+def _eqns(jaxpr, name):
+    return [e for e in _equations(jaxpr) if e.primitive.name == name]
+
+
+@pytest.mark.parametrize("under_vmap", [False, True],
+                         ids=["plain", "clients_under_vmap"])
+@pytest.mark.parametrize("length,blocks", [(12, 1), (13, 4)],
+                         ids=["one_block", "blocks_with_a_padded_tail"])
+@pytest.mark.parametrize("head", ["frozen", "differentiated"])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_the_losss_gradient_on_either_side_is_the_unblocked_losss(
+        tied, head, length, blocks, under_vmap, monkeypatch):
+    """Values, ``dx`` and (where the head takes a gradient) ``dW``
+    against ``per_token_cross_entropy(x @ w, y)`` at ``highest``, under
+    a per-token weight as a ``loss_mask`` gives and a multiplier on the
+    logits; a label outside ``[0, V)`` reads a logit of 0 on both
+    sides of the gradient too."""
+    c, b, d, v, multiplier = 3, 2, 16, 50, 1.5
+    kx, kw, ky, kg = jax.random.split(jax.random.key(length), 4)
+    x = jax.random.normal(kx, (c, b, length, d))
+    w = jax.random.normal(kw, (v, d) if tied else (d, v)) * d ** -0.5
+    y = jax.random.randint(ky, (c, b, length), 0, v)
+    y = y.at[0, 0, 0].set(v).at[-1, -1, -1].set(-1)
+    weight = jax.random.uniform(kg, (c, b, length)) * (
+        jnp.arange(length) % 3 != 1)
+    if not under_vmap:
+        x, y, weight = x[0], y[0], weight[0]
+    monkeypatch.setattr(transformer, "_LOGITS_BLOCK_BYTES",
+                        4 * b * 4 * v if blocks > 1 else 2 ** 40)
+
+    def unblocked(x, w, y):
+        return per_token_cross_entropy(
+            multiplier * (x @ (w.T if tied else w)), y)
+
+    def blocked(x, w, y):
+        return next_token_loss(x, w, y, tied=tied, multiplier=multiplier)
+
+    def through(fn):
+        over = (jax.vmap(fn, in_axes=(0, None, 0)) if under_vmap else fn)
+        return jax.value_and_grad(
+            lambda x, w: jnp.sum(over(x, w, y) * weight),
+            argnums=(0, 1) if head == "differentiated" else 0)(x, w)
+
+    x1, y1 = (x[0], y[0]) if under_vmap else (x, y)  # one client's
+    with jax.default_matmul_precision("highest"):
+        assert ("scan" in str(jax.make_jaxpr(blocked)(x1, w, y1))) == (
+            blocks > 1)
+        want, want_g = through(unblocked)
+        got, got_g = through(blocked)
+        tok = blocked(x1, w, y1)
+        logz = jax.nn.logsumexp(
+            multiplier * (x1 @ (w.T if tied else w)), axis=-1)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for g, wg in zip(jax.tree_util.tree_leaves(got_g),
+                     jax.tree_util.tree_leaves(want_g)):
+        assert g.shape == wg.shape and g.dtype == wg.dtype
+        _close(g, wg, rtol=1e-5)
+    assert float(tok[0, 0]) == pytest.approx(float(logz[0, 0]), rel=1e-6)
+
+
+@pytest.mark.parametrize("under_vmap", [False, True],
+                         ids=["plain", "clients_under_vmap"])
+@pytest.mark.parametrize("head,dots,said", [("frozen", 2, 2),
+                                            ("differentiated", 4, 3)])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_a_frozen_heads_gradient_is_made_in_the_forward(tied, head, dots, said,
+                                                        under_vmap,
+                                                        monkeypatch):
+    """With the head closed over, the gradient of the blocked loss is
+    one scan of two products a block, the logits and ``(softmax -
+    onehot)`` back through the head, and a multiply by the cotangent;
+    with the head differentiated the blocks are checkpointed as they
+    were: the logits, the logits again and ``dx``, and ``dW`` the
+    fourth. ``head_products_a_block`` says which was traced, and
+    nothing after a call that traced no gradient of a blocked loss."""
+    c, b, l, d, v = 3, 2, 12, 16, 50
+    monkeypatch.setattr(transformer, "_LOGITS_BLOCK_BYTES", 4 * b * 4 * v)
+    y = jnp.zeros((c, b, l), jnp.int32)
+    x, w = jnp.zeros((c, b, l, d)), jnp.zeros((v, d) if tied else (d, v))
+
+    def client(x, w, y):
+        return jnp.sum(next_token_loss(x, w, y, tied=tied))
+
+    def loss(x, w):
+        if under_vmap:
+            return jnp.sum(jax.vmap(client, in_axes=(0, None, 0))(x, w, y))
+        return client(x[0], w, y[0])
+
+    if head == "frozen":
+        grad = jax.grad(lambda x: loss(x, w))
+        jaxpr = jax.make_jaxpr(grad)(x).jaxpr
+    else:
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, w).jaxpr
+    assert transformer.head_products_a_block() == said
+    assert len(_eqns(jaxpr, "dot_general")) == dots
+    assert len(_eqns(jaxpr, "scan")) == (1 if head == "frozen" else 2)
+    assert bool(_eqns(jaxpr, "remat2")) == (head != "frozen")
+    if head == "frozen":  # the scan keeps one array of the stream's shape
+        scan, = _eqns(jaxpr, "scan")
+        shapes = [a.aval.shape for a in scan.outvars]
+        lead = (3, c) if under_vmap else (3,)
+        assert sorted(shapes) == sorted([lead + (b, 4), lead + (b, 4, d)])
+    jax.make_jaxpr(lambda x: loss(x, w))(x)  # values alone
+    assert transformer.head_products_a_block() is None
+    monkeypatch.setattr(transformer, "_LOGITS_BLOCK_BYTES", 2 ** 40)
+    jax.make_jaxpr(jax.grad(lambda x: loss(x, w)))(x)  # one block
+    assert transformer.head_products_a_block() is None
+
+
+def test_a_loss_that_takes_no_cotangent_hands_none_back(monkeypatch):
+    """The blocked loss as an output nothing differentiates, beside one
+    that is: the stream's gradient is the other output's alone."""
+    b, l, d, v = 2, 12, 16, 50
+    monkeypatch.setattr(transformer, "_LOGITS_BLOCK_BYTES", 4 * b * 4 * v)
+    x = jax.random.normal(jax.random.key(0), (b, l, d))
+    w = jax.random.normal(jax.random.key(1), (d, v))
+    y = jnp.zeros((b, l), jnp.int32)
+    g, tok = jax.grad(lambda x: (jnp.sum(x), next_token_loss(x, w, y)),
+                      has_aux=True)(x)
+    np.testing.assert_array_equal(np.asarray(g), np.ones((b, l, d)))
+    _close(tok, per_token_cross_entropy(x @ w, y), rtol=1e-5)
+
+
+@pytest.mark.parametrize("head,said", [("frozen", 2), ("trained", 3)])
+def test_a_decoder_says_which_side_its_loss_was_traced_on(head, said,
+                                                          monkeypatch):
+    """``span_attrs`` gains ``head_products_a_block`` where a gradient
+    of the blocked loss is traced: 2 under adapters, whose base holds
+    the head, 3 where the whole model trains; a model whose loss is one
+    block says nothing."""
+    cfg = _hybrid(n_layers=2)
+    model = (decoder_lora_model(cfg, rank=2, b_std=0.02) if head == "frozen"
+             else llama_lm_model(cfg))
+    params = model.init(jax.random.key(0))
+    ids = jax.random.randint(jax.random.key(1), (2, 13), 0, cfg.vocab_size)
+    batch = {"x": ids[:, :-1], "y": ids[:, 1:]}
+
+    def loss(trained, held):
+        p = {**held, **trained} if held else trained
+        return jnp.sum(model.per_example_loss(p, batch, None))
+
+    trained, held = (({"lora": params["lora"]}, {"base": params["base"]})
+                     if head == "frozen" else (params, None))
+    jax.make_jaxpr(jax.grad(loss))(trained, held)
+    assert "head_products_a_block" not in dict(model.span_attrs)
+    monkeypatch.setattr(transformer, "_LOGITS_BLOCK_BYTES",
+                        4 * 2 * 4 * cfg.vocab_size)
+    jax.make_jaxpr(jax.grad(loss))(trained, held)
+    assert dict(model.span_attrs)["head_products_a_block"] == said
 
 
 def test_gated_delta_init_draws_the_gates_as_the_papers_code_does():

@@ -43,10 +43,14 @@ def one_chip(topo):
 
 def test_the_tied_losss_backward_is_the_scan_alone(one_chip):
     """Two blocks of ``zaya1_c4_l8192``'s loss, four clients under
-    ``vmap``: 482 tokens are no multiple of 8 and 65,568 ids none of
-    128, so a scatter into a block's cotangent (the transpose of a
-    gathered label logit) runs on a flat ``f32[126415104]`` copy that
-    two more ``while`` loops fill and read back."""
+    ``vmap``, value and gradient as a training step takes them: 482
+    tokens are no multiple of 8 and 65,568 ids none of 128, so a
+    scatter into a block's cotangent (the transpose of a gathered label
+    logit) runs on a flat ``f32[126415104]`` copy that two more
+    ``while`` loops fill and read back. The table takes no gradient, so
+    the one loop is the forward's and holds two products over a block's
+    ``[4, 482, 65568]``, the logits and ``(softmax - onehot)`` back
+    through the table: no second loop makes the logits again."""
     clients, length, d, vocab = 4, 964, 2048, 65568
 
     def loss(x, table, y):
@@ -56,7 +60,7 @@ def test_the_tied_losss_backward_is_the_scan_alone(one_chip):
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    text = jax.jit(jax.grad(loss)).lower(
+    text = jax.jit(jax.value_and_grad(loss)).lower(
         shaped((clients, 1, length, d), jnp.bfloat16),
         shaped((vocab, d), jnp.bfloat16),
         shaped((clients, 1, length), jnp.int32)).compile().as_text()
@@ -65,6 +69,9 @@ def test_the_tied_losss_backward_is_the_scan_alone(one_chip):
     assert len(re.findall(r" while\(", text)) == 1
     assert " scatter(" not in text
     assert f"[{block}]" not in text
+    products = re.findall(r" = (\S+?)\{[^ ]* convolution\(", text)
+    assert sorted(products) == [f"f32[{clients},{length // 2},{d}]",
+                                f"f32[{clients},{length // 2},{vocab}]"]
 
 
 @pytest.mark.parametrize("kept,forwards", [(True, 1), (False, 2)],
