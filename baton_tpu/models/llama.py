@@ -68,9 +68,9 @@ from baton_tpu.models.transformer import (
     AttentionFn, CCAConfig, MLAConfig, Multipliers, attention_is_kernel,
     cca_apply, cca_core_is_kernel, cca_init, default_attention, dense_init,
     head_products_a_block, matmul, mha_apply, mha_init, mla_apply,
-    mla_core_is_kernel, mla_init, mla_rope_angles, next_token_loss,
-    normal_init, rms_init, rms_norm, rope_angles, scaled, swiglu_apply,
-    swiglu_init, tied_logits)
+    mla_core_is_kernel, mla_init, mla_qk_layout, mla_rope_angles,
+    next_token_loss, normal_init, rms_init, rms_norm, rope_angles, scaled,
+    swiglu_apply, swiglu_init, tied_logits)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -256,7 +256,10 @@ MIXERS = {
         # where queries choose their keys: ``transformer._choosing_mla``
         keeps_its_inputs=lambda cfg, length: cfg.mla.selects(length),
         core_is_kernel=lambda cfg, backend, batch, length, fn:
-        mla_core_is_kernel(cfg.mla, backend, length)),
+        mla_core_is_kernel(cfg.mla, backend, length),
+        # how the mixer laid out the queries and keys it made
+        seen=lambda cfg, length: {"mla_qk_layout": mla_qk_layout(
+            cfg.mla, jax.default_backend(), length)}),
     "compressed_attention": Mixer(
         key="cca",
         init=lambda rng, cfg, out_std: cca_init(

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from baton_tpu.core.model import FedModel
 from baton_tpu.core.partition import path_str
+from baton_tpu.models.transformer import contract_heads, cut_columns
 
 TargetPredicate = Callable[[str, Any], bool]
 
@@ -78,14 +79,49 @@ class Adapted:
     def astype(self, dtype):
         return Adapted(self.w.astype(dtype), self.a, self.b, self.scale)
 
-    def apply_to(self, x, preferred_element_type=None):
-        y = jnp.matmul(x, self.w,
-                       preferred_element_type=preferred_element_type)
+    def low_rank(self, x):
+        """``x A``: what every column of ``x @ adapted`` shares."""
         with jax.named_scope("adapter"):
-            low = jnp.matmul(x, self.a.astype(x.dtype))
-            return y + self.scale * jnp.matmul(
+            return jnp.matmul(x, self.a.astype(x.dtype))
+
+    def apply_to(self, x, preferred_element_type=None, low=None,
+                 product=jnp.matmul):
+        """``x @ adapted``; ``low`` is :meth:`low_rank` of ``x`` where
+        the caller has it already (the parts of :meth:`columns` share
+        one), ``product`` what makes both products (a caller that wants
+        ``x @ W`` in another layout says how, and the adapter's term
+        comes out beside it in the same)."""
+        y = product(x, self.w,
+                    preferred_element_type=preferred_element_type)
+        if low is None:
+            low = self.low_rank(x)
+        with jax.named_scope("adapter"):
+            return y + self.scale * product(
                 low, self.b.astype(x.dtype),
                 preferred_element_type=preferred_element_type)
+
+    def columns(self, n_heads: int, widths, made=None):
+        """The weight's columns seen as ``[in, n_heads, sum(widths)]``
+        and cut along the last axis into parts of ``widths``
+        (:func:`~baton_tpu.models.transformer.cut_columns`, with its
+        ``made``): an ``Adapted [in, n_heads * width]`` a part, ``W``
+        and ``B`` cut alike, ``A`` and the scale shared. ``x @ part``
+        is the same columns of ``x @ whole`` bit for bit (dropping
+        output columns reorders no contraction), so a model cuts a
+        frozen weight, which costs nothing a token, where it would cut
+        an activation."""
+        return [Adapted(w, self.a, b, self.scale)
+                for w, b in zip(cut_columns(self.w, n_heads, widths, made),
+                                cut_columns(self.b, n_heads, widths, made))]
+
+    def heads_apply_to(self, x, n_heads: int):
+        """``x [B, H, L, v]`` through the weight's rows seen as ``[H, v,
+        out]``: ``[B, L, out]``, the contraction over ``(head,
+        channel)`` where ``x`` lies, ``A``'s rows seen the same way."""
+        y = contract_heads(x, self.w, n_heads)
+        with jax.named_scope("adapter"):
+            low = contract_heads(x, self.a.astype(x.dtype), n_heads)
+            return y + self.scale * jnp.matmul(low, self.b.astype(x.dtype))
 
     def __rmatmul__(self, x):
         return self.apply_to(x)

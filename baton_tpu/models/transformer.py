@@ -31,6 +31,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.custom_batching import custom_vmap
+from jax.experimental.layout import Layout, with_layout_constraint
 
 # attention_fn(q, k, v, bias, causal) -> out
 #   q [B, Hq, L, Dh], k/v [B, Hkv, L, Dh], bias None or [B, 1, 1, L] additive
@@ -332,7 +333,14 @@ class MLAConfig:
     normalised latent of their own; with ``indexer`` a query attends
     the keys a learned index chose for it (:func:`index_scores`,
     :func:`select_keys`), one set a query for all heads. Neither is in
-    the parameters or the program of a configuration that names none."""
+    the parameters or the program of a configuration that names none.
+
+    The widths decide how the mixer lays out what it makes
+    (:func:`mla_qk_layout`): ``nope_dim + rope_dim`` of 192 rides the
+    flash kernel's sublanes, so q and k are made ``[B, H, 192, L]``;
+    256 is whole lane tiles and they are made ``[B, H, L, 256]``, the
+    rotary 64 in lanes 192 to 255. The projections' weights are cut at
+    ``nope_dim`` a head (:func:`cut_columns`), never their outputs."""
 
     kv_rank: int = 512
     nope_dim: int = 128
@@ -701,41 +709,208 @@ def _a_client_at_a_time(fn):
     return wrapped
 
 
+def cut_columns(w, n_heads: int, widths, made=None):
+    """``w [in, n_heads * sum(widths)]`` seen as ``[in, n_heads,
+    sum(widths)]`` and cut along the last axis: ``[in, n_heads *
+    width]`` a part, or ``n_heads * made[i]`` where ``made`` names a
+    wider part: zero columns after each head's own."""
+    heads = w.reshape(w.shape[0], n_heads, sum(widths))
+    edges = [sum(widths[:i]) for i in range(len(widths) + 1)]
+    parts = []
+    for start, width, wide in zip(edges, widths, made or widths):
+        part = heads[..., start:start + width]
+        if wide != width:
+            part = jnp.pad(part, ((0, 0), (0, 0), (0, wide - width)))
+        parts.append(part.reshape(w.shape[0], -1))
+    return parts
+
+
+def contract_heads(x, w, n_heads: int):
+    """``x [B, H, L, v]`` times ``w [H * v, n]`` seen as ``[H, v, n]``:
+    ``[B, L, n]``, contracted over ``(head, channel)`` where ``x``
+    lies. A weight that applies itself (``lora.py::Adapted``) does."""
+    apply_to = getattr(w, "heads_apply_to", None)
+    if apply_to is not None:
+        return apply_to(x, n_heads)
+    return jnp.einsum("bhlv,hvn->bln", x,
+                      w.reshape(n_heads, -1, w.shape[-1]))
+
+
+def _projected_parts(x, w, n_heads: int, widths, sequence_minor=(),
+                     made=None):
+    """``x [B, L, in]`` through the parts of ``w``'s columns
+    (:func:`cut_columns`, with its ``made``; a weight that applies
+    itself cuts itself and makes its low-rank product once for all
+    parts: ``lora.py::Adapted.columns``), a product a part: ``[B, H,
+    L, width]``, or for a part that ``sequence_minor`` names ``[B, H,
+    width, L]``, the sequence minor in memory too. The weight is cut,
+    never the activation: a part comes out of its product as its
+    consumer reads it, and an adapter's term beside it in the same
+    layout."""
+    w = w.astype(x.dtype)
+
+    def into(transposed: bool):
+        def product(x, part, preferred_element_type=None):
+            y = jnp.einsum("bld,dhk->bhkl" if transposed else "bld,dhk->bhlk",
+                           x, part.reshape(part.shape[0], n_heads, -1),
+                           preferred_element_type=preferred_element_type)
+            # XLA's layout assignment does not carry the kernel's
+            # layout back through the product on its own
+            return with_layout_constraint(
+                y, Layout(major_to_minor=(0, 1, 2, 3))) if transposed else y
+        return product
+
+    products = [into(i in sequence_minor) for i in range(len(widths))]
+    if hasattr(w, "columns"):
+        low = w.low_rank(x)
+        return [part.apply_to(x, low=low, product=product)
+                for part, product in zip(w.columns(n_heads, widths, made),
+                                         products)]
+    return [product(x, part) for part, product
+            in zip(cut_columns(w, n_heads, widths, made), products)]
+
+
+def mla_qk_layout(cfg: MLAConfig, backend: str, length: int) -> str:
+    """How :func:`_mla` lays out the queries and keys it makes:
+    ``"channel_major"``, ``[B, H, Dk, L]``, where its core is the flash
+    kernel and that kernel takes them so (a width that does not fill
+    its last lane tile, 192: ``flash_attention._keys_ride_sublanes``),
+    else ``"row_major"``, ``[B, H, L, Dk]``, as the kernel takes whole
+    lane tiles (256) and the plain blocked core anything."""
+    from baton_tpu.ops.flash_attention import _keys_ride_sublanes
+
+    return ("channel_major" if core_is_the_kernel(backend, length)
+            and _keys_ride_sublanes(cfg.qk_dim) else "row_major")
+
+
 def _mla(p, x, rope, choice, n_heads: int, cfg: MLAConfig, pre_norm=None):
     """The mixer itself: ``(y, choice)``. ``choice`` is ``(tau, cut)``
     of :func:`select_keys`, made here where it is None and the
-    sequence has a query that chooses (``cfg.selects``), else None."""
+    sequence has a query that chooses (``cfg.selects``), else None.
+
+    Each operand of the core is made once, by a product that writes
+    what the core reads (:func:`mla_qk_layout`: q and k ``[B, H, Dk,
+    L]`` where the kernel takes them with the sequence minor, else
+    ``[B, H, L, Dk]``; v ``[B, H, L, Dv]``):
+
+    * the frozen weights are cut, not the activations: ``wq`` (or
+      ``wq_b``), seen as ``[in, H, nope + rope]``, gives ``q_nope`` and
+      ``q_rope``, and ``wkv_b``, seen as ``[kv_rank, H, nope + v]``,
+      gives ``k_nope`` and ``v`` (:func:`_projected_parts`);
+    * the rotary parts are turned alone, always with the sequence
+      minor (``[B, H, rope, L]``: the rotation's halves are sublane
+      tiles and every pass is full across the lanes): ``q_rope``, and
+      the shared rotary key once, at ``[B, 1, rope, L]``, before it is
+      given to the heads. Under ``qk_norm`` a head's statistic is the
+      sum of its two parts' squares, and the head's scalar multiplies
+      the once-turned shared key (a scalar commutes with the rotation;
+      the norm's scale vector is applied before it);
+    * the matrix unit puts the turned rotary channels where they
+      belong: a product with the ``[rope, Dk]`` matrix that holds a one
+      at ``(i, nope + i)`` writes them into channels ``nope ...`` of an
+      array of q's or k's shape, exactly (one term a sum), in the
+      core's layout, and the nope part, padded and under ``qk_norm``
+      normed, is added in that product's epilogue: no concatenation,
+      no array whose minor dimension is a rotary half. Where nothing
+      stands between ``k_nope``'s product and k (no ``qk_norm``) that
+      product is ``Dk`` wide itself (zero columns of the weight) and
+      the shared key is added in its epilogue;
+    * ``wo`` reads the core's output where it lies
+      (:func:`contract_heads`)."""
     if pre_norm is not None:
         x = rms_normalize(x, pre_norm["scale"], cfg.norm_eps)
-    b, l, _ = x.shape
-    cos, sin = rope
+    l = x.shape[1]
+    nope, rope_dim = cfg.nope_dim, cfg.rope_dim
+    channel_major = mla_qk_layout(
+        cfg, jax.default_backend(), l) == "channel_major"
+    # the channels' axis of q and k in the core's layout
+    axis = -2 if channel_major else -1
+    cos, sin = (a.T for a in rope)  # [rope / 2, L]: against [.., rope, L]
 
-    def heads(y, width):
-        return y.reshape(b, l, n_heads, width).transpose(0, 2, 1, 3)
+    def widened(y):
+        """The nope part with zeros for the rotary channels."""
+        pad = [(0, 0)] * y.ndim
+        pad[axis] = (0, rope_dim)
+        return jnp.pad(y, pad)
 
+    def nope_scale(scale):
+        """A norm's scale vector against :func:`widened`'s array: its
+        nope entries, zeros for the rotary channels."""
+        scale = jnp.pad(scale[:nope], (0, rope_dim))
+        return scale[:, None] if channel_major else scale
+
+    def turned(y):
+        """:func:`apply_rope`'s rotation of a rotary part ``[.., rope,
+        L]``, in float32."""
+        y1, y2 = (half.astype(jnp.float32)
+                  for half in jnp.split(y, 2, axis=-2))
+        return jnp.concatenate([y1 * cos - y2 * sin, y2 * cos + y1 * sin],
+                               axis=-2)
+
+    def placed(y):
+        """``y [B, h, rope, L]`` as channels ``nope ...`` of an array
+        of the core's layout, zeros before them: exact on the matrix
+        unit (every sum has one term)."""
+        place = jnp.eye(rope_dim, cfg.qk_dim, k=nope, dtype=y.dtype)
+        return jnp.einsum(
+            "bhkl,kn->bhnl" if channel_major else "bhkl,kn->bhln", y, place,
+            precision=jax.lax.Precision.HIGHEST)
+
+    def squares(y, over):
+        return jnp.sum(y * y, axis=over, keepdims=True)
+
+    def head_scalars(y_nope, y_rope):
+        """The reciprocal root of each head's mean square over both its
+        parts, against the nope part in the core's layout and against
+        the rotary part ``[B, h, 1, L]``."""
+        nope_squares = squares(y_nope.astype(jnp.float32), axis)
+        if not channel_major:
+            nope_squares = nope_squares.swapaxes(2, 3)
+        r = jax.lax.rsqrt(
+            (nope_squares + squares(y_rope.astype(jnp.float32), -2))
+            / cfg.qk_dim + cfg.norm_eps)
+        return (r if channel_major else r.swapaxes(2, 3)), r
+
+    # the parts made with the sequence minor: the rotary ones always
+    minor = (0, 1) if channel_major else (1,)
     if cfg.q_rank is None:
-        q_in = x
-        q = heads(x @ p["wq"].astype(x.dtype), cfg.qk_dim)
+        q_in, wq = x, p["wq"]
     else:
         q_in = rms_normalize(x @ p["wq_a"].astype(x.dtype),
                              p["q_a_norm"]["scale"], cfg.norm_eps)
-        q = heads(q_in @ p["wq_b"].astype(x.dtype), cfg.qk_dim)
+        wq = p["wq_b"]
+    q_nope, q_rope = _projected_parts(q_in, wq, n_heads, (nope, rope_dim),
+                                      minor)
     c = x @ p["wkv_a"].astype(x.dtype)
     latent = rms_normalize(c[..., :cfg.kv_rank], p["kv_norm"]["scale"],
                            cfg.norm_eps)
-    kv = heads(latent @ p["wkv_b"].astype(x.dtype), cfg.nope_dim + cfg.v_dim)
-    k_rope = jnp.broadcast_to(c[:, None, :, cfg.kv_rank:],
-                              (b, n_heads, l, cfg.rope_dim))
-    k = jnp.concatenate([kv[..., :cfg.nope_dim], k_rope], axis=-1)
-    v = kv[..., cfg.nope_dim:]
+    # the shared rotary key, [B, 1, rope, L]
+    k_rope = c[:, None, :, cfg.kv_rank:].swapaxes(2, 3).astype(jnp.float32)
     if cfg.qk_norm:
-        q = rms_normalize(q, p["q_norm"]["scale"], cfg.norm_eps)
-        k = rms_normalize(k, p["k_norm"]["scale"], cfg.norm_eps)
-
-    def rotated(y):
-        return jnp.concatenate(
-            [y[..., :cfg.nope_dim],
-             apply_rope(y[..., cfg.nope_dim:], cos, sin)], axis=-1)
+        # the values are the core's row-major operand in either layout
+        k_nope, v = _projected_parts(latent, p["wkv_b"], n_heads,
+                                     (nope, cfg.v_dim), minor[:-1])
+        q_scale, k_scale = p["q_norm"]["scale"], p["k_norm"]["scale"]
+        r, r_rope = head_scalars(q_nope, q_rope)
+        # (the normed rotary part is rounded before it is turned, as
+        # the normed query was)
+        q_rope = (q_rope.astype(jnp.float32) * r_rope
+                  * q_scale[nope:, None]).astype(x.dtype)
+        q = (widened(q_nope).astype(jnp.float32) * r
+             * nope_scale(q_scale)).astype(x.dtype) \
+            + placed(turned(q_rope).astype(x.dtype))
+        r, _ = head_scalars(k_nope, k_rope)
+        k = ((widened(k_nope).astype(jnp.float32) * nope_scale(k_scale)
+              + placed(turned(k_rope * k_scale[nope:, None]))) * r).astype(
+                  x.dtype)
+    else:
+        k_nope, v = _projected_parts(
+            latent, p["wkv_b"], n_heads, (nope, cfg.v_dim), minor[:-1],
+            made=(cfg.qk_dim, cfg.v_dim))
+        q = widened(q_nope) + placed(turned(q_rope).astype(x.dtype))
+        k = k_nope + placed(turned(k_rope).astype(x.dtype))
+    if channel_major:  # views the kernel turns back for nothing
+        q, k = q.swapaxes(2, 3), k.swapaxes(2, 3)
 
     chosen = None
     if cfg.selects(l):
@@ -747,10 +922,8 @@ def _mla(p, x, rope, choice, n_heads: int, cfg: MLAConfig, pre_norm=None):
         if choice is None:
             choice = select_keys(scores, cfg.indexer.topk)
         chosen = chosen_keys(scores, *choice)
-    out = causal_core(rotated(q), rotated(k), v, cfg.softmax_scale, cfg.block,
-                      chosen)
-    out = out.transpose(0, 2, 1, 3).reshape(b, l, n_heads * cfg.v_dim)
-    return out @ p["wo"].astype(x.dtype), choice
+    out = causal_core(q, k, v, cfg.softmax_scale, cfg.block, chosen)
+    return contract_heads(out, p["wo"].astype(x.dtype), n_heads), choice
 
 
 @functools.lru_cache(maxsize=None)
@@ -798,6 +971,15 @@ def mla_apply(p, x, n_heads: int, cfg: MLAConfig, rope, pre_norm=None):
     (:func:`apply_rope`). ``pre_norm``: the RMSNorm that stands before
     the mixer, applied here (so that a mixer that keeps its own inputs
     for the backward keeps the block's and not a normalised copy too).
+
+    q, k and v are made once each, where the core reads them
+    (:func:`_mla`): the nope and the rotary parts of q, ``k_nope`` and
+    v are a product each of a part of a weight's columns, the rotary
+    parts are turned alone and the shared rotary key once, the matrix
+    unit places the turned channels beside the nope part in the core's
+    layout, and ``wo`` contracts over ``(head, channel)`` of the core's
+    output; no wide activation is cut, joined, padded to the lanes or
+    copied into another layout on the way.
 
     Where the configuration has an indexer and the sequence is longer
     than its ``topk`` (``cfg.selects``), a query attends the keys

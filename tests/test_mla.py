@@ -380,3 +380,270 @@ def test_the_blocks_that_keep_a_cores_outputs_are_those_of_the_kernel(
     assert core_outputs_kept(cfg, backend, 1, length) == kept
     assert (kept == cfg.n_layers) is transformer.core_is_the_kernel(
         backend, length)
+
+
+# ---------------------------------------------------------------------------
+# each operand of the core made once, where the core reads it (PR 47)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("widths", [(16, 8), (16, 12), (8, 4, 4)],
+                         ids=["nope_rope", "nope_v", "three"])
+def test_a_column_cut_weight_gives_the_whole_products_columns(
+        widths, dtype, nprng):
+    """``x @ part`` of a column-cut ``Adapted`` is the same columns of
+    ``x @ whole``, forward and for the gradients to ``a``, ``b`` and
+    ``x``. Dropping output columns reorders no contraction, so forward
+    and for ``b`` (contracted over the tokens) the two are one number;
+    the CPU's library picks its kernel by the product's shape, so the
+    test holds them to a rounding of the last place. The gradients to
+    ``a`` and ``x`` are contracted over the columns, which the whole
+    product sums with zeros between them."""
+    from baton_tpu.models.lora import Adapted
+
+    heads, d, r = 3, 32, 4
+    n = heads * sum(widths)
+    w, a, b, x = (jnp.asarray(nprng.normal(size=shape), jnp.float32)
+                  for shape in ((d, n), (d, r), (r, n), (2, 5, d)))
+    w, x = w.astype(dtype), x.astype(dtype)
+    edges = np.cumsum((0,) + widths)
+
+    def columns(y, i):  # part i of [..., heads * sum(widths)]
+        y = y.reshape(y.shape[:-1] + (heads, sum(widths)))
+        return y[..., edges[i]:edges[i + 1]].reshape(y.shape[:-2] + (-1,))
+
+    parts = Adapted(w, a, b, 2.0).columns(heads, widths)
+    assert [part.shape for part in parts] == [(d, heads * k) for k in widths]
+    for i, width in enumerate(widths):
+        weight = jnp.asarray(nprng.normal(size=(2, 5, heads * width)),
+                             jnp.float32)
+
+        def part(a, b, x):
+            y = x @ Adapted(w, a, b, 2.0).columns(heads, widths)[i]
+            return jnp.sum(y * weight), y
+
+        def whole(a, b, x):
+            y = columns(x @ Adapted(w, a, b, 2.0), i)
+            return jnp.sum(y * weight), y
+
+        (_, got), got_g = jax.jit(jax.value_and_grad(
+            part, argnums=(0, 1, 2), has_aux=True))(a, b, x)
+        (_, want), want_g = jax.jit(jax.value_and_grad(
+            whole, argnums=(0, 1, 2), has_aux=True))(a, b, x)
+        assert got.dtype == dtype
+        last_place = 2e-6 if dtype == jnp.float32 else 2 ** -7
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=last_place, atol=last_place)
+        np.testing.assert_allclose(np.asarray(got_g[1]),
+                                   np.asarray(want_g[1]), rtol=last_place,
+                                   atol=last_place)
+        tol = 1e-5 if dtype == jnp.float32 else 2e-2
+        for g, want_one in zip(got_g[::2], want_g[::2]):
+            np.testing.assert_allclose(
+                np.asarray(g, np.float32), np.asarray(want_one, np.float32),
+                rtol=tol, atol=tol)
+
+
+def test_the_parts_of_one_projection_share_its_low_rank_product(nprng):
+    """``x A`` is made once a projection, not once a part."""
+    from baton_tpu.models.lora import Adapted
+
+    w = Adapted(jnp.zeros((32, 3 * 24)), jnp.zeros((32, 4)),
+                jnp.zeros((4, 3 * 24)), 2.0)
+    jaxpr = jax.make_jaxpr(lambda x: transformer._projected_parts(
+        x, w, 3, (16, 8)))(jnp.zeros((2, 5, 32)))
+    products = [eqn for eqn in jaxpr.jaxpr.eqns
+                if eqn.primitive.name == "dot_general"]
+    # the low-rank product, then W's and B's a part
+    assert len(products) == 5
+    assert sum(eqn.outvars[0].aval.shape[-1] == 4 for eqn in products) == 1
+
+
+def _whole_head_mla(p, x, n_heads, cfg, rope):
+    """The mixer the plain way in JAX, for its gradients: whole heads,
+    the wide activations cut and joined, each head's key turned for
+    itself, the ``[L, L]`` scores at once."""
+    b, l, _ = x.shape
+    cos, sin = rope
+
+    def heads(y):
+        return y.reshape(b, l, n_heads, -1).transpose(0, 2, 1, 3)
+
+    def rms(y, scale):
+        return y * jax.lax.rsqrt(
+            jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps) * scale
+
+    def rotated(y):
+        half = cfg.rope_dim // 2
+        kept, y1, y2 = (y[..., :cfg.nope_dim],
+                        y[..., cfg.nope_dim:cfg.nope_dim + half],
+                        y[..., cfg.nope_dim + half:])
+        return jnp.concatenate(
+            [kept, y1 * cos - y2 * sin, y2 * cos + y1 * sin], -1)
+
+    if cfg.q_rank is None:
+        q = heads(x @ p["wq"])
+    else:
+        q = heads(rms(x @ p["wq_a"], p["q_a_norm"]["scale"]) @ p["wq_b"])
+    c = x @ p["wkv_a"]
+    kv = heads(rms(c[..., :cfg.kv_rank], p["kv_norm"]["scale"]) @ p["wkv_b"])
+    shared = jnp.broadcast_to(c[:, None, :, cfg.kv_rank:],
+                              (b, n_heads, l, cfg.rope_dim))
+    k = jnp.concatenate([kv[..., :cfg.nope_dim], shared], -1)
+    v = kv[..., cfg.nope_dim:]
+    if cfg.qk_norm:
+        q, k = rms(q, p["q_norm"]["scale"]), rms(k, p["k_norm"]["scale"])
+    s = jnp.einsum("bhqd,bhkd->bhqk", rotated(q), rotated(k),
+                   precision="highest") * cfg.softmax_scale
+    s = jnp.where(jnp.tril(jnp.ones((l, l), bool)), s, -jnp.inf)
+    out = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v,
+                     precision="highest")
+    return out.transpose(0, 2, 1, 3).reshape(b, l, -1) @ p["wo"]
+
+
+# the two shapes of configuration the cells run, narrow and at the
+# widths that decide the layout (``mla_qk_layout``: 192 rides the
+# sublanes of the kernel, 256 is whole lane tiles)
+_SHAPES = {
+    "sarvam": dict(kv_rank=32, qk_norm=True, rope_scaling=YARN),
+    "glm": dict(kv_rank=32, q_rank=24, rope_theta=1e6, norm_eps=1e-5),
+}
+_WIDTHS = {
+    ("sarvam", False): dict(nope_dim=16, rope_dim=8, v_dim=12),
+    ("sarvam", True): dict(nope_dim=128, rope_dim=64, v_dim=128),
+    ("glm", False): dict(nope_dim=24, rope_dim=8, v_dim=32),
+    ("glm", True): dict(nope_dim=192, rope_dim=64, v_dim=256),
+}
+
+
+def _shaped(shape, kernel, n_heads, length, seed=0):
+    """``(cfg, parameters, x, weight, rope)`` of one such mixer with
+    norm scales that differ, so that their place matters."""
+    cfg = MLAConfig(block=8, **_SHAPES[shape], **_WIDTHS[shape, kernel])
+    p = mla_init(jax.random.key(seed), D, n_heads, cfg)
+    for name in ("q_norm", "k_norm", "kv_norm", "q_a_norm"):
+        if name in p:
+            n = p[name]["scale"].shape[0]
+            p[name]["scale"] = 1 + 0.5 * jnp.sin(jnp.arange(n) + len(name))
+    x, weight = jax.random.normal(jax.random.key(seed + 1),
+                                  (2, 2, length, D))
+    return cfg, p, x, 0.1 * weight, mla_rope_angles(length, cfg)
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["the_plain_core", "the_kernel"])
+@pytest.mark.parametrize("shape", ["sarvam", "glm"])
+def test_the_mixer_is_the_whole_head_form_with_its_gradients(
+        shape, kernel, request):
+    """Both shapes of configuration, forward and every gradient,
+    against the plain mixer of this file: on the plain blocked core at
+    narrow widths (row-major operands), and with the core the
+    interpreted kernel at the cells' widths, two heads: 128 + 64 / 128
+    makes q and k channel-major (``[B, H, 192, L]``), 192 + 64 / 256
+    row-major with the rotary 64 in lanes 192 to 255."""
+    n_heads, length = (2, 32) if kernel else (HEADS, 12)
+    if kernel:  # blocks of 8 queries by 16 keys
+        request.getfixturevalue("kernel_core")
+    cfg, p, x, weight, rope = _shaped(shape, kernel, n_heads, length)
+    layout = transformer.mla_qk_layout(cfg, "tpu", length)
+    assert layout == ("channel_major" if kernel and shape == "sarvam"
+                      else "row_major")
+
+    def through(fn):
+        def loss(pp, xx):
+            y = fn(pp, xx, n_heads, cfg, rope)
+            return jnp.sum(y * weight), y
+
+        both = jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))
+        if kernel and fn is mla_apply:
+            _assert_kernel_ran(both, p, x)
+        return both(p, x)
+
+    ((_, got), got_g), ((_, want), want_g) = \
+        through(mla_apply), through(_whole_head_mla)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+    assert jax.tree_util.tree_structure(got_g) == \
+        jax.tree_util.tree_structure(want_g)
+    for g, w in zip(*map(jax.tree_util.tree_leaves, (got_g, want_g))):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("shape", ["sarvam", "glm"])
+def test_the_shared_key_turned_once_is_the_key_turned_a_head_at_a_time(
+        shape, monkeypatch):
+    """In bfloat16: the keys the mixer hands its core, the shared rotary
+    key turned once at ``[B, 1, L, rope]`` and then given to the heads
+    (under ``qk_norm`` times each head's scalar), against the keys of
+    the mixer that broadcast it to the heads first and normed and
+    turned each head's copy: the two differ by the order of two
+    roundings under ``qk_norm`` (a bfloat16 rounding of the normed key
+    before the rotation then, none now), and in nothing without it."""
+    cfg, p, x, _, rope = _shaped(shape, False, HEADS, 12)
+    p = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16) if a.ndim == 2 else a, p)
+    x = x.astype(jnp.bfloat16)
+    seen = {}
+
+    def core(q, k, v, scale, block, chosen=None):
+        seen.update(q=q, k=k, v=v)
+        return blocked_causal_core(q, k, v, scale, block, chosen)
+
+    monkeypatch.setattr(transformer, "causal_core", core)
+    _, handed = jax.jit(
+        lambda p, x: (mla_apply(p, x, HEADS, cfg, rope), dict(seen)))(p, x)
+    b, l = x.shape[:2]
+    c = x @ p["wkv_a"]
+    latent = transformer.rms_normalize(
+        c[..., :cfg.kv_rank], p["kv_norm"]["scale"], cfg.norm_eps)
+    kv = (latent @ p["wkv_b"]).reshape(b, l, HEADS, -1).transpose(0, 2, 1, 3)
+    k = jnp.concatenate(
+        [kv[..., :cfg.nope_dim],
+         jnp.broadcast_to(c[:, None, :, cfg.kv_rank:],
+                          (b, HEADS, l, cfg.rope_dim))], -1)
+    if cfg.qk_norm:
+        k = transformer.rms_normalize(k, p["k_norm"]["scale"], cfg.norm_eps)
+    k = jnp.concatenate(
+        [k[..., :cfg.nope_dim],
+         transformer.apply_rope(k[..., cfg.nope_dim:], *rope)], -1)
+    got, want = (np.asarray(y, np.float32) for y in (handed["k"], k))
+    assert handed["k"].dtype == jnp.bfloat16 and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(handed["v"], np.float32),
+                                  np.asarray(kv[..., cfg.nope_dim:],
+                                             np.float32))
+    if not cfg.qk_norm:
+        np.testing.assert_array_equal(got, want)
+    else:
+        # the nope part to the statistic's float32 rounding, which may
+        # tip a bfloat16; the rotary part to two roundings of 2 ** -8
+        assert np.abs(got - want).max() <= 2 ** -7 * np.abs(want).max()
+        assert np.mean(got != want) < 0.5
+
+
+@pytest.mark.parametrize("shape,backend,length,layout", [
+    ("sarvam", "tpu", 2048, "channel_major"),  # sarvam_105b_c4_l2048
+    ("glm", "tpu", 8192, "row_major"),         # glm5_c4_l8192: 256 wide
+    ("sarvam", "cpu", 2048, "row_major"),      # tier-1: the plain core
+    ("sarvam", "tpu", 2000, "row_major"),      # no whole blocks: the same
+    ("sarvam", "tpu", 1024, "row_major"),      # one block
+])
+def test_queries_and_keys_are_laid_out_as_the_core_takes_them(
+        shape, backend, length, layout):
+    cfg = MLAConfig(**_SHAPES[shape], **_WIDTHS[shape, True])
+    assert transformer.mla_qk_layout(cfg, backend, length) == layout
+
+
+def test_a_decoder_of_latent_attention_says_how_it_laid_them_out():
+    """``baton.round`` carries ``mla_qk_layout`` beside
+    ``core_outputs_kept`` once the model has been traced."""
+    cfg = LlamaConfig.tiny(mla=_cfg(8))
+    model = llama_lm_model(cfg)
+    assert "mla_qk_layout" not in dict(model.span_attrs)
+    ids = jnp.zeros((1, cfg.max_len), jnp.int32)
+    jax.eval_shape(lambda p: model.apply(p, {"x": ids}, None),
+                   jax.eval_shape(model.init, jax.random.key(0)))
+    assert dict(model.span_attrs)["mla_qk_layout"] == "row_major"  # the CPU

@@ -266,8 +266,12 @@ def _mla_of_pr_34(p, x, n_heads, cfg, rope):
 
 @pytest.mark.parametrize("qk_norm", [True, False])
 def test_without_a_query_latent_or_an_indexer_the_mixer_is_todays(qk_norm):
-    """The parameter tree (names, shapes and the draws themselves) and
-    the jaxpr of ``sarvam_105b``'s mixer, forward and gradient."""
+    """The parameter tree (names, shapes and the draws themselves) of
+    ``sarvam_105b``'s mixer, and its values, forward and gradient,
+    against the mixer PR 34 left: since PR 47 the program is another
+    (the weights are cut and not the activations, the rotary parts
+    turned alone, the shared key once), the function is the same to a
+    float32 rounding."""
     yarn = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
             "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
             "type": "deepseek_yarn"}
@@ -284,17 +288,25 @@ def test_without_a_query_latent_or_an_indexer_the_mixer_is_todays(qk_norm):
     np.testing.assert_array_equal(
         np.asarray(p["wo"]),
         np.asarray(transformer.dense_init(ko, HEADS * 12, D)))
-    x = jnp.zeros((2, 12, D))
+    if qk_norm:  # scales that differ, so that their place matters
+        p["q_norm"]["scale"] = 1 + 0.1 * jnp.arange(24.0)
+        p["k_norm"]["scale"] = 1 - 0.02 * jnp.arange(24.0)
+    x, weight = jax.random.normal(jax.random.key(5), (2, 2, 12, D))
     rope = mla_rope_angles(12, cfg)
 
-    def programs(fn):
-        forward = jax.make_jaxpr(lambda pp, xx: fn(pp, xx, HEADS, cfg, rope))
-        grad = jax.make_jaxpr(jax.grad(
-            lambda pp, xx: jnp.sum(fn(pp, xx, HEADS, cfg, rope)),
-            argnums=(0, 1)))
-        return str(forward(p, x)), str(grad(p, x))
+    def through(fn):
+        return jax.value_and_grad(
+            lambda pp, xx: jnp.sum(fn(pp, xx, HEADS, cfg, rope) * weight),
+            argnums=(0, 1))(p, x)
 
-    assert programs(mla_apply) == programs(_mla_of_pr_34)
+    (got, got_g), (want, want_g) = through(mla_apply), through(_mla_of_pr_34)
+    # (a sum of 1,536 terms of either sign)
+    assert float(got) == pytest.approx(float(want), rel=1e-4)
+    assert jax.tree_util.tree_structure(got_g) == \
+        jax.tree_util.tree_structure(want_g)
+    for g, w in zip(*map(jax.tree_util.tree_leaves, (got_g, want_g))):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
 
 
 # ------------------------------------------------------------ gradients

@@ -172,3 +172,158 @@ def test_the_pairs_block_keeps_its_attention_branchs_kernel_outputs(one_chip):
     assert not any("rematted_computation" in k for k in kernels)
     loops = re.findall(r' while\(.*?op_name="([^"]*)"', text)
     assert loops and all("/ssd_scan/" in name for name in loops)
+
+
+# ---------------------------------------------------------------------------
+# latent attention between its projections and its kernel (PR 47)
+
+_HLO_SHAPE = re.compile(r"(pred|[su]\d+|bf16|f16|f32)\[([\d,]*)\]\{([\d,]*)")
+
+
+def _materialised(text):
+    """``(opcode, op_name, [(dtype, dims, minor dim)])`` of every
+    instruction outside a fused computation that writes an array: what
+    the program reads and writes in memory, not what a fusion holds in
+    registers."""
+    fused = False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            fused = line.startswith("%fused_") or line.startswith("fused_")
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if fused or not m or m.group(2) in (
+                "parameter", "get-tuple-element", "tuple", "bitcast",
+                "constant"):
+            continue
+        arrays = [(dtype, [int(d) for d in dims.split(",") if d],
+                   [int(d) for d in order.split(",") if d])
+                  for dtype, dims, order in _HLO_SHAPE.findall(m.group(1))]
+        name = re.search(r'op_name="([^"]*)"', line)
+        yield (m.group(2), name.group(1) if name else "",
+               [(dtype, dims, dims[order[0]] if order else None)
+                for dtype, dims, order in arrays], line)
+
+
+_MLA_SHAPES = {
+    # sarvam_105b's shape of configuration: a norm over each head's
+    # query and key, no query latent, 128 + 64 / 128: q and k go to the
+    # kernel with the sequence minor
+    "sarvam": (dict(kv_rank=128, nope_dim=128, rope_dim=64, v_dim=128,
+                    qk_norm=True), "channel_major"),
+    # glm_5's: a query latent, no such norm, 192 + 64 / 256: row-major,
+    # the rotary 64 in lanes 192 to 255
+    "glm": (dict(kv_rank=128, nope_dim=192, rope_dim=64, v_dim=256,
+                 q_rank=256, norm_eps=1e-5), "row_major"),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_MLA_SHAPES))
+def test_latent_attention_makes_each_operand_of_its_core_once(one_chip,
+                                                              shape):
+    """The value and gradient of ``transformer._mla`` over a frozen
+    bfloat16 base under adapters, two clients under ``vmap``, 4 heads
+    of a 256-wide model at 2,048 tokens, compiled for the described
+    v5e. Between the projections and the kernels the program writes
+
+    * no ``copy`` of q's or k's size (the parent: 4 at GLM's shape,
+      of the projections' outputs and of the core's output before
+      ``wo``; at sarvam's shape its gradient program carried the
+      kernel's sequence-minor layout back to the projections and held
+      none, its forward-only program at the cell's full shapes held 2);
+    * no array of half a rotary part's size or more whose minor
+      dimension is 32 or 64: the rotary parts are made and turned with
+      the sequence minor in either layout (the parent: 11 at GLM's
+      shape, the rotation's halves ``[..., L, 32]`` four times padded
+      to the lanes and ``f32[..., L, 64]`` among them);
+    * no float32 array of q's size before the kernel, and after the
+      backward kernel (whose own outputs are float32) none but, under
+      ``qk_norm``, one each for q and k: the norm's backward needs the
+      cotangent times the head's scalar twice, for a sum over the
+      channels and for the nope part's gradient, and XLA writes it;
+    * the shared rotary key turned once, at ``[1, 1, 32, L]`` a half:
+      every multiplication of a rotary half (by the angles' cosines
+      and sines) is of that shape or of q's half ``[1, H, 32, L]``,
+      four of each;
+    * at most 4 arrays of a rotary part's size or more that neither a
+      product nor a kernel wrote in the forward and at most 6 in the
+      backward (found: 3 and 5 at sarvam's shape, 0 and 4 at GLM's;
+      the parent at these shapes: 12 and 10, 8 and 12)."""
+    from baton_tpu.models import transformer
+    from baton_tpu.models.lora import Adapted
+
+    kw, layout = _MLA_SHAPES[shape]
+    cfg = transformer.MLAConfig(block=512, **kw)
+    clients, heads, d, length, rank = 2, 4, 256, 2048, 16
+    assert transformer.mla_qk_layout(cfg, "tpu", length) == layout
+    rope = transformer.mla_rope_angles(length, cfg)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    base = jax.eval_shape(
+        lambda key: transformer.mla_init(key, d, heads, cfg),
+        jax.random.key(0))
+    base = jax.tree_util.tree_map(
+        lambda a: shaped(a.shape, jnp.bfloat16 if a.ndim == 2 else a.dtype),
+        base)
+    lora = {name: (shaped((clients, w.shape[0], rank), jnp.float32),
+                   shaped((clients, rank, w.shape[1]), jnp.float32))
+            for name, w in base.items() if getattr(w, "ndim", 0) == 2}
+
+    def mixer(lora, x, base):
+        def client(lora, x):
+            p = dict(base, **{name: Adapted(base[name], a, b, 2.0)
+                              for name, (a, b) in lora.items()})
+            return transformer._mla(p, x, rope, None, heads, cfg)[0]
+
+        with jax.named_scope("latent_attention"):
+            y = jax.vmap(client)(lora, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    x = shaped((clients, 1, length, d), jnp.bfloat16)
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:  # ``_mla`` asks the backend where it is traced
+        turns = [eqn.outvars[0].aval.shape for eqn in jax.make_jaxpr(
+            lambda x: transformer._mla(
+                jax.tree_util.tree_map(
+                    lambda a: jnp.zeros(a.shape, a.dtype), base),
+                x, rope, None, heads, cfg)[0])(
+                    jnp.zeros((1, length, d), jnp.bfloat16)).jaxpr.eqns
+                 if eqn.primitive.name == "mul"
+                 and eqn.outvars[0].aval.shape[-2:] == (32, length)]
+        text = jax.jit(jax.value_and_grad(mixer, argnums=(0, 1))).lower(
+            lora, x, base).compile().as_text()
+    finally:
+        jax.default_backend = backend
+    assert sorted(set(turns)) == [(1, 1, 32, length), (1, heads, 32, length)]
+    assert turns.count((1, 1, 32, length)) == 4  # the shared key, once
+    assert len(turns) == 8
+
+    q_size = clients * heads * length * cfg.qk_dim
+    part_size = clients * heads * length * cfg.rope_dim
+    kernels = [name for opcode, name, _, line in _materialised(text)
+               if "tpu_custom_call" in line]
+    assert kernels and all("mla_core" in name for name in kernels)
+    counted = {False: 0, True: 0}  # by direction: is it the backward's
+    wide_floats = dict(counted)
+    for opcode, name, arrays, line in _materialised(text):
+        # not the kernels, and not what the compiler moves between its
+        # memory spaces (asynchronous copies and their joins)
+        if opcode == "custom-call" or opcode.endswith(("-start", "-done")):
+            continue
+        product = opcode in ("convolution", "dot") or (
+            opcode == "fusion" and re.search(r"kind=k(Output|Conv)", line))
+        backward = "transpose(" in name
+        for dtype, dims, minor in arrays:
+            size = 1
+            for n in dims:
+                size *= n
+            assert not (opcode == "copy" and size == q_size), line
+            if size >= part_size // 2:
+                assert minor not in (32, 64), line
+            wide_floats[backward] += dtype == "f32" and size == q_size
+            if size >= part_size and not product:
+                counted[backward] += 1
+    assert wide_floats[False] == 0
+    assert wide_floats[True] <= (2 if cfg.qk_norm else 0)
+    assert counted[False] <= 4 and counted[True] <= 6, counted
