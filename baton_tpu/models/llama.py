@@ -9,7 +9,8 @@ makes it a hybrid: each layer's mixer is one of :data:`MIXERS` (full
 attention, the gated delta rule's linear attention with a recurrent
 state, latent attention in a low-rank latent whose queries may choose
 their keys, compressed convolutional attention, a Mamba-2 state-space
-branch and an attention branch side by side on one normed input), in
+branch and an attention branch side by side on one normed input, full
+attention's projections under a window of the last ``window`` keys), in
 the pattern the configuration gives. The table is all this module
 knows of a mixer: a further one is one more entry. With ``moe`` the layers after the
 first ``first_dense_layers`` replace their SwiGLU by the expert layer of
@@ -69,8 +70,8 @@ from baton_tpu.models.transformer import (
     cca_apply, cca_core_is_kernel, cca_init, default_attention, dense_init,
     head_products_a_block, matmul, mha_apply, mha_init, mla_apply,
     mla_core_is_kernel, mla_init, mla_qk_layout, mla_rope_angles,
-    next_token_loss, normal_init, rms_init, rms_norm, rope_angles, scaled,
-    swiglu_apply, swiglu_init, tied_logits)
+    multi_head_attention, next_token_loss, normal_init, rms_init, rms_norm,
+    rope_angles, scaled, swiglu_apply, swiglu_init, tied_logits)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,6 +88,15 @@ class LlamaConfig:
     # None: no rotary embedding (position comes from the recurrent
     # layers of a hybrid)
     rope_theta: Optional[float] = 500000.0
+    # the published ``yarn`` group of the full-attention layers'
+    # rotation (``factor``, ``original_max_position_embeddings``,
+    # ``beta_fast``, ``beta_slow``, ``attention_factor``:
+    # ``transformer.rope_angles``); None: plain frequencies. A windowed
+    # layer's are always plain
+    rope_yarn: Optional[tuple] = None
+    # a windowed layer's query sees itself and the ``window - 1`` keys
+    # before it; the full-attention layers beside it see their prefix
+    window: Optional[int] = None
     # the expert layer (models/moe.py) that stands for the SwiGLU of
     # width ``d_ff`` in every layer after the first
     # ``first_dense_layers``; its own width is ``moe.d_ff``
@@ -99,6 +109,9 @@ class LlamaConfig:
     ssm: Optional[SSMConfig] = None
     # RMSNorm of the whole query and key projections in full attention
     qk_norm: bool = False
+    # the share of a query head's draw that is its key head's, in full
+    # and windowed attention (``transformer.mha_init``); 0: independent
+    qk_aligned: float = 0.0
     # the mixer of each layer, a key of ``MIXERS``; the first
     # ``n_layers`` entries count (a depth cut keeps the published
     # list). None: one mixer everywhere, latent attention with ``mla``,
@@ -130,6 +143,9 @@ class LlamaConfig:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if isinstance(self.rope_yarn, dict):  # a JSON group, hashable
+            object.__setattr__(self, "rope_yarn",
+                               tuple(sorted(self.rope_yarn.items())))
 
     def kind_of(self, layer: int) -> str:
         if self.layer_types:
@@ -188,7 +204,7 @@ def _attention_init(rng, cfg, out_std):
     m = cfg.multipliers
     p = mha_init(rng, cfg.d_model, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
                  head_dim=cfg.head_dim, out_std=out_std / m.attention_out,
-                 qk_norm=cfg.qk_norm)
+                 qk_norm=cfg.qk_norm, qk_aligned=cfg.qk_aligned)
     for name, by in (("wq", m.attention_in), ("wk", m.attention_in * m.key),
                      ("wv", m.attention_in)):
         if by != 1:
@@ -198,21 +214,63 @@ def _attention_init(rng, cfg, out_std):
 
 def _attention_apply(p, h, cfg, rope, attention_fn):
     m = cfg.multipliers
+    # beside windowed layers the core has a scope of its own, to be told
+    # from theirs; no other configuration's names move
+    named = {} if cfg.window is None else {"core_scope": "full_core"}
     return scaled(mha_apply(
         p, scaled(h, m.attention_in), cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         causal=True, rope=rope, attention_fn=attention_fn,
-        key_multiplier=m.key), m.attention_out)
+        key_multiplier=m.key, **named), m.attention_out)
 
 
 def _attention_rope(cfg, length):
     # None: position comes from the recurrent layers of a hybrid
-    return None if cfg.rope_theta is None else rope_angles(
-        length, cfg.head_dim, cfg.rope_theta)
+    if cfg.rope_theta is None:
+        return None
+    return rope_angles(length, cfg.head_dim, cfg.rope_theta,
+                       cfg.rope_yarn and dict(cfg.rope_yarn))
+
+
+def _attention_facts(cfg):
+    return () if cfg.rope_yarn is None else (
+        ("rope_yarn_factor", dict(cfg.rope_yarn)["factor"]),)
 
 
 def _attention_is_kernel(cfg, backend, batch, length, attention_fn):
     return attention_is_kernel(attention_fn, backend, batch, cfg.n_heads,
                                length)
+
+
+@jax.named_scope("sliding_attention")
+def _sliding_apply(p, h, cfg, rope, attention_fn):
+    """Full attention's projections under a window: a query sees itself
+    and the ``cfg.window - 1`` keys before it. The core is whatever
+    ``attention_fn`` makes of the window (the flash kernel skips by it,
+    the dense path masks), under a scope of its own."""
+    return multi_head_attention(
+        p, h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads, causal=True, rope=rope,
+        attention_fn=attention_fn, window=cfg.window,
+        core_scope="window_core")
+
+
+def _layers_of(cfg, apply) -> int:
+    return sum(_mixer(cfg.kind_of(i)).apply is apply
+               for i in range(cfg.n_layers))
+
+
+def _sliding_facts(cfg):
+    return (("window", cfg.window),
+            ("window_layers", _layers_of(cfg, _sliding_apply)),
+            ("full_layers", _layers_of(cfg, _attention_apply)))
+
+
+def _sliding_seen(cfg, length):
+    """The tiles a head's forward kernel runs on in a windowed and in a
+    full layer over ``length`` tokens, by the kernel's own rule."""
+    from baton_tpu.ops.flash_attention import tiles_visited
+
+    return {"window_tiles": tiles_visited(length, cfg.window),
+            "causal_tiles": tiles_visited(length)}
 
 
 def _parallel_init(rng, cfg, out_std):
@@ -234,7 +292,7 @@ MIXERS = {
     "full_attention": Mixer(
         key="attn", init=_attention_init, apply=_attention_apply,
         projections=("wq", "wk", "wv", "wo"), rope=_attention_rope,
-        core_is_kernel=_attention_is_kernel),
+        core_is_kernel=_attention_is_kernel, facts=_attention_facts),
     "linear_attention": Mixer(
         key="linear_attn",
         init=lambda rng, cfg, out_std: gated_delta_init(
@@ -289,6 +347,20 @@ MIXERS = {
             ("conv_taps", cfg.ssm.conv_taps)),
         seen=lambda cfg, length: {
             "ssm_chunks": -(-length // min(cfg.ssm.chunk, length))}),
+    # full attention's parameters under a key of their own (a block's
+    # kind is the structure of its parameters), its rule for the kernel,
+    # plain rotary frequencies whatever the full layers' are
+    "sliding_attention": Mixer(
+        key="sliding_attn",
+        init=lambda rng, cfg, out_std: mha_init(
+            rng, cfg.d_model, cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+            head_dim=cfg.head_dim, out_std=out_std,
+            qk_aligned=cfg.qk_aligned),
+        apply=_sliding_apply, projections=("wq", "wk", "wv", "wo"),
+        rope=lambda cfg, length: rope_angles(length, cfg.head_dim,
+                                             cfg.rope_theta),
+        core_is_kernel=_attention_is_kernel, facts=_sliding_facts,
+        seen=_sliding_seen),
 }
 
 _MLP_PROJECTIONS = ("w_gate", "w_up", "w_down")  # a shared expert's too
@@ -554,6 +626,8 @@ def llama_lm_model(
         ("experts_held", cfg.moe.held), ("experts_total", cfg.moe.n_experts),
         ("routed_rows_bound", rows_bound(1024 * cfg.moe.top_k, cfg.moe.held,
                                          cfg.moe.router_outputs)))
+    if cfg.moe is not None and cfg.moe.router_scores != "sigmoid":
+        facts += (("router_scores", cfg.moe.router_scores),)
     if cfg.moe is not None and cfg.moe.skip:
         facts += (("router_outputs", cfg.moe.router_outputs),
                   ("skip_expert", cfg.moe.n_experts))

@@ -6,7 +6,10 @@ router outputs and weighs them, in float32. :func:`route` scores every
 token against all ``n_experts`` (sigmoid scores, DeepSeek-V3's
 convention, arXiv:2412.19437 section 2.1): the ``top_k`` largest of ``s
 + router_bias`` are chosen (the bias chooses, it does not weigh), their
-weights are ``routed_scale * s_i / sum of the chosen s``.
+weights are ``routed_scale * s_i / sum of the chosen s``; or, with
+``router_scores="softmax_chosen"``, the ``top_k`` largest logits are
+chosen and weigh as their softmax over the chosen alone (a softmax over
+all experts renormalised over the chosen: the same numbers).
 :func:`route_mlp` (``router_hidden``; ZAYA1's, arXiv:2511.17127) takes a
 token down to ``router_hidden`` channels, adds the state the layer
 before's router left, scaled a channel, and hands the sum on as its
@@ -95,6 +98,10 @@ class MoEConfig:
     # uniform in +-this range (a trained model's balances the load;
     # zeros could not tell choosing from weighing); None: no bias
     router_bias_range: Optional[float] = None
+    # how :func:`route` weighs what it chose: ``"sigmoid"`` of each
+    # logit over the chosen ones' sum, or ``"softmax_chosen"``, the
+    # softmax of the chosen logits
+    router_scores: str = "sigmoid"
     # the width of the MLP router and of the state it hands to the next
     # layer's (:func:`route_mlp`); None: the sigmoid router
     # (:func:`route`)
@@ -144,6 +151,14 @@ def moe_init(key, d_model: int, d_ff: int, cfg: MoEConfig):
 def route(p, x, cfg: MoEConfig):
     """``(idx, gate)``, each ``[..., top_k]``: the experts every token
     chose among all ``n_experts`` and their weights, float32."""
+    if cfg.router_scores == "softmax_chosen":
+        z = jnp.einsum("...d,de->...e", x.astype(jnp.float32), p["router"],
+                       precision=jax.lax.Precision.HIGHEST)
+        _, idx = jax.lax.top_k(z + p.get("router_bias", 0.0), cfg.top_k)
+        return idx, cfg.routed_scale * jax.nn.softmax(
+            jnp.take_along_axis(z, idx, axis=-1), axis=-1)
+    if cfg.router_scores != "sigmoid":
+        raise ValueError(f"unknown router_scores {cfg.router_scores!r}")
     s = jax.nn.sigmoid(jnp.einsum(
         "...d,de->...e", x.astype(jnp.float32), p["router"],
         precision=jax.lax.Precision.HIGHEST))

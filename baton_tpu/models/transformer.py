@@ -23,6 +23,7 @@ driver-set workloads that need one. These blocks are written TPU-first:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -131,16 +132,33 @@ def rms_norm(x, p, eps=1e-6):
 # rotary position embeddings (RoPE)
 
 
-def rope_angles(seq_len: int, head_dim: int, theta: float = 10000.0):
+def rope_angles(seq_len: int, head_dim: int, theta: float = 10000.0,
+                yarn: Optional[dict] = None):
     """Returns (cos, sin) each [L, Dh/2], fp32. ``theta`` may be an
-    integer past 32 bits (a configuration file's 100000000000)."""
-    inv_freq = 1.0 / (
-        float(theta)
-        ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
-    )
+    integer past 32 bits (a configuration file's 100000000000).
+
+    ``yarn``: the published group of a ``yarn`` rotation (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``): the frequencies are :func:`yarn_inv_freq`'s
+    and ``cos`` and ``sin`` are multiplied by ``attention_factor`` (so a
+    score of two turned vectors by its square), at every length: the
+    scaling is static, as the public ``yarn`` initialisation has it."""
+    if yarn is None:
+        inv_freq = 1.0 / (
+            float(theta)
+            ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+        )
+    else:
+        inv_freq = yarn_inv_freq(
+            head_dim, float(theta), yarn["factor"],
+            yarn["original_max_position_embeddings"], yarn["beta_fast"],
+            yarn["beta_slow"])
     pos = jnp.arange(seq_len, dtype=jnp.float32)
     ang = jnp.outer(pos, inv_freq)  # [L, Dh/2]
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return cos, sin
+    return yarn["attention_factor"] * cos, yarn["attention_factor"] * sin
 
 
 def apply_rope(x, cos, sin):
@@ -157,13 +175,14 @@ def apply_rope(x, cos, sin):
 # attention
 
 
-def dot_product_attention(q, k, v, bias=None, causal=False):
+def dot_product_attention(q, k, v, bias=None, causal=False, window=None):
     """Dense scaled-dot-product attention with GQA.
 
     q [B, Hq, L, Dh]; k, v [B, Hkv, L, Dh] with Hq % Hkv == 0. Softmax in
     fp32; the two contractions are einsums XLA maps onto the MXU. ``bias``
     is additive, broadcastable to [B, Hq, L, L] (padding uses -inf-like
-    large negatives).
+    large negatives). With ``window`` (causal attention alone) a query
+    sees itself and the ``window - 1`` keys before it.
     """
     b, hq, l, dh = q.shape
     hkv = k.shape[1]
@@ -179,7 +198,10 @@ def dot_product_attention(q, k, v, bias=None, causal=False):
         scores = scores + bias
     if causal:
         ql = jnp.arange(l)
-        scores = jnp.where(ql[:, None] >= ql[None, :], scores, -1e30)
+        seen = ql[:, None] >= ql[None, :]
+        if window is not None:
+            seen = seen & (ql[:, None] - ql[None, :] < window)
+        scores = jnp.where(seen, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     if hq != hkv:
         probs = probs.reshape(b, hkv, hq // hkv, l, l)
@@ -206,7 +228,7 @@ def dense_gives_way(backend: str, batch: int, heads: int, lq: int,
         or 4 * batch * heads * lq * lk > _DENSE_SCORES_BUDGET_BYTES)
 
 
-def default_attention(q, k, v, bias=None, causal=False):
+def default_attention(q, k, v, bias=None, causal=False, window=None):
     """Backend-dispatching attention — the model zoo's default kernel.
 
     On TPU, long sequences route to the Pallas flash-attention kernel
@@ -222,24 +244,31 @@ def default_attention(q, k, v, bias=None, causal=False):
     program contains exactly one kernel — there is no runtime branch.
     A ``bias`` that is not the standard per-key [B, 1, 1, L] padding
     bias falls back to the dense kernel, which accepts anything
-    broadcastable to [B, Hq, L, L].
+    broadcastable to [B, Hq, L, L]. A ``window`` goes to whichever side
+    is taken, by the rule a call without one is sent: the kernel skips
+    by it, the dense side masks.
     """
     b, hq, lq, _ = q.shape
     lk = k.shape[2]
+    # a call without a window passes none on: an attention of the
+    # caller's own need not know the word
+    windowed = {} if window is None else {"window": window}
     if dense_gives_way(jax.default_backend(), b, hq, lq, lk) and (
             bias is None or bias.shape == (b, 1, 1, lk)):
         from baton_tpu.ops.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, bias=bias, causal=causal)
-    return dot_product_attention(q, k, v, bias=bias, causal=causal)
+        return flash_attention(q, k, v, bias=bias, causal=causal, **windowed)
+    return dot_product_attention(q, k, v, bias=bias, causal=causal,
+                                 **windowed)
 
 
 def attention_is_kernel(attention_fn, backend: str, batch: int, heads: int,
                         length: int) -> bool:
     """Whether causal self-attention over ``length`` tokens by
     ``attention_fn`` is the flash kernel: :func:`default_attention`
-    where dense gives way (no bias stands in the kernel's way there);
-    an attention of the caller's is not known to be one."""
+    where dense gives way (no bias stands in the kernel's way there,
+    and a window does not move the rule: the kernel takes one); an
+    attention of the caller's is not known to be one."""
     return attention_fn is default_attention and dense_gives_way(
         backend, batch, heads, length, length)
 
@@ -250,10 +279,19 @@ def padding_bias(mask, dtype=jnp.float32):
 
 
 def mha_init(key, d_model, n_heads, n_kv_heads=None, head_dim=None,
-             out_std=None, qk_norm: bool = False):
+             out_std=None, qk_norm: bool = False, qk_aligned: float = 0.0):
     """Fused QKV-per-role projection params for (G)MQA attention.
     ``qk_norm`` adds the RMSNorm scales of the whole query and key
-    projections (all heads together, before the split into heads)."""
+    projections (all heads together, before the split into heads).
+    ``qk_aligned`` is the share of a query head's projection that is
+    its key head's (``wq_i = a wk_j + sqrt(1 - a^2) own``, the deviation
+    kept): a token's score of its own key then has the mean ``a
+    sqrt(head_dim)`` among scores of deviation 1. Random weights stand
+    for trained ones, and a trained head scores a token's own key high;
+    with independent draws a query's softmax over a thousand random keys
+    is flat, its own key weighs a thousandth, and adapters on the
+    projections get next to no gradient that does not average out. At 0
+    the draws are independent, as they were."""
     n_kv = n_kv_heads or n_heads
     dh = head_dim or d_model // n_heads
     kq, kk, kv, ko = jax.random.split(key, 4)
@@ -263,14 +301,18 @@ def mha_init(key, d_model, n_heads, n_kv_heads=None, head_dim=None,
         "wv": dense_init(kv, d_model, n_kv * dh),
         "wo": dense_init(ko, n_heads * dh, d_model, stddev=out_std),
     }
+    if qk_aligned:
+        own = p["wq"].reshape(d_model, n_kv, n_heads // n_kv, dh)
+        shared = p["wk"].reshape(d_model, n_kv, 1, dh)
+        p["wq"] = (qk_aligned * shared + (1 - qk_aligned ** 2) ** 0.5 * own
+                   ).reshape(d_model, n_heads * dh)
     if qk_norm:
         p["q_norm"] = rms_init(n_heads * dh)
         p["k_norm"] = rms_init(n_kv * dh)
     return p
 
 
-@jax.named_scope("attention")
-def mha_apply(
+def multi_head_attention(
     p,
     x,
     n_heads: int,
@@ -280,9 +322,15 @@ def mha_apply(
     rope: Optional[tuple] = None,
     attention_fn: AttentionFn = default_attention,
     key_multiplier: float = 1.0,
+    window: Optional[int] = None,
+    core_scope: Optional[str] = None,
 ):
-    """Multi-head attention over x [B, L, D] -> [B, L, D]; a head is
-    as wide as ``wq`` makes it (``d_model / n_heads`` or not)."""
+    """Multi-head attention over x [B, L, D] -> [B, L, D] under no
+    scope of its own; a head is as wide as ``wq`` makes it (``d_model /
+    n_heads`` or not). With ``window`` a query sees itself and the
+    ``window - 1`` keys before it (``attention_fn`` is handed it only
+    then). ``core_scope`` names the scope ``attention_fn`` is called
+    under, for a model that tells its cores apart; None opens none."""
     b, l, _ = x.shape
     n_kv = n_kv_heads or n_heads
     dh = p["wq"].shape[1] // n_heads
@@ -300,9 +348,15 @@ def mha_apply(
     if rope is not None:
         cos, sin = rope
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    out = attention_fn(q, k, v, bias=bias, causal=causal)
+    windowed = {} if window is None else {"window": window}
+    with (jax.named_scope(core_scope) if core_scope
+          else contextlib.nullcontext()):
+        out = attention_fn(q, k, v, bias=bias, causal=causal, **windowed)
     out = out.transpose(0, 2, 1, 3).reshape(b, l, n_heads * dh)
     return out @ p["wo"].astype(x.dtype)
+
+
+mha_apply = jax.named_scope("attention")(multi_head_attention)
 
 
 # ---------------------------------------------------------------------------
@@ -431,8 +485,10 @@ def mla_rope_angles(seq_len: int, cfg: MLAConfig):
     yarn = cfg.yarn
     if yarn:
         if yarn.get("mscale", 1) != yarn.get("mscale_all_dim", 1):
-            raise NotImplementedError("mscale != mscale_all_dim scales cos "
-                                      "and sin; no configuration needs it")
+            raise NotImplementedError(
+                "mscale != mscale_all_dim scales cos and sin: full "
+                "attention's rotation does (rope_angles, attention_factor), "
+                "latent attention's has had no configuration that needs it")
         inv_freq = yarn_inv_freq(
             cfg.rope_dim, cfg.rope_theta, yarn["factor"],
             yarn["original_max_position_embeddings"], yarn["beta_fast"],

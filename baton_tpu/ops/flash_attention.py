@@ -41,7 +41,16 @@ replacing the dense ``dot_product_attention`` einsum path
   is still visited (a learned choice scatters over the prefix; a tile
   no query chose anything of could be skipped and is not), so the work
   is the dense causal core's. A call without one builds the kernels it
-  built before there was any.
+  built before there was any;
+* **a window** beside the causal rule: with ``window`` a query sees
+  itself and the ``window - 1`` keys before it (``0 <= q - k <
+  window``). It is a second static bound of the same kind: a tile wholly
+  older than every one of its queries' windows is skipped as one wholly
+  in their future is, forward and both backwards, and its DMAs are kept
+  away from both sides. One rule (:func:`_sees` of two positions) is the
+  mask, the skip (the rule at a tile's corners) and, solved for a block
+  index, every index map (:func:`_key_blocks`, :func:`_query_blocks`).
+  A call without a window builds the kernels it built before.
 
 Interpret mode exists for the CPU tests (same code path, same math)
 and is refused on a TPU backend — :func:`_resolve_interpret` is the one
@@ -133,8 +142,22 @@ def _key_side_spec(d_major, block, dk, index_map):
     return _spec(shape, place)
 
 
-def _scores(q, k, bias, chosen, *, scale, causal, d_major, i, j, block_q,
-            block_k):
+def _sees(q_pos, k_pos, causal, window):
+    """The rule: whether the query at ``q_pos`` sees the key at
+    ``k_pos`` (positions, or arrays of them; ``None`` where every query
+    sees every key): no key after it under ``causal``, and with
+    ``window`` none ``window`` or more positions before it."""
+    seen = None
+    if causal:
+        seen = q_pos >= k_pos
+    if window is not None:
+        near = q_pos - k_pos < window
+        seen = near if seen is None else seen & near
+    return seen
+
+
+def _scores(q, k, bias, chosen, *, scale, causal, window, d_major, i, j,
+            block_q, block_k):
     """One tile's float32 scores ``[block_q, block_k]`` of ``q [bq, Dk]``
     and ``k [bk, Dk]`` (``[Dk, bq]`` and ``[Dk, bk]`` with ``d_major``)
     plus the keys' ``bias [1, bk]``, masked where the tile of ``chosen
@@ -143,53 +166,107 @@ def _scores(q, k, bias, chosen, *, scale, causal, d_major, i, j, block_q,
     (the MXU multiplies bfloat16 natively and accumulates in float32;
     upcasting first would force 4-8x slower float32 passes), contracted
     by ``dot_general`` (an explicit ``k.T`` would force a Mosaic relayout
-    before the MXU op). ``causal`` masks by the tile's global
-    positions."""
+    before the MXU op). ``causal`` and ``window`` mask by the tile's
+    global positions (:func:`_sees`)."""
     over = 0 if d_major else 1
     s = lax.dot_general(
         q, k, (((over,), (over,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) * scale + bias
-    if causal:
+    if causal or window is not None:
         q_pos = i * block_q + lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0
         )
         k_pos = j * block_k + lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        s = jnp.where(_sees(q_pos, k_pos, causal, window), s, NEG_INF)
     if chosen is not None:
         s = jnp.where(chosen.astype(jnp.int32) != 0, s, NEG_INF)
     return s
 
 
-def _on_needed_tiles(body, *, causal, i, j, block_q, block_k):
+def _tile_is_needed(causal, window, i, j, block_q, block_k):
+    """Whether any query of block ``i`` sees any key of block ``j``
+    (``None``: every tile is needed): :func:`_sees` at the tile's two
+    corners, its first key against its last query for the causal bound
+    and its first query against its last key for the window's."""
+    needed = None
+    if causal:
+        needed = j * block_k <= i * block_q + (block_q - 1)
+    if window is not None:
+        near = i * block_q - (j * block_k + (block_k - 1)) < window
+        needed = near if needed is None else needed & near
+    return needed
+
+
+def _on_needed_tiles(body, *, causal, window, i, j, block_q, block_k):
     """Run ``body()`` on tile ``(i, j)`` of queries and keys. Under a
     causal mask a tile strictly in every query's future contributes
-    nothing and is skipped, forward and backward (about half the FLOPs);
-    :func:`_last_needed_k` / :func:`_first_needed_q` keep its DMAs away
-    too."""
-    if causal:
-        pl.when(j * block_k <= i * block_q + (block_q - 1))(body)
-    else:
+    nothing and is skipped, forward and backward (about half the FLOPs),
+    and so is one wholly older than every query's window;
+    :func:`_needed_k` / :func:`_needed_q` keep its DMAs away too."""
+    needed = _tile_is_needed(causal, window, i, j, block_q, block_k)
+    if needed is None:
         body()
+    else:
+        pl.when(needed)(body)
 
 
-def _last_needed_k(causal, i, j, block_q, block_k):
-    """The block of keys the grid step ``(i, j)`` asks for: under a
-    causal mask a skipped step names the last needed block again, and
-    Pallas fetches nothing for a block index that did not change."""
-    if not causal:
-        return j
-    return jnp.minimum(j, (i * block_q + (block_q - 1)) // block_k)
+def _key_blocks(causal, window, i, block_q, block_k):
+    """``(first, last)``: the blocks of keys that block ``i`` of queries
+    sees anything of, :func:`_tile_is_needed` solved for ``j``; ``None``
+    on a side the rule does not bound (``first`` is never negative)."""
+    last = (i * block_q + (block_q - 1)) // block_k if causal else None
+    if window is None:
+        return None, last
+    return jnp.maximum(i * block_q - (window - 1), 0) // block_k, last
 
 
-def _first_needed_q(causal, i, j, block_q, block_k):
+def _query_blocks(causal, window, j, block_q, block_k):
+    """The same for a block ``j`` of keys: the blocks of queries that
+    see anything of it, :func:`_tile_is_needed` solved for ``i``."""
+    first = (j * block_k) // block_q if causal else None
+    if window is None:
+        return first, None
+    return first, (j * block_k + (block_k - 1) + (window - 1)) // block_q
+
+
+def _held_to(x, first, last):
+    """``x`` held to ``[first, last]``, either side ``None`` for open."""
+    if last is not None:
+        x = jnp.minimum(x, last)
+    if first is not None:
+        x = jnp.maximum(x, first)
+    return x
+
+
+def _needed_k(causal, window, i, j, block_q, block_k):
+    """The block of keys the grid step ``(i, j)`` asks for: a skipped
+    step names the nearest needed block again, the last one after them
+    and under a window the first one before them, and Pallas fetches
+    nothing for a block index that did not change."""
+    return _held_to(j, *_key_blocks(causal, window, i, block_q, block_k))
+
+
+def _needed_q(causal, window, i, j, block_q, block_k):
     """The same for a grid whose inner axis runs over blocks of queries:
-    the skipped steps come first and name the first needed block."""
-    if not causal:
-        return i
-    return jnp.maximum(i, (j * block_k) // block_q)
+    the steps skipped under the causal rule come first and name the
+    first needed block, those past the window the last."""
+    return _held_to(i, *_query_blocks(causal, window, j, block_q, block_k))
+
+
+def tiles_visited(length: int, window: Optional[int] = None,
+                  block_q: int = 512, block_k: int = 1024) -> int:
+    """The tiles a head's causal forward runs on (does not skip) over
+    ``length`` tokens on a TPU, at the blocks :func:`flash_attention`
+    would pick from these: the kernels' own rule, counted."""
+    block_q, block_k, pad_q, pad_k = _prepare_padding(
+        length, length, block_q, block_k, interpret=False)
+    return sum(
+        bool(_tile_is_needed(True, window, i, j, block_q, block_k))
+        for i in range((length + pad_q) // block_q)
+        for j in range((length + pad_k) // block_k))
 
 
 # ======================================================================
@@ -197,7 +274,7 @@ def _first_needed_q(causal, i, j, block_q, block_k):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest,
-                scale, causal, d_major, block_q, block_k, nk):
+                scale, causal, window, d_major, block_q, block_k, nk):
     # rest: the tile of chosen keys where the call has one, then the
     # outputs and the scratch
     *c_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
@@ -222,7 +299,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest,
         # softmax statistics are fp32 throughout
         s = _scores(q_ref[...], k_ref[...], b_ref[...],
                     c_ref[0][...] if c_ref else None, scale=scale,
-                    causal=causal, d_major=d_major, i=i, j=j,
+                    causal=causal, window=window, d_major=d_major, i=i, j=j,
                     block_q=block_q, block_k=block_k)
         m_prev = m_ref[:, :1]                            # [bq, 1]
         l_prev = l_ref[:, :1]
@@ -236,7 +313,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    _on_needed_tiles(_accumulate, causal=causal, i=i, j=j,
+    _on_needed_tiles(_accumulate, causal=causal, window=window, i=i, j=j,
                      block_q=block_q, block_k=block_k)
 
     @pl.when(j == nk - 1)
@@ -278,7 +355,7 @@ def _chosen_spec(block_q, block_k, place):
 
 
 def _fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
-         interpret):
+         interpret, window=None):
     b, hq, lq, dk = q.shape
     hkv, lk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
@@ -289,10 +366,11 @@ def _fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
         q, k = _sequence_minor(q), _sequence_minor(k)
 
     def kj(i, j):
-        return _last_needed_k(causal, i, j, block_q, block_k)
+        return _needed_k(causal, window, i, j, block_q, block_k)
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, d_major=d_major,
+        _fwd_kernel, scale=scale, causal=causal, window=window,
+        d_major=d_major,
         block_q=block_q, block_k=block_k, nk=nk,
     )
     out, lse = pl.pallas_call(
@@ -368,14 +446,14 @@ def _dq_fits_vmem(lq: int, dk: int) -> bool:
 
 
 def _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref, c_ref,
-              *, scale, causal, d_major, i, j, block_q, block_k):
+              *, scale, causal, window, d_major, i, j, block_q, block_k):
     """A tile's probabilities from the saved log-sum-exp, and the
     gradient of its scores, both float32 ``[bq, bk]``. ``c_ref``: the
     tile of chosen keys, or None."""
     s = _scores(q_ref[...], k_ref[...], b_ref[...],
                 None if c_ref is None else c_ref[...], scale=scale,
-                causal=causal, d_major=d_major, i=i, j=j, block_q=block_q,
-                block_k=block_k)
+                causal=causal, window=window, d_major=d_major, i=i, j=j,
+                block_q=block_q, block_k=block_k)
     p = jnp.exp(s - lse_ref[0][:, None])                       # [bq, bk]
     dp = lax.dot_general(
         do_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
@@ -398,7 +476,7 @@ def _grad_of_keys(x, ds, over: int, d_major: bool):
 
 
 def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
-                    *rest, scale, causal, d_major, block_q, block_k,
+                    *rest, scale, causal, window, d_major, block_q, block_k,
                     has_chosen):
     # rest: the tile of chosen keys where the call has one, then (dk, dv,
     # db) in the two-pass form; the one-kernel form puts the head's whole
@@ -419,8 +497,8 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
         doi = do_ref[...]                                      # [bq, Dv]
         p, ds = _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                           b_ref, c_ref, scale=scale, causal=causal,
-                          d_major=d_major, i=i, j=j, block_q=block_q,
-                          block_k=block_k)
+                          window=window, d_major=d_major, i=i, j=j,
+                          block_q=block_q, block_k=block_k)
         # contract the bq axis directly (p^T·do, ds^T·q without transposes)
         dv_ref[...] += lax.dot_general(
             p.astype(doi.dtype), doi, (((0,), (0,)), ((), ())),
@@ -435,22 +513,26 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
         rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
         at = (slice(None), rows) if d_major else (rows, slice(None))
 
-        # the first block of keys is visible to every block of queries,
-        # causal or not: it sets the rows, the later ones add to them
-        @pl.when(j == 0)
+        # the first block of keys a block of queries sees sets its rows,
+        # the later ones add to them: block 0, causal or not, unless a
+        # window has left it behind
+        first = 0 if window is None else _key_blocks(
+            causal, window, i, block_q, block_k)[0]
+
+        @pl.when(j == first)
         def _set():
             dq_ref[0][at] = dq
 
-        @pl.when(j > 0)
+        @pl.when(j > first)
         def _add():
             dq_ref[0][at] += dq
 
-    _on_needed_tiles(_accumulate, causal=causal, i=i, j=j,
+    _on_needed_tiles(_accumulate, causal=causal, window=window, i=i, j=j,
                      block_q=block_q, block_k=block_k)
 
 
 def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
-                   *rest, scale, causal, d_major, block_q, block_k,
+                   *rest, scale, causal, window, d_major, block_q, block_k,
                    has_chosen):
     c_ref, dq_ref = rest if has_chosen else (None, rest[0])
     i = pl.program_id(2)
@@ -464,17 +546,18 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
         kj = k_ref[...]
         _, ds = _p_and_ds(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                           b_ref, c_ref, scale=scale, causal=causal,
-                          d_major=d_major, i=i, j=j, block_q=block_q,
-                          block_k=block_k)
+                          window=window, d_major=d_major, i=i, j=j,
+                          block_q=block_q, block_k=block_k)
         dq_ref[...] += scale * _grad_of_keys(kj, ds.astype(kj.dtype), 1,
                                              d_major)
 
-    _on_needed_tiles(_accumulate, causal=causal, i=i, j=j,
+    _on_needed_tiles(_accumulate, causal=causal, window=window, i=i, j=j,
                      block_q=block_q, block_k=block_k)
 
 
 def _bwd_call(q, k, v, bias2d, out, dout, lse,
-              causal, scale, block_q, block_k, interpret, chosen=None):
+              causal, scale, block_q, block_k, interpret, chosen=None,
+              window=None):
     b, hq, lq, dk_ = q.shape
     hkv, lk, dv_ = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
@@ -519,7 +602,8 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
             (b, hq, dk_, length) if d_major else (b, hq, length, dk_),
             jnp.float32)
 
-    kernel_args = dict(scale=scale, causal=causal, d_major=d_major,
+    kernel_args = dict(scale=scale, causal=causal, window=window,
+                       d_major=d_major,
                        block_q=block_q, block_k=block_k,
                        has_chosen=chosen is not None)
 
@@ -547,7 +631,8 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
         functools.partial(_bwd_dkv_kernel, **kernel_args),
         grid=(b, hq, nk, nq),
         in_specs=in_specs(
-            qi=lambda x, y: _first_needed_q(causal, y, x, block_q, block_k),
+            qi=lambda x, y: _needed_q(causal, window, y, x, block_q,
+                                      block_k),
             kj=lambda x, y: x),
         out_specs=out_specs,
         out_shape=out_shape,
@@ -562,8 +647,8 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
             grid=(b, hq, nq, nk),
             in_specs=in_specs(
                 qi=lambda x, y: x,
-                kj=lambda x, y: _last_needed_k(causal, x, y, block_q,
-                                               block_k)),
+                kj=lambda x, y: _needed_k(causal, window, x, y, block_q,
+                                          block_k)),
             out_specs=[_key_side_spec(d_major, block_q, dk_,
                                       lambda b_, h, x, y: (b_, h, x))],
             out_shape=[key_side_shape(lq)],
@@ -583,14 +668,14 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
 
 
 # ======================================================================
-# custom-vjp core (static: causal/scale/blocks/interpret)
+# custom-vjp core (static: causal/scale/blocks/interpret/window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
-           interpret):
+           interpret, window):
     out, _ = _fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
-                  interpret)
+                  interpret, window)
     return out
 
 
@@ -603,19 +688,21 @@ KEPT_OUTPUTS = ("flash_out", "flash_lse")
 
 
 def _flash_fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
-               interpret):
+               interpret, window):
     out, lse = _fwd(
-        q, k, v, bias2d, chosen, causal, scale, block_q, block_k, interpret
+        q, k, v, bias2d, chosen, causal, scale, block_q, block_k, interpret,
+        window
     )
     out, lse = map(checkpoint_name, (out, lse), KEPT_OUTPUTS)
     return out, (q, k, v, bias2d, chosen, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, dout):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
+               dout):
     q, k, v, bias2d, chosen, out, lse = res
     dq, dk, dv, dbias = _bwd_call(
         q, k, v, bias2d, out, dout, lse,
-        causal, scale, block_q, block_k, interpret, chosen,
+        causal, scale, block_q, block_k, interpret, chosen, window,
     )
     return (
         dq.astype(q.dtype),
@@ -644,6 +731,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     scale: Optional[float] = None,
     chosen: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention matching ``dot_product_attention`` semantics
     (transformer.py:105-133): q [B, Hq, L, Dk], k [B, Hkv, L, Dk],
@@ -658,7 +746,10 @@ def flash_attention(
     q's dtype. Differentiable via Pallas forward+backward kernels.
     The kernels visit every tile the causal rule leaves and mask what
     was not chosen; with no ``chosen`` they take the operands and are
-    the kernels they were.
+    the kernels they were. With ``window`` (causal attention alone) a
+    query sees itself and the ``window - 1`` keys before it, and a tile
+    no query of which sees a key of is neither computed nor fetched,
+    on either side of the band.
 
     Sequence lengths are padded to the block size internally (padded
     keys get -inf bias; padded query rows are sliced off), so any L
@@ -669,6 +760,8 @@ def flash_attention(
     assert hq % hkv == 0, f"GQA needs Hq % Hkv == 0, got {hq} % {hkv}"
     assert k.shape == (b, hkv, lk, d) and v.shape[:3] == k.shape[:3], (
         f"q {q.shape}, k {k.shape}, v {v.shape}")
+    assert window is None or (causal and window >= 1), (
+        f"a window of {window} needs causal attention")
     interpret = _resolve_interpret(interpret)
     if scale is None:
         scale = d ** -0.5
@@ -694,7 +787,7 @@ def flash_attention(
                          ((0, 0), (0, pad_q), (0, pad_k)))
 
     out = _flash(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
-                 interpret)
+                 interpret, window)
     if pad_q:
         out = out[:, :, :lq, :]
     return out
@@ -816,10 +909,11 @@ def make_flash_attention_fn(block_q: int = 512, block_k: int = 1024,
     """Seam-compatible ``attention_fn`` (transformer.py:31-32) for any
     model in the zoo: ``model(..., attention_fn=make_flash_attention_fn())``."""
 
-    def attention_fn(q, k, v, bias=None, causal=False):
+    def attention_fn(q, k, v, bias=None, causal=False, window=None):
         return flash_attention(
             q, k, v, bias=bias, causal=causal,
             block_q=block_q, block_k=block_k, interpret=interpret,
+            window=window,
         )
 
     return attention_fn

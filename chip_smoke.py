@@ -1004,6 +1004,266 @@ def phase_ssm_lora(env: Env) -> None:
             f"branch {err:.4f} from the float32 one (of its largest entry)")
 
 
+def _dense_by_head(q, k, v, bias=None, causal=True, window=None):
+    """The dense masked core a query head at a time (``[L, L]`` float32
+    scores of one head are held, 256 MiB at 8,192 tokens): an
+    ``attention_fn`` for an oracle at lengths where all heads' scores
+    at once would crowd the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    from baton_tpu.models.transformer import dot_product_attention
+
+    group = q.shape[1] // k.shape[1]
+
+    @jax.checkpoint  # a head's scores are made again in its backward
+    def one(h):
+        kv = h // group
+        return dot_product_attention(
+            q[:, h][:, None], k[:, kv][:, None], v[:, kv][:, None],
+            causal=causal, window=window)[:, 0]
+
+    return jnp.moveaxis(jax.lax.map(one, jnp.arange(q.shape[1])), 0, 1)
+
+
+def _experts_one_at_a_time(p, x, idx, gate):
+    """The expert layer the plain way in ``x``'s dtype, as
+    ``moe.moe_dense_oracle`` computes it (every held expert computes
+    every token, masked by the token's weight for it) but as a
+    ``lax.scan`` over the stacks: 64 experts a layer unrolled in Python
+    are six minutes of compile on eight layers."""
+    import jax
+    import jax.numpy as jnp
+
+    def add_one(y, one):
+        e, w_gate, w_up, w_down = one
+        w = jnp.sum(jnp.where(idx == e, gate, 0.0), -1)[..., None]
+        mid = jax.nn.silu(x @ w_gate.astype(x.dtype)) \
+            * (x @ w_up.astype(x.dtype))
+        return y + w * (mid @ w_down.astype(x.dtype)), None
+
+    return jax.lax.scan(
+        add_one, jnp.zeros_like(x),
+        (jnp.arange(p["w_gate"].shape[0]), p["w_gate"], p["w_up"],
+         p["w_down"]))[0]
+
+
+def phase_window_lora(env: Env) -> None:
+    """Windowed layers beside full ones over small experts, the bfloat16
+    path. Two rounds through ``FedSim`` of four blocks (three windowed,
+    one full under ``yarn``; 8 query heads on 2 key-value heads of 128;
+    8 experts of 128, 2 a token by the softmax router) at 4,096 tokens
+    and a window of 1,024 (in rehearsal 64 and 16, the dense masked
+    path): on a TPU the wave program holds two Pallas calls a layer
+    under ``window_core`` and under ``full_core``. Then one sliding and
+    one full core at ``mellum2_12b``'s published widths over its cell's
+    8,192 tokens, the kernel (forward and the three gradients) against
+    the dense masked core a head at a time in float32, with the time of
+    a call of each. Then the configuration's whole stage on one
+    sequence, bfloat16 beside float32 at ``highest`` with a plain loop
+    over the experts: an expert's largest and smallest share of a
+    layer's assignments, and the share of assignments the two streams
+    route differently."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models import llama, moe, transformer
+    from baton_tpu.models.lora import lora_trainable
+    from baton_tpu.ops.flash_attention import flash_attention, tiles_visited
+    from baton_tpu.parallel.engine import FedSim
+    from fedbench import data as cohort
+    from fedbench import manifest
+
+    tiny = env.rehearsal
+    root = manifest.ROOT
+    config = manifest.load_config(root, manifest.load_manifest(root),
+                                  "mellum2_12b")
+    length, window = (64, 16) if tiny else (4096, 1024)
+    cfg = llama.LlamaConfig(
+        vocab_size=512, max_len=length, d_model=256, n_layers=4, n_heads=8,
+        n_kv_heads=2, head_dim=128, d_ff=512, rope_theta=500000,
+        rope_yarn=config["rope_yarn"], window=window, embed_std=1.0,
+        qk_aligned=config["qk_aligned"],
+        layer_types=config["decoder_layer_types"][:4],
+        moe=moe.MoEConfig(n_experts=8, top_k=2, d_ff=128,
+                          router_scores="softmax_chosen"))
+    model = llama.decoder_lora_model(cfg, rank=4, b_std=0.02)
+    params = jax.jit(model.init)(jax.random.key(0))
+    first = jax.random.randint(jax.random.key(1), (2, 1, 1), 0, 512)
+    tokens = (first + 7 * jnp.arange(length + 1)) % 512
+    data = {"x": tokens[..., :-1], "y": tokens[..., 1:]}
+    n_samples = np.asarray([1, 1], np.int32)
+    sim = FedSim(model, batch_size=1, learning_rate=0.05,
+                 trainable=lora_trainable)
+    losses, p, head_products = _lora_rounds(
+        sim, cfg.vocab_size, params, data, n_samples, -(-length // 3))
+    _check(all(a is b for a, b in zip(
+        jax.tree_util.tree_leaves(params["base"]),
+        jax.tree_util.tree_leaves(p["base"]))),
+        "a round copied or cast a leaf of the frozen base")
+    moved = [float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+        jax.tree_util.tree_leaves(params["lora"]),
+        jax.tree_util.tree_leaves(p["lora"]))]
+    _check(len(moved) == 2 * 4 * 4 and all(np.isfinite(moved))
+           and min(moved) > 0,
+           "an adapter factor of the four projections did not move or is "
+           "not finite")
+    text = sim.lower_wave(params, data, n_samples, jax.random.key(2), 1,
+                          None).compile().as_text()
+    under = {scope: len(re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="[^"]*/'
+        + scope + "/", text)) for scope in ("window_core", "full_core")}
+    facts = dict(model.span_attrs)
+    _check((under["window_core"], under["full_core"],
+            facts["core_outputs_kept"]) == ((0, 0, 0) if tiny else (6, 2, 4)),
+           f"Pallas calls under the cores {under}, "
+           f"{facts['core_outputs_kept']} blocks keep a kernel's outputs")
+    _check((facts["window"], facts["window_layers"], facts["full_layers"],
+            facts["router_scores"], facts["window_tiles"],
+            facts["causal_tiles"]) == (
+        window, 3, 1, "softmax_chosen", tiles_visited(length, window),
+        tiles_visited(length)), f"the model says {facts}")
+
+    # ---- the two cores at the published widths
+    sized = manifest.sized(config, tiny)
+    job = manifest.load_workload(root, "mellum2_c4_l8192")
+    if tiny:
+        job.update(job["tiny"])
+    seq = job["seq_len"]
+    hq, hkv, dh = (sized["num_attention_heads"], sized["num_key_value_heads"],
+                   sized["head_dim"])
+    kq, kk, kv, kw = jax.random.split(jax.random.key(7), 4)
+    q = jax.random.normal(kq, (1, hq, seq, dh), jnp.bfloat16)
+    k = jax.random.normal(kk, (1, hkv, seq, dh), jnp.bfloat16)
+    v = jax.random.normal(kv, (1, hkv, seq, dh), jnp.bfloat16)
+    w = jax.random.normal(kw, (1, hq, seq, dh), jnp.float32)  # cotangent
+    f32 = partial(jnp.asarray, dtype=jnp.float32)
+    cores = {}
+    for name, win in (("window", sized["sliding_window"]), ("full", None)):
+        kernel = jax.jit(lambda q, k, v, win=win: jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, window=win, interpret=tiny).astype(
+                    jnp.float32), q, k, v)[1](w) + (flash_attention(
+                        q, k, v, causal=True, window=win, interpret=tiny),))
+        if not tiny:
+            _check("tpu_custom_call" in kernel.lower(q, k, v).as_text(),
+                   f"the {name} core lowered without a tpu_custom_call")
+        got = jax.block_until_ready(kernel(q, k, v))
+        t0 = time.perf_counter()
+        for _ in range(3):
+            got = kernel(q, k, v)
+        jax.block_until_ready(got)
+        ms = (time.perf_counter() - t0) / 3 * 1e3
+
+        @jax.jit
+        def dense(q, k, v, win=win):
+            with jax.default_matmul_precision("highest"):
+                out, back = jax.vjp(partial(_dense_by_head, window=win),
+                                    q, k, v)
+                return back(w) + (out,)
+
+        want = dense(f32(q), f32(k), f32(v))
+        errs = {n: _rel_err(g, r) for n, g, r in zip(
+            ("dq", "dk", "dv", "out"), got, want)}
+        _check(max(errs.values()) <= BF16_TOL,
+               f"the {name} core against the dense masked one beyond "
+               f"{BF16_TOL}: {errs}")
+        cores[name] = (errs, ms, tiles_visited(seq, win))
+
+    # ---- the stage at the published widths: who chooses what
+    decoder = manifest.resolve(config["builder"]["kwargs"]["config"], sized)
+    seed = 17
+    big = llama.llama_lm_model(
+        decoder, param_dtype=jnp.float32 if tiny else jnp.bfloat16)
+    base = jax.jit(big.init)(jax.random.key(seed))
+    ids = cohort.make_cohort(
+        root, manifest.input_spec(config, tiny), np.asarray([1], np.int32),
+        1, seq, cohort.data_key(seed + 1))["x"][0]            # [1, L]
+    kinds = [llama.MIXERS[decoder.kind_of(i)]
+             for i in range(decoder.n_layers)]
+    ropes = {m: m.rope(decoder, seq) for m in dict.fromkeys(kinds)}
+
+    def stage(dtype, plain: bool):
+        """Every layer's choice ``[layers, L, K]`` with its weights and
+        the stream after the stage, the blocks' own parts wired as
+        ``_block_apply`` wires them; ``plain``: the dense core a head at
+        a time and a loop over the experts."""
+        attend = _dense_by_head if plain else transformer.default_attention
+
+        @jax.jit
+        def run(base):
+            x = base["tok_emb"][ids].astype(dtype)
+            chose, weighed = [], []
+            for m, blk in zip(kinds, base["blocks"]):
+                x = llama._mix(blk, x, decoder, ropes[m], attend)
+                hn = transformer.rms_norm(x, blk["norm_mlp"], decoder.norm_eps)
+                idx, gate = moe.route(blk["mlp"], hn, decoder.moe)
+                y = (_experts_one_at_a_time(blk["mlp"], hn, idx, gate)
+                     if plain else moe.moe_apply(blk["mlp"], hn, decoder.moe))
+                x = x + y
+                chose.append(jnp.sort(idx[0], axis=-1))
+                weighed.append(gate[0])
+            return jnp.stack(chose), jnp.stack(weighed), x[0]
+        return run(base)
+
+    dtype = jnp.float32 if tiny else jnp.bfloat16
+    chose, gates, out = stage(dtype, plain=False)
+    with jax.default_matmul_precision("highest"):
+        chose32, _, out32 = stage(jnp.float32, plain=True)
+    chose, chose32 = np.asarray(chose), np.asarray(chose32)
+    gates = np.asarray(gates)
+    out, out32 = np.asarray(out, np.float32), np.asarray(out32)
+    _check(np.isfinite(out).all() and np.isfinite(out32).all(),
+           "a non-finite stream")
+    n_experts, top_k = decoder.moe.n_experts, decoder.moe.top_k
+    rows = [np.bincount(c.reshape(-1), minlength=n_experts) for c in chose]
+    # an assignment differs where the float32 stream did not choose it
+    differs = np.asarray([
+        1.0 - np.mean([len(set(a) & set(b)) for a, b in zip(c, c32)]) / top_k
+        for c, c32 in zip(chose, chose32)])
+    apart = np.linalg.norm(out - out32, axis=-1) \
+        / np.linalg.norm(out32, axis=-1)
+    _check(all(r.min() > 0 for r in rows) or tiny,
+           f"an expert saw no row: {[r.tolist() for r in rows]}")
+    expected = seq * top_k / n_experts
+    (w_errs, w_ms, w_tiles), (f_errs, f_ms, f_tiles) = (cores["window"],
+                                                        cores["full"])
+    timed = ("not measured (rehearsal)" if tiny else
+             f"{w_ms:.2f} ms a call forward and backward in the window, "
+             f"{f_ms:.2f} without ({w_ms / f_ms:.3f} of it)")
+    env.say("window_lora",
+            f"{model.name}: three windowed blocks and a full one (yarn), 8 "
+            f"on 2 heads of 128, 8 experts 2 a token by softmax over the "
+            f"chosen, bf16 over a frozen bf16 base, 2 clients x {length} "
+            f"tokens, window {window}, 2 rounds, loss {losses[0]:.4f} -> "
+            f"{losses[1]:.4f} (in 3 blocks, {head_products} products a "
+            f"block); {len(moved)} adapter factors moved; Pallas calls "
+            f"under window_core {under['window_core']}, under full_core "
+            f"{under['full_core']}. mellum2_12b's cores at "
+            f"{'tiny' if tiny else 'the published'} widths "
+            f"[1, {hq} on {hkv}, {seq}, {dh}] against the dense masked core "
+            f"in float32: window {sized['sliding_window']} "
+            + " ".join(f"{n}={e:.1e}" for n, e in w_errs.items())
+            + f" ({w_tiles} tiles a head), full "
+            + " ".join(f"{n}={e:.1e}" for n, e in f_errs.items())
+            + f" ({f_tiles} tiles); {timed}. The stage, {len(rows)} layers, "
+            f"{seq} tokens, seed {seed}: a layer (fullest expert's rows, "
+            f"emptiest's) "
+            + " ".join(f"({r.max()}, {r.min()})" for r in rows)
+            + f" of {expected:.0f} expected; the chosen weights' largest "
+            f"and smallest, mean over tokens: "
+            f"{gates.max(-1).mean():.3f} and {gates.min(-1).mean():.3f}; "
+            f"{'float32' if tiny else 'bfloat16'} and float32 at highest "
+            f"route differently " + " ".join(
+                f"{100 * d:.2f}" for d in differs)
+            + f" % of a layer's assignments (mean "
+            f"{100 * differs.mean():.3f} %); the two streams lie apart by "
+            f"{float(apart.mean()):.4f} of the float32 one's norm")
+
+
 # ----------------------------------------------------------------------
 def _flash_alone(env: Env) -> str:
     import jax
@@ -1441,7 +1701,7 @@ PHASES = {"device": phase_device, "fedsim_resnet18": phase_fedsim_resnet18,
           "hybrid_lora": phase_hybrid_lora,
           "moe_mla_lora": phase_moe_mla_lora,
           "dsa_mla_lora": phase_dsa_mla_lora, "cca_lora": phase_cca_lora,
-          "ssm_lora": phase_ssm_lora,
+          "ssm_lora": phase_ssm_lora, "window_lora": phase_window_lora,
           "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
           "mesh": phase_mesh, "cache": phase_cache}
 

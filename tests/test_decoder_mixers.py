@@ -30,8 +30,10 @@ from baton_tpu.models.transformer import dense_init
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 LAYER_TYPES = ("full_attention", "linear_attention", "latent_attention",
-               "compressed_attention", "parallel_ssm_attention")
-PARAMETER_KEYS = ("attn", "linear_attn", "mla", "cca", "parallel")
+               "compressed_attention", "parallel_ssm_attention",
+               "sliding_attention")
+PARAMETER_KEYS = ("attn", "linear_attn", "mla", "cca", "parallel",
+                  "sliding_attn")
 
 
 def _accepted(name: str, tiny: bool) -> LlamaConfig:
@@ -58,6 +60,8 @@ def _accepted(name: str, tiny: bool) -> LlamaConfig:
         ("latent_attention", "glm_5", 8192, 0, True, False),
         ("compressed_attention", "zaya1_8b", 8192, 10, False, True),
         ("parallel_ssm_attention", "falcon_h1_34b", 4096, 6, False, True),
+        ("sliding_attention", "mellum2_12b", 8192, 8, False, True),
+        ("full_attention", "mellum2_12b", 8192, 8, False, True),
     ])
 def test_an_entry_answers_what_the_accepted_configuration_runs(
         kind, config, cell_length, cell_kept, keeps_at_8192, kernel_at_8192):
@@ -185,6 +189,92 @@ def test_a_layers_angles_are_its_kinds_made_once_a_kind_a_trace(monkeypatch):
         model.per_example_loss(params, batch, None))).all()
 
 
+def test_the_two_kinds_of_a_windowed_model_take_their_own_rotary_tables():
+    """Three windowed layers to one full one: each kind's angles are made
+    once a trace; the windowed layers' are plain, the full layers' are
+    ``yarn``'s (other frequencies, ``cos`` and ``sin`` times the
+    ``attention_factor``); a block is told by its parameters' key, and
+    the model says what its window is and what its kernels would visit."""
+    from baton_tpu.models.transformer import rope_angles
+
+    handed = {}
+
+    def recording(kind):
+        apply = MIXERS[kind].apply
+
+        def recorded(p, h, cfg, rope, attention_fn):
+            handed.setdefault(kind, []).append(rope)
+            return apply(p, h, cfg, rope, attention_fn)
+
+        return recorded
+
+    cfg = _accepted("mellum2_12b", True)
+    assert cfg.window == 5 and dict(cfg.rope_yarn)["factor"] == 16
+    kinds = [cfg.kind_of(i) for i in range(cfg.n_layers)]
+    assert kinds == ["sliding_attention"] * 3 + ["full_attention"]
+    model = llama.llama_lm_model(cfg, remat=True)
+    assert dict(model.span_attrs) == {
+        "experts_held": 8, "experts_total": 8, "routed_rows_bound": 2048,
+        "router_scores": "softmax_chosen", "window": 5, "window_layers": 3,
+        "full_layers": 1, "rope_yarn_factor": 16}
+    params = model.init(jax.random.key(0))
+    assert ["sliding_attn" in b for b in params["blocks"]] == [
+        True, True, True, False]
+    assert ["attn" in b for b in params["blocks"]] == [
+        False, False, False, True]
+    batch = {"x": jnp.zeros((2, 16), jnp.int32),
+             "y": jnp.zeros((2, 16), jnp.int32)}
+    with pytest.MonkeyPatch.context() as patch:
+        for kind in ("sliding_attention", "full_attention"):
+            patch.setitem(MIXERS, kind, dataclasses.replace(
+                MIXERS[kind], apply=recording(kind)))
+        recorded = llama.llama_lm_model(cfg)  # no jit: the arrays as made
+        assert np.isfinite(np.asarray(
+            recorded.per_example_loss(params, batch, None))).all()
+    assert len(handed["sliding_attention"]) == 3
+    assert len(handed["full_attention"]) == 1
+    plain = rope_angles(16, cfg.head_dim, cfg.rope_theta)
+    for cos, sin in handed["sliding_attention"]:
+        np.testing.assert_array_equal(cos, plain[0])
+        np.testing.assert_array_equal(sin, plain[1])
+        assert cos is handed["sliding_attention"][0][0]  # made once
+    cos, sin = handed["full_attention"][0]
+    factor = dict(cfg.rope_yarn)["attention_factor"]
+    np.testing.assert_allclose(cos[0], factor, rtol=1e-6)  # position 0
+    np.testing.assert_allclose(cos ** 2 + sin ** 2, factor ** 2, rtol=1e-5)
+    assert not np.allclose(cos / factor, plain[0])  # other frequencies
+    # once traced, what the kernels' rule visits at the traced length
+    assert dict(recorded.span_attrs)["window_tiles"] == 1
+    published = _accepted("mellum2_12b", False)
+    seen = MIXERS["sliding_attention"].seen(published, 8192)
+    assert seen == {"window_tiles": 30, "causal_tiles": 72}
+    assert MIXERS["full_attention"].seen(published, 8192) == {}
+
+
+def test_yarn_angles_against_a_hand_written_case():
+    """``rope_angles`` with a ``yarn`` group at a head of 8 channels,
+    theta 10,000, factor 4 over 64 original positions, 4 fast and 1 slow
+    turns: the correction dims are ``8 ln(64 / (4 . 2 pi)) / (2 ln 1e4)
+    = 0.406 -> 0`` and ``8 ln(64 / (2 pi)) / (2 ln 1e4) = 1.008 -> 2``,
+    so channel 0 keeps its frequency, channel 1 is half interpolated and
+    channels 2 and 3 turn at a quarter of theirs; ``cos`` and ``sin``
+    carry the ``attention_factor``."""
+    from baton_tpu.models.transformer import rope_angles
+
+    yarn = {"factor": 4, "original_max_position_embeddings": 64,
+            "beta_fast": 4, "beta_slow": 1, "attention_factor": 1.5}
+    cos, sin = rope_angles(6, 8, 10000.0, yarn)
+    plain = np.asarray([1.0, 0.1, 0.01, 0.001])
+    wanted = plain * np.asarray([1.0, 0.5 + 0.5 / 4, 0.25, 0.25])
+    angle = np.arange(6)[:, None] * wanted[None, :]
+    np.testing.assert_allclose(cos, 1.5 * np.cos(angle), rtol=1e-5)
+    np.testing.assert_allclose(sin, 1.5 * np.sin(angle), rtol=1e-5, atol=1e-7)
+    # no group: the plain table it always was
+    cos, sin = rope_angles(6, 8, 10000.0)
+    np.testing.assert_allclose(cos, np.cos(np.arange(6)[:, None] * plain),
+                               rtol=1e-5)
+
+
 def test_a_layer_type_outside_the_table_is_refused():
     cfg = LlamaConfig.tiny(layer_types=("full_attention", "sliding_window"))
     with pytest.raises(ValueError, match="sliding_window"):
@@ -199,8 +289,10 @@ def test_llama_names_a_mixer_only_in_its_table():
     """Read from the source, without importing it: each layer type and
     each parameter key of a mixer is a string literal once, in
     ``MIXERS``; ``LlamaConfig.kind_of`` names the two kinds a
-    configuration without ``layer_types`` falls back to; nothing else
-    in the module spells one out."""
+    configuration without ``layer_types`` falls back to; the windowed
+    mixer's scope in a trace carries its layer type's name (the
+    benchmark's metrics read it); nothing else in the module spells one
+    out."""
     tree = ast.parse((REPO / "baton_tpu" / "models" / "llama.py").read_text(
         encoding="utf-8"))
     names = set(LAYER_TYPES + PARAMETER_KEYS)
@@ -217,7 +309,52 @@ def test_llama_names_a_mixer_only_in_its_table():
     assert sorted(literals(table)) == sorted(names)
     assert [k.value for k in table.value.keys] == list(LAYER_TYPES)
     assert sorted(literals(kind_of)) == ["full_attention", "latent_attention"]
-    assert len(literals(tree)) == len(names) + 2, (
+    (sliding,) = [n for n in ast.walk(tree)
+                  if isinstance(n, ast.FunctionDef)
+                  and n.name == "_sliding_apply"]
+    assert [literals(d) for d in sliding.decorator_list] == [
+        ["sliding_attention"]]
+    assert len(literals(tree)) == len(names) + 3, (
         "baton_tpu/models/llama.py spells a layer type or a mixer's "
         "parameter key outside MIXERS and LlamaConfig.kind_of: ask the "
         "table instead")
+
+
+def test_an_aligned_draw_scores_a_tokens_own_key_high():
+    """``qk_aligned``: a query head's projection is that share of its
+    key head's and the rest its own, the deviation kept, so a token's
+    score of its own key has the mean ``a sqrt(head_dim)`` among scores
+    of deviation 1; at 0 the draws are the independent ones they were,
+    in both kinds of attention."""
+    from baton_tpu.models.transformer import mha_init
+
+    d, heads, kv, dh = 64, 8, 2, 32
+    plain = mha_init(jax.random.key(0), d, heads, kv, dh)
+    again = mha_init(jax.random.key(0), d, heads, kv, dh, qk_aligned=0.0)
+    np.testing.assert_array_equal(plain["wq"], again["wq"])
+    drawn = mha_init(jax.random.key(0), d, heads, kv, dh, qk_aligned=0.5)
+    np.testing.assert_array_equal(plain["wk"], drawn["wk"])
+    assert float(jnp.std(drawn["wq"])) == pytest.approx(
+        float(jnp.std(plain["wq"])), rel=0.05)
+    h = jax.random.normal(jax.random.key(1), (512, d))
+    q = (h @ drawn["wq"]).reshape(512, kv, heads // kv, dh)
+    k = (h @ drawn["wk"]).reshape(512, kv, 1, dh)
+    own = jnp.sum(q * k, -1) / dh ** 0.5
+    other = jnp.sum(q * jnp.roll(k, 1, axis=0), -1) / dh ** 0.5
+    assert float(jnp.mean(own)) == pytest.approx(0.5 * dh ** 0.5, rel=0.1)
+    assert abs(float(jnp.mean(other))) < 0.1
+    assert float(jnp.std(other)) == pytest.approx(1.0, rel=0.15)
+    cfg = LlamaConfig.tiny(layer_types=("sliding_attention",
+                                        "full_attention"),
+                           window=4, qk_aligned=0.5)
+    for kind in cfg.layer_types:
+        block = llama._block_init(jax.random.key(2), cfg, kind)
+        p = block[MIXERS[kind].key]
+        groups = p["wq"].reshape(cfg.d_model, cfg.n_kv_heads, -1,
+                                 cfg.head_dim)
+        shared = p["wk"].reshape(cfg.d_model, cfg.n_kv_heads, 1,
+                                 cfg.head_dim)
+        corr = float(jnp.sum(groups * shared) / (
+            jnp.linalg.norm(groups) * jnp.linalg.norm(
+                jnp.broadcast_to(shared, groups.shape))))
+        assert corr == pytest.approx(0.5, abs=0.05), kind

@@ -341,3 +341,208 @@ def test_a_policy_of_other_names_keeps_nothing_of_the_kernel():
     grad, operands = _checkpointed_grad(
         jax.checkpoint_policies.save_only_these_names("context"))
     assert flash_kernels(grad, *operands) == (2, 1)
+
+
+# ---------------------------------------------------------------------
+# a window beside the causal rule
+
+
+def _window_losses(window, block_q, block_k):
+    def dense(q, k, v):
+        out = dot_product_attention(q, k, v, causal=True, window=window)
+        return (out * jnp.cos(out)).sum()
+
+    def flash(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              block_q=block_q, block_k=block_k)
+        return (out * jnp.cos(out)).sum()
+
+    return dense, flash
+
+
+# window, blocks of queries and of keys over 48 tokens (padded to 64 by
+# the larger block): a multiple of both tiles, of neither, one tile, one
+# key, and one longer than the sequence (the causal kernel's result)
+WINDOWS = [(16, 8, 16), (20, 16, 8), (7, 16, 16), (1, 8, 8), (100, 16, 16)]
+
+
+@pytest.mark.parametrize("window,block_q,block_k", WINDOWS)
+def test_a_window_forward_and_all_three_gradients(window, block_q, block_k,
+                                                  backward_form):
+    """The windowed kernels against the dense masked core with grouped
+    heads (4 query heads on 2): the output and the gradients of q, k
+    and v, in both forms of the backward."""
+    q, k, v = _qkv(11, 2, 4, 2, 48, 8)
+    dense, flash = _window_losses(window, block_q, block_k)
+    want = jax.value_and_grad(dense, argnums=(0, 1, 2))(q, k, v)
+    got = jax.value_and_grad(flash, argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+    if window >= 48:  # it never binds: the causal core itself
+        np.testing.assert_allclose(
+            np.asarray(flash_attention(q, k, v, causal=True, window=window,
+                                       block_q=block_q, block_k=block_k)),
+            np.asarray(flash_attention(q, k, v, causal=True,
+                                       block_q=block_q, block_k=block_k)),
+            rtol=1e-6, atol=1e-6)
+
+
+def test_a_window_needs_the_causal_rule():
+    q, k, v = _qkv(12, 1, 2, 2, 16, 8)
+    with pytest.raises(AssertionError, match="window"):
+        flash_attention(q, k, v, causal=False, window=4)
+
+
+@pytest.mark.parametrize("window,block_q,block_k",
+                         [(16, 8, 16), (20, 16, 8), (7, 8, 8)])
+def test_a_skipped_tile_is_not_read(window, block_q, block_k, backward_form):
+    """NaN wherever the rule says a tile is skipped. A block of keys is
+    needed by some block of queries, so the poison is laid a row and a
+    column of the grid at a time: for a block of queries, the keys and
+    values of every block it skips are NaN, and its rows of the output
+    and of dq are finite and the clean call's; for a block of keys, the
+    queries of every block that skips it are NaN, and its rows of dk
+    and dv are finite and the clean call's."""
+    from baton_tpu.ops.flash_attention import _tile_is_needed
+
+    length = 64
+    q, k, v = _qkv(13, 1, 4, 2, length, 8)
+
+    def call(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              block_q=block_q, block_k=block_k)
+        return out * jnp.cos(out)
+
+    @jax.jit  # one trace for every pattern of poison
+    def run(q, k, v):
+        out, back = jax.vjp(call, q, k, v)
+        return (out,) + back(jnp.ones_like(out))
+
+    clean = run(q, k, v)
+    n_q, n_k = length // block_q, length // block_k
+    needed = np.asarray([[bool(_tile_is_needed(True, window, i, j, block_q,
+                                               block_k))
+                          for j in range(n_k)] for i in range(n_q)])
+    assert 0 < needed.sum() < needed.size
+
+    def poisoned(x, blocks, block):
+        at = np.repeat(np.asarray(blocks), block)
+        return jnp.where(at[None, None, :, None], jnp.nan, x)
+
+    for i in range(n_q):
+        if needed[i].all():
+            continue
+        got = run(q, poisoned(k, ~needed[i], block_k),
+                  poisoned(v, ~needed[i], block_k))
+        rows = slice(i * block_q, (i + 1) * block_q)
+        for g, c in zip(got[:2], clean[:2]):  # the output and dq
+            assert np.isfinite(np.asarray(g[:, :, rows])).all(), i
+            np.testing.assert_allclose(np.asarray(g[:, :, rows]),
+                                       np.asarray(c[:, :, rows]),
+                                       rtol=1e-5, atol=1e-5)
+    for j in range(n_k):
+        if needed[:, j].all():
+            continue
+        got = run(poisoned(q, ~needed[:, j], block_q), k, v)
+        rows = slice(j * block_k, (j + 1) * block_k)
+        for g, c in zip(got[2:], clean[2:]):  # dk and dv
+            assert np.isfinite(np.asarray(g[:, :, rows])).all(), j
+            np.testing.assert_allclose(np.asarray(g[:, :, rows]),
+                                       np.asarray(c[:, :, rows]),
+                                       rtol=1e-5, atol=1e-5)
+
+
+def test_the_rule_the_skip_and_the_index_maps_are_one():
+    """By brute force over blocks and windows that are and are not
+    multiples of one another: a tile is needed exactly where the rule
+    holds for some pair of its positions; the block ranges the index
+    maps hold a step to are exactly the needed tiles of a row or a
+    column of the grid; a held step names a needed tile."""
+    from baton_tpu.ops import flash_attention as fa
+
+    for block_q, block_k, length in ((8, 16, 64), (16, 8, 64), (8, 8, 48),
+                                     (32, 8, 64)):
+        n_q, n_k = length // block_q, length // block_k
+        for window in (None, 1, 5, 8, 16, 20, 33, 200):
+            needed = np.zeros((n_q, n_k), bool)
+            for i in range(n_q):
+                for j in range(n_k):
+                    q_pos = i * block_q + np.arange(block_q)[:, None]
+                    k_pos = j * block_k + np.arange(block_k)[None, :]
+                    pairs = fa._sees(q_pos, k_pos, True, window)
+                    needed[i, j] = pairs.any()
+                    assert bool(fa._tile_is_needed(
+                        True, window, i, j, block_q, block_k)) \
+                        == needed[i, j]
+            for i in range(n_q):
+                first, last = fa._key_blocks(True, window, i, block_q,
+                                             block_k)
+                first = 0 if first is None else int(first)
+                row = np.flatnonzero(needed[i])
+                assert (row[0], row[-1]) == (first, min(int(last), n_k - 1))
+                assert len(row) == row[-1] - row[0] + 1  # a band
+                for j in range(n_k):
+                    held = int(fa._needed_k(True, window, i, j, block_q,
+                                            block_k))
+                    assert needed[i, held] and (held == j) == needed[i, j]
+            for j in range(n_k):
+                first, last = fa._query_blocks(True, window, j, block_q,
+                                               block_k)
+                last = n_q - 1 if last is None else min(int(last), n_q - 1)
+                col = np.flatnonzero(needed[:, j])
+                assert (col[0], col[-1]) == (int(first), last)
+                for i in range(n_q):
+                    held = int(fa._needed_q(True, window, i, j, block_q,
+                                            block_k))
+                    assert needed[held, j] and (held == i) == needed[i, j]
+    # the benchmark's shape: a window of 1,024 over 8,192 tokens
+    assert (fa.tiles_visited(8192, 1024), fa.tiles_visited(8192)) == (30, 72)
+    assert (fa.tiles_visited(8192, 1024, 1024, 1024),
+            fa.tiles_visited(8192, None, 1024, 1024)) == (15, 36)
+    assert fa.tiles_visited(1024, 1024) == fa.tiles_visited(1024)
+
+
+# jaxpr digests of calls without a window, taken on the commit before
+# the window came (PR 47's tree: the same four calls, sha256 of
+# ``str(jax.make_jaxpr(grad))``): grouped heads in one backward kernel,
+# the two passes, keys on the sublanes with a choice of keys, no mask
+WINDOWLESS = {
+    "one_kernel_gqa": ((1, 4, 2, 512, 128, 128, 128, 256, False, True),
+                       "9c9b0e796563fc98"),
+    "two_pass": ((1, 2, 1, 32768, 128, 128, 512, 1024, False, True),
+                 "4bc0e3d395083a3a"),
+    "d_major_chosen": ((1, 2, 2, 256, 192, 128, 128, 128, True, True),
+                       "c890733467d1cead"),
+    "not_causal": ((1, 2, 2, 256, 128, 128, 128, 128, False, False),
+                   "bba0c5c2c5bad279"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WINDOWLESS))
+def test_a_call_without_a_window_is_the_program_it_was(name):
+    import hashlib
+
+    (b, hq, hkv, l, dk, dv, bq, bk, chosen, causal), digest = WINDOWLESS[name]
+    q = jnp.zeros((b, hq, l, dk), jnp.bfloat16)
+    k = jnp.zeros((b, hkv, l, dk), jnp.bfloat16)
+    v = jnp.zeros((b, hkv, l, dv), jnp.bfloat16)
+    c = jnp.ones((b, l, l), jnp.int8) if chosen else None
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=causal, block_q=bq, block_k=bk, chosen=c,
+            interpret=True).astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+    # and a window is another program
+    if causal and not chosen and l <= 512:
+        def windowed(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, causal=True, block_q=bq, block_k=bk, window=128,
+                interpret=True).astype(jnp.float32))
+        other = str(jax.make_jaxpr(jax.grad(windowed, argnums=(0, 1, 2)))(
+            q, k, v))
+        assert other != text

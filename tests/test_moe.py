@@ -128,6 +128,63 @@ def test_the_shares_add_up_to_the_uncut_layer(nprng):
     _close(moe_apply(p, x, WHOLE), moe_dense_oracle(p, x, WHOLE))
 
 
+# the softmax router: no bias, no scale, no shared expert, the weights a
+# softmax over the chosen logits alone
+SOFTMAX = MoEConfig(n_experts=4, top_k=2, d_ff=F,
+                    router_scores="softmax_chosen")
+
+
+def test_the_softmax_routers_weights_sum_to_one_over_the_chosen(nprng):
+    """The ``top_k`` largest logits are chosen and weigh as their
+    softmax over the chosen alone, which is the softmax over every
+    expert renormalised over the chosen (``norm_topk_prob``); uneven,
+    and no function of a sigmoid."""
+    p = moe_init(jax.random.key(0), D, 4 * F, SOFTMAX)
+    assert set(p) == {"router", "w_gate", "w_up", "w_down"}
+    x = jnp.asarray(nprng.normal(size=(2, 64, D)), jnp.float32)
+    idx, gate = route(p, x, SOFTMAX)
+    z = x @ p["router"]
+    np.testing.assert_array_equal(
+        np.sort(np.asarray(idx), -1),
+        np.sort(np.argsort(-np.asarray(z), -1)[..., :2], -1))
+    _close(gate.sum(-1), jnp.ones(gate.shape[:-1]))
+    over_all = jnp.take_along_axis(jax.nn.softmax(z, -1), idx, -1)
+    _close(gate, over_all / over_all.sum(-1, keepdims=True))
+    assert float(jnp.max(gate)) > 0.7 and float(jnp.min(gate)) < 0.3
+    sigmoid = route(p, x, dataclasses.replace(SOFTMAX,
+                                              router_scores="sigmoid"))[1]
+    assert float(jnp.max(jnp.abs(sigmoid - gate))) > 1e-2
+    with pytest.raises(ValueError, match="router_scores"):
+        route(p, x, dataclasses.replace(SOFTMAX, router_scores="tanh"))
+
+
+def test_four_ranks_of_one_expert_add_up_under_the_softmax_router(nprng):
+    """Ranks holding 16 + 16 + 16 + 16 of 64 experts (here 1 + 1 + 1 + 1
+    of 4) each compute their part of what the softmax router chose;
+    the parts add up to the oracle's whole layer, which the layer that
+    holds every expert equals, in the value and in the gradient of its
+    input."""
+    p = moe_init(jax.random.key(1), D, 4 * F, SOFTMAX)
+    x = jnp.asarray(nprng.normal(size=(2, 16, D)), jnp.float32)
+    whole = moe_dense_oracle(p, x, SOFTMAX)
+    parts = jnp.zeros_like(x)
+    for first in range(4):
+        cut = dataclasses.replace(SOFTMAX, experts_held=1, first_held=first)
+        held = {k: (v[first:first + 1] if k.startswith("w_") else v)
+                for k, v in p.items()}
+        drawn = moe_init(jax.random.key(1), D, 4 * F, cut)
+        assert jnp.array_equal(drawn["w_down"], held["w_down"])
+        part = moe_apply(held, x, cut)
+        _close(part, moe_dense_oracle(held, x, cut))
+        parts = parts + part
+    _close(parts, whole)
+    _close(moe_apply(p, x, SOFTMAX), whole)
+    got = jax.grad(lambda x: jnp.sum(jnp.sin(moe_apply(p, x, SOFTMAX))))(x)
+    want = jax.grad(lambda x: jnp.sum(jnp.sin(
+        moe_dense_oracle(p, x, SOFTMAX))))(x)
+    _close(got, want, rtol=1e-4)
+
+
 def test_the_bias_chooses_and_does_not_weigh(nprng):
     p = _params(WHOLE)
     x = jnp.asarray(nprng.normal(size=(1, 64, D)), jnp.float32)

@@ -327,3 +327,86 @@ def test_latent_attention_makes_each_operand_of_its_core_once(one_chip,
     assert wide_floats[False] == 0
     assert wide_floats[True] <= (2 if cfg.qk_norm else 0)
     assert counted[False] <= 4 and counted[True] <= 6, counted
+
+
+def test_a_windowed_block_holds_no_square_and_its_kernels_clamp_both_sides(
+        one_chip):
+    """The gradient of one checkpointed windowed block at Mellum2's
+    published attention widths (hidden 2,304, 32 query on 4 key-value
+    heads of 128, a window of 1,024) over 8,192 tokens, and of the full
+    block beside it: no array of the program is ``[8192, 8192]`` in its
+    last two dims (the dense path would mask one), each block is the
+    forward kernel and the one backward kernel, and the windowed block's
+    kernels name their blocks of keys (forward) and of queries
+    (backward) through a ``min`` *and* a ``max``, around ``window - 1``:
+    the DMAs are held to the band from both sides. The full block's
+    clamp one side, as they did."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    from baton_tpu.models import llama
+    from baton_tpu.models.transformer import rope_angles
+    from baton_tpu.ops.flash_attention import make_flash_attention_fn
+
+    length = 8192
+    cfg = llama.LlamaConfig(
+        vocab_size=256, d_model=2304, n_layers=2, n_heads=32, n_kv_heads=4,
+        head_dim=128, d_ff=256, window=1024,
+        layer_types=("sliding_attention", "full_attention"))
+    attend = make_flash_attention_fn(interpret=False)
+    rope = rope_angles(length, cfg.head_dim, cfg.rope_theta)
+    block = llama._checkpointed_block()
+
+    def kernels_of(kind):
+        def loss(p, x):
+            y, _ = block(p, x, None, cfg, rope, attend)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        p = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                           sharding=one_chip),
+            jax.eval_shape(lambda key: llama._block_init(key, cfg, kind),
+                           jax.random.key(0)))
+        x = jax.ShapeDtypeStruct((1, length, cfg.d_model), jnp.bfloat16,
+                                 sharding=one_chip)
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            p, x).compile().as_text()
+        assert not re.search(rf"\[(\d+,)*{length},{length}\]", text)
+        bodies = re.findall(r'"body":"([A-Za-z0-9+/=]+)"', text)
+        assert len(bodies) == 2  # one forward, one backward
+        ctx = mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True  # ``stable_mosaic``
+        out = {}
+        with ctx:
+            for body in bodies:
+                asm = ir.Module.parse(base64.b64decode(
+                    body)).operation.get_asm(enable_debug_info=False)
+                name = re.search(r"module @(\w+)", asm).group(1)
+                # the index maps, one a ``func`` after the kernel's own
+                out[name] = [f for f in asm.split("func.func\"()")[1:]
+                             if 'sym_name = "transform_' in f]
+        return out
+
+    def held_both_sides(maps):
+        return [("arith.minsi" in f, "arith.maxsi" in f) for f in maps]
+
+    windowed, full = kernels_of("sliding_attention"), kernels_of(
+        "full_attention")
+    assert set(windowed) == set(full) == {"_fwd_kernel", "_bwd_dkv_kernel"}
+    # forward: q, then k, v and the keys' bias by the block of keys
+    assert held_both_sides(windowed["_fwd_kernel"])[1:4] == [(True, True)] * 3
+    assert held_both_sides(full["_fwd_kernel"])[1:4] == [(True, False)] * 3
+    # backward (keys outer, queries inner): q, do, lse and delta by the
+    # block of queries
+    assert held_both_sides(windowed["_bwd_dkv_kernel"])[:4] \
+        == [(True, True)] * 4
+    assert held_both_sides(full["_bwd_dkv_kernel"])[:4] \
+        == [(False, True)] * 4
+    for maps in windowed.values():
+        assert any("value = 1023 : i32" in f for f in maps)
+    for maps in full.values():
+        assert not any("value = 1023 : i32" in f for f in maps)
