@@ -266,11 +266,15 @@ def _sliding_facts(cfg):
 
 def _sliding_seen(cfg, length):
     """The tiles a head's forward kernel runs on in a windowed and in a
-    full layer over ``length`` tokens, by the kernel's own rule."""
-    from baton_tpu.ops.flash_attention import tiles_visited
+    full layer over ``length`` tokens, by the kernel's own rule, and
+    the steps its grid takes to reach them (a windowed layer's runs
+    over the band, a full layer's over the sequence)."""
+    from baton_tpu.ops.flash_attention import grid_steps, tiles_visited
 
     return {"window_tiles": tiles_visited(length, cfg.window),
-            "causal_tiles": tiles_visited(length)}
+            "causal_tiles": tiles_visited(length),
+            "window_grid_steps": grid_steps(length, cfg.window),
+            "causal_grid_steps": grid_steps(length)}
 
 
 def _parallel_init(rng, cfg, out_std):

@@ -44,13 +44,26 @@ replacing the dense ``dot_product_attention`` einsum path
   built before there was any;
 * **a window** beside the causal rule: with ``window`` a query sees
   itself and the ``window - 1`` keys before it (``0 <= q - k <
-  window``). It is a second static bound of the same kind: a tile wholly
-  older than every one of its queries' windows is skipped as one wholly
-  in their future is, forward and both backwards, and its DMAs are kept
-  away from both sides. One rule (:func:`_sees` of two positions) is the
-  mask, the skip (the rule at a tile's corners) and, solved for a block
-  index, every index map (:func:`_key_blocks`, :func:`_query_blocks`).
-  A call without a window builds the kernels it built before.
+  window``). It is a second static bound of the same kind, and one rule
+  (:func:`_sees` of two positions) is the mask, the skip (the rule at a
+  tile's corners) and, solved for a block index, the band a row or a
+  column of tiles holds (:func:`_key_blocks`, :func:`_query_blocks`).
+  Under a window a grid's inner axis runs over that band and not over
+  the sequence: it is as long as the longest band (:func:`_inner_steps`,
+  counted where the call is traced: 2 blocks of keys a block of queries
+  and 2 the other way at 1,024 x 1,024 blocks, 8,192 tokens and a
+  window of 1,024; 2 and 4 at 512 x 1,024), step ``t`` is block
+  ``first + t`` (:func:`_stepped`), and the only steps that compute
+  nothing are a shorter band's spare ones, where the sequence starts
+  (rows) or ends (columns): 1 of a head's 16 (2 of 32 at 512 x 1,024,
+  where the grid over the sequence skipped 98 of 128). A skipped step
+  was measured on a v5e at 0.12 us in the forward kernel and 0.38 in
+  the backward's, a twentieth and a tenth of a computed one (2.2 and
+  3.8 us; a row of the forward grid costs 1.2 us of its own and a
+  column of the backward's 1.8: PERF.md section 6, PR 49). A windowed
+  call that names no blocks gets :func:`_own_blocks`. A call without
+  a window builds the kernels it built before: its grids run over the
+  sequence, and a tile in every query's future is a skipped step.
 
 Interpret mode exists for the CPU tests (same code path, same math)
 and is refused on a TPU backend — :func:`_resolve_interpret` is the one
@@ -200,36 +213,88 @@ def _tile_is_needed(causal, window, i, j, block_q, block_k):
     return needed
 
 
-def _on_needed_tiles(body, *, causal, window, i, j, block_q, block_k):
-    """Run ``body()`` on tile ``(i, j)`` of queries and keys. Under a
-    causal mask a tile strictly in every query's future contributes
-    nothing and is skipped, forward and backward (about half the FLOPs),
-    and so is one wholly older than every query's window;
-    :func:`_needed_k` / :func:`_needed_q` keep its DMAs away too."""
+def _step_computes(causal, window, i, j, block_q, block_k, nq, nk):
+    """Whether the grid step that stands for tile ``(i, j)`` computes
+    (``None``: every step does): the tile is needed and, where the grid
+    runs over a band, is one of the sequence's ``nq`` by ``nk``."""
     needed = _tile_is_needed(causal, window, i, j, block_q, block_k)
-    if needed is None:
+    if window is not None:
+        needed &= (i < nq) & (j < nk)
+    return needed
+
+
+def _on_needed_tiles(body, *, causal, window, i, j, block_q, block_k,
+                     nq, nk):
+    """Run ``body()`` on tile ``(i, j)`` of queries and keys, ``nq`` by
+    ``nk`` of them. Without a window the grid steps over every tile,
+    and under a causal mask one strictly in every query's future
+    contributes nothing and is skipped, forward and backward (about
+    half the FLOPs): 56 of a head's 128 steps at 8,192 tokens, each
+    measured at a twentieth of a computed one forward and a tenth
+    backward (PERF.md section 6, PR 49).
+    Under a window the grid steps over the band (:func:`_stepped`) and
+    what is skipped is a shorter band's spare step: a tile past the
+    row's last (the first rows, by the causal bound), past the
+    column's last or past the sequence's end (the last columns).
+    :func:`_needed_k` / :func:`_needed_q` keep a skipped step's DMAs
+    away too."""
+    computes = _step_computes(causal, window, i, j, block_q, block_k, nq, nk)
+    if computes is None:
         body()
     else:
-        pl.when(needed)(body)
+        pl.when(computes)(body)
+
+
+def _at_least_zero(x):
+    """``max(x, 0)`` of a block index in a kernel or an index map, and
+    of a Python int where a grid's extent is counted."""
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
 
 
 def _key_blocks(causal, window, i, block_q, block_k):
     """``(first, last)``: the blocks of keys that block ``i`` of queries
     sees anything of, :func:`_tile_is_needed` solved for ``j``; ``None``
-    on a side the rule does not bound (``first`` is never negative)."""
+    on a side the rule does not bound (``first`` is never negative, and
+    ``last`` may lie past the keys' last block where the queries are
+    padded further than the keys)."""
     last = (i * block_q + (block_q - 1)) // block_k if causal else None
     if window is None:
         return None, last
-    return jnp.maximum(i * block_q - (window - 1), 0) // block_k, last
+    return _at_least_zero(i * block_q - (window - 1)) // block_k, last
 
 
 def _query_blocks(causal, window, j, block_q, block_k):
     """The same for a block ``j`` of keys: the blocks of queries that
-    see anything of it, :func:`_tile_is_needed` solved for ``i``."""
+    see anything of it, :func:`_tile_is_needed` solved for ``i``
+    (``last`` lies past the sequence's end for the last blocks)."""
     first = (j * block_k) // block_q if causal else None
     if window is None:
         return first, None
     return first, (j * block_k + (block_k - 1) + (window - 1)) // block_q
+
+
+def _inner_steps(blocks, causal, window, n_outer, n_inner, block_q, block_k):
+    """How long a grid's inner axis is beside ``n_outer`` blocks of the
+    other side (``blocks``: :func:`_key_blocks` beside blocks of
+    queries, :func:`_query_blocks` beside blocks of keys): the
+    sequence's ``n_inner`` blocks without a window, under one the most
+    blocks any band holds. Python ints throughout."""
+    if window is None:
+        return n_inner
+    bands = (blocks(causal, window, x, block_q, block_k)
+             for x in range(n_outer))
+    return max(min(last, n_inner - 1) - first + 1 for first, last in bands)
+
+
+def _stepped(blocks, causal, window, outer, t, block_q, block_k):
+    """The block that step ``t`` of a grid's inner axis stands for
+    beside block ``outer`` of the other side: block ``t`` without a
+    window, under one the band's ``first + t``, which a spare step
+    carries past the band's last block (:func:`_on_needed_tiles` skips
+    it, the index maps hold it back)."""
+    if window is None:
+        return t
+    return blocks(causal, window, outer, block_q, block_k)[0] + t
 
 
 def _held_to(x, first, last):
@@ -241,40 +306,99 @@ def _held_to(x, first, last):
     return x
 
 
-def _needed_k(causal, window, i, j, block_q, block_k):
-    """The block of keys the grid step ``(i, j)`` asks for: a skipped
-    step names the nearest needed block again, the last one after them
-    and under a window the first one before them, and Pallas fetches
-    nothing for a block index that did not change."""
-    return _held_to(j, *_key_blocks(causal, window, i, block_q, block_k))
+def _needed(blocks, causal, window, outer, t, n, block_q, block_k):
+    """The block that step ``t`` of the inner axis asks for, one of
+    ``n``. Without a window a skipped step names the nearest needed
+    block again. Under one the step is the band's ``first + t``, and a
+    spare step names the band's last block again (the sequence's last,
+    where the band runs past its end). Pallas fetches nothing for a
+    block index that did not change."""
+    first, last = blocks(causal, window, outer, block_q, block_k)
+    if window is None:
+        return _held_to(t, first, last)
+    return jnp.minimum(first + t, jnp.minimum(last, n - 1))
 
 
-def _needed_q(causal, window, i, j, block_q, block_k):
-    """The same for a grid whose inner axis runs over blocks of queries:
-    the steps skipped under the causal rule come first and name the
-    first needed block, those past the window the last."""
-    return _held_to(i, *_query_blocks(causal, window, j, block_q, block_k))
+def _needed_k(causal, window, i, t, block_q, block_k, nk):
+    """The block of keys that step ``t`` beside block ``i`` of queries
+    asks for: without a window the steps skipped under the causal rule
+    come last and name the last needed block, and so do a band's spare
+    steps (the first rows': what a skipped step costs now is their 1 of
+    16 steps at Mellum2's shapes)."""
+    return _needed(_key_blocks, causal, window, i, t, nk, block_q, block_k)
+
+
+def _needed_q(causal, window, t, j, block_q, block_k, nq):
+    """The same for a grid whose inner axis runs over blocks of queries
+    beside block ``j`` of keys: without a window the steps skipped
+    under the causal rule come first and name the first needed block; a
+    band's spare steps (the last columns') name the last."""
+    return _needed(_query_blocks, causal, window, j, t, nq, block_q, block_k)
+
+
+def _own_blocks(window, block_q, block_k):
+    """The blocks of queries and keys of a call, each the caller's where
+    it named one: 512 x 1,024, and 1,024 x 1,024 under a window of 1,024
+    tokens or more (a rule of the window alone; the lengths only clamp,
+    :func:`_pick_blocks`). A band's row is two blocks of keys at either
+    size, so twice the queries a row halve the rows, and a row's own
+    cost (its first and last step, its write-back) is half a tile's: on
+    a v5e the windowed core at Mellum2's shapes took 2.64 ms forward
+    and 7.72 forward and backward at 1,024 x 1,024 against 2.93 and
+    8.22 at 512 x 1,024, 3.88 and 8.70 at 512 x 512, 4.58 and 10.14 at
+    1,024 x 512 (PERF.md section 6, PR 49). Smaller windows were not
+    measured and keep the blocks they had; a call without a window is
+    behind its digests."""
+    wide = window is not None and window >= 1024
+    return (block_q or (1024 if wide else 512), block_k or 1024)
+
+
+def _tpu_grid(length, window, block_q, block_k):
+    """The blocks :func:`flash_attention` would pick from these on a TPU
+    for ``length`` queries and keys, and how many of each there are."""
+    block_q, block_k, pad_q, pad_k = _prepare_padding(
+        length, length, *_own_blocks(window, block_q, block_k),
+        interpret=False)
+    return (block_q, block_k, (length + pad_q) // block_q,
+            (length + pad_k) // block_k)
 
 
 def tiles_visited(length: int, window: Optional[int] = None,
-                  block_q: int = 512, block_k: int = 1024) -> int:
+                  block_q: Optional[int] = None,
+                  block_k: Optional[int] = None) -> int:
     """The tiles a head's causal forward runs on (does not skip) over
     ``length`` tokens on a TPU, at the blocks :func:`flash_attention`
-    would pick from these: the kernels' own rule, counted."""
-    block_q, block_k, pad_q, pad_k = _prepare_padding(
-        length, length, block_q, block_k, interpret=False)
+    would pick from these (its own where none are named): the kernels'
+    own rule, counted."""
+    block_q, block_k, nq, nk = _tpu_grid(length, window, block_q, block_k)
     return sum(
         bool(_tile_is_needed(True, window, i, j, block_q, block_k))
-        for i in range((length + pad_q) // block_q)
-        for j in range((length + pad_k) // block_k))
+        for i in range(nq) for j in range(nk))
+
+
+def grid_steps(length: int, window: Optional[int] = None,
+               block_q: Optional[int] = None, block_k: Optional[int] = None,
+               backward: bool = False) -> int:
+    """The steps a head's causal forward grid takes to get there,
+    computed or skipped: the kernels' own grid, counted (``backward``:
+    the grid of dk and dv, blocks of keys by the blocks of queries that
+    see them)."""
+    block_q, block_k, nq, nk = _tpu_grid(length, window, block_q, block_k)
+    if backward:
+        return nk * _inner_steps(_query_blocks, True, window, nk, nq,
+                                 block_q, block_k)
+    return nq * _inner_steps(_key_blocks, True, window, nq, nk,
+                             block_q, block_k)
 
 
 # ======================================================================
-# forward kernel: grid (B, Hq, Lq/block_q, Lk/block_k)
+# forward kernel: grid (B, Hq, Lq/block_q, Lk/block_k), under a window
+# (B, Hq, Lq/block_q, the blocks of keys a band holds)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest,
-                scale, causal, window, d_major, block_q, block_k, nk):
+                scale, causal, window, d_major, block_q, block_k, nq, nk,
+                steps):
     # rest: the tile of chosen keys where the call has one, then the
     # outputs and the scratch
     *c_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = rest
@@ -285,10 +409,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest,
     # every q block (nq× traffic) and could not overlap DMA with compute.
     # m/l are (bq, 128) lane-broadcast: TPU vector layout wants the minor
     # dim lane-aligned, so the scalar-per-row state rides 128 lanes.
+    # Under a window the innermost axis has ``steps`` steps, the row's
+    # band of key blocks from its first on (:func:`_stepped`).
     i = pl.program_id(2)
-    j = pl.program_id(3)
+    t = pl.program_id(3)
+    j = _stepped(_key_blocks, causal, window, i, t, block_q, block_k)
 
-    @pl.when(j == 0)
+    @pl.when(t == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -314,9 +441,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, *rest,
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     _on_needed_tiles(_accumulate, causal=causal, window=window, i=i, j=j,
-                     block_q=block_q, block_k=block_k)
+                     block_q=block_q, block_k=block_k, nq=nq, nk=nk)
 
-    @pl.when(j == nk - 1)
+    @pl.when(t == steps - 1)
     def _finalize():
         m = m_ref[:, :1]
         l = jnp.maximum(l_ref[:, :1], 1e-30)
@@ -359,19 +486,21 @@ def _fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
     b, hq, lq, dk = q.shape
     hkv, lk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = hq // hkv
-    nk = lk // block_k
-    grid = (b, hq, lq // block_q, nk)
+    nq, nk = lq // block_q, lk // block_k
+    steps = _inner_steps(_key_blocks, causal, window, nq, nk, block_q,
+                         block_k)
+    grid = (b, hq, nq, steps)
     d_major = _keys_ride_sublanes(dk)
     if d_major:
         q, k = _sequence_minor(q), _sequence_minor(k)
 
-    def kj(i, j):
-        return _needed_k(causal, window, i, j, block_q, block_k)
+    def kj(i, t):
+        return _needed_k(causal, window, i, t, block_q, block_k, nk)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window,
         d_major=d_major,
-        block_q=block_q, block_k=block_k, nk=nk,
+        block_q=block_q, block_k=block_k, nq=nq, nk=nk, steps=steps,
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -414,7 +543,8 @@ def _fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
 # chosen by the shapes (:func:`_dq_fits_vmem`):
 #
 # * ONE kernel where a head's whole float32 dq [Lq, Dk] fits in VMEM:
-#   grid (B, Hq, Lk/block_k, Lq/block_q), q innermost; dk/dv/db
+#   grid (B, Hq, Lk/block_k, Lq/block_q), q innermost (under a window
+#   the blocks of queries a band holds); dk/dv/db
 #   accumulate in their output blocks over the q axis, dq's output block
 #   is the head's, resident over both inner axes, and every tile adds
 #   into its rows. A tile's p and ds are computed once for the three
@@ -423,7 +553,8 @@ def _fwd(q, k, v, bias2d, chosen, causal, scale, block_q, block_k,
 #   kernels on a v5e);
 # * the standard two passes for longer sequences: pass 1 on the same
 #   grid accumulates dk/dv/db, pass 2 grids (B, Hq, Lq/block_q,
-#   Lk/block_k) and accumulates dq over the innermost kv axis. Only
+#   Lk/block_k) as the forward does and accumulates dq over the
+#   innermost kv axis. Only
 #   block-sized tiles are ever VMEM-resident, so VMEM is O(block²),
 #   independent of L (the r1 single-program-per-head version held ~7
 #   full [L, d] buffers).
@@ -477,16 +608,17 @@ def _grad_of_keys(x, ds, over: int, d_major: bool):
 
 def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
                     *rest, scale, causal, window, d_major, block_q, block_k,
-                    has_chosen):
+                    nq, nk, has_chosen):
     # rest: the tile of chosen keys where the call has one, then (dk, dv,
     # db) in the two-pass form; the one-kernel form puts the head's whole
     # dq [Lq, Dk] first
     c_ref, out_refs = (rest[0], rest[1:]) if has_chosen else (None, rest)
     *dq_ref, dk_ref, dv_ref, db_ref = out_refs
     j = pl.program_id(2)
-    i = pl.program_id(3)
+    t = pl.program_id(3)
+    i = _stepped(_query_blocks, causal, window, j, t, block_q, block_k)
 
-    @pl.when(i == 0)
+    @pl.when(t == 0)
     def _init():
         dk_ref[...] = jnp.zeros_like(dk_ref[...])
         dv_ref[...] = jnp.zeros_like(dv_ref[...])
@@ -528,17 +660,18 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
             dq_ref[0][at] += dq
 
     _on_needed_tiles(_accumulate, causal=causal, window=window, i=i, j=j,
-                     block_q=block_q, block_k=block_k)
+                     block_q=block_q, block_k=block_k, nq=nq, nk=nk)
 
 
 def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
                    *rest, scale, causal, window, d_major, block_q, block_k,
-                   has_chosen):
+                   nq, nk, has_chosen):
     c_ref, dq_ref = rest if has_chosen else (None, rest[0])
     i = pl.program_id(2)
-    j = pl.program_id(3)
+    t = pl.program_id(3)
+    j = _stepped(_key_blocks, causal, window, i, t, block_q, block_k)
 
-    @pl.when(j == 0)
+    @pl.when(t == 0)
     def _init():
         dq_ref[...] = jnp.zeros_like(dq_ref[...])
 
@@ -552,7 +685,7 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, b_ref,
                                              d_major)
 
     _on_needed_tiles(_accumulate, causal=causal, window=window, i=i, j=j,
-                     block_q=block_q, block_k=block_k)
+                     block_q=block_q, block_k=block_k, nq=nq, nk=nk)
 
 
 def _bwd_call(q, k, v, bias2d, out, dout, lse,
@@ -604,11 +737,11 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
 
     kernel_args = dict(scale=scale, causal=causal, window=window,
                        d_major=d_major,
-                       block_q=block_q, block_k=block_k,
+                       block_q=block_q, block_k=block_k, nq=nq, nk=nk,
                        has_chosen=chosen is not None)
 
     # dk/dv/db (and dq where it fits) — grid (…, kv, q), q innermost
-    # (accumulated over)
+    # (accumulated over): every block of queries, or a band's
     out_specs = [
         _key_side_spec(d_major, block_k, dk_, lambda b_, h, x, y: (b_, h, x)),
         _spec((None, None, block_k, dv_), lambda b_, h, x, y: (b_, h, x, 0)),
@@ -629,10 +762,11 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
         params = _compiler_params(3, **_vmem_limit(chosen))
     *dq, dk_h, dv_h, db_h = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kernel_args),
-        grid=(b, hq, nk, nq),
+        grid=(b, hq, nk, _inner_steps(_query_blocks, causal, window, nk, nq,
+                                      block_q, block_k)),
         in_specs=in_specs(
             qi=lambda x, y: _needed_q(causal, window, y, x, block_q,
-                                      block_k),
+                                      block_k, nq),
             kj=lambda x, y: x),
         out_specs=out_specs,
         out_shape=out_shape,
@@ -644,11 +778,12 @@ def _bwd_call(q, k, v, bias2d, out, dout, lse,
         # pass 2: dq — grid (…, q, kv), kv innermost (accumulated over)
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, **kernel_args),
-            grid=(b, hq, nq, nk),
+            grid=(b, hq, nq, _inner_steps(_key_blocks, causal, window, nq,
+                                          nk, block_q, block_k)),
             in_specs=in_specs(
                 qi=lambda x, y: x,
                 kj=lambda x, y: _needed_k(causal, window, x, y, block_q,
-                                          block_k)),
+                                          block_k, nk)),
             out_specs=[_key_side_spec(d_major, block_q, dk_,
                                       lambda b_, h, x, y: (b_, h, x))],
             out_shape=[key_side_shape(lq)],
@@ -726,8 +861,8 @@ def flash_attention(
     v: jax.Array,
     bias: Optional[jax.Array] = None,
     causal: bool = False,
-    block_q: int = 512,
-    block_k: int = 1024,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     scale: Optional[float] = None,
     chosen: Optional[jax.Array] = None,
@@ -747,10 +882,14 @@ def flash_attention(
     The kernels visit every tile the causal rule leaves and mask what
     was not chosen; with no ``chosen`` they take the operands and are
     the kernels they were. With ``window`` (causal attention alone) a
-    query sees itself and the ``window - 1`` keys before it, and a tile
-    no query of which sees a key of is neither computed nor fetched,
-    on either side of the band.
+    query sees itself and the ``window - 1`` keys before it, and the
+    kernels' grids run over the band of tiles that leaves: a tile no
+    query of which sees a key of is neither computed nor fetched nor,
+    but for a shorter band's spare steps, stepped over.
 
+    ``block_q`` / ``block_k`` left ``None`` are the kernels' own
+    (:func:`_own_blocks`: 512 x 1,024, under a window of 1,024 tokens
+    or more 1,024 x 1,024), clamped to the sequences.
     Sequence lengths are padded to the block size internally (padded
     keys get -inf bias; padded query rows are sliced off), so any L
     works; multiples of 128 avoid the padding entirely.
@@ -775,7 +914,7 @@ def flash_attention(
         bias2d = bias.reshape(b, lk).astype(jnp.float32)
 
     block_q, block_k, pad_q, pad_k = _prepare_padding(
-        lq, lk, block_q, block_k, interpret
+        lq, lk, *_own_blocks(window, block_q, block_k), interpret
     )
     q = _pad_len(q, pad_q)
     k, v = _pad_len(k, pad_k), _pad_len(v, pad_k)
@@ -904,7 +1043,8 @@ def flash_block_bwd(q, k, v, bias2d, out, dout, lse, causal,
     )
 
 
-def make_flash_attention_fn(block_q: int = 512, block_k: int = 1024,
+def make_flash_attention_fn(block_q: Optional[int] = None,
+                            block_k: Optional[int] = None,
                             interpret: Optional[bool] = None):
     """Seam-compatible ``attention_fn`` (transformer.py:31-32) for any
     model in the zoo: ``model(..., attention_fn=make_flash_attention_fn())``."""

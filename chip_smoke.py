@@ -1048,6 +1048,79 @@ def _experts_one_at_a_time(p, x, idx, gate):
          p["w_down"]))[0]
 
 
+def _window_cores(tiny, shape, window, pairs):
+    """One windowed core at the blocks ``flash_attention`` gives a call
+    that names none (``(None, None)``) and at each of ``pairs`` (blocks
+    of queries x keys), and one full core at its own, ``[1, hq on hkv,
+    seq, dh]`` bfloat16: the kernel (forward and the three gradients)
+    held to the dense masked core a head at a time in float32, and
+    timed. ``{(name, block_q, block_k): (errors, ms a call forward, ms
+    a call forward and backward, the tiles and the grids' steps a
+    head)}``."""
+    import jax
+    import jax.numpy as jnp
+
+    from baton_tpu.ops.flash_attention import (flash_attention, grid_steps,
+                                               tiles_visited)
+
+    hq, hkv, seq, dh = shape
+    kq, kk, kv, kw = jax.random.split(jax.random.key(7), 4)
+    q = jax.random.normal(kq, (1, hq, seq, dh), jnp.bfloat16)
+    k = jax.random.normal(kk, (1, hkv, seq, dh), jnp.bfloat16)
+    v = jax.random.normal(kv, (1, hkv, seq, dh), jnp.bfloat16)
+    w = jax.random.normal(kw, (1, hq, seq, dh), jnp.float32)  # cotangent
+    f32 = partial(jnp.asarray, dtype=jnp.float32)
+
+    def ms_a_call(fn, calls=5):
+        got = jax.block_until_ready(fn(q, k, v))         # compiles
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            got = fn(q, k, v)
+        jax.block_until_ready(got)
+        return (time.perf_counter() - t0) / calls * 1e3, got
+
+    cores = {}
+    own = (None, None)
+    for name, win, at in (("window", window, [own] + pairs),
+                          ("full", None, [own])):
+        @jax.jit
+        def dense(q, k, v, win=win):
+            with jax.default_matmul_precision("highest"):
+                out, back = jax.vjp(partial(_dense_by_head, window=win),
+                                    q, k, v)
+                return back(w) + (out,)
+
+        want = dense(f32(q), f32(k), f32(v))
+        for bq, bk in at:
+            def core(q, k, v, win=win, bq=bq, bk=bk):
+                return flash_attention(q, k, v, causal=True, window=win,
+                                       block_q=bq, block_k=bk,
+                                       interpret=tiny)
+
+            def both(q, k, v, core=core):
+                out, back = jax.vjp(
+                    lambda *qkv: core(*qkv).astype(jnp.float32), q, k, v)
+                return back(w) + (out,)
+
+            if not tiny:
+                _check("tpu_custom_call"
+                       in jax.jit(both).lower(q, k, v).as_text(),
+                       f"the {name} core lowered without a tpu_custom_call")
+            forward_ms, _ = ms_a_call(jax.jit(core))
+            both_ms, got = ms_a_call(jax.jit(both))
+            errs = {n: _rel_err(g, r) for n, g, r in zip(
+                ("dq", "dk", "dv", "out"), got, want)}
+            _check(max(errs.values()) <= BF16_TOL,
+                   f"the {name} core at blocks {bq} x {bk} against the "
+                   f"dense masked one beyond {BF16_TOL}: {errs}")
+            cores[name, bq, bk] = (
+                errs, forward_ms, both_ms,
+                f"{tiles_visited(seq, win, bq, bk)} tiles a head in "
+                f"{grid_steps(seq, win, bq, bk)} steps of its grid forward, "
+                f"{grid_steps(seq, win, bq, bk, backward=True)} backward")
+    return cores
+
+
 def phase_window_lora(env: Env) -> None:
     """Windowed layers beside full ones over small experts, the bfloat16
     path. Two rounds through ``FedSim`` of four blocks (three windowed,
@@ -1059,7 +1132,9 @@ def phase_window_lora(env: Env) -> None:
     one full core at ``mellum2_12b``'s published widths over its cell's
     8,192 tokens, the kernel (forward and the three gradients) against
     the dense masked core a head at a time in float32, with the time of
-    a call of each. Then the configuration's whole stage on one
+    a call of each, the sliding one also at four named pairs of blocks,
+    none of which may beat its own by 3 % of a call
+    (:func:`_window_cores`). Then the configuration's whole stage on one
     sequence, bfloat16 beside float32 at ``highest`` with a plain loop
     over the experts: an expert's largest and smallest share of a
     layer's assignments, and the share of assignments the two streams
@@ -1072,7 +1147,7 @@ def phase_window_lora(env: Env) -> None:
 
     from baton_tpu.models import llama, moe, transformer
     from baton_tpu.models.lora import lora_trainable
-    from baton_tpu.ops.flash_attention import flash_attention, tiles_visited
+    from baton_tpu.ops.flash_attention import grid_steps, tiles_visited
     from baton_tpu.parallel.engine import FedSim
     from fedbench import data as cohort
     from fedbench import manifest
@@ -1123,9 +1198,11 @@ def phase_window_lora(env: Env) -> None:
            f"{facts['core_outputs_kept']} blocks keep a kernel's outputs")
     _check((facts["window"], facts["window_layers"], facts["full_layers"],
             facts["router_scores"], facts["window_tiles"],
-            facts["causal_tiles"]) == (
+            facts["causal_tiles"], facts["window_grid_steps"],
+            facts["causal_grid_steps"]) == (
         window, 3, 1, "softmax_chosen", tiles_visited(length, window),
-        tiles_visited(length)), f"the model says {facts}")
+        tiles_visited(length), grid_steps(length, window),
+        grid_steps(length)), f"the model says {facts}")
 
     # ---- the two cores at the published widths
     sized = manifest.sized(config, tiny)
@@ -1135,43 +1212,18 @@ def phase_window_lora(env: Env) -> None:
     seq = job["seq_len"]
     hq, hkv, dh = (sized["num_attention_heads"], sized["num_key_value_heads"],
                    sized["head_dim"])
-    kq, kk, kv, kw = jax.random.split(jax.random.key(7), 4)
-    q = jax.random.normal(kq, (1, hq, seq, dh), jnp.bfloat16)
-    k = jax.random.normal(kk, (1, hkv, seq, dh), jnp.bfloat16)
-    v = jax.random.normal(kv, (1, hkv, seq, dh), jnp.bfloat16)
-    w = jax.random.normal(kw, (1, hq, seq, dh), jnp.float32)  # cotangent
-    f32 = partial(jnp.asarray, dtype=jnp.float32)
-    cores = {}
-    for name, win in (("window", sized["sliding_window"]), ("full", None)):
-        kernel = jax.jit(lambda q, k, v, win=win: jax.vjp(
-            lambda q, k, v: flash_attention(
-                q, k, v, causal=True, window=win, interpret=tiny).astype(
-                    jnp.float32), q, k, v)[1](w) + (flash_attention(
-                        q, k, v, causal=True, window=win, interpret=tiny),))
-        if not tiny:
-            _check("tpu_custom_call" in kernel.lower(q, k, v).as_text(),
-                   f"the {name} core lowered without a tpu_custom_call")
-        got = jax.block_until_ready(kernel(q, k, v))
-        t0 = time.perf_counter()
-        for _ in range(3):
-            got = kernel(q, k, v)
-        jax.block_until_ready(got)
-        ms = (time.perf_counter() - t0) / 3 * 1e3
-
-        @jax.jit
-        def dense(q, k, v, win=win):
-            with jax.default_matmul_precision("highest"):
-                out, back = jax.vjp(partial(_dense_by_head, window=win),
-                                    q, k, v)
-                return back(w) + (out,)
-
-        want = dense(f32(q), f32(k), f32(v))
-        errs = {n: _rel_err(g, r) for n, g, r in zip(
-            ("dq", "dk", "dv", "out"), got, want)}
-        _check(max(errs.values()) <= BF16_TOL,
-               f"the {name} core against the dense masked one beyond "
-               f"{BF16_TOL}: {errs}")
-        cores[name] = (errs, ms, tiles_visited(seq, win))
+    cores = _window_cores(
+        tiny, (hq, hkv, seq, dh), sized["sliding_window"],
+        [(512, 1024), (512, 512), (1024, 1024), (1024, 512)])
+    # ISSUE 49's threshold: no named pair beats the blocks a windowed
+    # call gets by more than 3 % of a forward and backward call
+    windowed = {at[1:]: core[2] for at, core in cores.items()
+                if at[0] == "window"}
+    best = min(windowed, key=windowed.get)
+    _check(windowed[best] >= 0.97 * windowed[None, None] or tiny,
+           f"the windowed core at blocks {best[0]} x {best[1]} takes "
+           f"{windowed[best]:.2f} ms a call where its own take "
+           f"{windowed[None, None]:.2f}")
 
     # ---- the stage at the published widths: who chooses what
     decoder = manifest.resolve(config["builder"]["kwargs"]["config"], sized)
@@ -1229,11 +1281,16 @@ def phase_window_lora(env: Env) -> None:
     _check(all(r.min() > 0 for r in rows) or tiny,
            f"an expert saw no row: {[r.tolist() for r in rows]}")
     expected = seq * top_k / n_experts
-    (w_errs, w_ms, w_tiles), (f_errs, f_ms, f_tiles) = (cores["window"],
-                                                        cores["full"])
+    (w_errs, _, w_ms, w_tiles), (f_errs, _, f_ms, f_tiles) = (
+        cores["window", None, None], cores["full", None, None])
     timed = ("not measured (rehearsal)" if tiny else
              f"{w_ms:.2f} ms a call forward and backward in the window, "
-             f"{f_ms:.2f} without ({w_ms / f_ms:.3f} of it)")
+             f"{f_ms:.2f} without ({w_ms / f_ms:.3f} of it); forward alone "
+             f"and forward and backward by the blocks of queries x keys: "
+             + ", ".join(
+                 f"{name} {f'{bq} x {bk}' if bq else 'its own'} {fwd:.2f} "
+                 f"and {fb:.2f} ms ({tiles})"
+                 for (name, bq, bk), (_, fwd, fb, tiles) in cores.items()))
     env.say("window_lora",
             f"{model.name}: three windowed blocks and a full one (yarn), 8 "
             f"on 2 heads of 128, 8 experts 2 a token by softmax over the "
@@ -1247,9 +1304,9 @@ def phase_window_lora(env: Env) -> None:
             f"[1, {hq} on {hkv}, {seq}, {dh}] against the dense masked core "
             f"in float32: window {sized['sliding_window']} "
             + " ".join(f"{n}={e:.1e}" for n, e in w_errs.items())
-            + f" ({w_tiles} tiles a head), full "
+            + f" ({w_tiles}), full "
             + " ".join(f"{n}={e:.1e}" for n, e in f_errs.items())
-            + f" ({f_tiles} tiles); {timed}. The stage, {len(rows)} layers, "
+            + f" ({f_tiles}); {timed}. The stage, {len(rows)} layers, "
             f"{seq} tokens, seed {seed}: a layer (fullest expert's rows, "
             f"emptiest's) "
             + " ".join(f"({r.max()}, {r.min()})" for r in rows)

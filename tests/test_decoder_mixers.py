@@ -247,7 +247,8 @@ def test_the_two_kinds_of_a_windowed_model_take_their_own_rotary_tables():
     assert dict(recorded.span_attrs)["window_tiles"] == 1
     published = _accepted("mellum2_12b", False)
     seen = MIXERS["sliding_attention"].seen(published, 8192)
-    assert seen == {"window_tiles": 30, "causal_tiles": 72}
+    assert seen == {"window_tiles": 15, "causal_tiles": 72,
+                    "window_grid_steps": 16, "causal_grid_steps": 128}
     assert MIXERS["full_attention"].seen(published, 8192) == {}
 
 

@@ -389,6 +389,82 @@ def test_a_window_forward_and_all_three_gradients(window, block_q, block_k,
             rtol=1e-6, atol=1e-6)
 
 
+# the band's grid with what the other tests leave out: a gradient of the
+# keys' bias, keys that ride the sublanes (192 wide) and keys that do
+# not, and 40 tokens that the two blocks pad apart (to 48 queries and 40
+# keys, to 40 and 48), so that a band runs past its side's last block.
+# Windows: one key, less than a block, a block, several, and more than
+# the sequence
+BANDS = [(window, width, blocks)
+         for window in (1, 5, 8, 20, 100)
+         for width, blocks in ((8, (16, 8)), (192, (8, 16)))]
+
+
+@pytest.mark.parametrize("window,width,blocks", BANDS)
+def test_a_windows_band_against_the_dense_core(window, width, blocks,
+                                               backward_form):
+    """The windowed kernels, whose grids run over the band, against
+    ``dot_product_attention`` with grouped heads (4 query heads on 2):
+    the output and the gradients of q, k, v and the keys' bias, in both
+    forms of the backward."""
+    from baton_tpu.ops.flash_attention import _keys_ride_sublanes
+
+    assert _keys_ride_sublanes(width) is (width == 192)
+    length = 40
+    q, k, v = _qkv(21, 2, 4, 2, length, width)
+    bias = 0.5 * _rand(jax.random.key(22), 2, 1, 1, length)
+    weight = _rand(jax.random.key(23), 2, 4, length, width)
+
+    def dense(q, k, v, bias):
+        return dot_product_attention(q, k, v, bias=bias, causal=True,
+                                     window=window)
+
+    def flash(q, k, v, bias):
+        return flash_attention(q, k, v, bias=bias, causal=True,
+                               window=window, block_q=blocks[0],
+                               block_k=blocks[1])
+
+    want, back = jax.vjp(dense, q, k, v, bias)
+    got, kernel_back = jax.vjp(flash, q, k, v, bias)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    for g, w in zip(kernel_back(weight), back(weight)):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_a_windowed_call_that_names_no_blocks_gets_the_kernels_own():
+    """Under a window of 1,024 a call that names no blocks runs at 1,024
+    x 1,024 (``_own_blocks``): bit for bit the call that names them,
+    and the dense masked core's output and gradients, over 2,304 tokens
+    (three blocks of queries, the last one padded)."""
+    length, window = 2304, 1024
+    q, k, v = _qkv(31, 1, 2, 1, length, 8)
+    weight = _rand(jax.random.key(32), 1, 2, length, 8)
+
+    def dense(q, k, v):
+        return dot_product_attention(q, k, v, causal=True, window=window)
+
+    def own(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window)
+
+    def named(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=1024, block_k=1024)
+
+    want, back = jax.vjp(dense, q, k, v)
+    got, own_back = jax.vjp(own, q, k, v)
+    same, named_back = jax.vjp(named, q, k, v)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+    for g, n, w in zip(own_back(weight), named_back(weight), back(weight)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(n))
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+
+
 def test_a_window_needs_the_causal_rule():
     q, k, v = _qkv(12, 1, 2, 2, 16, 8)
     with pytest.raises(AssertionError, match="window"):
@@ -454,54 +530,121 @@ def test_a_skipped_tile_is_not_read(window, block_q, block_k, backward_form):
                                        rtol=1e-5, atol=1e-5)
 
 
-def test_the_rule_the_skip_and_the_index_maps_are_one():
+# blocks of queries and of keys, and how many of each: lengths that both
+# blocks divide, and 40 tokens padded to each block on its own (the
+# queries further than the keys, and the keys further than the queries)
+GRIDS = [(8, 16, 8, 4), (16, 8, 4, 8), (8, 8, 6, 6), (32, 8, 2, 8),
+         (16, 8, 3, 5), (8, 16, 5, 3)]
+
+
+@pytest.mark.parametrize("block_q,block_k,n_q,n_k", GRIDS)
+def test_the_rule_the_skip_and_the_index_maps_are_one(block_q, block_k, n_q,
+                                                      n_k):
     """By brute force over blocks and windows that are and are not
     multiples of one another: a tile is needed exactly where the rule
     holds for some pair of its positions; the block ranges the index
     maps hold a step to are exactly the needed tiles of a row or a
-    column of the grid; a held step names a needed tile."""
+    column of the grid; and the grids as the kernels walk them, the
+    sequence without a window and the band under one: the steps of
+    every row and of every column compute each needed tile once and
+    nothing else, a step that computes asks for its own tile and one
+    that does not for a needed tile of its row or column (a band's
+    spare step: the last one again), and under a window no row and no
+    column has more than the band's spare steps to skip."""
     from baton_tpu.ops import flash_attention as fa
 
-    for block_q, block_k, length in ((8, 16, 64), (16, 8, 64), (8, 8, 48),
-                                     (32, 8, 64)):
-        n_q, n_k = length // block_q, length // block_k
-        for window in (None, 1, 5, 8, 16, 20, 33, 200):
-            needed = np.zeros((n_q, n_k), bool)
-            for i in range(n_q):
-                for j in range(n_k):
-                    q_pos = i * block_q + np.arange(block_q)[:, None]
-                    k_pos = j * block_k + np.arange(block_k)[None, :]
-                    pairs = fa._sees(q_pos, k_pos, True, window)
-                    needed[i, j] = pairs.any()
-                    assert bool(fa._tile_is_needed(
-                        True, window, i, j, block_q, block_k)) \
-                        == needed[i, j]
-            for i in range(n_q):
-                first, last = fa._key_blocks(True, window, i, block_q,
-                                             block_k)
-                first = 0 if first is None else int(first)
-                row = np.flatnonzero(needed[i])
-                assert (row[0], row[-1]) == (first, min(int(last), n_k - 1))
-                assert len(row) == row[-1] - row[0] + 1  # a band
-                for j in range(n_k):
-                    held = int(fa._needed_k(True, window, i, j, block_q,
-                                            block_k))
-                    assert needed[i, held] and (held == j) == needed[i, j]
+    for window in (None, 1, 5, 8, 16, 20, 33, 200):
+        needed = np.zeros((n_q, n_k), bool)
+        for i in range(n_q):
             for j in range(n_k):
-                first, last = fa._query_blocks(True, window, j, block_q,
-                                               block_k)
-                last = n_q - 1 if last is None else min(int(last), n_q - 1)
-                col = np.flatnonzero(needed[:, j])
-                assert (col[0], col[-1]) == (int(first), last)
-                for i in range(n_q):
-                    held = int(fa._needed_q(True, window, i, j, block_q,
-                                            block_k))
-                    assert needed[held, j] and (held == i) == needed[i, j]
-    # the benchmark's shape: a window of 1,024 over 8,192 tokens
-    assert (fa.tiles_visited(8192, 1024), fa.tiles_visited(8192)) == (30, 72)
+                q_pos = i * block_q + np.arange(block_q)[:, None]
+                k_pos = j * block_k + np.arange(block_k)[None, :]
+                pairs = fa._sees(q_pos, k_pos, True, window)
+                needed[i, j] = pairs.any()
+                assert bool(fa._tile_is_needed(
+                    True, window, i, j, block_q, block_k)) == needed[i, j]
+        at = (True, window, block_q, block_k)
+        sides = (
+            # a row of the forward's and of dq's grid, then a column of
+            # dk's and dv's: the band's rule, the other side's blocks,
+            # the index map of (outer, step), tile (i, j) of the two
+            (fa._key_blocks, n_q, n_k, lambda i, t: fa._needed_k(
+                True, window, i, t, block_q, block_k, n_k),
+             lambda i, j: (i, j), needed),
+            (fa._query_blocks, n_k, n_q, lambda j, t: fa._needed_q(
+                True, window, t, j, block_q, block_k, n_q),
+             lambda j, i: (i, j), needed.T))
+        for blocks, n_outer, n_inner, asked, tile, seen in sides:
+            steps = fa._inner_steps(blocks, True, window, n_outer, n_inner,
+                                    block_q, block_k)
+            longest = 0
+            for outer in range(n_outer):
+                first, last = blocks(True, window, outer, block_q, block_k)
+                first = 0 if first is None else int(first)
+                last = n_inner - 1 if last is None else min(int(last),
+                                                            n_inner - 1)
+                band = np.flatnonzero(seen[outer])
+                assert (band[0], band[-1]) == (first, last)
+                assert len(band) == last - first + 1  # a band
+                longest = max(longest, len(band))
+                computed = []
+                for t in range(steps):
+                    inner = int(fa._stepped(blocks, True, window, outer, t,
+                                            block_q, block_k))
+                    held = int(asked(outer, t))
+                    assert seen[outer, held]
+                    if fa._step_computes(True, window, *tile(outer, inner),
+                                         block_q, block_k, n_q, n_k):
+                        computed.append(inner)
+                        assert held == inner
+                    elif window is not None:  # a spare step: the last again
+                        assert (held, inner > last) == (last, True)
+                assert computed == list(band)
+            assert steps == (n_inner if window is None else longest)
+
+
+def test_the_grids_steps_are_counted_as_the_kernels_walk_them():
+    """The benchmark's shape, a window of 1,024 over 8,192 tokens: at
+    512 x 1,024 blocks 30 tiles in 32 steps forward (16 blocks of
+    queries by 2 of keys) and backward (8 blocks of keys by 4 of
+    queries) where the grid over the sequence takes 128 for the causal
+    kernel's 72; at the blocks a windowed call gets where it names none
+    (1,024 x 1,024 under a window of 1,024 or more) 15 tiles in 16."""
+    from baton_tpu.ops import flash_attention as fa
+
+    assert (fa.tiles_visited(8192, 1024, 512, 1024),
+            fa.tiles_visited(8192)) == (30, 72)
     assert (fa.tiles_visited(8192, 1024, 1024, 1024),
             fa.tiles_visited(8192, None, 1024, 1024)) == (15, 36)
-    assert fa.tiles_visited(1024, 1024) == fa.tiles_visited(1024)
+    assert fa.tiles_visited(1024, 1024, 512, 1024) == fa.tiles_visited(1024)
+    assert [fa.grid_steps(8192, 1024, 512, 1024, backward=b)
+            for b in (False, True)] == [32, 32]
+    assert [fa.grid_steps(8192, backward=b) for b in (False, True)] \
+        == [128, 128]
+    # a call that names no blocks: the windowless 512 x 1,024 whatever
+    # the length, and under a window the window decides
+    assert fa._own_blocks(None, None, None) == (512, 1024)
+    assert fa._own_blocks(1023, None, None) == (512, 1024)
+    assert fa._own_blocks(1024, None, None) == (1024, 1024)
+    assert fa._own_blocks(4096, None, 512) == (1024, 512)
+    assert fa._own_blocks(4096, 256, None) == (256, 1024)
+    assert (fa.tiles_visited(8192, 1024), fa.grid_steps(8192, 1024),
+            fa.grid_steps(8192, 1024, backward=True)) == (15, 16, 16)
+    assert (fa.tiles_visited(8192, 512), fa.grid_steps(8192, 512)) \
+        == (fa.tiles_visited(8192, 512, 512, 1024),
+            fa.grid_steps(8192, 512, 512, 1024))
+    # blocks of 512 x 512 (3 a row and a column), 1,024 x 1,024 (2), and
+    # 1,024 x 512 (4 blocks of keys a row, 2 of queries a column)
+    assert [fa.grid_steps(8192, 1024, bq, bk, backward=b)
+            for bq, bk in ((512, 512), (1024, 1024), (1024, 512))
+            for b in (False, True)] == [48, 48, 16, 16, 32, 32]
+    # a window that never binds walks a row's causal prefix, the longest
+    # of which is the sequence; one key a query, one block or two
+    assert fa.grid_steps(1024, 4096, 128, 128) == 8 * 8
+    assert fa.grid_steps(1024, 1, 128, 128) == 8
+    assert fa.grid_steps(1024, 1, 256, 128) == 4 * 2
+    # a sequence shorter than a block is one step
+    assert fa.grid_steps(100, 10) == fa.grid_steps(100) == 1
 
 
 # jaxpr digests of calls without a window, taken on the commit before

@@ -337,10 +337,15 @@ def test_a_windowed_block_holds_no_square_and_its_kernels_clamp_both_sides(
     block beside it: no array of the program is ``[8192, 8192]`` in its
     last two dims (the dense path would mask one), each block is the
     forward kernel and the one backward kernel, and the windowed block's
-    kernels name their blocks of keys (forward) and of queries
-    (backward) through a ``min`` *and* a ``max``, around ``window - 1``:
-    the DMAs are held to the band from both sides. The full block's
-    clamp one side, as they did."""
+    grids run over the band at the blocks a windowed call gets (1,024
+    x 1,024 under this window): 2 blocks of keys a block of queries
+    forward and 2 blocks of queries a block of keys backward, where the
+    full block's (512 x 1,024) run over the sequence's 8 and 16. The
+    windowed kernels
+    name their blocks of keys (forward) and of queries (backward) as
+    the band's first, around ``window - 1``, plus the inner step, held
+    by a ``min`` to its last: the DMAs stay inside the band. The full
+    block's maps clamp the step itself, one side, as they did."""
     import base64
 
     from jax._src.interpreters import mlir
@@ -386,26 +391,38 @@ def test_a_windowed_block_holds_no_square_and_its_kernels_clamp_both_sides(
                 asm = ir.Module.parse(base64.b64decode(
                     body)).operation.get_asm(enable_debug_info=False)
                 name = re.search(r"module @(\w+)", asm).group(1)
+                grid = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>",
+                                 asm).group(1)
                 # the index maps, one a ``func`` after the kernel's own
-                out[name] = [f for f in asm.split("func.func\"()")[1:]
-                             if 'sym_name = "transform_' in f]
+                out[name] = (tuple(map(int, grid.split(","))), [
+                    f for f in asm.split("func.func\"()")[1:]
+                    if 'sym_name = "transform_' in f])
         return out
 
-    def held_both_sides(maps):
-        return [("arith.minsi" in f, "arith.maxsi" in f) for f in maps]
+    def held(maps):
+        """An index map's ``min``, its ``max``, and whether it adds the
+        inner grid index to something it computed: a band's offset."""
+        return [("arith.minsi" in f, "arith.maxsi" in f,
+                 bool(re.search(r'arith\.addi"\(%\d+, %arg3\)', f)))
+                for f in maps]
 
     windowed, full = kernels_of("sliding_attention"), kernels_of(
         "full_attention")
     assert set(windowed) == set(full) == {"_fwd_kernel", "_bwd_dkv_kernel"}
-    # forward: q, then k, v and the keys' bias by the block of keys
-    assert held_both_sides(windowed["_fwd_kernel"])[1:4] == [(True, True)] * 3
-    assert held_both_sides(full["_fwd_kernel"])[1:4] == [(True, False)] * 3
+    grids = {name: (windowed[name][0], full[name][0]) for name in windowed}
+    assert grids == {"_fwd_kernel": ((1, 32, 8, 2), (1, 32, 16, 8)),
+                     "_bwd_dkv_kernel": ((1, 32, 8, 2), (1, 32, 8, 16))}
+    windowed, full = ({name: maps for name, (_, maps) in kernels.items()}
+                      for kernels in (windowed, full))
+    # forward: q, then k, v and the keys' bias by the block of keys (the
+    # band's first is floored at block 0: the ``max``)
+    assert held(windowed["_fwd_kernel"])[0] == (False, False, False)
+    assert held(windowed["_fwd_kernel"])[1:4] == [(True, True, True)] * 3
+    assert held(full["_fwd_kernel"])[1:4] == [(True, False, False)] * 3
     # backward (keys outer, queries inner): q, do, lse and delta by the
-    # block of queries
-    assert held_both_sides(windowed["_bwd_dkv_kernel"])[:4] \
-        == [(True, True)] * 4
-    assert held_both_sides(full["_bwd_dkv_kernel"])[:4] \
-        == [(False, True)] * 4
+    # block of queries (a band's first is the causal bound's, no floor)
+    assert held(windowed["_bwd_dkv_kernel"])[:4] == [(True, False, True)] * 4
+    assert held(full["_bwd_dkv_kernel"])[:4] == [(False, True, False)] * 4
     for maps in windowed.values():
         assert any("value = 1023 : i32" in f for f in maps)
     for maps in full.values():
