@@ -22,9 +22,19 @@ Layout:
   data/      synthetic data + IID/Dirichlet partitioners
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()
+
 __version__ = "0.1.0"
 
 from baton_tpu.core.model import FedModel  # noqa: F401
 from baton_tpu.core.training import LocalTrainer, make_local_trainer  # noqa: F401
 from baton_tpu.ops.aggregation import weighted_tree_mean  # noqa: F401
 from baton_tpu.parallel.engine import FedSim, RoundResult  # noqa: F401
+
+#: host seconds this package's import took in this process, whatever it
+#: pulled in that was not yet imported (JAX itself, where the caller had
+#: not imported it): the part of a job's start that only the program can
+#: shorten
+IMPORT_S = _time.perf_counter() - _IMPORT_T0

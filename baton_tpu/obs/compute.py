@@ -22,24 +22,33 @@ Three design rules, each a recorded postmortem:
   "stopped measuring" and hides regressions.
 * **Compile visibility.** :class:`CompileTracker` watches the shape
   signatures each jitted callable is invoked with: a new signature is a
-  cache miss (XLA compiled during that call), and repeated new
-  signatures within a short window are a *recompile storm* — the
-  shape-churn pathology that silently multiplies round latency.
+  cache miss, and repeated new signatures within a short window are a
+  *recompile storm* — the shape-churn pathology that silently
+  multiplies round latency. It owns no time: what a build cost is
+  JAX's to say, and :class:`BuildLedger` hears it.
 
-``compile_s`` on a cache miss is the compiling call's wall time — an
-upper bound that includes one execution (the live path cannot afford a
-separate warm-up run; ``compile_s_source`` says so). On a cache hit it is
-an exact 0.0.
+``compile_s`` is the seconds JAX spent tracing, lowering and building
+(compiling, or loading from the persistent cache) programs inside the
+round, as ``jax.monitoring`` reported them (``compile_s_source:
+"jax_monitoring"``); ``compile_cold_s`` is the part of it that the
+persistent cache did not serve. A round in which nothing was built
+reads an exact 0.0 (``"cache_hit"``).
 
-Pure stdlib + optional lazy jax: the FLOPs/MFU math and the tracker
-import and unit-test without an accelerator stack.
+The FLOPs/MFU math and the tracker touch no backend: they import and
+unit-test without an accelerator.
 """
 
 from __future__ import annotations
 
 import math
+import threading
+import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+
+from jax import monitoring
+
+from baton_tpu.utils.profiling import annotate
 
 __all__ = [
     "RESNET18_CIFAR_FWD_FLOPS_PER_IMG",
@@ -52,6 +61,9 @@ __all__ = [
     "peak_flops_for",
     "compute_mfu",
     "CompileTracker",
+    "Build",
+    "BuildLedger",
+    "builds",
     "ComputeProbe",
     "build_record",
     "validate_record",
@@ -181,10 +193,11 @@ RECOMPILE_STORM_WINDOW = 8
 class CompileTracker:
     """Shape-signature watcher for jitted callables.
 
-    The live path cannot see inside XLA's jit cache, but it controls the
-    cache key: a call with a signature this tracker has not seen for
-    ``key`` compiled during that call. ``observe`` returns the compile
-    fields of the round's compute record.
+    The live path controls a jit's cache key: a call with a signature
+    this tracker has not seen for ``key`` builds a program. The engine's
+    control flow asks ``seen`` before a round, and ``observe`` returns
+    the round's ``cache_hit``, ``recompiles`` and ``recompile_storm``.
+    What the build cost is the :class:`BuildLedger`'s to say.
     """
 
     def __init__(
@@ -202,15 +215,9 @@ class CompileTracker:
         with one it has not had will compile."""
         return signature in self._sigs.get(key, ())
 
-    def observe(
-        self,
-        key: Any,
-        signature: Any,
-        wall_s: Optional[float] = None,
-    ) -> dict:
+    def observe(self, key: Any, signature: Any) -> dict:
         """Record one invocation of callable ``key`` with shape
-        ``signature``; ``wall_s`` is that call's wall time (the
-        compile_s upper bound on a miss)."""
+        ``signature``."""
         sigs = self._sigs.setdefault(key, set())
         miss = signature not in sigs
         if miss:
@@ -219,21 +226,213 @@ class CompileTracker:
             key, deque(maxlen=self.storm_window)
         )
         recent.append(miss)
-        out: dict = {
+        return {
             "cache_hit": not miss,
             "recompiles": max(0, len(sigs) - 1),
             "recompile_storm": sum(recent) >= self.storm_threshold,
         }
-        if not miss:
-            out["compile_s"] = 0.0
-            out["compile_s_source"] = "cache_hit"
-        elif wall_s is not None:
-            out["compile_s"] = float(wall_s)
-            out["compile_s_source"] = "first_call_wall"
-        else:
-            out["compile_s"] = None
-            out["compile_s_reason"] = "wall time unavailable for compiling call"
+
+
+# ---------------------------------------------------------------------------
+# The build ledger: what JAX itself says of every program it makes
+
+#: JAX's three build events (``jax/_src/dispatch.py``), each a scalar
+#: on entry and a duration on exit, both with ``fun_name``
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+#: fired inside a backend event that the persistent cache served
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+#: outermost events kept one by one; the totals count every one
+_EVENTS_KEPT = 4096
+
+
+class Build(NamedTuple):
+    """One outermost build event. ``ended`` is the host's
+    ``time.perf_counter()`` at its end; ``cache_hit`` is a ``backend``
+    event's (whether the persistent cache served it), else ``None``."""
+
+    program: str
+    phase: str
+    seconds: float
+    ended: float
+    cache_hit: Optional[bool] = None
+
+
+def _new_totals() -> dict:
+    return {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+            "cold_s": 0.0, "builds": 0, "cache_hits": 0}
+
+
+def _program_of(fun_name: str) -> str:
+    """A trace names its function (``outer``), the lowering and the
+    build that follow name the module (``jit(outer)``): one program."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+class _Open(threading.local):
+    """A thread's open build events: how many, and what the outermost
+    one is (``span`` is ``None`` while none is open)."""
+
+    depth = 0
+    phase = program = ""
+    span: Any = None
+    cache_hit = False
+
+
+class BuildLedger:
+    """Every jaxpr trace, lowering and backend build of this process,
+    by program, from ``jax.monitoring``'s own events.
+
+    The events nest: tracing a decoder enters thousands of inner jits,
+    a lowering rule may trace, an eager operation inside a trace builds
+    a program of its own. One depth a thread runs over the three phases
+    and only an event entered at depth 0 is recorded, under its own
+    phase with everything it holds, so no second is counted twice and
+    the three totals sum to no more than the wall time they took. An
+    inner event costs its two listeners an addition each.
+
+    Across each recorded event the ledger holds open a
+    ``baton.build.<phase>`` span (:func:`~baton_tpu.utils.profiling.
+    annotate`, attribute ``program``; ``cache_hit`` on ``.backend``):
+    in a profiler session a build lies on the device planes' clock
+    inside whatever ``baton.round.*`` span made it."""
+
+    def __init__(self) -> None:
+        self._open = _Open()
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        """Forget what was recorded (for tests). Events that are open
+        stay open and are recorded when they end."""
+        with self._lock:
+            self._totals = _new_totals()
+            self._by_program: Dict[str, dict] = {}
+            self._events: deque = deque(maxlen=_EVENTS_KEPT)
+
+    def listen(self) -> None:
+        """Register with ``jax.monitoring``; JAX keeps its listeners for
+        the life of the process, so once a ledger."""
+        monitoring.register_scalar_listener(self._on_scalar)
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    # -- the three listeners -------------------------------------------
+    def _on_scalar(self, event: str, value, fun_name: str = "", **_) -> None:
+        phase = _PHASES.get(event)
+        if phase is None:
+            return
+        opened = self._open
+        opened.depth += 1
+        if opened.depth > 1:
+            return
+        opened.phase, opened.program = phase, _program_of(fun_name)
+        opened.cache_hit = False
+        opened.span = annotate("baton.build." + phase, program=opened.program)
+        opened.span.__enter__()
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == _CACHE_HIT:
+            self._open.cache_hit = True
+
+    def _on_duration(self, event: str, seconds: float, **_) -> None:
+        opened = self._open
+        if event not in _PHASES:
+            return
+        if not opened.depth:  # entered before this ledger listened
+            return
+        opened.depth -= 1
+        if opened.depth:
+            return
+        ended = time.perf_counter()
+        span, opened.span = opened.span, None
+        if span is None:  # its entry raised before the span opened
+            return
+        phase, program = opened.phase, opened.program
+        backend = phase == "backend"
+        hit = opened.cache_hit if backend else None
+        if backend:
+            span.set_metadata(cache_hit=int(hit))
+        span.__exit__(None, None, None)
+        with self._lock:
+            self._events.append(Build(program, phase, seconds, ended, hit))
+            for totals in (self._totals,
+                           self._by_program.setdefault(program,
+                                                       _new_totals())):
+                totals[phase + "_s"] += seconds
+                if backend:
+                    totals["builds"] += 1
+                    if hit:
+                        totals["cache_hits"] += 1
+                    else:
+                        totals["cold_s"] += seconds
+
+    # -- what it answers -----------------------------------------------
+    def totals(self) -> dict:
+        """``{trace_s, lower_s, backend_s, cold_s, builds, cache_hits}``
+        of the whole process: seconds in outermost traces, lowerings and
+        backend builds; of ``backend_s``, the seconds of builds the
+        persistent cache did not serve; the backend builds, and how many
+        of them the cache served."""
+        with self._lock:
+            return dict(self._totals)
+
+    def by_program(self) -> Dict[str, dict]:
+        """The same six numbers for each program."""
+        with self._lock:
+            return {p: dict(t) for p, t in self._by_program.items()}
+
+    def events(self) -> List[Build]:
+        """The last outermost events, oldest first."""
+        with self._lock:
+            return list(self._events)
+
+    def since(self, t: float) -> List[Build]:
+        """The outermost events that ended after host time ``t``
+        (``time.perf_counter()``), newest first."""
+        out = []
+        with self._lock:
+            for build in reversed(self._events):
+                if build.ended <= t:
+                    break
+                out.append(build)
         return out
+
+    def summary(self, top: int = 5) -> List[str]:
+        """Lines a person can read: the totals, then the ``top``
+        programs by seconds."""
+        totals, programs = self.totals(), self.by_program()
+
+        def line(name: str, t: dict) -> str:
+            return (f"{name}: trace {t['trace_s']:.3f} s, lower "
+                    f"{t['lower_s']:.3f} s, backend {t['backend_s']:.3f} s "
+                    f"({t['cold_s']:.3f} s not from the cache; "
+                    f"{t['builds']} builds, {t['cache_hits']} cache hits)")
+
+        def cost(item) -> float:
+            return item[1]["trace_s"] + item[1]["lower_s"] + item[1][
+                "backend_s"]
+
+        return [line(f"{len(programs)} programs", totals)] + [
+            line(name, t)
+            for name, t in sorted(programs.items(), key=cost,
+                                  reverse=True)[:top]]
+
+
+_LEDGER = BuildLedger()
+_LEDGER.listen()
+
+
+def builds() -> BuildLedger:
+    """The process's one build ledger; it has listened since this
+    module was first imported."""
+    return _LEDGER
 
 
 # ---------------------------------------------------------------------------
@@ -405,7 +604,15 @@ class ComputeProbe:
 
                 self._cached_device = jax.devices()[0]
             dev = self._cached_device
-        compile_fields = self.tracker.observe(key, signature, wall_s=train_s)
+        compile_fields = self.tracker.observe(key, signature)
+        # both callers come here at their round's end with its wall time
+        built = _LEDGER.since(time.perf_counter() - train_s)
+        compile_fields.update(
+            compile_s=round(sum(b.seconds for b in built), 6),
+            compile_s_source="jax_monitoring" if built else "cache_hit",
+            # of compile_s, what the persistent cache did not serve
+            compile_cold_s=round(sum(b.seconds for b in built
+                                     if b.cache_hit is False), 6))
         hbm_gb, hbm_src, hbm_why = self._peak_hbm(dev)
         return build_record(
             train_s=train_s,
@@ -452,8 +659,8 @@ def summarize_round(records: Sequence[dict]) -> dict:
     records = [r for r in records if isinstance(r, dict)]
     out: dict = {"reporters": len(records)}
     if not records:
-        for key in ("compile_s", "steps", "samples_per_sec_per_chip",
-                    "mfu", "peak_hbm_gb"):
+        for key in ("compile_s", "compile_cold_s", "steps",
+                    "samples_per_sec_per_chip", "mfu", "peak_hbm_gb"):
             out[key] = None
             out[f"{key}_reason"] = "no compute records this round"
         out["recompile_storms"] = 0
@@ -469,6 +676,7 @@ def summarize_round(records: Sequence[dict]) -> dict:
             )
 
     put("compile_s", _nums(records, "compile_s"), max)
+    put("compile_cold_s", _nums(records, "compile_cold_s"), max)
     steps = _nums(records, "steps")
     out["steps"] = int(sum(steps)) if steps else None
     if not steps:

@@ -154,7 +154,7 @@ def _clean_timings(raw: Any) -> Optional[dict]:
 _COMPUTE_NUM_KEYS = (
     "train_s", "steps", "n_chips", "samples_per_sec",
     "samples_per_sec_per_chip", "mfu", "flops_per_sample",
-    "compile_s", "recompiles", "peak_hbm_gb",
+    "compile_s", "compile_cold_s", "recompiles", "peak_hbm_gb",
 )
 _COMPUTE_STR_KEYS = (
     "device_kind", "model_family",

@@ -88,28 +88,24 @@ def test_register_model_flops_roundtrip():
 
 def test_compile_tracker_hit_miss_and_storm():
     t = CompileTracker()
-    first = t.observe("train", ("sig", 1), wall_s=2.5)
+    first = t.observe("train", ("sig", 1))
     assert first["cache_hit"] is False
-    assert first["compile_s"] == 2.5
-    assert first["compile_s_source"] == "first_call_wall"
+    # the tracker owns no time: a build's seconds are the ledger's
+    assert set(first) == {"cache_hit", "recompiles", "recompile_storm"}
     assert first["recompiles"] == 0
     assert first["recompile_storm"] is False
+    assert t.seen("train", ("sig", 1)) and not t.seen("train", ("sig", 2))
 
-    hit = t.observe("train", ("sig", 1), wall_s=0.4)
+    hit = t.observe("train", ("sig", 1))
     assert hit["cache_hit"] is True
-    assert hit["compile_s"] == 0.0
-    assert hit["compile_s_source"] == "cache_hit"
+    assert hit["recompiles"] == 0
 
     # shape churn: enough NEW signatures in the window flips the flag
     out = {}
     for i in range(2, 2 + RECOMPILE_STORM_THRESHOLD):
-        out = t.observe("train", ("sig", i), wall_s=1.0)
+        out = t.observe("train", ("sig", i))
     assert out["recompile_storm"] is True
     assert out["recompiles"] == RECOMPILE_STORM_THRESHOLD
-
-    # a miss without wall time is null-with-reason, not a bare null
-    nowall = t.observe("train", ("sig", 99))
-    assert nowall["compile_s"] is None and nowall["compile_s_reason"]
 
 
 # ----------------------------------------------------------------------
@@ -157,23 +153,44 @@ def test_build_record_unknowns_are_null_with_reason():
 
 
 def test_probe_record_round_on_cpu():
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from baton_tpu.obs.compute import builds
+
     probe = ComputeProbe(model="lineartest")
+    # a round that builds a program: what JAX says the build took is
+    # the round's compile_s, and it lies inside the round's wall time
+    t0 = time.perf_counter()
+    jax.block_until_ready(
+        jax.jit(lambda x: jnp.tanh(x) * 0.25 + 1.5)(jnp.ones(7)))
+    built_s = sum(b.seconds for b in builds().since(t0))
     rec = probe.record_round(
-        key="train", signature=("s", 1), train_s=0.5, n_samples=64.0,
+        key="train", signature=("s", 1),
+        train_s=time.perf_counter() - t0, n_samples=64.0,
         n_epochs=2, steps=4,
     )
     assert validate_record(rec) == []
     assert rec["steps"] == 4
-    assert rec["samples_per_sec"] == pytest.approx(256.0)
-    assert rec["compile_s_source"] == "first_call_wall"
+    assert rec["samples_per_sec"] == pytest.approx(
+        128.0 / rec["train_s"], rel=1e-3)
+    assert rec["cache_hit"] is False
+    assert rec["compile_s_source"] == "jax_monitoring"
+    assert 0 < rec["compile_s"] <= rec["train_s"]
+    assert rec["compile_s"] == pytest.approx(built_s, abs=2e-6)
+    assert 0 <= rec["compile_cold_s"] <= rec["compile_s"]
     # CPU smoke: MFU + HBM are unmeasurable, and each says why
     assert rec["mfu"] is None and rec["mfu_reason"]
     assert rec["peak_hbm_gb"] is None and rec["peak_hbm_gb_reason"]
-    # second identical call is a cache hit
+    # a second identical call in which nothing was built is a cache hit
     rec2 = probe.record_round(
-        key="train", signature=("s", 1), train_s=0.1, n_samples=64.0,
+        key="train", signature=("s", 1), train_s=0.0, n_samples=64.0,
     )
     assert rec2["cache_hit"] is True and rec2["compile_s"] == 0.0
+    assert rec2["compile_s_source"] == "cache_hit"
+    assert rec2["compile_cold_s"] == 0.0
 
 
 def test_summarize_round_aggregates_and_keeps_reasons():
@@ -182,7 +199,8 @@ def test_summarize_round_aggregates_and_keeps_reasons():
         model_family="resnet18_cifar",
         compile_fields={"cache_hit": False, "recompiles": 1,
                         "recompile_storm": True, "compile_s": 1.5,
-                        "compile_s_source": "first_call_wall"},
+                        "compile_s_source": "jax_monitoring",
+                        "compile_cold_s": 0.5},
         peak_hbm_gb=3.0, peak_hbm_source="allocator",
     )
     r2 = build_record(
@@ -197,6 +215,8 @@ def test_summarize_round_aggregates_and_keeps_reasons():
     assert validate_record(s) == []
     assert s["reporters"] == 2
     assert s["compile_s"] == 1.5            # max
+    # r2's record carries no cold seconds: the one that does is the max
+    assert s["compile_cold_s"] == 0.5
     assert s["steps"] == 16                 # sum
     assert s["peak_hbm_gb"] == 3.5          # max
     assert s["recompile_storms"] == 1

@@ -180,7 +180,7 @@ def test_a_new_cohort_shape_mid_run_settles_the_round_before_and_then_itself(
     syncs = [a for name, a in recorder.opened if name == "baton.round.sync"]
     assert [(a["settles"], a["own"]) for a in syncs] == [(3, 0), (4, 1)]
     assert sim.last_compute["steps"] == 3 * 2
-    assert sim.last_compute["compile_s_source"] == "first_call_wall"
+    assert sim.last_compute["compile_s_source"] == "jax_monitoring"
     # the shape it had before is still on the fast path
     del at_each_wait[:]
     _round(sim, res.params, data, n, 5)
@@ -262,8 +262,13 @@ def test_n_rounds_leave_n_records_in_order_each_the_rounds_own(monkeypatch):
     last = sim.last_compute
     wall = time.perf_counter() - t0
     assert len(records) == len(cohorts) and last is records[-1]
-    for kw, record, chosen, settled_itself in zip(asked, records, cohorts,
-                                                  own):
+    # a steady round is settled by the round after it, once that round's
+    # head has run: where that head builds programs (a cohort size the
+    # process has not staged before), they fall into the steady round's
+    # time
+    next_builds = own[1:] + [False]
+    for kw, record, chosen, settled_itself, head_after_builds in zip(
+            asked, records, cohorts, own, next_builds):
         assert validate_record(record) == []
         assert kw["n_samples"] == float(n[chosen].sum())
         # two steps of 4 rows a client: the largest of each cohort has 8
@@ -273,12 +278,19 @@ def test_n_rounds_leave_n_records_in_order_each_the_rounds_own(monkeypatch):
                                             "found_ready_upper_bound")
         assert _clean_compute(record)["train_s_source"] == (
             record["train_s_source"])
-        # the compiling call's wall time is a round's own or no one's
+        # what JAX built while the round's time ran is in its record,
+        # and is less than that time. A shape new to the tracker need
+        # not build: three clients run the wave program of four
         assert record["cache_hit"] == (not settled_itself)
         assert record["compile_s_source"] == (
-            "first_call_wall" if settled_itself else "cache_hit")
-        if settled_itself:
-            assert record["compile_s"] == kw["train_s"]
+            "jax_monitoring" if record["compile_s"] > 0 else "cache_hit")
+        if record is records[0]:  # this FedSim's own wave program
+            assert record["compile_s"] > 0
+        elif not (settled_itself or head_after_builds):
+            assert record["compile_s"] == 0.0
+        assert record["compile_cold_s"] <= record["compile_s"] < kw["train_s"]
+        assert _clean_compute(record)["compile_cold_s"] == (
+            record["compile_cold_s"])
     # a round's time starts where the round before it ended: the
     # intervals do not overlap, so they fit in the loop's wall time
     assert all(kw["train_s"] > 0 for kw in asked)
