@@ -63,7 +63,8 @@ from baton_tpu.core.partition import path_str
 from baton_tpu.models.delta_rule import gated_delta_apply, gated_delta_init
 from baton_tpu.models.lora import lora_wrap
 from baton_tpu.models.moe import (
-    MoEConfig, moe_apply, moe_apply_with_state, moe_init, rows_bound)
+    MoEConfig, expert_tiles, moe_apply, moe_apply_with_state, moe_init,
+    rows_bound)
 from baton_tpu.models.state_space import SSMConfig, mamba2_apply, mamba2_init
 from baton_tpu.models.transformer import (
     AttentionFn, CCAConfig, MLAConfig, Multipliers, attention_is_kernel,
@@ -580,6 +581,9 @@ def llama_lm_model(
         ropes = {m: m.rope(cfg, l) for m in dict.fromkeys(mixers)}
         for m in ropes:
             seen.update(m.seen(cfg, l))
+        if cfg.moe is not None:
+            seen.update(expert_tiles(cfg.d_model, cfg.moe.d_ff or cfg.d_ff,
+                                     compute_dtype))
         with jax.named_scope("embed"):
             x = scaled(params["tok_emb"][ids], on.embedding).astype(
                 compute_dtype)

@@ -229,29 +229,112 @@ def route_mlp(p, x, state, cfg: MoEConfig):
 
 
 # ------------------------------------------------------- grouped products
-# (rows, contraction, columns) a tile of the Pallas grouped product. Read
-# on a v5e at 16,384 rows in 32 groups of 4,096 x 2,048 in bfloat16 (my
-# chip run, PR 33): 3.00 ms, 92 TFLOP/s; (512, 1024, 1024) 3.13;
-# (512, 512, 512) 3.81; the kernel's default (128, 128, 128) 29.8;
-# ``jax.lax.ragged_dot`` 3.76, and 5.51 against a transposed stack
-_GMM_TILING = (256, 1024, 1024)
+# The Pallas grouped product's grid is (column tiles, row tiles that
+# hold rows, contraction tiles), the contraction innermost, and the
+# weight block a step reads is (the row tile's expert, contraction tile,
+# column tile): with the contraction in one tile that index is the same
+# for every row tile of an expert, Pallas copies no block whose index
+# did not change, and a step is bound by the matrix unit; tiled, every
+# row tile loads its expert's weights again. A tile that does not
+# divide its width is masked (contraction) or padded (columns) and
+# multiplied all the same. One product in bfloat16 on a v5e at the
+# cells' rows and group sizes, ms, either direction within 0.03 of the
+# other (my chip run, PR 51; ``(tk, tn)``, the row tile 256; ``*`` the
+# tiles that were, ``(1024, 1024)`` clipped to the widths, last the
+# rule's):
+#
+#   K x N, rows in groups      tiles: ms
+#   2,304 x 896, 65,536 in 64  (1024, 896)* 3.30  (768, 896) 2.61
+#                              (1152, 896) 2.55  (2304, 896) 1.95
+#   896 x 2,304, the same      (896, 1024)* 2.78  (896, 768) 2.21
+#                              (896, 1152) 2.12  (896, 2304) 2.04
+#   2,048 x 2,048, 30,840 in   (1024, 1024)* 2.23  (2048, 512) 1.75
+#   16 of 32,768               (2048, 1024) 1.70
+#   4,096 x 2,048, 8,192 in    (1024, 1024)* 1.49  (2048, 1024) 1.47
+#   16 of 16,384               (4096, 256) 1.33  (4096, 512) 1.26
+#   2,048 x 4,096, the same    (1024, 1024)* 1.56  (2048, 512) 1.32
+#                              (2048, 1024) 1.27
+#   6,144 x 2,048, 8,192 in    (1024, 1024)* 1.80  (2048, 1024) 1.79
+#   8 of 16,384                (3072, 512) 2.25  (6144, 256) 1.58
+#   2,048 x 6,144, the same    (1024, 1024)* 1.93  (2048, 512) 1.57
+#                              (2048, 768) 1.52  (2048, 1024) 1.50
+#
+# the rows of a tile (PR 33 read 512 slower at groups of 512 +- 300
+# rows: 3.13 ms for 3.00; ``rows_bound`` rounds to it)
+_GMM_ROW_TILE = 256
+# what the kernel's blocks and accumulator may take of the 16 MiB of
+# scoped VMEM a v5e kernel gets: its body keeps more beside them, up to
+# 0.83 MiB by the compiler's own count (compiled for a described v5e,
+# PR 51: blocks of 15.25 MiB were refused at 16.08, 14.6 MiB compiled)
+_GMM_VMEM_BUDGET = 14 * 2 ** 20
+
+
+def _gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """What one grid step of the kernel holds in VMEM: the row, weight
+    and output blocks, two buffers each, and the float32 accumulator."""
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def gmm_tiles(k: int, n: int, itemsize: int) -> tuple:
+    """``(tm, tk, tn)`` of a grouped product that contracts ``k``
+    channels into ``n`` columns (multiples of 128 both) over operands of
+    ``itemsize`` bytes, from these alone. Both tiles divide their
+    widths, so no step multiplies padding. The contraction is one tile
+    wherever that fits ``_GMM_VMEM_BUDGET`` beside some column tile,
+    and the column tile the widest that fits beside it (an expert's
+    weights then stay in VMEM across its row tiles); where it is not,
+    the column tile is the widest up to 1,024 (PR 33's, read with the
+    contraction tiled) and the contraction tile the largest that fits
+    beside that."""
+    tm = _GMM_ROW_TILE
+
+    def fits(tk, tn):
+        return _gmm_vmem_bytes(tm, tk, tn, itemsize) <= _GMM_VMEM_BUDGET
+
+    def dividing(width):  # widest first
+        return [t for t in range(width, 0, -128) if width % t == 0]
+
+    if fits(k, 128):
+        return tm, k, next(t for t in dividing(n) if fits(k, t))
+    tn = next(t for t in dividing(n) if t <= 1024)
+    return tm, next(t for t in dividing(k) if fits(t, tn)), tn
+
+
+def expert_tiles(d_model: int, d_ff: int, dtype) -> dict:
+    """What a trace says of the grouped products of an expert layer of
+    these widths over activations of ``dtype``, where they are the
+    Pallas kernel (elsewhere nothing): under ``expert_tiles``, for each
+    ``K x N`` (a ``t`` after it: against the transposed stack, the
+    backward's) the tiles ``tm x tk x tn`` :func:`gmm_tiles` gives it,
+    ``whole`` or ``tiled`` for its contraction, and after ``pad`` the
+    share of the elements it multiplies that are padding."""
+    if jax.default_backend() != "tpu":
+        return {}
+    said = []
+    for k, n in ((d_model, d_ff), (d_ff, d_model)):
+        tm, tk, tn = gmm_tiles(k, n, jnp.dtype(dtype).itemsize)
+        padding = 1 - k * n / ((-(-k // tk) * tk) * (-(-n // tn) * tn))
+        said += [f"{k}x{n}{t}:{tm}x{tk}x{tn}:"
+                 f"{'whole' if tk == k else 'tiled'}:pad{padding:g}"
+                 for t in ("", "t")]
+    return {"expert_tiles": " ".join(said)}
 
 
 def _gmm(x, w, sizes, transpose_rhs: bool, interpret: bool = False):
     """The Pallas ``megablox`` grouped product that ships with JAX (it
-    visits the tiles that hold rows and no others), at ``_GMM_TILING``.
-    ``interpret``: the kernel's body as plain JAX, for a test off the
-    chip."""
+    visits the row tiles that hold rows and no others), at the tiles
+    :func:`gmm_tiles` picks for its widths and dtype. ``interpret``:
+    the kernel's body as plain JAX, for a test off the chip."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     n, k = x.shape
     m = w.shape[1] if transpose_rhs else w.shape[2]
-    if n % _GMM_TILING[0] or k % 128 or m % 128:
+    if n % _GMM_ROW_TILE or k % 128 or m % 128:
         raise ValueError(
             f"grouped product of {n} rows, {k} x {m}: the rows have to be "
-            f"a multiple of {_GMM_TILING[0]}, both widths of 128")
-    tiling = (_GMM_TILING[0], min(_GMM_TILING[1], k), min(_GMM_TILING[2], m))
-    return gmm(x, w, sizes, preferred_element_type=x.dtype, tiling=tiling,
+            f"a multiple of {_GMM_ROW_TILE}, both widths of 128")
+    return gmm(x, w, sizes, preferred_element_type=x.dtype,
+               tiling=gmm_tiles(k, m, x.dtype.itemsize),
                transpose_rhs=transpose_rhs, interpret=interpret)
 
 
@@ -261,8 +344,10 @@ def grouped_matmul(x, w, sizes, transpose_rhs: bool = False):
     expert, times that expert's ``w [E, K, M]`` (``[E, M, K]`` with
     ``transpose_rhs``); rows past ``sum(sizes)`` come out zero. The
     result is in ``x``'s dtype, accumulated in float32. On a TPU the
-    Pallas kernel (:func:`_gmm`; sizes its tiles do not divide are
-    refused, not taken elsewhere); off it ``jax.lax.ragged_dot``."""
+    Pallas kernel at tiles chosen from ``K``, ``M`` and the dtype
+    (:func:`_gmm`, :func:`gmm_tiles`; rows that are no multiple of its
+    row tile and widths that are none of 128 are refused, not taken
+    elsewhere); off it ``jax.lax.ragged_dot``."""
     w = w.astype(x.dtype)
     if jax.default_backend() == "tpu":
         out = _gmm(x, w, sizes, transpose_rhs)
@@ -324,7 +409,7 @@ def rows_bound(n: int, held: int, n_experts: int) -> int:
     are held: the expected held rows times ``_ROWS_HEADROOM``, rounded
     up to the grouped product's row tile, at most ``n`` (every expert
     held: ``n``, and the layer is one block over every row)."""
-    tile = _GMM_TILING[0]
+    tile = _GMM_ROW_TILE
     expected = -(-n * held // n_experts)
     return min(n, -(-_ROWS_HEADROOM * expected // tile) * tile)
 

@@ -596,6 +596,10 @@ def phase_moe_mla_lora(env: Env) -> None:
             f"(expected {100 * (1 - held / total):.2f}), the router in "
             f"bfloat16 and in float32 differ on {100 * differ:.3f} %")
         _check(rows.sum() > 0, "no assignment fell on a held expert")
+    products = _expert_products(
+        env, decoder, np.bincount(program[0].ravel(), minlength=total)[
+            first_held:first_held + held],
+        moe.rows_bound(program[0].size, held, total))
     env.say("moe_mla_lora",
             f"{model.name}: latent attention, a dense layer and 2 expert "
             f"layers holding 3 of 8, bf16 over a frozen bf16 base, 4 clients "
@@ -609,6 +613,7 @@ def phase_moe_mla_lora(env: Env) -> None:
             f"sarvam_105b at {'tiny' if tiny else 'the published'} sizes, "
             f"{held} of {total} experts held, 4 sequences of "
             f"{job['seq_len']} tokens, seed {seed}: " + "; ".join(lines)
+            + f"; {products}"
             + f"; the two streams after the stage lie apart by "
             f"{_mean(apart[agree]):.4f} of the float32 one's norm, a token, "
             f"over the {agree.sum()} tokens whose every choice agrees, by "
@@ -711,6 +716,11 @@ def phase_dsa_mla_lora(env: Env) -> None:
                      & (routed < cut.first_held + cut.held)).sum())
     bound = moe.rows_bound(routed.size, cut.held, cut.n_experts)
     _check(held_rows > 0, "no assignment fell on a held expert")
+    # the cell folds four clients of this length into one sort
+    products = _expert_products(
+        env, decoder, 4 * np.bincount(routed.ravel(), minlength=cut.n_experts)[
+            cut.first_held:cut.first_held + cut.held],
+        moe.rows_bound(4 * routed.size, cut.held, cut.n_experts))
 
     with jax.default_matmul_precision("highest"):
         x32 = base["tok_emb"][ids].astype(jnp.float32)
@@ -744,6 +754,7 @@ def phase_dsa_mla_lora(env: Env) -> None:
             f"{held_rows} held rows of {routed.size} assignments "
             f"({cut.held} of {cut.n_experts} experts held) against a block "
             f"of {bound} ({-(-held_rows // bound)} block(s) run); "
+            f"{products}, four clients of this routing; "
             f"{'float32' if tiny else 'bfloat16'} and float32 at highest "
             f"agree on {100 * _mean(common[chooses] / topk):.3f} % of a "
             f"choosing query's keys ({int(alike[chooses].sum())} of "
@@ -892,6 +903,8 @@ def phase_cca_lora(env: Env) -> None:
         / np.linalg.norm(out32, axis=-1)
     _check(all(r[:-1].min() > 0 for r in rows) or tiny,
            f"an expert saw no row: {[r.tolist() for r in rows]}")
+    # the cell folds four clients of this length; the skip holds no row
+    products = _expert_products(env, decoder, 4 * rows[0][:-1], 4 * seq)
 
     def _mean(a):
         return float(a.mean()) if a.size else float("nan")
@@ -908,7 +921,8 @@ def phase_cca_lora(env: Env) -> None:
             f"(fullest expert, emptiest, skipped) "
             + " ".join(f"({r[:-1].max()}, {r[:-1].min()}, {r[-1]})"
                        for r in rows)
-            + f" of {seq / n_out:.0f} expected; "
+            + f" of {seq / n_out:.0f} expected; {products}, four clients "
+            f"of the first layer's routing; "
             f"{'float32' if tiny else 'bfloat16'} and float32 at highest "
             f"choose differently on " + " ".join(
                 f"{100 * d:.2f}" for d in differs)
@@ -1046,6 +1060,57 @@ def _experts_one_at_a_time(p, x, idx, gate):
         add_one, jnp.zeros_like(x),
         (jnp.arange(p["w_gate"].shape[0]), p["w_gate"], p["w_up"],
          p["w_down"]))[0]
+
+
+def _expert_products(env: Env, cfg, sizes, rows: int) -> str:
+    """The four grouped products an expert layer of the decoder ``cfg``
+    makes (into its experts' width and back, against a stack and
+    against a transposed one), one at a time in bfloat16 (float32 in
+    rehearsal) on ``rows`` sorted rows of which ``sizes`` an expert are
+    held, the cell's: what ``moe.expert_tiles`` hands the trace for
+    them, each held to ``jax.lax.ragged_dot`` and timed."""
+    import jax
+    import jax.numpy as jnp
+
+    from baton_tpu.models import moe
+
+    d, f = cfg.d_model, cfg.moe.d_ff or cfg.d_ff
+    dtype = jnp.float32 if env.rehearsal else jnp.bfloat16
+    sizes = jnp.asarray(sizes, jnp.int32)
+    held = int(sizes.sum())
+    _check(0 < held <= rows, f"{held} held rows in a block of {rows}")
+    timed = []
+    for k, n in ((d, f), (f, d)):
+        x = jax.random.normal(jax.random.key(3), (rows, k), dtype)
+        w = jax.random.normal(jax.random.key(4), (sizes.shape[0], k, n),
+                              dtype) * k ** -0.5
+        want = jax.jit(lambda x, w: jax.lax.ragged_dot(
+            x, w, sizes, preferred_element_type=jnp.float32))(x, w)[:held]
+        for transposed in (False, True):
+            rhs = jnp.swapaxes(w, 1, 2) if transposed else w
+            product = jax.jit(partial(moe.grouped_matmul,
+                                      transpose_rhs=transposed))
+            err = _rel_err(product(x, rhs, sizes)[:held], want)
+            _check(err < BF16_TOL, f"a grouped product of {k} x {n} lies "
+                   f"{err:.4f} from ragged_dot")
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.perf_counter()
+                for _ in range(8):
+                    out = product(x, rhs, sizes)
+                jax.block_until_ready(out)
+                best = min(best, (time.perf_counter() - t0) / 8)
+            timed.append(
+                f"{k}x{n}{'t' if transposed else ''} "
+                + ("not measured (rehearsal)" if env.rehearsal else
+                   f"{1e3 * best:.3f} ms, "
+                   f"{2e-12 * held * k * n / best:.1f} TFLOP/s"))
+    said = moe.expert_tiles(d, f, dtype).get(
+        "expert_tiles", "none, ragged_dot off a TPU")
+    return (f"the layer's grouped products alone, {rows} sorted rows, "
+            f"{held} of them held by {sizes.shape[0]} experts (fullest "
+            f"{int(sizes.max())}, emptiest {int(sizes.min())}): "
+            f"expert_tiles {said}; a call " + "; ".join(timed))
 
 
 def _window_cores(tiny, shape, window, pairs):
@@ -1281,6 +1346,7 @@ def phase_window_lora(env: Env) -> None:
     _check(all(r.min() > 0 for r in rows) or tiny,
            f"an expert saw no row: {[r.tolist() for r in rows]}")
     expected = seq * top_k / n_experts
+    products = _expert_products(env, decoder, rows[0], seq * top_k)
     (w_errs, _, w_ms, w_tiles), (f_errs, _, f_ms, f_tiles) = (
         cores["window", None, None], cores["full", None, None])
     timed = ("not measured (rehearsal)" if tiny else
@@ -1310,7 +1376,8 @@ def phase_window_lora(env: Env) -> None:
             f"{seq} tokens, seed {seed}: a layer (fullest expert's rows, "
             f"emptiest's) "
             + " ".join(f"({r.max()}, {r.min()})" for r in rows)
-            + f" of {expected:.0f} expected; the chosen weights' largest "
+            + f" of {expected:.0f} expected; {products}, one client of "
+            f"the first layer's routing; the chosen weights' largest "
             f"and smallest, mean over tokens: "
             f"{gates.max(-1).mean():.3f} and {gates.min(-1).mean():.3f}; "
             f"{'float32' if tiny else 'bfloat16'} and float32 at highest "

@@ -28,12 +28,16 @@ from baton_tpu.models.llama import (
     llama_lm_model,
     projection_lora_target,
 )
+from baton_tpu.models import moe
 from baton_tpu.models.moe import (
     MoEConfig,
     _block_sizes,
     _gmm,
+    _gmm_vmem_bytes,
     _rows_of_the_groups,
     _sorted_rows,
+    expert_tiles,
+    gmm_tiles,
     grouped_matmul,
     moe_apply,
     moe_apply_with_state,
@@ -571,16 +575,26 @@ def test_experts_sharded_over_a_mesh_axis_equal_the_replicated_layer(nprng):
         _close(got, want, rtol=1e-5)
 
 
+@pytest.mark.parametrize("k,budget,tiles", [
+    (128, None, (256, 128, 256)), (384, None, (256, 384, 256)),
+    (384, 1_400_000, (256, 128, 256))],
+    ids=["one_tile_of_128", "384_whole", "384_in_three_tiles"])
 @pytest.mark.parametrize("transpose_rhs", [False, True],
                          ids=["stack", "transposed"])
-def test_the_chips_grouped_product_is_ragged_dot(transpose_rhs, nprng):
+def test_the_chips_grouped_product_is_ragged_dot(transpose_rhs, k, budget,
+                                                 tiles, nprng, monkeypatch):
     """The Pallas kernel a TPU runs, its body interpreted here: the
     groups' rows are ``ragged_dot``'s (one group empty, one straddling
     a tile of 256 rows), the rows past them are not written and come
     out zero through the same mask; sizes its tiles do not divide are
-    refused."""
-    x = jnp.asarray(nprng.normal(size=(512, 128)), jnp.float32)
-    w = jnp.asarray(nprng.normal(size=(3, 128, 256)), jnp.float32)
+    refused. A contraction of 384, 2,304's shape in miniature: in the
+    one tile it gets, and in three of 128 where the budget is cut so
+    that it does not fit whole."""
+    if budget is not None:
+        monkeypatch.setattr(moe, "_GMM_VMEM_BUDGET", budget)
+    assert gmm_tiles(k, 256, 4) == tiles
+    x = jnp.asarray(nprng.normal(size=(512, k)), jnp.float32)
+    w = jnp.asarray(nprng.normal(size=(3, k, 256)), jnp.float32)
     sizes = jnp.asarray([200, 0, 120], jnp.int32)
     want = _rows_of_the_groups(jax.lax.ragged_dot(x, w, sizes), sizes)
     if transpose_rhs:
@@ -591,6 +605,72 @@ def test_the_chips_grouped_product_is_ragged_dot(transpose_rhs, nprng):
     assert not np.any(np.asarray(got[320:]))
     with pytest.raises(ValueError, match="multiple of 256"):
         _gmm(x[:500], w, sizes, transpose_rhs, interpret=True)
+
+
+# the grouped products of the four configurations with experts, ``K x
+# N``, and the ``(tk, tn)`` each was read fastest at on the chip
+# (moe.py's table): the contraction whole, the widest column tile that
+# fits beside it
+_CELLS_PRODUCTS = {
+    "mellum2_12b_up": (2304, 896, (2304, 896)),
+    "mellum2_12b_down": (896, 2304, (896, 2304)),
+    "zaya1_8b_up": (2048, 2048, (2048, 1024)),
+    "zaya1_8b_down": (2048, 2048, (2048, 1024)),
+    "sarvam_105b_up": (4096, 2048, (4096, 512)),
+    "sarvam_105b_down": (2048, 4096, (2048, 1024)),
+    "glm_5_up": (6144, 2048, (6144, 256)),
+    "glm_5_down": (2048, 6144, (2048, 1024)),
+    # 1,408 = 11 x 128: only 128 and itself divide it
+    "only_128_divides_the_columns": (2048, 1408, (2048, 128)),
+    "only_128_divides_the_contraction": (1408, 2048, (1408, 1024)),
+    # a row tile of 16,384 channels alone is the whole of VMEM
+    "a_contraction_that_does_not_fit": (16384, 2048, (2048, 1024)),
+    "nor_beside_columns_only_128_divides": (16384, 1408, (8192, 128)),
+}
+
+
+@pytest.mark.parametrize("k,n,want", _CELLS_PRODUCTS.values(),
+                         ids=_CELLS_PRODUCTS.keys())
+def test_a_products_tiles_come_from_its_widths(k, n, want):
+    """Both tiles divide their widths and are multiples of 128 (no
+    masked remainder, no padded column tile), the blocks fit the
+    budget, the row tile is ``rows_bound``'s, the choice is blind to
+    anything but the widths and the item size, and in bfloat16 the
+    contraction is one tile in every product a configuration makes."""
+    tm, tk, tn = gmm_tiles(k, n, 2)
+    assert (tk, tn) == want
+    assert tm == 256 == rows_bound(10 ** 6, 1, 10 ** 6)
+    assert moe._GMM_VMEM_BUDGET < 16 * 2 ** 20
+    for itemsize in (2, 4):
+        tm, tk, tn = gmm_tiles(k, n, itemsize)
+        assert k % tk == 0 and n % tn == 0 and tk % 128 == 0 == tn % 128
+        assert _gmm_vmem_bytes(tm, tk, tn, itemsize) <= moe._GMM_VMEM_BUDGET
+        # a wider dividing column tile beside a whole contraction
+        # would not fit
+        wider = [t for t in range(tn + 128, n + 1, 128) if n % t == 0]
+        assert tk < k or not wider or _gmm_vmem_bytes(
+            tm, tk, wider[0], itemsize) > moe._GMM_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("d_model,d_ff", [
+    (2304, 896), (2048, 2048), (4096, 2048), (6144, 2048)],
+    ids=["mellum2_12b", "zaya1_8b", "sarvam_105b", "glm_5"])
+def test_a_trace_says_the_tiles_where_the_kernel_runs(d_model, d_ff,
+                                                      monkeypatch):
+    """Off a TPU the grouped product is ``ragged_dot`` and the model's
+    span says nothing of tiles; on one, both widths' products in either
+    direction, every contraction whole, no element padding."""
+    assert expert_tiles(d_model, d_ff, jnp.bfloat16) == {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    said = expert_tiles(d_model, d_ff, jnp.bfloat16)["expert_tiles"].split()
+    assert [s.split(":")[0] for s in said] == [
+        f"{d_model}x{d_ff}", f"{d_model}x{d_ff}t",
+        f"{d_ff}x{d_model}", f"{d_ff}x{d_model}t"]
+    for entry in said:
+        shape, tiles, contraction, padding = entry.split(":")
+        k, n = map(int, shape.rstrip("t").split("x"))
+        assert tiles == "x".join(map(str, gmm_tiles(k, n, 2)))
+        assert (contraction, padding) == ("whole", "pad0")
 
 
 def test_the_wave_program_holds_the_stacks_once_and_takes_no_gradient(

@@ -6,6 +6,7 @@ this file: keep every such compile in this file."""
 
 import os
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -427,3 +428,48 @@ def test_a_windowed_block_holds_no_square_and_its_kernels_clamp_both_sides(
         assert any("value = 1023 : i32" in f for f in maps)
     for maps in full.values():
         assert not any("value = 1023 : i32" in f for f in maps)
+
+
+# the grouped products of the four cells with experts: ``K x N``, the
+# experts held, and the sorted rows one product handles there
+# (``moe.rows_bound`` of the folded clients' assignments, or one
+# client's where the fold would pass ``moe._FOLDED_ROWS_BYTES``)
+_GROUPED_PRODUCTS = {
+    "mellum2_c4_l8192-2304x896": (2304, 896, 64, 65536),
+    "mellum2_c4_l8192-896x2304": (896, 2304, 64, 65536),
+    "zaya1_c4_l8192-2048x2048": (2048, 2048, 16, 32768),
+    "sarvam_105b_c4_l2048-4096x2048": (4096, 2048, 16, 16384),
+    "sarvam_105b_c4_l2048-2048x4096": (2048, 4096, 16, 16384),
+    "glm5_c4_l8192-6144x2048": (6144, 2048, 8, 16384),
+    "glm5_c4_l8192-2048x6144": (2048, 6144, 8, 16384),
+}
+
+
+@pytest.mark.parametrize("k,n,held,rows", _GROUPED_PRODUCTS.values(),
+                         ids=_GROUPED_PRODUCTS.keys())
+def test_a_grouped_product_fits_the_chips_vmem_at_the_tiles_it_picks(
+        one_chip, k, n, held, rows):
+    """``grouped_matmul`` in bfloat16 at a cell's widths, rows and
+    experts, against the stack and against the transposed stack: each
+    is one Pallas kernel that the chip's compiler takes, so a tile past
+    the scoped VMEM fails here and not on the chip; and the contraction
+    is one tile, which is what keeps an expert's weights where they
+    are across its row tiles."""
+    from baton_tpu.models import moe
+
+    assert moe.gmm_tiles(k, n, 2)[1] == k
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    backend, jax.default_backend = jax.default_backend, lambda: "tpu"
+    try:  # ``grouped_matmul`` asks the backend where it is traced
+        for transposed in (False, True):
+            text = jax.jit(partial(
+                moe.grouped_matmul, transpose_rhs=transposed)).lower(
+                    shaped((rows, k)),
+                    shaped((held, n, k) if transposed else (held, k, n)),
+                    shaped((held,), jnp.int32)).compile().as_text()
+            assert text.count('custom_call_target="tpu_custom_call"') == 1
+    finally:
+        jax.default_backend = backend
