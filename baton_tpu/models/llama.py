@@ -20,7 +20,14 @@ state (``moe.router_hidden``) a block takes and hands on two streams,
 the tokens' and the routers', and the first block is handed zeros. With
 ``residual_merge`` a sub-layer's output joins the stream by a learned
 affine merge a channel and not a plain add; with ``tie_embeddings`` the
-head is the embedding table itself. ``multipliers`` are the fixed
+head is the embedding table itself. A block is sequential, ``x +
+mixer(N(x))`` then ``x + feed_forward(N'(x))`` with a norm of its own
+before each, or with ``parallel_block`` it holds one norm and computes
+``x + mixer(N(x)) + feed_forward(N(x))``, the norm made once and one add;
+``norm`` says whether the decoder's norms are RMSNorms or LayerNorms with
+a scale and no bias; beside windowed layers that rotate, the full layers
+may take no rotation (``full_layer_rope``), and ``rope_pairs`` says which
+channels a rotation turns together. ``multipliers`` are the fixed
 scalars a model publishes for its projections, the embedding and the
 logits (:class:`~baton_tpu.models.transformer.Multipliers`; at 1, as
 every other model has them, they add no op). A block is traced once a
@@ -67,12 +74,16 @@ from baton_tpu.models.moe import (
     rows_bound)
 from baton_tpu.models.state_space import SSMConfig, mamba2_apply, mamba2_init
 from baton_tpu.models.transformer import (
-    AttentionFn, CCAConfig, MLAConfig, Multipliers, attention_is_kernel,
-    cca_apply, cca_core_is_kernel, cca_init, default_attention, dense_init,
-    head_products_a_block, matmul, mha_apply, mha_init, mla_apply,
-    mla_core_is_kernel, mla_init, mla_qk_layout, mla_rope_angles,
-    multi_head_attention, next_token_loss, normal_init, rms_init, rms_norm,
-    rope_angles, scaled, swiglu_apply, swiglu_init, tied_logits)
+    ROPE_PAIRS, AttentionFn, CCAConfig, MLAConfig, Multipliers,
+    attention_is_kernel, cca_apply, cca_core_is_kernel, cca_init,
+    default_attention, dense_init, head_products_a_block, layer_norm, matmul,
+    mha_apply, mha_init, mla_apply, mla_core_is_kernel, mla_init,
+    mla_qk_layout, mla_rope_angles, multi_head_attention, next_token_loss,
+    normal_init, rms_init, rms_norm, rope_angles, scaled, swiglu_apply,
+    swiglu_init, tied_logits)
+
+# a decoder's norm by ``LlamaConfig.norm``: both hold a ``scale`` alone
+_NORMS = {"rms": rms_norm, "layer": layer_norm}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,6 +149,20 @@ class LlamaConfig:
     # the head is the embedding table transposed; no ``lm_head`` leaf
     tie_embeddings: bool = False
     multipliers: Multipliers = Multipliers()
+    # a block holds one norm, ``norm``, and is ``x + mixer(N(x)) +
+    # feed_forward(N(x))``; False: ``norm_attn`` before the mixer,
+    # ``norm_mlp`` before the feed-forward, one after the other
+    parallel_block: bool = False
+    # the norm of a block and the one before the head, at ``norm_eps``:
+    # ``"rms"``, or ``"layer"``, a LayerNorm with a scale and no bias
+    norm: str = "rms"
+    # False: a full-attention layer takes no rotation while the windowed
+    # layers beside it keep ``rope_theta``
+    full_layer_rope: bool = True
+    # the channels a rotation of full or windowed attention turns
+    # together: ``"split"`` ``(i, i + head_dim / 2)`` or ``"adjacent"``
+    # ``(2i, 2i + 1)`` (``transformer.apply_rope``)
+    rope_pairs: str = "split"
 
     def __post_init__(self):
         if self.layer_types is not None:  # a JSON list hashes as a tuple
@@ -147,6 +172,11 @@ class LlamaConfig:
         if isinstance(self.rope_yarn, dict):  # a JSON group, hashable
             object.__setattr__(self, "rope_yarn",
                                tuple(sorted(self.rope_yarn.items())))
+        if self.norm not in _NORMS:
+            raise ValueError(f"unknown norm {self.norm!r}: {sorted(_NORMS)}")
+        if self.rope_pairs not in ROPE_PAIRS:
+            raise ValueError(
+                f"unknown rope_pairs {self.rope_pairs!r}: {ROPE_PAIRS}")
 
     def kind_of(self, layer: int) -> str:
         if self.layer_types:
@@ -221,12 +251,14 @@ def _attention_apply(p, h, cfg, rope, attention_fn):
     return scaled(mha_apply(
         p, scaled(h, m.attention_in), cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         causal=True, rope=rope, attention_fn=attention_fn,
-        key_multiplier=m.key, **named), m.attention_out)
+        key_multiplier=m.key, rope_pairs=cfg.rope_pairs, **named),
+        m.attention_out)
 
 
 def _attention_rope(cfg, length):
-    # None: position comes from the recurrent layers of a hybrid
-    if cfg.rope_theta is None:
+    # None: position comes from the recurrent layers of a hybrid, or
+    # from the windowed layers beside a full layer that takes none
+    if cfg.rope_theta is None or not cfg.full_layer_rope:
         return None
     return rope_angles(length, cfg.head_dim, cfg.rope_theta,
                        cfg.rope_yarn and dict(cfg.rope_yarn))
@@ -251,7 +283,7 @@ def _sliding_apply(p, h, cfg, rope, attention_fn):
     return multi_head_attention(
         p, h, cfg.n_heads, n_kv_heads=cfg.n_kv_heads, causal=True, rope=rope,
         attention_fn=attention_fn, window=cfg.window,
-        core_scope="window_core")
+        core_scope="window_core", rope_pairs=cfg.rope_pairs)
 
 
 def _layers_of(cfg, apply) -> int:
@@ -396,7 +428,9 @@ def _block_init(key, cfg: LlamaConfig, kind: Optional[str] = None,
     """A block holds its mixer (``kind``, a key of :data:`MIXERS`; None:
     the first layer's) under that mixer's ``key``; an expert layer's
     ``mlp`` holds a ``router``; with ``residual_merge`` the block holds
-    ``merge_attn`` and ``merge_mlp``."""
+    ``merge_attn`` and ``merge_mlp``; a parallel block holds one
+    ``norm`` where a sequential one holds ``norm_attn`` and
+    ``norm_mlp``."""
     ka, km = jax.random.split(key)
     if experts:
         mlp = moe_init(km, cfg.d_model, cfg.d_ff, cfg.moe)
@@ -409,6 +443,8 @@ def _block_init(key, cfg: LlamaConfig, kind: Optional[str] = None,
         k1, k2 = jax.random.split(jax.random.fold_in(key, 2))
         mixer.update(merge_attn=_merge_init(k1, cfg.d_model),
                      merge_mlp=_merge_init(k2, cfg.d_model))
+    if cfg.parallel_block:
+        return {"norm": rms_init(cfg.d_model), **mixer, "mlp": mlp}
     return {"norm_attn": rms_init(cfg.d_model), **mixer,
             "norm_mlp": rms_init(cfg.d_model), "mlp": mlp}
 
@@ -434,34 +470,71 @@ def _joined(p, name: str, x, y):
             + m["a_y"] * (y.astype(jnp.float32) + m["b_y"])).astype(x.dtype)
 
 
-def _mix(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
+def _normed(x, scale, cfg: LlamaConfig):
+    return _NORMS[cfg.norm](x, scale, cfg.norm_eps)
+
+
+def _mixer_of(p) -> Mixer:
     # the kind of a block is the structure of its parameters
-    m = next(m for m in MIXERS.values() if m.key in p)
+    return next(m for m in MIXERS.values() if m.key in p)
+
+
+def _mix(p, x, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
+    m = _mixer_of(p)
     if m.keeps_its_inputs(cfg, x.shape[1]):
         y = m.apply(p[m.key], x, cfg, rope, attention_fn,
                     pre_norm=p["norm_attn"])
     else:
-        y = m.apply(p[m.key], rms_norm(x, p["norm_attn"], cfg.norm_eps), cfg,
-                    rope, attention_fn)
+        y = m.apply(p[m.key], _normed(x, p["norm_attn"], cfg), cfg, rope,
+                    attention_fn)
     return _joined(p, "merge_attn", x, y)
 
 
-def _feed_forward(p, x, r, cfg: LlamaConfig):
-    """``(x, r)``: the stream after the feed-forward, and the routers'
-    state, which an expert layer whose router carries one (``r`` not
-    None) takes from the layer before and hands to the next."""
-    h = rms_norm(x, p["norm_mlp"], cfg.norm_eps)
+def _fed_forward(p, h, r, cfg: LlamaConfig):
+    """``(y, r)``: the feed-forward of the normed stream ``h``, and the
+    routers' state, which an expert layer whose router carries one
+    (``r`` not None) takes from the layer before and hands to the
+    next."""
     if r is not None:
-        y, r = moe_apply_with_state(p["mlp"], h, r, cfg.moe)
-    elif "router" in p["mlp"]:
-        y = moe_apply(p["mlp"], h, cfg.moe)
-    else:
-        y = swiglu_apply(p["mlp"], h, cfg.multipliers.mlp)
+        return moe_apply_with_state(p["mlp"], h, r, cfg.moe)
+    if "router" in p["mlp"]:
+        return moe_apply(p["mlp"], h, cfg.moe), r
+    return swiglu_apply(p["mlp"], h, cfg.multipliers.mlp), r
+
+
+def _feed_forward(p, x, r, cfg: LlamaConfig):
+    """``(x, r)``: the stream after the feed-forward and the routers'
+    state after it."""
+    y, r = _fed_forward(p, _normed(x, p["norm_mlp"], cfg), r, cfg)
     return _joined(p, "merge_mlp", x, y), r
 
 
 def _block_apply(p, x, r, cfg: LlamaConfig, rope, attention_fn: AttentionFn):
-    return _feed_forward(p, _mix(p, x, cfg, rope, attention_fn), r, cfg)
+    """A sequential block, or with ``cfg.parallel_block`` the parallel
+    one: the mixer and the feed-forward read one normed stream and
+    their outputs join the stream in one add."""
+    if not cfg.parallel_block:
+        return _feed_forward(p, _mix(p, x, cfg, rope, attention_fn), r, cfg)
+    h = _normed(x, p["norm"], cfg)
+    m = _mixer_of(p)
+    y, r = _fed_forward(p, h, r, cfg)
+    return x + m.apply(p[m.key], h, cfg, rope, attention_fn) + y, r
+
+
+def _block_facts(cfg: LlamaConfig) -> tuple:
+    """What ``baton.round`` says of a block that is not the sequential
+    RMSNorm one with every layer rotated by halves, and of the heads it
+    was built with (a rank of a deployment holds its share of them)."""
+    said = {"parallel_block": True} if cfg.parallel_block else {}
+    if cfg.norm != "rms":
+        said["norm"] = cfg.norm
+    if not cfg.full_layer_rope:
+        said["full_layer_rope"] = "none"
+    if cfg.rope_pairs != "split":
+        said["rope_pairs"] = cfg.rope_pairs
+    if said:
+        said["heads_held"] = f"{cfg.n_heads}+{cfg.n_kv_heads}"
+    return tuple(said.items())
 
 
 def _checkpointed_block():
@@ -542,6 +615,9 @@ def llama_lm_model(
         raise NotImplementedError(
             "a router's state runs through every layer: no dense layer "
             "stands among expert layers whose router carries one")
+    if cfg.parallel_block and cfg.residual_merge:
+        raise NotImplementedError(
+            "a parallel block joins the stream by one plain add, no merge")
 
     def init(rng):
         keys = jax.random.split(rng, cfg.n_layers + 2)
@@ -595,11 +671,15 @@ def llama_lm_model(
             rope = ropes[m]
             with jax.named_scope(f"block{i}"):
                 if m.keeps_its_inputs(cfg, l):
+                    if cfg.parallel_block:
+                        raise NotImplementedError(
+                            "a parallel block stands whole under its "
+                            "checkpoint: no mixer that keeps its inputs")
                     x, r = ff_fn(blk, _mix(blk, x, cfg, rope, attention_fn),
                                  r, cfg)
                 else:
                     x, r = block_fn(blk, x, r, cfg, rope, attention_fn)
-        return rms_norm(x, params["norm_f"], cfg.norm_eps)
+        return _normed(x, params["norm_f"], cfg)
 
     def apply(params, batch, rng):
         """Returns next-token logits [B, L, V] (fp32): bf16 operands,
@@ -639,8 +719,12 @@ def llama_lm_model(
     if cfg.moe is not None and cfg.moe.skip:
         facts += (("router_outputs", cfg.moe.router_outputs),
                   ("skip_expert", cfg.moe.n_experts))
+    if cfg.moe is not None and cfg.moe.shared_combine != "sum":
+        facts += (("shared_experts", cfg.moe.n_shared),
+                  ("shared_combine", cfg.moe.shared_combine))
     for m in dict.fromkeys(mixers):
         facts += m.facts(cfg)
+    facts += _block_facts(cfg)
     return FedModel(init=init, apply=apply, per_example_loss=per_example_loss,
                     name=name, aux=cfg, span_attrs=_ModelFacts(facts, seen))
 

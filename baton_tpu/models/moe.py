@@ -23,6 +23,9 @@ rank does, and computes its own experts' part of the result::
     y = sum over chosen i that are held of g_i E_i(h)  +  E_shared(h)
     E(h) = (SiLU(h w_gate) * (h w_up)) w_down
 
+``E_shared`` is the ``n_shared`` shared experts as one SwiGLU of their
+widths together, which is their sum; with ``shared_combine="average"``
+that product times ``1 / n_shared``, their mean.
 A choice that falls on an expert held elsewhere, or on the skip, which
 no rank holds, adds nothing here; the
 exchange between ranks (an all-to-all of tokens) is not written. No
@@ -73,6 +76,7 @@ from baton_tpu.models.transformer import (
     dense_init,
     normal_init,
     rms_normalize,
+    scaled,
     swiglu,
     swiglu_init,
 )
@@ -94,6 +98,9 @@ class MoEConfig:
     # shared experts, computed for every token beside the routed ones,
     # as one SwiGLU of ``n_shared * d_ff``
     n_shared: int = 0
+    # what the shared experts' outputs become: their ``"sum"`` (the wide
+    # SwiGLU as it is) or their ``"average"`` (it times ``1 / n_shared``)
+    shared_combine: str = "sum"
     # a per-expert bias added to the scores for the choice alone, drawn
     # uniform in +-this range (a trained model's balances the load;
     # zeros could not tell choosing from weighing); None: no bias
@@ -118,6 +125,14 @@ class MoEConfig:
     @property
     def router_outputs(self) -> int:
         return self.n_experts + self.skip
+
+    @property
+    def shared_weight(self) -> float:
+        """What the wide shared SwiGLU's output is multiplied by."""
+        if self.shared_combine not in ("sum", "average"):
+            raise ValueError(
+                f"unknown shared_combine {self.shared_combine!r}")
+        return 1.0 if self.shared_combine == "sum" else 1.0 / self.n_shared
 
 
 def moe_init(key, d_model: int, d_ff: int, cfg: MoEConfig):
@@ -607,7 +622,7 @@ routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 def _held_part(p, x, idx, gate, cfg: MoEConfig):
     """The held experts' part of what the router chose, plus the shared
-    expert."""
+    experts' sum or mean (``cfg.shared_combine``)."""
     b, l, d = x.shape
     local = idx - cfg.first_held
     local = jnp.where((local >= 0) & (local < cfg.held), local, cfg.held)
@@ -617,7 +632,7 @@ def _held_part(p, x, idx, gate, cfg: MoEConfig):
         p["w_gate"], p["w_up"], p["w_down"]).reshape(b, l, d)
     if "shared" in p:
         with jax.named_scope("shared_expert"):
-            y = y + swiglu(p["shared"], x)
+            y = y + scaled(swiglu(p["shared"], x), cfg.shared_weight)
     return y
 
 
@@ -653,5 +668,5 @@ def moe_dense_oracle(p, x, cfg: MoEConfig, state=None):
     if "shared" in p:
         s = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
                                    p["shared"])
-        y = y + swiglu(s, xf)
+        y = y + cfg.shared_weight * swiglu(s, xf)
     return y.astype(x.dtype)

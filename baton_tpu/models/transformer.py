@@ -104,12 +104,15 @@ def rms_init(d):
 
 def layer_normalize(x, p, eps=1e-6):
     """LayerNorm over the last axis under no scope of its own (see
-    :func:`rms_normalize`)."""
+    :func:`rms_normalize`); a ``p`` without a ``bias`` has a scale
+    alone."""
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.var(xf, axis=-1, keepdims=True)
-    xf = (xf - mean) * jax.lax.rsqrt(var + eps)
-    return (xf * p["scale"] + p["bias"]).astype(x.dtype)
+    xf = (xf - mean) * jax.lax.rsqrt(var + eps) * p["scale"]
+    if "bias" in p:
+        xf = xf + p["bias"]
+    return xf.astype(x.dtype)
 
 
 layer_norm = jax.named_scope("norm")(layer_normalize)
@@ -161,9 +164,26 @@ def rope_angles(seq_len: int, head_dim: int, theta: float = 10000.0,
     return yarn["attention_factor"] * cos, yarn["attention_factor"] * sin
 
 
-def apply_rope(x, cos, sin):
-    """Rotate pairs of channels. x [B, H, L, Dh]; cos/sin [L, Dh/2]."""
+ROPE_PAIRS = ("split", "adjacent")
+
+
+def apply_rope(x, cos, sin, pairs: str = "split"):
+    """Rotate pairs of channels. x [B, H, L, Dh]; cos/sin [L, Dh/2].
+    ``pairs``: ``"split"`` turns channel ``i`` with ``i + Dh/2``,
+    ``"adjacent"`` channel ``2i`` with ``2i + 1``, both by the angle
+    ``i``, on the channels where they lie (each channel times its
+    pair's cosine plus its neighbour times the signed sine: no axis of
+    two is made)."""
     xf = x.astype(jnp.float32)
+    if pairs == "adjacent":
+        even = jnp.arange(x.shape[-1]) % 2 == 0
+        other = jnp.where(even, jnp.roll(xf, -1, axis=-1),
+                          jnp.roll(xf, 1, axis=-1))
+        sin = jnp.repeat(sin, 2, axis=-1)
+        return (xf * jnp.repeat(cos, 2, axis=-1)
+                + other * jnp.where(even, -sin, sin)).astype(x.dtype)
+    if pairs != "split":
+        raise ValueError(f"unknown rope pairs {pairs!r}: {ROPE_PAIRS}")
     x1, x2 = jnp.split(xf, 2, axis=-1)
     # broadcast [L, Dh/2] over [B, H, L, Dh/2]
     r1 = x1 * cos - x2 * sin
@@ -324,13 +344,16 @@ def multi_head_attention(
     key_multiplier: float = 1.0,
     window: Optional[int] = None,
     core_scope: Optional[str] = None,
+    rope_pairs: str = "split",
 ):
     """Multi-head attention over x [B, L, D] -> [B, L, D] under no
     scope of its own; a head is as wide as ``wq`` makes it (``d_model /
     n_heads`` or not). With ``window`` a query sees itself and the
     ``window - 1`` keys before it (``attention_fn`` is handed it only
     then). ``core_scope`` names the scope ``attention_fn`` is called
-    under, for a model that tells its cores apart; None opens none."""
+    under, for a model that tells its cores apart; None opens none.
+    ``rope_pairs``: the channels ``rope`` turns together
+    (:func:`apply_rope`)."""
     b, l, _ = x.shape
     n_kv = n_kv_heads or n_heads
     dh = p["wq"].shape[1] // n_heads
@@ -347,7 +370,8 @@ def multi_head_attention(
     v = proj(p["wv"], n_kv)
     if rope is not None:
         cos, sin = rope
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        q, k = (apply_rope(q, cos, sin, rope_pairs),
+                apply_rope(k, cos, sin, rope_pairs))
     windowed = {} if window is None else {"window": window}
     with (jax.named_scope(core_scope) if core_scope
           else contextlib.nullcontext()):

@@ -13,7 +13,10 @@ layers and full attention over a frozen bfloat16 base), two more of a
 tiny decoder of latent attention and expert layers with the routing
 of ``sarvam_105b`` at its published widths (``moe_mla_lora``), two of a
 small decoder whose blocks run a Mamba-2 state-space branch beside
-attention under Falcon-H1-34B's multipliers (``ssm_lora``), one
+attention under Falcon-H1-34B's multipliers (``ssm_lora``), what no
+cell checks of ``command_a_plus``'s period of parallel blocks (a layer's
+held rows beside ``rows_bound`` and the share of assignments bfloat16
+and float32 route differently: ``parallel_lora``, no round), one
 in-process HTTP federation whose workers train on the
 device, the client mesh when the host has more than one device, and
 the compile cache. Weights are random from a seed, depth is cut, data
@@ -1388,6 +1391,117 @@ def phase_window_lora(env: Env) -> None:
             f"{float(apart.mean()):.4f} of the float32 one's norm")
 
 
+def phase_parallel_lora(env: Env) -> None:
+    """What no cell checks of ``command_a_plus``: its period at the
+    published widths (three windowed layers that turn adjacent pairs and
+    a full one that turns nothing, parallel blocks under a LayerNorm,
+    32 query heads on 2, 8 of 128 experts held beside 4 shared ones
+    averaged) on one sequence of its cell's 8,192 tokens, the blocks'
+    own parts in bfloat16 beside float32 at ``highest`` with the dense
+    masked core a head at a time and a plain loop over the held experts:
+    a layer's held rows (the fullest and the emptiest held expert)
+    beside the block of sorted rows the layer handles at a time
+    (``moe.rows_bound``), the share of its assignments the two streams
+    route differently, and the grouped products at 4,096 x 4,096 alone
+    against ``ragged_dot``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from baton_tpu.models import llama, moe, transformer
+    from fedbench import data as cohort
+    from fedbench import manifest
+
+    tiny = env.rehearsal
+    root = manifest.ROOT
+    config = manifest.load_config(root, manifest.load_manifest(root),
+                                  "command_a_plus")
+    sized = manifest.sized(config, tiny)
+    job = manifest.load_workload(root, "command_a_plus_c4_l8192")
+    if tiny:
+        job.update(job["tiny"])
+    seq, seed = job["seq_len"], 17
+    decoder = manifest.resolve(config["builder"]["kwargs"]["config"], sized)
+    _check(decoder.parallel_block and decoder.norm == "layer",
+           "the configuration's block is not the parallel LayerNorm one")
+    big = llama.llama_lm_model(
+        decoder, param_dtype=jnp.float32 if tiny else jnp.bfloat16)
+    base = jax.jit(big.init)(jax.random.key(seed))
+    ids = cohort.make_cohort(
+        root, manifest.input_spec(config, tiny), np.asarray([1], np.int32),
+        1, seq, cohort.data_key(seed + 1))["x"][0]            # [1, L]
+    kinds = [llama.MIXERS[decoder.kind_of(i)]
+             for i in range(decoder.n_layers)]
+    ropes = {m: m.rope(decoder, seq) for m in dict.fromkeys(kinds)}
+    first, held = decoder.moe.first_held, decoder.moe.held
+
+    def stage(dtype, plain: bool):
+        """Every layer's choice ``[layers, L, K]`` and the stream after
+        the period, the blocks' own parts wired as ``_block_apply`` wires
+        a parallel block; ``plain``: the dense core a head at a time and
+        a loop over the held experts."""
+        attend = _dense_by_head if plain else transformer.default_attention
+
+        @jax.jit
+        def run(base):
+            x = base["tok_emb"][ids].astype(dtype)
+            chose = []
+            for m, blk in zip(kinds, base["blocks"]):
+                h = llama._normed(x, blk["norm"], decoder)
+                a = m.apply(blk[m.key], h, decoder, ropes[m], attend)
+                idx, gate = moe.route(blk["mlp"], h, decoder.moe)
+                if plain:
+                    y = _experts_one_at_a_time(
+                        blk["mlp"], h, idx - first, gate) + transformer.scaled(
+                            transformer.swiglu(blk["mlp"]["shared"], h),
+                            decoder.moe.shared_weight)
+                else:
+                    y = moe.moe_apply(blk["mlp"], h, decoder.moe)
+                x = x + a + y
+                chose.append(jnp.sort(idx[0], axis=-1))
+            return jnp.stack(chose), x[0]
+        return run(base)
+
+    chose, out = stage(jnp.float32 if tiny else jnp.bfloat16, plain=False)
+    with jax.default_matmul_precision("highest"):
+        chose32, out32 = stage(jnp.float32, plain=True)
+    chose, chose32 = np.asarray(chose), np.asarray(chose32)
+    out, out32 = np.asarray(out, np.float32), np.asarray(out32)
+    _check(np.isfinite(out).all() and np.isfinite(out32).all(),
+           "a non-finite stream")
+    n_experts, top_k = decoder.moe.n_experts, decoder.moe.top_k
+    rows = [np.bincount(c.reshape(-1), minlength=n_experts)[
+        first:first + held] for c in chose]
+    differs = np.asarray([
+        1.0 - np.mean([len(set(a) & set(b)) for a, b in zip(c, c32)]) / top_k
+        for c, c32 in zip(chose, chose32)])
+    apart = np.linalg.norm(out - out32, axis=-1) \
+        / np.linalg.norm(out32, axis=-1)
+    bound = moe.rows_bound(seq * top_k, held, n_experts)
+    expected = seq * top_k * held / n_experts
+    _check(all(0 < r.sum() <= bound for r in rows) or tiny,
+           f"a layer's held rows {[int(r.sum()) for r in rows]} do not fit "
+           f"one block of {bound}")
+    products = _expert_products(env, decoder, rows[0], bound)
+    env.say("parallel_lora",
+            f"command_a_plus's period at {'tiny' if tiny else 'the published'}"
+            f" widths, {decoder.n_heads} on {decoder.n_kv_heads} heads of "
+            f"{decoder.head_dim}, parallel blocks under a LayerNorm, "
+            f"experts {first} to {first + held - 1} of {n_experts} held, "
+            f"{top_k} a token, {decoder.moe.n_shared} shared averaged, "
+            f"{seq} tokens, seed {seed}: a layer (held rows, fullest held "
+            f"expert's, emptiest's) "
+            + " ".join(f"({r.sum()}, {r.max()}, {r.min()})" for r in rows)
+            + f" of {expected:.0f} expected in a block of {bound} "
+            f"(rows_bound); {products}, one client of the first layer's "
+            f"routing; {'float32' if tiny else 'bfloat16'} and float32 at "
+            f"highest route differently " + " ".join(
+                f"{100 * d:.2f}" for d in differs)
+            + f" % of a layer's assignments (mean "
+            f"{100 * differs.mean():.3f} %); the two streams lie apart by "
+            f"{float(apart.mean()):.4f} of the float32 one's norm")
+
+
 # ----------------------------------------------------------------------
 def _flash_alone(env: Env) -> str:
     import jax
@@ -1826,6 +1940,7 @@ PHASES = {"device": phase_device, "fedsim_resnet18": phase_fedsim_resnet18,
           "moe_mla_lora": phase_moe_mla_lora,
           "dsa_mla_lora": phase_dsa_mla_lora, "cca_lora": phase_cca_lora,
           "ssm_lora": phase_ssm_lora, "window_lora": phase_window_lora,
+          "parallel_lora": phase_parallel_lora,
           "flash_kernel": phase_flash_kernel, "http_round": phase_http_round,
           "mesh": phase_mesh, "cache": phase_cache}
 

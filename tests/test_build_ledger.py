@@ -335,9 +335,15 @@ def test_host_late_share_is_ready_syncs_over_syncs(ready, runs, share):
 
 
 def test_the_six_entries_are_the_last_of_the_list():
+    """Appended together, in this order, after everything the benchmark
+    had then; a later PR appends after them (by membership, at no fixed
+    distance from the end)."""
     bench = manifest.load_manifest(ROOT)
-    last = bench["per_layer"][-6:]
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(SET_UP_READERS[0])
+    last = bench["per_layer"][at:at + 6]
     assert [m["name"] for m in last] == SET_UP_READERS + ["host_late_share"]
+    assert "window_core_roofline" in names[:at]
     assert all("workloads" not in m for m in last)
     assert {m["layer"] for m in last[:5]} == {"set-up"}
     assert {m["moves"] for m in last[:5]} == {"setup_s"}

@@ -56,7 +56,8 @@ def test_chip_smoke_rehearsal_runs_every_phase(tmp_path):
     phase_lines = lines[:-1]
     assert all("rehearsal" in ln for ln in phase_lines)
     for phase in ("device", "fedsim_resnet18", "hybrid_lora", "moe_mla_lora",
-                  "cca_lora", "ssm_lora", "window_lora", "flash_kernel",
+                  "cca_lora", "ssm_lora", "window_lora", "parallel_lora",
+                  "flash_kernel",
                   "http_round", "mesh", "cache"):
         assert any(f"phase={phase} " in ln for ln in phase_lines), phase
     # ("skipped:" is a phase that did not run; cca_lora counts the

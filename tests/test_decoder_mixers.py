@@ -62,6 +62,8 @@ def _accepted(name: str, tiny: bool) -> LlamaConfig:
         ("parallel_ssm_attention", "falcon_h1_34b", 4096, 6, False, True),
         ("sliding_attention", "mellum2_12b", 8192, 8, False, True),
         ("full_attention", "mellum2_12b", 8192, 8, False, True),
+        ("sliding_attention", "command_a_plus", 8192, 4, False, True),
+        ("full_attention", "command_a_plus", 8192, 4, False, True),
     ])
 def test_an_entry_answers_what_the_accepted_configuration_runs(
         kind, config, cell_length, cell_kept, keeps_at_8192, kernel_at_8192):

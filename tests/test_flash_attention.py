@@ -364,15 +364,22 @@ def _window_losses(window, block_q, block_k):
 # the larger block): a multiple of both tiles, of neither, one tile, one
 # key, and one longer than the sequence (the causal kernel's result)
 WINDOWS = [(16, 8, 16), (20, 16, 8), (7, 16, 16), (1, 8, 8), (100, 16, 16)]
+# query heads on key-value heads: the cells' small groups, and 16 query
+# heads on one key-value head, whose dk and dv the backward sums over
+# the group outside the kernel
+HEADS = [(w, (4, 2)) for w in WINDOWS] + [((20, 16, 8), (16, 1))]
 
 
-@pytest.mark.parametrize("window,block_q,block_k", WINDOWS)
+@pytest.mark.parametrize(
+    "window,block_q,block_k,heads", [w + (h,) for w, h in HEADS],
+    ids=[f"{w[0]}-{w[1]}-{w[2]}" + ("" if h == (4, 2) else f"-{h[0]}on{h[1]}")
+         for w, h in HEADS])
 def test_a_window_forward_and_all_three_gradients(window, block_q, block_k,
-                                                  backward_form):
+                                                  heads, backward_form):
     """The windowed kernels against the dense masked core with grouped
-    heads (4 query heads on 2): the output and the gradients of q, k
-    and v, in both forms of the backward."""
-    q, k, v = _qkv(11, 2, 4, 2, 48, 8)
+    heads (4 query heads on 2, 16 on 1): the output and the gradients
+    of q, k and v, in both forms of the backward."""
+    q, k, v = _qkv(11, 2, *heads, 48, 8)
     dense, flash = _window_losses(window, block_q, block_k)
     want = jax.value_and_grad(dense, argnums=(0, 1, 2))(q, k, v)
     got = jax.value_and_grad(flash, argnums=(0, 1, 2))(q, k, v)

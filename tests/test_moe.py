@@ -189,6 +189,63 @@ def test_four_ranks_of_one_expert_add_up_under_the_softmax_router(nprng):
     _close(got, want, rtol=1e-4)
 
 
+# the sigmoid router with no bias and no scale beside shared experts
+# that are averaged: 8 ranks of 2 of 16 experts, 2 a token, 2 shared
+AVERAGED = MoEConfig(n_experts=16, top_k=2, d_ff=F, n_shared=2,
+                     shared_combine="average")
+
+
+def _swiglu_of_a_slice(shared, x, j):
+    at = slice(j * F, (j + 1) * F)
+    return (jax.nn.silu(x @ shared["w_gate"][:, at])
+            * (x @ shared["w_up"][:, at])) @ shared["w_down"][at]
+
+
+def test_averaged_shared_experts_are_the_mean_of_their_slices(nprng):
+    """``shared_combine="average"``: the wide SwiGLU times ``1 /
+    n_shared`` is the mean of the ``n_shared`` SwiGLUs made from slices
+    of its matrices (columns of ``w_gate`` and ``w_up``, rows of
+    ``w_down``); summed, as every other configuration has them, it is
+    ``n_shared`` times that."""
+    p = moe_init(jax.random.key(2), D, 4 * F, AVERAGED)
+    assert p["shared"]["w_gate"].shape == (D, 2 * F)
+    x = jnp.asarray(nprng.normal(size=(2, 16, D)), jnp.float32)
+    no_routed = dict(p, w_down=jnp.zeros_like(p["w_down"]))
+    mean = sum(_swiglu_of_a_slice(p["shared"], x, j) for j in range(2)) / 2
+    _close(moe_apply(no_routed, x, AVERAGED), mean)
+    summed = dataclasses.replace(AVERAGED, shared_combine="sum")
+    _close(moe_apply(no_routed, x, summed), 2 * mean)
+    _close(moe_apply(p, x, AVERAGED), moe_dense_oracle(p, x, AVERAGED))
+    assert (AVERAGED.shared_weight, summed.shared_weight) == (0.5, 1.0)
+    with pytest.raises(ValueError, match="shared_combine"):
+        moe_apply(p, x, dataclasses.replace(AVERAGED, shared_combine="max"))
+
+
+def test_the_ranks_parts_and_the_shared_mean_once_add_up(nprng):
+    """16 ranks holding 8 of 128 experts (here 8 ranks of 2 of 16) each
+    compute their part of what the sigmoid router chose among all of
+    them; the parts, with the averaged shared experts counted once,
+    add up to the oracle's whole layer."""
+    p = moe_init(jax.random.key(3), D, 4 * F, AVERAGED)
+    x = jnp.asarray(nprng.normal(size=(2, 16, D)), jnp.float32)
+    whole = moe_dense_oracle(p, x, AVERAGED)
+    parts = jnp.zeros_like(x)
+    for first in range(0, 16, 2):
+        cut = dataclasses.replace(AVERAGED, experts_held=2, first_held=first,
+                                  n_shared=0)
+        held = {k: (v[first:first + 2] if k.startswith("w_") else v)
+                for k, v in p.items() if k != "shared"}
+        drawn = moe_init(jax.random.key(3), D, 4 * F, cut)
+        assert jnp.array_equal(drawn["w_gate"], held["w_gate"])
+        parts = parts + moe_apply(held, x, cut)
+    shared_once = sum(_swiglu_of_a_slice(p["shared"], x, j)
+                      for j in range(2)) / 2
+    _close(parts + shared_once, whole)
+    _close(moe_apply(p, x, AVERAGED), whole)
+    # every rank adding the shared mean would count it eight times
+    assert float(jnp.max(jnp.abs(parts + 8 * shared_once - whole))) > 1e-2
+
+
 def test_the_bias_chooses_and_does_not_weigh(nprng):
     p = _params(WHOLE)
     x = jnp.asarray(nprng.normal(size=(1, 64, D)), jnp.float32)
@@ -607,7 +664,7 @@ def test_the_chips_grouped_product_is_ragged_dot(transpose_rhs, k, budget,
         _gmm(x[:500], w, sizes, transpose_rhs, interpret=True)
 
 
-# the grouped products of the four configurations with experts, ``K x
+# the grouped products of the five configurations with experts, ``K x
 # N``, and the ``(tk, tn)`` each was read fastest at on the chip
 # (moe.py's table): the contraction whole, the widest column tile that
 # fits beside it
@@ -620,6 +677,7 @@ _CELLS_PRODUCTS = {
     "sarvam_105b_down": (2048, 4096, (2048, 1024)),
     "glm_5_up": (6144, 2048, (6144, 256)),
     "glm_5_down": (2048, 6144, (2048, 1024)),
+    "command_a_plus_both": (4096, 4096, (4096, 512)),
     # 1,408 = 11 x 128: only 128 and itself divide it
     "only_128_divides_the_columns": (2048, 1408, (2048, 128)),
     "only_128_divides_the_contraction": (1408, 2048, (1408, 1024)),

@@ -388,7 +388,7 @@ LOCATION = re.compile(
     r',? ?(source_file="[^"]*"|source_line=\d+|source_end_line=\d+'
     r'|source_column=\d+|source_end_column=\d+|stack_frame_id=\d+)')
 UNCHANGED = ["olmo_hybrid_7b", "sarvam_105b", "glm_5", "zaya1_8b",
-             "bert_base", "falcon_h1_34b"]
+             "bert_base", "falcon_h1_34b", "mellum2_12b"]
 
 
 def program_digest(name: str) -> dict:

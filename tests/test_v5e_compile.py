@@ -430,7 +430,7 @@ def test_a_windowed_block_holds_no_square_and_its_kernels_clamp_both_sides(
         assert not any("value = 1023 : i32" in f for f in maps)
 
 
-# the grouped products of the four cells with experts: ``K x N``, the
+# the grouped products of the five cells with experts: ``K x N``, the
 # experts held, and the sorted rows one product handles there
 # (``moe.rows_bound`` of the folded clients' assignments, or one
 # client's where the fold would pass ``moe._FOLDED_ROWS_BYTES``)
@@ -442,6 +442,7 @@ _GROUPED_PRODUCTS = {
     "sarvam_105b_c4_l2048-2048x4096": (2048, 4096, 16, 16384),
     "glm5_c4_l8192-6144x2048": (6144, 2048, 8, 16384),
     "glm5_c4_l8192-2048x6144": (2048, 6144, 8, 16384),
+    "command_a_plus_c4_l8192-4096x4096": (4096, 4096, 8, 32768),
 }
 
 
