@@ -35,6 +35,22 @@ Params = Any  # a pytree of arrays
 Batch = Mapping[str, Any]
 PRNGKey = jax.Array
 
+# the ``vmap`` axis over the clients a wave trains side by side on one
+# device (on a mesh: over a device's share of them)
+WAVE_AXIS = "wave_clients"
+
+
+def clients_in_wave() -> int:
+    """How many clients the program being traced trains side by side on
+    a device: the extent of the engine's client ``vmap`` where the trace
+    runs under one, else 1 (one client's training, jitted alone). Under
+    the ``vmap`` a model sees one client's shapes; what it holds in
+    memory is this many times that."""
+    try:
+        return jax.lax.axis_size(WAVE_AXIS)
+    except NameError:  # the axis is not bound
+        return 1
+
 
 @dataclasses.dataclass(frozen=True)
 class FedModel:

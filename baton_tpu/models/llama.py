@@ -31,7 +31,9 @@ channels a rotation turns together. ``multipliers`` are the fixed
 scalars a model publishes for its projections, the embedding and the
 logits (:class:`~baton_tpu.models.transformer.Multipliers`; at 1, as
 every other model has them, they add no op). A block is traced once a
-kind (of mixer and of feed-forward), whatever the depth.
+kind (of mixer and of feed-forward), whatever the depth, and with
+``remat`` once more a kind where the trailing blocks keep their
+products (:func:`blocks_kept`).
 
 * params fp32 / activations ``compute_dtype`` (bf16 on TPU), norms,
   softmax and the experts' router in fp32;
@@ -60,12 +62,14 @@ as the framework contract requires (core/model.py).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Tuple
+import math
+from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
-from baton_tpu.core.model import FedModel
+from baton_tpu.core.model import FedModel, clients_in_wave
 from baton_tpu.core.partition import path_str
 from baton_tpu.models.delta_rule import gated_delta_apply, gated_delta_init
 from baton_tpu.models.lora import lora_wrap
@@ -76,11 +80,11 @@ from baton_tpu.models.state_space import SSMConfig, mamba2_apply, mamba2_init
 from baton_tpu.models.transformer import (
     ROPE_PAIRS, AttentionFn, CCAConfig, MLAConfig, Multipliers,
     attention_is_kernel, cca_apply, cca_core_is_kernel, cca_init,
-    default_attention, dense_init, head_products_a_block, layer_norm, matmul,
-    mha_apply, mha_init, mla_apply, mla_core_is_kernel, mla_init,
-    mla_qk_layout, mla_rope_angles, multi_head_attention, next_token_loss,
-    normal_init, rms_init, rms_norm, rope_angles, scaled, swiglu_apply,
-    swiglu_init, tied_logits)
+    default_attention, dense_init, head_products_a_block, layer_norm,
+    logits_held, matmul, mha_apply, mha_init, mla_apply, mla_core_is_kernel,
+    mla_init, mla_qk_layout, mla_rope_angles, multi_head_attention,
+    next_token_loss, normal_init, rms_init, rms_norm, rope_angles, scaled,
+    swiglu_apply, swiglu_init, tied_logits)
 
 # a decoder's norm by ``LlamaConfig.norm``: both hold a ``scale`` alone
 _NORMS = {"rms": rms_norm, "layer": layer_norm}
@@ -537,18 +541,125 @@ def _block_facts(cfg: LlamaConfig) -> tuple:
     return tuple(said.items())
 
 
-def _checkpointed_block():
+# the operations whose results a kept block saves for its backward:
+# the products, the recurrences' scans and the kernels, what a second
+# forward spends its time on. What lies between them (elementwise
+# passes, norms, reductions, reshapes) the backward makes again where it
+# reads it, which costs a pass over memory and halves what a block holds
+_PRODUCTS = frozenset({
+    "dot_general", "ragged_dot_general", "conv_general_dilated", "scan",
+    "while", "cond", "pallas_call", "sort", "gather", "scatter",
+    "scatter-add", "dynamic_slice", "dynamic_update_slice", "cumsum",
+    "cumprod", "cummax", "cumlogsumexp", "triangular_solve", "top_k"})
+
+
+def _products_saveable(prim, *_, **__) -> bool:
+    return prim.name in _PRODUCTS
+
+
+def _checkpointed_block(keeps: Optional[bool] = False):
     """:func:`_block_apply` as a ``remat`` model runs it: nothing of a
     block survives to its backward but a flash kernel's two outputs,
     where its attention is one (as much memory again as the block's
     input or so, and the dearest thing in a block to make again: a
     second run of the forward kernel). Where none runs nothing carries
-    those names and the checkpoint is the bare one."""
+    those names and the checkpoint is the bare one. With ``keeps`` the
+    checkpoint saves every product (:data:`_PRODUCTS`) and its backward
+    makes no product again: a trailing block whose residuals fit the
+    device (:func:`blocks_kept`). With ``keeps=None`` it saves
+    everything, which no block runs: the plan reads a block's whole
+    backward off it. Whatever the policy, ``jax.checkpoint`` keeps one
+    trace of the block's forward a kind of block."""
     from baton_tpu.ops.flash_attention import KEPT_OUTPUTS
 
+    policies = jax.checkpoint_policies
     return jax.checkpoint(
         _block_apply, static_argnums=(3, 5),
-        policy=jax.checkpoint_policies.save_only_these_names(*KEPT_OUTPUTS))
+        policy=policies.everything_saveable if keeps is None
+        else _products_saveable if keeps
+        else policies.save_only_these_names(*KEPT_OUTPUTS))
+
+
+def _tiled_bytes(aval) -> int:
+    """An array's bytes as the chip lays it out: of its axes longer
+    than 1 the two minor ones padded to a tile, ``(8, 128)`` of 32-bit
+    entries and as many bytes of narrower ones."""
+    item = jnp.dtype(aval.dtype).itemsize
+    shape = [n for n in aval.shape if n != 1]
+    if shape:
+        shape[-1] = -(-shape[-1] // 128) * 128
+    if len(shape) > 1:
+        rows = 8 * max(1, 4 // item)
+        shape[-2] = -(-shape[-2] // rows) * rows
+    return math.prod(shape) * item
+
+
+def residual_bytes(fn, p, *rest) -> int:
+    """The bytes the backward of ``fn(p, *rest)`` holds of its forward,
+    the parameters ``p`` themselves apart (they are held whatever is
+    differentiated), as the chip lays them out: the residuals of its
+    linearisation by every argument, read from one abstract trace,
+    nothing compiled. A ``jax.checkpoint`` inside ``fn``, or ``fn``
+    itself being one, counts as what its policy saves; a ``custom_vjp``
+    as what its forward rule hands on."""
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (p, *rest))
+    jaxpr = jax.make_jaxpr(
+        lambda *args: jax.linearize(fn, *args)[1])(*shapes).jaxpr
+    given = set(jaxpr.invars[:len(jax.tree_util.tree_leaves(p))])
+    kept = {v for v in jaxpr.outvars
+            if isinstance(v, jax.extend.core.Var) and v not in given}
+    return sum(_tiled_bytes(v.aval) for v in kept)
+
+
+def plan_bytes(k: int, fixed: int, loss: int, checkpointed: Sequence[int],
+               kept: Sequence[int], whole: Sequence[int]) -> int:
+    """An estimate, made to err high, of the bytes a training step's
+    plan holds with the last ``k`` blocks keeping their products. The
+    step holds ``fixed`` throughout and ``checkpointed[i]`` of every
+    block that keeps nothing; ``kept[i]`` is what a block holds that
+    keeps its products and ``whole[i]`` what its backward holds of its
+    forward in all, every intermediate a derivative reads. Two moments
+    compete for the peak. After the forward every kept block's
+    ``kept[i]`` is held, and beside them the larger of ``loss``, what
+    the loss holds while it runs, and the last block's backward, which
+    makes the rest of that block's ``whole`` again. In the backward of
+    a checkpointed block no kept block holds anything (they are the
+    trailing ones, their backward came first and a block's residuals go
+    as it ends) and that block's ``whole`` lies there made again."""
+    first = len(kept) - k
+    after_forward = sum(kept[first:]) + max(
+        loss, whole[-1] - kept[-1] if k else 0)
+    made_again = max(whole[:first], default=0)
+    return fixed + sum(checkpointed[:first]) + max(after_forward, made_again)
+
+
+def blocks_kept(budget: Optional[int], fixed: int, loss: int,
+                checkpointed: Sequence[int], kept: Sequence[int],
+                whole: Sequence[int]) -> int:
+    """How many trailing blocks run with their products kept: the
+    largest ``k`` whose :func:`plan_bytes` is within ``budget``; 0
+    without a budget (a device
+    :func:`baton_tpu.utils.profiling.hbm_budget_gb` does not know), or
+    where no ``k`` fits: the program with every block checkpointed."""
+    if budget is not None:
+        for k in range(len(kept), 0, -1):
+            if plan_bytes(k, fixed, loss, checkpointed, kept,
+                          whole) <= budget:
+                return k
+    return 0
+
+
+def _plan_budget_bytes() -> Optional[int]:
+    """The plan budget of the device the program is traced for, the one
+    ``FedSim.auto_wave_size`` holds a wave's plan to; None for a device
+    the table does not hold (the CPU)."""
+    from baton_tpu.utils.profiling import hbm_budget_gb
+
+    try:
+        return int(hbm_budget_gb(jax.devices()[0]) * 2 ** 30)
+    except ValueError:
+        return None
 
 
 def core_outputs_kept(cfg: LlamaConfig, backend: str, batch: int, length: int,
@@ -589,11 +700,20 @@ def llama_lm_model(
     param_dtype=jnp.float32,
 ) -> FedModel:
     """``remat=True`` wraps each decoder block in ``jax.checkpoint``:
-    the backward pass recomputes block activations instead of storing
-    them, cutting activation memory from O(L·n_layers) to O(L) at ~1/3
-    extra FLOPs — what makes long-sequence / large-model training
-    (config 4) fit HBM; a flash kernel's output and log-sum-exp are kept
-    (:func:`_checkpointed_block`). ``param_dtype`` is the dtype ``init``
+    the backward pass makes a block's forward again instead of storing
+    its activations, which cuts activation memory from O(L·n_layers) to
+    O(L) and costs a second forward, what makes long-sequence /
+    large-model training (config 4) fit HBM; a flash kernel's output
+    and log-sum-exp are kept (:func:`_checkpointed_block`). The trade
+    is made only as far as the device's memory asks: on a device whose
+    plan budget is known (``profiling.hbm_budget_gb``) the trailing
+    blocks whose products fit beside the rest of the step keep them
+    and make none again (:func:`blocks_kept`, from the batch's shapes
+    and the clients of the wave where the model is traced; nothing a
+    caller sets), and ``baton.round`` says how many (``blocks_kept``)
+    and on what (``kept_block_bytes``, ``plan_estimate_bytes``). On
+    any other device (the CPU) every block is checkpointed.
+    ``param_dtype`` is the dtype ``init``
     gives the matrices and an expert layer's 3-D stacks (a base that
     stays frozen is held in bfloat16); vectors (norm scales, the linear layers'
     ``a_log`` and ``dt_bias``, a router's bias) and the router itself
@@ -608,9 +728,23 @@ def llama_lm_model(
     # the block's checkpoint, which would make it a third time
     ff_fn = (jax.checkpoint(_feed_forward, static_argnums=(3,)) if remat
              else _feed_forward)
+    # the trailing blocks whose residuals fit the device (``_kept``)
+    # keep their products: the same checkpoints, no product made again
+    block_keeps = _checkpointed_block(keeps=True)
+    ff_keeps = jax.checkpoint(
+        _feed_forward, static_argnums=(3,), policy=_products_saveable)
+    # and with everything kept, for the plan to read (``_kept``)
+    block_whole = _checkpointed_block(keeps=None)
+    ff_whole = jax.checkpoint(
+        _feed_forward, static_argnums=(3,),
+        policy=jax.checkpoint_policies.everything_saveable)
     stateful = cfg.moe is not None and cfg.moe.router_hidden is not None
     on = cfg.multipliers
     seen = {}  # what ``_hidden`` learned of the batch it was last traced on
+    # a client's bytes of a block as it is checkpointed, as it keeps its
+    # products and whole, by the block's kind and the batch's shape
+    # (``_kept``): a program's later traces read what its first found
+    parts = {}
     if stateful and cfg.first_dense_layers:
         raise NotImplementedError(
             "a router's state runs through every layer: no dense layer "
@@ -647,6 +781,63 @@ def llama_lm_model(
             lambda path, a: a.astype(param_dtype) if a.ndim >= 2
             and "/router/" not in path_str(path) + "/" else a, params)
 
+    def _kept(params, ids, ropes) -> int:
+        """How many trailing blocks keep their products on sequences
+        ``ids`` (:func:`blocks_kept`), with what the choice rests on
+        for ``baton.round``; 0 and no estimate on a device without a
+        plan budget. The plan is made from shapes alone. A block's part
+        is read off an abstract trace a kind of block
+        (:func:`residual_bytes`): as it is checkpointed, as it keeps
+        and with everything kept, times the clients of the wave,
+        and what it keeps a quarter more: compiled for a v5e the
+        hybrid's kept blocks held 0.97 to 1.16 times the count (PERF.md
+        section 5). Held throughout: the parameters once and a client
+        the float32 leaves' value, gradient, update and weighted sum
+        (what a client trains is float32, a frozen base is not), and
+        the stream at both ends of the blocks, in its own dtype and as
+        the loss's float32 ``dx``. The loss holds a block of logits,
+        its softmax and the cast of it
+        (:func:`~baton_tpu.models.transformer.next_token_loss`)."""
+        budget = _plan_budget_bytes()
+        if budget is None:
+            return 0
+        b, l = ids.shape
+        clients = clients_in_wave()
+        x = jax.ShapeDtypeStruct((b, l, cfg.d_model), compute_dtype)
+        r = (jax.ShapeDtypeStruct((b, l, cfg.moe.router_hidden), jnp.float32)
+             if stateful else None)
+
+        def part(m, blk):
+            inner = m.keeps_its_inputs(cfg, l)
+            kind = (m, b, l, jax.tree_util.tree_structure(blk), tuple(
+                a.shape for a in jax.tree_util.tree_leaves(blk)))
+            if kind not in parts:
+                parts[kind] = tuple(
+                    residual_bytes(
+                        lambda p, x, r, rope: fn(p, x, r, cfg) if inner
+                        else fn(p, x, r, cfg, rope, attention_fn),
+                        blk, x, r, ropes[m])
+                    for fn in ((ff_fn, ff_keeps, ff_whole) if inner
+                               else (block_fn, block_keeps, block_whole)))
+            return parts[kind]
+
+        sizes = [part(m, blk) for m, blk in zip(mixers, params["blocks"])]
+        checkpointed = [clients * size[0] for size in sizes]
+        kept = [clients * size[1] * 5 // 4 for size in sizes]
+        whole = [max(clients * size[2], keeps)
+                 for size, keeps in zip(sizes, kept)]
+        leaves = jax.tree_util.tree_leaves(params)
+        stream = _tiled_bytes(x)
+        fixed = sum(a.size * a.dtype.itemsize for a in leaves) + clients * (
+            4 * sum(a.size * 4 for a in leaves if a.dtype == jnp.float32)
+            + 4 * stream + 2 * stream * 4 // x.dtype.itemsize)
+        loss = clients * 3 * logits_held(b, l, params["tok_emb"].shape[0])
+        plan = (fixed, loss, checkpointed, kept, whole)
+        k = blocks_kept(budget, *plan)
+        seen.update(kept_block_bytes=max(kept),
+                    plan_estimate_bytes=plan_bytes(k, *plan))
+        return k
+
     def _hidden(params, batch):
         """The final norm's output ``[B, L, D]``."""
         ids = batch["x"]
@@ -655,6 +846,9 @@ def llama_lm_model(
             cfg, jax.default_backend(), *ids.shape, attention_fn)
         # a layer's angles are its kind's, made once a kind a trace
         ropes = {m: m.rope(cfg, l) for m in dict.fromkeys(mixers)}
+        if remat:
+            seen["blocks_kept"] = _kept(params, ids, ropes)
+        kept_from = cfg.n_layers - seen.get("blocks_kept", 0)
         for m in ropes:
             seen.update(m.seen(cfg, l))
         if cfg.moe is not None:
@@ -675,10 +869,11 @@ def llama_lm_model(
                         raise NotImplementedError(
                             "a parallel block stands whole under its "
                             "checkpoint: no mixer that keeps its inputs")
-                    x, r = ff_fn(blk, _mix(blk, x, cfg, rope, attention_fn),
-                                 r, cfg)
+                    x, r = (ff_keeps if i >= kept_from else ff_fn)(
+                        blk, _mix(blk, x, cfg, rope, attention_fn), r, cfg)
                 else:
-                    x, r = block_fn(blk, x, r, cfg, rope, attention_fn)
+                    x, r = (block_keeps if i >= kept_from else block_fn)(
+                        blk, x, r, cfg, rope, attention_fn)
         return _normed(x, params["norm_f"], cfg)
 
     def apply(params, batch, rng):
@@ -741,7 +936,9 @@ def decoder_lora_model(
     """The decoder as a frozen base held in ``param_dtype`` with rank-
     ``rank`` adapters on every projection of its mixers and MLPs
     (:func:`projection_lora_target`), applied to activations; train it
-    with ``FedSim(..., trainable=lora_trainable)``."""
+    with ``FedSim(..., trainable=lora_trainable)``. ``remat`` is
+    :func:`llama_lm_model`'s: every block under a checkpoint, of which
+    the trailing ones keep their products where the device has room."""
     base = llama_lm_model(config, compute_dtype=compute_dtype, remat=remat,
                           param_dtype=param_dtype, name="decoder_lm")
     return lora_wrap(base, rank=rank, alpha=alpha,

@@ -1370,6 +1370,12 @@ def per_token_cross_entropy(logits, labels):
 _LOGITS_BLOCK_BYTES = 128 * 1024 ** 2
 
 
+def logits_held(b: int, l: int, vocab: int) -> int:
+    """The bytes of float32 logits :func:`next_token_loss` holds at a
+    time over ``[b, l]`` tokens."""
+    return min(4 * b * l * vocab, _LOGITS_BLOCK_BYTES)
+
+
 def tied_logits(x, table):
     """``x [..., D]`` against the embedding ``table [V, D]`` itself,
     float32: the head of a model whose embeddings are tied, contracted

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from baton_tpu.core.model import WAVE_AXIS
 from baton_tpu.ops import aggregation as agg
 from baton_tpu.parallel.engine import FedSim
 
@@ -128,7 +129,7 @@ class ClusteredFedSim:
                 )
                 return new_p, losses
 
-            trained, closs = jax.vmap(one)(
+            trained, closs = jax.vmap(one, axis_name=WAVE_AXIS)(
                 my_params, data, n_samples, rngs
             )
 

@@ -39,7 +39,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh
 
-from baton_tpu.core.model import FedModel
+from baton_tpu.core.model import WAVE_AXIS, FedModel
 from baton_tpu.core.partition import PathPredicate, make_partition
 from baton_tpu.core.training import LocalTrainer, make_local_trainer, make_evaluator
 from baton_tpu.obs.compute import ComputeProbe
@@ -402,8 +402,8 @@ class FedSim:
             return p, losses
 
         with jax.named_scope("local_train"):
-            client_params, client_losses = jax.vmap(one_client)(
-                data, n_samples, rngs)
+            client_params, client_losses = jax.vmap(
+                one_client, axis_name=WAVE_AXIS)(data, n_samples, rngs)
         with jax.named_scope("wave_sums"):
             w = n_samples.astype(jnp.float32)
             psum = agg.weighted_tree_sum(client_params, w)
@@ -442,7 +442,8 @@ class FedSim:
             )
             return p, losses
 
-        return jax.vmap(one_client)(data, n_samples, rngs)
+        return jax.vmap(one_client, axis_name=WAVE_AXIS)(
+            data, n_samples, rngs)
 
     # donation decided no: same retained-anchor contract as
     # _wave_sums_vmap

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from baton_tpu.core.model import WAVE_AXIS
 from baton_tpu.ops import aggregation as agg
 from baton_tpu.parallel.engine import FedSim
 from baton_tpu.parallel.mesh import (
@@ -151,7 +152,8 @@ class FedBuff:
             )
             return new_p, losses
 
-        return jax.vmap(one)(anchors, data, n_samples, rngs)
+        return jax.vmap(one, axis_name=WAVE_AXIS)(
+            anchors, data, n_samples, rngs)
 
     def _train_buffer(self, anchors, data, n_samples, rngs, n_epochs,
                       frozen):

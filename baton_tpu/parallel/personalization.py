@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from baton_tpu.core.model import WAVE_AXIS
 from baton_tpu.core.partition import PathPredicate, make_partition
 from baton_tpu.ops import aggregation as agg
 from baton_tpu.parallel.engine import FedSim, client_eval_sums
@@ -123,7 +124,8 @@ class FedPer:
                 new_pers, new_shared = part.split(new_full)
                 return new_pers, new_shared, losses
 
-            return jax.vmap(one)(personal_state, data, n_samples, rngs)
+            return jax.vmap(one, axis_name=WAVE_AXIS)(
+                personal_state, data, n_samples, rngs)
 
         return train_local
 

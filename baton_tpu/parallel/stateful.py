@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from baton_tpu.core.model import WAVE_AXIS
 from baton_tpu.ops import aggregation as agg
 from baton_tpu.parallel.engine import FedSim, _server_update
 
@@ -93,7 +94,8 @@ class StatefulClients:
                 )
                 return new_p, new_os, losses
 
-            return jax.vmap(one)(opt_states, data, n_samples, rngs)
+            return jax.vmap(one, axis_name=WAVE_AXIS)(
+                opt_states, data, n_samples, rngs)
 
         return train_local
 

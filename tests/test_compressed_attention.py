@@ -556,13 +556,16 @@ def test_the_blocks_that_keep_a_cores_outputs(backend, length, kept):
 def test_the_model_says_what_its_last_trace_keeps(remat, kernel_core):
     """``span_attrs`` gains ``core_outputs_kept`` where the model is
     traced and meets a length: with the selector forced, every block of
-    a ``remat`` model, none of one that holds no checkpoint."""
+    a ``remat`` model, none of one that holds no checkpoint; and a
+    ``remat`` model says how many blocks keep their products, none on a
+    device without a plan budget (``llama.blocks_kept``)."""
     model = llama_lm_model(DECODER, remat=remat)
     static = dict(model.span_attrs)
     assert "core_outputs_kept" not in static
     params = jax.eval_shape(model.init, jax.random.key(0))
     jax.eval_shape(model.per_example_loss, params, _batch(1, length=32), None)
     assert dict(model.span_attrs) == {
-        **static, "core_outputs_kept": 3 if remat else 0}
+        **static, "core_outputs_kept": 3 if remat else 0,
+        **({"blocks_kept": 0} if remat else {})}
     assert model.span_attrs == tuple(static.items())
     assert hash(model.span_attrs) == hash(tuple(static.items()))

@@ -438,9 +438,15 @@ def test_multipliers_of_one_leave_the_other_models_programs_alone(name):
     programs the benchmark measures, and rename none (the roofline
     metrics read the names). ``falcon_h1_34b``'s entry was taken on PR
     47's tree, before the window, the ``yarn`` rotation and the softmax
-    router came (PR 48), and is held with the others from then on. A PR
-    that means to change one of these programs writes the file anew and
-    says so."""
+    router came (PR 48), and is held with the others from then on. PR
+    53's choice of the blocks that keep their products
+    (``llama.blocks_kept``) did not move them: it rests on the device's
+    plan budget, the CPU these programs are lowered for has none
+    (``profiling.hbm_budget_gb``), so every block is checkpointed as it
+    was and no estimate is traced; and the name PR 53 gave the engine's
+    client ``vmap`` (``core.model.WAVE_AXIS``) is in no instruction. A
+    PR that means to change one of these programs writes the file anew
+    and says so."""
     got = program_digest(name)
     out = os.environ.get("BATON_WRITE_DIGESTS")
     if out:

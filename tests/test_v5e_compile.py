@@ -474,3 +474,69 @@ def test_a_grouped_product_fits_the_chips_vmem_at_the_tiles_it_picks(
             assert text.count('custom_call_target="tpu_custom_call"') == 1
     finally:
         jax.default_backend = backend
+
+
+def test_kept_blocks_hold_no_more_than_the_plan_says(one_chip, monkeypatch):
+    """Two blocks of ``olmo_hybrid_7b`` at the published widths (one of
+    the gated delta rule, one of full attention) over the frozen
+    bfloat16 base with rank-16 adapters, four clients of 1,024 tokens
+    under the engine's ``vmap``, ``olmo_hybrid_c4_l1024``'s step: value
+    and the adapters' gradients compiled with both blocks checkpointed
+    and with both keeping their products. The estimate a model makes
+    from shapes alone (``llama.plan_bytes``, what ``llama.blocks_kept``
+    chooses by) is an upper bound where the compiler can be asked: of
+    the whole plan both times, and the kept blocks add no more to the
+    temporaries than the estimate holds them to keep."""
+    import dataclasses
+    import json
+    import pathlib
+
+    from baton_tpu.core.model import WAVE_AXIS
+    from baton_tpu.models import llama
+    from baton_tpu.utils import profiling
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    monkeypatch.syspath_prepend(str(root))
+    from fedbench import manifest
+
+    config = json.loads(
+        (root / "fedbench" / "configs" / "olmo_hybrid_7b.json").read_text())
+    cfg = manifest.resolve(config["builder"]["kwargs"]["config"], config)
+    cfg = dataclasses.replace(cfg, n_layers=2,
+                              layer_types=cfg.layer_types[2:4])
+    assert cfg.layer_types == ("linear_attention", "full_attention")
+    clients, length = 4, 1024
+    # the model asks the backend and the device where it is traced
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setitem(profiling.HBM_BUDGET_GB, "cpu", 13.5)
+
+    def step(model):
+        def loss(lora, base, x, y):
+            return jnp.sum(model.per_example_loss(
+                {"base": base, "lora": lora}, {"x": x, "y": y}, None))
+
+        return lambda lora, base, x, y: jax.vmap(
+            jax.value_and_grad(loss), in_axes=(None, None, 0, 0),
+            axis_name=WAVE_AXIS)(lora, base, x, y)
+
+    plans = {}
+    for k in (0, cfg.n_layers):
+        monkeypatch.setattr(llama, "blocks_kept", lambda *plan, k=k: k)
+        model = llama.decoder_lora_model(cfg)
+        params = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(model.init, jax.random.key(0)))
+        ids = jax.ShapeDtypeStruct((clients, 1, length), jnp.int32,
+                                   sharding=one_chip)
+        memory = jax.jit(step(model)).lower(
+            params["lora"], params["base"], ids, ids).compile(
+                ).memory_analysis()
+        said = dict(model.span_attrs)
+        assert said["blocks_kept"] == k
+        plans[k] = (memory.temp_size_in_bytes, memory.argument_size_in_bytes
+                    + memory.output_size_in_bytes, said)
+    (temp, held, said), (temp_kept, _, said_kept) = plans[0], plans[2]
+    assert temp + held <= said["plan_estimate_bytes"]
+    assert temp_kept + held <= said_kept["plan_estimate_bytes"]
+    assert 0 < temp_kept - temp <= 2 * said_kept["kept_block_bytes"]
